@@ -822,11 +822,7 @@ class MeshQueryExecutor:
         est = _COLLECTIVE_BENCH.get(key)
         if est is None:
             P = jax.sharding.PartitionSpec
-            if hasattr(jax, "shard_map"):
-                shard_map = jax.shard_map
-            else:
-                from jax.experimental.shard_map import shard_map
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 lambda x: jax.lax.psum(x, SEGMENT_AXIS), mesh=self.mesh,
                 in_specs=(P(),), out_specs=P()))
             arr = jax.device_put(np.zeros(bucket, np.float32),
@@ -1324,11 +1320,6 @@ class MeshQueryExecutor:
                     and len(shape) > key_dim and shape[key_dim] == num_seg
                     and not name.endswith((".min", ".max")))
 
-        if hasattr(jax, "shard_map"):
-            shard_map = jax.shard_map
-        else:  # jax < 0.5: shard_map not yet promoted out of experimental
-            from jax.experimental.shard_map import shard_map
-
         built: Dict[str, Any] = {}
 
         def fn(inputs):
@@ -1364,7 +1355,7 @@ class MeshQueryExecutor:
                     name: ((P(None, ax) if batch else P(ax))
                            if name in scat else repl)
                     for name in out_shapes}
-                built["fn"] = jax.jit(shard_map(
+                built["fn"] = jax.jit(jax.shard_map(
                     shard_body, mesh=self.mesh, in_specs=in_specs,
                     out_specs=out_specs))
                 compiled = built["fn"]

@@ -70,4 +70,8 @@ def default_mesh(n_devices: Optional[int] = None, axis: str = SEGMENT_AXIS) -> j
     n = n_devices or len(devices)
     if n > len(devices):
         raise ValueError(f"requested {n} devices, only {len(devices)} available")
-    return jax.make_mesh((n,), (axis,), devices=devices[:n])
+    # Auto axis type: the kernels are plain jitted code over mesh-placed arrays
+    # and leave sharding propagation to the compiler; jax 0.9's default
+    # (Explicit) would type-check every gather/slice of a sharded dim instead.
+    return jax.make_mesh((n,), (axis,), devices=devices[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,))
