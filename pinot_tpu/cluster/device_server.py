@@ -36,6 +36,7 @@ host path.
 
 from __future__ import annotations
 
+import logging
 import queue
 import threading
 import time
@@ -46,6 +47,9 @@ from typing import Dict, List, Optional, Sequence
 
 from ..utils.faults import FaultInjected, fault_point
 from ..utils.metrics import get_registry
+
+
+log = logging.getLogger(__name__)
 
 
 class _Sentinel:
@@ -125,6 +129,11 @@ class DeviceQueryPipeline:
         self.batches = 0
         self.dispatched = 0
         self.fallbacks = 0
+        # the device path RAISED (vs. `fallbacks`: the plan is not
+        # device-eligible) — the host still answers, but never silently
+        self.device_errors = 0
+        self._error_counter = get_registry().counter(
+            "pinot_server_device_errors")
         self.timeouts = 0
         self.launches = 0
         self.dedupe_hits = 0
@@ -151,6 +160,15 @@ class DeviceQueryPipeline:
             self._thread.start()
         if not self._fetcher.is_alive():
             self._fetcher.start()
+
+    def record_error(self, where: str) -> None:
+        """Call from an `except` block on the device path: log the traceback
+        and count it apart from plan fallbacks (`deviceErrors` in stats(),
+        `pinot_server_device_errors` in /metrics)."""
+        self.device_errors += 1
+        self._error_counter.inc()
+        log.exception("device path raised in %s; the host path answers",
+                      where)
 
     def _observe(self, stage: str, ms: float) -> None:
         self._stage_ms[stage].append(ms)
@@ -321,10 +339,12 @@ class DeviceQueryPipeline:
             try:
                 p = self.mesh_exec.prepare_partial(item.ctx, item.segments)
             except Exception:
-                # planning failed on the device path (e.g. a shape the mesh
-                # planner missets) — the host path is the answer, not a
-                # query error
-                p = None
+                # planning RAISED on the device path: the host path still
+                # answers the query, but as a counted, logged device error —
+                # not as a plan fallback
+                self.record_error("prepare_partial")
+                _resolve(item.future, DEVICE_FALLBACK)
+                continue
             if p is None:
                 self.fallbacks += 1
                 _resolve(item.future, DEVICE_FALLBACK)
@@ -346,12 +366,12 @@ class DeviceQueryPipeline:
         try:
             launches = self.mesh_exec.dispatch_prepared(reps)
         except Exception:
-            # a grouped launch failing (e.g. a stacked-shape trace the
-            # executor mishandles) downgrades to host execution for the
-            # whole drain — availability over the fast path
+            # a grouped launch failing downgrades the whole drain to host
+            # execution (availability over the fast path) — logged and
+            # counted as ONE device error, not as plan fallbacks
+            self.record_error("dispatch_prepared")
             for group in rep_groups:
                 for item, _ in group:
-                    self.fallbacks += 1
                     _resolve(item.future, DEVICE_FALLBACK)
             return [], 0
         self.stacked_launches += sum(1 for _, _, idxs in launches
@@ -387,7 +407,9 @@ class DeviceQueryPipeline:
             try:
                 dp = self.mesh_exec.dispatch_partial(item.ctx, item.segments)
             except Exception:
-                dp = None
+                self.record_error("dispatch_partial")
+                _resolve(item.future, DEVICE_FALLBACK)
+                continue
             if dp is None:
                 self.fallbacks += 1
                 _resolve(item.future, DEVICE_FALLBACK)
@@ -473,7 +495,8 @@ class DeviceQueryPipeline:
 
     def stats(self) -> dict:
         out = {"batches": self.batches, "dispatched": self.dispatched,
-               "fallbacks": self.fallbacks, "timeouts": self.timeouts,
+               "fallbacks": self.fallbacks,
+               "deviceErrors": self.device_errors, "timeouts": self.timeouts,
                "launches": self.launches, "dedupeHits": self.dedupe_hits,
                "stackedLaunches": self.stacked_launches,
                "fusedLaunches": self.fused_launches,

@@ -778,7 +778,10 @@ class ServerNode:
                         out = self.device_pipeline.execute_partial(ctx,
                                                                    admitted)
                     except Exception:
-                        out = DEVICE_FALLBACK  # device fault -> host answers
+                        # fetch/decode raised on the device path: the host
+                        # answers, the pipeline logs and counts the error
+                        self.device_pipeline.record_error("execute_partial")
+                        out = DEVICE_FALLBACK
                 if out is not DEVICE_FALLBACK:
                     device_partial = out
                     reg.counter("pinot_server_device_queries",

@@ -112,11 +112,7 @@ def _valid(caps: KernelCaps) -> bool:
 def platform_key() -> str:
     """Cache key: caps measured on one platform must not leak onto another."""
     import jax
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        kind = "unknown"
-    return f"{jax.default_backend()}:{kind}"
+    return f"{jax.default_backend()}:{jax.devices()[0].device_kind}"
 
 
 def cache_path() -> str:
@@ -271,13 +267,9 @@ def get_caps() -> KernelCaps:
     if _ACTIVE is None:
         caps = load_cached_caps() or KernelCaps()
         if os.environ.get("PINOT_TPU_CALIBRATE") == "1":
-            try:
-                caps = calibrate()
-                save_cached_caps(caps)
-            # graftcheck: ignore[exception-hygiene] -- calibration is
-            # best-effort by design; the defaults still dispatch correctly
-            except Exception:
-                pass  # calibration is best-effort; defaults still dispatch
+            # asked for explicitly: a failed calibration raises
+            caps = calibrate()
+            save_cached_caps(caps)
         _ACTIVE = _env_overrides(caps)
     return _ACTIVE
 

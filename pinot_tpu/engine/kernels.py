@@ -223,18 +223,17 @@ _ROOFLINE_GBPS: Optional[float] = None
 
 
 def _nominal_hbm_gbps() -> float:
-    """The platform's nominal HBM bandwidth (the same 819 GB/s constant
-    bench.py's platform_calibration publishes), overridable via
-    PINOT_TPU_HBM_GBPS for other parts/backends."""
+    """The device's published peak HBM bandwidth from the `device_kind`-keyed
+    table (utils/device_peaks.py; an unknown non-CPU device raises there),
+    overridable via PINOT_TPU_HBM_GBPS."""
     global _NOMINAL_HBM_GBPS
     if _NOMINAL_HBM_GBPS is None:
-        try:
-            _NOMINAL_HBM_GBPS = float(os.environ.get("PINOT_TPU_HBM_GBPS",
-                                                     "819"))
-        except ValueError:
-            _NOMINAL_HBM_GBPS = 819.0
-        if _NOMINAL_HBM_GBPS <= 0:
-            _NOMINAL_HBM_GBPS = 819.0
+        env = os.environ.get("PINOT_TPU_HBM_GBPS")
+        gbps = float(env) if env else 0.0
+        if gbps <= 0:
+            from ..utils.device_peaks import device_peak
+            gbps = device_peak()["hbm_gbps"]
+        _NOMINAL_HBM_GBPS = gbps
     return _NOMINAL_HBM_GBPS
 
 

@@ -62,25 +62,25 @@ _PUBLISH_INTERVAL_S = 0.05
 def device_capacity_bytes() -> Tuple[int, bool]:
     """(capacity_bytes, estimated): the device memory budget headroom is
     computed against. Order: env override, jax `memory_stats()["bytes_limit"]`,
-    then a flagged 16 GiB estimate (CPU backends report no limit)."""
+    then — on the CPU backend only, which reports no limit — a flagged 16 GiB
+    estimate. An accelerator that reports no `bytes_limit` raises: budgeting
+    admission against a guess there is a wrong answer that looks right."""
     env = os.environ.get("PINOT_TPU_HBM_CAPACITY_BYTES")
     if env:
         try:
             return max(1, int(env)), False
         except ValueError:
             pass
-    try:
-        import jax
-        stats = jax.local_devices()[0].memory_stats()
-        limit = int((stats or {}).get("bytes_limit", 0))
-        if limit > 0:
-            return limit, False
-    # graftcheck: ignore[exception-hygiene] -- memory_stats() is optional
-    # backend introspection (absent/raising on CPU); the flagged-estimate
-    # return below IS the observable outcome of this probe failing
-    except Exception:
-        pass
-    return _DEFAULT_CAPACITY, True
+    import jax
+    dev = jax.local_devices()[0]
+    if dev.platform == "cpu":
+        return _DEFAULT_CAPACITY, True
+    limit = int((dev.memory_stats() or {}).get("bytes_limit", 0))
+    if limit <= 0:
+        raise RuntimeError(
+            f"{dev.device_kind!r} reports no memory_stats()['bytes_limit']; "
+            "set PINOT_TPU_HBM_CAPACITY_BYTES / server.hbm.capacity.bytes")
+    return limit, False
 
 
 def live_device_bytes() -> Optional[int]:

@@ -104,6 +104,17 @@ def test_get_caps_bogus_cache_uses_defaults(tmp_path, monkeypatch,
     assert got.source == "default"
 
 
+def test_requested_calibration_failure_raises(monkeypatch, restore_caps):
+    """PINOT_TPU_CALIBRATE=1 asks for a calibration: a failed one is an error,
+    not a silent return to the defaults."""
+    def boom():
+        raise RuntimeError("calibration failed")
+    monkeypatch.setenv("PINOT_TPU_CALIBRATE", "1")
+    monkeypatch.setattr(cal, "calibrate", boom)
+    with pytest.raises(RuntimeError, match="calibration failed"):
+        cal.set_caps(None)  # re-resolves through get_caps()
+
+
 def test_env_override_wins_over_cache(tmp_path, monkeypatch, restore_caps):
     path = str(tmp_path / "caps.json")
     cal.save_cached_caps(_caps(), path=path)

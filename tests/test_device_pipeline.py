@@ -220,6 +220,61 @@ def test_fallback_and_stage_timings():
         pipeline.stop()
 
 
+class _Boom(RuntimeError):
+    pass
+
+
+class _RaisingPrepare(FakeMeshExec):
+    def prepare_partial(self, ctx, segments):
+        if ctx.get("boom"):
+            raise _Boom("prepare")
+        return super().prepare_partial(ctx, segments)
+
+
+class _RaisingDispatch(FakeMeshExec):
+    def dispatch_prepared(self, reps):
+        raise _Boom("dispatch")
+
+
+class _RaisingLegacy:
+    def dispatch_partial(self, ctx, segments):
+        raise _Boom("legacy")
+
+
+@pytest.mark.parametrize("fake,where", [
+    (_RaisingPrepare, "prepare_partial"),
+    (_RaisingDispatch, "dispatch_prepared"),
+    (_RaisingLegacy, "dispatch_partial"),
+])
+def test_device_exception_is_logged_and_counted_apart(fake, where, caplog):
+    """The device path RAISING is not a plan fallback: the host still answers
+    (DEVICE_FALLBACK), but the traceback is logged and `deviceErrors` counts
+    it; a None plan increments only `fallbacks`."""
+    pipeline = DeviceQueryPipeline(mesh_exec=fake(), start=False)
+    try:
+        with caplog.at_level("ERROR", logger="pinot_tpu.cluster.device_server"):
+            results = _submit_concurrently(
+                pipeline, [{"shape": "A", "literal": 1, "boom": True}])
+        assert results[0] is DEVICE_FALLBACK
+        s = pipeline.stats()
+        assert s["deviceErrors"] == 1 and s["fallbacks"] == 0
+        rec = [r for r in caplog.records if where in r.getMessage()]
+        assert rec and rec[0].exc_info and rec[0].exc_info[0] is _Boom
+    finally:
+        pipeline.stop()
+    if fake is _RaisingLegacy:
+        return
+    quiet = DeviceQueryPipeline(mesh_exec=FakeMeshExec(), start=False)
+    try:
+        results = _submit_concurrently(
+            quiet, [{"shape": "A", "literal": 1, "fallback": True}])
+        assert results[0] is DEVICE_FALLBACK
+        s = quiet.stats()
+        assert s["deviceErrors"] == 0 and s["fallbacks"] == 1
+    finally:
+        quiet.stop()
+
+
 def test_legacy_executor_without_prepared_api():
     class LegacyExec:
         def __init__(self):

@@ -47,6 +47,49 @@ def _gauge_value(name, **labels):
     return None
 
 
+# -- unknown device != default device ------------------------------------------
+
+class _FakeDevice:
+    def __init__(self, platform, kind, stats=None):
+        self.platform, self.device_kind, self._stats = platform, kind, stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("dev,gbps", [
+    (_FakeDevice("cpu", "cpu"), 819.0),
+    (_FakeDevice("tpu", "TPU v5 lite"), 819.0),
+    (_FakeDevice("tpu", "TPU v99"), None),
+])
+def test_device_peak_keyed_by_kind(monkeypatch, dev, gbps):
+    import jax
+    from pinot_tpu.utils.device_peaks import device_peak
+    monkeypatch.setattr(jax, "devices", lambda *a: [dev])
+    if gbps is None:
+        with pytest.raises(RuntimeError, match="unknown device kind"):
+            device_peak()
+    else:
+        assert device_peak()["hbm_gbps"] == gbps
+
+
+@pytest.mark.parametrize("dev,want", [
+    (_FakeDevice("cpu", "cpu"), (16 << 30, True)),
+    (_FakeDevice("tpu", "TPU v5 lite", {"bytes_limit": 123}), (123, False)),
+    (_FakeDevice("tpu", "TPU v5 lite", None), None),
+])
+def test_capacity_estimate_is_cpu_only(monkeypatch, dev, want):
+    import jax
+    from pinot_tpu.utils.memledger import device_capacity_bytes
+    monkeypatch.delenv("PINOT_TPU_HBM_CAPACITY_BYTES", raising=False)
+    monkeypatch.setattr(jax, "local_devices", lambda *a: [dev])
+    if want is None:
+        with pytest.raises(RuntimeError, match="bytes_limit"):
+            device_capacity_bytes()
+    else:
+        assert device_capacity_bytes() == want
+
+
 # -- ledger arithmetic --------------------------------------------------------
 
 def test_register_release_and_filters(ledger):
