@@ -38,6 +38,8 @@ def _controller(args):
 
 def cmd_start_role(args) -> int:
     from ..cluster import process
+    from ..utils.compile_cache import place_compile_cache
+    place_compile_cache()
     if args.cmd == "start-controller":
         process.run_controller(args.work_dir, args.run_dir, args.port, args.config)
     elif args.cmd == "start-server":
@@ -345,6 +347,8 @@ def build_parser() -> argparse.ArgumentParser:
 def cmd_start_service_manager(args) -> int:
     """Reference: StartServiceManagerCommand — all roles in one process."""
     from ..cluster.process import run_service_manager
+    from ..utils.compile_cache import place_compile_cache
+    place_compile_cache()
     run_service_manager(args.work_dir, args.run_dir, args.port, args.config)
     return 0
 

@@ -212,6 +212,30 @@ def test_process_cluster_device_mode(tmp_path, ssb_schema):
         assert st["device"]["batches"] >= 1, st
 
 
+@pytest.mark.parametrize("device_enabled", [False, True])
+def test_process_cluster_role_env(monkeypatch, device_enabled):
+    """One process for each chip: only a device server inherits the platform;
+    every other role (and a host-engine server) is held to CPU jax."""
+    from pinot_tpu.cluster.process import ProcessCluster
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.setenv("XLA_FLAGS", "--some_flag")
+    monkeypatch.setattr(ProcessCluster, "_spawn", lambda *a, **k: None)
+    monkeypatch.setattr(ProcessCluster, "_await_ready",
+                        lambda *a, **k: "http://127.0.0.1:1")
+    server_env = ({"PINOT_TPU_SERVER_DEVICE_ENABLED": "true"}
+                  if device_enabled else None)
+    cluster = ProcessCluster(num_servers=1, server_env=server_env)
+    for role in ("controller", "broker", "minion"):
+        env = cluster._role_env(role)
+        assert env["JAX_PLATFORMS"] == "cpu" and "XLA_FLAGS" not in env
+    env = cluster._role_env("server")
+    if device_enabled:
+        assert env["JAX_PLATFORMS"] == "tpu"
+        assert env["XLA_FLAGS"] == "--some_flag"
+    else:
+        assert env["JAX_PLATFORMS"] == "cpu" and "XLA_FLAGS" not in env
+
+
 def test_served_high_card_groupby_differential(tmp_path, ssb_schema):
     """High-cardinality GROUP BY through the SERVED device path (the
     chunked kernel feeding an UNTRIMMED server partial that the broker

@@ -174,3 +174,36 @@ def test_cli_against_http_cluster(tmp_path, capsys):
         brc.close()
         for s in (csvc, ssvc, bsvc):
             s.stop()
+
+
+# -- compile cache placement ----------------------------------------------------
+
+@pytest.mark.parametrize("env_dir", [None, "/operator/chose/this"])
+def test_place_compile_cache(monkeypatch, env_dir):
+    """Env set -> code sets no directory (JAX reads the variable itself); env
+    unset -> the fixed in-checkout path, the same on every call."""
+    import os
+
+    import jax
+
+    import pinot_tpu
+    from pinot_tpu.utils.compile_cache import place_compile_cache
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    first, second = place_compile_cache(), place_compile_cache()
+    assert first == second
+    dirs = [v for k, v in updates if k == "jax_compilation_cache_dir"]
+    if env_dir is None:
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(pinot_tpu.__file__)))
+        assert first == os.path.join(checkout, ".jax_cache")
+        assert dirs == [first, first]
+    else:
+        assert first == env_dir and dirs == []
+    # small scan kernels must be written too
+    assert ("jax_persistent_cache_min_compile_time_secs", 0.0) in updates
