@@ -330,6 +330,7 @@ def _fence_first_call(fn):
         _account_cost(state["cost"])
         return fn(*args, **kwargs)
 
+    call.__wrapped__ = fn  # the jitted callable (AOT lowering in tests)
     return call
 
 
@@ -1096,6 +1097,21 @@ def compute_filter_count(spec: KernelSpec,
     and `lax.population_count` reduces them, so no per-row mask is ever
     materialized. Returns None when the filter doesn't evaluate fully in the
     word domain (caller falls back to the mask kernel)."""
+    fn = filter_count_kernel(spec)
+    if fn is None:
+        return None
+    # the staged packed valid keeps the whole count O(P/32); packing on the
+    # fly (upsert valid-doc intersection) is the O(P) exception
+    vw = inputs.valid_words
+    if vw is None:
+        vw = _pack_valid(inputs.valid)
+    return int(fetch_outputs(fn(vw, inputs.bitmaps)))
+
+
+def filter_count_kernel(spec: KernelSpec):
+    """Cached jit of the word-domain COUNT kernel, fn(valid_words, bitmaps) ->
+    uint32 count; None when the filter doesn't evaluate fully in the word
+    domain."""
     if _make_word_fn(spec) is None:
         return None
     key = ("bitcount", spec.filter.signature(), spec.padded_rows,
@@ -1110,13 +1126,7 @@ def compute_filter_count(spec: KernelSpec,
 
         return jax.jit(body)
 
-    fn = _cached_kernel(key, build)
-    # the staged packed valid keeps the whole count O(P/32); packing on the
-    # fly (upsert valid-doc intersection) is the O(P) exception
-    vw = inputs.valid_words
-    if vw is None:
-        vw = _pack_valid(inputs.valid)
-    return int(fetch_outputs(fn(vw, inputs.bitmaps)))
+    return _cached_kernel(key, build)
 
 
 def topk_kernel(spec: KernelSpec, order_expr, desc: bool, k: int,

@@ -341,6 +341,32 @@ class SegmentSetBlock:
         return self._stack("valid", "", False, per_seg)
 
 
+def _pack_kernel(meta: Tuple, trim_keys: Tuple[int, int], batched: bool):
+    """Cached jit of `MeshQueryExecutor._pack`'s device-side concatenation for
+    one output layout `meta` = sorted (name, shape, dtype str) triples."""
+    key = ("pack", meta, trim_keys, batched)
+    fn = _SHARD_KERNEL_CACHE.get(key)
+    if fn is None:
+        pad, real = trim_keys
+
+        def pack_impl(outs):
+            by_dt: Dict[str, list] = {}
+            for name, shape, dts in meta:
+                v = outs[name]
+                core = shape[1:] if batched else shape
+                if pad and real < pad and core and core[0] in (pad, pad + 1):
+                    v = v[:, :real] if batched else v[:real]
+                flat = v.reshape((v.shape[0], -1)) if batched \
+                    else v.reshape(-1)
+                by_dt.setdefault(dts, []).append(flat)
+            return {dt: (jnp.concatenate(parts, axis=-1)
+                         if len(parts) > 1 else parts[0])
+                    for dt, parts in by_dt.items()}
+        fn = jax.jit(pack_impl)
+        _SHARD_KERNEL_CACHE[key] = fn
+    return fn
+
+
 class MeshQueryExecutor:
     """Executes aggregation queries over segment sets sharded across a device mesh."""
 
@@ -744,8 +770,7 @@ class MeshQueryExecutor:
         meta = tuple(sorted((k, tuple(v.shape), v.dtype.str)
                             for k, v in outs_dev.items()))
         pad, real = trim_keys
-        key = ("pack", meta, trim_keys, bool(batched))
-        fn = _SHARD_KERNEL_CACHE.get(key)
+        fn = _pack_kernel(meta, trim_keys, bool(batched))
 
         # grouped outputs carry the key axis at either `pad` (reduce-scattered
         # dense outputs, overflow bucket dropped on device) or `pad + 1` (the
@@ -757,23 +782,6 @@ class MeshQueryExecutor:
             if pad and real < pad and core and core[0] in (pad, pad + 1):
                 core = (real,) + tuple(core[1:])
             return core
-
-        if fn is None:
-            def pack_impl(outs):
-                by_dt: Dict[str, list] = {}
-                for name, shape, dts in meta:
-                    v = outs[name]
-                    core = shape[1:] if batched else shape
-                    if pad and real < pad and core and core[0] in (pad, pad + 1):
-                        v = v[:, :real] if batched else v[:real]
-                    flat = v.reshape((v.shape[0], -1)) if batched \
-                        else v.reshape(-1)
-                    by_dt.setdefault(dts, []).append(flat)
-                return {dt: (jnp.concatenate(parts, axis=-1)
-                             if len(parts) > 1 else parts[0])
-                        for dt, parts in by_dt.items()}
-            fn = jax.jit(pack_impl)
-            _SHARD_KERNEL_CACHE[key] = fn
 
         def unpack(host: Dict[str, np.ndarray], b: Optional[int] = None):
             out = {}
@@ -1322,7 +1330,9 @@ class MeshQueryExecutor:
 
         built: Dict[str, Any] = {}
 
-        def fn(inputs):
+        def jitted_for(inputs):
+            """The jit(shard_map(...)) for these inputs (arrays, or
+            ShapeDtypeStructs for an AOT lowering), built at first use."""
             compiled = built.get("fn")
             if compiled is None:
                 # learn output names/shapes from the per-shard input shapes
@@ -1337,6 +1347,9 @@ class MeshQueryExecutor:
                 out_shapes = jax.eval_shape(call_body, shard_in)
                 scat = {name for name, s in out_shapes.items()
                         if scatterable(name, s.shape)}
+                if scat:
+                    get_registry().counter(
+                        "pinot_kernel_scatter_builds").inc()
 
                 def shard_body(sin):
                     outs = call_body(sin)
@@ -1355,12 +1368,15 @@ class MeshQueryExecutor:
                     name: ((P(None, ax) if batch else P(ax))
                            if name in scat else repl)
                     for name in out_shapes}
-                built["fn"] = jax.jit(jax.shard_map(
+                built["fn"] = compiled = jax.jit(jax.shard_map(
                     shard_body, mesh=self.mesh, in_specs=in_specs,
                     out_specs=out_specs))
-                compiled = built["fn"]
-            return compiled(inputs)
+            return compiled
 
+        def fn(inputs):
+            return jitted_for(inputs)(inputs)
+
+        fn.jitted_for = jitted_for
         return fn
 
 

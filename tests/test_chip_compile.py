@@ -1,0 +1,286 @@
+"""Ask the TPU compiler before the chip: the served path's kernels, compiled
+for a DESCRIBED v5e:2x2 at chip_smoke.py's real widths (no chip attached,
+nothing runs — a compile that passes is not a chip run).
+
+The plans come from the real planner over small segments drawn from
+chip_smoke's own generator (same schema, distributions and key spaces, so the
+dictionary widths and regime choices are the real ones); only the stacked
+block's [segments, rows] extent is replaced by the smoke's, as abstract
+`ShapeDtypeStruct`s placed on the described devices.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load libtpu, and every xdist worker imports this file.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+import chip_smoke
+from pinot_tpu.engine import kernels
+from pinot_tpu.engine.calibrate import get_caps, set_caps
+from pinot_tpu.parallel import combine
+from pinot_tpu.parallel.combine import MeshQueryExecutor
+from pinot_tpu.parallel.mesh import SEGMENT_AXIS, default_mesh
+from pinot_tpu.query.context import compile_query
+from pinot_tpu.segment import load_segment
+from pinot_tpu.segment.writer import SegmentBuilder, SegmentGeneratorConfig
+
+SMOKE_SEGS = chip_smoke.DEFAULT_ROWS // chip_smoke.SEGMENT_ROWS   # 16
+SEG_ROWS = chip_smoke.SEGMENT_ROWS                                # 4Mi
+#: the largest stacked block (16Mi rows) that keeps the f32-exact one-hot
+#: matmul regimes; past it every group-by takes a sort regime
+MATMUL_SEGS = (1 << 24) // SEG_ROWS
+FIXTURE_ROWS = 1 << 20          # 500k keys stay dictionary-encoded (<= 0.7 x rows)
+SQL = dict(chip_smoke.QUERIES)
+#: inputs replicated over the mesh (everything else carries the segment axis)
+_REPLICATED = ("luts", "iscal", "fscal", "strides")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without the chip; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def segments(tmp_path_factory):
+    """Two small segments from the smoke's generator at its real key spaces."""
+    out = tmp_path_factory.mktemp("chipcompile")
+    names = np.array(chip_smoke.REGIONS, dtype=object)
+    builder = SegmentBuilder(chip_smoke.lineorder_schema(),
+                             SegmentGeneratorConfig(
+                                 inverted_index_columns=["lo_region"]))
+    segs = []
+    for i in range(2):
+        cols = chip_smoke.segment_columns(0, i, FIXTURE_ROWS,
+                                          chip_smoke.SUPPKEYS,
+                                          chip_smoke.CUSTKEYS)
+        segs.append(load_segment(builder.build(
+            dict(cols, lo_region=names[cols["lo_region"]]), str(out),
+            f"lineorder_{i}")))
+    return segs
+
+
+@pytest.fixture(scope="module")
+def cpu_exec():
+    """Plans and stages the small segments on one CPU device."""
+    return MeshQueryExecutor(default_mesh(1))
+
+
+def _mesh(topo, n):
+    return Mesh(np.array(topo.devices[:n]), (SEGMENT_AXIS,),
+                axis_types=(jax.sharding.AxisType.Auto,))
+
+
+def _abstract(inputs, small, real, mesh):
+    """The prepared inputs' tree as ShapeDtypeStructs on `mesh`, the stacked
+    block extent `small` = (s_pad, rows) replaced by `real`."""
+    (s0, r0), (s1, r1) = small, real
+    out = {}
+    for key, val in inputs.items():
+        sharded = key not in _REPLICATED
+
+        def leaf(x, sharded=sharded):
+            shape = tuple(x.shape)
+            if sharded:
+                assert shape[0] == s0, (key, shape)
+                shape = (s1,) + shape[1:]
+                if len(shape) > 1 and shape[1] == r0:
+                    shape = (s1, r1) + shape[2:]
+            return jax.ShapeDtypeStruct(
+                shape, x.dtype,
+                sharding=NamedSharding(mesh, P(SEGMENT_AXIS) if sharded
+                                       else P()))
+        out[key] = jax.tree_util.tree_map(leaf, val)
+    return out
+
+
+def _prepare(cpu_exec, segments, name):
+    ctx = compile_query(SQL[name], segments[0].schema)
+    p = cpu_exec.prepare_partial(ctx, segments)
+    assert p is not None, f"{name}: the plan is not device-eligible"
+    return p
+
+
+def _real_spec(spec):
+    return kernels.KernelSpec(spec.filter, spec.group_cols, spec.num_keys_pad,
+                              spec.aggs, spec.distinct_lut_sizes, SEG_ROWS,
+                              mv_cols=spec.mv_cols,
+                              bitmap_leaves=spec.bitmap_leaves,
+                              fused_cols=spec.fused_cols)
+
+
+def _compile_agg(topo, cpu_exec, segments, name, segs, n_devices=1):
+    """Compile the served shard kernel of QUERIES[name] at [segs, SEG_ROWS]
+    on `n_devices` described chips; returns (prepared, compiled)."""
+    p = _prepare(cpu_exec, segments, name)
+    mesh = _mesh(topo, n_devices)
+    ax = _abstract(p.inputs, (p.s_pad, p.rows), (segs, SEG_ROWS), mesh)
+    chip_exec = MeshQueryExecutor(mesh)
+    fn = chip_exec._build_shard_kernel(_real_spec(p.spec))
+    return p, fn.jitted_for(ax).lower(ax).compile()
+
+
+def _fits(compiled, hbm_bytes=16e9):
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes)
+    assert total < hbm_bytes, f"program needs {total / 1e9:.1f} GB"
+
+
+# (smoke query, stacked segments, forced high-card regime or None)
+AGG_CASES = [
+    pytest.param("q1.1 filter+sum", SMOKE_SEGS, None, id="q1.1-fused-scan"),
+    pytest.param("group-by region", MATMUL_SEGS, None, id="lowcard-onehot"),
+    pytest.param("group-by 20k keys", MATMUL_SEGS, None, id="20k-chunk64"),
+    pytest.param("group-by 500k keys", SMOKE_SEGS, "partitioned",
+                 id="500k-partitioned"),
+    pytest.param("group-by 500k keys", SMOKE_SEGS, "sorted",
+                 id="500k-sorted"),
+    pytest.param("group-by region", SMOKE_SEGS, None, id="region-at-smoke"),
+    pytest.param("group-by 20k keys", SMOKE_SEGS, None, id="20k-at-smoke"),
+    pytest.param("bitmap-filter count", SMOKE_SEGS, None, id="lut-count"),
+    pytest.param("distinctcounthll", SMOKE_SEGS, None, id="hll-presence"),
+]
+
+
+@pytest.mark.parametrize("name,segs,regime", AGG_CASES)
+def test_served_agg_kernel_compiles_for_v5e(topo, cpu_exec, segments, name,
+                                            segs, regime):
+    prev = get_caps()
+    if regime is not None:
+        from dataclasses import replace
+        set_caps(replace(prev, high_card_regime=regime))
+    try:
+        p, compiled = _compile_agg(topo, cpu_exec, segments, name, segs)
+    finally:
+        if regime is not None:
+            set_caps(prev)
+    _fits(compiled)
+    if name == "q1.1 filter+sum":
+        # the served scan decodes its dict columns in-register
+        assert p.spec.fused_cols, "q1.1 no longer rides the fused decode"
+
+
+def test_topk_kernel_compiles_for_v5e(topo, cpu_exec, segments):
+    p = _prepare(cpu_exec, segments, "top-k")
+    assert p.kind == "topk"
+    ctx = compile_query(SQL["top-k"], segments[0].schema)
+    order = ctx.order_by[0]
+    from pinot_tpu.query.executor import ServerQueryExecutor
+    from pinot_tpu.query.planner import plan_segment
+    plan = plan_segment(ctx, segments[0])
+    spec = kernels.KernelSpec(plan.filter_prog, (), 1, (), {}, SEG_ROWS)
+    fn, _ = kernels.topk_kernel(
+        spec, order.expr, order.desc,
+        ctx.limit + ServerQueryExecutor.TOPK_SLACK,
+        total_rows=SMOKE_SEGS * SEG_ROWS)
+    mesh = _mesh(topo, 1)
+    ax = _abstract(p.inputs, (p.s_pad, p.rows), (SMOKE_SEGS, SEG_ROWS), mesh)
+    compiled = fn.__wrapped__.lower(
+        ax["ids"], ax["vals"], ax["luts"], ax["iscal"], ax["fscal"],
+        ax["nulls"], ax["valid"], ()).compile()
+    _fits(compiled)
+
+
+def test_pack_kernel_compiles_for_v5e(topo, cpu_exec, segments):
+    """The device-side output concatenation, with the key-axis trim of the
+    500k-key partial (the slice jax 0.9's explicit axes refused)."""
+    p = _prepare(cpu_exec, segments, "group-by 500k keys")
+    pad, real = p.trim_keys
+    assert pad and real < pad
+    mesh = _mesh(topo, 1)
+    ax = _abstract(p.inputs, (p.s_pad, p.rows), (SMOKE_SEGS, SEG_ROWS), mesh)
+    fn = MeshQueryExecutor(mesh)._build_shard_kernel(_real_spec(p.spec))
+    outs = jax.eval_shape(fn.jitted_for(ax), ax)
+    repl = NamedSharding(mesh, P())
+    outs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=repl)
+            for k, v in outs.items()}
+    meta = tuple(sorted((k, tuple(v.shape), v.dtype.str)
+                        for k, v in outs.items()))
+    compiled = combine._pack_kernel(meta, p.trim_keys, False).lower(
+        outs).compile()
+    _fits(compiled)
+
+
+def test_bitmap_word_kernel_compiles_for_v5e(topo, segments):
+    """The packed-word COUNT kernel of the per-segment engine path at one
+    4Mi-row segment (the mesh path evaluates the same filter as a LUT leaf)."""
+    from jax.sharding import SingleDeviceSharding
+    from pinot_tpu.query.planner import plan_segment, select_bitmap_leaves
+    ctx = compile_query(SQL["bitmap-filter count"], segments[0].schema)
+    plan = plan_segment(ctx, segments[0])
+    leaves = select_bitmap_leaves(plan, segments[0])
+    assert leaves, "lo_region = 'ASIA' no longer qualifies for the bitmap"
+    spec = kernels.KernelSpec(plan.filter_prog, (), 1, (), {}, SEG_ROWS,
+                              bitmap_leaves=leaves)
+    fn = kernels.filter_count_kernel(spec)
+    assert fn is not None
+    one = SingleDeviceSharding(topo.devices[0])
+    words = jax.ShapeDtypeStruct((SEG_ROWS // 32,), jnp.uint32, sharding=one)
+    bitmaps = tuple(jax.ShapeDtypeStruct((1, SEG_ROWS // 32), jnp.uint32,
+                                         sharding=one) for _ in leaves)
+    _fits(fn.__wrapped__.lower(words, bitmaps).compile())
+
+
+@pytest.mark.parametrize("name,collective", [
+    ("q1.1 filter+sum", "all-reduce"),
+    ("group-by 20k keys", "reduce-scatter"),
+])
+def test_four_chip_program_compiles_with_its_collective(topo, cpu_exec,
+                                                        segments, name,
+                                                        collective):
+    """The four-chip program: the stacked [S, P] shard kernel on a Mesh of the
+    described devices; >= SCATTER_MIN_KEYS dense sums reduce-scatter."""
+    _, compiled = _compile_agg(topo, cpu_exec, segments, name, SMOKE_SEGS,
+                               n_devices=4)
+    _fits(compiled)
+    text = compiled.as_text()
+    if collective == "reduce-scatter":
+        # the compiler may lower a small reduce-scatter as all-reduce + slice
+        assert "reduce-scatter" in text or (
+            "all-reduce" in text and "dynamic-slice" in text)
+        # each device keeps 1/4 of the key space: the outputs stay sharded
+        assert all(s.spec == P(SEGMENT_AXIS) for s in
+                   jax.tree_util.tree_leaves(compiled.output_shardings))
+    else:
+        assert collective in text
+
+
+@pytest.mark.parametrize("variant", ["pallas", "xla"])
+def test_pallas_scan_compiles_for_v5e(topo, variant):
+    from jax.sharding import SingleDeviceSharding
+    from pinot_tpu.engine import pallas_scan
+    one = SingleDeviceSharding(topo.devices[0])
+    n = 1 << 24
+    assert n % pallas_scan.BLOCK_ROWS == 0
+    i32 = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one)
+    f32 = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one)
+    bands = [(19930101, 19931231), (1, 3), (-(1 << 31), 24)]
+    impl = (pallas_scan.masked_sums_pallas if variant == "pallas"
+            else pallas_scan.masked_sums_xla)
+    compiled = jax.jit(lambda *a: impl(a[:3], bands, a[3:])).lower(
+        i32, i32, i32, f32, f32).compile()
+    if variant == "pallas":
+        assert "tpu_custom_call" in compiled.as_text()
