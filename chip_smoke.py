@@ -438,6 +438,7 @@ def main() -> int:
         raise SystemExit(f"--rows {args.rows} is too small for the real key "
                          "spaces; sizes are cut only with --rehearse")
 
+    t_start = time.perf_counter()
     jax, cache_dir = describe_platform(args)
     chips = args.chips or 1
     mem_before = [(d.memory_stats() or {}).get("bytes_in_use")
@@ -475,7 +476,8 @@ def main() -> int:
     finally:
         stop_services(handles)
         shutil.rmtree(work, ignore_errors=True)
-    print(f"compile cache: {len(os.listdir(cache_dir))} entries at end")
+    print(f"compile cache: {len(os.listdir(cache_dir))} entries at end; "
+          f"smoke took {time.perf_counter() - t_start:.0f} s")
     d = jax.devices()[0]
     print(json.dumps({"ok": True, "device": {
         "platform": d.platform, "kind": d.device_kind,

@@ -251,8 +251,8 @@ class SegmentBlock:
         into the kernel and decode in-register, so no decoded column is ever
         written back to HBM. The staged ladder rung keeps this path for
         shapes where in-kernel decode loses (oversized decode tables,
-        multi-value value columns, relay platforms whose calibration probe
-        measured device gathers as an extra host round trip per dispatch).
+        multi-value value columns, platforms whose calibration probe
+        measured in-kernel gathers slower than the staged decode).
         """
         reader = self.segment.column(col)
         if not reader.has_dictionary:
