@@ -196,10 +196,10 @@ def describe_platform(args):
     import jaxlib
 
     from pinot_tpu import native
+    from importlib import metadata
     try:
-        from importlib.metadata import version
-        libtpu = version("libtpu")
-    except Exception:  # noqa: BLE001 - version string only
+        libtpu = metadata.version("libtpu")
+    except metadata.PackageNotFoundError:
         libtpu = "absent"
     devs = jax.devices()
     want = args.chips or 1
@@ -244,7 +244,8 @@ def stop_services(handles) -> None:
         handles[role].stop()
 
 
-def load_table(handles, work: str, args, suppkeys: int, custkeys: int) -> dict:
+def load_table(handles, work: str, args, seg_rows: int, suppkeys: int,
+               custkeys: int) -> dict:
     """Phase 2b: schema + OFFLINE table, segments from --seed through the
     controller, wait for the broker's COUNT(*). Returns the full columns."""
     from pinot_tpu.cluster.process import BrokerClient, ControllerClient
@@ -257,7 +258,6 @@ def load_table(handles, work: str, args, suppkeys: int, custkeys: int) -> dict:
     ctrl.add_schema(schema)
     table = TableConfig("lineorder")
     ctrl.add_table(table)
-    seg_rows = min(SEGMENT_ROWS, args.rows // 4)
     n_segs = args.rows // seg_rows
     region_names = np.array(REGIONS, dtype=object)
 
@@ -446,7 +446,7 @@ def main() -> int:
     work = tempfile.mkdtemp(prefix="chip_smoke_")
     handles = start_services(work, chips)
     try:
-        cols = load_table(handles, work, args, suppkeys, custkeys)
+        cols = load_table(handles, work, args, seg_rows, suppkeys, custkeys)
         base = server_state(handles)["device"]
         sent = run_queries(handles, cols)
         state = server_state(handles)

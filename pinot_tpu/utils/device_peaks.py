@@ -14,7 +14,6 @@ from typing import Dict
 #: device_kind -> {"hbm_gbps": peak HBM bandwidth GB/s, "hbm_bytes": HBM size}
 DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
     "TPU v5 lite": {"hbm_gbps": 819.0, "hbm_bytes": 16e9},
-    "TPU v5e": {"hbm_gbps": 819.0, "hbm_bytes": 16e9},
 }
 
 #: what the CPU backend (tests, rehearsals) models: the v5e the code targets,

@@ -63,9 +63,9 @@ def test_calendar_fields(epochs):
 def test_calendar_fields_on_jax(epochs):
     # The scan path ships 64-bit epochs to the device decomposed (or falls back to host —
     # planner rejects >int32 columns); under x64 the traced math must match numpy exactly.
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         host = np.asarray(ev("year(ts)", {"ts": epochs}))
         dev = np.asarray(ev("year(ts)", {"ts": jnp.asarray(epochs)}, xp=jnp))
         np.testing.assert_array_equal(host, dev)
