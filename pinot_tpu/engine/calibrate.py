@@ -319,9 +319,7 @@ def _regime_runners(nseg: int, block: int):
     from . import kernels
 
     def matmul(key, val):
-        oh = jax.nn.one_hot(key, nseg, dtype=jnp.float32)
-        return jax.lax.dot(jnp.stack([jnp.ones_like(val), val]), oh,
-                           precision=jax.lax.Precision.HIGHEST)
+        return kernels._onehot_sums(key, nseg, [jnp.ones_like(val), val])
 
     def chunk(key, val):
         return kernels._grouped_chunk64(key, nseg, [jnp.ones_like(val)], [val])

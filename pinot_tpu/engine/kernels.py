@@ -37,6 +37,7 @@ the measured foundation for future hand-scheduled integration.
 
 from __future__ import annotations
 
+import math
 import os
 import threading
 import time
@@ -603,6 +604,30 @@ def _presence_2d(fmask: jnp.ndarray, col_ids: jnp.ndarray, size: int) -> jnp.nda
     return counts[:size]
 
 
+#: rows per slab of the skinny one-hot matmul's blocked contraction
+_ONEHOT_SLAB = 1 << 16
+
+
+def _onehot_sums(key: jnp.ndarray, num_seg: int, rows) -> jnp.ndarray:
+    """f32[len(rows), num_seg] per-key sums of `rows` as the skinny one-hot
+    matmul [R, N] @ one_hot(key)[N, num_seg] at HIGHEST precision, the
+    N-length contraction blocked into <= 64Ki-row slabs whose partials add in
+    f32 outside the dot.
+
+    Blocked because ONE HIGHEST contraction loses with its length on the MXU:
+    on the v5e a 16Mi-row contraction came back 6e-4 LOW on every key (1Mi
+    rows: 6e-6; the same data in 64Ki-row slabs: 1.3e-7 — chip probe, PR 22),
+    while the CPU backend is exact either way. The one-hot is still NOT
+    materialized: its iota-compare fuses into the dot's operand tiles."""
+    slab = math.gcd(int(key.size), _ONEHOT_SLAB)
+    oh = jax.nn.one_hot(key.reshape(-1, slab), num_seg, dtype=jnp.float32)
+    lhs = jnp.stack(rows).reshape(len(rows), -1, slab)
+    partials = jax.lax.dot_general(              # batch over slabs: [nb, R, K]
+        lhs, oh, (((2,), (1,)), ((1,), (0,))),
+        precision=jax.lax.Precision.HIGHEST)
+    return partials.sum(axis=0)
+
+
 def _bf16_parts(v: jnp.ndarray):
     """f32 `v` as three bf16 parts v1 + v2 + v3, each the bf16 rounding of the
     remaining residual (3 x 8 mantissa bits = full f32 per-element precision).
@@ -902,13 +927,9 @@ def _make_body(spec: KernelSpec):
             # padded block sits exactly at 2^24 and must keep the matmul path.
             count_exact_in_f32 = key.size <= (1 << 24)
             if num_seg <= caps.matmul_cap and count_exact_in_f32:
-                # one-hot is NOT materialized: XLA:TPU fuses its iota-compare into the
-                # matmul tiles (measured: N=8M, K=4096 runs in ~100ms on a 16GB chip —
-                # a dense [N, K] f32 operand would be 137GB). HIGHEST precision keeps
-                # the value operand in f32 on the MXU instead of bf16 truncation.
-                oh = jax.nn.one_hot(key, num_seg, dtype=jnp.float32)
-                partials = jax.lax.dot(jnp.stack(sum_rows), oh,
-                                       precision=jax.lax.Precision.HIGHEST)
+                # HIGHEST precision keeps the value operand in f32 on the MXU
+                # instead of bf16 truncation (see _onehot_sums)
+                partials = _onehot_sums(key, num_seg, sum_rows)
                 for r, name in enumerate(sum_names):
                     p = partials[r]
                     out[name] = (jnp.round(p).astype(jnp.int32) if name == "count" else p)

@@ -75,6 +75,26 @@ def test_bf16_split_is_exact_and_survives_the_tpu_compiler():
     assert jaxpr.count("reduce_precision") == 3
 
 
+@pytest.mark.parametrize("n", [3 * 4096, 1 << 17, 5 * (1 << 16)])
+def test_onehot_sums_blocked_contraction_matches_numpy(n):
+    """The skinny one-hot matmul with its contraction blocked into slabs (on
+    the v5e one 16Mi-row HIGHEST contraction came back 6e-4 low, PR 22): any
+    row count the padded blocks produce, counts exact, sums to f32."""
+    import jax.numpy as jnp
+    from pinot_tpu.engine.kernels import _onehot_sums
+    rng = np.random.default_rng(n)
+    key = rng.integers(0, 7, n).astype(np.int32)
+    val = np.round(rng.uniform(1.0, 60_000.0, n), 2).astype(np.float32)
+    got = np.asarray(_onehot_sums(jnp.asarray(key), 7,
+                                  [jnp.ones(n, jnp.float32),
+                                   jnp.asarray(val)]), dtype=np.float64)
+    assert got.shape == (2, 7)
+    np.testing.assert_array_equal(got[0], np.bincount(key, minlength=7))
+    np.testing.assert_allclose(
+        got[1], np.bincount(key, weights=val.astype(np.float64), minlength=7),
+        rtol=1e-6)
+
+
 def test_cap_structure():
     assert MATMUL_KEY_CAP < N_KEYS + 1 <= CHUNK_KEY_CAP
 
