@@ -37,7 +37,6 @@ the measured foundation for future hand-scheduled integration.
 
 from __future__ import annotations
 
-import math
 import os
 import threading
 import time
@@ -604,28 +603,24 @@ def _presence_2d(fmask: jnp.ndarray, col_ids: jnp.ndarray, size: int) -> jnp.nda
     return counts[:size]
 
 
-#: rows per slab of the skinny one-hot matmul's blocked contraction
-_ONEHOT_SLAB = 1 << 16
-
-
 def _onehot_sums(key: jnp.ndarray, num_seg: int, rows) -> jnp.ndarray:
-    """f32[len(rows), num_seg] per-key sums of `rows` as the skinny one-hot
-    matmul [R, N] @ one_hot(key)[N, num_seg] at HIGHEST precision, the
-    N-length contraction blocked into <= 64Ki-row slabs whose partials add in
-    f32 outside the dot.
+    """f32[len(rows), num_seg] per-key sums of `rows`: the skinny one-hot matmul
+    [R, N] @ one_hot(key)[N, num_seg], its f32 value rows carried as three
+    exact bf16 parts (`_bf16_parts`) against a bf16 one-hot, f32 accumulation
+    — the operand form `_grouped_chunk64` uses.
 
-    Blocked because ONE HIGHEST contraction loses with its length on the MXU:
-    on the v5e a 16Mi-row contraction came back 6e-4 LOW on every key (1Mi
-    rows: 6e-6; the same data in 64Ki-row slabs: 1.3e-7 — chip probe, PR 22),
-    while the CPU backend is exact either way. The one-hot is still NOT
+    NOT one f32 `Precision.HIGHEST` dot: on the v5e that contraction loses
+    with its length — every sum 6e-6 LOW over 1Mi rows and 6e-4 LOW over 16Mi
+    (chip probe and served group-by, PR 22; the CPU backend is exact) — and
+    blocking it does not help, because XLA folds a batched dot plus the sum
+    over its batch back into the one long contraction. The three-part form
+    measured 1.6e-6 over 16Mi rows on the chip. The one-hot is not
     materialized: its iota-compare fuses into the dot's operand tiles."""
-    slab = math.gcd(int(key.size), _ONEHOT_SLAB)
-    oh = jax.nn.one_hot(key.reshape(-1, slab), num_seg, dtype=jnp.float32)
-    lhs = jnp.stack(rows).reshape(len(rows), -1, slab)
-    partials = jax.lax.dot_general(              # batch over slabs: [nb, R, K]
-        lhs, oh, (((2,), (1,)), ((1,), (0,))),
-        precision=jax.lax.Precision.HIGHEST)
-    return partials.sum(axis=0)
+    oh = jax.nn.one_hot(key, num_seg, dtype=jnp.bfloat16)
+    parts = zip(*[_bf16_parts(r) for r in rows])     # 3 x [R rows of bf16]
+    return sum(jax.lax.dot(jnp.stack(p), oh,
+                           preferred_element_type=jnp.float32)
+               for p in parts)
 
 
 def _bf16_parts(v: jnp.ndarray):
@@ -927,8 +922,6 @@ def _make_body(spec: KernelSpec):
             # padded block sits exactly at 2^24 and must keep the matmul path.
             count_exact_in_f32 = key.size <= (1 << 24)
             if num_seg <= caps.matmul_cap and count_exact_in_f32:
-                # HIGHEST precision keeps the value operand in f32 on the MXU
-                # instead of bf16 truncation (see _onehot_sums)
                 partials = _onehot_sums(key, num_seg, sum_rows)
                 for r, name in enumerate(sum_names):
                     p = partials[r]

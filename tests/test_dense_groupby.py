@@ -76,10 +76,10 @@ def test_bf16_split_is_exact_and_survives_the_tpu_compiler():
 
 
 @pytest.mark.parametrize("n", [3 * 4096, 1 << 17, 5 * (1 << 16)])
-def test_onehot_sums_blocked_contraction_matches_numpy(n):
-    """The skinny one-hot matmul with its contraction blocked into slabs (on
-    the v5e one 16Mi-row HIGHEST contraction came back 6e-4 low, PR 22): any
-    row count the padded blocks produce, counts exact, sums to f32."""
+def test_onehot_sums_matches_numpy(n):
+    """The skinny one-hot matmul in its three-part bf16 form (on the v5e one
+    f32 HIGHEST contraction over 16Mi rows came back 6e-4 low, PR 22): counts
+    exact, sums to f32, and no HIGHEST-precision dot in the program."""
     import jax.numpy as jnp
     from pinot_tpu.engine.kernels import _onehot_sums
     rng = np.random.default_rng(n)
@@ -89,6 +89,10 @@ def test_onehot_sums_blocked_contraction_matches_numpy(n):
                                   [jnp.ones(n, jnp.float32),
                                    jnp.asarray(val)]), dtype=np.float64)
     assert got.shape == (2, 7)
+    import jax
+    jaxpr = str(jax.make_jaxpr(lambda k, v: _onehot_sums(k, 7, [v]))(
+        jnp.asarray(key), jnp.asarray(val)))
+    assert "HIGHEST" not in jaxpr and jaxpr.count("dot_general") == 3
     np.testing.assert_array_equal(got[0], np.bincount(key, minlength=7))
     np.testing.assert_allclose(
         got[1], np.bincount(key, weights=val.astype(np.float64), minlength=7),
