@@ -1,0 +1,158 @@
+"""The load generator: a child process that only speaks HTTP, so that it shares
+no GIL with the server. Stdlib only; never imports jax or the program.
+
+Started by run.py before the parent touches JAX. It reads one JSON command a
+line on stdin and answers each with one JSON line on stdout:
+
+  {"cmd": "window", "url", "pool": [sql...], "walks": [[pool index...]...],
+   "seconds", "timeout_s"}
+      closed loop: one thread and one keep-alive connection a client, each
+      sending its walk's next query when the last one is answered, until
+      `seconds` have passed; queries in flight at the close are waited for.
+      -> {"t0", "t_close", "records": [...]}
+  {"cmd": "one", "url", "sql", "timeout_s"}   one query, alone -> {"record"}
+  {"cmd": "burst", "url", "blocker", "sqls", "delay_s", "timeout_s"}
+      warm-up of stacked shapes: the blocker alone, then after `delay_s` all of
+      `sqls` at once, so that they queue behind it -> {"records"}
+  {"cmd": "quit"}
+
+A record is {"client", "pool", "sent", "done", "ok", "error", "response"}:
+times on this process's monotonic clock, relative to the window's start; the
+response is the broker's JSON object as it came.
+"""
+
+import http.client
+import json
+import sys
+import threading
+import time
+from urllib.parse import urlparse
+
+
+def post_query(conn, sql: str):
+    body = json.dumps({"sql": sql}).encode()
+    conn.request("POST", "/query", body=body,
+                 headers={"Content-Type": "application/json"})
+    resp = conn.getresponse()
+    data = resp.read()
+    if resp.status != 200:
+        raise RuntimeError(f"HTTP {resp.status}: {data[:300]!r}")
+    return json.loads(data)
+
+
+def connect(url: str, timeout_s: float):
+    u = urlparse(url)
+    return http.client.HTTPConnection(u.hostname, u.port, timeout=timeout_s)
+
+
+def send(conn_box, url, timeout_s, sql):
+    """One query on the client's connection; a dropped keep-alive connection
+    is opened again, the failed query is reported as failed."""
+    rec = {"ok": True, "error": "", "response": None}
+    try:
+        if conn_box[0] is None:
+            conn_box[0] = connect(url, timeout_s)
+        rec["response"] = post_query(conn_box[0], sql)
+    except Exception as e:   # the record carries it; the harness fails the run
+        rec.update(ok=False, error=f"{type(e).__name__}: {e}")
+        try:
+            conn_box[0].close()
+        except Exception:
+            pass
+        conn_box[0] = None
+    return rec
+
+
+def window(cmd: dict) -> dict:
+    url, pool, walks = cmd["url"], cmd["pool"], cmd["walks"]
+    seconds, timeout_s = float(cmd["seconds"]), float(cmd["timeout_s"])
+    records, lock = [], threading.Lock()
+    start = threading.Barrier(len(walks) + 1)
+    t0_box = [0.0]
+
+    def client(c: int):
+        box = [None]
+        try:
+            box[0] = connect(url, timeout_s)
+            box[0].connect()
+        except Exception:
+            box[0] = None
+        mine = []
+        start.wait()
+        t0 = t0_box[0]
+        k = 0
+        while True:
+            sent = time.perf_counter() - t0
+            if sent >= seconds:
+                break
+            p = walks[c][k % len(walks[c])]
+            k += 1
+            rec = send(box, url, timeout_s, pool[p])
+            rec.update(client=c, pool=p, sent=sent,
+                       done=time.perf_counter() - t0)
+            mine.append(rec)
+        if box[0] is not None:
+            box[0].close()
+        with lock:
+            records.extend(mine)
+
+    threads = [threading.Thread(target=client, args=(c,), daemon=True)
+               for c in range(len(walks))]
+    for t in threads:
+        t.start()
+    t0_box[0] = time.perf_counter()
+    start.wait()
+    for t in threads:
+        t.join()
+    return {"t0": t0_box[0], "t_close": seconds, "records": records}
+
+
+def burst(cmd: dict) -> dict:
+    url, timeout_s = cmd["url"], float(cmd["timeout_s"])
+    records = [None] * (1 + len(cmd["sqls"]))
+
+    def one(i, sql):
+        box = [None]
+        records[i] = send(box, url, timeout_s, sql)
+        if box[0] is not None:
+            box[0].close()
+
+    threads = [threading.Thread(target=one, args=(0, cmd["blocker"]))]
+    threads[0].start()
+    time.sleep(float(cmd["delay_s"]))
+    for i, sql in enumerate(cmd["sqls"]):
+        threads.append(threading.Thread(target=one, args=(i + 1, sql)))
+        threads[-1].start()
+    for t in threads:
+        t.join()
+    return {"records": records}
+
+
+def main() -> int:
+    out = sys.stdout
+    for line in sys.stdin:
+        cmd = json.loads(line)
+        if cmd["cmd"] == "quit":
+            break
+        if cmd["cmd"] == "window":
+            reply = window(cmd)
+        elif cmd["cmd"] == "one":
+            box = [None]
+            t0 = time.perf_counter()
+            rec = send(box, cmd["url"], float(cmd["timeout_s"]), cmd["sql"])
+            rec.update(client=0, pool=cmd.get("pool", -1), sent=0.0,
+                       done=time.perf_counter() - t0)
+            if box[0] is not None:
+                box[0].close()
+            reply = {"record": rec}
+        elif cmd["cmd"] == "burst":
+            reply = burst(cmd)
+        else:
+            reply = {"error": f"unknown command {cmd['cmd']!r}"}
+        out.write(json.dumps(reply) + "\n")
+        out.flush()
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,0 +1,456 @@
+#!/usr/bin/env python3
+"""One run of one cell of BENCHMARK.json.
+
+    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+Builds the cell's table from `--seed` (a process pool, one segment a worker),
+serves it from the chip through `run_service_manager` (every role in this one
+chip-owning process), drives it over broker HTTP from a child process, and
+compares every answer with the numpy reference. The last line of stdout is the
+result object; see benchmark/README.md.
+
+`--rehearse` (CPU sandbox) relaxes the platform check and cuts the rows; it
+prints `cpu` as its device and is never a measurement. `--control 1` also
+evaluates the controls (the reference at bfloat16; the reference with one
+segment left out) and reports how far each is from the reference.
+"""
+
+import time
+
+T_START = time.perf_counter()
+
+import argparse  # noqa: E402
+import json  # noqa: E402
+import math  # noqa: E402
+import multiprocessing  # noqa: E402
+import os  # noqa: E402
+import shutil  # noqa: E402
+import statistics  # noqa: E402
+import subprocess  # noqa: E402
+import sys  # noqa: E402
+from concurrent.futures import ProcessPoolExecutor, as_completed  # noqa: E402
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark.harness import (build, cells, readers, reference,  # noqa: E402
+                               serve, traffic)
+
+REHEARSE_SEGMENT_ROWS = 65_536
+TRACE_LEAD_S = 2.0
+TRACE_SLICE_S = 8.0
+DEVICE = "device not opened yet"
+
+
+def log(msg: str) -> None:
+    print(f"[{DEVICE}] {msg}", flush=True)
+
+
+def percentile(values, q: float) -> float:
+    """Nearest-rank percentile of all the values."""
+    v = sorted(values)
+    return v[max(0, math.ceil(q * len(v)) - 1)]
+
+
+class LoadGen:
+    """The child that sends the queries (harness/loadgen.py)."""
+
+    def __init__(self):
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        self.proc = subprocess.Popen(
+            [sys.executable, os.path.join(ROOT, "benchmark", "harness",
+                                          "loadgen.py")],
+            stdin=subprocess.PIPE, stdout=subprocess.PIPE, env=env, text=True)
+
+    def send(self, cmd: dict) -> None:
+        self.proc.stdin.write(json.dumps(cmd) + "\n")
+        self.proc.stdin.flush()
+
+    def reply(self) -> dict:
+        line = self.proc.stdout.readline()
+        if not line:
+            raise RuntimeError("the load generator died")
+        return json.loads(line)
+
+    def ask(self, cmd: dict) -> dict:
+        self.send(cmd)
+        return self.reply()
+
+    def stop(self) -> None:
+        try:
+            self.send({"cmd": "quit"})
+        except Exception:
+            pass
+        try:
+            self.proc.wait(timeout=10)
+        except subprocess.TimeoutExpired:
+            self.proc.kill()
+            self.proc.wait()
+
+
+def incomplete(resp: dict) -> bool:
+    return bool(resp.get("exceptions") or resp.get("partialResult")
+                or resp.get("numServersResponded")
+                != resp.get("numServersQueried")
+                or "resultTable" not in resp)
+
+
+def judge(records, pool, want, sum_limit: float) -> dict:
+    """Every answer against the reference; the numbers `correct` rests on."""
+    n = {"unanswered": 0, "incomplete": 0, "wrong_rows": 0,
+         "count_mismatch": 0, "sum_rel_gap_max": 0.0}
+    failed, notes = 0, []
+    for r in records:
+        bad = ""
+        if not r["ok"]:
+            n["unanswered"] += 1
+            bad = r["error"]
+        elif incomplete(r["response"]):
+            n["incomplete"] += 1
+            bad = "incomplete: " + str({k: v for k, v in r["response"].items()
+                                        if k != "resultTable"})[:300]
+        else:
+            c = reference.compare(pool[r["pool"]]["spec"],
+                                  r["response"]["resultTable"]["rows"],
+                                  want[r["pool"]], sum_limit)
+            n["wrong_rows"] += c["wrong"]
+            n["count_mismatch"] += c["count_wrong"]
+            n["sum_rel_gap_max"] = max(n["sum_rel_gap_max"], c["sum_gap"])
+            if c["wrong"] or c["count_wrong"] or not c["sum_gap"] <= sum_limit:
+                bad = c["why"] or f"sum gap {c['sum_gap']}"
+        if bad:
+            failed += 1
+            if len(notes) < 5:
+                notes.append(f"{pool[r['pool']]['sql'][:120]} -> {bad}")
+    return {"numbers": n, "failed": failed, "notes": notes}
+
+
+def reference_answers(pool_exec, cell, seed, rows_per_segment, pool, tables,
+                      control: bool):
+    """The reference's answer to every query of the pool: the segments are
+    generated again from the seed in the workers, evaluated in parts, and the
+    parts added by group key."""
+    jobs = [{"config": cell["config"], "seed": seed, "index": i,
+             "rows": rows_per_segment, "pool": [p["spec"] for p in pool],
+             "control": control}
+            for i in range(cell["config"]["segments"])]
+    parts = sorted(pool_exec.map(build.reference_segment, jobs),
+                   key=lambda p: p["index"])
+
+    def answers(key, keep=lambda i: True):
+        return [reference.finish(
+            pool[q]["spec"],
+            reference.merge([p[key][q] for p in parts if keep(p["index"])]),
+            tables) for q in range(len(pool))]
+    want = answers("parts")
+    controls = None
+    if control:
+        last = len(parts) - 1
+        controls = {"bf16": answers("control"),
+                    "segment_left_out": answers("parts", lambda i: i != last)}
+    return want, controls
+
+
+def warm_stacks(loadgen, handles, cell, pool, tables, url, seed) -> dict:
+    """Where the mix names `warm_stacks`: for every template and every listed
+    batch size B, one blocker query alone and, while the device works on it, B
+    variants of the template at once, so that the pipeline drains them as one
+    stacked launch and builds that shape now, not in the window. A burst that
+    split into several launches is sent again."""
+    mix = cell["traffic"]
+    sizes = mix.get("warm_stacks", [])
+    out = {"bursts": 0, "split": 0, "built": 0}
+    if not sizes:
+        return out
+    import numpy as np
+    blocker_t = cells.read_json(cells.BENCH, "queries",
+                                mix["warm_blocker"] + ".json")
+    holes = traffic.draw_holes(blocker_t, tables,
+                               np.random.default_rng([seed, 13]))
+    blocker = blocker_t["sql"].format(**holes)
+    m0 = serve.kernel_cache_misses()
+    for t in cell["templates"]:
+        mine = [p["sql"] for p in pool if p["template"] == t["name"]]
+        for b in sizes:
+            # b variants pad to a batch of b; fewer variants than b still
+            # reach that padded batch if they round up to it
+            k = min(b, len(mine))
+            if k < 2 or 1 << (k - 1).bit_length() != b:
+                continue
+            for attempt in range(5):
+                c0 = serve.pipeline_counters(handles)
+                recs = loadgen.ask({"cmd": "burst", "url": url,
+                                    "blocker": blocker, "sqls": mine[:k],
+                                    "delay_s": 0.03, "timeout_s": 900.0}
+                                   )["records"]
+                for r in recs:
+                    if not r["ok"] or incomplete(r["response"]):
+                        raise SystemExit(f"warm-up burst: {r}")
+                c1 = serve.pipeline_counters(handles)
+                out["bursts"] += 1
+                if c1["launches"] - c0["launches"] == 2:
+                    break
+                out["split"] += 1
+    out["built"] = serve.kernel_cache_misses() - m0
+    return out
+
+
+def main(argv=None) -> int:
+    global DEVICE
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=[0, 1], default=0)
+    ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--control", type=int, choices=[0, 1], default=0)
+    ap.add_argument("--keep-trace", default="",
+                    help="copy the traced slice's .xplane.pb to this file")
+    args = ap.parse_args(argv)
+
+    import pinot_tpu  # noqa: F401  (a checkout without the program fails here)
+    cell = cells.load_cell(args.workload)
+    config, mix = cell["config"], cell["traffic"]
+    n_seg = int(config["segments"])
+    seg_rows = (REHEARSE_SEGMENT_ROWS if args.rehearse
+                else int(config["rows"]) // n_seg)
+    rows = seg_rows * n_seg
+    sum_limit = float(config["guarantees"]["sum_rel_gap"])
+    work = os.path.join(ROOT, ".bench_work", args.workload)
+    shutil.rmtree(work, ignore_errors=True)
+    os.makedirs(work)
+
+    gen = cells.load_generator(config)
+    tables = gen.tables(config)
+    pool = traffic.build_pool(mix, cell["templates"], tables, args.seed)
+    walks = traffic.client_walks(mix, pool, args.seed)
+    table_with_type = config["table"] + "_OFFLINE"
+    seg_out = serve.server_segment_dir(work, table_with_type)
+    os.makedirs(seg_out)
+
+    loadgen = LoadGen()     # before this process touches JAX
+    workers = min(os.cpu_count() or 1, n_seg)
+    pool_exec = ProcessPoolExecutor(
+        max_workers=workers, mp_context=multiprocessing.get_context("spawn"))
+    handles = None
+    try:
+        t0 = time.perf_counter()
+        builds = [pool_exec.submit(build.build_segment, {
+            "config": config, "seed": args.seed, "index": i, "rows": seg_rows,
+            "out_dir": seg_out}) for i in range(n_seg)]
+
+        # -- the chip, while the workers build ---------------------------------
+        from pinot_tpu.utils.compile_cache import place_compile_cache
+        cache_dir = place_compile_cache()
+        import jax
+        devs = jax.devices()
+        DEVICE = (f"{devs[0].platform} {devs[0].device_kind} x{len(devs)}"
+                  + (" REHEARSAL" if args.rehearse else ""))
+        if not args.rehearse and devs[0].platform != "tpu":
+            raise SystemExit(f"the benchmark needs a TPU; jax found "
+                             f"{devs[0].platform!r}")
+        if len(devs) < cell["chips"]:
+            raise SystemExit(f"{args.workload} needs {cell['chips']} chips; "
+                             f"jax found {len(devs)}")
+        peaks = None if args.rehearse else cells.peaks(devs[0].device_kind)
+        log(f"{args.workload} seed {args.seed}: {rows} rows in {n_seg} "
+            f"segments, {mix['clients']} clients, pool of {len(pool)} queries; "
+            f"host cores {os.cpu_count()}, build workers {workers}; compile "
+            f"cache {cache_dir} ({len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0} entries)")
+        handles = serve.start_services(work, config["cluster"])
+        serve.create_table(handles, config)
+        built = [f.result() for f in as_completed(builds)]
+        t_build = time.perf_counter() - t0
+        load = serve.upload_and_load(
+            handles, config, table_with_type,
+            [b["seg_dir"] for b in sorted(built, key=lambda b: b["index"])],
+            rows)
+        log(f"set-up: generate+build {t_build:.1f} s wall (a segment: generate "
+            f"{statistics.fmean(b['generate_s'] for b in built):.1f} s, build "
+            f"{statistics.fmean(b['build_s'] for b in built):.1f} s, "
+            f"{sum(b['bytes'] for b in built)} bytes in all), upload (gzip, "
+            f"metadata, assignment) {load['upload_s']:.1f} s, load onto the "
+            f"device {load['load_s']:.1f} s")
+
+        # -- warm-up: each query of the pool alone, then the mix together ------
+        url = handles["broker"].url
+        timeout_s = float(mix["timeout_s"])
+        t0 = time.perf_counter()
+        m0 = serve.kernel_cache_misses()
+        warm = [p for p in range(len(pool))
+                if mix.get("warm", "pool") == "pool" or pool[p]["variant"] == 0]
+        for p in warm:
+            rec = loadgen.ask({"cmd": "one", "url": url, "sql": pool[p]["sql"],
+                               "timeout_s": max(timeout_s, 900.0)})["record"]
+            if not rec["ok"] or incomplete(rec["response"]):
+                raise SystemExit(f"warm-up: {pool[p]['sql']} -> "
+                                 f"{rec['error'] or rec['response']}")
+        m1 = serve.kernel_cache_misses()
+        stacks = warm_stacks(loadgen, handles, cell, pool, tables, url,
+                             args.seed)
+        loadgen.ask({"cmd": "window", "url": url,
+                     "pool": [p["sql"] for p in pool],
+                     "walks": [w[len(w) // 2:] for w in walks],
+                     "seconds": float(mix.get("warm_seconds", 3.0)),
+                     "timeout_s": max(timeout_s, 900.0)})
+        m2 = serve.kernel_cache_misses()
+        log(f"warm-up {time.perf_counter() - t0:.1f} s: {len(warm)} queries "
+            f"alone built {m1 - m0} executables, the mix together "
+            f"{m2 - m1 - stacks['built']} more; stacked shapes: {stacks}")
+
+        # -- the window --------------------------------------------------------
+        c0 = serve.pipeline_counters(handles)
+        setup_s = time.perf_counter() - T_START
+        loadgen.send({"cmd": "window", "url": url,
+                      "pool": [p["sql"] for p in pool], "walks": walks,
+                      "seconds": args.seconds, "timeout_s": timeout_s})
+        trace = None
+        prof_dir = os.path.join(work, "profile")
+        if args.trace:
+            from benchmark.harness import trace_reduce
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            lead = min(TRACE_LEAD_S, args.seconds / 4)
+            time.sleep(lead)
+            jax.profiler.start_trace(prof_dir, profiler_options=opts)
+            with jax.profiler.TraceAnnotation("bench:window"):
+                time.sleep(min(TRACE_SLICE_S, args.seconds / 2))
+            jax.profiler.stop_trace()
+            xplane = trace_reduce.newest_xplane(prof_dir)
+            trace = trace_reduce.reduce(xplane, cpu_stand_in=args.rehearse)
+            if args.keep_trace:
+                os.makedirs(os.path.dirname(args.keep_trace) or ".",
+                            exist_ok=True)
+                shutil.copy(xplane, args.keep_trace)
+            log(f"trace: planes and lines {trace['seen']}")
+        win = loadgen.reply()
+        c1 = serve.pipeline_counters(handles)
+        compiles = serve.kernel_cache_misses() - m2
+        stats = [d.memory_stats() or {} for d in devs[:cell["chips"]]]
+        memory_peak = max(s.get("peak_bytes_in_use", 0) for s in stats)
+        counters = {k: c1[k] - c0[k] for k in c0
+                    if isinstance(c0[k], (int, float))
+                    and isinstance(c1.get(k), (int, float))}
+
+        window_records = win["records"]
+        records = list(window_records)
+        for r in records:
+            r["latency_ms"] = (r["done"] - r["sent"]) * 1000.0
+        in_window = [r for r in records if r["done"] <= args.seconds]
+        lat = [r["latency_ms"] for r in records]
+
+        # -- the solo replay (traced runs): each template once, alone ----------
+        solo = []
+        if args.trace:
+            solo_dir = os.path.join(work, "profile_solo")
+            jax.profiler.start_trace(solo_dir, profiler_options=opts)
+            firsts = [p for p in range(len(pool)) if pool[p]["variant"] == 0]
+            for p in firsts:
+                with jax.profiler.TraceAnnotation(
+                        f"bench:solo:{pool[p]['template']}"):
+                    rec = loadgen.ask({"cmd": "one", "url": url, "pool": p,
+                                       "sql": pool[p]["sql"],
+                                       "timeout_s": timeout_s})["record"]
+                rec["latency_ms"] = (rec["done"] - rec["sent"]) * 1000.0
+                records.append(rec)
+                solo.append({"template": pool[p]["template"],
+                             "latency_ms": rec["latency_ms"],
+                             "least_bytes": readers.least_bytes(
+                                 pool[p]["spec"], dict(config, rows=rows))})
+            jax.profiler.stop_trace()
+            st = trace_reduce.reduce(trace_reduce.newest_xplane(solo_dir),
+                                     cpu_stand_in=args.rehearse)
+            for s in solo:
+                spans = st["spans"].get(f"bench:solo:{s['template']}", [])
+                s["busy_s"] = sum(sp["busy_s"] for sp in spans)
+                log(f"solo {s['template']}: latency {s['latency_ms']:.1f} ms, "
+                    f"device busy {s['busy_s'] * 1000:.2f} ms, least bytes "
+                    f"{s['least_bytes']}")
+        serve.stop_services(handles)
+        handles = None
+
+        # -- the reference, once the window has closed -------------------------
+        t0 = time.perf_counter()
+        want, controls = reference_answers(pool_exec, cell, args.seed, seg_rows,
+                                           pool, tables, bool(args.control))
+        verdict = judge(records, pool, want, sum_limit)
+        log(f"reference: {len(pool)} answers over {rows} rows and the "
+            f"comparison of {len(records)} served answers in "
+            f"{time.perf_counter() - t0:.1f} s")
+        numbers = verdict["numbers"]
+        numbers["device_errors"] = counters.get("deviceErrors", 0)
+        numbers["timeouts"] = counters.get("timeouts", 0)
+        numbers["compiles_in_window"] = compiles
+        limits = {k: 0 for k in numbers}
+        limits["sum_rel_gap_max"] = sum_limit
+        correct = (len(records) > 0 and len(in_window) > 0
+                   and all(numbers[k] <= limits[k] for k in numbers))
+        checked = {k: {"value": numbers[k], "limit": limits[k]}
+                   for k in numbers}
+        control_out = None
+        if controls:
+            control_out = {}
+            for name, answers in controls.items():
+                fake = [{"ok": True, "pool": q, "response": {
+                    "numServersQueried": 1, "numServersResponded": 1,
+                    "resultTable": {"rows": answers[q]}}}
+                    for q in range(len(pool))]
+                control_out[name] = judge(fake, pool, want, sum_limit)["numbers"]
+            per_query = [reference.compare(
+                pool[r["pool"]]["spec"], r["response"]["resultTable"]["rows"],
+                want[r["pool"]], sum_limit)["sum_gap"]
+                for r in records if r["ok"] and not incomplete(r["response"])]
+            control_out["program_sum_gaps_sorted_top"] = sorted(per_query)[-5:]
+
+        # -- the metrics -------------------------------------------------------
+        values = {"qps": len(in_window) / args.seconds,
+                  "p50_ms": statistics.median(lat) if lat else None,
+                  "p95_ms": percentile(lat, 0.95) if lat else None,
+                  "setup_s": setup_s}
+        if args.trace:
+            ctx = {"records": [r for r in window_records if r["ok"]],
+                   "counters": counters, "trace": trace, "solo": solo,
+                   "peaks": peaks}
+            owed, values = cell["per_layer"], {}
+            for m in owed:
+                values[m["name"]] = cells.load_reader(m["name"])(ctx)
+        else:
+            owed = cell["end_to_end"]
+        metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
+                   for m in owed if values.get(m["name"]) is not None}
+        log(f"window {args.seconds} s: sent {len(records) - len(solo)}, "
+            f"answered inside {len(in_window)}, latency p50 "
+            f"{values.get('p50_ms', statistics.median(lat) if lat else None)} ms "
+            f"p95 {percentile(lat, 0.95) if lat else None} ms max "
+            f"{max(lat) if lat else None} ms; pipeline {counters}")
+        device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+                  "count": len(devs), "memory_peak_bytes": memory_peak}
+        result = {"correct": bool(correct), "attempted": len(records),
+                  "failed": verdict["failed"], "metrics": metrics,
+                  "device": device}
+        if trace is not None:
+            device["busy_s"] = trace["busy_s"]
+            device["window_s"] = trace["window_s"]
+            result["breakdown"] = {"device_ops": trace["device_ops"],
+                                   "idle_gaps": trace["idle_gaps"]}
+        if control_out is not None:
+            result["control"] = control_out
+        result["checked"] = checked
+        for note in verdict["notes"]:
+            print(f"[{DEVICE}] failed: {note}", file=sys.stderr)
+        for k in numbers:
+            print(f"[{DEVICE}] checked {k} = {numbers[k]} (limit {limits[k]})",
+                  file=sys.stderr)
+        sys.stderr.flush()
+        print(json.dumps(result), flush=True)
+        return 0
+    finally:
+        loadgen.stop()
+        pool_exec.shutdown(wait=True, cancel_futures=True)
+        if handles is not None:
+            serve.stop_services(handles)
+        shutil.rmtree(work, ignore_errors=True)
+
+
+if __name__ == "__main__":
+    sys.exit(main())
