@@ -10,10 +10,33 @@ in jax, so the pool can start before the parent touches the chip.
 import os
 import sys
 import time
-
-import numpy as np
+from collections.abc import Mapping
 
 from . import cells, reference
+
+
+class LazyColumns(Mapping):
+    """The builder's `columns`: each column's values are made when the builder
+    asks for that column and dropped after it, so a worker never holds all the
+    string columns of a 4Mi-row segment at once (1.7 GB as fixed-width
+    arrays)."""
+
+    def __init__(self, names, n, make):
+        self._names, self._n, self._make = list(names), n, make
+
+    def __getitem__(self, name):
+        if name not in self._names:
+            raise KeyError(name)
+        return self._make(name)
+
+    def __iter__(self):
+        return iter(self._names)
+
+    def __len__(self):
+        return len(self._names)
+
+    def values(self):       # the builder only takes their lengths
+        return [range(self._n)] * len(self._names)
 
 
 def make_schema(config: dict):
@@ -38,16 +61,12 @@ def build_segment(job: dict) -> dict:
     t1 = time.perf_counter()
     # strings go in as fixed-width numpy arrays against a fixed dictionary:
     # the builder's searchsorted then runs in C, not once a python string
-    raw, fixed = {}, {}
-    for c in config["schema"]:
-        name = c["name"]
-        if name in tables:
-            raw[name] = tables[name][cols[name]]
-            if c["type"] == "STRING":
-                fixed[name] = Dictionary([str(v) for v in tables[name]],
-                                         DataType.STRING)
-        else:
-            raw[name] = cols[name]
+    fixed = {c["name"]: Dictionary([str(v) for v in tables[c["name"]]],
+                                   DataType.STRING)
+             for c in config["schema"] if c["type"] == "STRING"}
+    raw = LazyColumns(
+        [c["name"] for c in config["schema"]], job["rows"],
+        lambda name: tables[name][cols[name]] if name in tables else cols[name])
     builder = SegmentBuilder(make_schema(config), SegmentGeneratorConfig(
         no_dictionary_columns=list(config.get("no_dictionary_columns", []))))
     seg_dir = builder.build(raw, job["out_dir"],

@@ -12,7 +12,6 @@ from concurrent.futures import ThreadPoolExecutor
 from . import build
 
 LOAD_TIMEOUT_S = 600.0
-UPLOAD_THREADS = 8
 
 
 def start_services(work: str, cluster_cfg: dict):
@@ -52,18 +51,29 @@ def create_table(handles, config: dict) -> str:
     return table.table_name_with_type
 
 
-def upload_and_load(handles, config: dict, table_with_type: str,
-                    seg_dirs: list, rows: int) -> dict:
-    """Each built segment through the controller object's `upload_segment`
-    (gzip into the deep store, metadata, assignment: zlib frees the GIL, so a
-    few threads), then wait until the broker counts every row."""
-    from pinot_tpu.cluster.process import BrokerClient
+def upload_as_built(handles, table_with_type: str, builds) -> list:
+    """Each segment, as soon as its worker has built it, through the
+    controller object's `upload_segment` (gzip into the deep store, metadata,
+    assignment; zlib frees the GIL, so one thread a segment). `builds` are the
+    workers' futures; returns their results."""
+    from concurrent.futures import as_completed
     controller = handles["controller_obj"]
-    t0 = time.perf_counter()
-    with ThreadPoolExecutor(max_workers=UPLOAD_THREADS) as pool:
-        list(pool.map(lambda d: controller.upload_segment(table_with_type, d),
-                      seg_dirs))
-    t_upload = time.perf_counter() - t0
+    built, uploads = [], []
+    with ThreadPoolExecutor(max_workers=len(builds)) as pool:
+        for f in as_completed(builds):
+            b = f.result()
+            built.append(b)
+            uploads.append(pool.submit(controller.upload_segment,
+                                       table_with_type, b["seg_dir"]))
+        t0 = time.perf_counter()
+        for u in uploads:
+            u.result()
+    return built, time.perf_counter() - t0
+
+
+def wait_loaded(handles, config: dict, rows: int) -> float:
+    """Until the broker counts every row; the seconds it took."""
+    from pinot_tpu.cluster.process import BrokerClient
     broker = BrokerClient(handles["broker"].url)
     t0 = time.perf_counter()
     loaded = -1
@@ -76,7 +86,7 @@ def upload_and_load(handles, config: dict, table_with_type: str,
         loaded = r[0][0] if r else 0
         if loaded != rows:
             time.sleep(0.1)
-    return {"upload_s": t_upload, "load_s": time.perf_counter() - t0}
+    return time.perf_counter() - t0
 
 
 def pipeline_counters(handles) -> dict:

@@ -15,6 +15,7 @@ where no chip is.
 
 OPS_LINE = "XLA Ops"
 SPAN_PREFIX = "bench:"
+NAME_CHARS = 96      # an operation's name in the trace is its whole HLO text
 
 
 def load(path: str, cpu_stand_in: bool = False) -> dict:
@@ -86,6 +87,7 @@ def reduce(path: str, cpu_stand_in: bool = False) -> dict:
         for s, d, name in dev["ops"]:
             part = max(0.0, min(s + d, hi) - max(s, lo))
             if part > 0:
+                name = name[:NAME_CHARS]
                 by_name[name] = by_name.get(name, 0.0) + part / n
     top = sorted(by_name.items(), key=lambda kv: -kv[1])[:10]
     # idle gaps of the first device inside the window, each labelled by the

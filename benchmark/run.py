@@ -28,7 +28,7 @@ import shutil  # noqa: E402
 import statistics  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
-from concurrent.futures import ProcessPoolExecutor, as_completed  # noqa: E402
+from concurrent.futures import ProcessPoolExecutor  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -229,7 +229,9 @@ def main(argv=None) -> int:
     os.makedirs(seg_out)
 
     loadgen = LoadGen()     # before this process touches JAX
-    workers = min(os.cpu_count() or 1, n_seg)
+    cores = os.cpu_count() or 1
+    # one wave of workers where the segments are at most twice the cores
+    workers = n_seg if n_seg <= 2 * cores else cores
     pool_exec = ProcessPoolExecutor(
         max_workers=workers, mp_context=multiprocessing.get_context("spawn"))
     handles = None
@@ -259,18 +261,16 @@ def main(argv=None) -> int:
             f"cache {cache_dir} ({len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0} entries)")
         handles = serve.start_services(work, config["cluster"])
         serve.create_table(handles, config)
-        built = [f.result() for f in as_completed(builds)]
-        t_build = time.perf_counter() - t0
-        load = serve.upload_and_load(
-            handles, config, table_with_type,
-            [b["seg_dir"] for b in sorted(built, key=lambda b: b["index"])],
-            rows)
+        built, upload_tail_s = serve.upload_as_built(handles, table_with_type,
+                                                     builds)
+        t_build = time.perf_counter() - t0 - upload_tail_s
+        load_s = serve.wait_loaded(handles, config, rows)
         log(f"set-up: generate+build {t_build:.1f} s wall (a segment: generate "
             f"{statistics.fmean(b['generate_s'] for b in built):.1f} s, build "
             f"{statistics.fmean(b['build_s'] for b in built):.1f} s, "
             f"{sum(b['bytes'] for b in built)} bytes in all), upload (gzip, "
-            f"metadata, assignment) {load['upload_s']:.1f} s, load onto the "
-            f"device {load['load_s']:.1f} s")
+            f"metadata, assignment) {upload_tail_s:.1f} s more after the last "
+            f"build, load onto the device {load_s:.1f} s")
 
         # -- warm-up: each query of the pool alone, then the mix together ------
         url = handles["broker"].url
@@ -322,7 +322,8 @@ def main(argv=None) -> int:
                 os.makedirs(os.path.dirname(args.keep_trace) or ".",
                             exist_ok=True)
                 shutil.copy(xplane, args.keep_trace)
-            log(f"trace: planes and lines {trace['seen']}")
+            log(f"trace: planes and lines "
+                f"{[s for s in trace['seen'] if s[1]]}")
         win = loadgen.reply()
         c1 = serve.pipeline_counters(handles)
         compiles = serve.kernel_cache_misses() - m2

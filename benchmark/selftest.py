@@ -1,0 +1,288 @@
+#!/usr/bin/env python3
+"""The benchmark's own checks, on the CPU: `python3 benchmark/selftest.py [--quick]`.
+
+  1. the generic reference evaluator against a brute-force python loop over a
+     tiny table, for every template of every family;
+  2. the least-bytes arithmetic of one template, by hand;
+  3. the trace reducer: interval arithmetic on a made-up trace, and the recorded
+     chip trace under fixtures/ against the numbers written down beside it;
+  4. the controls come out as not correct: the reference at bfloat16 fails the
+     sum limit, the reference with one segment left out fails counts and rows;
+  5. (not with --quick) three whole rehearsal runs through run.py, which skip
+     only the look for a chip: a clean one, whose last line must have the
+     contract's shape and `correct` true; and two with the timed path broken
+     underneath (the broker's answers altered where they are produced), which
+     must come out `correct` false, each fault by its own number.
+
+Exits 0 only if every check passed. Nothing here is a measurement.
+"""
+
+import contextlib
+import io
+import json
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import numpy as np  # noqa: E402
+
+from benchmark.harness import (cells, readers, reference, traffic,  # noqa: E402
+                               trace_reduce)
+
+SEED = 2_147_483_777
+
+
+def all_templates():
+    out = []
+    for family in sorted(os.listdir(os.path.join(cells.BENCH, "queries"))):
+        d = os.path.join(cells.BENCH, "queries", family)
+        for f in sorted(os.listdir(d)):
+            if f.endswith(".json"):
+                out.append(cells.read_json(d, f))
+    return out
+
+
+def brute_force(spec, cols, tables) -> list:
+    """The same answer by a python loop over the rows, sharing no code with
+    reference.partial/merge/finish except the final ordering rule."""
+    def value(c, i):
+        return tables[c][cols[c][i]] if c in tables else cols[c][i]
+    ops = {"eq": lambda v, a: v == a[0], "lt": lambda v, a: v < a[0],
+           "le": lambda v, a: v <= a[0], "gt": lambda v, a: v > a[0],
+           "ge": lambda v, a: v >= a[0],
+           "between": lambda v, a: a[0] <= v <= a[1],
+           "in": lambda v, a: v in a}
+    groups = {}
+    n = len(next(iter(cols.values())))
+    for i in range(n):
+        if not all(ops[f["op"]](value(f["column"], i), f["args"])
+                   for f in spec.get("filters", [])):
+            continue
+        key = tuple(value(c, i) for c in spec.get("group_by", []))
+        if spec["aggregate"]["fn"] == "count":
+            add = 1
+        else:
+            add = 0
+            for t in spec["aggregate"]["terms"]:
+                term = int(t.get("coef", 1))
+                for c in t["columns"]:
+                    term *= int(value(c, i))
+                add += term
+        groups[key] = groups.get(key, 0) + add
+    if not spec.get("group_by"):
+        return [[groups.get((), 0)]]
+    rows = []
+    for key, agg in groups.items():
+        named = dict(zip(spec["group_by"], key), agg=agg)
+        rows.append(named)
+    for col, direction in reversed(spec.get("order_by", [])):
+        rows.sort(key=lambda r: r[col], reverse=(direction == "desc"))
+    rows = rows[:spec.get("limit", len(rows))]
+    return [[r[c] for c in spec["select"]] for r in rows]
+
+
+def check_reference() -> None:
+    config = cells.read_json(cells.BENCH, "configs", "ssb10-flat.json")
+    gen = cells.load_generator(config)
+    tables = gen.tables(config)
+    segs = [gen.segment(config, SEED, i, 3000) for i in range(2)]
+    whole = {c: np.concatenate([s[c] for s in segs]) for c in segs[0]}
+    rng = np.random.default_rng(SEED)
+    for t in all_templates():
+        for _ in range(3):
+            holes = traffic.draw_holes(t, tables, rng)
+            # wide literals so that a tiny table still has rows to group
+            spec = reference.bind(t["reference"], holes)
+            got = reference.finish(spec, reference.merge(
+                [reference.partial(spec, s, tables) for s in segs]), tables)
+            want = brute_force(spec, whole, tables)
+            assert len(got) == len(want), (t["name"], len(got), len(want))
+            for g, w in zip(got, want):
+                assert [str(x) if isinstance(x, str) else float(x) for x in g] \
+                    == [str(x) if isinstance(x, (str, np.str_)) else float(x)
+                        for x in w], (t["name"], g, w)
+            c = reference.compare(spec, json.loads(json.dumps(got)), got, 1e-6)
+            assert not c["wrong"] and not c["count_wrong"] \
+                and c["sum_gap"] == 0.0, (t["name"], c)
+    # the comparison itself: each kind of wrong answer is seen
+    t = next(t for t in all_templates() if t["name"] == "q3.1")
+    spec = reference.bind(t["reference"], {"region": "ASIA", "y0": 1992,
+                                           "y1": 1997})
+    want = reference.finish(spec, reference.merge(
+        [reference.partial(spec, s, tables) for s in segs]), tables)
+    assert len(want) > 3
+    agg = spec["select"].index("agg")
+    scaled = [list(r) for r in want]
+    scaled[1][agg] *= 1 + 1e-4
+    assert 0.9e-4 < reference.compare(spec, scaled, want, 1e-6)["sum_gap"] < 1.1e-4
+    assert reference.compare(spec, want[:-1], want, 1e-6)["wrong"]
+    assert reference.compare(spec, want[1:] + want[:1], want, 1e-6)["wrong"]
+    renamed = [list(r) for r in want]
+    renamed[0][0] = "NOWHERE"
+    assert reference.compare(spec, renamed, want, 1e-6)["wrong"]
+    print("ok reference: every template equals the brute-force loop; the "
+          "comparison sees a scaled sum, a missing row, a wrong order, a "
+          "wrong key")
+
+
+def check_least_bytes() -> None:
+    config = cells.read_json(cells.BENCH, "configs", "ssb10-flat.json")
+    t = next(t for t in all_templates() if t["name"] == "q2.1")
+    # Q2.1 reads lo_revenue (81,000..10,000,000: 4 bytes), d_year (7 values: 1),
+    # p_brand1 (1,000 values: 2), p_category (25: 1), s_region (5: 1) = 9 bytes
+    # a row, times 67,108,864 rows
+    assert reference.columns_read(t["reference"]) == [
+        "d_year", "lo_revenue", "p_brand1", "p_category", "s_region"]
+    assert readers.least_bytes(t["reference"], config) == 9 * 67_108_864
+    t = next(t for t in all_templates() if t["name"] == "q1.1")
+    # d_year 1 + lo_discount 1 + lo_quantity 1 + lo_extendedprice 4
+    assert readers.least_bytes(t["reference"], config) == 7 * 67_108_864
+    print("ok least bytes: Q2.1 = 9 bytes a row, Q1.1 = 7 bytes a row")
+
+
+def check_trace_reduce() -> None:
+    merged = trace_reduce.union([(0, 10), (5, 20), (30, 40), (40, 45)])
+    assert merged == [[0, 20], [30, 45]], merged
+    assert trace_reduce.covered(merged, 10, 35) == 15
+    fixture = os.path.join(cells.BENCH, "fixtures", "quarter-flights.xplane.pb")
+    expect = os.path.join(cells.BENCH, "fixtures", "quarter-flights.json")
+    if not os.path.exists(fixture):
+        print("ok trace reducer: interval arithmetic (no recorded trace here)")
+        return
+    want = cells.read_json(expect)
+    got = trace_reduce.reduce(fixture)
+    assert got["devices"] == want["devices"], got["devices"]
+    for k in ("window_s", "busy_s", "idle_share"):
+        assert abs(got[k] - want[k]) <= 1e-9 * max(1.0, abs(want[k])), (k, got[k])
+    assert [n for n, _ in got["device_ops"]] == \
+        [n for n, _ in want["device_ops"]], got["device_ops"]
+    assert 0.0 < got["busy_s"] <= got["window_s"]
+    print(f"ok trace reducer: the recorded chip trace reduces to busy "
+          f"{got['busy_s']:.3f} s of {got['window_s']:.3f} s, as written down")
+
+
+def check_controls() -> None:
+    config = cells.read_json(cells.BENCH, "configs", "ssb10-flat-quarter.json")
+    limit = float(config["guarantees"]["sum_rel_gap"])
+    gen = cells.load_generator(config)
+    tables = gen.tables(config)
+    segs = [gen.segment(config, SEED, i, 65_536) for i in range(4)]
+    worst_bf16, counts_wrong, rows_wrong = 0.0, 0, 0
+    rng = np.random.default_rng(SEED + 1)
+    for t in all_templates():
+        holes = traffic.draw_holes(t, tables, rng)
+        spec = reference.bind(t["reference"], holes)
+
+        def answer(precision="exact", keep=4):
+            return reference.finish(spec, reference.merge(
+                [reference.partial(spec, s, tables, precision)
+                 for s in segs[:keep]]), tables)
+        want = answer()
+        c = reference.compare(spec, answer("bf16"), want, limit)
+        worst_bf16 = max(worst_bf16, c["sum_gap"])
+        c = reference.compare(spec, answer(keep=3), want, limit)
+        counts_wrong += c["count_wrong"]
+        rows_wrong += c["wrong"] or not c["sum_gap"] <= limit
+    assert worst_bf16 > 3 * limit, worst_bf16
+    assert counts_wrong >= 1 and rows_wrong >= 1, (counts_wrong, rows_wrong)
+    print(f"ok controls: the reference at bfloat16 is {worst_bf16:.2e} off "
+          f"(limit {limit:.0e}); with a segment left out {counts_wrong} counts "
+          f"and {rows_wrong} other answers are wrong")
+
+
+def rehearse(workload: str, fault=None) -> dict:
+    """One whole run through run.main with --rehearse; `fault(n, rows)` alters
+    the broker's n-th answer where it is produced."""
+    import benchmark.run as run
+    from pinot_tpu.cluster.broker import Broker
+    original = Broker.handle_query
+    counter = [0]
+
+    def broken(self, sql, stmt=None):
+        result = original(self, sql, stmt)
+        if sql.strip() == "SELECT COUNT(*) FROM lineorder":
+            return result            # the harness's own load probe
+        counter[0] += 1
+        result.rows = fault(counter[0], [list(r) for r in result.rows],
+                            result.columns)
+        return result
+
+    if fault:
+        Broker.handle_query = broken
+    out = io.StringIO()
+    try:
+        with contextlib.redirect_stdout(out):
+            rc = run.main(["--workload", workload, "--seed", str(SEED),
+                           "--seconds", "5", "--trace", "0", "--rehearse"])
+    finally:
+        Broker.handle_query = original
+    assert rc == 0
+    return json.loads(out.getvalue().strip().splitlines()[-1])
+
+
+def check_runs() -> None:
+    quarter = "ssb10-flat-quarter.flights-c4"
+    tiles = "ssb10-flat-stack.tiles-c32"
+    line = rehearse(quarter)
+    assert list(line)[:5] == ["correct", "attempted", "failed", "metrics",
+                              "device"] and list(line)[-1] == "checked", list(line)
+    assert line["correct"] is True and line["failed"] == 0 \
+        and line["attempted"] > 0, line
+    assert set(line["metrics"]) == {"qps", "p50_ms", "setup_s"}, line["metrics"]
+    for m in line["metrics"].values():
+        assert set(m) == {"value", "unit"} and m["value"] > 0
+    assert set(line["device"]) == {"platform", "kind", "count",
+                                   "memory_peak_bytes"}
+    for c in line["checked"].values():
+        assert set(c) == {"value", "limit"}
+    print("ok last line: the contract's keys, `checked` last, correct true "
+          "on a clean rehearsal")
+
+    def is_number(v):
+        return isinstance(v, (int, float)) and not isinstance(v, bool)
+
+    def three_faults(n, rows, columns):
+        agg = [i for i, c in enumerate(columns) if "(" in c]
+        if n % 3 == 0 and rows and agg and is_number(rows[0][agg[0]]):
+            rows[0][agg[0]] = rows[0][agg[0]] * (1 + 1e-3)   # a sum altered
+        elif n % 3 == 1 and len(rows) > 1:
+            rows = rows[:-1]                                 # a row lost
+        elif n % 3 == 2 and len(rows) > 1:
+            rows[0], rows[-1] = rows[-1], rows[0]            # the order broken
+        return rows
+    line = rehearse(quarter, three_faults)
+    chk = line["checked"]
+    assert line["correct"] is False and line["failed"] > 0, line
+    assert chk["sum_rel_gap_max"]["value"] > 10 * chk["sum_rel_gap_max"]["limit"]
+    assert chk["wrong_rows"]["value"] >= 2, chk
+    print(f"ok faults (flights): a sum altered reads "
+          f"{chk['sum_rel_gap_max']['value']:.2e}, rows lost or out of order "
+          f"{chk['wrong_rows']['value']}; correct false")
+
+    def off_by_one(n, rows, columns):
+        if n % 7 == 0 and rows and is_number(rows[0][0]):
+            rows[0][0] += 1
+        return rows
+    line = rehearse(tiles, off_by_one)
+    assert line["correct"] is False \
+        and line["checked"]["count_mismatch"]["value"] >= 1, line
+    print(f"ok faults (tiles): a count one too high reads count_mismatch "
+          f"{line['checked']['count_mismatch']['value']}; correct false")
+
+
+def main() -> int:
+    check_reference()
+    check_least_bytes()
+    check_trace_reduce()
+    check_controls()
+    if "--quick" not in sys.argv:
+        check_runs()
+    print("selftest passed (cpu; nothing here is a measurement)")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
