@@ -37,6 +37,7 @@ from benchmark.harness import (build, cells, readers, reference,  # noqa: E402
                                serve, traffic)
 
 REHEARSE_SEGMENT_ROWS = 65_536
+MAX_WORKERS = 4          # more at once ran a 40 GiB host out of memory (PERF.md)
 TRACE_LEAD_S = 2.0
 TRACE_SLICE_S = 8.0
 DEVICE = "device not opened yet"
@@ -195,8 +196,147 @@ def warm_stacks(loadgen, handles, cell, pool, tables, url, seed) -> dict:
     return out
 
 
-def main(argv=None) -> int:
+class LeastMemory:
+    """Samples /proc/meminfo's MemAvailable once a second during set-up; the
+    least it saw goes into the set-up line (the chip's host ends a command
+    that passes its limit)."""
+
+    def __init__(self):
+        import threading
+        self.least_mb, self._stop = None, threading.Event()
+        self._thread = threading.Thread(target=self._run, daemon=True)
+        self._thread.start()
+
+    def _run(self):
+        while not self._stop.wait(1.0):
+            try:
+                with open("/proc/meminfo") as f:
+                    for line in f:
+                        if line.startswith("MemAvailable:"):
+                            mb = int(line.split()[1]) // 1024
+                            if self.least_mb is None or mb < self.least_mb:
+                                self.least_mb = mb
+            except OSError:
+                return
+
+    def stop(self):
+        self._stop.set()
+        self._thread.join()
+        return self.least_mb
+
+
+def open_chip(args, cell):
+    """JAX's devices, or an exit with no result where the chip is not there."""
     global DEVICE
+    from pinot_tpu.utils.compile_cache import place_compile_cache
+    cache_dir = place_compile_cache()
+    import jax
+    devs = jax.devices()
+    DEVICE = (f"{devs[0].platform} {devs[0].device_kind} x{len(devs)}"
+              + (" REHEARSAL" if args.rehearse else ""))
+    if not args.rehearse and devs[0].platform != "tpu":
+        raise SystemExit(f"the benchmark needs a TPU; jax found "
+                         f"{devs[0].platform!r}")
+    if len(devs) < cell["chips"]:
+        raise SystemExit(f"{args.workload} needs {cell['chips']} chips; "
+                         f"jax found {len(devs)}")
+    entries = len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+    log(f"compile cache {cache_dir} ({entries} entries); host cores "
+        f"{os.cpu_count()}")
+    return jax, devs
+
+
+def warm_up(loadgen, handles, cell, pool, walks, tables, url, seed) -> int:
+    """Every query of the pool once alone, the stacked shapes the mix asks
+    for, then the mix together for a few seconds. Returns the kernel-cache
+    miss count the window starts from."""
+    mix = cell["traffic"]
+    patient = max(float(mix["timeout_s"]), 900.0)    # a cold cache compiles
+    t0 = time.perf_counter()
+    m0 = serve.kernel_cache_misses()
+    for p in pool:
+        rec = loadgen.ask({"cmd": "one", "url": url, "sql": p["sql"],
+                           "timeout_s": patient})["record"]
+        if not rec["ok"] or incomplete(rec["response"]):
+            raise SystemExit(f"warm-up: {p['sql']} -> "
+                             f"{rec['error'] or rec['response']}")
+    m1 = serve.kernel_cache_misses()
+    stacks = warm_stacks(loadgen, handles, cell, pool, tables, url, seed)
+    loadgen.ask({"cmd": "window", "url": url,
+                 "pool": [p["sql"] for p in pool],
+                 "walks": [w[len(w) // 2:] for w in walks],
+                 "seconds": float(mix.get("warm_seconds", 3.0)),
+                 "timeout_s": patient})
+    m2 = serve.kernel_cache_misses()
+    log(f"warm-up {time.perf_counter() - t0:.1f} s: {len(pool)} queries "
+        f"alone built {m1 - m0} executables, the mix together "
+        f"{m2 - m1 - stacks['built']} more; stacked shapes: {stacks}")
+    return m2
+
+
+def trace_slice(jax, args, prof_dir) -> None:
+    """Profile a slice of the running window under a `bench:window` span."""
+    time.sleep(min(TRACE_LEAD_S, args.seconds / 4))
+    jax.profiler.start_trace(prof_dir, profiler_options=trace_options(jax))
+    with jax.profiler.TraceAnnotation("bench:window"):
+        time.sleep(min(TRACE_SLICE_S, args.seconds / 2))
+    jax.profiler.stop_trace()
+
+
+def trace_options(jax):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return opts
+
+
+def solo_replay(jax, args, loadgen, cell, pool, url, rows, solo_dir):
+    """Traced: each template's first variant once, alone, under a
+    `bench:solo:<template>` span. Returns (records, [{"template",
+    "latency_ms", "least_bytes", "busy_s"}])."""
+    from benchmark.harness import trace_reduce
+    records, solo = [], []
+    jax.profiler.start_trace(solo_dir, profiler_options=trace_options(jax))
+    for i, p in enumerate(pool):
+        if p["variant"] != 0:
+            continue
+        with jax.profiler.TraceAnnotation(f"bench:solo:{p['template']}"):
+            rec = loadgen.ask({"cmd": "one", "url": url, "pool": i,
+                               "sql": p["sql"], "timeout_s":
+                               float(cell["traffic"]["timeout_s"])})["record"]
+        records.append(rec)
+        solo.append({"template": p["template"],
+                     "latency_ms": (rec["done"] - rec["sent"]) * 1000.0,
+                     "least_bytes": readers.least_bytes(
+                         p["spec"], dict(cell["config"], rows=rows))})
+    jax.profiler.stop_trace()
+    spans = trace_reduce.reduce(trace_reduce.newest_xplane(solo_dir),
+                                cpu_stand_in=args.rehearse)["spans"]
+    for s in solo:
+        s["busy_s"] = sum(sp["busy_s"] for sp in
+                          spans.get(f"bench:solo:{s['template']}", []))
+        log(f"solo {s['template']}: latency {s['latency_ms']:.1f} ms, device "
+            f"busy {s['busy_s'] * 1000:.2f} ms, least bytes {s['least_bytes']}")
+    return records, solo
+
+
+def control_numbers(controls, records, pool, want, sum_limit) -> dict:
+    """How far each control is from the reference, by the same comparison, and
+    the program's own widest sum gaps beside them."""
+    out = {}
+    for name, answers in controls.items():
+        fake = [{"ok": True, "pool": q, "response": {
+            "numServersQueried": 1, "numServersResponded": 1,
+            "resultTable": {"rows": answers[q]}}} for q in range(len(pool))]
+        out[name] = judge(fake, pool, want, sum_limit)["numbers"]
+    gaps = [reference.compare(pool[r["pool"]]["spec"],
+                              r["response"]["resultTable"]["rows"],
+                              want[r["pool"]], sum_limit)["sum_gap"]
+            for r in records if r["ok"] and not incomplete(r["response"])]
+    out["program_sum_gaps_sorted_top"] = sorted(gaps)[-5:]
+    return out
+
+
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -220,45 +360,31 @@ def main(argv=None) -> int:
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(work)
 
-    gen = cells.load_generator(config)
-    tables = gen.tables(config)
+    tables = cells.load_generator(config).tables(config)
     pool = traffic.build_pool(mix, cell["templates"], tables, args.seed)
     walks = traffic.client_walks(mix, pool, args.seed)
+    sqls = [p["sql"] for p in pool]
     table_with_type = config["table"] + "_OFFLINE"
     seg_out = serve.server_segment_dir(work, table_with_type)
     os.makedirs(seg_out)
 
     loadgen = LoadGen()     # before this process touches JAX
-    cores = os.cpu_count() or 1
-    # one wave of workers where the segments are at most twice the cores
-    workers = n_seg if n_seg <= 2 * cores else cores
+    workers = min(os.cpu_count() or 1, n_seg, MAX_WORKERS)
     pool_exec = ProcessPoolExecutor(
         max_workers=workers, mp_context=multiprocessing.get_context("spawn"))
     handles = None
     try:
+        # -- set-up: the workers build while this process opens the chip -------
         t0 = time.perf_counter()
+        memory = LeastMemory()
         builds = [pool_exec.submit(build.build_segment, {
             "config": config, "seed": args.seed, "index": i, "rows": seg_rows,
             "out_dir": seg_out}) for i in range(n_seg)]
-
-        # -- the chip, while the workers build ---------------------------------
-        from pinot_tpu.utils.compile_cache import place_compile_cache
-        cache_dir = place_compile_cache()
-        import jax
-        devs = jax.devices()
-        DEVICE = (f"{devs[0].platform} {devs[0].device_kind} x{len(devs)}"
-                  + (" REHEARSAL" if args.rehearse else ""))
-        if not args.rehearse and devs[0].platform != "tpu":
-            raise SystemExit(f"the benchmark needs a TPU; jax found "
-                             f"{devs[0].platform!r}")
-        if len(devs) < cell["chips"]:
-            raise SystemExit(f"{args.workload} needs {cell['chips']} chips; "
-                             f"jax found {len(devs)}")
+        jax, devs = open_chip(args, cell)
         peaks = None if args.rehearse else cells.peaks(devs[0].device_kind)
         log(f"{args.workload} seed {args.seed}: {rows} rows in {n_seg} "
-            f"segments, {mix['clients']} clients, pool of {len(pool)} queries; "
-            f"host cores {os.cpu_count()}, build workers {workers}; compile "
-            f"cache {cache_dir} ({len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0} entries)")
+            f"segments on {workers} build workers, {mix['clients']} clients, "
+            f"pool of {len(pool)} queries")
         handles = serve.start_services(work, config["cluster"])
         serve.create_table(handles, config)
         built, upload_tail_s = serve.upload_as_built(handles, table_with_type,
@@ -270,52 +396,32 @@ def main(argv=None) -> int:
             f"{statistics.fmean(b['build_s'] for b in built):.1f} s, "
             f"{sum(b['bytes'] for b in built)} bytes in all), upload (gzip, "
             f"metadata, assignment) {upload_tail_s:.1f} s more after the last "
-            f"build, load onto the device {load_s:.1f} s")
-
-        # -- warm-up: each query of the pool alone, then the mix together ------
+            f"build, load {load_s:.1f} s; least host memory available "
+            f"{memory.stop()} MB")
         url = handles["broker"].url
-        timeout_s = float(mix["timeout_s"])
-        t0 = time.perf_counter()
-        m0 = serve.kernel_cache_misses()
-        warm = [p for p in range(len(pool))
-                if mix.get("warm", "pool") == "pool" or pool[p]["variant"] == 0]
-        for p in warm:
-            rec = loadgen.ask({"cmd": "one", "url": url, "sql": pool[p]["sql"],
-                               "timeout_s": max(timeout_s, 900.0)})["record"]
-            if not rec["ok"] or incomplete(rec["response"]):
-                raise SystemExit(f"warm-up: {pool[p]['sql']} -> "
-                                 f"{rec['error'] or rec['response']}")
-        m1 = serve.kernel_cache_misses()
-        stacks = warm_stacks(loadgen, handles, cell, pool, tables, url,
-                             args.seed)
-        loadgen.ask({"cmd": "window", "url": url,
-                     "pool": [p["sql"] for p in pool],
-                     "walks": [w[len(w) // 2:] for w in walks],
-                     "seconds": float(mix.get("warm_seconds", 3.0)),
-                     "timeout_s": max(timeout_s, 900.0)})
-        m2 = serve.kernel_cache_misses()
-        log(f"warm-up {time.perf_counter() - t0:.1f} s: {len(warm)} queries "
-            f"alone built {m1 - m0} executables, the mix together "
-            f"{m2 - m1 - stacks['built']} more; stacked shapes: {stacks}")
+        misses0 = warm_up(loadgen, handles, cell, pool, walks, tables, url,
+                          args.seed)
 
         # -- the window --------------------------------------------------------
         c0 = serve.pipeline_counters(handles)
         setup_s = time.perf_counter() - T_START
-        loadgen.send({"cmd": "window", "url": url,
-                      "pool": [p["sql"] for p in pool], "walks": walks,
-                      "seconds": args.seconds, "timeout_s": timeout_s})
-        trace = None
+        loadgen.send({"cmd": "window", "url": url, "pool": sqls,
+                      "walks": walks, "seconds": args.seconds,
+                      "timeout_s": float(mix["timeout_s"])})
         prof_dir = os.path.join(work, "profile")
         if args.trace:
+            trace_slice(jax, args, prof_dir)
+        window_records = loadgen.reply()["records"]
+        c1 = serve.pipeline_counters(handles)
+        compiles = serve.kernel_cache_misses() - misses0
+        memory_peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                          for d in devs[:cell["chips"]])
+        counters = {k: c1[k] - c0[k] for k in c0
+                    if isinstance(c0[k], (int, float))
+                    and isinstance(c1.get(k), (int, float))}
+        trace, solo_records, solo = None, [], []
+        if args.trace:      # reduced only now: this process served the window
             from benchmark.harness import trace_reduce
-            opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0
-            lead = min(TRACE_LEAD_S, args.seconds / 4)
-            time.sleep(lead)
-            jax.profiler.start_trace(prof_dir, profiler_options=opts)
-            with jax.profiler.TraceAnnotation("bench:window"):
-                time.sleep(min(TRACE_SLICE_S, args.seconds / 2))
-            jax.profiler.stop_trace()
             xplane = trace_reduce.newest_xplane(prof_dir)
             trace = trace_reduce.reduce(xplane, cpu_stand_in=args.rehearse)
             if args.keep_trace:
@@ -324,119 +430,70 @@ def main(argv=None) -> int:
                 shutil.copy(xplane, args.keep_trace)
             log(f"trace: planes and lines "
                 f"{[s for s in trace['seen'] if s[1]]}")
-        win = loadgen.reply()
-        c1 = serve.pipeline_counters(handles)
-        compiles = serve.kernel_cache_misses() - m2
-        stats = [d.memory_stats() or {} for d in devs[:cell["chips"]]]
-        memory_peak = max(s.get("peak_bytes_in_use", 0) for s in stats)
-        counters = {k: c1[k] - c0[k] for k in c0
-                    if isinstance(c0[k], (int, float))
-                    and isinstance(c1.get(k), (int, float))}
-
-        window_records = win["records"]
-        records = list(window_records)
-        for r in records:
-            r["latency_ms"] = (r["done"] - r["sent"]) * 1000.0
-        in_window = [r for r in records if r["done"] <= args.seconds]
-        lat = [r["latency_ms"] for r in records]
-
-        # -- the solo replay (traced runs): each template once, alone ----------
-        solo = []
-        if args.trace:
-            solo_dir = os.path.join(work, "profile_solo")
-            jax.profiler.start_trace(solo_dir, profiler_options=opts)
-            firsts = [p for p in range(len(pool)) if pool[p]["variant"] == 0]
-            for p in firsts:
-                with jax.profiler.TraceAnnotation(
-                        f"bench:solo:{pool[p]['template']}"):
-                    rec = loadgen.ask({"cmd": "one", "url": url, "pool": p,
-                                       "sql": pool[p]["sql"],
-                                       "timeout_s": timeout_s})["record"]
-                rec["latency_ms"] = (rec["done"] - rec["sent"]) * 1000.0
-                records.append(rec)
-                solo.append({"template": pool[p]["template"],
-                             "latency_ms": rec["latency_ms"],
-                             "least_bytes": readers.least_bytes(
-                                 pool[p]["spec"], dict(config, rows=rows))})
-            jax.profiler.stop_trace()
-            st = trace_reduce.reduce(trace_reduce.newest_xplane(solo_dir),
-                                     cpu_stand_in=args.rehearse)
-            for s in solo:
-                spans = st["spans"].get(f"bench:solo:{s['template']}", [])
-                s["busy_s"] = sum(sp["busy_s"] for sp in spans)
-                log(f"solo {s['template']}: latency {s['latency_ms']:.1f} ms, "
-                    f"device busy {s['busy_s'] * 1000:.2f} ms, least bytes "
-                    f"{s['least_bytes']}")
+            solo_records, solo = solo_replay(
+                jax, args, loadgen, cell, pool, url, rows,
+                os.path.join(work, "profile_solo"))
         serve.stop_services(handles)
         handles = None
 
         # -- the reference, once the window has closed -------------------------
         t0 = time.perf_counter()
+        records = window_records + solo_records
         want, controls = reference_answers(pool_exec, cell, args.seed, seg_rows,
                                            pool, tables, bool(args.control))
         verdict = judge(records, pool, want, sum_limit)
         log(f"reference: {len(pool)} answers over {rows} rows and the "
             f"comparison of {len(records)} served answers in "
             f"{time.perf_counter() - t0:.1f} s")
-        numbers = verdict["numbers"]
-        numbers["device_errors"] = counters.get("deviceErrors", 0)
-        numbers["timeouts"] = counters.get("timeouts", 0)
-        numbers["compiles_in_window"] = compiles
-        limits = {k: 0 for k in numbers}
-        limits["sum_rel_gap_max"] = sum_limit
-        correct = (len(records) > 0 and len(in_window) > 0
-                   and all(numbers[k] <= limits[k] for k in numbers))
-        checked = {k: {"value": numbers[k], "limit": limits[k]}
-                   for k in numbers}
-        control_out = None
-        if controls:
-            control_out = {}
-            for name, answers in controls.items():
-                fake = [{"ok": True, "pool": q, "response": {
-                    "numServersQueried": 1, "numServersResponded": 1,
-                    "resultTable": {"rows": answers[q]}}}
-                    for q in range(len(pool))]
-                control_out[name] = judge(fake, pool, want, sum_limit)["numbers"]
-            per_query = [reference.compare(
-                pool[r["pool"]]["spec"], r["response"]["resultTable"]["rows"],
-                want[r["pool"]], sum_limit)["sum_gap"]
-                for r in records if r["ok"] and not incomplete(r["response"])]
-            control_out["program_sum_gaps_sorted_top"] = sorted(per_query)[-5:]
+        numbers = dict(verdict["numbers"],
+                       device_errors=counters.get("deviceErrors", 0),
+                       timeouts=counters.get("timeouts", 0),
+                       compiles_in_window=compiles)
+        limits = dict({k: 0 for k in numbers}, sum_rel_gap_max=sum_limit)
+        lat = [(r["done"] - r["sent"]) * 1000.0 for r in window_records]
+        for r, ms in zip(window_records, lat):
+            r["latency_ms"] = ms
+        in_window = [r for r in window_records if r["done"] <= args.seconds]
+        correct = bool(in_window) and all(numbers[k] <= limits[k]
+                                          for k in numbers)
 
         # -- the metrics -------------------------------------------------------
-        values = {"qps": len(in_window) / args.seconds,
-                  "p50_ms": statistics.median(lat) if lat else None,
-                  "p95_ms": percentile(lat, 0.95) if lat else None,
-                  "setup_s": setup_s}
         if args.trace:
             ctx = {"records": [r for r in window_records if r["ok"]],
                    "counters": counters, "trace": trace, "solo": solo,
                    "peaks": peaks}
-            owed, values = cell["per_layer"], {}
-            for m in owed:
-                values[m["name"]] = cells.load_reader(m["name"])(ctx)
+            owed = cell["per_layer"]
+            values = {m["name"]: cells.load_reader(m["name"])(ctx)
+                      for m in owed}
         else:
             owed = cell["end_to_end"]
-        metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
-                   for m in owed if values.get(m["name"]) is not None}
-        log(f"window {args.seconds} s: sent {len(records) - len(solo)}, "
-            f"answered inside {len(in_window)}, latency p50 "
-            f"{values.get('p50_ms', statistics.median(lat) if lat else None)} ms "
-            f"p95 {percentile(lat, 0.95) if lat else None} ms max "
+            values = {"qps": len(in_window) / args.seconds,
+                      "p50_ms": statistics.median(lat) if lat else None,
+                      "p95_ms": percentile(lat, 0.95) if lat else None,
+                      "setup_s": setup_s}
+        log(f"window {args.seconds} s: sent {len(window_records)}, answered "
+            f"inside {len(in_window)}, latency p50 "
+            f"{statistics.median(lat) if lat else None} ms p95 "
+            f"{percentile(lat, 0.95) if lat else None} ms max "
             f"{max(lat) if lat else None} ms; pipeline {counters}")
         device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
                   "count": len(devs), "memory_peak_bytes": memory_peak}
-        result = {"correct": bool(correct), "attempted": len(records),
-                  "failed": verdict["failed"], "metrics": metrics,
+        result = {"correct": correct, "attempted": len(records),
+                  "failed": verdict["failed"],
+                  "metrics": {m["name"]: {"value": values[m["name"]],
+                                          "unit": m["unit"]}
+                              for m in owed
+                              if values.get(m["name"]) is not None},
                   "device": device}
         if trace is not None:
-            device["busy_s"] = trace["busy_s"]
-            device["window_s"] = trace["window_s"]
+            device.update(busy_s=trace["busy_s"], window_s=trace["window_s"])
             result["breakdown"] = {"device_ops": trace["device_ops"],
                                    "idle_gaps": trace["idle_gaps"]}
-        if control_out is not None:
-            result["control"] = control_out
-        result["checked"] = checked
+        if controls:
+            result["control"] = control_numbers(controls, records, pool, want,
+                                                sum_limit)
+        result["checked"] = {k: {"value": numbers[k], "limit": limits[k]}
+                             for k in numbers}
         for note in verdict["notes"]:
             print(f"[{DEVICE}] failed: {note}", file=sys.stderr)
         for k in numbers:
