@@ -1,8 +1,10 @@
-"""Finds a cell's files by the names in BENCHMARK.json. Imports the stdlib only."""
+"""Finds a cell's files by the names in BENCHMARK.json."""
 
 import importlib.util
 import json
 import os
+
+from . import readers
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
@@ -58,7 +60,6 @@ def load_reader(metric_name: str):
     beside = os.path.join(BENCH, "metrics", metric_name + ".py")
     if os.path.exists(beside):
         return load_py(beside).read
-    from . import readers
     return getattr(readers, meta["reader"])
 
 

@@ -10,6 +10,8 @@ ctx: {"records": the window's answered queries, each {"latency_ms",
 
 import statistics
 
+from . import reference
+
 
 def _responses(ctx):
     return [r for r in ctx["records"] if r.get("response")]
@@ -76,7 +78,6 @@ def least_bytes(spec: dict, config: dict) -> int:
     """The least a scan of the columns this template reads must move: rows x
     the narrowest of 1, 2 or 4 bytes that holds each column's cardinality or
     value range. From the configuration file alone."""
-    from . import reference
     by_name = {c["name"]: c for c in config["schema"]}
     total = 0
     for col in reference.columns_read(spec):

@@ -168,12 +168,16 @@ def compare(spec, got: list, want: list, sum_limit: float) -> dict:
         out.update(wrong=1, why=f"{len(got)} rows, want {len(want)}")
         return out
     want_by_key = {tuple(r[i] for i in key_at): r[agg_at] for r in want}
-    ref_agg = []
+    ref_agg, seen = [], set()
     for r in got:
         if len(r) != len(sel):
             out.update(wrong=1, why=f"row of {len(r)} columns")
             return out
         k = tuple(_like(r[i], want[0][i]) for i in key_at)
+        if k in seen:
+            out.update(wrong=1, why=f"group {k} twice")
+            return out
+        seen.add(k)
         if k not in want_by_key:
             out.update(wrong=1, why=f"group {k} is not in the reference")
             return out
@@ -187,10 +191,6 @@ def compare(spec, got: list, want: list, sum_limit: float) -> dict:
             gap = abs(float(r[agg_at]) - w) / max(abs(w), 1.0)
             if not gap <= out["sum_gap"]:      # a nan counts as the widest
                 out["sum_gap"] = gap if gap == gap else float("inf")
-    if len(set(tuple(_like(r[i], want[0][i]) for i in key_at)
-               for r in got)) != len(got):
-        out.update(wrong=1, why="a group twice")
-        return out
     order = spec.get("order_by", [])
     if order and len(got) > 1:
         def okey(r, a):

@@ -7,7 +7,7 @@ counters). The segments reach the server through the controller's
 import json
 import os
 import time
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import ThreadPoolExecutor, as_completed
 
 from . import build
 
@@ -41,22 +41,26 @@ def server_segment_dir(work: str, table_with_type: str) -> str:
     return os.path.join(work, "work", "server_0", table_with_type)
 
 
-def create_table(handles, config: dict) -> str:
+def create_table(handles, config: dict, table_with_type: str) -> None:
+    """Schema + OFFLINE table; `table_with_type` is the name the segment
+    directories were made under, which has to be the program's."""
     from pinot_tpu.cluster.process import ControllerClient
     from pinot_tpu.table import TableConfig
     ctrl = ControllerClient(handles["controller"].url)
     ctrl.add_schema(build.make_schema(config))
     table = TableConfig(config["table"])
+    if table.table_name_with_type != table_with_type:
+        raise SystemExit(f"the program names the table "
+                         f"{table.table_name_with_type!r}, not {table_with_type!r}")
     ctrl.add_table(table)
-    return table.table_name_with_type
 
 
-def upload_as_built(handles, table_with_type: str, builds) -> list:
+def upload_as_built(handles, table_with_type: str, builds) -> tuple:
     """Each segment, as soon as its worker has built it, through the
     controller object's `upload_segment` (gzip into the deep store, metadata,
     assignment; zlib frees the GIL, so one thread a segment). `builds` are the
-    workers' futures; returns their results."""
-    from concurrent.futures import as_completed
+    workers' futures; returns (their results, the seconds the uploads took
+    after the last build)."""
     controller = handles["controller_obj"]
     built, uploads = [], []
     with ThreadPoolExecutor(max_workers=len(builds)) as pool:

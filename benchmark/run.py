@@ -28,6 +28,7 @@ import shutil  # noqa: E402
 import statistics  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
+import threading  # noqa: E402
 from concurrent.futures import ProcessPoolExecutor  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -202,7 +203,6 @@ class LeastMemory:
     that passes its limit)."""
 
     def __init__(self):
-        import threading
         self.least_mb, self._stop = None, threading.Event()
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
@@ -386,7 +386,7 @@ def main(argv=None) -> int:
             f"segments on {workers} build workers, {mix['clients']} clients, "
             f"pool of {len(pool)} queries")
         handles = serve.start_services(work, config["cluster"])
-        serve.create_table(handles, config)
+        serve.create_table(handles, config, table_with_type)
         built, upload_tail_s = serve.upload_as_built(handles, table_with_type,
                                                      builds)
         t_build = time.perf_counter() - t0 - upload_tail_s
