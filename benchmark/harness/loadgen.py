@@ -5,15 +5,14 @@ Started by run.py before the parent touches JAX. It reads one JSON command a
 line on stdin and answers each with one JSON line on stdout:
 
   {"cmd": "window", "url", "pool": [sql...], "walks": [[pool index...]...],
-   "seconds", "timeout_s"}
+   "clients", "seconds", "timeout_s"}
       closed loop: one thread and one keep-alive connection a client, each
       sending its walk's next query when the last one is answered, until
       `seconds` have passed; queries in flight at the close are waited for.
+      Client c walks `walks[c % len(walks)]`; clients that share a walk each
+      take its next query (one walk for all: a shared queue).
       -> {"t0", "t_close", "records": [...]}
   {"cmd": "one", "url", "sql", "timeout_s"}   one query, alone -> {"record"}
-  {"cmd": "burst", "url", "blocker", "sqls", "delay_s", "timeout_s"}
-      warm-up of stacked shapes: the blocker alone, then after `delay_s` all of
-      `sqls` at once, so that they queue behind it -> {"records"}
   {"cmd": "quit"}
 
 A record is {"client", "pool", "sent", "done", "ok", "error", "response"}:
@@ -66,8 +65,10 @@ def send(conn_box, url, timeout_s, sql):
 def window(cmd: dict) -> dict:
     url, pool, walks = cmd["url"], cmd["pool"], cmd["walks"]
     seconds, timeout_s = float(cmd["seconds"]), float(cmd["timeout_s"])
+    clients = int(cmd.get("clients", len(walks)))
     records, lock = [], threading.Lock()
-    start = threading.Barrier(len(walks) + 1)
+    cursors = [0] * len(walks)
+    start = threading.Barrier(clients + 1)
     t0_box = [0.0]
 
     def client(c: int):
@@ -80,13 +81,14 @@ def window(cmd: dict) -> dict:
         mine = []
         start.wait()
         t0 = t0_box[0]
-        k = 0
+        w = c % len(walks)      # fewer walks than clients: a shared queue
         while True:
             sent = time.perf_counter() - t0
             if sent >= seconds:
                 break
-            p = walks[c][k % len(walks[c])]
-            k += 1
+            with lock:
+                k, cursors[w] = cursors[w], cursors[w] + 1
+            p = walks[w][k % len(walks[w])]
             rec = send(box, url, timeout_s, pool[p])
             rec.update(client=c, pool=p, sent=sent,
                        done=time.perf_counter() - t0)
@@ -97,7 +99,7 @@ def window(cmd: dict) -> dict:
             records.extend(mine)
 
     threads = [threading.Thread(target=client, args=(c,), daemon=True)
-               for c in range(len(walks))]
+               for c in range(clients)]
     for t in threads:
         t.start()
     t0_box[0] = time.perf_counter()
@@ -105,27 +107,6 @@ def window(cmd: dict) -> dict:
     for t in threads:
         t.join()
     return {"t0": t0_box[0], "t_close": seconds, "records": records}
-
-
-def burst(cmd: dict) -> dict:
-    url, timeout_s = cmd["url"], float(cmd["timeout_s"])
-    records = [None] * (1 + len(cmd["sqls"]))
-
-    def one(i, sql):
-        box = [None]
-        records[i] = send(box, url, timeout_s, sql)
-        if box[0] is not None:
-            box[0].close()
-
-    threads = [threading.Thread(target=one, args=(0, cmd["blocker"]))]
-    threads[0].start()
-    time.sleep(float(cmd["delay_s"]))
-    for i, sql in enumerate(cmd["sqls"]):
-        threads.append(threading.Thread(target=one, args=(i + 1, sql)))
-        threads[-1].start()
-    for t in threads:
-        t.join()
-    return {"records": records}
 
 
 def main() -> int:
@@ -145,8 +126,6 @@ def main() -> int:
             if box[0] is not None:
                 box[0].close()
             reply = {"record": rec}
-        elif cmd["cmd"] == "burst":
-            reply = burst(cmd)
         else:
             reply = {"error": f"unknown command {cmd['cmd']!r}"}
         out.write(json.dumps(reply) + "\n")

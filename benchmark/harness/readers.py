@@ -39,6 +39,21 @@ def broker_plan_reduce_ms(ctx):
     return _mean_of(ctx, pick)
 
 
+def broker_server_hop_ms(ctx):
+    """What is left of the broker's timeUsedMs after its own plan and reduce,
+    the pipeline's queue wait and the device sync: scatter, the server's HTTP
+    hop, serialisation, and waiting for the interpreter."""
+    def pick(resp):
+        p = resp.get("phaseTimesMs") or {}
+        if "timeUsedMs" not in resp:
+            return None
+        return (float(resp["timeUsedMs"]) - float(p.get("compile", 0.0))
+                - float(p.get("reduce", 0.0))
+                - float(resp.get("queueWaitMs", 0.0))
+                - float(resp.get("deviceFetchMs", 0.0)))
+    return _mean_of(ctx, pick)
+
+
 def pipeline_queue_wait_ms(ctx):
     return _mean_of(ctx, lambda r: float(r["queueWaitMs"])
                     if "queueWaitMs" in r else None)

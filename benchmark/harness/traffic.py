@@ -53,15 +53,20 @@ def build_pool(traffic: dict, templates: list, tables: dict, seed: int) -> list:
 
 
 def client_walks(traffic: dict, pool: list, seed: int) -> list:
-    """For each client the pool indexes it sends, in order: rounds over all
-    the templates, each round shuffled from the seed, the variant stepping
-    with the round, so every seed offers the same work in another order."""
+    """The pool indexes to send, in order: rounds over all the templates, each
+    round shuffled from the seed, the variant stepping with the round, so every
+    seed offers the same work in another order. One walk a client; where the
+    mix says `"queue": "shared"`, one walk in all, from which every client
+    takes the next query as its last one is answered (a connection pool that
+    drains one queue of dashboard refreshes), so that any stretch of the window
+    holds whole rounds and at most one part of a round, not one part a client."""
     by_template = {}
     for i, p in enumerate(pool):
         by_template.setdefault(p["template"], []).append(i)
     variants = list(by_template.values())
+    shared = traffic.get("queue") == "shared"
     walks = []
-    for c in range(int(traffic["clients"])):
+    for c in range(1 if shared else int(traffic["clients"])):
         rng = np.random.default_rng([seed, 11, c])
         walk = []
         for r in range(WALK_ROUNDS):

@@ -54,6 +54,16 @@ def percentile(values, q: float) -> float:
     return v[max(0, math.ceil(q * len(v)) - 1)]
 
 
+def answered_by_slice(records, seconds: float, width: float = 5.0) -> list:
+    """Answers that arrived in each `width` seconds of the window: a stall or a
+    change of regime inside a run shows here."""
+    counts = [0] * max(1, math.ceil(seconds / width))
+    for r in records:
+        if r["done"] <= seconds:
+            counts[min(len(counts) - 1, int(r["done"] // width))] += 1
+    return counts
+
+
 class LoadGen:
     """The child that sends the queries (harness/loadgen.py)."""
 
@@ -153,50 +163,6 @@ def reference_answers(pool_exec, cell, seed, rows_per_segment, pool, tables,
     return want, controls
 
 
-def warm_stacks(loadgen, handles, cell, pool, tables, url, seed) -> dict:
-    """Where the mix names `warm_stacks`: for every template and every listed
-    batch size B, one blocker query alone and, while the device works on it, B
-    variants of the template at once, so that the pipeline drains them as one
-    stacked launch and builds that shape now, not in the window. A burst that
-    split into several launches is sent again."""
-    mix = cell["traffic"]
-    sizes = mix.get("warm_stacks", [])
-    out = {"bursts": 0, "split": 0, "built": 0}
-    if not sizes:
-        return out
-    import numpy as np
-    blocker_t = cells.read_json(cells.BENCH, "queries",
-                                mix["warm_blocker"] + ".json")
-    holes = traffic.draw_holes(blocker_t, tables,
-                               np.random.default_rng([seed, 13]))
-    blocker = blocker_t["sql"].format(**holes)
-    m0 = serve.kernel_cache_misses()
-    for t in cell["templates"]:
-        mine = [p["sql"] for p in pool if p["template"] == t["name"]]
-        for b in sizes:
-            # b variants pad to a batch of b; fewer variants than b still
-            # reach that padded batch if they round up to it
-            k = min(b, len(mine))
-            if k < 2 or 1 << (k - 1).bit_length() != b:
-                continue
-            for attempt in range(5):
-                c0 = serve.pipeline_counters(handles)
-                recs = loadgen.ask({"cmd": "burst", "url": url,
-                                    "blocker": blocker, "sqls": mine[:k],
-                                    "delay_s": 0.03, "timeout_s": 900.0}
-                                   )["records"]
-                for r in recs:
-                    if not r["ok"] or incomplete(r["response"]):
-                        raise SystemExit(f"warm-up burst: {r}")
-                c1 = serve.pipeline_counters(handles)
-                out["bursts"] += 1
-                if c1["launches"] - c0["launches"] == 2:
-                    break
-                out["split"] += 1
-    out["built"] = serve.kernel_cache_misses() - m0
-    return out
-
-
 class LeastMemory:
     """Samples /proc/meminfo's MemAvailable once a second during set-up; the
     least it saw goes into the set-up line (the chip's host ends a command
@@ -246,10 +212,9 @@ def open_chip(args, cell):
     return jax, devs
 
 
-def warm_up(loadgen, handles, cell, pool, walks, tables, url, seed) -> int:
-    """Every query of the pool once alone, the stacked shapes the mix asks
-    for, then the mix together for a few seconds. Returns the kernel-cache
-    miss count the window starts from."""
+def warm_up(loadgen, cell, pool, walks, url) -> int:
+    """Every query of the pool once alone, then the mix together for a few
+    seconds. Returns the kernel-cache miss count the window starts from."""
     mix = cell["traffic"]
     patient = max(float(mix["timeout_s"]), 900.0)    # a cold cache compiles
     t0 = time.perf_counter()
@@ -261,16 +226,14 @@ def warm_up(loadgen, handles, cell, pool, walks, tables, url, seed) -> int:
             raise SystemExit(f"warm-up: {p['sql']} -> "
                              f"{rec['error'] or rec['response']}")
     m1 = serve.kernel_cache_misses()
-    stacks = warm_stacks(loadgen, handles, cell, pool, tables, url, seed)
     loadgen.ask({"cmd": "window", "url": url,
                  "pool": [p["sql"] for p in pool],
                  "walks": [w[len(w) // 2:] for w in walks],
                  "seconds": float(mix.get("warm_seconds", 3.0)),
-                 "timeout_s": patient})
+                 "clients": int(mix["clients"]), "timeout_s": patient})
     m2 = serve.kernel_cache_misses()
     log(f"warm-up {time.perf_counter() - t0:.1f} s: {len(pool)} queries "
-        f"alone built {m1 - m0} executables, the mix together "
-        f"{m2 - m1 - stacks['built']} more; stacked shapes: {stacks}")
+        f"alone built {m1 - m0} executables, the mix together {m2 - m1} more")
     return m2
 
 
@@ -399,14 +362,14 @@ def main(argv=None) -> int:
             f"build, load {load_s:.1f} s; least host memory available "
             f"{memory.stop()} MB")
         url = handles["broker"].url
-        misses0 = warm_up(loadgen, handles, cell, pool, walks, tables, url,
-                          args.seed)
+        misses0 = warm_up(loadgen, cell, pool, walks, url)
 
         # -- the window --------------------------------------------------------
         c0 = serve.pipeline_counters(handles)
         setup_s = time.perf_counter() - T_START
         loadgen.send({"cmd": "window", "url": url, "pool": sqls,
-                      "walks": walks, "seconds": args.seconds,
+                      "walks": walks, "clients": int(mix["clients"]),
+                      "seconds": args.seconds,
                       "timeout_s": float(mix["timeout_s"])})
         prof_dir = os.path.join(work, "profile")
         if args.trace:
@@ -453,6 +416,9 @@ def main(argv=None) -> int:
         lat = [(r["done"] - r["sent"]) * 1000.0 for r in window_records]
         for r, ms in zip(window_records, lat):
             r["latency_ms"] = ms
+        mean_ms = statistics.fmean(lat) if lat else None
+        p50_ms = statistics.median(lat) if lat else None
+        p95_ms = percentile(lat, 0.95) if lat else None
         in_window = [r for r in window_records if r["done"] <= args.seconds]
         correct = bool(in_window) and all(numbers[k] <= limits[k]
                                           for k in numbers)
@@ -468,14 +434,14 @@ def main(argv=None) -> int:
         else:
             owed = cell["end_to_end"]
             values = {"qps": len(in_window) / args.seconds,
-                      "p50_ms": statistics.median(lat) if lat else None,
-                      "p95_ms": percentile(lat, 0.95) if lat else None,
-                      "setup_s": setup_s}
+                      "mean_ms": mean_ms, "p50_ms": p50_ms,
+                      "p95_ms": p95_ms, "setup_s": setup_s}
         log(f"window {args.seconds} s: sent {len(window_records)}, answered "
-            f"inside {len(in_window)}, latency p50 "
-            f"{statistics.median(lat) if lat else None} ms p95 "
-            f"{percentile(lat, 0.95) if lat else None} ms max "
-            f"{max(lat) if lat else None} ms; pipeline {counters}")
+            f"inside {len(in_window)}, latency mean {mean_ms} ms p50 "
+            f"{p50_ms} ms p95 {p95_ms} ms max "
+            f"{max(lat) if lat else None} ms; answered in each 5 s "
+            f"{answered_by_slice(window_records, args.seconds)}; pipeline "
+            f"{counters}")
         device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
                   "count": len(devs), "memory_peak_bytes": memory_peak}
         result = {"correct": correct, "attempted": len(records),

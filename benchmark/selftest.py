@@ -8,9 +8,9 @@
      chip trace under fixtures/ against the numbers written down beside it;
   4. the controls come out as not correct: the reference at bfloat16 fails the
      sum limit, the reference with one segment left out fails counts and rows;
-  5. (not with --quick) three whole rehearsal runs through run.py, which skip
+  5. (not with --quick) two whole rehearsal runs through run.py, which skip
      only the look for a chip: a clean one, whose last line must have the
-     contract's shape and `correct` true; and two with the timed path broken
+     contract's shape and `correct` true; and one with the timed path broken
      underneath (the broker's answers altered where they are produced), which
      must come out `correct` false, each fault by its own number.
 
@@ -225,13 +225,12 @@ def rehearse(workload: str, fault=None) -> dict:
 
 def check_runs() -> None:
     quarter = "ssb10-flat-quarter.flights-c4"
-    tiles = "ssb10-flat-stack.tiles-c32"
     line = rehearse(quarter)
     assert list(line)[:5] == ["correct", "attempted", "failed", "metrics",
                               "device"] and list(line)[-1] == "checked", list(line)
     assert line["correct"] is True and line["failed"] == 0 \
         and line["attempted"] > 0, line
-    assert set(line["metrics"]) == {"qps", "p50_ms", "setup_s"}, line["metrics"]
+    assert set(line["metrics"]) == {"qps", "mean_ms", "setup_s"}, line["metrics"]
     for m in line["metrics"].values():
         assert set(m) == {"value", "unit"} and m["value"] > 0
     assert set(line["device"]) == {"platform", "kind", "count",
@@ -261,16 +260,6 @@ def check_runs() -> None:
     print(f"ok faults (flights): a sum altered reads "
           f"{chk['sum_rel_gap_max']['value']:.2e}, rows lost or out of order "
           f"{chk['wrong_rows']['value']}; correct false")
-
-    def off_by_one(n, rows, columns):
-        if n % 7 == 0 and rows and is_number(rows[0][0]):
-            rows[0][0] += 1
-        return rows
-    line = rehearse(tiles, off_by_one)
-    assert line["correct"] is False \
-        and line["checked"]["count_mismatch"]["value"] >= 1, line
-    print(f"ok faults (tiles): a count one too high reads count_mismatch "
-          f"{line['checked']['count_mismatch']['value']}; correct false")
 
 
 def main() -> int:
