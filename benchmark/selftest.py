@@ -8,9 +8,9 @@
      chip trace under fixtures/ against the numbers written down beside it;
   4. the controls come out as not correct: the reference at bfloat16 fails the
      sum limit, the reference with one segment left out fails counts and rows;
-  5. (not with --quick) two whole rehearsal runs through run.py, which skip
+  5. (not with --quick) three whole rehearsal runs through run.py, which skip
      only the look for a chip: a clean one, whose last line must have the
-     contract's shape and `correct` true; and one with the timed path broken
+     contract's shape and `correct` true; and two with the timed path broken
      underneath (the broker's answers altered where they are produced), which
      must come out `correct` false, each fault by its own number.
 
@@ -260,6 +260,16 @@ def check_runs() -> None:
     print(f"ok faults (flights): a sum altered reads "
           f"{chk['sum_rel_gap_max']['value']:.2e}, rows lost or out of order "
           f"{chk['wrong_rows']['value']}; correct false")
+
+    def off_by_one(n, rows, columns):
+        if n % 7 == 0 and rows and is_number(rows[0][0]):
+            rows[0][0] += 1
+        return rows
+    line = rehearse("ssb10-flat-quarter.tiles-c4", off_by_one)
+    assert line["correct"] is False \
+        and line["checked"]["count_mismatch"]["value"] >= 1, line
+    print(f"ok faults (tiles): a count one too high reads count_mismatch "
+          f"{line['checked']['count_mismatch']['value']}; correct false")
 
 
 def main() -> int:
