@@ -1,8 +1,9 @@
 """Readers of the per-layer metrics: `read(ctx) -> float | None`. A reader that
 finds nothing to read returns None and the harness leaves the metric out.
 
-ctx: {"records": the window's answered queries, each {"latency_ms",
-      "response"}; "counters": the pipeline's counter deltas over the window;
+ctx: {"records": the window's answered queries, each {"latency_ms", "pool",
+      "response"}; "templates": the template's name by pool index;
+      "counters": the pipeline's counter deltas over the window;
       "trace": trace_reduce.reduce() of the traced slice, or None;
       "solo": [{"template", "least_bytes", "busy_s", "latency_ms"}] from the
       solo replay; "peaks": this device kind's row of peaks.json}

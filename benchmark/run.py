@@ -11,8 +11,9 @@ result object; see benchmark/README.md.
 
 `--rehearse` (CPU sandbox) relaxes the platform check and cuts the rows; it
 prints `cpu` as its device and is never a measurement. `--control 1` also
-evaluates the controls (the reference at bfloat16; the reference with one
-segment left out) and reports how far each is from the reference.
+puts each control in the program's place (the reference at bfloat16; the
+reference with one segment left out) and reports its numbers and its own
+`correct`, by the same limits; each has to come out false.
 """
 
 import time
@@ -213,18 +214,25 @@ def open_chip(args, cell):
 
 
 def warm_up(loadgen, cell, pool, walks, url) -> int:
-    """Every query of the pool once alone, then the mix together for a few
-    seconds. Returns the kernel-cache miss count the window starts from."""
+    """Every query of the pool once alone (a literal can build a program of
+    its own: PERF.md, section 5), then the mix together for a few seconds.
+    Returns the kernel-cache miss count the window starts from; a program
+    first met inside the window fails the run (`compiles_in_window`)."""
     mix = cell["traffic"]
     patient = max(float(mix["timeout_s"]), 900.0)    # a cold cache compiles
     t0 = time.perf_counter()
     m0 = serve.kernel_cache_misses()
+    built_by = []
     for p in pool:
+        before = serve.kernel_cache_misses()
         rec = loadgen.ask({"cmd": "one", "url": url, "sql": p["sql"],
                            "timeout_s": patient})["record"]
         if not rec["ok"] or incomplete(rec["response"]):
             raise SystemExit(f"warm-up: {p['sql']} -> "
                              f"{rec['error'] or rec['response']}")
+        built = serve.kernel_cache_misses() - before
+        if built:
+            built_by.append(f"{p['template']}/{p['variant']}:{built}")
     m1 = serve.kernel_cache_misses()
     loadgen.ask({"cmd": "window", "url": url,
                  "pool": [p["sql"] for p in pool],
@@ -233,7 +241,8 @@ def warm_up(loadgen, cell, pool, walks, url) -> int:
                  "clients": int(mix["clients"]), "timeout_s": patient})
     m2 = serve.kernel_cache_misses()
     log(f"warm-up {time.perf_counter() - t0:.1f} s: {len(pool)} queries "
-        f"alone built {m1 - m0} executables, the mix together {m2 - m1} more")
+        f"alone built {m1 - m0} executables ({' '.join(built_by)}), the mix "
+        f"together {m2 - m1} more")
     return m2
 
 
@@ -282,15 +291,19 @@ def solo_replay(jax, args, loadgen, cell, pool, url, rows, solo_dir):
     return records, solo
 
 
-def control_numbers(controls, records, pool, want, sum_limit) -> dict:
-    """How far each control is from the reference, by the same comparison, and
-    the program's own widest sum gaps beside them."""
+def control_numbers(controls, records, pool, want, limits) -> dict:
+    """Each control put in the program's place and judged by the same
+    comparison and the same limits: its numbers and its own `correct`, which
+    has to come out false; and the program's widest sum gaps beside them."""
+    sum_limit = limits["sum_rel_gap_max"]
     out = {}
     for name, answers in controls.items():
         fake = [{"ok": True, "pool": q, "response": {
             "numServersQueried": 1, "numServersResponded": 1,
             "resultTable": {"rows": answers[q]}}} for q in range(len(pool))]
-        out[name] = judge(fake, pool, want, sum_limit)["numbers"]
+        numbers = judge(fake, pool, want, sum_limit)["numbers"]
+        out[name] = {"correct": all(numbers[k] <= limits[k] for k in numbers),
+                     "numbers": numbers}
     gaps = [reference.compare(pool[r["pool"]]["spec"],
                               r["response"]["resultTable"]["rows"],
                               want[r["pool"]], sum_limit)["sum_gap"]
@@ -426,6 +439,7 @@ def main(argv=None) -> int:
         # -- the metrics -------------------------------------------------------
         if args.trace:
             ctx = {"records": [r for r in window_records if r["ok"]],
+                   "templates": [p["template"] for p in pool],
                    "counters": counters, "trace": trace, "solo": solo,
                    "peaks": peaks}
             owed = cell["per_layer"]
@@ -457,7 +471,11 @@ def main(argv=None) -> int:
                                    "idle_gaps": trace["idle_gaps"]}
         if controls:
             result["control"] = control_numbers(controls, records, pool, want,
-                                                sum_limit)
+                                                limits)
+            for name, c in result["control"].items():
+                if isinstance(c, dict):
+                    log(f"control {name}: correct {c['correct']} "
+                        f"{c['numbers']}")
         result["checked"] = {k: {"value": numbers[k], "limit": limits[k]}
                              for k in numbers}
         for note in verdict["notes"]:
