@@ -2532,11 +2532,9 @@ def main():
             if dev_loaded_100k == 100_000 else None,
             "e2e_device_loaded_rows": dev_loaded_100k,
             "e2e_p50_device_1client_ms": dev_stats.get("soloP50Ms"),
-            "e2e_device_mean_batch": dev_stats.get("meanBatch", 0.0),
-            # per-stage pipeline attribution (queue wait vs device dispatch
-            # vs device fetch vs host decode): where the round-trip floor
-            # actually lands
-            "e2e_device_pipeline_stage_ms": dev_stats.get("stageMs"),
+            "e2e_device_mean_batch": round(
+                dev_stats.get("dispatched", 0)
+                / max(dev_stats.get("batches", 0), 1), 2),
             "e2e_device_launches": dev_stats.get("launches", 0),
             "e2e_device_dedupe_hits": dev_stats.get("dedupeHits", 0),
             "e2e_device_stacked_launches": dev_stats.get("stackedLaunches",
@@ -2548,8 +2546,9 @@ def main():
             "e2e_p50_device_4m_ms": round(e2e_dev_p50_4m, 3)
             if dev_loaded_4m == 4 * 1024 * 1024 else None,
             "e2e_device_4m_loaded_rows": dev_loaded_4m,
-            "e2e_device_4m_mean_batch": dev_stats_4m.get("meanBatch", 0.0),
-            "e2e_device_4m_pipeline_stage_ms": dev_stats_4m.get("stageMs"),
+            "e2e_device_4m_mean_batch": round(
+                dev_stats_4m.get("dispatched", 0)
+                / max(dev_stats_4m.get("batches", 0), 1), 2),
             "e2e_qps_cpu_4m": round(e2e_cpu_qps_4m, 1),
             "e2e_p50_cpu_4m_ms": round(e2e_cpu_p50_4m, 3),
             "numpy_single_thread_rows_per_sec": round(np_rows_per_sec, 1),

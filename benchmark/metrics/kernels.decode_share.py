@@ -1,0 +1,8 @@
+"""kernels.decode_share: what it reads is in the `.json` beside it.
+None where the program has no such field, span or scope (PR 26's parent)."""
+
+from benchmark.harness import program_trace as pt
+
+
+def read(ctx):
+    return pt.scope_share(ctx, pt.SCOPE_PREFIX + "decode")

@@ -660,8 +660,8 @@ class Broker:
         return dataclasses.replace(stmt, where=rw(stmt.where))
 
     def _handle_single(self, stmt, t0: float) -> ResultTable:
-        from ..utils.trace import current_trace, span
-        with span("compile"):
+        from ..utils.trace import current_depth, current_trace, span
+        with span("broker.compile"):
             stmt_ctx = compile_query(stmt)  # schema resolved below per physical table
         raw_table = stmt_ctx.table
         t_compile = time.perf_counter()
@@ -746,71 +746,74 @@ class Broker:
         tr = current_trace()
 
         def _traced(handle, server_id):
-            # scatter-pool threads share the request's trace (activate is per-thread)
+            # scatter-pool threads share the request's trace (activate is
+            # per-thread), nested where the dispatch happens: under the scatter
             if tr is None:
                 return handle
+            depth = current_depth()
 
             def call(*args):
-                with tr.activate(), span(f"server:{server_id}"):
+                with tr.activate(depth=depth), span(f"server:{server_id}"):
                     return handle(*args)
             return call
 
-        for table in physical:
-            tf_expr = _boundary_expr(boundary, table)
-            tf = to_sql(tf_expr) if tf_expr is not None else None
-            unroutable: List[str] = []
-            prune_counts: Dict[str, float] = {}
-            routing = self.routing.route_query(table, ctx, extra_filter=tf_expr,
-                                               uncovered=unroutable,
-                                               prune_stats=prune_counts)
-            _record_prune_stats(exec_stats, prune_counts)
-            uncovered_segments.extend(f"{table}:{s}" for s in sorted(unroutable))
-            missing: Dict[str, Set[str]] = {}  # segment -> servers that missed it
-            units: List[_DispatchUnit] = []
-            for server_id, segments in routing.items():
-                handle = self._servers.get(server_id)
-                if handle is None:
-                    # routed to a server whose handle was unregistered between
-                    # route_query and dispatch — its segments enter the retry
-                    # round like any other miss, never silently dropped
-                    for seg in segments:
-                        missing.setdefault(seg, set()).add(server_id)
-                    continue
-                fut = self._dispatch_partial(handle, server_id, _traced,
-                                             table, ctx, segments, tf)
-                units.append(_DispatchUnit(server_id, list(segments), fut))
-            q, f = self._gather_units(table, ctx, tf, _traced, units, partials,
-                                      exec_stats, missing, query_errors,
-                                      error_segments)
-            servers_queried += q
-            servers_failed += f
-            if missing:
-                # a replica mid segment-transition (commit adoption, move) can
-                # briefly serve without a segment it was routed — ONE retry
-                # round on the other replicas keeps results complete instead
-                # of silently short (counts must never regress mid-commit)
-                retry_results, retry_failed = self._retry_missing(
-                    table, ctx, missing, tf, _traced, exec_stats=exec_stats)
-                partials.extend(r for r, _ in retry_results)
-                for r, _ in retry_results:
-                    exec_stats.merge(r.stats)
-                servers_queried += len(retry_results) + retry_failed
-                servers_failed += retry_failed
-                # coverage audit: a segment can stay unserved even after the
-                # retry round (no eligible candidate, retry target crashed, or
-                # the retry partial's own served list omits it) — surface it
-                # as a partial result instead of silently returning short
-                uncovered = _uncovered_after_retry(missing, retry_results)
-                if query_errors and error_segments & uncovered:
-                    # a query-error server's segments failed on EVERY replica
-                    # tried: the error is deterministic, not replica-local —
-                    # propagate it instead of a misleading partial result
-                    raise query_errors[0]
-                uncovered_segments.extend(
-                    f"{table}:{s}" for s in sorted(uncovered))
+        with span("broker.scatter"):
+            for table in physical:
+                tf_expr = _boundary_expr(boundary, table)
+                tf = to_sql(tf_expr) if tf_expr is not None else None
+                unroutable: List[str] = []
+                prune_counts: Dict[str, float] = {}
+                routing = self.routing.route_query(table, ctx, extra_filter=tf_expr,
+                                                   uncovered=unroutable,
+                                                   prune_stats=prune_counts)
+                _record_prune_stats(exec_stats, prune_counts)
+                uncovered_segments.extend(f"{table}:{s}" for s in sorted(unroutable))
+                missing: Dict[str, Set[str]] = {}  # segment -> servers that missed it
+                units: List[_DispatchUnit] = []
+                for server_id, segments in routing.items():
+                    handle = self._servers.get(server_id)
+                    if handle is None:
+                        # routed to a server whose handle was unregistered between
+                        # route_query and dispatch — its segments enter the retry
+                        # round like any other miss, never silently dropped
+                        for seg in segments:
+                            missing.setdefault(seg, set()).add(server_id)
+                        continue
+                    fut = self._dispatch_partial(handle, server_id, _traced,
+                                                 table, ctx, segments, tf)
+                    units.append(_DispatchUnit(server_id, list(segments), fut))
+                q, f = self._gather_units(table, ctx, tf, _traced, units, partials,
+                                          exec_stats, missing, query_errors,
+                                          error_segments)
+                servers_queried += q
+                servers_failed += f
+                if missing:
+                    # a replica mid segment-transition (commit adoption, move) can
+                    # briefly serve without a segment it was routed — ONE retry
+                    # round on the other replicas keeps results complete instead
+                    # of silently short (counts must never regress mid-commit)
+                    retry_results, retry_failed = self._retry_missing(
+                        table, ctx, missing, tf, _traced, exec_stats=exec_stats)
+                    partials.extend(r for r, _ in retry_results)
+                    for r, _ in retry_results:
+                        exec_stats.merge(r.stats)
+                    servers_queried += len(retry_results) + retry_failed
+                    servers_failed += retry_failed
+                    # coverage audit: a segment can stay unserved even after the
+                    # retry round (no eligible candidate, retry target crashed, or
+                    # the retry partial's own served list omits it) — surface it
+                    # as a partial result instead of silently returning short
+                    uncovered = _uncovered_after_retry(missing, retry_results)
+                    if query_errors and error_segments & uncovered:
+                        # a query-error server's segments failed on EVERY replica
+                        # tried: the error is deterministic, not replica-local —
+                        # propagate it instead of a misleading partial result
+                        raise query_errors[0]
+                    uncovered_segments.extend(
+                        f"{table}:{s}" for s in sorted(uncovered))
 
         t_scatter = time.perf_counter()
-        with span("reduce"):
+        with span("broker.reduce"):
             merged = merge_segment_results(partials, aggs)
             if not partials:
                 merged.kind = ("groups" if group_exprs else

@@ -40,13 +40,14 @@ import logging
 import queue
 import threading
 import time
-from collections import deque
 from concurrent.futures import CancelledError, Future, InvalidStateError
 from concurrent.futures import TimeoutError as FutureTimeoutError
 from typing import Dict, List, Optional, Sequence
 
+from ..query import stats as qstats
 from ..utils.faults import FaultInjected, fault_point
 from ..utils.metrics import get_registry
+from ..utils.trace import stage
 
 
 log = logging.getLogger(__name__)
@@ -62,7 +63,22 @@ DEVICE_FALLBACK = _Sentinel()
 
 #: pipeline stages timed per drain (ms); exported under
 #: pinot_server_device_pipeline_<stage>_ms via /metrics
-_STAGES = ("queue_wait", "dispatch", "fetch", "decode")
+_STAGES = ("queue_wait", "dispatch", "prepare", "launch", "handoff", "fetch",
+           "decode")
+
+#: what the kernel cache and the first-call fence record on the dispatcher
+#: thread, folded from a scratch record into the items a launch answers
+_LAUNCH_KEYS = (qstats.COMPILE_MS, qstats.COMPILE_CACHE_MISSES,
+                qstats.COMPILE_CACHE_HITS, qstats.DEVICE_LAUNCHES)
+
+#: the pipeline's per-query phases in the order a query passes them: the
+#: item.stats key of each and the request-Trace span `execute_partial` rebuilds
+_PHASES = ((qstats.QUEUE_WAIT_MS, "pipeline:queue_wait"),
+           (qstats.DEVICE_PREPARE_MS, "pipeline:prepare"),
+           (qstats.DEVICE_LAUNCH_MS, "pipeline:launch"),
+           (qstats.DEVICE_HANDOFF_MS, "pipeline:handoff"),
+           (qstats.DEVICE_FETCH_MS, "pipeline:fetch"),
+           (qstats.DEVICE_DECODE_MS, "pipeline:decode"))
 
 
 def _resolve(future: Future, value, exc: Optional[BaseException] = None) -> None:
@@ -80,12 +96,27 @@ def _resolve(future: Future, value, exc: Optional[BaseException] = None) -> None
         pass
 
 
-class _Item:
-    __slots__ = ("ctx", "segments", "future", "t_enqueue", "stats")
+def _fold(stats: dict, recorded: dict) -> None:
+    """Add what a scratch record caught on the dispatcher thread to an item."""
+    for k in _LAUNCH_KEYS:
+        v = recorded.get(k)
+        if v:
+            stats[k] = round(stats.get(k, 0) + v, 3)
 
-    def __init__(self, ctx, segments):
+
+def _items(launches):
+    return (item for _, _, groups in launches for group in groups
+            for item, _ in group)
+
+
+class _Item:
+    __slots__ = ("ctx", "segments", "future", "t_enqueue", "stats",
+                 "trace_id")
+
+    def __init__(self, ctx, segments, trace_id: str = ""):
         self.ctx = ctx
         self.segments = segments
+        self.trace_id = trace_id
         self.future: Future = Future()
         self.t_enqueue = time.perf_counter()
         # per-item launch attribution (queue wait, dedupe/stack flags): the
@@ -139,10 +170,14 @@ class DeviceQueryPipeline:
         self.dedupe_hits = 0
         self.stacked_launches = 0
         self.fused_launches = 0
-        # per-stage wall times: bounded deques back stats() percentiles;
-        # the process registry histograms back /metrics
-        self._stage_ms: Dict[str, deque] = {s: deque(maxlen=512)
-                                            for s in _STAGES}
+        # how the batches form: drains that held one live query, why each
+        # drain closed (`_drain`), and hand-offs that met a full fetch queue
+        self.batches_of_one = 0
+        self.drains_closed_idle = 0
+        self.drains_closed_full = 0
+        self.drains_closed_burst = 0
+        self.handoff_blocked = 0
+        # per-stage wall times: the process registry histograms back /metrics
         self._hists = {s: get_registry().histogram(
             f"pinot_server_device_pipeline_{s}_ms") for s in _STAGES}
         self._thread = threading.Thread(target=self._loop, daemon=True,
@@ -170,16 +205,16 @@ class DeviceQueryPipeline:
         log.exception("device path raised in %s; the host path answers",
                       where)
 
-    def _observe(self, stage: str, ms: float) -> None:
-        self._stage_ms[stage].append(ms)
-        self._hists[stage].observe(ms)
+    def _observe(self, stage_name: str, ms: float) -> None:
+        self._hists[stage_name].observe(ms)
 
     # -- caller side ------------------------------------------------------
     def execute_partial(self, ctx, segments: Sequence):
         """Submit and wait; returns a SegmentResult partial or DEVICE_FALLBACK."""
         from ..utils.trace import current_depth, current_trace
-        item = _Item(ctx, list(segments))
         tr = current_trace()
+        item = _Item(ctx, list(segments),
+                     trace_id=tr.trace_id if tr is not None else "")
         submit_ms = tr.now_ms() if tr is not None else 0.0
         # deadline propagation: never wait on the device past the broker's
         # stamped deadline — timing out here cancels the item, and the
@@ -196,16 +231,18 @@ class DeviceQueryPipeline:
             result = item.future.result(timeout=timeout_s)
             if tr is not None and result is not DEVICE_FALLBACK:
                 # the pipeline threads can't see this query's trace; rebuild
-                # the device-side phases from the item's launch attribution —
-                # queue wait starts at submit, the batched fetch ends now
+                # its phases from the item's attribution, laid end to end from
+                # the submit (the time between them, other queries' prepares
+                # and decodes, is not shown; the profiler's `pinot:pipeline.*`
+                # spans are the true timeline)
                 depth = current_depth()
                 s = getattr(result, "stats", None) or {}
-                wait_ms = float(s.get("queueWaitMs") or 0.0)
-                tr.record("pipeline:queue_wait", submit_ms, wait_ms,
-                          depth=depth)
-                fetch_ms = float(s.get("deviceFetchMs") or 0.0)
-                tr.record("pipeline:fetch", tr.now_ms() - fetch_ms, fetch_ms,
-                          depth=depth)
+                at_ms = submit_ms
+                for key, name in _PHASES:
+                    ms = float(s.get(key) or 0.0)
+                    if ms or key in s:
+                        tr.record(name, at_ms, ms, depth=depth)
+                    at_ms += ms
             return result
         except FutureTimeoutError:
             # cancel so the dispatcher/fetcher SKIP the stale item instead of
@@ -232,13 +269,11 @@ class DeviceQueryPipeline:
             _resolve(item.future, DEVICE_FALLBACK)
         while True:
             try:
-                entry = self._fetchq.get_nowait()
+                entry, _ = self._fetchq.get_nowait()
             except queue.Empty:
                 break
-            for _, _, groups in entry:
-                for group in groups:
-                    for item, _ in group:
-                        _resolve(item.future, DEVICE_FALLBACK)
+            for item in _items(entry):
+                _resolve(item.future, DEVICE_FALLBACK)
 
     # -- dispatcher thread ------------------------------------------------
     def _drain(self) -> Optional[list]:
@@ -246,26 +281,45 @@ class DeviceQueryPipeline:
         fetch is still in flight — whatever arrives before it completes.
         Dispatching earlier than that wins nothing (the fetcher is busy for
         a full host round trip anyway) and would shatter the batch into
-        singleton fetches, each paying its own round trip."""
-        try:
-            first = self._q.get(timeout=0.05)
-        except queue.Empty:
-            return None
+        singleton fetches, each paying its own round trip.
+
+        Why the drain closed is counted: `drainsClosedFull` (`max_batch`),
+        `drainsClosedBurst` (the queue was empty and nothing in flight, and the
+        burst window had held the drain open until it ran out),
+        `drainsClosedIdle` (queue empty and no fetch in flight)."""
+        with stage("pipeline.wait"):
+            try:
+                first = self._q.get(timeout=0.05)
+            except queue.Empty:
+                return None
         batch = [first]
         deadline = (time.perf_counter() + self.burst_window_s
                     if self.burst_window_s > 0 else None)
-        while len(batch) < self.max_batch:
-            try:
-                batch.append(self._q.get_nowait())
-            except queue.Empty:
-                busy = self._fetch_busy.is_set() or not self._fetchq.empty()
-                if not busy and (deadline is None
-                                 or time.perf_counter() >= deadline):
+        held_by_window = False
+        with stage("pipeline.gather") as gather:
+            while True:
+                if len(batch) >= self.max_batch:
+                    self.drains_closed_full += 1
                     break
+                try:
+                    batch.append(self._q.get_nowait())
+                    continue
+                except queue.Empty:
+                    pass
+                busy = self._fetch_busy.is_set() or not self._fetchq.empty()
+                if not busy:
+                    if deadline is None or time.perf_counter() >= deadline:
+                        if held_by_window:
+                            self.drains_closed_burst += 1
+                        else:
+                            self.drains_closed_idle += 1
+                        break
+                    held_by_window = True
                 try:
                     batch.append(self._q.get(timeout=0.005))
                 except queue.Empty:
                     continue
+            gather.note(n=len(batch))
         return batch
 
     def _loop(self) -> None:
@@ -298,26 +352,34 @@ class DeviceQueryPipeline:
                 entry, n_live = self._dispatch_legacy(batch, t0)
             if not entry:
                 continue
-            self._observe("dispatch", (time.perf_counter() - t0) * 1000)
+            t_launched = time.perf_counter()
+            self._observe("dispatch", (t_launched - t0) * 1000)
             self.batches += 1
+            self.batches_of_one += n_live == 1
             self.dispatched += n_live
             self.launches += len(entry)
+            for item in _items(entry):
+                item.stats[qstats.DEVICE_BATCH_SIZE] = n_live
             handed_off = False
-            while not self._stop.is_set():
-                try:
-                    self._fetchq.put(entry, timeout=0.2)
-                    handed_off = True
-                    break
-                except queue.Full:
-                    continue  # fetcher backlogged: backpressure dispatch
+            try:
+                self._fetchq.put_nowait((entry, t_launched))
+                handed_off = True
+            except queue.Full:
+                self.handoff_blocked += 1
+                with stage("pipeline.handoff"):
+                    while not handed_off and not self._stop.is_set():
+                        try:
+                            # fetcher backlogged: backpressure dispatch
+                            self._fetchq.put((entry, t_launched), timeout=0.2)
+                            handed_off = True
+                        except queue.Full:
+                            continue
             if not handed_off:
                 # stopping with the fetch queue full: these futures would
                 # otherwise dangle past stop()'s drain for the full submit
                 # timeout — resolve them to the host path now
-                for _, _, groups in entry:
-                    for group in groups:
-                        for item, _ in group:
-                            _resolve(item.future, DEVICE_FALLBACK)
+                for item in _items(entry):
+                    _resolve(item.future, DEVICE_FALLBACK)
 
     def _dispatch_grouped(self, batch, t0):
         """Prepare every live item, collapse identical dispatches, launch the
@@ -335,9 +397,16 @@ class DeviceQueryPipeline:
                 continue
             wait_ms = (t0 - item.t_enqueue) * 1000
             self._observe("queue_wait", wait_ms)
-            item.stats["queueWaitMs"] = round(wait_ms, 3)
+            item.stats[qstats.QUEUE_WAIT_MS] = round(wait_ms, 3)
+            # this thread serves many queries: what the kernel cache records
+            # while it plans THIS one goes to a scratch record, then the item
+            scratch = qstats.ExecutionStats()
             try:
-                p = self.mesh_exec.prepare_partial(item.ctx, item.segments)
+                with qstats.activate(scratch), \
+                        stage("pipeline.prepare",
+                              trace_id=item.trace_id) as prep:
+                    p = self.mesh_exec.prepare_partial(item.ctx,
+                                                       item.segments)
             except Exception:
                 # planning RAISED on the device path: the host path still
                 # answers the query, but as a counted, logged device error —
@@ -345,6 +414,9 @@ class DeviceQueryPipeline:
                 self.record_error("prepare_partial")
                 _resolve(item.future, DEVICE_FALLBACK)
                 continue
+            self._observe("prepare", prep.ms)
+            item.stats[qstats.DEVICE_PREPARE_MS] = round(prep.ms, 3)
+            _fold(item.stats, scratch.counters)
             if p is None:
                 self.fallbacks += 1
                 _resolve(item.future, DEVICE_FALLBACK)
@@ -363,8 +435,11 @@ class DeviceQueryPipeline:
             rep_groups.append([(item, p.decode)])
         if not reps:
             return [], 0
+        n_live = sum(len(g) for g in rep_groups)
         try:
-            launches = self.mesh_exec.dispatch_prepared(reps)
+            with stage("pipeline.launch", batch=n_live) as launch:
+                launches = self.mesh_exec.dispatch_prepared(reps)
+                launch.note(launches=len(launches))
         except Exception:
             # a grouped launch failing downgrades the whole drain to host
             # execution (availability over the fast path) — logged and
@@ -374,9 +449,10 @@ class DeviceQueryPipeline:
                 for item, _ in group:
                     _resolve(item.future, DEVICE_FALLBACK)
             return [], 0
-        self.stacked_launches += sum(1 for _, _, idxs in launches
+        self._observe("launch", launch.ms)
+        self.stacked_launches += sum(1 for _, _, idxs, _ in launches
                                      if len(idxs) > 1)
-        for _, _, idxs in launches:
+        for _, _, idxs, recorded in launches:
             stacked = len(idxs) > 1
             fused = any(getattr(getattr(reps[i], "spec", None),
                                 "fused_cols", ()) for i in idxs)
@@ -384,14 +460,15 @@ class DeviceQueryPipeline:
                 self.fused_launches += 1
             for i in idxs:
                 for item, _ in rep_groups[i]:
-                    item.stats["deviceLaunches"] = 1
+                    item.stats[qstats.DEVICE_LAUNCH_MS] = round(launch.ms, 3)
+                    _fold(item.stats, recorded)
                     if fused:
                         item.stats["fusedLaunches"] = 1
                     if stacked:
                         item.stats["stackedLaunches"] = 1
         entry = [(outs_dev, finish, [rep_groups[i] for i in idxs])
-                 for outs_dev, finish, idxs in launches]
-        return entry, sum(len(g) for g in rep_groups)
+                 for outs_dev, finish, idxs, _ in launches]
+        return entry, n_live
 
     def _dispatch_legacy(self, batch, t0):
         """One launch per item for executors without the prepared API (fakes,
@@ -403,9 +480,12 @@ class DeviceQueryPipeline:
                 continue
             wait_ms = (t0 - item.t_enqueue) * 1000
             self._observe("queue_wait", wait_ms)
-            item.stats["queueWaitMs"] = round(wait_ms, 3)
+            item.stats[qstats.QUEUE_WAIT_MS] = round(wait_ms, 3)
             try:
-                dp = self.mesh_exec.dispatch_partial(item.ctx, item.segments)
+                with stage("pipeline.launch", batch=1,
+                           trace_id=item.trace_id) as launch:
+                    dp = self.mesh_exec.dispatch_partial(item.ctx,
+                                                         item.segments)
             except Exception:
                 self.record_error("dispatch_partial")
                 _resolve(item.future, DEVICE_FALLBACK)
@@ -414,7 +494,9 @@ class DeviceQueryPipeline:
                 self.fallbacks += 1
                 _resolve(item.future, DEVICE_FALLBACK)
                 continue
-            item.stats["deviceLaunches"] = 1
+            self._observe("launch", launch.ms)
+            item.stats[qstats.DEVICE_LAUNCH_MS] = round(launch.ms, 3)
+            item.stats[qstats.DEVICE_LAUNCHES] = 1
             entry.append((dp[0], (lambda host: [host]),
                           [[(item, dp[1])]]))
         return entry, len(entry)
@@ -425,10 +507,12 @@ class DeviceQueryPipeline:
         fetch = getattr(self.mesh_exec, "fetch", None) or jax.device_get
         while not self._stop.is_set():
             try:
-                entry = self._fetchq.get(timeout=0.05)
+                entry, t_launched = self._fetchq.get(timeout=0.05)
             except queue.Empty:
                 continue
             self._fetch_busy.set()
+            handoff_ms = (time.perf_counter() - t_launched) * 1000
+            self._observe("handoff", handoff_ms)
             try:
                 # launches whose every caller timed out are dead weight:
                 # dropping them BEFORE the host sync keeps a storm of
@@ -438,28 +522,31 @@ class DeviceQueryPipeline:
                                for group in L[2] for item, _ in group)]
                 if not live:
                     continue
-                t0 = time.perf_counter()
+                n_items = sum(len(group) for L in live for group in L[2])
                 try:
                     # ONE host sync for the whole dispatched batch
-                    fetched = fetch([L[0] for L in live])
+                    with stage("pipeline.fetch", batch=n_items,
+                               launches=len(live)) as sync:
+                        fetched = fetch([L[0] for L in live])
                 except Exception as e:
-                    for _, _, groups in live:
-                        for group in groups:
-                            for item, _ in group:
-                                _resolve(item.future, None, exc=e)
+                    for item in _items(live):
+                        _resolve(item.future, None, exc=e)
                     continue
-                fetch_ms = (time.perf_counter() - t0) * 1000
-                self._observe("fetch", fetch_ms)
-                t1 = time.perf_counter()
-                for (_, finish, groups), host in zip(live, fetched):
-                    self._decode_launch(finish, groups, host,
-                                        fetch_ms=fetch_ms)
-                self._observe("decode", (time.perf_counter() - t1) * 1000)
+                self._observe("fetch", sync.ms)
+                waited = {qstats.DEVICE_HANDOFF_MS: round(handoff_ms, 3),
+                          qstats.DEVICE_FETCH_MS: round(sync.ms, 3)}
+                with stage("pipeline.decode", batch=n_items) as dec:
+                    for (_, finish, groups), host in zip(live, fetched):
+                        self._decode_launch(finish, groups, host, waited)
+                self._observe("decode", dec.ms)
             finally:
                 self._fetch_busy.clear()
 
-    def _decode_launch(self, finish, groups, host,
-                       fetch_ms: float = 0.0) -> None:
+    def _decode_launch(self, finish, groups, host, waited: dict) -> None:
+        """Unpack one launch and resolve the queries it answers. `waited` is
+        what every item of the batch waited for before this: the hand-off and
+        the batched host sync (wall, shared)."""
+        t0 = time.perf_counter()
         try:
             outs_list = finish(host)
         except Exception as e:
@@ -484,39 +571,28 @@ class DeviceQueryPipeline:
                     # attach this item's launch attribution to its partial
                     # BEFORE resolving: the query thread folds it into the
                     # per-query ExecutionStats (the fetcher thread has no
-                    # query-scoped thread-locals to publish into). fetch_ms
-                    # is the batched host sync this result waited on (wall,
-                    # shared by every item in the batch)
-                    s = dict(item.stats)
-                    s["deviceFetchMs"] = round(fetch_ms, 3)
+                    # query-scoped thread-locals to publish into).
+                    # deviceDecodeMs runs from the start of its launch's
+                    # decode to this answer
+                    s = dict(item.stats, **waited)
+                    s[qstats.DEVICE_DECODE_MS] = round(
+                        (time.perf_counter() - t0) * 1000, 3)
                     s.update(r.stats or {})
                     r.stats = s
                 _resolve(item.future, r)
 
     def stats(self) -> dict:
-        out = {"batches": self.batches, "dispatched": self.dispatched,
-               "fallbacks": self.fallbacks,
-               "deviceErrors": self.device_errors, "timeouts": self.timeouts,
-               "launches": self.launches, "dedupeHits": self.dedupe_hits,
-               "stackedLaunches": self.stacked_launches,
-               "fusedLaunches": self.fused_launches,
-               "meanBatch": round(self.dispatched / self.batches, 2)
-               if self.batches else 0.0}
-        out["stageMs"] = {s: _summarize(self._stage_ms[s]) for s in _STAGES}
-        return out
-
-
-def _summarize(samples: deque) -> dict:
-    vals = sorted(samples)
-    if not vals:
-        return {"count": 0, "meanMs": 0.0, "p50Ms": 0.0, "p95Ms": 0.0,
-                "maxMs": 0.0}
-    n = len(vals)
-    return {"count": n,
-            "meanMs": round(sum(vals) / n, 3),
-            "p50Ms": round(vals[min(n - 1, int(0.5 * n))], 3),
-            "p95Ms": round(vals[min(n - 1, int(0.95 * n))], 3),
-            "maxMs": round(vals[-1], 3)}
+        return {"batches": self.batches, "dispatched": self.dispatched,
+                "fallbacks": self.fallbacks,
+                "deviceErrors": self.device_errors, "timeouts": self.timeouts,
+                "launches": self.launches, "dedupeHits": self.dedupe_hits,
+                "stackedLaunches": self.stacked_launches,
+                "fusedLaunches": self.fused_launches,
+                "batchesOfOne": self.batches_of_one,
+                "drainsClosedIdle": self.drains_closed_idle,
+                "drainsClosedFull": self.drains_closed_full,
+                "drainsClosedBurst": self.drains_closed_burst,
+                "handoffBlocked": self.handoff_blocked}
 
 
 def pipeline_from_config(cfg) -> Optional[DeviceQueryPipeline]:

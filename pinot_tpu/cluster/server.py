@@ -687,9 +687,14 @@ class ServerNode:
         # per-query telemetry record for this server-level partial: executor /
         # kernel hooks on THIS thread publish into it; pipeline-attributed
         # launch stats arrive attached to the device partial and fold in after
-        with qstats.collect_stats() as st:
+        import time as _t
+
+        from ..utils.trace import span
+        t0 = _t.perf_counter()
+        with qstats.collect_stats() as st, span("server.execute"):
             merged = self._run_partial(table, ctx, segment_names)
         st.merge(merged.stats)
+        st.set_max(qstats.SERVER_TIME_MS, (_t.perf_counter() - t0) * 1000)
         merged.stats = st.to_wire()
         return merged
 

@@ -178,11 +178,9 @@ def save_cached_caps(caps: KernelCaps, path: Optional[str] = None,
 
 # -- measured HBM bandwidth (the shared roofline denominator) ----------------
 # bench.py's platform calibration measures the streaming scan bandwidth the
-# chip actually sustains and persists it here; `kernels.fetch_outputs` and the
-# bench lanes then divide by the SAME figure, so a `rooflinePct`/`*_pct_of_
-# measured_roofline` above ~100 is a bug, not a denominator mismatch (the
-# BENCH_r05 464.8% report came from bench using a measured figure while the
-# stats plane divided by nominal). Stored as a sibling top-level key in the
+# chip actually sustains and persists it here; the bench lanes divide by it
+# (`kernels.roofline_hbm_gbps`), so a `*_pct_of_measured_roofline` above ~100
+# is a bug, not a denominator mismatch. Stored as a sibling top-level key in the
 # caps cache file (`<platform>#hbm_gbps`) so caps saves never clobber it.
 
 def _hbm_key(key: Optional[str] = None) -> str:

@@ -38,7 +38,6 @@ the measured foundation for future hand-scheduled integration.
 from __future__ import annotations
 
 import os
-import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional, Tuple
@@ -213,11 +212,7 @@ def _block_tree(out):
     return out
 
 
-# -- per-kernel cost profiles (XLA cost_analysis at compile time) ------------
-
-#: pending modeled bytes since the last fetch, per dispatch thread: launches
-#: accumulate, `fetch_outputs` drains into an achieved-vs-roofline pct
-_pending_cost = threading.local()
+# -- the HBM roofline denominator bench.py's lanes divide by ------------------
 
 _NOMINAL_HBM_GBPS: Optional[float] = None
 _ROOFLINE_GBPS: Optional[float] = None
@@ -239,10 +234,8 @@ def _nominal_hbm_gbps() -> float:
 
 
 def roofline_hbm_gbps() -> float:
-    """THE roofline denominator — shared by `rooflinePct` here and every
-    `*_pct_of_measured_roofline` figure bench.py publishes, so the two can
-    never disagree again (the BENCH_r05 464.8% report was exactly such a
-    denominator mismatch). Resolution: PINOT_TPU_HBM_GBPS env override, else
+    """THE roofline denominator of every `*_pct_of_measured_roofline` figure
+    bench.py publishes. Resolution: PINOT_TPU_HBM_GBPS env override, else
     the bandwidth bench.py's platform calibration measured and persisted via
     `calibrate.save_measured_hbm_gbps`, else the nominal constant."""
     global _ROOFLINE_GBPS
@@ -262,58 +255,13 @@ def invalidate_roofline_cache() -> None:
     _NOMINAL_HBM_GBPS = None
 
 
-def _tree_device_nbytes(tree) -> int:
-    """Sum of leaf nbytes WITHOUT materializing (no np.asarray — that would
-    sync); device and host leaves both carry `.nbytes`."""
-    total = 0
-    for leaf in jax.tree_util.tree_leaves(tree):
-        total += int(getattr(leaf, "nbytes", 0) or 0)
-    return total
-
-
-def _kernel_cost(fn, args, kwargs) -> Dict[str, float]:
-    """One compiled executable's per-launch cost profile. Primary source is
-    XLA's `cost_analysis()` via the AOT lowering path (flops + bytes
-    accessed); when the backend exposes neither (CPU builds vary), fall back
-    to a deterministic input-bytes estimate with zero modeled flops — still
-    monotone in problem size, so roofline percentages stay comparable."""
-    flops = 0.0
-    nbytes = 0.0
-    try:
-        analysis = fn.lower(*args, **kwargs).compile().cost_analysis()
-        if isinstance(analysis, (list, tuple)):
-            analysis = analysis[0] if analysis else {}
-        if isinstance(analysis, dict):
-            flops = float(analysis.get("flops") or 0.0)
-            nbytes = float(analysis.get("bytes accessed") or 0.0)
-    # graftcheck: ignore[exception-hygiene] -- cost_analysis() is a
-    # best-effort XLA introspection API (shape varies by backend, may raise
-    # on donated/stablehlo paths); the input-bytes fallback below IS the
-    # observation of this failure
-    except Exception:
-        pass
-    if nbytes <= 0.0:
-        nbytes = float(_tree_device_nbytes((args, kwargs)))
-    return {"flops": max(flops, 0.0), "bytes": max(nbytes, 0.0)}
-
-
-def _account_cost(cost: Optional[Dict[str, float]]) -> None:
-    """Fold one launch's modeled cost into the active per-query stats and the
-    process-lifetime counters."""
-    if not cost:
-        return
-    qstats.record(qstats.DEVICE_FLOPS, cost["flops"])
-    qstats.record(qstats.DEVICE_BYTES_ACCESSED, cost["bytes"])
-    _pending_cost.nbytes = getattr(_pending_cost, "nbytes", 0.0) + cost["bytes"]
-
-
 def _fence_first_call(fn):
     """jax.jit is LAZY — trace + compile happen at the first invocation. Fence
     that call with block_until_ready so its wall time (trace + compile + first
     run) lands in the compile histogram / per-query `compileMs` instead of
     silently inflating whichever query hits the cold cache; every invocation
-    counts one device launch and its modeled cost-analysis flops/bytes."""
-    state: Dict[str, Any] = {"cold": True, "cost": None}
+    counts one device launch."""
+    state: Dict[str, Any] = {"cold": True}
 
     def call(*args, **kwargs):
         qstats.record(qstats.DEVICE_LAUNCHES)
@@ -325,10 +273,7 @@ def _fence_first_call(fn):
             ms = (time.perf_counter() - t0) * 1000
             get_registry().histogram("pinot_kernel_compile_ms").observe(ms)
             qstats.record(qstats.COMPILE_MS, ms)
-            state["cost"] = _kernel_cost(fn, args, kwargs)
-            _account_cost(state["cost"])
             return out
-        _account_cost(state["cost"])
         return fn(*args, **kwargs)
 
     call.__wrapped__ = fn  # the jitted callable (AOT lowering in tests)
@@ -364,17 +309,6 @@ def fetch_outputs(outs_dev):
     fetched = tree_bytes(out)
     qstats.record(qstats.BYTES_FETCHED, fetched)
     get_ledger().note_transient(fetched)
-    # drain the modeled bytes the launches since the last fetch accumulated:
-    # achieved GB/s over this fetch window vs the MEASURED HBM roofline
-    # (the same calibrated figure bench.py divides by)
-    pending = getattr(_pending_cost, "nbytes", 0.0)
-    if pending > 0.0:
-        _pending_cost.nbytes = 0.0
-        if ms > 0.0:
-            achieved_gbps = pending / (ms * 1e6)
-            qstats.record_max(
-                qstats.ROOFLINE_PCT,
-                min(100.0, 100.0 * achieved_gbps / roofline_hbm_gbps()))
     return out
 
 
@@ -453,16 +387,18 @@ def _fused_env(spec: KernelSpec, ids, vals, iscal):
     if not spec.fused_cols:
         return vals
     env = dict(vals)
-    for col, form in spec.fused_cols:
-        if form == "dict":
-            lut = vals[col]
-            idx = ids[col]
-            if lut.ndim == 2 and idx.ndim == 2:
-                env[col] = jnp.take_along_axis(lut, idx, axis=1)
-            else:
-                env[col] = lut[idx]
-        else:  # "for": narrow unsigned deltas + scalar-stream base
-            env[col] = vals[col].astype(jnp.int32) + iscal[spec.for_offset[col]]
+    with jax.named_scope("pinot.decode"):
+        for col, form in spec.fused_cols:
+            if form == "dict":
+                lut = vals[col]
+                idx = ids[col]
+                if lut.ndim == 2 and idx.ndim == 2:
+                    env[col] = jnp.take_along_axis(lut, idx, axis=1)
+                else:
+                    env[col] = lut[idx]
+            else:  # "for": narrow unsigned deltas + scalar-stream base
+                env[col] = (vals[col].astype(jnp.int32)
+                            + iscal[spec.for_offset[col]])
     return env
 
 
@@ -552,12 +488,14 @@ def _make_mask_fn(spec: KernelSpec):
                 bitmaps=()):
         if spec.filter.is_match_all:
             return valid
-        if word_fn is not None:
-            # every leaf is a bitmap leaf: the tree evaluates as fused bitwise
-            # ops over packed words, one unpack for the row mask at the end
-            return _unpack_words(word_fn(bitmaps) & _pack_valid(valid))
-        env = (ids, vals, luts, iscal, fscal, nulls, docsets, bitmaps)
-        return tree_mask(spec.filter.tree, env, valid) & valid
+        with jax.named_scope("pinot.filter"):
+            if word_fn is not None:
+                # every leaf is a bitmap leaf: the tree evaluates as fused
+                # bitwise ops over packed words, one unpack for the row mask
+                # at the end
+                return _unpack_words(word_fn(bitmaps) & _pack_valid(valid))
+            env = (ids, vals, luts, iscal, fscal, nulls, docsets, bitmaps)
+            return tree_mask(spec.filter.tree, env, valid) & valid
 
     return mask_fn
 
@@ -740,20 +678,25 @@ def _grouped_sorted(key: jnp.ndarray, nseg: int, value_rows, block: int = 4096):
     residual cardinality makes even the rank-partitioned matmul's per-key
     decode expensive. Returns [int32 counts[nseg], f32 sums[nseg]...].
     """
-    key_s, vals_s, pad = _sort_by_key(key, nseg, value_rows, block)
+    with jax.named_scope("pinot.groupby.sorted.sort"):
+        key_s, vals_s, pad = _sort_by_key(key, nseg, value_rows, block)
     n = key_s.size
-    left, counts = _counts_from_sorted(key_s, nseg, pad)
+    with jax.named_scope("pinot.groupby.sorted.trim"):
+        left, counts = _counts_from_sorted(key_s, nseg, pad)
     outs = [counts]
     if not vals_s:
         return outs
-    head = jnp.concatenate([jnp.ones((1,), bool), key_s[1:] != key_s[:-1]])
-    v = jnp.stack(vals_s)  # [R, n]
-    flags = jnp.broadcast_to(head[None, :], v.shape)
-    _, scan = jax.lax.associative_scan(_seg_sum_op, (flags, v), axis=1)
-    end = jnp.clip(left[1:] - 1, 0, n - 1)  # last row of each key's run
-    occ = counts > 0
-    for r in range(v.shape[0]):
-        outs.append(jnp.where(occ, scan[r][end], 0.0))
+    with jax.named_scope("pinot.groupby.sorted.scan"):
+        head = jnp.concatenate([jnp.ones((1,), bool),
+                                key_s[1:] != key_s[:-1]])
+        v = jnp.stack(vals_s)  # [R, n]
+        flags = jnp.broadcast_to(head[None, :], v.shape)
+        _, scan = jax.lax.associative_scan(_seg_sum_op, (flags, v), axis=1)
+    with jax.named_scope("pinot.groupby.sorted.trim"):
+        end = jnp.clip(left[1:] - 1, 0, n - 1)  # last row of each key's run
+        occ = counts > 0
+        for r in range(v.shape[0]):
+            outs.append(jnp.where(occ, scan[r][end], 0.0))
     return outs
 
 
@@ -779,65 +722,72 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
     gathers. Value sums use the 3-part bf16 split (full f32 precision) with
     f32 accumulation. Returns [int32 counts[nseg], f32 sums[nseg]...].
     """
-    key_s, vals_s, pad = _sort_by_key(key, nseg, value_rows, block)
+    with jax.named_scope("pinot.groupby.partitioned.sort"):
+        key_s, vals_s, pad = _sort_by_key(key, nseg, value_rows, block)
     n = key_s.size
     nb = n // block
-    left, counts = _counts_from_sorted(key_s, nseg, pad)
+    with jax.named_scope("pinot.groupby.partitioned.trim"):
+        left, counts = _counts_from_sorted(key_s, nseg, pad)
     outs = [counts]
     if not vals_s:
         return outs
-    head = jnp.concatenate([jnp.ones((1,), bool), key_s[1:] != key_s[:-1]])
-    rank = jnp.cumsum(head.astype(jnp.int32)) - 1           # nondecreasing
-    rank_start = rank.reshape(nb, block)[:, 0]              # [nb]
-    j = rank.reshape(nb, block) - rank_start[:, None]       # local id < block
-    bf = jnp.bfloat16
-    oh_hi = jax.nn.one_hot(j // 64, block // 64, dtype=bf)  # [nb, block, B/64]
-    oh_lo = jax.nn.one_hot(j % 64, 64, dtype=bf)            # [nb, block, 64]
-    dot = lambda a, b: jax.lax.dot_general(                 # noqa: E731
-        a, b, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    local = []
-    for v in vals_s:
-        s = None
-        for part in _bf16_parts(v.reshape(nb, block)):
-            d = dot(oh_hi, part[:, :, None] * oh_lo)        # [nb, B/64, 64]
-            s = d if s is None else s + d
-        local.append(s.reshape(nb, block))                  # sums per (slab, j)
-    # stitch slab-spanning groups: a group continuing into slab b sits at
-    # local id 0 there, so a segmented scan over local[:, 0] (heads where
-    # rank_start changes) accumulates each continuation chain
-    heads_b = jnp.concatenate([jnp.ones((1,), bool),
-                               rank_start[1:] != rank_start[:-1]])
-    slab0 = jnp.stack([l[:, 0] for l in local])             # [R, nb]
-    flags = jnp.broadcast_to(heads_b[None, :], slab0.shape)
-    _, chain = jax.lax.associative_scan(_seg_sum_op, (flags, slab0), axis=1)
+    with jax.named_scope("pinot.groupby.partitioned.scan"):
+        head = jnp.concatenate([jnp.ones((1,), bool),
+                                key_s[1:] != key_s[:-1]])
+        rank = jnp.cumsum(head.astype(jnp.int32)) - 1       # nondecreasing
+        rank_start = rank.reshape(nb, block)[:, 0]          # [nb]
+        j = rank.reshape(nb, block) - rank_start[:, None]   # local id < block
+        bf = jnp.bfloat16
+        oh_hi = jax.nn.one_hot(j // 64, block // 64, dtype=bf)  # [nb, block, B/64]
+        oh_lo = jax.nn.one_hot(j % 64, 64, dtype=bf)            # [nb, block, 64]
+        dot = lambda a, b: jax.lax.dot_general(             # noqa: E731
+            a, b, (((1,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        local = []
+        for v in vals_s:
+            s = None
+            for part in _bf16_parts(v.reshape(nb, block)):
+                d = dot(oh_hi, part[:, :, None] * oh_lo)    # [nb, B/64, 64]
+                s = d if s is None else s + d
+            local.append(s.reshape(nb, block))              # sums per (slab, j)
+        # stitch slab-spanning groups: a group continuing into slab b sits at
+        # local id 0 there, so a segmented scan over local[:, 0] (heads where
+        # rank_start changes) accumulates each continuation chain
+        heads_b = jnp.concatenate([jnp.ones((1,), bool),
+                                   rank_start[1:] != rank_start[:-1]])
+        slab0 = jnp.stack([l[:, 0] for l in local])         # [R, nb]
+        flags = jnp.broadcast_to(heads_b[None, :], slab0.shape)
+        _, chain = jax.lax.associative_scan(_seg_sum_op, (flags, slab0),
+                                            axis=1)
     # dense decode: each key's first sorted row -> (slab g0, local id j0); the
     # last slab of its chain is the last rank_start <= its rank
-    p = jnp.minimum(left[:-1], n - 1)
-    r = rank[p]
-    g0 = p // block
-    j0 = r - rank_start[g0]
-    g1 = jnp.searchsorted(rank_start, r, side="right") - 1
-    occ = counts > 0
-    for li, ci in zip(local, chain):
-        start = li[g0, j0]
-        tail = ci[g1]
-        # j0 == 0: the chain includes slab g0 itself; otherwise the chain
-        # (if any: g1 > g0) covers only the continuation slabs after g0
-        total = jnp.where(j0 == 0, tail,
-                          start + jnp.where(g1 > g0, tail, 0.0))
-        outs.append(jnp.where(occ, total, 0.0))
+    with jax.named_scope("pinot.groupby.partitioned.trim"):
+        p = jnp.minimum(left[:-1], n - 1)
+        r = rank[p]
+        g0 = p // block
+        j0 = r - rank_start[g0]
+        g1 = jnp.searchsorted(rank_start, r, side="right") - 1
+        occ = counts > 0
+        for li, ci in zip(local, chain):
+            start = li[g0, j0]
+            tail = ci[g1]
+            # j0 == 0: the chain includes slab g0 itself; otherwise the chain
+            # (if any: g1 > g0) covers only the continuation slabs after g0
+            total = jnp.where(j0 == 0, tail,
+                              start + jnp.where(g1 > g0, tail, 0.0))
+            outs.append(jnp.where(occ, total, 0.0))
     return outs
 
 
 def combine_collective(name: str, v, axis: str):
     """The cross-device combine for one kernel output: partials agree on dense keys
     (aligned dictionaries), so one ICI collective merges them."""
-    if name.endswith(".min"):
-        return jax.lax.pmin(v, axis)
-    if name.endswith(".max"):
-        return jax.lax.pmax(v, axis)
-    return jax.lax.psum(v, axis)
+    with jax.named_scope("pinot.collective"):
+        if name.endswith(".min"):
+            return jax.lax.pmin(v, axis)
+        if name.endswith(".max"):
+            return jax.lax.pmax(v, axis)
+        return jax.lax.psum(v, axis)
 
 
 def make_kernel_body(spec: KernelSpec):
@@ -851,6 +801,58 @@ def _make_body(spec: KernelSpec):
     num_seg = spec.num_keys_pad + 1  # +1 overflow bucket for masked-out rows
     mask_fn = _make_mask_fn(spec)
     caps = get_caps()  # regime crossovers (calibrated; part of signature())
+    scope = jax.named_scope  # each stage of the scan, named in the device trace
+
+    def grouped_distinct(ai, agg, ids, key, mask):
+        """PER-GROUP presence counts [keys, dict ids] (the grouped
+        DISTINCTCOUNT/HLL/theta path, BASELINE config 5): one combined dense
+        key over the (group, id) product space — masked rows ride the
+        overflow band exactly like `key`. The SKINNY one-hot matmul is ~100x
+        slower than a scatter at this width (keys*ids, tens of thousands),
+        but the CHUNKED 64x64-tile formulation (_grouped_chunk64) runs the
+        same product space at full MXU tile utilization — count-only, so one
+        bf16 part per chunk (exact: 0/1 operands, f32 accumulation,
+        2^24-increment guard shared with the sum path). segment_sum remains
+        for widths past CHUNK_KEY_CAP and blocks that could overflow an f32
+        cell."""
+        size = spec.distinct_lut_sizes[ai]
+        col_ids = ids[agg.arg.name].ravel()
+        comb = key * size + col_ids
+        width = num_seg * size
+        if width <= caps.chunk_cap and key.size <= (1 << 24):
+            fm = mask.ravel().astype(jnp.float32)
+            pres = _grouped_chunk64(comb, width, [fm], [])[0]
+            return jnp.round(pres).astype(jnp.int32).reshape(num_seg, size)
+        if caps.high_card_regime == "scatter":
+            return jax.ops.segment_sum(
+                mask.ravel().astype(jnp.int32), comb,
+                num_segments=width).reshape(num_seg, size)
+        # presence counts over the combined (group, id) space past the chunk
+        # cap: sorted-run boundary counts are exact int32 with no matmul and
+        # no scatter
+        pres = _grouped_sorted(comb, width, [], caps.partition_block)[0]
+        return pres.reshape(num_seg, size)
+
+    def scalar_distinct(ai, agg, ids, mask, fmask):
+        """Exact distinct over a dict column: per-dict-id presence vector.
+        Returned as a vector (not a count) because cross-segment merge needs
+        the id set — dictionaries differ per segment."""
+        size = spec.distinct_lut_sizes[ai]
+        col_ids = ids[agg.arg.name].ravel()
+        wants_counts = getattr(agg, "wants_id_counts", False)
+        # count consumers (t-digest: per-id multiplicities as centroid
+        # weights) need the EXACT histogram; f32 matmul cells stop
+        # incrementing past 2^24, so blocks that could overflow a cell take
+        # the int32 scatter (same guard as the grouped sum path). Presence
+        # consumers (>0) are immune to the saturation and keep the matmul.
+        counts_exact = mask.size <= (1 << 24)
+        if size <= PRESENCE_MATMUL_CAP and (not wants_counts or counts_exact):
+            counts = _presence_2d(fmask, col_ids, size)
+            if wants_counts:
+                return counts.astype(jnp.int32)
+            return (counts > 0).astype(jnp.int32)
+        return jax.ops.segment_sum(mask.ravel().astype(jnp.int32), col_ids,
+                                   num_segments=size)
 
     def kernel(ids, vals, luts, iscal, fscal, nulls, valid, strides, agg_luts,
                docsets, bitmaps=()):
@@ -860,61 +862,35 @@ def _make_body(spec: KernelSpec):
         out: Dict[str, jnp.ndarray] = {}
 
         if group:
-            key = jnp.zeros_like(ids[spec.group_cols[0]])
-            for gi, gc in enumerate(spec.group_cols):
-                key = key + ids[gc] * strides[gi]
-            key = jnp.where(mask, key, spec.num_keys_pad).ravel()
-            fmask = mask.ravel().astype(jnp.float32)
+            with scope("pinot.groupby.key"):
+                key = jnp.zeros_like(ids[spec.group_cols[0]])
+                for gi, gc in enumerate(spec.group_cols):
+                    key = key + ids[gc] * strides[gi]
+                key = jnp.where(mask, key, spec.num_keys_pad).ravel()
+                fmask = mask.ravel().astype(jnp.float32)
             # collect count + every sum row, then ONE stacked one-hot matmul:
             # [1 + n_sums, N] @ one_hot(key)[N, num_seg] -> [1 + n_sums, num_seg]
             sum_rows, sum_names = [fmask], ["count"]
             minmax = []  # (out name, values, is_min)
             for ai, (agg, outs) in enumerate(spec.aggs):
                 if "distinct" in outs:
-                    # PER-GROUP presence counts [keys, dict ids] (the grouped
-                    # DISTINCTCOUNT/HLL/theta path, BASELINE config 5): one
-                    # combined dense key over the (group, id) product space —
-                    # masked rows ride the overflow band exactly like `key`.
-                    # The SKINNY one-hot matmul is ~100x slower than a
-                    # scatter at this width (keys*ids, tens of thousands),
-                    # but the CHUNKED 64x64-tile formulation
-                    # (_grouped_chunk64) runs the same product space at full
-                    # MXU tile utilization — count-only, so one bf16 part
-                    # per chunk (exact: 0/1 operands, f32 accumulation,
-                    # 2^24-increment guard shared with the sum path).
-                    # segment_sum remains for widths past CHUNK_KEY_CAP and
-                    # blocks that could overflow an f32 cell.
-                    size = spec.distinct_lut_sizes[ai]
-                    col_ids = ids[agg.arg.name].ravel()
-                    comb = key * size + col_ids
-                    width = num_seg * size
-                    if width <= caps.chunk_cap and key.size <= (1 << 24):
-                        fm = mask.ravel().astype(jnp.float32)
-                        pres = _grouped_chunk64(comb, width, [fm], [])[0]
-                        out[f"{ai}.distinct"] = jnp.round(pres).astype(
-                            jnp.int32).reshape(num_seg, size)
-                    elif caps.high_card_regime == "scatter":
-                        out[f"{ai}.distinct"] = jax.ops.segment_sum(
-                            mask.ravel().astype(jnp.int32), comb,
-                            num_segments=width).reshape(num_seg, size)
-                    else:
-                        # presence counts over the combined (group, id) space
-                        # past the chunk cap: sorted-run boundary counts are
-                        # exact int32 with no matmul and no scatter
-                        pres = _grouped_sorted(comb, width, [],
-                                               caps.partition_block)[0]
-                        out[f"{ai}.distinct"] = pres.reshape(num_seg, size)
+                    with scope("pinot.distinct"):
+                        out[f"{ai}.distinct"] = grouped_distinct(
+                            ai, agg, ids, key, mask)
                     continue
-                v = _agg_arg(agg, vals)
-                for o in outs:
-                    if o in _POWER_SUMS:
-                        # sums of powers ride the same stacked matmul (variance /
-                        # skewness / kurtosis moments, VarianceAggregationFunction)
-                        row = v.ravel().astype(jnp.float32) ** _POWER_SUMS[o]
-                        sum_rows.append(row * fmask)
-                        sum_names.append(f"{ai}.{o}")
-                    elif o in ("min", "max"):
-                        minmax.append((f"{ai}.{o}", v.ravel(), o == "min"))
+                with scope("pinot.groupby.key"):
+                    v = _agg_arg(agg, vals)
+                    for o in outs:
+                        if o in _POWER_SUMS:
+                            # sums of powers ride the same stacked matmul
+                            # (variance / skewness / kurtosis moments,
+                            # VarianceAggregationFunction)
+                            row = v.ravel().astype(jnp.float32) \
+                                ** _POWER_SUMS[o]
+                            sum_rows.append(row * fmask)
+                            sum_names.append(f"{ai}.{o}")
+                        elif o in ("min", "max"):
+                            minmax.append((f"{ai}.{o}", v.ravel(), o == "min"))
             # f32 one-hot counts are exact only up to 2^24 increments (2^24 itself
             # IS representable); the row count is static at trace time, so pick the
             # exact int32 scatter when a single group could overflow the f32
@@ -922,93 +898,109 @@ def _make_body(spec: KernelSpec):
             # padded block sits exactly at 2^24 and must keep the matmul path.
             count_exact_in_f32 = key.size <= (1 << 24)
             if num_seg <= caps.matmul_cap and count_exact_in_f32:
-                partials = _onehot_sums(key, num_seg, sum_rows)
-                for r, name in enumerate(sum_names):
-                    p = partials[r]
-                    out[name] = (jnp.round(p).astype(jnp.int32) if name == "count" else p)
+                with scope("pinot.groupby.onehot"):
+                    partials = _onehot_sums(key, num_seg, sum_rows)
+                    for r, name in enumerate(sum_names):
+                        p = partials[r]
+                        out[name] = (jnp.round(p).astype(jnp.int32)
+                                     if name == "count" else p)
             elif num_seg <= caps.chunk_cap and count_exact_in_f32:
                 # HIGH-CARDINALITY group-by: chunked 64x64-tile matmuls (the
                 # redesigned >cap path — 6.4x the segment_sum scatter at 20k
                 # keys; see _grouped_chunk64's measurement + limit analysis)
-                res = _grouped_chunk64(key, num_seg, [fmask], sum_rows[1:])
-                out["count"] = jnp.round(res[0]).astype(jnp.int32)
+                with scope("pinot.groupby.chunk64"):
+                    res = _grouped_chunk64(key, num_seg, [fmask], sum_rows[1:])
+                    out["count"] = jnp.round(res[0]).astype(jnp.int32)
                 for arr, name in zip(res[1:], sum_names[1:]):
                     out[name] = arr
             elif caps.high_card_regime == "scatter":
                 # explicit escape hatch (calibration baseline / pathological
                 # platforms): the K-independent flat scatter
-                counts = jax.ops.segment_sum(mask.ravel().astype(jnp.int32), key,
-                                             num_segments=num_seg)
-                out["count"] = counts
-                for row, name in zip(sum_rows[1:], sum_names[1:]):
-                    out[name] = jax.ops.segment_sum(row, key, num_segments=num_seg)
+                with scope("pinot.groupby.scatter"):
+                    out["count"] = jax.ops.segment_sum(
+                        mask.ravel().astype(jnp.int32), key,
+                        num_segments=num_seg)
+                    for row, name in zip(sum_rows[1:], sum_names[1:]):
+                        out[name] = jax.ops.segment_sum(row, key,
+                                                        num_segments=num_seg)
             else:
                 # VERY-HIGH-CARDINALITY group-by (> chunk_cap, or row counts
                 # past the f32 2^24 guard at any cardinality): sort-based
                 # regimes with exact int32 counts and no scatter
-                grouped = (_grouped_sorted if caps.high_card_regime == "sorted"
+                regime = ("sorted" if caps.high_card_regime == "sorted"
+                          else "partitioned")
+                grouped = (_grouped_sorted if regime == "sorted"
                            else _grouped_partitioned)
-                res = grouped(key, num_seg, sum_rows[1:], caps.partition_block)
+                with scope("pinot.groupby." + regime):
+                    res = grouped(key, num_seg, sum_rows[1:],
+                                  caps.partition_block)
                 out["count"] = res[0]
                 for arr, name in zip(res[1:], sum_names[1:]):
                     out[name] = arr
             for name, v, is_min in minmax:
-                if num_seg <= caps.minmax_bcast_cap:
-                    ident = (_INT_MIN_IDENT if is_min else _INT_MAX_IDENT) \
-                        if v.dtype.kind == "i" else (jnp.inf if is_min else -jnp.inf)
-                    onehot = key[:, None] == jnp.arange(num_seg)[None, :]
-                    cells = jnp.where(onehot, v[:, None], ident)
-                    out[name] = cells.min(axis=0) if is_min else cells.max(axis=0)
-                else:
-                    op = jax.ops.segment_min if is_min else jax.ops.segment_max
-                    out[name] = op(v, key, num_segments=num_seg)
+                with scope("pinot.groupby.minmax"):
+                    if num_seg <= caps.minmax_bcast_cap:
+                        ident = (_INT_MIN_IDENT if is_min else _INT_MAX_IDENT) \
+                            if v.dtype.kind == "i" \
+                            else (jnp.inf if is_min else -jnp.inf)
+                        onehot = key[:, None] == jnp.arange(num_seg)[None, :]
+                        cells = jnp.where(onehot, v[:, None], ident)
+                        out[name] = (cells.min(axis=0) if is_min
+                                     else cells.max(axis=0))
+                    else:
+                        op = (jax.ops.segment_min if is_min
+                              else jax.ops.segment_max)
+                        out[name] = op(v, key, num_segments=num_seg)
         else:
-            fmask = mask.ravel().astype(jnp.float32)
-            out["count"] = mask.sum(dtype=jnp.int32)
+            with scope("pinot.agg"):
+                fmask = mask.ravel().astype(jnp.float32)
+                out["count"] = mask.sum(dtype=jnp.int32)
             for ai, (agg, outs) in enumerate(spec.aggs):
                 if "distinct" in outs:
-                    # exact distinct over a dict column: per-dict-id presence vector.
-                    # Returned as a vector (not a count) because cross-segment merge
-                    # needs the id set — dictionaries differ per segment.
-                    size = spec.distinct_lut_sizes[ai]
-                    col_ids = ids[agg.arg.name].ravel()
-                    wants_counts = getattr(agg, "wants_id_counts", False)
-                    # count consumers (t-digest: per-id multiplicities as
-                    # centroid weights) need the EXACT histogram; f32 matmul
-                    # cells stop incrementing past 2^24, so blocks that could
-                    # overflow a cell take the int32 scatter (same guard as
-                    # the grouped sum path). Presence consumers (>0) are
-                    # immune to the saturation and keep the matmul.
-                    counts_exact = mask.size <= (1 << 24)
-                    if size <= PRESENCE_MATMUL_CAP and (not wants_counts
-                                                   or counts_exact):
-                        counts = _presence_2d(fmask, col_ids, size)
-                        if wants_counts:
-                            out[f"{ai}.distinct"] = counts.astype(jnp.int32)
-                        else:
-                            out[f"{ai}.distinct"] = (counts > 0).astype(jnp.int32)
-                    else:
-                        out[f"{ai}.distinct"] = jax.ops.segment_sum(
-                            mask.ravel().astype(jnp.int32), col_ids, num_segments=size)
+                    with scope("pinot.distinct"):
+                        out[f"{ai}.distinct"] = scalar_distinct(
+                            ai, agg, ids, mask, fmask)
                     continue
                 if outs == ("count",):
                     continue
-                v = _agg_arg(agg, vals)
-                for o in outs:
-                    if o == "count":
-                        continue
-                    if o in _POWER_SUMS:
-                        row = v.ravel().astype(jnp.float32) ** _POWER_SUMS[o]
-                        out[f"{ai}.{o}"] = (row * fmask).sum()
-                    elif o == "min":
-                        ident = _INT_MIN_IDENT if v.dtype.kind == "i" else jnp.inf
-                        out[f"{ai}.min"] = jnp.where(mask, v, ident).min()
-                    elif o == "max":
-                        ident = _INT_MAX_IDENT if v.dtype.kind == "i" else -jnp.inf
-                        out[f"{ai}.max"] = jnp.where(mask, v, ident).max()
+                with scope("pinot.agg"):
+                    v = _agg_arg(agg, vals)
+                    for o in outs:
+                        if o == "count":
+                            continue
+                        if o in _POWER_SUMS:
+                            row = v.ravel().astype(jnp.float32) \
+                                ** _POWER_SUMS[o]
+                            out[f"{ai}.{o}"] = (row * fmask).sum()
+                        elif o == "min":
+                            ident = (_INT_MIN_IDENT if v.dtype.kind == "i"
+                                     else jnp.inf)
+                            out[f"{ai}.min"] = jnp.where(mask, v, ident).min()
+                        elif o == "max":
+                            ident = (_INT_MAX_IDENT if v.dtype.kind == "i"
+                                     else -jnp.inf)
+                            out[f"{ai}.max"] = jnp.where(mask, v, ident).max()
         return out
 
+    kernel.__name__ = kernel_name(spec)
     return kernel
+
+
+def kernel_name(spec: KernelSpec, batch: int = 0) -> str:
+    """What a compiled scan is called in the profiler's trace (the "XLA
+    Modules" line, the host's `PjitFunction(...)` events): `pinot_groupby`,
+    `pinot_distinct` or `pinot_agg` by the spec's shape, `_fused` where it
+    decodes compressed forms in-register, `_b<batch>` for a stacked launch.
+    A name only: the kernel caches are keyed by `signature()`."""
+    if spec.group_cols:
+        name = "pinot_groupby"
+    elif any("distinct" in outs for _, outs in spec.aggs):
+        name = "pinot_distinct"
+    else:
+        name = "pinot_agg"
+    if spec.fused_cols:
+        name += "_fused"
+    return name + (f"_b{batch}" if batch else "")
 
 
 def _build_kernel(spec: KernelSpec):
@@ -1089,6 +1081,7 @@ def _mask_kernel(spec: KernelSpec):
             return mask_fn(ids, vals, luts, iscal, fscal, nulls, valid,
                            docsets, bitmaps)
 
+        body.__name__ = "pinot_mask" + ("_fused" if spec.fused_cols else "")
         return jax.jit(body)
 
     return _cached_kernel(key, build)
@@ -1138,9 +1131,11 @@ def filter_count_kernel(spec: KernelSpec):
         word_fn = _make_word_fn(spec)
 
         def body(valid_words, bitmaps):
-            words = word_fn(bitmaps) & valid_words
-            return jax.lax.population_count(words).sum(dtype=jnp.uint32)
+            with jax.named_scope("pinot.filter"):
+                words = word_fn(bitmaps) & valid_words
+                return jax.lax.population_count(words).sum(dtype=jnp.uint32)
 
+        body.__name__ = "pinot_bitcount"
         return jax.jit(body)
 
     return _cached_kernel(key, build)
@@ -1171,18 +1166,20 @@ def topk_kernel(spec: KernelSpec, order_expr, desc: bool, k: int,
         def body(ids, vals, luts, iscal, fscal, nulls, valid, docsets):
             vals = _fused_env(spec, ids, vals, iscal)
             mask = mask_fn(ids, vals, luts, iscal, fscal, nulls, valid, docsets).ravel()
-            v = eval_expr(order_expr, vals, jnp).ravel().astype(jnp.float32)
-            # NaN keys sink to the bottom (numpy sorts NaN last ascending; exact
-            # parity for NaN keys is out of contract either way)
-            nan = jnp.isnan(v)
-            usable = mask & ~nan
-            score = jnp.where(usable, v if desc else -v, -jnp.inf)
-            _, idx = jax.lax.top_k(score, k)
-            return {"idx": idx.astype(jnp.int32),
-                    "count": mask.sum(dtype=jnp.int32),
-                    "ok": usable[idx],
-                    "nanMatches": (mask & nan).sum(dtype=jnp.int32)}
+            with jax.named_scope("pinot.topk"):
+                v = eval_expr(order_expr, vals, jnp).ravel().astype(jnp.float32)
+                # NaN keys sink to the bottom (numpy sorts NaN last ascending;
+                # exact parity for NaN keys is out of contract either way)
+                nan = jnp.isnan(v)
+                usable = mask & ~nan
+                score = jnp.where(usable, v if desc else -v, -jnp.inf)
+                _, idx = jax.lax.top_k(score, k)
+                return {"idx": idx.astype(jnp.int32),
+                        "count": mask.sum(dtype=jnp.int32),
+                        "ok": usable[idx],
+                        "nanMatches": (mask & nan).sum(dtype=jnp.int32)}
 
+        body.__name__ = "pinot_topk" + ("_fused" if spec.fused_cols else "")
         return jax.jit(body)
 
     return _cached_kernel(key, build), k

@@ -350,17 +350,20 @@ def _pack_kernel(meta: Tuple, trim_keys: Tuple[int, int], batched: bool):
 
         def pack_impl(outs):
             by_dt: Dict[str, list] = {}
-            for name, shape, dts in meta:
-                v = outs[name]
-                core = shape[1:] if batched else shape
-                if pad and real < pad and core and core[0] in (pad, pad + 1):
-                    v = v[:, :real] if batched else v[:real]
-                flat = v.reshape((v.shape[0], -1)) if batched \
-                    else v.reshape(-1)
-                by_dt.setdefault(dts, []).append(flat)
-            return {dt: (jnp.concatenate(parts, axis=-1)
-                         if len(parts) > 1 else parts[0])
-                    for dt, parts in by_dt.items()}
+            with jax.named_scope("pinot.pack"):
+                for name, shape, dts in meta:
+                    v = outs[name]
+                    core = shape[1:] if batched else shape
+                    if pad and real < pad and core \
+                            and core[0] in (pad, pad + 1):
+                        v = v[:, :real] if batched else v[:real]
+                    flat = v.reshape((v.shape[0], -1)) if batched \
+                        else v.reshape(-1)
+                    by_dt.setdefault(dts, []).append(flat)
+                return {dt: (jnp.concatenate(parts, axis=-1)
+                             if len(parts) > 1 else parts[0])
+                        for dt, parts in by_dt.items()}
+        pack_impl.__name__ = "pinot_pack"
         fn = jax.jit(pack_impl)
         _SHARD_KERNEL_CACHE[key] = fn
     return fn
@@ -696,10 +699,13 @@ class MeshQueryExecutor:
         """Launch a deduped batch of prepared dispatches.
 
         `reps` are dedupe-group representatives. Returns a list of launches
-        `(outs_dev, finish, indices)`: `indices` are positions into `reps`
-        covered by that launch and `finish(host_fetched)` -> list of decoded
-        host outs dicts aligned with `indices`. Stackable reps sharing a
-        `stack_key` collapse into ONE batched kernel launch."""
+        `(outs_dev, finish, indices, recorded)`: `indices` are positions into
+        `reps` covered by that launch, `finish(host_fetched)` -> list of
+        decoded host outs dicts aligned with `indices`, and `recorded` is what
+        the kernel cache and the first-call fence recorded during THAT launch
+        (`compileMs`, `compileCacheMisses`, ...: the pipeline folds it into
+        the queries the launch answers). Stackable reps sharing a `stack_key`
+        collapse into ONE batched kernel launch."""
         groups: Dict[Tuple, List[int]] = {}
         order: List[Tuple] = []
         for i, p in enumerate(reps):
@@ -713,25 +719,25 @@ class MeshQueryExecutor:
         for key in order:
             idxs = groups[key]
             ps = [reps[i] for i in idxs]
-            if len(ps) == 1:
-                p = ps[0]
-                if p.kind == "topk":
-                    outs = p.launch()
+            with qstats.scoped() as recorded:
+                if len(ps) == 1:
+                    p = ps[0]
+                    if p.kind == "topk":
+                        outs = p.launch()
+                    else:
+                        fn = self._get_shard_kernel(p.spec, p.s_pad, p.rows)
+                        if p.spec.fused_cols:
+                            qstats.record(qstats.FUSED_LAUNCHES)
+                        outs = fn(p.inputs)
+                    packed, unpack = self._pack(outs, p.trim_keys, batched=0)
+                    finish = (lambda host, u=unpack: [u(host)])
                 else:
-                    fn = self._get_shard_kernel(p.spec, p.s_pad, p.rows)
-                    if p.spec.fused_cols:
-                        qstats.record(qstats.FUSED_LAUNCHES)
-                    outs = fn(p.inputs)
-                packed, unpack = self._pack(outs, p.trim_keys, batched=0)
-                launches.append((packed,
-                                 (lambda host, u=unpack: [u(host)]), idxs))
-            else:
-                outs, b_real = self._launch_stacked(ps)
-                packed, unpack = self._pack(outs, ps[0].trim_keys,
-                                            batched=b_real)
-                launches.append((packed,
-                                 (lambda host, u=unpack, n=b_real:
-                                  [u(host, b) for b in range(n)]), idxs))
+                    outs, b_real = self._launch_stacked(ps)
+                    packed, unpack = self._pack(outs, ps[0].trim_keys,
+                                                batched=b_real)
+                    finish = (lambda host, u=unpack, n=b_real:
+                              [u(host, b) for b in range(n)])
+            launches.append((packed, finish, idxs, recorded.to_wire()))
         return launches
 
     def _launch_stacked(self, ps: List[PreparedDispatch]):
@@ -1287,7 +1293,8 @@ class MeshQueryExecutor:
         `batch > 0` builds the STACKED variant: iscal/fscal arrive [B, n] and
         the body scans over them — B same-shape queries in one launch, reading
         the HBM columns once per scan step but paying ONE dispatch."""
-        from ..engine.kernels import combine_collective, make_kernel_body
+        from ..engine.kernels import (combine_collective, kernel_name,
+                                      make_kernel_body)
         body = make_kernel_body(spec)
         P = jax.sharding.PartitionSpec
         ax = SEGMENT_AXIS
@@ -1355,13 +1362,17 @@ class MeshQueryExecutor:
                     res = {}
                     for name, v in outs.items():
                         if name in scat:
-                            core = v[:, :pad] if batch else v[:pad]
-                            res[name] = jax.lax.psum_scatter(
-                                core, ax, scatter_dimension=key_dim,
-                                tiled=True)
+                            with jax.named_scope("pinot.collective"):
+                                core = v[:, :pad] if batch else v[:pad]
+                                res[name] = jax.lax.psum_scatter(
+                                    core, ax, scatter_dimension=key_dim,
+                                    tiled=True)
                         else:
                             res[name] = combine_collective(name, v, ax)
                     return res
+
+                # what ran, by name, in the trace's "XLA Modules" line
+                shard_body.__name__ = kernel_name(spec, batch)
 
                 out_specs = {
                     name: ((P(None, ax) if batch else P(ax))

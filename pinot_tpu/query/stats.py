@@ -10,9 +10,11 @@ then merged back into `QueryResult.stats` under well-known keys.
 Accounting sites publish through a thread-local "current stats" slot (same
 pattern as `utils.trace`): the server activates a fresh record on its
 execution thread, kernel/launch/fetch hooks `record()` into whatever record is
-active (a no-op when none is — e.g. pipeline dispatcher threads serving many
-queries at once, which attribute per-item launch stats explicitly instead),
-and the record rides `SegmentResult.stats` back across the wire as a flat
+active (a no-op when none is, e.g. warm-up and calibration; the pipeline's
+dispatcher thread serves many queries at once, so it activates a scratch
+record around each query's prepare and around each launch and folds what the
+kernel cache recorded there into the items that launch answers), and the
+record rides `SegmentResult.stats` back across the wire as a flat
 summable dict. Per-operator rows/ms breakdowns (EXPLAIN ANALYZE) flatten into
 the same dict under `op:<label>:rows` / `op:<label>:ms` keys so one merge rule
 covers everything; the public export strips them.
@@ -51,6 +53,17 @@ DEVICE_EXEC_MS = "deviceExecMs"
 DEVICE_FETCH_MS = "deviceFetchMs"
 BYTES_FETCHED = "bytesFetched"
 QUEUE_WAIT_MS = "queueWaitMs"
+# the device pipeline's phases after the queue wait, per query (PR 26): the
+# query's own plan + input build; its drain's launch (shared by the batch);
+# launch end -> the fetcher taking the batch; from the start of its launch's
+# decode to its answer. deviceBatchSize is the live queries of its drain and
+# serverTimeMs the server's whole execute wall (both max-merged)
+DEVICE_PREPARE_MS = "devicePrepareMs"
+DEVICE_LAUNCH_MS = "deviceLaunchMs"
+DEVICE_HANDOFF_MS = "deviceHandoffMs"
+DEVICE_DECODE_MS = "deviceDecodeMs"
+DEVICE_BATCH_SIZE = "deviceBatchSize"
+SERVER_TIME_MS = "serverTimeMs"
 DEDUPED_LAUNCHES = "dedupedLaunches"
 STACKED_LAUNCHES = "stackedLaunches"
 # fused-vs-staged execution split (PR 16): fusedLaunches counts single-launch
@@ -67,12 +80,6 @@ COLLECTIVE_MS = "collectiveMs"
 DEVICE_SKEW_PCT = "deviceSkewPct"
 HEDGED_REQUESTS = "hedgedRequests"
 ADMISSION_DEFER_MS = "admissionDeferMs"
-# per-kernel cost-profile attribution (XLA cost_analysis at compile time,
-# folded with live launch counters): modeled flops / bytes the query's device
-# launches accounted for, and the achieved-vs-roofline bandwidth percentage
-DEVICE_FLOPS = "deviceFlops"
-DEVICE_BYTES_ACCESSED = "deviceBytesAccessed"
-ROOFLINE_PCT = "rooflinePct"
 # tiered-storage lifecycle: segments the admission gate kept OFF the device
 # (served by the host plan instead of OOMing), segments freshly promoted
 # host→HBM this query, and cold-tier segments lazily downloaded from the
@@ -104,11 +111,11 @@ COUNTER_KEYS = (
     SCAN_ROWS_AVOIDED, NUM_SEGMENTS_MATCHED,
     DEVICE_LAUNCHES, COMPILE_CACHE_HITS, COMPILE_CACHE_MISSES,
     COMPILE_MS, DEVICE_EXEC_MS, DEVICE_FETCH_MS, BYTES_FETCHED,
-    QUEUE_WAIT_MS, DEDUPED_LAUNCHES, STACKED_LAUNCHES,
+    QUEUE_WAIT_MS, DEVICE_PREPARE_MS, DEVICE_LAUNCH_MS, DEVICE_HANDOFF_MS,
+    DEVICE_DECODE_MS, DEDUPED_LAUNCHES, STACKED_LAUNCHES,
     FUSED_LAUNCHES, STAGED_LAUNCHES,
     NUM_CONSUMING_SEGMENTS_QUERIED, MUX_FRAME_QUEUE_MS, MUX_FLOW_CONTROL_MS,
     COLLECTIVE_MS, HEDGED_REQUESTS, ADMISSION_DEFER_MS,
-    DEVICE_FLOPS, DEVICE_BYTES_ACCESSED,
     SEGMENTS_SERVED_HOST_TIER, TIER_PROMOTIONS,
     SEGMENTS_COLD_LOADED, COLD_LOAD_MS,
     JOIN_BUILD_MS, JOIN_PROBE_MS, JOIN_SHUFFLE_BYTES,
@@ -126,9 +133,9 @@ MIN_KEYS = (MIN_CONSUMING_FRESHNESS_TIME_MS,)
 # exec-time imbalance any mesh launch saw (summing percentages across
 # launches/servers is meaningless; the slowest chip bounds the query).
 # Absent on responses that never took a multi-device mesh path.
-# rooflinePct likewise keeps the BEST achieved-vs-roofline fetch window the
-# query saw (sums are meaningless for percentages).
-MAX_KEYS = (DEVICE_SKEW_PCT, ROOFLINE_PCT, JOIN_SKEW_PCT)
+# deviceBatchSize and serverTimeMs keep the largest any server reported (a
+# query waits for its slowest server).
+MAX_KEYS = (DEVICE_SKEW_PCT, JOIN_SKEW_PCT, DEVICE_BATCH_SIZE, SERVER_TIME_MS)
 
 # the query's 16-hex plan-shape fingerprint (sql/fingerprint.py): stamped by
 # the broker so any response / slow-log line / trace resolves to its shape
@@ -302,6 +309,20 @@ def collect_stats(st: Optional[ExecutionStats] = None
         yield st
     finally:
         _local.stats = prev
+
+
+@contextmanager
+def scoped() -> Iterator[ExecutionStats]:
+    """A fresh record for the scope, folded into the record that was active
+    around it (if any) on the way out: what ONE launch recorded, read apart,
+    with nothing lost to an enclosing query."""
+    prev = getattr(_local, "stats", None)
+    try:
+        with collect_stats() as st:
+            yield st
+    finally:
+        if prev is not None:
+            prev.merge(st)
 
 
 @contextmanager

@@ -82,24 +82,22 @@ def render_report(stats: Dict[str, Any]) -> str:
                        ("deviceExecMs", "device exec"),
                        ("deviceFetchMs", "device fetch"),
                        ("queueWaitMs", "queue wait"),
+                       ("devicePrepareMs", "pipeline prepare"),
+                       ("deviceLaunchMs", "pipeline launch"),
+                       ("deviceHandoffMs", "pipeline handoff"),
+                       ("deviceDecodeMs", "pipeline decode"),
+                       ("serverTimeMs", "server execute"),
                        ("muxFrameQueueMs", "mux frame queue"),
                        ("muxFlowControlMs", "mux flow ctl"),
                        ("collectiveMs", "ici collective")):
         if key in stats:
-            out.append(f"  {label:<15} {_fmt_ms(stats.get(key, 0))}")
+            out.append(f"  {label:<16} {_fmt_ms(stats.get(key, 0))}")
     if "deviceSkewPct" in stats:
         try:
             skew = f"{float(stats['deviceSkewPct']):10.1f} %"
         except (TypeError, ValueError):
             skew = f"{stats['deviceSkewPct']!s:>10}"
         out.append(f"  {'device skew':<15} {skew}  (worst mesh launch)")
-    if "rooflinePct" in stats:
-        try:
-            roofline = f"{float(stats['rooflinePct']):10.1f} %"
-        except (TypeError, ValueError):
-            roofline = f"{stats['rooflinePct']!s:>10}"
-        out.append(f"  {'hbm roofline':<15} {roofline}  "
-                   "(achieved/measured bandwidth, worst fetch window)")
     # join section only when a join ran (joinStrategy is set by both the
     # funnel and the P2P multistage paths)
     if stats.get("joinStrategy") or any(
@@ -141,9 +139,8 @@ def render_report(stats: Dict[str, Any]) -> str:
                 "numGroupsTotal", "deviceLaunches", "fusedLaunches",
                 "stagedLaunches",
                 "dedupedLaunches", "stackedLaunches", "compileCacheHits",
-                "compileCacheMisses", "bytesFetched", "deviceFlops",
-                "deviceBytesAccessed", "numServersQueried",
-                "numServersResponded"):
+                "compileCacheMisses", "bytesFetched", "deviceBatchSize",
+                "numServersQueried", "numServersResponded"):
         if key in stats:
             out.append(f"  {key:<20} {stats[key]}")
     if stats.get("partialResult"):

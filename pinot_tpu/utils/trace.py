@@ -24,6 +24,14 @@ Always-on sampling layer (the broker owns one of each):
   the head decision, so every slow-query log line resolves to a full trace;
 * `to_chrome_trace` — renders ring entries as a Chrome trace-event JSON document
   (loadable in Perfetto / chrome://tracing) with one track per server hop.
+
+Second sink, the profiler's clock: `span()` and `stage()` also open a
+`jax.profiler.TraceAnnotation("pinot:<name>")`, so while a profiler session
+runs the same spans land in its `/host:CPU` plane on the clock of the device
+operations (a span of a request carries its `trace_id`). `stage()` is for
+threads that serve many requests and so have no `Trace` of their own (the
+device pipeline's dispatcher and fetcher). With no session an annotation is a
+flag test.
 """
 
 from __future__ import annotations
@@ -35,6 +43,11 @@ import uuid
 from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, Iterable, List, Optional, Union
+
+from jax.profiler import TraceAnnotation
+
+#: every annotation this recorder opens in the profiler's trace starts so
+ANNOTATION_PREFIX = "pinot:"
 
 _local = threading.local()
 
@@ -149,7 +162,8 @@ def span(name: str):
     phases are visible in exported timelines."""
     tr = getattr(_local, "trace", None)
     if tr is None:
-        yield
+        with TraceAnnotation(ANNOTATION_PREFIX + name):
+            yield
         return
     depth = getattr(_local, "depth", 0)
     _local.depth = depth + 1
@@ -157,7 +171,8 @@ def span(name: str):
     t0 = time.perf_counter()
     error = False
     try:
-        yield
+        with TraceAnnotation(ANNOTATION_PREFIX + name, trace_id=tr.trace_id):
+            yield
     except BaseException:
         error = True
         raise
@@ -165,6 +180,33 @@ def span(name: str):
         _local.depth = depth
         tr.record(name, start_ms, (time.perf_counter() - t0) * 1000, depth,
                   error=error)
+
+
+class stage:
+    """`with stage("pipeline.fetch", batch=2) as st: ...` then `st.ms`: a span
+    on a thread that has no request `Trace`. It opens the profiler annotation
+    `pinot:<name>` with `attrs` and times the body on `perf_counter`; where the
+    milliseconds go (an item's stats, a histogram) is the caller's business."""
+
+    __slots__ = ("_annotation", "_t0", "ms")
+
+    def __init__(self, name: str, **attrs: Any):
+        self._annotation = TraceAnnotation(ANNOTATION_PREFIX + name, **attrs)
+        self.ms = 0.0
+
+    def __enter__(self) -> "stage":
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def note(self, **attrs: Any) -> None:
+        """Attributes known only once the body has run (a batch's size)."""
+        self._annotation.set_metadata(**attrs)
+
+    def __exit__(self, *exc) -> bool:
+        self.ms = (time.perf_counter() - self._t0) * 1000
+        self._annotation.__exit__(*exc)
+        return False
 
 
 # -- sampling + retention -----------------------------------------------------

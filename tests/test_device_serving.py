@@ -504,9 +504,12 @@ def test_pipeline_stage_histograms_exported(device_cluster):
     cluster, pipeline = device_cluster
     cluster.query("SELECT COUNT(*) FROM lineorder WHERE lo_quantity >= 2")
     text = get_registry().render_prometheus()
-    for stage in ("queue_wait", "dispatch", "fetch"):
+    for stage in ("queue_wait", "dispatch", "prepare", "launch", "handoff",
+                  "fetch", "decode"):
         name = f"pinot_server_device_pipeline_{stage}_ms"
         assert f"# TYPE {name} histogram" in text, name
         assert f'{name}_bucket{{le="+Inf"}}' in text, name
+        assert get_registry().histogram(name).count >= 1, name
     st = pipeline.stats()
-    assert st["stageMs"]["fetch"]["count"] >= 1
+    assert "stageMs" not in st and "meanBatch" not in st
+    assert st["batches"] >= 1 and st["drainsClosedIdle"] >= 1

@@ -202,7 +202,7 @@ def test_stacked_burst_one_launch_byte_identical(seg):
     with qstats.collect_stats() as st:
         launches = mex.dispatch_prepared(preps)
         assert len(launches) == 1, "same-signature burst must stack"
-        outs_dev, finish, idxs = launches[0]
+        outs_dev, finish, idxs, _ = launches[0]
         assert sorted(idxs) == list(range(len(sqls)))
         outs_list = finish(mex.fetch([outs_dev])[0])
     burst_launches = int(st.counters.get(qstats.DEVICE_LAUNCHES, 0))

@@ -1,6 +1,6 @@
 """Device-memory observability plane tests: the HBM ledger, its gauges, the
-controller's per-table memory verdicts, per-kernel cost profiles in query
-stats, Chrome-trace memory counters, and a ledger-backed leak regression.
+controller's per-table memory verdicts, launch attribution in query stats,
+Chrome-trace memory counters, and a ledger-backed leak regression.
 
 The ledger is the accounting substrate (utils/memledger.py) — these tests pin
 its arithmetic exactly (byte-accurate totals, filter semantics, re-registration
@@ -404,7 +404,7 @@ def test_memory_check_publishes_and_removes_gauges(verdict_cluster):
                         instance="server_1") == 60.0
 
 
-# -- cost profiles + end-to-end ledger (in-proc) ------------------------------
+# -- launch attribution + end-to-end ledger (in-proc) ---------------------------
 
 @pytest.fixture()
 def lineorder_cluster(tmp_path, ssb_schema):
@@ -419,23 +419,31 @@ def lineorder_cluster(tmp_path, ssb_schema):
     return cluster, cfg
 
 
-def test_query_stats_carry_cost_profile(lineorder_cluster):
-    """EXPLAIN-ANALYZE-grade cost fields ride every query response: modeled
-    flops + bytes from XLA cost_analysis (or its deterministic input-bytes
-    fallback) and the achieved-vs-nominal HBM roofline percentage."""
+REMOVED_COST_KEYS = ("deviceFlops", "deviceBytesAccessed", "rooflinePct")
+
+
+def test_query_stats_carry_launch_and_compile_attribution(lineorder_cluster):
+    """What a query's launches cost rides every response as counts and wall
+    times taken where the work happens (launches, the kernel cache, the
+    first-call compile fence, the server's execute wall); the modeled
+    cost_analysis() figures and the host-clock roofline are gone (PR 26)."""
     cluster, cfg = lineorder_cluster
-    res = cluster.query("SELECT SUM(lo_revenue), COUNT(*) FROM lineorder")
+    # a literal nothing else in the suite uses: the shape is cold here
+    res = cluster.query("SELECT SUM(lo_revenue), COUNT(*) FROM lineorder "
+                        "WHERE lo_quantity BETWEEN 7 AND 23")
     stats = res.stats
-    assert stats["deviceBytesAccessed"] > 0
-    assert stats["deviceFlops"] >= 0
-    assert 0.0 <= stats["rooflinePct"] <= 100.0
-    # counters accumulate across launches; the roofline is max-merged so it
-    # stays a percentage even over multi-segment scatter
+    assert stats["deviceLaunches"] >= 1
+    assert stats["compileCacheMisses"] + stats["compileCacheHits"] >= 1
+    assert stats["serverTimeMs"] > 0
+    assert stats["serverTimeMs"] <= stats["timeUsedMs"]
+    for key in REMOVED_COST_KEYS:
+        assert key not in stats
+    # serverTimeMs is max-merged: over a multi-segment scatter it stays one
+    # server's wall, inside the broker's
     res2 = cluster.query(
         "SELECT lo_region, SUM(lo_revenue) FROM lineorder "
         "GROUP BY lo_region LIMIT 10")
-    assert res2.stats["deviceBytesAccessed"] > 0
-    assert 0.0 <= res2.stats["rooflinePct"] <= 100.0
+    assert 0 < res2.stats["serverTimeMs"] <= res2.stats["timeUsedMs"]
 
 
 def test_query_staging_lands_in_ledger_and_verdict(lineorder_cluster):
@@ -568,7 +576,7 @@ def http_cluster(tmp_path):
 
 
 def test_memory_plane_over_http(http_cluster):
-    """The whole plane through real sockets: cost fields in broker responses,
+    """The whole plane through real sockets: launch stats in broker responses,
     the server's /debug/memory ledger panel, and the controller's
     memoryStatus verdict fed by its HTTP /debug/memory poller."""
     from pinot_tpu.cluster.http_service import get_json
@@ -582,9 +590,10 @@ def test_memory_plane_over_http(http_cluster):
         timeout=15.0, interval=0.1)
     # stats keys ride at the top level of the broker response (Pinot style)
     resp = bc.query("SELECT SUM(fare) FROM trips")
-    assert resp["deviceBytesAccessed"] > 0
-    assert "deviceFlops" in resp
-    assert 0.0 <= resp.get("rooflinePct", 0.0) <= 100.0
+    assert resp["deviceLaunches"] >= 1
+    assert 0 < resp["serverTimeMs"] <= resp["timeUsedMs"]
+    for key in REMOVED_COST_KEYS:
+        assert key not in resp
 
     # the server's ledger panel shows the staged columns, attributed
     snap = get_json(f"{http_cluster['ssvc'].url}/debug/memory")

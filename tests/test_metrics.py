@@ -160,7 +160,8 @@ def test_trace_through_broker(lineorder_cluster):
                         "GROUP BY lo_region OPTION(trace=true)")
     spans = res.stats["traceInfo"]
     names = [s["name"] for s in spans]
-    assert "compile" in names and "reduce" in names
+    assert {"broker.compile", "broker.scatter", "broker.reduce"} <= set(names)
+    assert "server.execute" in names
     assert any(n.startswith("server:") for n in names)
     assert any(n.startswith("segment:") for n in names)
     # untraced query carries no traceInfo

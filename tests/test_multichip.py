@@ -168,7 +168,7 @@ def test_topk_prepared_mesh_vs_mesh1_vs_host(segments, mesh8, mesh1, host):
         assert p is not None and p.kind == "topk"
         launches = me.dispatch_prepared([p])
         assert len(launches) == 1, "topk must be ONE stacked launch"
-        outs_dev, finish, _ = launches[0]
+        outs_dev, finish, _, _ = launches[0]
         outs_list = finish(me.fetch([outs_dev])[0])
         partial = p.decode(outs_list[0])
         assert partial is not DEVICE_FALLBACK

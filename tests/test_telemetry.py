@@ -176,7 +176,7 @@ def test_slow_query_log_carries_trace_spans(tel_cluster, slow_log_capture):
         cat.put_property("clusterConfig/broker.slow.query.ms", None)
     entry = json.loads(slow_log_capture.records[-1].getMessage())
     assert entry["traceSpans"], entry
-    assert any(s["name"] == "compile" for s in entry["traceSpans"])
+    assert any(s["name"] == "broker.compile" for s in entry["traceSpans"])
 
 
 def test_debug_stats_rollup(tel_cluster, slow_log_capture):

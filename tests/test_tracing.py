@@ -286,7 +286,7 @@ def _server_exec_shape(spans):
         m = re.match(r"(server:server_\d+)/(.+)", name)
         if m:                                   # HTTP: spliced + prefixed
             base, rel = m.group(2), depth - dispatch_depth[m.group(1)]
-        elif name in dispatch_depth or name in ("compile", "reduce"):
+        elif name in dispatch_depth or name.startswith("broker."):
             continue                            # broker-side spans
         else:                                   # in-proc: shared trace
             base, rel = name, depth - min(dispatch_depth.values())
