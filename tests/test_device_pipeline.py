@@ -339,3 +339,250 @@ def test_smoke_real_executor_mean_batch_gt_one(tmp_path, ssb_schema):
         assert all(r is not None and r.rows for r in results)
     finally:
         pipeline.stop()
+
+
+# -- PR 26: per-query phase fields, drain counters, compile attribution -------
+
+class FakePartial:
+    """What a decode returns on the served path: something with `.stats`."""
+
+    def __init__(self, tag):
+        self.tag = tag
+        self.stats = None
+
+
+class StatsMeshExec(FakeMeshExec):
+    """FakeMeshExec whose phases take a known time and whose results carry
+    stats, as a SegmentResult does."""
+
+    def __init__(self, prepare_s=0.0, launch_s=0.0, fetch_latency=0.0):
+        super().__init__(fetch_latency=fetch_latency)
+        self.prepare_s, self.launch_s = prepare_s, launch_s
+
+    def prepare_partial(self, ctx, segments):
+        time.sleep(self.prepare_s)
+        p = super().prepare_partial(ctx, segments)
+        if p is not None:
+            p.decode = lambda outs, c=ctx: FakePartial(c["literal"])
+        return p
+
+    def dispatch_prepared(self, reps):
+        time.sleep(self.launch_s)
+        return super().dispatch_prepared(reps)
+
+
+PHASE_KEYS = ("queueWaitMs", "devicePrepareMs", "deviceLaunchMs",
+              "deviceHandoffMs", "deviceFetchMs", "deviceDecodeMs")
+
+
+def test_every_phase_field_reaches_the_partial_and_fits_the_wall():
+    """A query the pipeline answers carries every phase of its way through
+    it, and the phases never add up to more than the wall the caller saw."""
+    fake = StatsMeshExec(prepare_s=0.01, launch_s=0.01, fetch_latency=0.02)
+    pipeline = DeviceQueryPipeline(mesh_exec=fake, start=False)
+    try:
+        t0 = time.perf_counter()
+        results = _submit_concurrently(
+            pipeline, [{"shape": "A", "literal": 1},
+                       {"shape": "B", "literal": 2}])
+        wall_ms = (time.perf_counter() - t0) * 1000
+        for r in results:
+            s = r.stats
+            for key in PHASE_KEYS:
+                assert key in s, key
+            assert s["devicePrepareMs"] >= 9.0      # its own prepare only
+            assert s["deviceLaunchMs"] >= 9.0       # the drain's, shared
+            assert s["deviceFetchMs"] >= 19.0
+            assert s["deviceHandoffMs"] >= 0.0 and s["deviceDecodeMs"] >= 0.0
+            assert s["deviceBatchSize"] == 2
+            assert sum(s[k] for k in PHASE_KEYS) <= wall_ms
+        # the launch and the fetch are the batch's: the same for both
+        assert results[0].stats["deviceLaunchMs"] == \
+            results[1].stats["deviceLaunchMs"]
+        assert results[0].stats["deviceFetchMs"] == \
+            results[1].stats["deviceFetchMs"]
+    finally:
+        pipeline.stop()
+
+
+def test_execute_partial_rebuilds_every_phase_in_the_request_trace():
+    """/debug/traces and OPTION(trace=true) show the whole pipeline: one
+    `pipeline:<phase>` span a field, end to end from the submit."""
+    from pinot_tpu.utils.trace import Trace
+    fake = StatsMeshExec(prepare_s=0.005, fetch_latency=0.005)
+    pipeline = DeviceQueryPipeline(mesh_exec=fake)
+    tr = Trace("r")
+    try:
+        with tr.activate():
+            r = pipeline.execute_partial({"shape": "A", "literal": 1}, [])
+        rows = [s for s in tr.to_rows() if s["name"].startswith("pipeline:")]
+        assert [s["name"] for s in rows] == [
+            "pipeline:queue_wait", "pipeline:prepare", "pipeline:launch",
+            "pipeline:handoff", "pipeline:fetch", "pipeline:decode"]
+        for row, key in zip(rows, PHASE_KEYS):
+            assert row["durationMs"] == pytest.approx(r.stats[key], abs=2e-3)
+        for a, b in zip(rows, rows[1:]):
+            assert b["startMs"] == pytest.approx(
+                a["startMs"] + a["durationMs"], abs=5e-3)
+    finally:
+        pipeline.stop()
+
+
+def _queued(pipeline, n):
+    for i in range(n):
+        pipeline._q.put(_Item({"shape": "A", "literal": i}, []))
+
+
+@pytest.mark.parametrize("reason,kwargs,queued,drained", [
+    ("drainsClosedIdle", dict(), 2, 2),
+    ("drainsClosedFull", dict(max_batch=2), 3, 2),
+    ("drainsClosedBurst", dict(burst_window_s=0.03), 1, 1),
+])
+def test_why_a_drain_closed_is_counted(reason, kwargs, queued, drained):
+    """Each reason `_drain` has for closing a batch has its own counter,
+    driven here by a constructed queue with no thread running."""
+    pipeline = DeviceQueryPipeline(mesh_exec=FakeMeshExec(), start=False,
+                                   **kwargs)
+    _queued(pipeline, queued)
+    t0 = time.perf_counter()
+    batch = pipeline._drain()
+    assert len(batch) == drained
+    if reason == "drainsClosedBurst":
+        assert time.perf_counter() - t0 >= 0.03   # the window held it open
+    s = pipeline.stats()
+    closed = {k: v for k, v in s.items() if k.startswith("drainsClosed")}
+    assert closed == {"drainsClosedIdle": 0, "drainsClosedFull": 0,
+                      "drainsClosedBurst": 0, reason: 1}
+    # an empty queue is no drain at all
+    if queued == drained:
+        assert pipeline._drain() is None
+        assert sum(v for k, v in pipeline.stats().items()
+                   if k.startswith("drainsClosed")) == 1
+
+
+def test_a_fetch_in_flight_keeps_the_drain_open():
+    """While the fetcher is busy the drain goes on gathering: what arrives
+    meanwhile rides the same batch, and it closes idle once the fetch ends."""
+    pipeline = DeviceQueryPipeline(mesh_exec=FakeMeshExec(), start=False)
+    _queued(pipeline, 1)
+    pipeline._fetch_busy.set()
+
+    def late():
+        time.sleep(0.03)
+        _queued(pipeline, 1)
+        time.sleep(0.03)
+        pipeline._fetch_busy.clear()
+    t = threading.Thread(target=late)
+    t.start()
+    batch = pipeline._drain()
+    t.join()
+    assert len(batch) == 2
+    assert pipeline.stats()["drainsClosedIdle"] == 1
+
+
+@pytest.mark.parametrize("n,ones", [(1, 1), (3, 0)])
+def test_batches_of_one_counts_drains_with_one_live_query(n, ones):
+    pipeline = DeviceQueryPipeline(mesh_exec=FakeMeshExec(), start=False)
+    try:
+        _submit_concurrently(
+            pipeline, [{"shape": "A", "literal": i} for i in range(n)])
+        s = pipeline.stats()
+        assert (s["batches"], s["dispatched"], s["batchesOfOne"]) == \
+            (1, n, ones)
+    finally:
+        pipeline.stop()
+
+
+def test_handoff_blocked_counts_a_put_that_met_a_full_fetch_queue():
+    """max_batch=1 closes every drain at once; with one slot in the fetch
+    queue and a slow fetch, the third launch finds the slot taken."""
+    fake = StatsMeshExec(fetch_latency=0.15)
+    pipeline = DeviceQueryPipeline(mesh_exec=fake, start=False, max_batch=1,
+                                   max_inflight=1)
+    try:
+        results = _submit_concurrently(
+            pipeline, [{"shape": "A", "literal": i} for i in range(3)])
+        s = pipeline.stats()
+        assert s["batches"] == 3 and s["drainsClosedFull"] == 3
+        assert s["handoffBlocked"] >= 1
+        # the blocked hand-off is time the queries of that batch waited
+        assert max(r.stats["deviceHandoffMs"] for r in results) >= 100.0
+    finally:
+        pipeline.stop()
+
+
+def test_what_a_launch_recorded_goes_to_the_queries_it_answers():
+    """The dispatcher folds what each launch recorded (the kernel cache, the
+    compile fence) into the items that launch answers, and no others."""
+    fake = StatsMeshExec()
+    fake.recorded = {("shape", "cold"): {"compileMs": 12.5,
+                                         "compileCacheMisses": 1,
+                                         "deviceLaunches": 1},
+                     ("shape", "warm"): {"compileCacheHits": 1,
+                                         "deviceLaunches": 1}}
+    pipeline = DeviceQueryPipeline(mesh_exec=fake, start=False)
+    try:
+        cold, warm = _submit_concurrently(
+            pipeline, [{"shape": "cold", "literal": 1},
+                       {"shape": "warm", "literal": 2}])
+        assert cold.stats["compileMs"] == 12.5
+        assert cold.stats["compileCacheMisses"] == 1
+        assert "compileCacheHits" not in cold.stats
+        assert warm.stats.get("compileMs", 0) == 0
+        assert warm.stats.get("compileCacheMisses", 0) == 0
+        assert warm.stats["compileCacheHits"] == 1
+        assert cold.stats["deviceLaunches"] == warm.stats["deviceLaunches"] == 1
+    finally:
+        pipeline.stop()
+
+
+def test_cold_kernel_says_so_in_its_own_response_not_its_neighbours(
+        tmp_path, ssb_schema):
+    """Real executor, CPU mesh: two queries ride one drain, one of a shape
+    the process has compiled, one of a shape it has not. The cold one's
+    response carries compileCacheMisses >= 1 and compileMs > 0; its
+    neighbour's carries 0 of both."""
+    cluster = QuickCluster(num_servers=1, work_dir=str(tmp_path))
+    cluster.servers[0].device_pipeline = warm_up = DeviceQueryPipeline()
+    rng = np.random.default_rng(26)
+    cfg = TableConfig(ssb_schema.name)
+    cluster.create_table(ssb_schema, cfg)
+    cluster.ingest_columns(cfg, make_ssb_columns(rng, 1500))
+    warm_sql = ("SELECT COUNT(*), SUM(lo_revenue) FROM lineorder "
+                "WHERE lo_quantity >= 7")
+    cold_sql = ("SELECT lo_quantity, MAX(lo_revenue), MIN(lo_revenue) "
+                "FROM lineorder WHERE lo_quantity < 31 GROUP BY lo_quantity")
+    try:
+        first = cluster.query(warm_sql)
+        assert first.stats["deviceLaunches"] >= 1
+    finally:
+        warm_up.stop()
+    pipeline = DeviceQueryPipeline(start=False)
+    cluster.servers[0].device_pipeline = pipeline
+    try:
+        results = {}
+
+        def run(sql):
+            results[sql] = cluster.query(sql)
+        threads = [threading.Thread(target=run, args=(sql,))
+                   for sql in (warm_sql, cold_sql)]
+        for t in threads:
+            t.start()
+        deadline = time.time() + 10
+        while pipeline._q.qsize() < 2 and time.time() < deadline:
+            time.sleep(0.01)
+        pipeline.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert pipeline.stats()["batches"] == 1
+        cold, warm = results[cold_sql].stats, results[warm_sql].stats
+        assert cold["compileCacheMisses"] >= 1 and cold["compileMs"] > 0
+        assert warm["compileCacheMisses"] == 0 and warm["compileMs"] == 0
+        assert warm["compileCacheHits"] >= 1
+        for s in (cold, warm):
+            assert s["deviceBatchSize"] == 2 and s["deviceLaunches"] >= 1
+            for key in PHASE_KEYS + ("serverTimeMs",):
+                assert key in s, key
+            assert sum(s[k] for k in PHASE_KEYS) <= s["serverTimeMs"]
+    finally:
+        pipeline.stop()

@@ -1,0 +1,223 @@
+"""benchmark/harness/program_trace.py and the ten per-layer metrics PR 26
+added, on a fixture cut from that PR's own chip trace
+(benchmark/fixtures/pr26-quarter-slice.*): the readers give the numbers
+recorded with it, and None where the program wrote nothing for them to read
+(a CPU trace with no TPU plane; PR 25's trace, taken before the program had
+spans, scopes or the response fields)."""
+
+import json
+import os
+
+import pytest
+
+from benchmark.harness import cells, program_trace, trace_reduce
+
+FIXTURES = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark", "fixtures")
+SLICE = os.path.join(FIXTURES, "pr26-quarter-slice.xplane.pb")
+PARENT = os.path.join(FIXTURES, "quarter-flights.xplane.pb")   # PR 25's
+NEW_METRICS = (
+    "pipeline.prepare_launch_ms", "pipeline.handoff_ms", "pipeline.decode_ms",
+    "server.host_ms", "broker.scatter_overhead_ms",
+    "pipeline.singleton_batch_share", "device.idle_host_busy_share",
+    "device.idle_starved_share", "kernels.decode_share",
+    "kernels.groupby_share")
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    with open(os.path.join(FIXTURES, "pr26-quarter-slice.json")) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def sliced():
+    return program_trace.reduce(SLICE)
+
+
+def _ctx(responses, counters, program):
+    return {"records": [{"response": r, "latency_ms": 1.0, "pool": 0}
+                        for r in responses],
+            "templates": ["q"], "counters": counters, "trace": {}, "solo": [],
+            "peaks": None, "program_trace": program}
+
+
+@pytest.mark.parametrize("name", NEW_METRICS)
+def test_reader_gives_the_number_recorded_with_the_fixture(
+        name, recorded, sliced):
+    ctx = _ctx(recorded["responses"], recorded["counters"], sliced)
+    got = cells.load_reader(name)(ctx)
+    assert got == pytest.approx(recorded["metrics"][name], rel=1e-9, abs=1e-9)
+
+
+@pytest.mark.parametrize("name", NEW_METRICS)
+def test_reader_returns_none_on_the_parents_run(name):
+    """PR 25's program: no `pinot.*` scope, no `pinot:*` span, none of the
+    response fields, no `batchesOfOne` counter. Nothing raises; the harness
+    leaves the metric out."""
+    parent = program_trace.reduce(PARENT)
+    assert parent is not None and parent["busy"] and not parent["spans"]
+    responses = [{"timeUsedMs": 600.0, "queueWaitMs": 250.0,
+                  "deviceFetchMs": 320.0,
+                  "phaseTimesMs": {"compile": 2.0, "scatter": 595.0,
+                                   "reduce": 1.0}}]
+    ctx = _ctx(responses, {"batches": 10, "dispatched": 20}, parent)
+    assert cells.load_reader(name)(ctx) is None
+
+
+@pytest.mark.parametrize("name", NEW_METRICS)
+def test_every_new_metric_is_declared_like_the_old(name):
+    """A `.json` with the keys of PR 25's, a `per_layer` entry that says the
+    same, and no `workloads` key: every cell owes it."""
+    meta = cells.read_json(cells.BENCH, "metrics", name + ".json")
+    entry = [m for m in cells.read_json(cells.ROOT, "BENCHMARK.json")
+             ["per_layer"] if m["name"] == name]
+    assert len(entry) == 1 and "workloads" not in entry[0]
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert entry[0][key] == meta[key], key
+    assert meta["name"] == name and meta["what"]
+    assert meta["moves"] in ("qps", "mean_ms")
+
+
+def test_walker_agrees_with_profile_data_on_the_device(recorded, sliced):
+    """Two readers of one file: the wire-format walker's slice and busy time
+    are trace_reduce's (jax.profiler.ProfileData), to the nanosecond's
+    rounding."""
+    by_jax = trace_reduce.reduce(SLICE)
+    assert (sliced["hi"] - sliced["lo"]) / 1e9 == pytest.approx(
+        by_jax["window_s"], abs=1e-6)
+    busy_s = program_trace.length(sliced["busy"]) / 1e9
+    assert busy_s == pytest.approx(by_jax["busy_s"], abs=1e-5)
+    assert busy_s == pytest.approx(recorded["busy_s"], abs=1e-9)
+    assert by_jax["idle_share"] == pytest.approx(recorded["idle_share"])
+
+
+def test_slice_holds_what_the_program_named(recorded, sliced):
+    assert sliced["modules"] == recorded["modules"]
+    assert all(name.startswith("jit_pinot_") for name in sliced["modules"])
+    assert {k: len(v) for k, v in sliced["spans"].items()} == \
+        recorded["span_counts"]
+    scoped = trace_reduce.union([(s, e) for s, e, scope in sliced["ops"]
+                                 if scope])
+    share = 100 * program_trace.length(scoped) \
+        / program_trace.length(sliced["busy"])
+    assert share == pytest.approx(recorded["scoped_share"])
+    assert share >= 95.0
+    # a request's spans carry its trace id; the pipeline's own carry sizes
+    for name in ("pinot:broker.scatter", "pinot:server.execute",
+                 "pinot:pipeline.prepare"):
+        assert all(len(stats.get("trace_id", "")) == 16
+                   for _, _, stats, _ in sliced["spans"][name]), name
+    for _, _, stats, _ in sliced["spans"]["pinot:pipeline.fetch"]:
+        assert stats["batch"] >= 1 and stats["launches"] >= 1
+    # dispatcher and fetcher are two threads: two lines of the host plane
+    lines = {name: {line for *_, line in sliced["spans"][name]}
+             for name in ("pinot:pipeline.launch", "pinot:pipeline.fetch")}
+    assert all(len(v) == 1 for v in lines.values())
+
+
+def test_family_time_is_a_union_not_a_sum(sliced):
+    """A `while` and the fusions inside it are both events of the "XLA Ops"
+    line: summed, the operations pass the busy time; united they are it, and
+    no family can pass it."""
+    ops = [(s, e) for s, e, _ in sliced["ops"]]
+    busy = program_trace.length(sliced["busy"])
+    assert sum(e - s for s, e in ops) > 1.2 * busy
+    assert program_trace.length(trace_reduce.union(ops)) == \
+        pytest.approx(busy)
+    groupby = [(s, e) for s, e, scope in sliced["ops"]
+               if scope.startswith("pinot.groupby.")]
+    assert 0 < program_trace.length(trace_reduce.union(groupby)) <= busy
+
+
+def test_scope_of_takes_the_outermost_pinot_scope():
+    scope_of = program_trace.scope_of
+    assert scope_of("jit(pinot_groupby_fused)/jit(shmap_body)/pinot.decode/"
+                    "jit(take_along_axis)/gather:") == "pinot.decode"
+    assert scope_of("jit(pinot_groupby)/pinot.groupby.partitioned/"
+                    "pinot.groupby.partitioned.sort/sort:") == \
+        "pinot.groupby.partitioned"
+    assert scope_of("jit(pinot_distinct)/pinot.distinct/"
+                    "pinot.groupby.sorted.sort/sort") == "pinot.distinct"
+    assert scope_of("jit(shard_body)/jit(take_along_axis)/gather:") == ""
+    assert scope_of(None) == "" and scope_of("reduce_window_sum:") == ""
+
+
+def _program(busy, spans, lo=0.0, hi=100.0, ops=None):
+    return {"lo": lo, "hi": hi, "busy": busy,
+            "ops": ops if ops is not None
+            else [(s, e, "pinot.agg") for s, e in busy],
+            "modules": {}, "spans": {k: [(s, e, {}, "t") for s, e in v]
+                                     for k, v in spans.items()}}
+
+
+def test_idle_shares_on_a_slice_made_by_hand():
+    """100 ns: the device idle in [10, 30) and [60, 100). prepare open in
+    [5, 15) and decode in [25, 28): 8 idle ns of host work. wait open in
+    [20, 30) and [60, 90), a fetch open in [70, 80): 30 ns starved."""
+    program = _program(
+        busy=[[0.0, 10.0], [30.0, 60.0]],
+        spans={"pinot:pipeline.prepare": [(5.0, 15.0)],
+               "pinot:pipeline.decode": [(25.0, 28.0)],
+               "pinot:pipeline.wait": [(20.0, 30.0), (60.0, 90.0)],
+               "pinot:pipeline.fetch": [(70.0, 80.0)]})
+    ctx = _ctx([], {}, program)
+    assert cells.load_reader("device.idle_host_busy_share")(ctx) == \
+        pytest.approx(8.0)
+    assert cells.load_reader("device.idle_starved_share")(ctx) == \
+        pytest.approx(30.0)
+    # a program that has scopes but none of a family reads a true zero
+    assert cells.load_reader("kernels.decode_share")(ctx) == 0.0
+    assert cells.load_reader("kernels.groupby_share")(ctx) == 0.0
+
+
+def test_interval_helpers():
+    a, b = [[0, 10], [20, 30]], [[5, 25]]
+    assert program_trace.intersect(a, b) == [[5, 10], [20, 25]]
+    assert program_trace.complement(a, 0, 40) == [[10, 20], [30, 40]]
+    assert program_trace.complement([], 0, 5) == [[0, 5]]
+    assert program_trace.length(a) == 20
+
+
+def test_no_device_plane_means_nothing_to_read(tmp_path):
+    """A CPU rehearsal's trace has host planes only: `reduce` gives None and
+    so does every reader that needs the device."""
+    import jax
+    import jax.numpy as jnp
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    with jax.profiler.TraceAnnotation("bench:window"):
+        jnp.arange(8).sum().block_until_ready()
+    jax.profiler.stop_trace()
+    path = trace_reduce.newest_xplane(str(tmp_path))
+    assert any(p["name"].startswith("/host:CPU")
+               for p in program_trace.load(path))
+    assert program_trace.reduce(path) is None
+    ctx = _ctx([], {}, None)
+    for name in ("device.idle_host_busy_share", "device.idle_starved_share",
+                 "kernels.decode_share", "kernels.groupby_share"):
+        assert cells.load_reader(name)(ctx) is None
+
+
+def test_slice_of_finds_the_runs_one_profile_directory(tmp_path, monkeypatch):
+    """`ctx` has no path: the slice is the newest .xplane.pb under the one
+    `.bench_work/<cell>/profile` the run keeps; none or two, nothing is read;
+    and one run parses the file once."""
+    import shutil
+    monkeypatch.setattr(program_trace, "ROOT", str(tmp_path))
+    ctx = _ctx([], {}, None)
+    del ctx["program_trace"]
+    ctx["trace"] = {"busy_s": 1.0}
+    assert program_trace.slice_of(ctx) is None      # no directory at all
+    prof = tmp_path / ".bench_work" / "a-cell" / "profile" / "plugins" \
+        / "profile" / "2026_09_30"
+    prof.mkdir(parents=True)
+    shutil.copy(SLICE, prof / "host.xplane.pb")
+    del ctx["program_trace"]
+    got = program_trace.slice_of(ctx)
+    assert got is not None and got["modules"]
+    assert program_trace.slice_of(ctx) is got        # kept on ctx
+    (tmp_path / ".bench_work" / "b-cell" / "profile").mkdir(parents=True)
+    del ctx["program_trace"]
+    assert program_trace.slice_of(ctx) is None       # two cells: ambiguous

@@ -12,6 +12,7 @@ import json
 import random
 import re
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -20,6 +21,7 @@ from pinot_tpu.cluster import QuickCluster
 from pinot_tpu.query.scheduler import QueryScheduler
 from pinot_tpu.schema import DataType, Schema, dimension, metric
 from pinot_tpu.table import TableConfig
+from pinot_tpu.utils import trace as tracing
 from pinot_tpu.utils.trace import (Trace, TraceRing, TraceSampler,
                                    request_trace, span, to_chrome_trace)
 
@@ -417,3 +419,190 @@ def test_query_report_renders_exported_traces(http_traced, capsys):
     chrome = _trace_entries(get_json(f"{url}/debug/traces?format=chrome"))
     assert chrome and any("serialize" in s["name"]
                           for e in chrome for s in e["spans"])
+
+
+# -- the second sink: the profiler's clock (PR 26) ----------------------------
+
+def _profiled(tmp_path, body):
+    """Run `body()` under a profiler session; the planes of its .xplane.pb as
+    benchmark/harness/program_trace.py reads them."""
+    import jax
+    from benchmark.harness import program_trace, trace_reduce
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    return program_trace.load(trace_reduce.newest_xplane(str(tmp_path)))
+
+
+def _host_events(planes, prefix="pinot:"):
+    return {e[0]: e for p in planes if p["name"].startswith("/host:CPU")
+            for ln in p["lines"] for e in ln["events"]
+            if e[0].startswith(prefix)}
+
+
+def test_stage_times_its_body_and_annotates_the_profile(tmp_path):
+    """`stage()` is the span of a thread with no request Trace: the caller
+    reads its milliseconds, and under a profiler session it is an event
+    `pinot:<name>` carrying its attributes, those noted afterwards too."""
+    def body():
+        with tracing.stage("pipeline.fetch", batch=3) as st:
+            time.sleep(0.02)
+            st.note(launches=2)
+        assert 15.0 < st.ms < 2000.0
+    events = _host_events(_profiled(tmp_path, body))
+    name, _start, dur_ns, stats = events["pinot:pipeline.fetch"]
+    assert dur_ns >= 15e6
+    assert stats == {"batch": 3, "launches": 2}
+
+
+def test_stage_without_a_profiler_session_only_times():
+    with tracing.stage("pipeline.wait") as st:
+        time.sleep(0.005)
+    assert st.ms >= 4.0
+    assert tracing.current_trace() is None      # and it opened no Trace
+
+
+def test_span_lands_in_both_sinks_with_the_trace_id(tmp_path):
+    """One name, one identifier, two sinks: the request Trace's row and the
+    profiler's `pinot:<name>` event with the request's trace_id."""
+    tr = tracing.Trace("r1")
+
+    def body():
+        with tr.activate():
+            with tracing.span("broker.compile"):
+                with tracing.span("inner"):
+                    time.sleep(0.002)
+        with tracing.span("orphan"):        # no Trace: annotation only
+            pass
+    events = _host_events(_profiled(tmp_path, body))
+    assert [(s["name"], s["depth"]) for s in tr.to_rows()] == [
+        ("broker.compile", 0), ("inner", 1)]
+    assert events["pinot:broker.compile"][3] == {"trace_id": tr.trace_id}
+    assert events["pinot:inner"][3] == {"trace_id": tr.trace_id}
+    assert events["pinot:orphan"][3] == {}
+    outer, inner = events["pinot:broker.compile"], events["pinot:inner"]
+    assert outer[1] <= inner[1] and inner[1] + inner[2] <= outer[1] + outer[2]
+
+
+def test_served_query_spans_share_the_trace_id_in_the_profile(
+        inproc_traced, tmp_path):
+    """A served query under a profiler session: broker.compile / scatter /
+    reduce and server.execute land on the profiler's clock with the trace id
+    the response carries."""
+    out = {}
+
+    def body():
+        out["res"] = inproc_traced.query(
+            "SELECT site, SUM(v) FROM ev GROUP BY site")
+    planes = _profiled(tmp_path, body)
+    trace_id = out["res"].stats["traceId"]
+    events = [e for p in planes if p["name"].startswith("/host:CPU")
+              for ln in p["lines"] for e in ln["events"]
+              if e[3].get("trace_id") == trace_id]
+    names = {e[0] for e in events}
+    assert {"pinot:broker.compile", "pinot:broker.scatter",
+            "pinot:broker.reduce", "pinot:server.execute"} <= names, names
+
+
+# -- names on the device: scopes in the lowered program (PR 26) ----------------
+
+@pytest.fixture(scope="module")
+def scope_segment(tmp_path_factory):
+    """6000 keys (padded 8192): every GROUP BY regime is a choice of caps. `d`
+    is a dictionary-encoded metric (the fused in-register decode), `w` a raw
+    one (a compare leaf for the filter)."""
+    import numpy as np
+
+    from pinot_tpu.schema import DataType, Schema, dimension, metric
+    from pinot_tpu.segment.reader import load_segment
+    from pinot_tpu.segment.writer import (SegmentBuilder,
+                                          SegmentGeneratorConfig)
+    rng = np.random.default_rng(26)
+    rows = 8000
+    schema = Schema("scopes", [dimension("k", DataType.INT),
+                               dimension("d", DataType.INT),
+                               metric("w", DataType.INT)])
+    cols = {"k": rng.integers(0, 6000, rows).astype(np.int32),
+            "d": rng.integers(0, 40, rows).astype(np.int32) * 7,
+            "w": rng.integers(-1000, 1000, rows).astype(np.int32)}
+    out = tmp_path_factory.mktemp("scopes")
+    builder = SegmentBuilder(schema, SegmentGeneratorConfig(
+        no_dictionary_columns=["w"]))
+    return load_segment(builder.build(cols, str(out), "scopes_0"))
+
+
+def _lowered_text(seg, sql):
+    """The served path's program for `sql`, lowered with debug info: the
+    name stack of every operation (what becomes `tf_op` in a device trace)."""
+    from pinot_tpu.parallel.combine import MeshQueryExecutor
+    from pinot_tpu.query.context import compile_query
+    mex = MeshQueryExecutor()
+    p = mex.prepare_partial(compile_query(sql, seg.schema), [seg])
+    assert p is not None
+    kern = mex._get_shard_kernel(p.spec, p.s_pad, p.rows)
+    jitted = kern.__wrapped__.jitted_for(p.inputs)
+    return p, jitted.lower(p.inputs).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("regime,caps", [
+    ("onehot", dict(matmul_cap=16384)),
+    ("chunk64", dict()),
+    ("scatter", dict(chunk_cap=4096, high_card_regime="scatter")),
+    ("sorted", dict(chunk_cap=4096, high_card_regime="sorted")),
+    ("partitioned", dict(chunk_cap=4096, high_card_regime="partitioned")),
+])
+def test_groupby_regime_scope_in_lowered_program(scope_segment, regime, caps):
+    """Each GROUP BY regime's program names its stages: its own
+    `pinot.groupby.<regime>` scope, the decode and the filter; and the jitted
+    function is named for what it is, not `shard_body`."""
+    from pinot_tpu.engine.calibrate import KernelCaps, get_caps, set_caps
+    prev = get_caps()
+    set_caps(KernelCaps(**caps))
+    try:
+        p, text = _lowered_text(
+            scope_segment,
+            "SELECT k, COUNT(*), SUM(d) FROM scopes WHERE w > 0 GROUP BY k")
+    finally:
+        set_caps(prev)
+    assert p.spec.fused_cols, "SUM(d) should decode the dictionary in-register"
+    for scope in (f"pinot.groupby.{regime}", "pinot.groupby.key",
+                  "pinot.decode", "pinot.filter"):
+        assert scope in text, scope
+    others = {"onehot", "chunk64", "scatter", "sorted", "partitioned"}
+    for other in others - {regime}:
+        assert f"pinot.groupby.{other}" not in text, other
+    if regime in ("sorted", "partitioned"):
+        for part in ("sort", "scan", "trim"):
+            assert f"pinot.groupby.{regime}.{part}" in text, part
+    assert "module @jit_pinot_groupby_fused " in text
+    assert "jit(pinot_groupby_fused)/" in text     # the head of every tf_op
+
+
+@pytest.mark.parametrize("sql,name,scopes", [
+    ("SELECT COUNT(*), SUM(d) FROM scopes WHERE w > 0",
+     "pinot_agg_fused", ("pinot.agg", "pinot.decode", "pinot.filter")),
+    ("SELECT DISTINCTCOUNT(d) FROM scopes WHERE w > 0",
+     "pinot_distinct", ("pinot.distinct", "pinot.filter")),
+])
+def test_scalar_kernels_named_and_scoped(scope_segment, sql, name, scopes):
+    p, text = _lowered_text(scope_segment, sql)
+    from pinot_tpu.engine.kernels import kernel_name
+    assert kernel_name(p.spec) == name
+    assert f"module @jit_{name} " in text
+    for scope in scopes:
+        assert scope in text, scope
+
+
+def test_kernel_names_do_not_change_cache_keys(scope_segment):
+    """A name only: two specs that differ in nothing but what the name is made
+    from still differ in `signature()`, and the name is not part of it."""
+    from pinot_tpu.engine.kernels import kernel_name
+    p, _ = _lowered_text(scope_segment,
+                         "SELECT k, COUNT(*) FROM scopes GROUP BY k")
+    assert kernel_name(p.spec) == "pinot_groupby"
+    assert kernel_name(p.spec, batch=4) == "pinot_groupby_b4"
+    assert not any("pinot" in str(part) for part in p.spec.signature())
