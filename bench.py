@@ -1723,9 +1723,8 @@ def platform_calibration():
 
     scan_dt = timed(scan_chain, *cols4)
     scan_gbps = round(16 * n / scan_dt / 1e9, 1)
-    # persist THE roofline denominator: serving-side rooflinePct
-    # (kernels.roofline_hbm_gbps) and every bench pct divide by this same
-    # measured figure — the one-number fix for the 464.8% self-inconsistency
+    # persist THE roofline denominator (kernels.roofline_hbm_gbps): every
+    # bench pct divides by this same measured figure
     try:
         _caps_mod.save_measured_hbm_gbps(scan_gbps)
     except (ValueError, OSError) as e:

@@ -26,7 +26,7 @@ import glob
 import os
 import struct
 
-from . import trace_reduce
+from . import readers, trace_reduce
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -300,16 +300,17 @@ def idle_share_while(ctx, open_names, closed_names=()) -> float | None:
     return 100.0 * length(held) / (t["hi"] - t["lo"])
 
 
-def mean_of(ctx, pick) -> float | None:
-    """Mean of `pick(response)` over the window's answers, leaving out those
-    for which it gives None (a program that does not report the field)."""
-    vals = [v for v in (pick(r["response"]) for r in ctx["records"]
-                        if r.get("response")) if v is not None]
-    return sum(vals) / len(vals) if vals else None
-
-
 def fields(resp, *names):
-    """The named response fields as floats, or None if any is absent."""
+    """The named response fields as floats, or None if any is absent (a
+    program that does not report it)."""
     if any(n not in resp for n in names):
         return None
     return [float(resp[n]) for n in names]
+
+
+def mean_sum(ctx, *names) -> float | None:
+    """Mean over the window's answers of the sum of the named fields."""
+    def pick(resp):
+        v = fields(resp, *names)
+        return sum(v) if v else None
+    return readers._mean_of(ctx, pick)

@@ -5,7 +5,4 @@ from benchmark.harness import program_trace as pt
 
 
 def read(ctx):
-    def pick(resp):
-        v = pt.fields(resp, "deviceHandoffMs")
-        return v[0] if v else None
-    return pt.mean_of(ctx, pick)
+    return pt.mean_sum(ctx, "deviceHandoffMs")

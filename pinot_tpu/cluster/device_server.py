@@ -239,10 +239,10 @@ class DeviceQueryPipeline:
                 s = getattr(result, "stats", None) or {}
                 at_ms = submit_ms
                 for key, name in _PHASES:
-                    ms = float(s.get(key) or 0.0)
-                    if ms or key in s:
+                    if key in s:
+                        ms = float(s[key] or 0.0)
                         tr.record(name, at_ms, ms, depth=depth)
-                    at_ms += ms
+                        at_ms += ms
             return result
         except FutureTimeoutError:
             # cancel so the dispatcher/fetcher SKIP the stale item instead of

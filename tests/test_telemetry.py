@@ -405,6 +405,11 @@ def test_stats_and_debug_over_http(tmp_path):
         # GET /debug: rollups + slow ring as JSON
         catalog.put_property("clusterConfig/broker.slow.query.ms", "0")
         try:
+            # the broker reads the key from its mirror of the catalog, which
+            # a long poll brings up to date: wait for it, or the query below
+            # races the poll and is not slow
+            assert wait_until(lambda: broker._slow_threshold_ms() == 0.0,
+                              timeout=15.0, interval=0.02, swallow=())
             bc.query("SELECT COUNT(*) FROM ev")
         finally:
             catalog.put_property("clusterConfig/broker.slow.query.ms", None)
