@@ -12,8 +12,8 @@ TensorFlow's, which takes 19 s to import and is not wanted in the process
 that owns the chip (my chip run, PR 26).
 
 `ctx` carries no path, so `slice_of(ctx)` finds the file where run.py's
-`trace_slice` left it: `<root>/.bench_work/<cell>/profile`, `<cell>` being the
-one directory run.py keeps there during a run. Every reader built on this
+`trace_slice` left it: the newest under `<root>/.bench_work/<cell>/profile`,
+`<cell>` being the directory run.py keeps there during a run. Every reader built on this
 returns None where there is no `/device:TPU` plane (a CPU rehearsal), no
 `bench:window` span, or no span or scope of the kind it reads (the parent of
 PR 26 has none).
@@ -222,16 +222,16 @@ def reduce(path: str) -> dict | None:
 
 def slice_of(ctx) -> dict | None:
     """`reduce()` of the run's traced slice, kept on `ctx` so that the
-    readers of one run parse the file once."""
+    readers of one run parse the file once. The file is the newest
+    `.xplane.pb` under a `.bench_work/<cell>/profile` (the solo replay's is
+    under `profile_solo`; a directory that an ended run left behind is older)."""
     if "program_trace" not in ctx:
-        ctx["program_trace"] = None
-        found = glob.glob(os.path.join(ROOT, ".bench_work", "*", "profile"))
-        if ctx.get("trace") and len(found) == 1:
-            try:
-                ctx["program_trace"] = reduce(
-                    trace_reduce.newest_xplane(found[0]))
-            except FileNotFoundError:
-                pass
+        found = glob.glob(os.path.join(
+            ROOT, ".bench_work", "*", "profile", "plugins", "profile", "*",
+            "*.xplane.pb"))
+        ctx["program_trace"] = (
+            reduce(max(found, key=os.path.getmtime))
+            if ctx.get("trace") and found else None)
     return ctx["program_trace"]
 
 

@@ -200,24 +200,32 @@ def test_no_device_plane_means_nothing_to_read(tmp_path):
         assert cells.load_reader(name)(ctx) is None
 
 
-def test_slice_of_finds_the_runs_one_profile_directory(tmp_path, monkeypatch):
-    """`ctx` has no path: the slice is the newest .xplane.pb under the one
-    `.bench_work/<cell>/profile` the run keeps; none or two, nothing is read;
-    and one run parses the file once."""
+def test_slice_of_finds_the_runs_profile(tmp_path, monkeypatch):
+    """`ctx` has no path: the slice is the newest .xplane.pb under a
+    `.bench_work/<cell>/profile`; the solo replay's `profile_solo` and what
+    an ended run left behind do not count; one run parses the file once."""
     import shutil
+    import time
     monkeypatch.setattr(program_trace, "ROOT", str(tmp_path))
-    ctx = _ctx([], {}, None)
-    del ctx["program_trace"]
-    ctx["trace"] = {"busy_s": 1.0}
-    assert program_trace.slice_of(ctx) is None      # no directory at all
-    prof = tmp_path / ".bench_work" / "a-cell" / "profile" / "plugins" \
-        / "profile" / "2026_09_30"
-    prof.mkdir(parents=True)
-    shutil.copy(SLICE, prof / "host.xplane.pb")
-    del ctx["program_trace"]
+
+    def fresh():
+        return {"records": [], "counters": {}, "trace": {"busy_s": 1.0}}
+
+    def put(cell, kind, src):
+        d = tmp_path / ".bench_work" / cell / kind / "plugins" / "profile" \
+            / "2026_09_30"
+        d.mkdir(parents=True)
+        shutil.copy(src, d / "host.xplane.pb")
+        return d / "host.xplane.pb"
+
+    assert program_trace.slice_of(fresh()) is None      # no directory at all
+    stale = put("an-ended-cell", "profile", PARENT)
+    os.utime(stale, (time.time() - 3600, time.time() - 3600))
+    put("a-cell", "profile", SLICE)
+    put("a-cell", "profile_solo", PARENT)               # newer, not the slice
+    ctx = fresh()
     got = program_trace.slice_of(ctx)
-    assert got is not None and got["modules"]
-    assert program_trace.slice_of(ctx) is got        # kept on ctx
-    (tmp_path / ".bench_work" / "b-cell" / "profile").mkdir(parents=True)
-    del ctx["program_trace"]
-    assert program_trace.slice_of(ctx) is None       # two cells: ambiguous
+    assert got is not None and got["spans"] and got["modules"]
+    assert program_trace.slice_of(ctx) is got            # kept on ctx
+    untraced = dict(fresh(), trace=None)
+    assert program_trace.slice_of(untraced) is None
