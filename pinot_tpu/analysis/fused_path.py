@@ -2,10 +2,12 @@
 fused kernel modules.
 
 The fused single-launch plan (PR 16) keeps value columns in their compressed
-resident forms — dict ids gathered through an in-register LUT
-(`take_along_axis` on the VMEM-resident table), FOR deltas re-based in the
-kernel body — so filter+aggregate never writes a decoded full-width column
-back to HBM. What silently regresses it is a "convenience" decode inside the
+resident forms — dict ids decoded through the column's table inside the
+kernel (selects over a small table; `take_along_axis` over a wide one, which
+on the v5e still writes the decoded column from a pass of its own: PERF.md,
+PR 27), FOR deltas re-based in the kernel body — so filter+aggregate keeps no
+decoded full-width column resident in HBM. What silently regresses it is a
+"convenience" decode inside the
 kernel builders: a `jnp.take`/`np.take` dict-LUT gather that materializes the
 whole column, or a call back into the staged decode surface
 (`block.values(...)` / `block.decoded(...)`) from code that is supposed to

@@ -66,10 +66,12 @@ DEVICE_FALLBACK = _Sentinel()
 _STAGES = ("queue_wait", "dispatch", "prepare", "launch", "handoff", "fetch",
            "decode")
 
-#: what the kernel cache and the first-call fence record on the dispatcher
-#: thread, folded from a scratch record into the items a launch answers
+#: what the kernel cache, the first-call fence and the executor's launch
+#: accounting record on the dispatcher thread, folded from a scratch record
+#: into the items a launch answers
 _LAUNCH_KEYS = (qstats.COMPILE_MS, qstats.COMPILE_CACHE_MISSES,
-                qstats.COMPILE_CACHE_HITS, qstats.DEVICE_LAUNCHES)
+                qstats.COMPILE_CACHE_HITS, qstats.DEVICE_LAUNCHES,
+                qstats.GATHER_FREE_LAUNCHES)
 
 #: the pipeline's per-query phases in the order a query passes them: the
 #: item.stats key of each and the request-Trace span `execute_partial` rebuilds

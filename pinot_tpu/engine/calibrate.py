@@ -408,10 +408,16 @@ def calibrate(rows: Optional[int] = None,
 
     # fused-vs-staged probe: masked sum with an in-kernel dict decode (LUT
     # gather) vs the same sum over a pre-decoded column. Fusion also saves a
-    # dispatch and the decoded HBM write, so the gather form gets 2x slack
-    # before the ladder falls back to staged (a platform whose device
-    # gathers are pathologically slow is the case this probe exists to
-    # catch).
+    # dispatch and the decoded column's residency, so the gather form gets
+    # 2x slack before the ladder falls back to staged (a platform whose
+    # device gathers are pathologically slow is the case this probe exists
+    # to catch). It times a 4,096-entry table, i.e. the gather that tables
+    # over `kernels.SELECT_DECODE_CAP` keep, and has never run on the v5e:
+    # there that gather is a pass of its own at ~100M rows/s (PR 26's
+    # traces; 208 ms at 16Mi rows against 0.7 ms for the decoded column, my
+    # chip run, PR 27), so this probe would read "staged" for every fused
+    # plan, the select-decoded small tables included. Re-aiming it is
+    # ROADMAP D5's.
     fused_enabled = defaults.fused_enabled
     try:
         import jax

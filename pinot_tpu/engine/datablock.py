@@ -143,7 +143,9 @@ class SegmentBlock:
     def dict_values(self, col: str) -> jnp.ndarray:
         """Decode table: dictionary values padded to `lut_size(card)` (invalid id -> 0).
 
-        Numeric dict decode on device is `dict_values(col)[ids(col)]` — one gather.
+        Numeric dict decode on device is `dict_values(col)[ids(col)]`: a tree
+        of selects for a small table, one gather for a wide one
+        (`kernels._fused_env`).
         """
         if col not in self._dict_vals:
             reader = self.segment.column(col)

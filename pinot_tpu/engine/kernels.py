@@ -14,7 +14,9 @@ matmuls —
 
 * dict predicates -> id-interval compares (sorted dictionaries make EQ/RANGE/small-IN
   contiguous id runs, resolved host-side at plan time);
-* dict decode -> host-materialized value columns cached in HBM (`datablock.values`);
+* dict decode -> fused plans: a tree of selects over a small decode table
+  (SELECT_DECODE_CAP; the one gather left is a table wider than that); staged
+  plans: host-materialized value columns cached in HBM (`datablock.values`);
 * group-by partials -> one-hot matmul `[rows, N] @ [N, keys]` up to MATMUL_KEY_CAP
   (the common OLAP case; XLA fuses the iota-compare into the dot's tiles), the
   CHUNKED 64x64-tile matmul `_grouped_chunk64` from there to CHUNK_KEY_CAP
@@ -84,6 +86,26 @@ PRESENCE_MATMUL_CAP = 8192   # _presence_2d chunked presence counts
 # linear in keys (~2.1ms per 4096-key chunk per bf16 part per 16M rows) while
 # a jax.lax.sort of 16M keys+payload is ~67ms flat — crossover near 128k keys.
 CHUNK_KEY_CAP = 131072
+# A fused dict column whose padded decode table has at most this many entries
+# (`W`, the static last dimension of the table: a shape, so the choice is made
+# at trace time from the input) is decoded by a balanced tree of selects over
+# the table's entries (`_decode_select`), which fuses into the consuming scan;
+# a wider table keeps the gather (`_decode_gather`), which the v5e runs as a
+# fusion of its own that writes the whole decoded column at ~100M rows/s
+# whatever `W`. Measured on the v5e (PERF.md, PR 27, chip call c1: a
+# Q1.1-shaped scan of four int32 columns, solo; ms at 16Mi / 64Mi rows):
+#      W    gather       select tree    the tree's compile, s (gather's: ~1)
+#     16    210 / 665    0.6 /  1.7      0.3 /  0.4
+#     64    210 / 665    1.0 /  1.8      0.9 /  1.0
+#    256    208 / 789    3.6 /  6.7      8.5 / 13.4
+#   1024    208 / 789   15.2 / 27.7     30.4 / 43.8
+# The run times do not cross by 1024 (extrapolated: near W = 24,000). What
+# bounds the cap is the unrolled tree's compile: at 1024 it is longer than any
+# program this server compiles today (the 64Mi-row sorts, 22-37 s) and a cold
+# shape stalls the serial device pipeline that long; 256 stays under them.
+# A constant of the program, not a calibrated `KernelCaps` field: it is not
+# runtime-tunable, so it has no place in `signature()`.
+SELECT_DECODE_CAP = 256
 
 
 @dataclass
@@ -109,7 +131,10 @@ class KernelSpec:
     # value columns the kernel decodes from their COMPRESSED resident form
     # in-register instead of reading a decoded HBM column: (col, form) pairs,
     # form "dict" (vals[col] is the padded decode table, ids[col] the dict
-    # ids — the gather fuses into the scan, nothing is materialized) or "for"
+    # ids; `_fused_env` decodes a table of at most SELECT_DECODE_CAP entries
+    # by selects inside the scan's fusion, so nothing is materialized, and a
+    # wider one by a gather, which the v5e runs as a fusion of its own that
+    # writes the decoded column to HBM) or "for"
     # (vals[col] is a narrow unsigned delta column; the frame-of-reference
     # base rides the int scalar stream at `for_offset[col]`). Empty = the
     # staged layout (vals[col] is the decoded column), so the flag is part of
@@ -376,27 +401,72 @@ def _make_word_fn(spec: KernelSpec):
     return lambda bitmaps: tree_words(tree, bitmaps)
 
 
+def _decode_gather(lut, idx):
+    """Dictionary decode as one LUT gather over the ids: a 1-D table, or the
+    stacked mesh form's one table PER SEGMENT ([s, W] against [s, rows]). On
+    the v5e the gather does NOT fuse into the scan: it is a fusion of its own
+    whose output is the whole decoded column (PR 26's traces: `s32[16,16]` ->
+    `s32[67108864]` in 665 ms, about 100M rows a second at any `W`)."""
+    if lut.ndim == 2 and idx.ndim == 2:
+        return jnp.take_along_axis(lut, idx, axis=1)
+    return lut[idx]
+
+
+def _decode_select(lut, idx):
+    """The same decode with no gather, for a small table: a balanced tree of
+    selects over the table's `W` entries (a power of two, `lut_size`), level
+    `b` choosing between neighbours by bit `b` of the id — `W - 1` selects and
+    `log2 W` bit tests a row, all elementwise, so XLA fuses them into the
+    consuming reduction and the decoded column never exists in HBM. Entry `k`
+    is a scalar for a 1-D table and a per-segment column `[s, 1]` for the
+    stacked form. Exact: every id in `[0, W)` selects its own entry in the
+    table's dtype (the fill id `cardinality` < `W` reads the padded zero, as
+    the gather does); ids are never outside `[0, W)`."""
+    stacked = lut.ndim == 2
+    level = [lut[:, k][:, None] if stacked else lut[k]
+             for k in range(lut.shape[-1])]
+    bit = 0
+    while len(level) > 1:
+        odd = (idx & (1 << bit)) != 0
+        level = [jnp.where(odd, level[k + 1], level[k])
+                 for k in range(0, len(level), 2)]
+        bit += 1
+    return jnp.broadcast_to(level[0], idx.shape)
+
+
+def gather_free(spec: KernelSpec, vals) -> bool:
+    """Whether a launch of `spec` over the staged `vals` decodes compressed
+    forms in-kernel and none of them by a gather: every dict column's table is
+    small enough for the selects (what `gatherFreeLaunches` counts)."""
+    return bool(spec.fused_cols) and all(
+        vals[col].shape[-1] <= SELECT_DECODE_CAP
+        for col, form in spec.fused_cols if form == "dict")
+
+
 def _fused_env(spec: KernelSpec, ids, vals, iscal):
     """The expression env over COMPRESSED resident forms: for every fused
-    column, synthesize the decoded values in-register at trace time — a dict
-    column as one LUT gather over its ids (XLA fuses it into the scan tiles;
-    the decoded column never exists in HBM), a FOR column as delta + base.
-    Non-fused columns pass through (staged layout: already decoded). The
-    stacked mesh form carries one decode table PER SEGMENT ([s, W] sharded on
-    the segment axis, like every other per-segment operand)."""
+    column, synthesize the decoded values at trace time — a dict column from
+    its ids and its decode table, by selects where the table is small
+    (`_decode_select`: in-register, fused into the scan) and by a gather where
+    it is not (`_decode_gather`: a pass of its own that writes the decoded
+    column), a FOR column as delta + base. Non-fused columns pass through
+    (staged layout: already decoded). The stacked mesh form carries one decode
+    table PER SEGMENT ([s, W] sharded on the segment axis, like every other
+    per-segment operand)."""
     if not spec.fused_cols:
         return vals
     env = dict(vals)
-    with jax.named_scope("pinot.decode"):
-        for col, form in spec.fused_cols:
-            if form == "dict":
-                lut = vals[col]
-                idx = ids[col]
-                if lut.ndim == 2 and idx.ndim == 2:
-                    env[col] = jnp.take_along_axis(lut, idx, axis=1)
-                else:
-                    env[col] = lut[idx]
-            else:  # "for": narrow unsigned deltas + scalar-stream base
+    for col, form in spec.fused_cols:
+        if form == "dict":
+            lut, idx = vals[col], ids[col]
+            if lut.shape[-1] <= SELECT_DECODE_CAP:
+                with jax.named_scope("pinot.decode.select"):
+                    env[col] = _decode_select(lut, idx)
+            else:
+                with jax.named_scope("pinot.decode.gather"):
+                    env[col] = _decode_gather(lut, idx)
+        else:  # "for": narrow unsigned deltas + scalar-stream base
+            with jax.named_scope("pinot.decode"):
                 env[col] = (vals[col].astype(jnp.int32)
                             + iscal[spec.for_offset[col]])
     return env
@@ -1025,8 +1095,10 @@ def dispatch_kernel(spec: KernelSpec, inputs: KernelInputs):
 def run_kernel(spec: KernelSpec, inputs: KernelInputs) -> Dict[str, np.ndarray]:
     """Single-launch fused execution: filter + project + aggregate in ONE
     dispatch over the resident forms (compressed when `spec.fused_cols` routes
-    them — decode then happens in-register, never through HBM)."""
+    them — decode then happens inside the kernel: `_fused_env`)."""
     qstats.record(qstats.FUSED_LAUNCHES)
+    if gather_free(spec, inputs.vals):
+        qstats.record(qstats.GATHER_FREE_LAUNCHES)
     # device_get, never np.asarray: asarray syncs leaf by leaf, device_get
     # fetches the whole tree in one batched round trip
     return fetch_outputs(dispatch_kernel(spec, inputs))

@@ -37,7 +37,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..engine.datablock import lut_size, padded_rows
-from ..engine.kernels import KernelSpec, _fence_first_call, tree_bytes
+from ..engine.kernels import (KernelSpec, _fence_first_call, gather_free,
+                              tree_bytes)
 from ..query import stats as qstats
 from ..query.aggregates import make_agg
 from ..query.context import QueryContext, compile_query
@@ -120,6 +121,16 @@ class PreparedDispatch:
     fscal_np: Optional[np.ndarray] = None
     trim_keys: Tuple[int, int] = (0, 0)  # (num_keys_pad, num_keys_real) device trim
     launch: Any = None           # "topk": () -> outs_dev (pre-bound kernel)
+
+
+def _record_fused(p: PreparedDispatch) -> None:
+    """Count one launch of a spec that decodes compressed forms in-kernel, and
+    whether it does so with no gather (the table widths are shapes of its
+    staged inputs)."""
+    if p.spec.fused_cols:
+        qstats.record(qstats.FUSED_LAUNCHES)
+        if gather_free(p.spec, p.inputs["vals"]):
+            qstats.record(qstats.GATHER_FREE_LAUNCHES)
 
 
 class DocsetPlanDivergence(Exception):
@@ -304,11 +315,14 @@ class SegmentSetBlock:
         the segment axis like every other block array.
 
         Row i is segment i's OWN dictionary zero-padded to the set-wide max
-        lut_size, so the fused kernel's `take_along_axis` gather
-        (`kernels._fused_env`) decodes segment-local ids in-register and the
-        decoded [S_pad, rows] column never materializes in HBM. Aligned
-        sets only: merged views remap ids into the global dictionary space,
-        which a per-segment LUT stack cannot decode."""
+        lut_size, so the fused kernel (`kernels._fused_env`) decodes
+        segment-local ids itself: by selects fused into the scan where Lmax
+        is at most `kernels.SELECT_DECODE_CAP` (the decoded [S_pad, rows]
+        column never exists in HBM), by a `take_along_axis` gather where it
+        is wider — which the v5e runs as a pass of its own that DOES write
+        the decoded column (PR 26's traces), saving only its residency.
+        Aligned sets only: merged views remap ids into the global dictionary
+        space, which a per-segment LUT stack cannot decode."""
         key = ("dictlut", col)
         if key not in self._cache:
             from ..engine.datablock import _narrow, lut_size
@@ -726,8 +740,7 @@ class MeshQueryExecutor:
                         outs = p.launch()
                     else:
                         fn = self._get_shard_kernel(p.spec, p.s_pad, p.rows)
-                        if p.spec.fused_cols:
-                            qstats.record(qstats.FUSED_LAUNCHES)
+                        _record_fused(p)
                         outs = fn(p.inputs)
                     packed, unpack = self._pack(outs, p.trim_keys, batched=0)
                     finish = (lambda host, u=unpack: [u(host)])
@@ -757,9 +770,8 @@ class MeshQueryExecutor:
         inputs["fscal"] = self._const(fscal)
         fn = self._get_shard_kernel(ps[0].spec, ps[0].s_pad, ps[0].rows,
                                     batch=b_pad)
-        if ps[0].spec.fused_cols:
-            # one persistent launch carries every stacked query's fused scan
-            qstats.record(qstats.FUSED_LAUNCHES)
+        # one persistent launch carries every stacked query's fused scan
+        _record_fused(ps[0])
         return fn(inputs), b
 
     def _pack(self, outs_dev: Dict[str, jnp.ndarray], trim_keys: Tuple[int, int],
@@ -997,7 +1009,7 @@ class MeshQueryExecutor:
         iscal_np = np.asarray(iscal, dtype=np.int32)
         fscal_np = np.asarray(fscal, dtype=np.float32)
         # fused dict columns ship their per-segment LUT stack via vals and
-        # their id column via ids; the kernel gathers in-register, so the
+        # their id column via ids; the kernel decodes them itself, so the
         # decoded HBM column is never built for them
         fused = dict(fused_cols)
         for c in vals_cols:

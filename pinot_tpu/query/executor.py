@@ -348,7 +348,8 @@ class ServerQueryExecutor:
 
         # fused plans keep value columns in compressed resident form: a
         # "dict" column ships its padded decode table via vals plus the id
-        # column via ids (gathered in-register by _fused_env), a "for"
+        # column via ids (decoded by _fused_env: selects for a small table,
+        # a gather for a wide one), a "for"
         # column ships narrow deltas via vals with its base appended to
         # iscal AFTER every filter scalar, in fused_cols order — must
         # mirror KernelSpec.__post_init__'s for_offset routing exactly

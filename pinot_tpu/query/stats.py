@@ -72,6 +72,10 @@ STACKED_LAUNCHES = "stackedLaunches"
 # (mask kernel + aggregate kernel over decoded HBM columns)
 FUSED_LAUNCHES = "fusedLaunches"
 STAGED_LAUNCHES = "stagedLaunches"
+# of the launches that decode compressed forms in-kernel, those with no gather
+# in them: every dict column's decode table was small enough for the select
+# tree (kernels.SELECT_DECODE_CAP), so the decode fused into the scan (PR 27)
+GATHER_FREE_LAUNCHES = "gatherFreeLaunches"
 NUM_CONSUMING_SEGMENTS_QUERIED = "numConsumingSegmentsQueried"
 MIN_CONSUMING_FRESHNESS_TIME_MS = "minConsumingFreshnessTimeMs"
 MUX_FRAME_QUEUE_MS = "muxFrameQueueMs"
@@ -113,7 +117,7 @@ COUNTER_KEYS = (
     COMPILE_MS, DEVICE_EXEC_MS, DEVICE_FETCH_MS, BYTES_FETCHED,
     QUEUE_WAIT_MS, DEVICE_PREPARE_MS, DEVICE_LAUNCH_MS, DEVICE_HANDOFF_MS,
     DEVICE_DECODE_MS, DEDUPED_LAUNCHES, STACKED_LAUNCHES,
-    FUSED_LAUNCHES, STAGED_LAUNCHES,
+    FUSED_LAUNCHES, STAGED_LAUNCHES, GATHER_FREE_LAUNCHES,
     NUM_CONSUMING_SEGMENTS_QUERIED, MUX_FRAME_QUEUE_MS, MUX_FLOW_CONTROL_MS,
     COLLECTIVE_MS, HEDGED_REQUESTS, ADMISSION_DEFER_MS,
     SEGMENTS_SERVED_HOST_TIER, TIER_PROMOTIONS,

@@ -297,3 +297,46 @@ def test_pallas_scan_compiles_for_v5e(topo, variant):
         i32, i32, i32, f32, f32).compile()
     if variant == "pallas":
         assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("width,decode", [
+    (16, "select"),                                  # lo_discount's own table
+    (2 * kernels.SELECT_DECODE_CAP, "gather"),       # the same plan, wide table
+])
+def test_q11_small_table_decode_fuses_into_the_scan(topo, cpu_exec, segments,
+                                                    width, decode):
+    """The Q1.1-shaped fused scan at 16Mi rows, compiled for the v5e: with its
+    16-entry table the program holds no gather, names `pinot.decode.select`
+    and writes no rows-sized decoded column; over the cap it keeps the gather
+    (a fusion of its own whose output is the decoded column) under
+    `pinot.decode.gather`."""
+    p = _prepare(cpu_exec, segments, "q1.1 filter+sum")
+    assert dict(p.spec.fused_cols) == {"lo_discount": "dict"}
+    assert p.inputs["vals"]["lo_discount"].shape == (p.s_pad, 16)
+    mesh = _mesh(topo, 1)
+    ax = _abstract(p.inputs, (p.s_pad, p.rows), (MATMUL_SEGS, SEG_ROWS), mesh)
+    table = ax["vals"]["lo_discount"]
+    ax["vals"]["lo_discount"] = jax.ShapeDtypeStruct(
+        (MATMUL_SEGS, width), table.dtype, sharding=table.sharding)
+    fn = MeshQueryExecutor(mesh)._build_shard_kernel(_real_spec(p.spec))
+    compiled = fn.jitted_for(ax).lower(ax).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    rows = MATMUL_SEGS * SEG_ROWS
+    entry = text[text.index("ENTRY"):]
+    # fusions whose result is a whole int32 column (a prefetch copy of an
+    # input column into the chip's other memory space is not one)
+    decoded = [ln.strip() for ln in entry.splitlines()
+               if " fusion(" in ln and any(
+                   f" = s32[{shape}]" in ln for shape in
+                   (rows, f"{MATMUL_SEGS},{SEG_ROWS}",
+                    f"1,{MATMUL_SEGS},{SEG_ROWS}"))]
+    assert f"pinot.decode.{decode}" in text
+    if decode == "select":
+        assert " gather(" not in text
+        assert "pinot.decode.gather" not in text
+        assert not decoded, decoded
+    else:
+        assert " gather(" in text
+        assert "pinot.decode.select" not in text
+        assert decoded, "the gather no longer writes the decoded column?"

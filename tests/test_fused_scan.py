@@ -269,3 +269,197 @@ def test_fused_signature_distinct_from_staged(seg):
         assert spec_fused.signature() != spec_staged.signature()
     finally:
         release_block(seg)
+
+
+# -- small decode tables: selects, not a gather (PR 27) ---------------------
+
+CAP = kernels.SELECT_DECODE_CAP
+DECODE_WIDTHS = [16, 64, CAP, 2 * CAP]
+#: value ranges a dictionary's entries span once narrowed for the device
+#: (negatives in each); the SUM of 640 rows stays exact in f32 for the first two
+VALUE_RANGES = {"int8": (-128, 127), "int16": (-20000, 20000),
+                "int32": (-(1 << 31), (1 << 31) - 1)}
+DEC_ROWS = 640
+
+
+def _card_for(width: int) -> int:
+    """A cardinality whose padded decode table (`lut_size`) is `width` wide."""
+    from pinot_tpu.engine.datablock import lut_size
+    card = width // 2
+    assert lut_size(card) == width
+    return card
+
+
+def _decode_segments(tmp, tag, width, values, n_segs):
+    """`n_segs` segments of one dict-encoded INT column `d` (each segment its
+    OWN dictionary of `_card_for(width)` values drawn from the range, so the
+    stacked per-segment tables differ), a filter column and a group column
+    whose dictionaries agree across segments."""
+    lo, hi = VALUE_RANGES[values]
+    card = _card_for(width)
+    schema = Schema("dec", [dimension("g"), dimension("d", DataType.INT),
+                            metric("w", DataType.INT)])
+    builder = SegmentBuilder(schema, SegmentGeneratorConfig(
+        no_dictionary_columns=["w"]))
+    segs = []
+    for i in range(n_segs):
+        rng = np.random.default_rng([27, width, i, len(values)])
+        dvals = rng.choice(np.arange(lo, hi + 1, max(1, (hi - lo) // 4096),
+                                     dtype=np.int64),
+                           size=card, replace=False).astype(np.int32)
+        d = dvals[np.arange(DEC_ROWS) % card]          # every entry is read
+        rng.shuffle(d)
+        cols = {"g": [f"g{j % 3}" for j in range(DEC_ROWS)], "d": d,
+                "w": rng.integers(-50, 50, DEC_ROWS).astype(np.int32)}
+        segs.append(load_segment(builder.build(
+            {k: (v.copy() if isinstance(v, np.ndarray) else list(v))
+             for k, v in cols.items()}, str(tmp), f"{tag}_{i}")))
+    return segs
+
+
+def _clear_kernel_caches():
+    from pinot_tpu.parallel import combine
+    kernels._KERNEL_CACHE.clear()
+    combine._SHARD_KERNEL_CACHE.clear()
+
+
+@pytest.fixture
+def decode_cap(monkeypatch):
+    """Set `SELECT_DECODE_CAP` for a block of the test: the cap is read at
+    trace time and is not part of any kernel cache key (it is a constant of
+    the program), so the caches are emptied around each change."""
+    def set_cap(cap):
+        _clear_kernel_caches()
+        monkeypatch.setattr(kernels, "SELECT_DECODE_CAP", cap)
+    yield set_cap
+    _clear_kernel_caches()
+
+
+def _served_rows(mex, segs, sqls):
+    """The served path of the mesh executor (what the device pipeline
+    drives): prepare each query, launch them together, fetch, decode and
+    reduce. Returns (rows per query, the launches)."""
+    from pinot_tpu.query.aggregates import make_agg
+    from pinot_tpu.query.context import compile_query
+    from pinot_tpu.query.reduce import (merge_segment_results,
+                                        reduce_to_result)
+    ctxs = [compile_query(sql, segs[0].schema) for sql in sqls]
+    preps = [mex.prepare_partial(ctx, segs) for ctx in ctxs]
+    assert all(p is not None for p in preps)
+    launches = mex.dispatch_prepared(preps)
+    rows = [None] * len(sqls)
+    for outs_dev, finish, idxs, _ in launches:
+        outs = finish(mex.fetch([outs_dev])[0])
+        for pos, i in enumerate(idxs):
+            aggs = [make_agg(f) for f in ctxs[i].aggregations]
+            partial = preps[i].decode(outs[pos])
+            rows[i] = _rows(reduce_to_result(
+                ctxs[i], merge_segment_results([partial], aggs), aggs,
+                list(ctxs[i].group_by)))
+    return rows, launches, preps
+
+
+def _execute(form, segs, sql, **kw):
+    if form == "1d":
+        return _rows(ServerQueryExecutor(**kw).execute(segs, sql))
+    from pinot_tpu.parallel.combine import MeshQueryExecutor
+    return _served_rows(MeshQueryExecutor(**kw), segs, [sql])[0][0]
+
+
+@pytest.mark.parametrize("values", list(VALUE_RANGES))
+@pytest.mark.parametrize("form", ["1d", "stacked"])
+@pytest.mark.parametrize("width", DECODE_WIDTHS)
+def test_small_table_decode_select_gather_staged_host_identical(
+        tmp_path, decode_cap, width, form, values):
+    """A fused dict column decoded by selects, by the gather, staged (decoded
+    in HBM) and on the host gives the same rows, at each table width, for the
+    direct executor's 1-D table and the mesh path's per-segment tables."""
+    segs = _decode_segments(tmp_path, f"{form}{width}{values}", width,
+                               values, 1 if form == "1d" else 3)
+    sqls = ["SELECT COUNT(*), MIN(d), MAX(d), SUM(d) FROM dec WHERE w > -20",
+            "SELECT g, COUNT(*), MIN(d), MAX(d), SUM(d) FROM dec "
+            "WHERE w < 30 GROUP BY g"]
+    small = width <= CAP
+    for sql in sqls:
+        decode_cap(CAP)                     # the program's own choice
+        with qstats.collect_stats() as st_rule:
+            by_rule = _execute(form, segs, sql, fused_enabled=True)
+        decode_cap(0 if small else 1 << 20)  # the decode it did not choose
+        with qstats.collect_stats() as st_other:
+            by_other = _execute(form, segs, sql, fused_enabled=True)
+        staged = _execute(form, segs, sql, fused_enabled=False)
+        host = _rows(ServerQueryExecutor(use_device=False).execute(segs, sql))
+        assert by_rule == by_other == staged, (sql, width)
+        # the counts and the int32 MIN / MAX are exact on every path; SUM is
+        # f32 on the device, exact while every partial sum is under 2^24
+        assert len(by_rule) == len(host)
+        for drow, hrow in zip(by_rule, host):
+            assert drow[:-1] == hrow[:-1], (sql, width)
+            if values == "int32":
+                assert drow[-1] == pytest.approx(hrow[-1], rel=1e-5)
+            else:
+                assert drow[-1] == hrow[-1], (sql, width)
+        # the count says which decode a launch rode
+        for st, by_select in ((st_rule, small), (st_other, not small)):
+            assert int(st.counters.get(qstats.FUSED_LAUNCHES, 0)) == 1
+            assert int(st.counters.get(qstats.GATHER_FREE_LAUNCHES, 0)) == \
+                int(by_select), (st.counters, width)
+
+
+@pytest.mark.parametrize("dtype", [np.int8, np.int16, np.int32, np.float32])
+@pytest.mark.parametrize("stacked", [False, True], ids=["1d", "stacked"])
+@pytest.mark.parametrize("width", DECODE_WIDTHS)
+def test_decode_select_is_the_gather_value_for_value(width, stacked, dtype):
+    """`_decode_select` against `_decode_gather` and numpy: same values, same
+    dtype, the fill id (= cardinality, which reads the table's padded zero)
+    and every other id of the table included."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng([width, stacked, np.dtype(dtype).itemsize])
+    card = _card_for(width)
+    shape = (3, width) if stacked else (width,)
+    if np.dtype(dtype).kind == "f":
+        lut = rng.normal(0, 1e6, shape).astype(dtype)
+    else:
+        info = np.iinfo(dtype)
+        lut = rng.integers(info.min, info.max, shape, dtype=dtype,
+                           endpoint=True)
+    lut[..., card:] = 0                      # the padding the fill id reads
+    rows = 4 * width
+    ids = np.concatenate([np.arange(width), np.full(width, card),
+                          rng.integers(0, card + 1, rows - 2 * width)]
+                         ).astype(np.int32)
+    if stacked:
+        ids = np.stack([rng.permutation(ids) for _ in range(3)])
+        want = np.take_along_axis(lut, ids, axis=1)
+    else:
+        want = lut[ids]
+    got_s = kernels._decode_select(jnp.asarray(lut), jnp.asarray(ids))
+    got_g = kernels._decode_gather(jnp.asarray(lut), jnp.asarray(ids))
+    assert got_s.dtype == got_g.dtype == want.dtype
+    assert got_s.shape == got_g.shape == want.shape
+    assert np.asarray(got_s).tobytes() == np.asarray(got_g).tobytes() \
+        == want.tobytes()
+
+
+@pytest.mark.parametrize("values", list(VALUE_RANGES))
+def test_stacked_b2_launch_of_small_table_plan_agrees(tmp_path, decode_cap,
+                                                      values):
+    """Two same-shape queries over per-segment 16-entry tables ride ONE
+    stacked (`_b2`) launch that decodes by selects; each answer is its solo
+    gather-decoded answer, and the launch counts as gather-free once."""
+    from pinot_tpu.parallel.combine import MeshQueryExecutor
+    segs = _decode_segments(tmp_path, f"b2{values}", 16, values, 3)
+    sqls = [f"SELECT COUNT(*), MIN(d), MAX(d), SUM(d) FROM dec WHERE w > {t}"
+            for t in (-20, 10)]
+    decode_cap(CAP)
+    with qstats.collect_stats() as st:
+        got, launches, preps = _served_rows(MeshQueryExecutor(), segs, sqls)
+    assert all(p.spec.fused_cols for p in preps)
+    assert preps[0].inputs["vals"]["d"].shape == (preps[0].s_pad, 16)
+    assert len(launches) == 1 and sorted(launches[0][2]) == [0, 1]
+    assert int(st.counters.get(qstats.DEVICE_LAUNCHES, 0)) == 1
+    assert int(st.counters.get(qstats.GATHER_FREE_LAUNCHES, 0)) == 1
+    assert launches[0][3].get(qstats.GATHER_FREE_LAUNCHES) == 1
+    decode_cap(0)
+    for sql, rows in zip(sqls, got):
+        assert rows == _execute("stacked", segs, sql), sql

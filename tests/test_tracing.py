@@ -606,3 +606,66 @@ def test_kernel_names_do_not_change_cache_keys(scope_segment):
     assert kernel_name(p.spec) == "pinot_groupby"
     assert kernel_name(p.spec, batch=4) == "pinot_groupby_b4"
     assert not any("pinot" in str(part) for part in p.spec.signature())
+
+
+@pytest.mark.parametrize("sql,present,absent", [
+    # d: 40 values, a 64-entry table -> decoded by selects
+    ("SELECT COUNT(*), SUM(d) FROM scopes WHERE w > 0",
+     ("pinot.decode.select",), ("pinot.decode.gather", "gather")),
+    # k: 6000 values, an 8192-entry table -> the gather stays
+    ("SELECT COUNT(*), SUM(k) FROM scopes WHERE w > 0",
+     ("pinot.decode.gather", "gather"), ("pinot.decode.select",)),
+    ("SELECT COUNT(*), SUM(d), SUM(k) FROM scopes WHERE w > 0",
+     ("pinot.decode.select", "pinot.decode.gather"), ()),
+])
+def test_decode_scope_names_the_decode_it_chose(scope_segment, sql, present,
+                                                absent):
+    """The dictionary decode is scoped by its form, both under the
+    `pinot.decode` prefix `kernels.decode_share` matches; a program whose
+    tables are all small holds no gather operation at all."""
+    p, text = _lowered_text(scope_segment, sql)
+    assert p.spec.fused_cols
+    for s in present:
+        assert s in text, s
+    for s in absent:
+        assert s not in text, s
+    from benchmark.harness.program_trace import scope_of
+    assert scope_of("jit(pinot_agg_fused)/jit(shmap_body)/"
+                    "pinot.decode.select/select_n:") == "pinot.decode.select"
+    assert scope_of("x/pinot.decode.gather/gather:").startswith("pinot.decode")
+
+
+def test_gather_free_launches_reaches_the_served_response(tmp_path):
+    """Through the device pipeline of a served cluster: the query whose fused
+    column has a small table answers with `gatherFreeLaunches` 1, the one
+    whose table is over the cap with 0 (both `fusedLaunches` 1), and a query
+    that decodes nothing in-kernel with neither."""
+    from pinot_tpu.cluster.device_server import DeviceQueryPipeline
+    from pinot_tpu.engine.kernels import SELECT_DECODE_CAP
+    cluster = QuickCluster(num_servers=1, work_dir=str(tmp_path))
+    cluster.servers[0].device_pipeline = pipeline = DeviceQueryPipeline()
+    rng = np.random.default_rng(27)
+    rows = 4000
+    schema = Schema("dec", [dimension("small", DataType.INT),
+                            dimension("wide", DataType.INT),
+                            metric("w", DataType.INT)])
+    from pinot_tpu.table import IndexingConfig
+    cfg = TableConfig("dec", indexing=IndexingConfig(
+        no_dictionary_columns=["w"]))
+    cluster.create_table(schema, cfg)
+    cluster.ingest_columns(cfg, {
+        "small": rng.integers(0, 11, rows).astype(np.int32),
+        "wide": rng.integers(0, 4 * SELECT_DECODE_CAP, rows).astype(np.int32),
+        "w": rng.integers(-1000, 1000, rows).astype(np.int32)})
+    try:
+        got = {name: cluster.query(
+            f"SELECT COUNT(*), SUM({expr}) FROM dec WHERE w > 0").stats
+            for name, expr in (("small", "small"), ("wide", "wide"),
+                               ("raw", "w"))}
+    finally:
+        pipeline.stop()
+    for name, fused, free in (("small", 1, 1), ("wide", 1, 0), ("raw", 0, 0)):
+        s = got[name]
+        assert s["deviceLaunches"] >= 1, (name, s)
+        assert s["fusedLaunches"] == fused, (name, s)
+        assert s["gatherFreeLaunches"] == free, (name, s)
