@@ -2136,8 +2136,8 @@ def _multichip_child(n: int) -> None:
             k: int(merged.get(k, 0)) for k in _COUNTER_INVARIANT_KEYS}
         counters[name]["bytesFetched"] = int(
             st.counters.get(qstats.BYTES_FETCHED, 0))
-        counters[name]["collectiveMs"] = round(
-            float(st.counters.get(qstats.COLLECTIVE_MS, 0.0)), 3)
+        counters[name]["collectiveBytes"] = int(
+            st.counters.get(qstats.COLLECTIVE_BYTES, 0))
         counters[name]["deviceSkewPct"] = round(
             float(st.counters.get(qstats.DEVICE_SKEW_PCT, 0.0)), 3)
         t0 = time.perf_counter()

@@ -66,12 +66,17 @@ DEVICE_FALLBACK = _Sentinel()
 _STAGES = ("queue_wait", "dispatch", "prepare", "launch", "handoff", "fetch",
            "decode")
 
+#: what a launch on more than one device records about what crosses the
+#: chips; also summed over the launches in `stats()`
+_MESH_KEYS = (qstats.MESH_LAUNCHES, qstats.SCATTER_LAUNCHES,
+              qstats.COLLECTIVE_BYTES)
+
 #: what the kernel cache, the first-call fence and the executor's launch
 #: accounting record on the dispatcher thread, folded from a scratch record
 #: into the items a launch answers
 _LAUNCH_KEYS = (qstats.COMPILE_MS, qstats.COMPILE_CACHE_MISSES,
                 qstats.COMPILE_CACHE_HITS, qstats.DEVICE_LAUNCHES,
-                qstats.GATHER_FREE_LAUNCHES)
+                qstats.GATHER_FREE_LAUNCHES) + _MESH_KEYS
 
 #: the pipeline's per-query phases in the order a query passes them: the
 #: item.stats key of each and the request-Trace span `execute_partial` rebuilds
@@ -138,6 +143,8 @@ class DeviceQueryPipeline:
             from ..parallel.combine import MeshQueryExecutor
             mesh_exec = MeshQueryExecutor()
         self.mesh_exec = mesh_exec
+        # chips one launch enqueues on and one fetch reads (fakes have none)
+        self.devices = getattr(mesh_exec, "n_devices", 1)
         self.max_batch = max_batch
         self.submit_timeout_s = submit_timeout_s
         self.stack = stack
@@ -172,6 +179,7 @@ class DeviceQueryPipeline:
         self.dedupe_hits = 0
         self.stacked_launches = 0
         self.fused_launches = 0
+        self.mesh = dict.fromkeys(_MESH_KEYS, 0)
         # how the batches form: drains that held one live query, why each
         # drain closed (`_drain`), and hand-offs that met a full fetch queue
         self.batches_of_one = 0
@@ -439,7 +447,8 @@ class DeviceQueryPipeline:
             return [], 0
         n_live = sum(len(g) for g in rep_groups)
         try:
-            with stage("pipeline.launch", batch=n_live) as launch:
+            with stage("pipeline.launch", batch=n_live,
+                       devices=self.devices) as launch:
                 launches = self.mesh_exec.dispatch_prepared(reps)
                 launch.note(launches=len(launches))
         except Exception:
@@ -460,6 +469,8 @@ class DeviceQueryPipeline:
                                 "fused_cols", ()) for i in idxs)
             if fused:
                 self.fused_launches += 1
+            for k in _MESH_KEYS:
+                self.mesh[k] += int(recorded.get(k, 0))
             for i in idxs:
                 for item, _ in rep_groups[i]:
                     item.stats[qstats.DEVICE_LAUNCH_MS] = round(launch.ms, 3)
@@ -528,7 +539,8 @@ class DeviceQueryPipeline:
                 try:
                     # ONE host sync for the whole dispatched batch
                     with stage("pipeline.fetch", batch=n_items,
-                               launches=len(live)) as sync:
+                               launches=len(live),
+                               devices=self.devices) as sync:
                         fetched = fetch([L[0] for L in live])
                 except Exception as e:
                     for item in _items(live):
@@ -589,7 +601,7 @@ class DeviceQueryPipeline:
                 "deviceErrors": self.device_errors, "timeouts": self.timeouts,
                 "launches": self.launches, "dedupeHits": self.dedupe_hits,
                 "stackedLaunches": self.stacked_launches,
-                "fusedLaunches": self.fused_launches,
+                "fusedLaunches": self.fused_launches, **self.mesh,
                 "batchesOfOne": self.batches_of_one,
                 "drainsClosedIdle": self.drains_closed_idle,
                 "drainsClosedFull": self.drains_closed_full,

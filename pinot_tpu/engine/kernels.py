@@ -852,11 +852,11 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
 def combine_collective(name: str, v, axis: str):
     """The cross-device combine for one kernel output: partials agree on dense keys
     (aligned dictionaries), so one ICI collective merges them."""
-    with jax.named_scope("pinot.collective"):
-        if name.endswith(".min"):
-            return jax.lax.pmin(v, axis)
-        if name.endswith(".max"):
-            return jax.lax.pmax(v, axis)
+    if name.endswith((".min", ".max")):
+        with jax.named_scope("pinot.collective.minmax"):
+            return (jax.lax.pmin if name.endswith(".min")
+                    else jax.lax.pmax)(v, axis)
+    with jax.named_scope("pinot.collective.sum"):
         return jax.lax.psum(v, axis)
 
 

@@ -76,12 +76,6 @@ _SHARD_KERNEL_CACHE: Dict[Tuple, object] = {}
 # Below it the savings don't cover the sharded-layout bookkeeping.
 SCATTER_MIN_KEYS = 4096
 
-# one-time measured cost of a mesh psum per (mesh, element-count bucket):
-# the `collectiveMs` ESTIMATE attached to mesh results (the collective is
-# fused into the kernel by XLA, so it cannot be timed in situ without
-# perturbing the launch)
-_COLLECTIVE_BENCH: Dict[Tuple, float] = {}
-
 
 def device_topk_screen(ctx: QueryContext) -> bool:
     """Cheap handler-thread pre-screen: could this SELECTION ride the device
@@ -738,6 +732,10 @@ class MeshQueryExecutor:
                     p = ps[0]
                     if p.kind == "topk":
                         outs = p.launch()
+                        if self.n_devices > 1:
+                            # a plain jit over the sharded block: the
+                            # compiler's own collectives are not counted
+                            qstats.record(qstats.MESH_LAUNCHES)
                     else:
                         fn = self._get_shard_kernel(p.spec, p.s_pad, p.rows)
                         _record_fused(p)
@@ -834,53 +832,20 @@ class MeshQueryExecutor:
                 entry[1].pad_waste_pct)
         return entry[1]
 
-    def _collective_ms(self, nelems: int) -> float:
-        """Measured-once estimate of one mesh psum over `nelems` f32 elements
-        (pow2-bucketed), the `collectiveMs` attached to mesh results. XLA
-        fuses the collective into the fused-scan kernel, so the real launch
-        cannot time it in isolation; a standalone shard_map psum of the same
-        payload is the honest proxy."""
-        if self.n_devices <= 1 or nelems <= 0:
-            return 0.0
-        bucket = 1 << (max(int(nelems), 1) - 1).bit_length()
-        key = (id(self.mesh), self.n_devices, bucket)
-        est = _COLLECTIVE_BENCH.get(key)
-        if est is None:
-            P = jax.sharding.PartitionSpec
-            fn = jax.jit(jax.shard_map(
-                lambda x: jax.lax.psum(x, SEGMENT_AXIS), mesh=self.mesh,
-                in_specs=(P(),), out_specs=P()))
-            arr = jax.device_put(np.zeros(bucket, np.float32),
-                                 self._replicated)
-            jax.block_until_ready(fn(arr))  # compile + warm outside the timer
-            t0 = time.perf_counter()
-            reps = 3
-            for _ in range(reps):
-                out = fn(arr)
-            jax.block_until_ready(out)
-            est = (time.perf_counter() - t0) / reps * 1000.0
-            _COLLECTIVE_BENCH[key] = est
-        return est
-
-    def _finish_mesh_stats(self, res, outs, block: SegmentSetBlock):
-        """Attach per-launch mesh accounting to a decoded result: worst
-        per-device doc-load skew (`deviceSkewPct`, max-merged upstream) and
-        the estimated cross-chip merge time (`collectiveMs`). Partials carry
-        them in `SegmentResult.stats` (riding the wire to the broker merge);
-        full results record into the request thread's active stats."""
+    def _finish_mesh_stats(self, res, block: SegmentSetBlock):
+        """Attach the launch's worst per-device doc-load skew
+        (`deviceSkewPct`, max-merged upstream) to a decoded result. Partials
+        carry it in `SegmentResult.stats` (riding the wire to the broker
+        merge); full results record into the request thread's active stats."""
         if self.n_devices <= 1:
             return res
         from ..query.reduce import SegmentResult
-        est = self._collective_ms(
-            sum(int(np.asarray(v).size) for v in outs.values()))
         if isinstance(res, SegmentResult):
             st = dict(res.stats or {})
-            st[qstats.COLLECTIVE_MS] = st.get(qstats.COLLECTIVE_MS, 0.0) + est
             st[qstats.DEVICE_SKEW_PCT] = max(
                 st.get(qstats.DEVICE_SKEW_PCT, 0.0), block.skew_pct)
             res.stats = st
         else:
-            qstats.record(qstats.COLLECTIVE_MS, est)
             qstats.record_max(qstats.DEVICE_SKEW_PCT, block.skew_pct)
         return res
 
@@ -1030,7 +995,7 @@ class MeshQueryExecutor:
         )
 
         def decode(outs):
-            return self._finish_mesh_stats(_decode_impl(outs), outs, block)
+            return self._finish_mesh_stats(_decode_impl(outs), block)
 
         def _decode_impl(outs):
             # replicated outputs decode exactly like the single-segment path;
@@ -1368,13 +1333,28 @@ class MeshQueryExecutor:
                 if scat:
                     get_registry().counter(
                         "pinot_kernel_scatter_builds").inc()
+                if n > 1:
+                    # what one device hands to this program's collectives:
+                    # each per-shard output whole, a scattered one without
+                    # its overflow row
+                    def handed(name, s) -> int:
+                        shape = list(s.shape)
+                        if name in scat:
+                            shape[key_dim] = pad
+                        return int(np.prod(shape, dtype=np.int64)) \
+                            * s.dtype.itemsize
+                    built["mesh"] = {
+                        qstats.MESH_LAUNCHES: 1,
+                        qstats.SCATTER_LAUNCHES: int(bool(scat)),
+                        qstats.COLLECTIVE_BYTES: sum(
+                            handed(k, s) for k, s in out_shapes.items())}
 
                 def shard_body(sin):
                     outs = call_body(sin)
                     res = {}
                     for name, v in outs.items():
                         if name in scat:
-                            with jax.named_scope("pinot.collective"):
+                            with jax.named_scope("pinot.collective.scatter"):
                                 core = v[:, :pad] if batch else v[:pad]
                                 res[name] = jax.lax.psum_scatter(
                                     core, ax, scatter_dimension=key_dim,
@@ -1396,7 +1376,10 @@ class MeshQueryExecutor:
             return compiled
 
         def fn(inputs):
-            return jitted_for(inputs)(inputs)
+            compiled = jitted_for(inputs)
+            for key, v in built.get("mesh", {}).items():
+                qstats.record(key, v)
+            return compiled(inputs)
 
         fn.jitted_for = jitted_for
         return fn

@@ -80,7 +80,14 @@ NUM_CONSUMING_SEGMENTS_QUERIED = "numConsumingSegmentsQueried"
 MIN_CONSUMING_FRESHNESS_TIME_MS = "minConsumingFreshnessTimeMs"
 MUX_FRAME_QUEUE_MS = "muxFrameQueueMs"
 MUX_FLOW_CONTROL_MS = "muxFlowControlMs"
-COLLECTIVE_MS = "collectiveMs"
+# what crosses the chips (PR 28), per launch, from the static shapes the
+# shard kernel was built with: launches whose program ran on more than one
+# device; those of them with at least one reduce-scattered output
+# (`psum_scatter`, combine.SCATTER_MIN_KEYS); and the bytes one device hands
+# to the launch's psum / pmin / pmax / psum_scatter. All 0 on a mesh of one
+MESH_LAUNCHES = "meshLaunches"
+SCATTER_LAUNCHES = "scatterLaunches"
+COLLECTIVE_BYTES = "collectiveBytes"
 DEVICE_SKEW_PCT = "deviceSkewPct"
 HEDGED_REQUESTS = "hedgedRequests"
 ADMISSION_DEFER_MS = "admissionDeferMs"
@@ -119,7 +126,8 @@ COUNTER_KEYS = (
     DEVICE_DECODE_MS, DEDUPED_LAUNCHES, STACKED_LAUNCHES,
     FUSED_LAUNCHES, STAGED_LAUNCHES, GATHER_FREE_LAUNCHES,
     NUM_CONSUMING_SEGMENTS_QUERIED, MUX_FRAME_QUEUE_MS, MUX_FLOW_CONTROL_MS,
-    COLLECTIVE_MS, HEDGED_REQUESTS, ADMISSION_DEFER_MS,
+    MESH_LAUNCHES, SCATTER_LAUNCHES, COLLECTIVE_BYTES,
+    HEDGED_REQUESTS, ADMISSION_DEFER_MS,
     SEGMENTS_SERVED_HOST_TIER, TIER_PROMOTIONS,
     SEGMENTS_COLD_LOADED, COLD_LOAD_MS,
     JOIN_BUILD_MS, JOIN_PROBE_MS, JOIN_SHUFFLE_BYTES,

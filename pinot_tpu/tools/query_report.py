@@ -88,8 +88,7 @@ def render_report(stats: Dict[str, Any]) -> str:
                        ("deviceDecodeMs", "pipeline decode"),
                        ("serverTimeMs", "server execute"),
                        ("muxFrameQueueMs", "mux frame queue"),
-                       ("muxFlowControlMs", "mux flow ctl"),
-                       ("collectiveMs", "ici collective")):
+                       ("muxFlowControlMs", "mux flow ctl")):
         if key in stats:
             out.append(f"  {label:<16} {_fmt_ms(stats.get(key, 0))}")
     if "deviceSkewPct" in stats:
@@ -137,7 +136,8 @@ def render_report(stats: Dict[str, Any]) -> str:
                 "numSegmentsPrunedByRange", "numSegmentsPrunedByBloom",
                 "numSegmentsMatched", "numDocsScanned", "scanRowsAvoided",
                 "numGroupsTotal", "deviceLaunches", "fusedLaunches",
-                "stagedLaunches",
+                "stagedLaunches", "meshLaunches", "scatterLaunches",
+                "collectiveBytes",
                 "dedupedLaunches", "stackedLaunches", "compileCacheHits",
                 "compileCacheMisses", "bytesFetched", "deviceBatchSize",
                 "numServersQueried", "numServersResponded"):

@@ -3,7 +3,9 @@ added, on a fixture cut from that PR's own chip trace
 (benchmark/fixtures/pr26-quarter-slice.*): the readers give the numbers
 recorded with it, and None where the program wrote nothing for them to read
 (a CPU trace with no TPU plane; PR 25's trace, taken before the program had
-spans, scopes or the response fields)."""
+spans, scopes or the response fields). The four metrics of PR 28's four-chip
+cell likewise, on a fixture cut from that PR's traced four-chip run
+(benchmark/fixtures/pr28-mesh4-slice.*)."""
 
 import json
 import os
@@ -22,6 +24,11 @@ NEW_METRICS = (
     "pipeline.singleton_batch_share", "device.idle_host_busy_share",
     "device.idle_starved_share", "kernels.decode_share",
     "kernels.groupby_share")
+MESH_SLICE = os.path.join(FIXTURES, "pr28-mesh4-slice.xplane.pb")
+MESH_CELL = "ssb10-flat-mesh4.flights-c4"
+MESH_METRICS = (
+    "mesh.devices_busy", "kernels.collective_share",
+    "mesh.scatter_launch_share", "mesh.collective_bytes_per_answer")
 
 
 @pytest.fixture(scope="module")
@@ -65,18 +72,110 @@ def test_reader_returns_none_on_the_parents_run(name):
     assert cells.load_reader(name)(ctx) is None
 
 
-@pytest.mark.parametrize("name", NEW_METRICS)
+@pytest.mark.parametrize("name", NEW_METRICS + MESH_METRICS)
 def test_every_new_metric_is_declared_like_the_old(name):
-    """A `.json` with the keys of PR 25's, a `per_layer` entry that says the
-    same, and no `workloads` key: every cell owes it."""
+    """A `.json` with the keys of PR 25's and a `per_layer` entry that says
+    the same; PR 26's have no `workloads` key (every cell owes them), PR 28's
+    list the one four-chip cell (a mesh of one has nothing for them to read)."""
     meta = cells.read_json(cells.BENCH, "metrics", name + ".json")
-    entry = [m for m in cells.read_json(cells.ROOT, "BENCHMARK.json")
-             ["per_layer"] if m["name"] == name]
-    assert len(entry) == 1 and "workloads" not in entry[0]
+    bench = cells.read_json(cells.ROOT, "BENCHMARK.json")
+    entry = [m for m in bench["per_layer"] if m["name"] == name]
+    assert len(entry) == 1
+    if name in MESH_METRICS:
+        assert entry[0]["workloads"] == [MESH_CELL]
+        assert entry[0]["layer"] in {m["layer"] for m in bench["per_layer"]
+                                     if "workloads" not in m}
+    else:
+        assert "workloads" not in entry[0]
     for key in ("unit", "better", "source", "layer", "moves"):
         assert entry[0][key] == meta[key], key
     assert meta["name"] == name and meta["what"]
     assert meta["moves"] in ("qps", "mean_ms")
+
+
+@pytest.fixture(scope="module")
+def mesh_recorded():
+    with open(os.path.join(FIXTURES, "pr28-mesh4-slice.json")) as f:
+        return json.load(f)
+
+
+def _put_profile(root, src, cell="a-cell", kind="profile"):
+    """`src` where run.py leaves a profile: `trace_slice`'s under `profile`,
+    the solo replay's under `profile_solo`."""
+    import shutil
+    d = root / ".bench_work" / cell / kind / "plugins" / "profile" \
+        / "2026_09_30"
+    d.mkdir(parents=True)
+    shutil.copy(src, d / "host.xplane.pb")
+    return d / "host.xplane.pb"
+
+
+@pytest.mark.parametrize("name", MESH_METRICS)
+def test_mesh_reader_gives_the_number_recorded_with_the_fixture(
+        name, mesh_recorded, tmp_path, monkeypatch):
+    """Through the file, as in a run: `ctx` has the harness's reduction and
+    the counters, and the readers find the slice under `.bench_work`."""
+    monkeypatch.setattr(program_trace, "ROOT", str(tmp_path))
+    _put_profile(tmp_path, MESH_SLICE)
+    ctx = _ctx([{"timeUsedMs": 1.0}] * mesh_recorded["answers"],
+               mesh_recorded["counters"], None)
+    del ctx["program_trace"]
+    ctx["trace"] = trace_reduce.reduce(MESH_SLICE)
+    got = cells.load_reader(name)(ctx)
+    assert got == pytest.approx(mesh_recorded["metrics"][name], rel=1e-9)
+
+
+@pytest.mark.parametrize("name", MESH_METRICS)
+def test_mesh_reader_returns_none_on_the_parents_run(name, tmp_path,
+                                                     monkeypatch):
+    """PR 25's program on one chip: no scope, no mesh counter, and a `ctx`
+    whose trace says nothing of devices. Nothing raises."""
+    monkeypatch.setattr(program_trace, "ROOT", str(tmp_path))
+    _put_profile(tmp_path, PARENT)
+    ctx = _ctx([{"timeUsedMs": 600.0}], {"batches": 10, "dispatched": 20},
+               None)
+    del ctx["program_trace"]
+    ctx["trace"] = {"busy_s": 1.0}
+    assert cells.load_reader(name)(ctx) is None
+    assert cells.load_reader(name)(dict(ctx, trace=None)) is None
+
+
+def test_mesh_slice_holds_four_devices_and_the_collectives(mesh_recorded):
+    """What the four-chip run wrote: four device planes with operations, the
+    launch and fetch spans saying `devices` 4, the `psum`s under
+    `pinot.collective.sum`, and the `psum_scatter`s as the v5e compiled them:
+    an `all-reduce` with no `tf_op`, known by its opcode alone."""
+    by_jax = trace_reduce.reduce(MESH_SLICE)
+    assert by_jax["devices"] == mesh_recorded["devices"] == 4
+    assert by_jax["busy_s"] == pytest.approx(mesh_recorded["busy_s"])
+    t = program_trace.reduce(MESH_SLICE)
+    assert program_trace.length(t["busy"]) / 1e9 == pytest.approx(
+        mesh_recorded["first_device_busy_s"])
+    assert t["modules"] == mesh_recorded["modules"]
+    scopes = {}
+    for _, _, scope in t["ops"]:
+        if scope.startswith("pinot.collective"):
+            scopes[scope] = scopes.get(scope, 0) + 1
+    assert scopes == mesh_recorded["collective_scopes"]
+    assert "pinot.collective.sum" in scopes
+    for name in ("pinot:pipeline.launch", "pinot:pipeline.fetch"):
+        assert {stats["devices"] for _, _, stats, _ in t["spans"][name]} \
+            == set(mesh_recorded["span_devices"]) == {4}
+    share = cells.load_py(os.path.join(
+        cells.BENCH, "metrics", "kernels.collective_share.py"))
+    ops = share.opcode_intervals(MESH_SLICE, t["lo"], t["hi"])
+    assert len(ops) == mesh_recorded["collective_opcode_ops"]
+    device0 = [p for p in program_trace.load(MESH_SLICE)
+               if p["name"] == "/device:TPU:0"][0]
+    unnamed = [name for ln in device0["lines"] if ln["name"] == "XLA Ops"
+               for name, _, _, stats in ln["events"]
+               if share.COLLECTIVE_OPCODE.search(name)
+               and not stats.get("tf_op")]
+    assert unnamed and all(" all-reduce(" in name for name in unnamed)
+    # by scope alone the share would miss them
+    by_scope = program_trace.scope_share(
+        {"program_trace": t}, "pinot.collective")
+    assert 0 < by_scope < mesh_recorded["metrics"]["kernels.collective_share"]
 
 
 def test_walker_agrees_with_profile_data_on_the_device(recorded, sliced):
@@ -204,25 +303,17 @@ def test_slice_of_finds_the_runs_profile(tmp_path, monkeypatch):
     """`ctx` has no path: the slice is the newest .xplane.pb under a
     `.bench_work/<cell>/profile`; the solo replay's `profile_solo` and what
     an ended run left behind do not count; one run parses the file once."""
-    import shutil
     import time
     monkeypatch.setattr(program_trace, "ROOT", str(tmp_path))
 
     def fresh():
         return {"records": [], "counters": {}, "trace": {"busy_s": 1.0}}
 
-    def put(cell, kind, src):
-        d = tmp_path / ".bench_work" / cell / kind / "plugins" / "profile" \
-            / "2026_09_30"
-        d.mkdir(parents=True)
-        shutil.copy(src, d / "host.xplane.pb")
-        return d / "host.xplane.pb"
-
     assert program_trace.slice_of(fresh()) is None      # no directory at all
-    stale = put("an-ended-cell", "profile", PARENT)
+    stale = _put_profile(tmp_path, PARENT, "an-ended-cell")
     os.utime(stale, (time.time() - 3600, time.time() - 3600))
-    put("a-cell", "profile", SLICE)
-    put("a-cell", "profile_solo", PARENT)               # newer, not the slice
+    _put_profile(tmp_path, SLICE)
+    _put_profile(tmp_path, PARENT, kind="profile_solo")  # newer, not the slice
     ctx = fresh()
     got = program_trace.slice_of(ctx)
     assert got is not None and got["spans"] and got["modules"]
