@@ -71,6 +71,11 @@ _STAGES = ("queue_wait", "dispatch", "prepare", "launch", "handoff", "fetch",
 _MESH_KEYS = (qstats.MESH_LAUNCHES, qstats.SCATTER_LAUNCHES,
               qstats.COLLECTIVE_BYTES)
 
+#: which decode branch a sort-regime GROUP BY launch ran: known once its
+#: outputs are fetched (`qstats.decode_branch`), summed over the launches in
+#: `stats()`; the decode hook puts the same key on each answer's partial
+_DECODE_KEYS = (qstats.COMPACT_DECODE_LAUNCHES, qstats.DENSE_DECODE_LAUNCHES)
+
 #: what the kernel cache, the first-call fence and the executor's launch
 #: accounting record on the dispatcher thread, folded from a scratch record
 #: into the items a launch answers
@@ -180,6 +185,7 @@ class DeviceQueryPipeline:
         self.stacked_launches = 0
         self.fused_launches = 0
         self.mesh = dict.fromkeys(_MESH_KEYS, 0)
+        self.decodes = dict.fromkeys(_DECODE_KEYS, 0)
         # how the batches form: drains that held one live query, why each
         # drain closed (`_drain`), and hand-offs that met a full fetch queue
         self.batches_of_one = 0
@@ -569,6 +575,9 @@ class DeviceQueryPipeline:
                     _resolve(item.future, None, exc=e)
             return
         for outs, group in zip(outs_list, groups):
+            took = qstats.decode_branch(outs)
+            if took:
+                self.decodes[took] += 1
             for item, decode in group:
                 if item.future.done():
                     continue  # caller timed out mid-fetch: skip the decode
@@ -602,6 +611,7 @@ class DeviceQueryPipeline:
                 "launches": self.launches, "dedupeHits": self.dedupe_hits,
                 "stackedLaunches": self.stacked_launches,
                 "fusedLaunches": self.fused_launches, **self.mesh,
+                **self.decodes,
                 "batchesOfOne": self.batches_of_one,
                 "drainsClosedIdle": self.drains_closed_idle,
                 "drainsClosedFull": self.drains_closed_full,

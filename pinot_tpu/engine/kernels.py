@@ -738,7 +738,85 @@ def _counts_from_sorted(key_s: jnp.ndarray, nseg: int, pad: int):
     return left, counts
 
 
-def _grouped_sorted(key: jnp.ndarray, nseg: int, value_rows, block: int = 4096):
+def compact_cap(n: int, nseg: int, block: int) -> int:
+    """How many sorted rows the compact decode reads, from shapes alone: n / 64
+    (the widest SSB template passes n / 630), or 0 where no branch is built
+    because today's per-key decode is the cheaper of the two: its gathers,
+    nseg x (log2 n + log2(n / block) + 5), against `cap` updates. At 67M rows
+    the 8,193-key templates decode with 0.4M gathers against 1M updates, so
+    their programs hold one branch, as before."""
+    cap = n // 64
+    steps = n.bit_length() + max(n // block, 1).bit_length() + 3  # the log2s + 5
+    return cap if nseg * steps > cap > 0 else 0
+
+
+def _compact_decode(key_c: jnp.ndarray, vals_c, m, nseg: int, rows: int):
+    """Dense [nseg] counts and sums from the sorted PREFIX `key_c`, `vals_c`
+    (the first `cap` sorted rows), of which the first `m` passed the filter;
+    rows past m carry the overflow key nseg-1 and zero values. Work is set by
+    `cap`, not by nseg: one segmented scan over the prefix gives every run's
+    total at its last row (added as a tree, so a run of a million rows keeps
+    f32's per-element precision), and one scatter-add of `cap` sorted updates
+    per output lands the run tails in zeros[nseg] — every other row adds an
+    exact 0. Counts are run lengths, int32 throughout. `rows` is the real
+    (unpadded) row count: the overflow bucket holds rows - m, as the dense
+    decode's does. Returns [int32 counts[nseg], f32 sums[nseg]...]."""
+    cap = key_c.size
+    pos = jnp.arange(cap, dtype=jnp.int32)
+    nxt = jnp.concatenate([key_c[1:], jnp.full((1,), nseg - 1, key_c.dtype)])
+    # a live run ends where the next row has another key; past m every row
+    # has the overflow key, which no live row has. The prefix's last row
+    # ends its run whatever follows it (m <= cap: the next row did not pass)
+    tail = (pos < m) & ((key_c != nxt) | (pos == cap - 1))
+    head = jnp.concatenate([jnp.ones((1,), bool), key_c[1:] != key_c[:-1]])
+    start = jax.lax.cummax(jnp.where(head, pos, 0))
+    add = lambda zero, upd: zero.at[key_c].add(      # noqa: E731
+        upd, indices_are_sorted=True, mode="promise_in_bounds")
+    counts = add(jnp.zeros((nseg,), jnp.int32),
+                 jnp.where(tail, pos - start + 1, 0))
+    outs = [counts.at[nseg - 1].set(jnp.int32(rows) - m)]
+    if vals_c:
+        v = jnp.stack(vals_c)  # [R, cap]
+        flags = jnp.broadcast_to(head[None, :], v.shape)
+        _, scan = jax.lax.associative_scan(_seg_sum_op, (flags, v), axis=1)
+        for r in range(v.shape[0]):
+            outs.append(add(jnp.zeros((nseg,), jnp.float32),
+                            jnp.where(tail, scan[r], 0.0)))
+    return outs
+
+
+def _decode_sorted(regime: str, key_s, vals_s, nseg: int, pad: int, block: int,
+                   dense, took):
+    """The dense [nseg] answer of a sort regime from its sorted rows: `dense`
+    (the per-key decode, a binary search for every dense key) or, where few
+    rows passed the filter, `_compact_decode` over the sorted prefix. One HLO
+    conditional on m, the count of rows that passed, which only the device
+    knows; both branches share the sort. Where `compact_cap` builds no branch
+    the program is the dense decode alone. `took` (a list, or None) collects
+    the scalar that says which branch ran."""
+    n = key_s.size
+    cap = compact_cap(n, nseg, block)
+    if not cap:
+        return dense()
+    m = jnp.sum(key_s < nseg - 1, dtype=jnp.int32)
+    fits = m <= cap
+
+    def compact():
+        with jax.named_scope(f"pinot.groupby.{regime}.compact"):
+            return _compact_decode(key_s[:cap], [v[:cap] for v in vals_s], m,
+                                   nseg, n - pad)
+
+    def dense_branch():
+        with jax.named_scope(f"pinot.groupby.{regime}.dense"):
+            return dense()
+
+    if took is not None:
+        took.append(fits)
+    return jax.lax.cond(fits, compact, dense_branch)
+
+
+def _grouped_sorted(key: jnp.ndarray, nseg: int, value_rows, block: int = 4096,
+                    took=None):
     """Sort + segmented-scan group-by: the pathological-cardinality fallback.
 
     One `jax.lax.sort` of (key, values), head flags at run boundaries, one
@@ -746,32 +824,39 @@ def _grouped_sorted(key: jnp.ndarray, nseg: int, value_rows, block: int = 4096):
     run's last position (left[k+1]-1). Cost is the sort plus O(N log N) scan
     work with NO per-key term, so it is the regime of last resort when the
     residual cardinality makes even the rank-partitioned matmul's per-key
-    decode expensive. Returns [int32 counts[nseg], f32 sums[nseg]...].
+    decode expensive. Where few rows passed the filter the answer comes from
+    the sorted prefix instead (`_decode_sorted`).
+    Returns [int32 counts[nseg], f32 sums[nseg]...].
     """
     with jax.named_scope("pinot.groupby.sorted.sort"):
         key_s, vals_s, pad = _sort_by_key(key, nseg, value_rows, block)
     n = key_s.size
-    with jax.named_scope("pinot.groupby.sorted.trim"):
-        left, counts = _counts_from_sorted(key_s, nseg, pad)
-    outs = [counts]
-    if not vals_s:
+
+    def dense():
+        with jax.named_scope("pinot.groupby.sorted.trim"):
+            left, counts = _counts_from_sorted(key_s, nseg, pad)
+        outs = [counts]
+        if not vals_s:
+            return outs
+        with jax.named_scope("pinot.groupby.sorted.scan"):
+            head = jnp.concatenate([jnp.ones((1,), bool),
+                                    key_s[1:] != key_s[:-1]])
+            v = jnp.stack(vals_s)  # [R, n]
+            flags = jnp.broadcast_to(head[None, :], v.shape)
+            _, scan = jax.lax.associative_scan(_seg_sum_op, (flags, v), axis=1)
+        with jax.named_scope("pinot.groupby.sorted.trim"):
+            end = jnp.clip(left[1:] - 1, 0, n - 1)  # last row of each key's run
+            occ = counts > 0
+            for r in range(v.shape[0]):
+                outs.append(jnp.where(occ, scan[r][end], 0.0))
         return outs
-    with jax.named_scope("pinot.groupby.sorted.scan"):
-        head = jnp.concatenate([jnp.ones((1,), bool),
-                                key_s[1:] != key_s[:-1]])
-        v = jnp.stack(vals_s)  # [R, n]
-        flags = jnp.broadcast_to(head[None, :], v.shape)
-        _, scan = jax.lax.associative_scan(_seg_sum_op, (flags, v), axis=1)
-    with jax.named_scope("pinot.groupby.sorted.trim"):
-        end = jnp.clip(left[1:] - 1, 0, n - 1)  # last row of each key's run
-        occ = counts > 0
-        for r in range(v.shape[0]):
-            outs.append(jnp.where(occ, scan[r][end], 0.0))
-    return outs
+
+    return _decode_sorted("sorted", key_s, vals_s, nseg, pad, block, dense,
+                          took)
 
 
 def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
-                         block: int = 4096):
+                         block: int = 4096, took=None):
     """Two-level radix-partitioned sort group-by — the high-cardinality regime
     replacing the flat `segment_sum` scatter.
 
@@ -786,72 +871,82 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
     key count, where the chunked path pays per 4096 keys and the scatter pays
     its K-independent ~248ms. Groups spanning slab boundaries always occupy
     local id 0 of the continuation slabs, so a short segmented scan over the
-    [B] slab-head sums stitches them. The dense decode is scatter-free too:
-    `searchsorted` run boundaries give exact int32 counts and each key's first
-    sorted position, from which (slab, local id, continuation chain) are pure
-    gathers. Value sums use the 3-part bf16 split (full f32 precision) with
-    f32 accumulation. Returns [int32 counts[nseg], f32 sums[nseg]...].
+    [B] slab-head sums stitches them. The dense decode has no n-row scatter
+    either: `searchsorted` run boundaries give exact int32 counts and each
+    key's first sorted position, from which (slab, local id, continuation
+    chain) are pure gathers — two binary searches for EVERY dense key, so
+    where few rows passed the filter `_decode_sorted` answers from the sorted
+    prefix instead and none of the above runs. Value sums use the 3-part bf16
+    split (full f32 precision) with f32 accumulation.
+    Returns [int32 counts[nseg], f32 sums[nseg]...].
     """
     with jax.named_scope("pinot.groupby.partitioned.sort"):
         key_s, vals_s, pad = _sort_by_key(key, nseg, value_rows, block)
     n = key_s.size
     nb = n // block
-    with jax.named_scope("pinot.groupby.partitioned.trim"):
-        left, counts = _counts_from_sorted(key_s, nseg, pad)
-    outs = [counts]
-    if not vals_s:
+
+    def dense():
+        with jax.named_scope("pinot.groupby.partitioned.trim"):
+            left, counts = _counts_from_sorted(key_s, nseg, pad)
+        outs = [counts]
+        if not vals_s:
+            return outs
+        with jax.named_scope("pinot.groupby.partitioned.scan"):
+            head = jnp.concatenate([jnp.ones((1,), bool),
+                                    key_s[1:] != key_s[:-1]])
+            rank = jnp.cumsum(head.astype(jnp.int32)) - 1       # nondecreasing
+            rank_start = rank.reshape(nb, block)[:, 0]          # [nb]
+            j = rank.reshape(nb, block) - rank_start[:, None]   # local id < block
+            bf = jnp.bfloat16
+            oh_hi = jax.nn.one_hot(j // 64, block // 64, dtype=bf)  # [nb, block, B/64]
+            oh_lo = jax.nn.one_hot(j % 64, 64, dtype=bf)            # [nb, block, 64]
+            dot = lambda a, b: jax.lax.dot_general(             # noqa: E731
+                a, b, (((1,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            local = []
+            for v in vals_s:
+                s = None
+                for part in _bf16_parts(v.reshape(nb, block)):
+                    d = dot(oh_hi, part[:, :, None] * oh_lo)    # [nb, B/64, 64]
+                    s = d if s is None else s + d
+                local.append(s.reshape(nb, block))              # sums per (slab, j)
+            # stitch slab-spanning groups: a group continuing into slab b sits at
+            # local id 0 there, so a segmented scan over local[:, 0] (heads where
+            # rank_start changes) accumulates each continuation chain
+            heads_b = jnp.concatenate([jnp.ones((1,), bool),
+                                       rank_start[1:] != rank_start[:-1]])
+            slab0 = jnp.stack([l[:, 0] for l in local])         # [R, nb]
+            flags = jnp.broadcast_to(heads_b[None, :], slab0.shape)
+            _, chain = jax.lax.associative_scan(_seg_sum_op, (flags, slab0),
+                                                axis=1)
+        # dense decode: each key's first sorted row -> (slab g0, local id j0); the
+        # last slab of its chain is the last rank_start <= its rank
+        with jax.named_scope("pinot.groupby.partitioned.trim"):
+            p = jnp.minimum(left[:-1], n - 1)
+            r = rank[p]
+            g0 = p // block
+            j0 = r - rank_start[g0]
+            g1 = jnp.searchsorted(rank_start, r, side="right") - 1
+            occ = counts > 0
+            for li, ci in zip(local, chain):
+                start = li[g0, j0]
+                tail = ci[g1]
+                # j0 == 0: the chain includes slab g0 itself; otherwise the chain
+                # (if any: g1 > g0) covers only the continuation slabs after g0
+                total = jnp.where(j0 == 0, tail,
+                                  start + jnp.where(g1 > g0, tail, 0.0))
+                outs.append(jnp.where(occ, total, 0.0))
         return outs
-    with jax.named_scope("pinot.groupby.partitioned.scan"):
-        head = jnp.concatenate([jnp.ones((1,), bool),
-                                key_s[1:] != key_s[:-1]])
-        rank = jnp.cumsum(head.astype(jnp.int32)) - 1       # nondecreasing
-        rank_start = rank.reshape(nb, block)[:, 0]          # [nb]
-        j = rank.reshape(nb, block) - rank_start[:, None]   # local id < block
-        bf = jnp.bfloat16
-        oh_hi = jax.nn.one_hot(j // 64, block // 64, dtype=bf)  # [nb, block, B/64]
-        oh_lo = jax.nn.one_hot(j % 64, 64, dtype=bf)            # [nb, block, 64]
-        dot = lambda a, b: jax.lax.dot_general(             # noqa: E731
-            a, b, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        local = []
-        for v in vals_s:
-            s = None
-            for part in _bf16_parts(v.reshape(nb, block)):
-                d = dot(oh_hi, part[:, :, None] * oh_lo)    # [nb, B/64, 64]
-                s = d if s is None else s + d
-            local.append(s.reshape(nb, block))              # sums per (slab, j)
-        # stitch slab-spanning groups: a group continuing into slab b sits at
-        # local id 0 there, so a segmented scan over local[:, 0] (heads where
-        # rank_start changes) accumulates each continuation chain
-        heads_b = jnp.concatenate([jnp.ones((1,), bool),
-                                   rank_start[1:] != rank_start[:-1]])
-        slab0 = jnp.stack([l[:, 0] for l in local])         # [R, nb]
-        flags = jnp.broadcast_to(heads_b[None, :], slab0.shape)
-        _, chain = jax.lax.associative_scan(_seg_sum_op, (flags, slab0),
-                                            axis=1)
-    # dense decode: each key's first sorted row -> (slab g0, local id j0); the
-    # last slab of its chain is the last rank_start <= its rank
-    with jax.named_scope("pinot.groupby.partitioned.trim"):
-        p = jnp.minimum(left[:-1], n - 1)
-        r = rank[p]
-        g0 = p // block
-        j0 = r - rank_start[g0]
-        g1 = jnp.searchsorted(rank_start, r, side="right") - 1
-        occ = counts > 0
-        for li, ci in zip(local, chain):
-            start = li[g0, j0]
-            tail = ci[g1]
-            # j0 == 0: the chain includes slab g0 itself; otherwise the chain
-            # (if any: g1 > g0) covers only the continuation slabs after g0
-            total = jnp.where(j0 == 0, tail,
-                              start + jnp.where(g1 > g0, tail, 0.0))
-            outs.append(jnp.where(occ, total, 0.0))
-    return outs
+
+    return _decode_sorted("partitioned", key_s, vals_s, nseg, pad, block,
+                          dense, took)
 
 
 def combine_collective(name: str, v, axis: str):
     """The cross-device combine for one kernel output: partials agree on dense keys
     (aligned dictionaries), so one ICI collective merges them."""
+    if name == qstats.COMPACT_FLAG:
+        name = ".min"  # a launch took the compact decode only if every chip did
     if name.endswith((".min", ".max")):
         with jax.named_scope("pinot.collective.minmax"):
             return (jax.lax.pmin if name.endswith(".min")
@@ -873,7 +968,7 @@ def _make_body(spec: KernelSpec):
     caps = get_caps()  # regime crossovers (calibrated; part of signature())
     scope = jax.named_scope  # each stage of the scan, named in the device trace
 
-    def grouped_distinct(ai, agg, ids, key, mask):
+    def grouped_distinct(ai, agg, ids, key, mask, took):
         """PER-GROUP presence counts [keys, dict ids] (the grouped
         DISTINCTCOUNT/HLL/theta path, BASELINE config 5): one combined dense
         key over the (group, id) product space — masked rows ride the
@@ -900,7 +995,8 @@ def _make_body(spec: KernelSpec):
         # presence counts over the combined (group, id) space past the chunk
         # cap: sorted-run boundary counts are exact int32 with no matmul and
         # no scatter
-        pres = _grouped_sorted(comb, width, [], caps.partition_block)[0]
+        pres = _grouped_sorted(comb, width, [], caps.partition_block,
+                               took)[0]
         return pres.reshape(num_seg, size)
 
     def scalar_distinct(ai, agg, ids, mask, fmask):
@@ -930,6 +1026,7 @@ def _make_body(spec: KernelSpec):
         mask = mask_fn(ids, vals, luts, iscal, fscal, nulls, valid, docsets,
                        bitmaps)
         out: Dict[str, jnp.ndarray] = {}
+        took: list = []  # which decode branch each sort regime of the scan ran
 
         if group:
             with scope("pinot.groupby.key"):
@@ -946,7 +1043,7 @@ def _make_body(spec: KernelSpec):
                 if "distinct" in outs:
                     with scope("pinot.distinct"):
                         out[f"{ai}.distinct"] = grouped_distinct(
-                            ai, agg, ids, key, mask)
+                            ai, agg, ids, key, mask, took)
                     continue
                 with scope("pinot.groupby.key"):
                     v = _agg_arg(agg, vals)
@@ -1003,7 +1100,7 @@ def _make_body(spec: KernelSpec):
                            else _grouped_partitioned)
                 with scope("pinot.groupby." + regime):
                     res = grouped(key, num_seg, sum_rows[1:],
-                                  caps.partition_block)
+                                  caps.partition_block, took)
                 out["count"] = res[0]
                 for arr, name in zip(res[1:], sum_names[1:]):
                     out[name] = arr
@@ -1021,6 +1118,10 @@ def _make_body(spec: KernelSpec):
                         op = (jax.ops.segment_min if is_min
                               else jax.ops.segment_max)
                         out[name] = op(v, key, num_segments=num_seg)
+            if took:
+                # compact only if every sort regime of the scan took it
+                out[qstats.COMPACT_FLAG] = jnp.all(
+                    jnp.stack(took)).astype(jnp.int32)
         else:
             with scope("pinot.agg"):
                 fmask = mask.ravel().astype(jnp.float32)
@@ -1101,7 +1202,15 @@ def run_kernel(spec: KernelSpec, inputs: KernelInputs) -> Dict[str, np.ndarray]:
         qstats.record(qstats.GATHER_FREE_LAUNCHES)
     # device_get, never np.asarray: asarray syncs leaf by leaf, device_get
     # fetches the whole tree in one batched round trip
-    return fetch_outputs(dispatch_kernel(spec, inputs))
+    return _record_decode(fetch_outputs(dispatch_kernel(spec, inputs)))
+
+
+def _record_decode(outs):
+    """Count which decode branch a fetched sort-regime launch ran."""
+    took = qstats.decode_branch(outs)
+    if took:
+        qstats.record(took)
+    return outs
 
 
 def _staged_agg_spec(spec: KernelSpec) -> KernelSpec:
@@ -1135,7 +1244,7 @@ def run_kernel_staged(spec: KernelSpec,
                                 inputs.iscal, inputs.fscal, inputs.nulls,
                                 mask_dev, inputs.strides, inputs.agg_luts,
                                 (), ())
-    return fetch_outputs(outs)
+    return _record_decode(fetch_outputs(outs))
 
 
 def _mask_kernel(spec: KernelSpec):

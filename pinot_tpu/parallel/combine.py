@@ -832,21 +832,31 @@ class MeshQueryExecutor:
                 entry[1].pad_waste_pct)
         return entry[1]
 
-    def _finish_mesh_stats(self, res, block: SegmentSetBlock):
-        """Attach the launch's worst per-device doc-load skew
-        (`deviceSkewPct`, max-merged upstream) to a decoded result. Partials
-        carry it in `SegmentResult.stats` (riding the wire to the broker
-        merge); full results record into the request thread's active stats."""
-        if self.n_devices <= 1:
+    def _finish_mesh_stats(self, res, block: SegmentSetBlock, outs=None):
+        """Attach to a decoded result what only the launch knows: its worst
+        per-device doc-load skew (`deviceSkewPct`, max-merged upstream; a mesh
+        of one has none) and which decode branch its sort regimes ran
+        (`qstats.decode_branch` of the fetched `outs`). Partials carry both in
+        `SegmentResult.stats` (riding the wire to the broker merge); full
+        results record into the request thread's active stats."""
+        took = qstats.decode_branch(outs)
+        skewed = self.n_devices > 1
+        if not (took or skewed):
             return res
         from ..query.reduce import SegmentResult
         if isinstance(res, SegmentResult):
             st = dict(res.stats or {})
-            st[qstats.DEVICE_SKEW_PCT] = max(
-                st.get(qstats.DEVICE_SKEW_PCT, 0.0), block.skew_pct)
+            if skewed:
+                st[qstats.DEVICE_SKEW_PCT] = max(
+                    st.get(qstats.DEVICE_SKEW_PCT, 0.0), block.skew_pct)
+            if took:
+                st[took] = st.get(took, 0) + 1
             res.stats = st
         else:
-            qstats.record_max(qstats.DEVICE_SKEW_PCT, block.skew_pct)
+            if skewed:
+                qstats.record_max(qstats.DEVICE_SKEW_PCT, block.skew_pct)
+            if took:
+                qstats.record(took)
         return res
 
     def _dispatch_sharded(self, ctx: QueryContext, plan, segments, view=None,
@@ -995,7 +1005,7 @@ class MeshQueryExecutor:
         )
 
         def decode(outs):
-            return self._finish_mesh_stats(_decode_impl(outs), block)
+            return self._finish_mesh_stats(_decode_impl(outs), block, outs)
 
         def _decode_impl(outs):
             # replicated outputs decode exactly like the single-segment path;

@@ -76,6 +76,15 @@ STAGED_LAUNCHES = "stagedLaunches"
 # in them: every dict column's decode table was small enough for the select
 # tree (kernels.SELECT_DECODE_CAP), so the decode fused into the scan (PR 27)
 GATHER_FREE_LAUNCHES = "gatherFreeLaunches"
+# which decode a sort-regime GROUP BY launch ran (PR 29): the dense answer from
+# the sorted prefix of rows that passed the filter (compact), or the per-key
+# binary searches (dense). The kernel decides on the device and returns the
+# scalar COMPACT_FLAG with its outputs (1 only if every sort regime of the
+# scan, on every chip, took compact); a program built without the branch
+# returns none and counts neither
+COMPACT_DECODE_LAUNCHES = "compactDecodeLaunches"
+DENSE_DECODE_LAUNCHES = "denseDecodeLaunches"
+COMPACT_FLAG = "decode.compact"
 NUM_CONSUMING_SEGMENTS_QUERIED = "numConsumingSegmentsQueried"
 MIN_CONSUMING_FRESHNESS_TIME_MS = "minConsumingFreshnessTimeMs"
 MUX_FRAME_QUEUE_MS = "muxFrameQueueMs"
@@ -125,6 +134,7 @@ COUNTER_KEYS = (
     QUEUE_WAIT_MS, DEVICE_PREPARE_MS, DEVICE_LAUNCH_MS, DEVICE_HANDOFF_MS,
     DEVICE_DECODE_MS, DEDUPED_LAUNCHES, STACKED_LAUNCHES,
     FUSED_LAUNCHES, STAGED_LAUNCHES, GATHER_FREE_LAUNCHES,
+    COMPACT_DECODE_LAUNCHES, DENSE_DECODE_LAUNCHES,
     NUM_CONSUMING_SEGMENTS_QUERIED, MUX_FRAME_QUEUE_MS, MUX_FLOW_CONTROL_MS,
     MESH_LAUNCHES, SCATTER_LAUNCHES, COLLECTIVE_BYTES,
     HEDGED_REQUESTS, ADMISSION_DEFER_MS,
@@ -286,6 +296,16 @@ def record(key: str, n: float = 1) -> None:
     st = getattr(_local, "stats", None)
     if st is not None:
         st.add(key, n)
+
+
+def decode_branch(outs) -> Optional[str]:
+    """The counter one launch's fetched outputs add to: which decode branch
+    its sort regimes ran (COMPACT_FLAG), or None for a program without the
+    branch (and for whatever a test's fake executor hands back)."""
+    flag = outs.get(COMPACT_FLAG) if isinstance(outs, dict) else None
+    if flag is None:
+        return None
+    return COMPACT_DECODE_LAUNCHES if int(flag) else DENSE_DECODE_LAUNCHES
 
 
 def record_min(key: str, v: float) -> None:
