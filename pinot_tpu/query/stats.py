@@ -84,6 +84,8 @@ GATHER_FREE_LAUNCHES = "gatherFreeLaunches"
 # returns none and counts neither
 COMPACT_DECODE_LAUNCHES = "compactDecodeLaunches"
 DENSE_DECODE_LAUNCHES = "denseDecodeLaunches"
+# graftcheck: ignore[drift-stats-keys] -- the kernel OUTPUT's name (read by
+# decode_branch below), never a key of a stats record
 COMPACT_FLAG = "decode.compact"
 NUM_CONSUMING_SEGMENTS_QUERIED = "numConsumingSegmentsQueried"
 MIN_CONSUMING_FRESHNESS_TIME_MS = "minConsumingFreshnessTimeMs"

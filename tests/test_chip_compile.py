@@ -194,6 +194,13 @@ def test_served_agg_kernel_compiles_for_v5e(topo, cpu_exec, segments, name,
     if name == "q1.1 filter+sum":
         # the served scan decodes its dict columns in-register
         assert p.spec.fused_cols, "q1.1 no longer rides the fused decode"
+    if regime is not None:
+        # 500k keys: the program holds both decodes of its sorted rows under
+        # one conditional (PR 29), and the dense one's searches stay inside it
+        text = compiled.as_text()
+        assert " conditional(" in text
+        for branch in ("compact", "dense"):
+            assert f"pinot.groupby.{regime}.{branch}" in text, branch
 
 
 def test_topk_kernel_compiles_for_v5e(topo, cpu_exec, segments):

@@ -373,6 +373,151 @@ def test_scatter_escape_hatch_matches_host(vhc_segments, mesh_exec):
         set_caps(prev)
 
 
+# --- the compact decode of the sort regimes (PR 29) --------------------------
+# 6000 keys pad to 8192 (nseg 8193) and cross a forced chunk_cap of 4096;
+# `pos` numbers the rows, so `WHERE pos < m` lets exactly m rows pass.
+COMPACT_ROWS = 12_000      # pads to 16,384 rows on one device
+
+
+@pytest.fixture(scope="module")
+def compact_segment(tmp_path_factory):
+    rng = np.random.default_rng(29)
+    rows = COMPACT_ROWS
+    schema = Schema("cd", [
+        dimension("k", DataType.INT),
+        metric("pos", DataType.INT),
+        metric("v", DataType.DOUBLE),
+        metric("q", DataType.INT),
+    ])
+    v = np.round(rng.uniform(-500, 500, rows), 3).astype(object)
+    v[rng.random(rows) < 0.02] = None          # a table with nulls
+    cols = {
+        # runs of ~2 rows a key, so the prefix's last row ends a real group
+        "k": rng.integers(0, 6000, rows).astype(np.int32),
+        "pos": np.arange(rows, dtype=np.int32),
+        "v": v,
+        "q": rng.integers(0, 1 << 30, rows).astype(np.int32),
+    }
+    out = tmp_path_factory.mktemp("cd")
+    cfg = SegmentGeneratorConfig(no_dictionary_columns=["pos", "v", "q"])
+    paths = build_aligned_segments(schema, cols, str(out), "cd", 1, config=cfg)
+    return load_segment(paths[0])
+
+
+def _compact_sql(seg, m):
+    """Exactly m rows pass. For none, a range the segment's min/max cannot
+    prune: the first row alone, less itself by its own `q`."""
+    where = f"pos < {m}" if m else \
+        f"pos < 1 AND q > {int(np.asarray(seg.column('q').values())[0])}"
+    return ("SELECT k, COUNT(*), SUM(v), SUM(q) FROM cd "
+            f"WHERE {where} GROUP BY k ORDER BY k LIMIT 3000000")
+
+
+def _executed(mex, segs, sql):
+    """(rows, the decode counters the launch recorded)."""
+    from pinot_tpu.query import stats as qstats
+    with qstats.collect_stats() as st:
+        rows = mex.execute(segs, sql).rows
+    return rows, {k: int(st.counters.get(k, 0)) for k in (
+        qstats.COMPACT_DECODE_LAUNCHES, qstats.DENSE_DECODE_LAUNCHES)}
+
+
+def _dense_only(monkeypatch):
+    """Programs built from here on hold today's decode alone: no branch."""
+    from pinot_tpu.engine import kernels
+    from pinot_tpu.parallel import combine
+    monkeypatch.setattr(kernels, "compact_cap", lambda n, nseg, block: 0)
+    monkeypatch.setattr(combine, "_SHARD_KERNEL_CACHE", {})
+
+
+@pytest.mark.parametrize("block", [256, 320], ids=["aligned", "ragged"])
+@pytest.mark.parametrize("passing", ["0", "1", "cap-1", "cap", "cap+1", "all"])
+@pytest.mark.parametrize("regime", ["partitioned", "sorted"])
+def test_compact_decode_matches_dense_and_host(compact_segment, monkeypatch,
+                                               regime, passing, block):
+    """Both branches of a sort regime's decode, against the host executor and
+    against each other, around the cap: the sorted prefix of rows that passed
+    answers up to `cap` rows (the last of them ends its group on the prefix's
+    last row), the per-key decode above. 16,384 padded rows are a multiple of
+    a 256-row block and 64 short of one of 320 (the sort pads them)."""
+    from pinot_tpu.engine import kernels
+    n = 16_384 + (-16_384) % block
+    cap = kernels.compact_cap(n, 8193, block)
+    assert cap == n // 64
+    m = {"0": 0, "1": 1, "cap-1": cap - 1, "cap": cap, "cap+1": cap + 1,
+         "all": COMPACT_ROWS}[passing]
+    sql = _compact_sql(compact_segment, m)
+    mex = MeshQueryExecutor(default_mesh(1))
+    prev = get_caps()
+    set_caps(KernelCaps(chunk_cap=4096, high_card_regime=regime,
+                        partition_block=block))
+    try:
+        got, took = _executed(mex, [compact_segment], sql)
+        want = ServerQueryExecutor(use_device=False).execute(
+            [compact_segment], sql).rows
+        _dense_only(monkeypatch)
+        dense, neither = _executed(mex, [compact_segment], sql)
+    finally:
+        set_caps(prev)
+    assert took == {"compactDecodeLaunches": int(m <= cap),
+                    "denseDecodeLaunches": int(m > cap)}
+    assert neither == {"compactDecodeLaunches": 0, "denseDecodeLaunches": 0}
+    assert len(want) == len(np.unique(
+        np.asarray(compact_segment.column("k").values())[:m]))
+    _assert_rows_close(got, want, (regime, passing, block))
+    _assert_rows_close(dense, want, (regime, passing, block, "dense"))
+    # against each other: groups and counts exactly, sums within tolerance
+    assert [r[:2] for r in got] == [r[:2] for r in dense]
+    _assert_rows_close(got, dense, (regime, passing, block, "branches"))
+
+
+def one_full_segment_of_four(tmp_path_factory, name):
+    """Four aligned segments of 8,000 rows; `w < 100` passes every row of the
+    SECOND segment and 10 rows of each other one, `w < 10` passes 40 rows of
+    the second alone. (Not the first: the set is planned on its first
+    segment, whose min/max must not fold the predicate.)"""
+    rng = np.random.default_rng(31)
+    per = 8000
+    w = rng.integers(500, 1000, 4 * per).astype(np.int32)
+    w[per:2 * per] = rng.integers(10, 100, per)
+    w[per:per + 40] = 5
+    for s in (0, 2, 3):
+        w[s * per:s * per + 10] = 50
+    schema = Schema(name, [dimension("k", DataType.INT),
+                           metric("w", DataType.INT),
+                           metric("v", DataType.DOUBLE)])
+    cols = {"k": rng.integers(0, 6000, 4 * per).astype(np.int32), "w": w,
+            "v": np.round(rng.uniform(-500, 500, 4 * per), 3)}
+    out = tmp_path_factory.mktemp(name)
+    cfg = SegmentGeneratorConfig(no_dictionary_columns=["w", "v"])
+    return schema, cols, [load_segment(p) for p in build_aligned_segments(
+        schema, cols, str(out), name, 4, config=cfg)]
+
+
+def test_one_chip_dense_three_compact_on_the_mesh(tmp_path_factory):
+    """Four devices, a segment each: every row of one segment passes (that
+    chip's prefix does not fit: `dense`) and 10 rows of each other one
+    (`compact`). Each chip takes its own branch from its own count, the
+    answer equals the host's, and the launch counts as dense: compact only if
+    every chip took it."""
+    _, _, segs = one_full_segment_of_four(tmp_path_factory, "cm")
+    mex = MeshQueryExecutor(default_mesh(4))
+    host = ServerQueryExecutor(use_device=False)
+    prev = get_caps()
+    set_caps(KernelCaps(chunk_cap=4096, high_card_regime="partitioned"))
+    try:
+        for bound, passing, compact in ((100, 8030, 0), (10, 40, 1)):
+            sql = ("SELECT k, COUNT(*), SUM(v) FROM cm "
+                   f"WHERE w < {bound} GROUP BY k ORDER BY k LIMIT 3000000")
+            got, took = _executed(mex, segs, sql)
+            assert sum(r[1] for r in got) == passing
+            _assert_rows_close(got, host.execute(segs, sql).rows, bound)
+            assert took == {"compactDecodeLaunches": compact,
+                            "denseDecodeLaunches": 1 - compact}, bound
+    finally:
+        set_caps(prev)
+
+
 def _guaranteed_card_keys(rng, card, rows):
     """Exactly min(card, rows) distinct keys: one pass of every key, the rest
     random repeats. Pure random draws top out far below the nominal card
@@ -493,6 +638,21 @@ def test_dense_partial_roundtrip(vhc_segments, mesh_exec):
     _assert_rows_close(got.rows, want.rows, sql)
 
 
+def _scatter_update_rows(jaxpr):
+    """Rows of the updates operand of every scatter in a jaxpr, conditional
+    branches and other nested jaxprs included."""
+    sizes = []
+    for eqn in jaxpr.eqns:
+        if "scatter" in eqn.primitive.name:
+            sizes.append(int(np.prod(eqn.invars[2].aval.shape, dtype=np.int64)))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    sizes.extend(_scatter_update_rows(sub))
+    return sizes
+
+
 @pytest.mark.slow
 def test_no_flat_scatter_at_high_card(tmp_path_factory):
     """Regression guard: the >=128k-group count+sum kernel must never lower
@@ -535,5 +695,11 @@ def test_no_flat_scatter_at_high_card(tmp_path_factory):
         inputs.ids, inputs.vals, inputs.luts, inputs.iscal, inputs.fscal,
         inputs.nulls, inputs.valid, inputs.strides, inputs.agg_luts,
         inputs.docsets)
-    assert "scatter" not in str(jaxpr), \
-        ">=128k-group count+sum kernel dispatched through flat scatter"
+    # an n-row scatter is still forbidden; the compact decode's scatter-adds
+    # of the sorted prefix (`kernels.compact_cap` rows, n / 64) are not
+    n = block.padded
+    cap = kernels.compact_cap(n, plan.num_keys_pad + 1,
+                              get_caps().partition_block)
+    sizes = _scatter_update_rows(jaxpr.jaxpr)
+    assert sizes and set(sizes) == {cap, 1}, sizes   # 1: the overflow bucket
+    assert cap <= n // 64

@@ -30,6 +30,10 @@ TEMPLATES = ("q1.1", "q1.2", "q1.3", "q2.1", "q2.2", "q2.3", "q3.1", "q3.2",
 # the others are at or over combine.SCATTER_MIN_KEYS and divisible by 4
 SCATTERS = {t: t not in ("q1.1", "q1.2", "q1.3", "q4.1") for t in TEMPLATES}
 MESH_KEYS = ("meshLaunches", "scatterLaunches", "collectiveBytes")
+# past combine's chunk cap (131,072 keys) a GROUP BY takes the partitioned
+# sort, whose program holds both decodes (PR 29); the filters leave a few rows
+WIDE_KEY = ("q3.2", "q3.3", "q3.4", "q4.3")
+DECODE_KEYS = ("compactDecodeLaunches", "denseDecodeLaunches")
 
 
 def _serve_and_ask(work, config, mesh_devices, seg_src, pool):
@@ -124,16 +128,34 @@ def test_answer_says_what_crossed_the_chips(served, template):
     assert "collectiveMs" not in four and "collectiveMs" not in one
 
 
+@pytest.mark.parametrize("template", TEMPLATES)
+def test_wide_key_templates_say_which_decode_ran(served, template):
+    """A wide-key template's launch counts in exactly one of the two decode
+    counters, on a mesh of four and of one (at 16,384 rows a device the prefix
+    is 256 rows, which Q3.2's filter overflows: both branches answer here,
+    and `correct` above holds for both); a template that takes no sort regime
+    counts in neither."""
+    for n in (4, 1):
+        resp = served[n][0][template]
+        assert sum(resp[k] for k in DECODE_KEYS) == \
+            int(template in WIDE_KEY), (n, template)
+    took = {t: DECODE_KEYS[served[4][0][t]["denseDecodeLaunches"]]
+            for t in WIDE_KEY}
+    assert set(took.values()) == set(DECODE_KEYS), took
+
+
 def test_health_sums_what_the_answers_said(served):
     for n in (4, 1):
         answers, before, after = served[n]
-        for k in MESH_KEYS:
+        for k in MESH_KEYS + DECODE_KEYS:
             assert after[k] - before[k] == sum(a[k] for a in answers.values())
         for k in ("deviceErrors", "fallbacks", "timeouts"):
             assert after[k] == before[k], k
         assert after["launches"] - before["launches"] == len(answers)
     assert served[4][2]["scatterLaunches"] - served[4][1]["scatterLaunches"] \
         == sum(SCATTERS.values()) == 9
+    assert sum(served[4][2][k] - served[4][1][k] for k in DECODE_KEYS) \
+        == len(WIDE_KEY)
 
 
 @pytest.fixture(scope="module")

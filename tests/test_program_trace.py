@@ -29,6 +29,7 @@ MESH_CELL = "ssb10-flat-mesh4.flights-c4"
 MESH_METRICS = (
     "mesh.devices_busy", "kernels.collective_share",
     "mesh.scatter_launch_share", "mesh.collective_bytes_per_answer")
+DECODE_METRIC = "kernels.compact_decode_share"      # PR 29, every cell
 
 
 @pytest.fixture(scope="module")
@@ -72,11 +73,13 @@ def test_reader_returns_none_on_the_parents_run(name):
     assert cells.load_reader(name)(ctx) is None
 
 
-@pytest.mark.parametrize("name", NEW_METRICS + MESH_METRICS)
+@pytest.mark.parametrize("name",
+                         NEW_METRICS + MESH_METRICS + (DECODE_METRIC,))
 def test_every_new_metric_is_declared_like_the_old(name):
     """A `.json` with the keys of PR 25's and a `per_layer` entry that says
-    the same; PR 26's have no `workloads` key (every cell owes them), PR 28's
-    list the one four-chip cell (a mesh of one has nothing for them to read)."""
+    the same; PR 26's and PR 29's have no `workloads` key (every cell owes
+    them), PR 28's list the one four-chip cell (a mesh of one has nothing for
+    them to read)."""
     meta = cells.read_json(cells.BENCH, "metrics", name + ".json")
     bench = cells.read_json(cells.ROOT, "BENCHMARK.json")
     entry = [m for m in bench["per_layer"] if m["name"] == name]
@@ -91,6 +94,26 @@ def test_every_new_metric_is_declared_like_the_old(name):
         assert entry[0][key] == meta[key], key
     assert meta["name"] == name and meta["what"]
     assert meta["moves"] in ("qps", "mean_ms")
+
+
+@pytest.mark.parametrize("counters,want", [
+    # a window's deltas of /health's device block, made by hand
+    ({"compactDecodeLaunches": 152, "denseDecodeLaunches": 0}, 100.0),
+    ({"compactDecodeLaunches": 3, "denseDecodeLaunches": 1}, 75.0),
+    ({"compactDecodeLaunches": 0, "denseDecodeLaunches": 7}, 0.0),
+    # no launch held the two decode branches: nothing to read, never 0
+    ({"compactDecodeLaunches": 0, "denseDecodeLaunches": 0}, None),
+    # the parent's counters (PR 28's recorded run has neither key)
+    ({"batches": 10, "dispatched": 20, "meshLaunches": 0}, None),
+])
+def test_compact_decode_share_reads_the_counter_delta(counters, want,
+                                                      mesh_recorded, recorded):
+    read = cells.load_reader(DECODE_METRIC)
+    got = read(_ctx([], counters, None))
+    assert got == want if want is None else got == pytest.approx(want)
+    for parent in (mesh_recorded["counters"], recorded["counters"]):
+        assert "compactDecodeLaunches" not in parent
+        assert read(_ctx([], parent, None)) is None
 
 
 @pytest.fixture(scope="module")

@@ -669,3 +669,126 @@ def test_gather_free_launches_reaches_the_served_response(tmp_path):
         assert s["deviceLaunches"] >= 1, (name, s)
         assert s["fusedLaunches"] == fused, (name, s)
         assert s["gatherFreeLaunches"] == free, (name, s)
+
+
+# -- PR 29: the sort regimes' two decodes, their scopes and their counters ----
+
+@pytest.mark.parametrize("regime", ["sorted", "partitioned"])
+def test_sort_regime_program_holds_both_decodes(scope_segment, regime):
+    """One conditional on the count of rows that passed: the answer from the
+    sorted prefix under `.compact`, today's per-key decode (its `.trim` and
+    `.scan` names unchanged) under `.dense`; the sort is shared. Neither is
+    the flat n-row scatter, whose scope name stays its own."""
+    from pinot_tpu.engine.calibrate import KernelCaps, get_caps, set_caps
+    prev = get_caps()
+    set_caps(KernelCaps(chunk_cap=4096, high_card_regime=regime))
+    try:
+        _, text = _lowered_text(
+            scope_segment,
+            "SELECT k, COUNT(*), SUM(d) FROM scopes WHERE w > 0 GROUP BY k")
+    finally:
+        set_caps(prev)
+    assert "stablehlo.case" in text
+    assert f"pinot.groupby.{regime}/pinot.groupby.{regime}.sort/" in text
+    for branch in ("compact", "dense"):     # each inside the conditional
+        assert re.search(rf"pinot\.groupby\.{regime}/cond/branch_\d_fun/"
+                         rf"pinot\.groupby\.{regime}\.{branch}/", text), branch
+    for part in ("trim", "scan"):       # inside the dense branch, as before
+        assert f"pinot.groupby.{regime}.dense/pinot.groupby.{regime}.{part}" \
+            in text, part
+        assert f"pinot.groupby.{regime}.compact/pinot.groupby.{regime}." \
+            f"{part}" not in text, part
+    assert "pinot.groupby.scatter" not in text
+    from benchmark.harness.program_trace import scope_of
+    assert scope_of(f"jit(pinot_groupby)/pinot.groupby.{regime}/cond/"
+                    f"branch_1_fun/pinot.groupby.{regime}.compact/"
+                    "scatter-add:") \
+        == f"pinot.groupby.{regime}"        # still one family for the share
+
+
+def test_small_key_program_past_2_24_rows_holds_no_decode_branch():
+    """Where today's decode costs less than a `cap`-row pass no branch is
+    built: the full-size cell's 8,193-key templates (32Mi and 64Mi rows, where
+    every GROUP BY takes a sort regime) keep their one-branch programs, the
+    wide-key ones get n / 64 rows."""
+    import jax
+    import jax.numpy as jnp
+    from pinot_tpu.engine import kernels
+    for n in (1 << 25, 1 << 26):
+        assert kernels.compact_cap(n, 8193, 4096) == 0
+        assert kernels.compact_cap(n, 438_273, 4096) == n // 64
+        assert kernels.compact_cap(n, 1_753_089, 4096) == n // 64
+    assert kernels.compact_cap(1 << 24, 438_273, 4096) == 262_144
+    n = 1 << 25
+    took = []
+    text = jax.jit(lambda k, v: kernels._grouped_partitioned(
+        k, 8193, [v], 4096, took)).lower(
+            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n,), jnp.float32)).as_text(debug_info=True)
+    assert not took                     # no scalar: the host counts neither
+    for part in ("sort", "trim", "scan"):
+        assert f"pinot.groupby.partitioned.{part}" in text, part
+    for absent in ("pinot.groupby.partitioned.compact", "stablehlo.case",
+                   "pinot.groupby.partitioned.dense", "stablehlo.scatter"):
+        assert absent not in text, absent
+
+
+def test_decode_counters_reach_response_explain_and_health(tmp_path):
+    """Through the device pipeline of a served cluster whose mesh is four
+    devices, a segment each: a query that passes 40 rows answers with
+    `compactDecodeLaunches` 1; one that passes every row of ONE chip's segment
+    (and 10 rows of each other chip's) with `denseDecodeLaunches` 1, since a
+    mesh launch is compact only if every chip took it; the same GROUP BY under
+    the default caps, where it takes no sort regime, with neither. EXPLAIN ANALYZE carries the same fields and
+    `/health`'s device block (the pipeline's `stats()`) sums the launches."""
+    from tests.test_dense_groupby import one_full_segment_of_four
+    from pinot_tpu.cluster.device_server import DeviceQueryPipeline
+    from pinot_tpu.engine.calibrate import KernelCaps, get_caps, set_caps
+    from pinot_tpu.parallel import MeshQueryExecutor, default_mesh
+    from pinot_tpu.table import IndexingConfig
+    schema, cols, _ = one_full_segment_of_four(
+        type("f", (), {"mktemp": staticmethod(
+            lambda name: tmp_path / f"unused_{name}")}), "dc")
+    # every key in every segment: four equal dictionaries, the aligned path
+    # (5,000 keys of 8,000 rows: few enough to stay dictionary-encoded)
+    per = len(cols["k"]) // 4
+    cols["k"] %= 5000
+    for s in range(4):
+        cols["k"][s * per + 100:s * per + 5100] = np.arange(5000)
+    cluster = QuickCluster(num_servers=1, work_dir=str(tmp_path / "cluster"))
+    cluster.servers[0].device_pipeline = pipeline = DeviceQueryPipeline(
+        mesh_exec=MeshQueryExecutor(default_mesh(4)))
+    cfg = TableConfig("dc", indexing=IndexingConfig(
+        no_dictionary_columns=["w", "v"]))
+    cluster.create_table(schema, cfg)
+    for s in range(4):
+        cluster.ingest_columns(cfg, {c: v[s * per:(s + 1) * per]
+                                     for c, v in cols.items()})
+    sql = "SELECT k, COUNT(*), SUM(v) FROM dc WHERE w < {} GROUP BY k " \
+          "ORDER BY k LIMIT 10000"
+    prev = get_caps()
+    try:
+        # 8,192 padded keys: the chunked matmul by default, a sort regime
+        # once the chunk cap is forced under them
+        got = {"no sort regime": cluster.query(sql.format(100))}
+        set_caps(KernelCaps(chunk_cap=4096))
+        got.update({
+            "compact": cluster.query(sql.format(10)),
+            "dense": cluster.query(sql.format(100)),
+            "explain": cluster.query("EXPLAIN ANALYZE " + sql.format(11))})
+        health = pipeline.stats()
+    finally:
+        set_caps(prev)
+        pipeline.stop()
+    assert sum(r[1] for r in got["compact"].rows) == 40
+    assert sum(r[1] for r in got["dense"].rows) == per + 30
+    for name, compact, dense in (("compact", 1, 0), ("dense", 0, 1),
+                                 ("no sort regime", 0, 0), ("explain", 1, 0)):
+        s = got[name].stats
+        assert s["deviceLaunches"] >= 1 and s["meshLaunches"] >= 1, (name, s)
+        assert (s["compactDecodeLaunches"], s["denseDecodeLaunches"]) \
+            == (compact, dense), (name, s)
+    assert got["explain"].stats["analyze"] is True
+    assert (health["compactDecodeLaunches"], health["denseDecodeLaunches"]) \
+        == (2, 1)
+    assert health["deviceErrors"] == 0 and health["fallbacks"] == 0
