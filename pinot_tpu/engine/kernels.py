@@ -739,80 +739,140 @@ def _counts_from_sorted(key_s: jnp.ndarray, nseg: int, pad: int):
 
 
 def compact_cap(n: int, nseg: int, block: int) -> int:
-    """How many sorted rows the compact decode reads, from shapes alone: n / 64
-    (the widest SSB template passes n / 630), or 0 where no branch is built
-    because today's per-key decode is the cheaper of the two: its gathers,
-    nseg x (log2 n + log2(n / block) + 5), against `cap` updates. At 67M rows
-    the 8,193-key templates decode with 0.4M gathers against 1M updates, so
-    their programs hold one branch, as before."""
+    """How many sorted rows the compact decode reads at most, from shapes
+    alone: n / 64 (the widest SSB template passes n / 630), or 0 where no
+    branch is built because today's per-key decode is the cheaper of the two:
+    its gathers, nseg x (log2 n + log2(n / block) + 5), against `cap` updates.
+    At 67M rows the 8,193-key templates decode with 0.4M gathers against 1M
+    updates, so their programs hold one branch, as before."""
     cap = n // 64
     steps = n.bit_length() + max(n // block, 1).bit_length() + 3  # the log2s + 5
     return cap if nseg * steps > cap > 0 else 0
 
 
+# Shorter prefixes tried before `compact_cap` rows, where they are at most a
+# quarter of it: a pass over 2^20 rows is 15.6 ms on the v5e and one over 2^13
+# 1.2 ms (PR 29's chip probe), and Q3.4 passes 50 rows
+COMPACT_RUNGS = (1 << 13, 1 << 17)
+
+
+SCAN_WIDTH = 256  # rows a block of `_run_totals`
+
+
+def _run_totals(head: jnp.ndarray, v: jnp.ndarray):
+    """Running length (int32 [L]) and running sums (`v` [R, L] -> f32 [R, L])
+    of every run of rows, restarting wherever `head` [L] is set; at a run's
+    last row they are the run's. L is a multiple of SCAN_WIDTH.
+
+    Two levels. Inside a block of SCAN_WIDTH rows, a masked max over the
+    block's [W, W] triangle finds where row w's run began and a masked sum
+    adds the rows since: two reduces XLA fuses (nothing of that size is
+    written). Across blocks, the blocks' closing lengths and sums go through a
+    segmented `associative_scan` over L / SCAN_WIDTH elements, and a block's
+    rows before its first head add what the blocks before them carried.
+    NOT a flat `associative_scan`, `cumsum` or `cummax` over L: they compute
+    the same and run as fast, but the v5e's compiler takes its time over each
+    by the row count (2^20 rows: 26-77 s apiece, against 0.8 s for the masked
+    reduce and 1.2 s for the scan over 4,096 blocks; PR 29, compiled for the
+    described chip). A sum is still added as a tree: a block's reduce, then
+    about log2(L / SCAN_WIDTH) levels."""
+    r, length = v.shape
+    nb, w = length // SCAN_WIDTH, SCAN_WIDTH
+    at = jnp.arange(w, dtype=jnp.int32)
+    upto = jnp.tri(w, dtype=bool)                               # u <= w
+    heads = head.reshape(nb, 1, w)
+    # in-block row where w's run began; -1: in a block before this one
+    began = jnp.max(jnp.where(heads & upto, at, -1), axis=-1)   # [nb, w]
+    seen = began >= 0
+    mine = upto & (at >= began[:, :, None])                     # [nb, w, u]
+    sums = jnp.sum(jnp.where(mine[None], v.reshape(r, nb, 1, w), 0.0),
+                   axis=-1)                                     # [r, nb, w]
+    lengths = at - jnp.maximum(began, 0) + 1
+    closed = seen[:, -1]
+    _, carried = jax.lax.associative_scan(
+        _seg_sum_op, (jnp.broadcast_to(closed, (r, nb)), sums[:, :, -1]),
+        axis=1)
+    _, carried_len = jax.lax.associative_scan(
+        _seg_sum_op, (closed, lengths[:, -1]))
+    owed = jnp.pad(carried[:, :-1], ((0, 0), (1, 0)))       # by the blocks before
+    owed_len = jnp.pad(carried_len[:-1], (1, 0))
+    return ((lengths + jnp.where(seen, 0, owed_len[:, None])).reshape(length),
+            (sums + jnp.where(seen[None], 0.0, owed[:, :, None])
+             ).reshape(r, length))
+
+
 def _compact_decode(key_c: jnp.ndarray, vals_c, m, nseg: int, rows: int):
     """Dense [nseg] counts and sums from the sorted PREFIX `key_c`, `vals_c`
-    (the first `cap` sorted rows), of which the first `m` passed the filter;
-    rows past m carry the overflow key nseg-1 and zero values. Work is set by
-    `cap`, not by nseg: one segmented scan over the prefix gives every run's
-    total at its last row (added as a tree, so a run of a million rows keeps
-    f32's per-element precision), and one scatter-add of `cap` sorted updates
-    per output lands the run tails in zeros[nseg] — every other row adds an
-    exact 0. Counts are run lengths, int32 throughout. `rows` is the real
-    (unpadded) row count: the overflow bucket holds rows - m, as the dense
-    decode's does. Returns [int32 counts[nseg], f32 sums[nseg]...]."""
-    cap = key_c.size
-    pos = jnp.arange(cap, dtype=jnp.int32)
+    (the first rows of the sorted array), of which the first `m` passed the
+    filter; rows past m carry the overflow key nseg-1 and zero values. Work is
+    set by the prefix's length, not by nseg: `_run_totals` gives every run's
+    length and sums at its last row (added as a tree, so a run of a million
+    rows keeps f32's per-element precision), and one scatter per output SETS
+    the run tails into zeros[nseg] — a run has one tail, so the indices are
+    unique, and every other row is sent out of bounds and dropped (on the v5e
+    4.6 ms for 2^18 rows and 15.6 for 2^20, against 6.7 and 24.3 as a
+    scatter-add of zeros and 5.6 and 19.3 for a plain scatter-add of every
+    row, which adds a run in row order; PR 29's chip probe). Counts are run
+    lengths, int32 throughout. `rows` is the real (unpadded) row count: the
+    overflow bucket holds rows - m, as the dense decode's does.
+    Returns [int32 counts[nseg], f32 sums[nseg]...]."""
+    short = (-key_c.size) % SCAN_WIDTH
+    if short:   # more rows that did not pass
+        key_c = jnp.pad(key_c, (0, short), constant_values=nseg - 1)
+        vals_c = [jnp.pad(v, (0, short)) for v in vals_c]
+    length = key_c.size
+    pos = jnp.arange(length, dtype=jnp.int32)
     nxt = jnp.concatenate([key_c[1:], jnp.full((1,), nseg - 1, key_c.dtype)])
     # a live run ends where the next row has another key; past m every row
     # has the overflow key, which no live row has. The prefix's last row
-    # ends its run whatever follows it (m <= cap: the next row did not pass)
-    tail = (pos < m) & ((key_c != nxt) | (pos == cap - 1))
+    # ends its run whatever follows it (m <= its length: the next row, if
+    # there is one, did not pass)
+    tail = (pos < m) & ((key_c != nxt) | (pos == length - 1))
     head = jnp.concatenate([jnp.ones((1,), bool), key_c[1:] != key_c[:-1]])
-    start = jax.lax.cummax(jnp.where(head, pos, 0))
-    add = lambda zero, upd: zero.at[key_c].add(      # noqa: E731
-        upd, indices_are_sorted=True, mode="promise_in_bounds")
-    counts = add(jnp.zeros((nseg,), jnp.int32),
-                 jnp.where(tail, pos - start + 1, 0))
-    outs = [counts.at[nseg - 1].set(jnp.int32(rows) - m)]
-    if vals_c:
-        v = jnp.stack(vals_c)  # [R, cap]
-        flags = jnp.broadcast_to(head[None, :], v.shape)
-        _, scan = jax.lax.associative_scan(_seg_sum_op, (flags, v), axis=1)
-        for r in range(v.shape[0]):
-            outs.append(add(jnp.zeros((nseg,), jnp.float32),
-                            jnp.where(tail, scan[r], 0.0)))
-    return outs
+    v = jnp.stack(vals_c) if vals_c else jnp.zeros((0, length), jnp.float32)
+    lengths, totals = _run_totals(head, v)
+    idx = jnp.where(tail, key_c, nseg + pos)
+    put = lambda zero, upd: zero.at[idx].set(        # noqa: E731
+        upd, unique_indices=True, mode="drop")
+    counts = put(jnp.zeros((nseg,), jnp.int32), lengths)
+    return [counts.at[nseg - 1].set(jnp.int32(rows) - m)] + [
+        put(jnp.zeros((nseg,), jnp.float32), t) for t in totals]
 
 
 def _decode_sorted(regime: str, key_s, vals_s, nseg: int, pad: int, block: int,
                    dense, took):
     """The dense [nseg] answer of a sort regime from its sorted rows: `dense`
     (the per-key decode, a binary search for every dense key) or, where few
-    rows passed the filter, `_compact_decode` over the sorted prefix. One HLO
-    conditional on m, the count of rows that passed, which only the device
-    knows; both branches share the sort. Where `compact_cap` builds no branch
-    the program is the dense decode alone. `took` (a list, or None) collects
-    the scalar that says which branch ran."""
+    rows passed the filter, `_compact_decode` over the sorted prefix: the
+    shortest of a short ladder of prefixes (COMPACT_RUNGS, then
+    `compact_cap`) that holds them. One HLO conditional on m, the count of
+    rows that passed, which only the device knows; one branch runs, and all
+    share the sort. Where `compact_cap` builds no branch the program is the
+    dense decode alone. `took` (a list, or None) collects the scalar that
+    says whether a compact branch ran."""
     n = key_s.size
     cap = compact_cap(n, nseg, block)
     if not cap:
         return dense()
+    rungs = tuple(c for c in COMPACT_RUNGS if 4 * c <= cap) + (cap,)
     m = jnp.sum(key_s < nseg - 1, dtype=jnp.int32)
-    fits = m <= cap
 
-    def compact():
-        with jax.named_scope(f"pinot.groupby.{regime}.compact"):
-            return _compact_decode(key_s[:cap], [v[:cap] for v in vals_s], m,
-                                   nseg, n - pad)
+    def compact(rows):
+        def branch():
+            with jax.named_scope(f"pinot.groupby.{regime}.compact"):
+                return _compact_decode(key_s[:rows], [v[:rows] for v in vals_s],
+                                       m, nseg, n - pad)
+        return branch
 
     def dense_branch():
         with jax.named_scope(f"pinot.groupby.{regime}.dense"):
             return dense()
 
     if took is not None:
-        took.append(fits)
-    return jax.lax.cond(fits, compact, dense_branch)
+        took.append(m <= cap)
+    # the first rung that holds m rows; past the last one, the dense decode
+    rung = jnp.sum(m > jnp.asarray(rungs, jnp.int32), dtype=jnp.int32)
+    return jax.lax.switch(rung, [compact(c) for c in rungs] + [dense_branch])
 
 
 def _grouped_sorted(key: jnp.ndarray, nseg: int, value_rows, block: int = 4096,

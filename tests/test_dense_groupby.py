@@ -471,6 +471,65 @@ def test_compact_decode_matches_dense_and_host(compact_segment, monkeypatch,
     _assert_rows_close(got, dense, (regime, passing, block, "branches"))
 
 
+@pytest.mark.parametrize("length,p_head", [
+    (256, 0.3), (1024, 0.3), (4096, 0.01), (8192, 0.0005), (2048, 1.0),
+    (2048, 0.0)])
+@pytest.mark.parametrize("rows", [2, 0], ids=["sums", "lengths-only"])
+def test_run_totals_match_a_row_by_row_walk(length, p_head, rows):
+    """Running lengths exactly and running sums to f32 rounding, for runs
+    inside one 256-row block, across many, one row long, and one run in all."""
+    import jax
+    from pinot_tpu.engine.kernels import _run_totals
+    rng = np.random.default_rng(length)
+    head = rng.random(length) < p_head
+    head[0] = True
+    v = rng.uniform(-500, 500, (rows, length)).astype(np.float32)
+    lengths, sums = jax.jit(_run_totals)(head, v)
+    want_len = np.zeros(length, np.int64)
+    want = np.zeros((rows, length))
+    acc, run = np.zeros(rows), 0
+    for i in range(length):
+        acc = v[:, i].astype(np.float64) if head[i] else acc + v[:, i]
+        run = 1 if head[i] else run + 1
+        want[:, i], want_len[i] = acc, run
+    assert np.array_equal(np.asarray(lengths), want_len)
+    assert lengths.dtype == np.int32 and sums.shape == (rows, length)
+    np.testing.assert_allclose(np.asarray(sums), want, rtol=2e-6, atol=1e-2)
+
+
+@pytest.mark.parametrize("regime", ["partitioned", "sorted"])
+def test_compact_ladder_takes_the_shortest_prefix_that_fits(monkeypatch,
+                                                            regime):
+    """A ladder of prefixes (16 and 64 rows here, then the cap's 256) chosen
+    by the same count: each side of every rung answers as numpy does, with
+    three keys so that a run spans many blocks of the run-totals scan."""
+    import jax
+    from pinot_tpu.engine import kernels
+    monkeypatch.setattr(kernels, "COMPACT_RUNGS", (16, 64))
+    fn = (kernels._grouped_partitioned if regime == "partitioned"
+          else kernels._grouped_sorted)
+    n, nseg, block = 16_384, 8193, 256
+    took = []
+    run = jax.jit(lambda k, v: (fn(k, nseg, [v], block, took), took[-1]))
+    jaxpr = str(jax.make_jaxpr(lambda k, v: fn(k, nseg, [v], block))(
+        np.zeros(n, np.int32), np.zeros(n, np.float32)))
+    assert jaxpr.count("branches=") == 1        # one conditional, four ways
+    rng = np.random.default_rng(9)
+    for m in (0, 1, 16, 17, 64, 65, 256, 257, n):
+        key = np.full(n, nseg - 1, np.int32)
+        key[rng.choice(n, m, replace=False)] = rng.integers(0, 3, m) * 100
+        v = np.where(key < nseg - 1, rng.uniform(-500, 500, n),
+                     0).astype(np.float32)
+        (counts, sums), compact = run(key, v)
+        assert bool(compact) == (m <= 256), m
+        assert np.array_equal(np.asarray(counts),
+                              np.bincount(key, minlength=nseg)), m
+        np.testing.assert_allclose(
+            np.asarray(sums), np.bincount(key, weights=v.astype(np.float64),
+                                          minlength=nseg),
+            rtol=1e-5, atol=1e-3, err_msg=str(m))
+
+
 def one_full_segment_of_four(tmp_path_factory, name):
     """Four aligned segments of 8,000 rows; `w < 100` passes every row of the
     SECOND segment and 10 rows of each other one, `w < 10` passes 40 rows of
