@@ -751,8 +751,8 @@ def compact_cap(n: int, nseg: int, block: int) -> int:
 
 
 # Shorter prefixes tried before `compact_cap` rows, where they are at most a
-# quarter of it: a pass over 2^20 rows is 15.6 ms on the v5e and one over 2^13
-# 1.2 ms (PR 29's chip probe), and Q3.4 passes 50 rows
+# quarter of it: a pass over 2^20 rows is 11.9 ms on the v5e, one over 2^17
+# 2.3 ms and one over 2^13 1.0 ms (PR 29's chip probe), and Q3.4 passes 50 rows
 COMPACT_RUNGS = (1 << 13, 1 << 17)
 
 
@@ -810,9 +810,10 @@ def _compact_decode(key_c: jnp.ndarray, vals_c, m, nseg: int, rows: int):
     rows keeps f32's per-element precision), and one scatter per output SETS
     the run tails into zeros[nseg] — a run has one tail, so the indices are
     unique, and every other row is sent out of bounds and dropped (on the v5e
-    4.6 ms for 2^18 rows and 15.6 for 2^20, against 6.7 and 24.3 as a
-    scatter-add of zeros and 5.6 and 19.3 for a plain scatter-add of every
-    row, which adds a run in row order; PR 29's chip probe). Counts are run
+    3.8 ms in all for 2^18 rows and 11.9 for 2^20, against 6.7 and 24.3 with
+    a scatter-add of the tails among zeros, and 5.6 and 19.3 for a plain
+    scatter-add of every row, which adds a run in row order; PR 29's chip
+    probe). Counts are run
     lengths, int32 throughout. `rows` is the real (unpadded) row count: the
     overflow bucket holds rows - m, as the dense decode's does.
     Returns [int32 counts[nseg], f32 sums[nseg]...]."""
@@ -1005,12 +1006,11 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
 def combine_collective(name: str, v, axis: str):
     """The cross-device combine for one kernel output: partials agree on dense keys
     (aligned dictionaries), so one ICI collective merges them."""
-    if name == qstats.COMPACT_FLAG:
-        name = ".min"  # a launch took the compact decode only if every chip did
-    if name.endswith((".min", ".max")):
+    # the decode flag: a launch took the compact decode only if every chip did
+    if name.endswith((".min", ".max")) or name == qstats.COMPACT_FLAG:
         with jax.named_scope("pinot.collective.minmax"):
-            return (jax.lax.pmin if name.endswith(".min")
-                    else jax.lax.pmax)(v, axis)
+            return (jax.lax.pmax if name.endswith(".max")
+                    else jax.lax.pmin)(v, axis)
     with jax.named_scope("pinot.collective.sum"):
         return jax.lax.psum(v, axis)
 

@@ -530,10 +530,10 @@ def test_compact_ladder_takes_the_shortest_prefix_that_fits(monkeypatch,
             rtol=1e-5, atol=1e-3, err_msg=str(m))
 
 
-def one_full_segment_of_four(tmp_path_factory, name):
-    """Four aligned segments of 8,000 rows; `w < 100` passes every row of the
-    SECOND segment and 10 rows of each other one, `w < 10` passes 40 rows of
-    the second alone. (Not the first: the set is planned on its first
+def one_full_quarter(name):
+    """(schema, columns) of 4 x 8,000 rows: `w < 100` passes every row of the
+    SECOND quarter and 10 rows of each other one, `w < 10` passes 40 rows of
+    the second alone. (Not the first: a segment set is planned on its first
     segment, whose min/max must not fold the predicate.)"""
     rng = np.random.default_rng(31)
     per = 8000
@@ -545,12 +545,9 @@ def one_full_segment_of_four(tmp_path_factory, name):
     schema = Schema(name, [dimension("k", DataType.INT),
                            metric("w", DataType.INT),
                            metric("v", DataType.DOUBLE)])
-    cols = {"k": rng.integers(0, 6000, 4 * per).astype(np.int32), "w": w,
-            "v": np.round(rng.uniform(-500, 500, 4 * per), 3)}
-    out = tmp_path_factory.mktemp(name)
-    cfg = SegmentGeneratorConfig(no_dictionary_columns=["w", "v"])
-    return schema, cols, [load_segment(p) for p in build_aligned_segments(
-        schema, cols, str(out), name, 4, config=cfg)]
+    return schema, {"k": rng.integers(0, 6000, 4 * per).astype(np.int32),
+                    "w": w,
+                    "v": np.round(rng.uniform(-500, 500, 4 * per), 3)}
 
 
 def test_one_chip_dense_three_compact_on_the_mesh(tmp_path_factory):
@@ -559,7 +556,11 @@ def test_one_chip_dense_three_compact_on_the_mesh(tmp_path_factory):
     (`compact`). Each chip takes its own branch from its own count, the
     answer equals the host's, and the launch counts as dense: compact only if
     every chip took it."""
-    _, _, segs = one_full_segment_of_four(tmp_path_factory, "cm")
+    schema, cols = one_full_quarter("cm")
+    cfg = SegmentGeneratorConfig(no_dictionary_columns=["w", "v"])
+    segs = [load_segment(p) for p in build_aligned_segments(
+        schema, cols, str(tmp_path_factory.mktemp("cm")), "cm", 4,
+        config=cfg)]
     mex = MeshQueryExecutor(default_mesh(4))
     host = ServerQueryExecutor(use_device=False)
     prev = get_caps()

@@ -741,21 +741,19 @@ def test_decode_counters_reach_response_explain_and_health(tmp_path):
     mesh launch is compact only if every chip took it; the same GROUP BY under
     the default caps, where it takes no sort regime, with neither. EXPLAIN ANALYZE carries the same fields and
     `/health`'s device block (the pipeline's `stats()`) sums the launches."""
-    from tests.test_dense_groupby import one_full_segment_of_four
+    from tests.test_dense_groupby import one_full_quarter
     from pinot_tpu.cluster.device_server import DeviceQueryPipeline
     from pinot_tpu.engine.calibrate import KernelCaps, get_caps, set_caps
     from pinot_tpu.parallel import MeshQueryExecutor, default_mesh
     from pinot_tpu.table import IndexingConfig
-    schema, cols, _ = one_full_segment_of_four(
-        type("f", (), {"mktemp": staticmethod(
-            lambda name: tmp_path / f"unused_{name}")}), "dc")
+    schema, cols = one_full_quarter("dc")
     # every key in every segment: four equal dictionaries, the aligned path
     # (5,000 keys of 8,000 rows: few enough to stay dictionary-encoded)
     per = len(cols["k"]) // 4
     cols["k"] %= 5000
     for s in range(4):
         cols["k"][s * per + 100:s * per + 5100] = np.arange(5000)
-    cluster = QuickCluster(num_servers=1, work_dir=str(tmp_path / "cluster"))
+    cluster = QuickCluster(num_servers=1, work_dir=str(tmp_path))
     cluster.servers[0].device_pipeline = pipeline = DeviceQueryPipeline(
         mesh_exec=MeshQueryExecutor(default_mesh(4)))
     cfg = TableConfig("dc", indexing=IndexingConfig(
