@@ -872,7 +872,7 @@ def _decode_sorted(regime: str, key_s, vals_s, nseg: int, pad: int, block: int,
     if took is not None:
         took.append(m <= cap)
     # the first rung that holds m rows; past the last one, the dense decode
-    rung = jnp.sum(m > jnp.asarray(rungs, jnp.int32), dtype=jnp.int32)
+    rung = sum((m > c).astype(jnp.int32) for c in rungs)
     return jax.lax.switch(rung, [compact(c) for c in rungs] + [dense_branch])
 
 
