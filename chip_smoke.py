@@ -40,8 +40,8 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 SEGMENT_ROWS = 4 << 20          # 4Mi-row segments
 DEFAULT_ROWS = 64 << 20         # ~ SSB SF10's lineorder (59,986,052 rows)
 REHEARSE_ROWS = 1 << 20
-SUPPKEYS = 20_000               # bench.py HIGH_CARD_SUPPKEYS: the chunked matmul
-CUSTKEYS = 500_000              # bench.py VERY_HIGH_CARD_KEYS: the sort regimes
+SUPPKEYS = 20_000               # the chunked matmul
+CUSTKEYS = 500_000              # past chunk_cap: the sort regime
 REGIONS = ["AFRICA", "AMERICA", "ASIA", "EUROPE", "MIDDLE EAST"]
 LOAD_TIMEOUT_S = 600.0
 BUILD_THREADS = 4
@@ -75,7 +75,7 @@ QUERIES = [
 
 
 def lineorder_schema():
-    """bench.py's lineorder (SSB) schema."""
+    """The lineorder (SSB) schema."""
     from pinot_tpu.schema import DataType, Schema, date_time, dimension, metric
     return Schema("lineorder", [
         dimension("lo_region", DataType.STRING),
@@ -90,7 +90,7 @@ def lineorder_schema():
 
 
 def segment_columns(seed: int, i: int, n: int, suppkeys: int, custkeys: int):
-    """Segment i's columns, from the seed alone (bench.py's distributions).
+    """Segment i's columns, from the seed alone (uniform draws).
     Every key value occurs in every segment (the first `keys` rows are a
     permutation of the key space), so the per-segment dictionaries agree and
     the set rides the aligned stacked block like `build_aligned_segments`

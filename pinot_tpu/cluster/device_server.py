@@ -23,8 +23,7 @@ then groups the prepared work before touching the device:
     into ONE batched kernel launch instead of N sequential dispatches;
   * everything dispatched in a drain is fetched with ONE host sync, so under
     concurrency the host round trip amortizes across the batch
-    (the productized form of `bench.py`'s pipeline_depth; reference:
-    `QueryScheduler.java:56` bounds per-server concurrency — here batching
+    (reference: `QueryScheduler.java:56` bounds per-server concurrency — here batching
     is what concurrency buys, because the device serializes dispatches
     anyway).
 

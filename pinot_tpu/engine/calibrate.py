@@ -176,56 +176,6 @@ def save_cached_caps(caps: KernelCaps, path: Optional[str] = None,
     os.replace(tmp, path)
 
 
-# -- measured HBM bandwidth (the shared roofline denominator) ----------------
-# bench.py's platform calibration measures the streaming scan bandwidth the
-# chip actually sustains and persists it here; the bench lanes divide by it
-# (`kernels.roofline_hbm_gbps`), so a `*_pct_of_measured_roofline` above ~100
-# is a bug, not a denominator mismatch. Stored as a sibling top-level key in the
-# caps cache file (`<platform>#hbm_gbps`) so caps saves never clobber it.
-
-def _hbm_key(key: Optional[str] = None) -> str:
-    return f"{key or platform_key()}#hbm_gbps"
-
-
-def load_measured_hbm_gbps(path: Optional[str] = None,
-                           key: Optional[str] = None) -> Optional[float]:
-    """The persisted measured HBM bandwidth for this platform, or None."""
-    path = path or cache_path()
-    try:
-        with open(path) as f:
-            blob = json.load(f)
-        gbps = float(blob[_hbm_key(key)])
-    except Exception:
-        return None
-    return gbps if 0.0 < gbps < 1e5 else None
-
-
-def save_measured_hbm_gbps(gbps: float, path: Optional[str] = None,
-                           key: Optional[str] = None) -> None:
-    """Persist a measured bandwidth figure and drop kernels' cached copy."""
-    if not (0.0 < float(gbps) < 1e5):
-        raise ValueError(f"implausible HBM bandwidth: {gbps} GB/s")
-    path = path or cache_path()
-    blob: Dict[str, object] = {}
-    try:
-        with open(path) as f:
-            loaded = json.load(f)
-        if isinstance(loaded, dict):
-            blob = loaded
-    # graftcheck: ignore[exception-hygiene] -- a missing/corrupt cache file
-    # just means a fresh blob; the save below rewrites it
-    except Exception:
-        pass
-    blob[_hbm_key(key)] = round(float(gbps), 3)
-    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    tmp = f"{path}.tmp.{os.getpid()}"
-    with open(tmp, "w") as f:
-        json.dump(blob, f, indent=2, sort_keys=True)
-    os.replace(tmp, path)
-    from . import kernels
-    kernels.invalidate_roofline_cache()
-
-
 def _env_overrides(caps: KernelCaps) -> KernelCaps:
     def _int(name):
         v = os.environ.get(name)

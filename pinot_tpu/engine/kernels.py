@@ -39,7 +39,6 @@ the measured foundation for future hand-scheduled integration.
 
 from __future__ import annotations
 
-import os
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional, Tuple
@@ -235,49 +234,6 @@ def _block_tree(out):
     for leaf in jax.tree_util.tree_leaves(out):  # jax < 0.4 compat
         getattr(leaf, "block_until_ready", lambda: None)()
     return out
-
-
-# -- the HBM roofline denominator bench.py's lanes divide by ------------------
-
-_NOMINAL_HBM_GBPS: Optional[float] = None
-_ROOFLINE_GBPS: Optional[float] = None
-
-
-def _nominal_hbm_gbps() -> float:
-    """The device's published peak HBM bandwidth from the `device_kind`-keyed
-    table (utils/device_peaks.py; an unknown non-CPU device raises there),
-    overridable via PINOT_TPU_HBM_GBPS."""
-    global _NOMINAL_HBM_GBPS
-    if _NOMINAL_HBM_GBPS is None:
-        env = os.environ.get("PINOT_TPU_HBM_GBPS")
-        gbps = float(env) if env else 0.0
-        if gbps <= 0:
-            from ..utils.device_peaks import device_peak
-            gbps = device_peak()["hbm_gbps"]
-        _NOMINAL_HBM_GBPS = gbps
-    return _NOMINAL_HBM_GBPS
-
-
-def roofline_hbm_gbps() -> float:
-    """THE roofline denominator of every `*_pct_of_measured_roofline` figure
-    bench.py publishes. Resolution: PINOT_TPU_HBM_GBPS env override, else
-    the bandwidth bench.py's platform calibration measured and persisted via
-    `calibrate.save_measured_hbm_gbps`, else the nominal constant."""
-    global _ROOFLINE_GBPS
-    if _ROOFLINE_GBPS is None:
-        if os.environ.get("PINOT_TPU_HBM_GBPS"):
-            _ROOFLINE_GBPS = _nominal_hbm_gbps()
-        else:
-            from .calibrate import load_measured_hbm_gbps
-            _ROOFLINE_GBPS = load_measured_hbm_gbps() or _nominal_hbm_gbps()
-    return _ROOFLINE_GBPS
-
-
-def invalidate_roofline_cache() -> None:
-    """Drop the cached denominator (a fresh calibration was just persisted)."""
-    global _ROOFLINE_GBPS, _NOMINAL_HBM_GBPS
-    _ROOFLINE_GBPS = None
-    _NOMINAL_HBM_GBPS = None
 
 
 def _fence_first_call(fn):

@@ -216,7 +216,7 @@ def recommend_partitioning(seg_or_dir, queries: List[str],
 
 
 # measured single-partition realtime consume rate of THIS engine
-# (bench.py ingest_rows_per_sec: kafkalite fetch->decode->MutableSegment.index)
+# (kafkalite fetch->decode->MutableSegment.index, on the CPU)
 ENGINE_CONSUME_ROWS_PER_SEC = 25_000.0
 
 

@@ -21,15 +21,14 @@ Design points:
   raises, and the `event-kind-drift` graftcheck rule holds call sites and
   the README glossary to this table;
 * the ring evicts strictly oldest-first (like `TraceRing`) and keeps
-  emitted/evicted conservation counters so the bench lane can assert
+  emitted/evicted conservation counters so a test can assert
   `emitted == retained + evicted`;
 * events emitted while a traced query is active on the calling thread
   inherit the trace id, so query reports can interleave cluster events
   into the waterfall.
 
 The `emit()` fast path is a dataclass construction plus one lock-guarded
-deque append and a cached counter increment — benched under 1% of the
-in-proc query p50 (`bench.py --events`).
+deque append and a cached counter increment.
 """
 
 from __future__ import annotations
@@ -79,7 +78,7 @@ KINDS: Dict[str, Tuple[str, str]] = {
     "verdict.memory": ("WARN", "device-memory health verdict changed for a table"),
     "verdict.workload": ("WARN", "workload shape regression verdict changed for a fingerprint"),
     "incident.captured": ("ERROR", "flight recorder captured an incident bundle"),
-    "bench.probe": ("INFO", "synthetic event emitted by the bench --events lane"),
+    "bench.probe": ("INFO", "synthetic event the ring's own tests emit"),
 }
 
 

@@ -12,8 +12,7 @@ Design constraints, in order:
 
 1. **Zero overhead when disabled.** `fault_point` is on the mux write loop,
    the consume pump, and the server execute path; disabled it is one module
-   global load + a None check (the bench's chaos lane publishes the measured
-   cost as `fault_plane_overhead_pct`). No registry lookups, no dict walks.
+   global load + a None check. No registry lookups, no dict walks.
 2. **Deterministic under a seed.** Every site draws from its own
    `random.Random(f"{seed}:{site}")` stream, so concurrency *between* sites
    never perturbs a site's decision sequence, and two runs of the same
@@ -103,8 +102,8 @@ class _SiteSpec:
 class FaultSchedule:
     """Seeded, budgeted fault decisions for a set of sites.
 
-    Thread-safe; `fired()` exposes per-site fire counts so tests and the
-    bench can assert exactly what the schedule did."""
+    Thread-safe; `fired()` exposes per-site fire counts so tests can
+    assert exactly what the schedule did."""
 
     def __init__(self, sites: Dict[str, dict], seed: int = 0):
         unknown = set(sites) - SITES

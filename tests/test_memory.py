@@ -7,7 +7,7 @@ its arithmetic exactly (byte-accurate totals, filter semantics, re-registration
 replacement), then prove the plane end to end: staging through the engine shows
 up in `/debug/memory`, the controller turns server headroom into
 HEALTHY/DEGRADED/UNHEALTHY, and unloading a segment returns the ledger to
-baseline (the leak gate `bench.py --memory` enforces continuously).
+baseline (the leak gate).
 """
 
 import json
@@ -466,8 +466,7 @@ def test_query_staging_lands_in_ledger_and_verdict(lineorder_cluster):
 
 def test_segment_unload_returns_ledger_to_baseline(lineorder_cluster):
     """The leak regression: block_for/release_block cycles and a table-manager
-    remove_segment must return the ledger exactly to baseline (this is the
-    gate `bench.py --memory` runs over 100 cycles)."""
+    remove_segment must return the ledger exactly to baseline."""
     from pinot_tpu.engine import datablock
     cluster, cfg = lineorder_cluster
     table = cfg.table_name_with_type
