@@ -35,13 +35,12 @@ from typing import Iterable, List, Set
 from .core import AnalysisContext, Finding, Module, Rule, dotted_name
 from .ingest_hot_loop import slow_path_names
 
-#: fused-execution hot modules (repo-relative suffixes): the kernel builder,
-#: the hand-tiled Pallas scan, and the compressed-form datablock. The
+#: fused-execution hot modules (repo-relative suffixes): the kernel builder
+#: and the compressed-form datablock. The
 #: executor routes between fused and staged plans, so its staged input
 #: builder legitimately calls `block.values(...)` — it is not listed here.
 HOT_MODULES = (
     "pinot_tpu/engine/kernels.py",
-    "pinot_tpu/engine/pallas_scan.py",
     "pinot_tpu/engine/datablock.py",
 )
 

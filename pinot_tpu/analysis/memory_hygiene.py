@@ -9,8 +9,8 @@ review hope:
   in the engine/segment/cluster layers (the layers that put long-lived data
   on device) must flow through the `staged(...)` registration wrapper.
   Transient math inside jit'd kernels is NOT staging — the rule skips calls
-  inside jit-decorated functions — and deliberate exceptions (bench data
-  generation, calibration micro-benchmarks) suppress with a rationale.
+  inside jit-decorated functions — and deliberate exceptions suppress with
+  a rationale.
 """
 
 from __future__ import annotations

@@ -625,20 +625,13 @@ def pipeline_from_config(cfg) -> Optional[DeviceQueryPipeline]:
     if not cfg.get_bool("server.device.enabled", False):
         return None
     mesh_exec = None
-    # fused single-launch execution over compressed forms: the knob only
-    # forces it OFF cluster-wide; when on (default), the calibrated
-    # KernelCaps.fused_enabled regime still decides per platform
-    fused = None if cfg.get_bool("server.fused.enabled", True) else False
     n_mesh = cfg.get_int("server.mesh.devices", 0)
     if n_mesh > 0:
         # explicit mesh width (0 = every visible device): a server can pin its
         # pipeline to a sub-mesh, e.g. to split chips between serving replicas
         from ..parallel.combine import MeshQueryExecutor
         from ..parallel.mesh import default_mesh
-        mesh_exec = MeshQueryExecutor(default_mesh(n_mesh), fused_enabled=fused)
-    elif fused is not None:
-        from ..parallel.combine import MeshQueryExecutor
-        mesh_exec = MeshQueryExecutor(fused_enabled=fused)
+        mesh_exec = MeshQueryExecutor(default_mesh(n_mesh))
     return DeviceQueryPipeline(
         mesh_exec=mesh_exec,
         max_batch=cfg.get_int("server.device.max.batch", 64),

@@ -132,18 +132,11 @@ class ServerNode:
         # report) without redeploying servers
         bitmap_on = str(catalog.get_property(
             "clusterConfig/server.index.bitmap.enabled", "true")).lower() != "false"
-        # fused single-launch execution: the cluster knob only forces it OFF;
-        # when on (default) the calibrated KernelCaps regime decides per shape
-        fused_on = str(catalog.get_property(
-            "clusterConfig/server.fused.enabled", "true")).lower() != "false"
-        fused = None if fused_on else False
-        self.executor = ServerQueryExecutor(bitmap_enabled=bitmap_on,
-                                            fused_enabled=fused)
+        self.executor = ServerQueryExecutor(bitmap_enabled=bitmap_on)
         # host-tier executor: never stages device blocks — what unadmitted
         # segments run on when the HBM admission gate rejects them
         self.host_executor = ServerQueryExecutor(use_device=False,
-                                                 bitmap_enabled=bitmap_on,
-                                                 fused_enabled=fused)
+                                                 bitmap_enabled=bitmap_on)
         # HBM capacity override knob (env PINOT_TPU_HBM_CAPACITY_BYTES is the
         # process-level equivalent): lets tests/bench pin a tiny budget
         cap_raw = catalog.get_property(

@@ -134,26 +134,6 @@ class TieringManager:
         cap, _ = get_ledger().capacity_bytes()
         return max(1, int(cap * (1.0 - self._headroom_pct() / 100.0)))
 
-    def _fused_pricing(self) -> bool:
-        """Whether admission prices the COMPRESSED fused working set instead
-        of the decoded one: on iff the cluster knob allows fusion and the
-        calibrated caps regime enables it — exactly when queries skip the
-        decoded HBM cache for single-value dict columns. Mispricing is
-        safe in one direction only: a segment admitted on fused bytes whose
-        query degrades to staged simply stages the decoded cache under the
-        ledger (pressure eviction handles overshoot), while pricing decoded
-        bytes for fused plans rejects segments that would have fit."""
-        if self._catalog is not None:
-            try:
-                raw = self._catalog.get_property(
-                    "clusterConfig/server.fused.enabled", "true")
-                if str(raw).lower() == "false":
-                    return False
-            except (TypeError, ValueError):
-                pass
-        from ..engine.calibrate import get_caps
-        return bool(get_caps().fused_enabled)
-
     def _reserved_bytes(self) -> int:
         """Predicted bytes of admitted-but-not-yet-staged blocks. A
         reservation expires the moment the block lands in the ledger (it
@@ -183,7 +163,12 @@ class TieringManager:
                 entry.last_access = time.monotonic()   # hot-path touch
                 return True
         try:
-            need = predicted_block_bytes(segment, fused=self._fused_pricing())
+            # the COMPRESSED fused working set: queries skip the decoded HBM
+            # cache for single-value dict columns. A segment admitted on
+            # fused bytes whose plan takes the staged path stages the decoded
+            # cache under the ledger (pressure eviction handles overshoot);
+            # pricing decoded bytes would reject segments that fit
+            need = predicted_block_bytes(segment, fused=True)
         # graftcheck: ignore[exception-hygiene] -- a segment without sizing
         # metadata (synthetic test doubles) admits defensively; the ledger
         # still accounts whatever it actually stages

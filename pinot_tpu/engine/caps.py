@@ -1,0 +1,89 @@
+"""The constants that choose the kernel: one frozen table beside the ladder
+that reads it.
+
+Which kernel runs for a plan is decided from what the trace can see (padded
+key count, row count, decode-table width, whether the plan can fuse) against
+the fields of `KernelCaps`, and these defaults are the only place the numbers
+are written. There is one supported `device_kind` (the v5e), so the table is
+a set of constants: retuning one is an edit of its default here, with the
+chip measurement in `PERF.md` and the benchmark's cells as the judge.
+
+`get_caps()` / `set_caps()` are the seam by which TESTS run the chunked and
+sort regimes at 16k rows (`set_caps(KernelCaps(chunk_cap=4096))`); nothing in
+the served path calls `set_caps`, and no file, environment variable or
+cluster key feeds the table. `token()` is part of `KernelSpec.signature()`
+and of the join kernels' cache keys, so a program built under other caps is
+never reused.
+"""
+
+from __future__ import annotations
+
+from dataclasses import astuple, dataclass
+from typing import Tuple
+
+
+@dataclass(frozen=True)
+class KernelCaps:
+    """Crossovers of the kernel ladders. Key counts are PADDED keys + 1 (the
+    overflow bucket), as `_make_body` compares them."""
+
+    # SKINNY one-hot matmul ([1+sums, N] @ [N, keys]) up to here: each
+    # 128-wide output column tile re-walks the full contraction, so cost grows
+    # linearly in keys and the chunked 64x64 formulation overtakes it at some
+    # key count. Where is not yet measured on the directly attached chip
+    # (ROADMAP S8).
+    matmul_cap: int = 512
+    # CHUNKED 64x64 one-hot matmul (`_grouped_chunk64`) up to here: measured
+    # v5e 16M rows count+sum 24ms @1024..2048 keys, 30ms @4096, 39ms @20k,
+    # 69ms @32k. Its cost is linear in keys (~2.1ms per 4096-key chunk per
+    # bf16 part per 16M rows) while a `jax.lax.sort` of 16M keys+payload is
+    # ~67ms flat: crossover near 128k keys. Past it, and past 2^24 rows a
+    # device at any key count, the sort regime (`_grouped_partitioned`).
+    chunk_cap: int = 131072
+    # per-key broadcast-reduce min/max up to here (VPU-bound: above it the
+    # broadcast does more device work than `segment_min` / `segment_max`)
+    minmax_bcast_cap: int = 1024
+    # rows a slab of the sort regime (a multiple of 64: its local ids are
+    # the chunked matmul's two 64-wide digits)
+    partition_block: int = 4096
+    # bitmap-vs-gather filter regime: a dict-column filter leaf takes the
+    # packed-word bitmap path when its estimated selectivity (matched docs /
+    # docs) is at or below this fraction; denser predicates keep the
+    # interval-compare / one-hot LUT path
+    bitmap_sel_cap: float = 0.25
+    # the longest decode table (padded entries) a fused plan may decode
+    # in-kernel; a column with a larger dictionary sends its plan down the
+    # staged two-launch path (`run_kernel_staged`)
+    fused_lut_cap: int = 1 << 16
+    # device hash-join regime split (PR 17): a single-integer-key build side
+    # whose value span fits under this many direct-address slots takes the
+    # scatter-table probe (one gather launch, at most one match per probe
+    # row); wider/duplicate-key builds take the sort-merge probe ladder.
+    join_scatter_cap: int = 1 << 20
+
+    def token(self) -> Tuple:
+        """The caps as a jit cache key: every field changes compiled kernels."""
+        return astuple(self)
+
+
+_ACTIVE = KernelCaps()
+
+
+def get_caps() -> KernelCaps:
+    return _ACTIVE
+
+
+def set_caps(caps: KernelCaps) -> KernelCaps:
+    """Install other caps — for tests: nothing in the served path calls this.
+    Flushes the compiled kernel caches: a cap change changes dispatch, and
+    `KernelSpec.signature()` only protects NEW lookups, not memory held by
+    stale entries."""
+    global _ACTIVE
+    if caps.partition_block <= 0 or caps.partition_block % 64:
+        raise ValueError(f"partition_block must be a multiple of 64: {caps}")
+    _ACTIVE = caps
+    from ..parallel import combine
+    from . import kernels
+    kernels._KERNEL_CACHE.clear()
+    combine._SHARD_KERNEL_CACHE.clear()
+    return caps

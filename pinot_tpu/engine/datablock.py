@@ -251,10 +251,9 @@ class SegmentBlock:
         value-buffer cache, `DataFetcher.java:47`). Fused plans never call
         this: they route `dict_values(col)` + `ids(col)` (or `for_form`)
         into the kernel and decode in-register, so no decoded column is ever
-        written back to HBM. The staged ladder rung keeps this path for
-        shapes where in-kernel decode loses (oversized decode tables,
-        multi-value value columns, platforms whose calibration probe
-        measured in-kernel gathers slower than the staged decode).
+        written back to HBM. The staged path keeps this for the two inputs
+        only it runs (decode tables over `fused_lut_cap`, multi-value value
+        columns).
         """
         reader = self.segment.column(col)
         if not reader.has_dictionary:

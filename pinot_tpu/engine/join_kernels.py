@@ -4,7 +4,7 @@ The multistage `hash_join` was correctness-only host numpy: both sides fetched
 to the host, keys factorized through a per-row Python dict, indices expanded
 with `np.repeat`. This module moves the heavy part — ordering the build side
 and locating each probe row's match range — onto the device as two jitted
-launches, in two calibrated regimes (mirroring the PR 1 group-by ladder):
+launches, in two regimes (mirroring the PR 1 group-by ladder):
 
 * **scatter regime** — a single integer key whose build-side value span fits
   under `KernelCaps.join_scatter_cap` direct-address slots: the build launch
@@ -41,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..query import stats as qstats
-from .calibrate import get_caps
+from .caps import get_caps
 from .kernels import _cached_kernel, fetch_outputs
 
 #: probe-hash histogram width for the skew detector (buckets = hash & 255)
@@ -56,8 +56,8 @@ def _next_pow2(n: int) -> int:
 
 
 def scatter_table_cap() -> int:
-    """Direct-address slot budget for the scatter regime (calibrated cap)."""
-    return int(getattr(get_caps(), "join_scatter_cap", 1 << 20))
+    """Direct-address slot budget for the scatter regime."""
+    return get_caps().join_scatter_cap
 
 
 def fold_codes32(codes: np.ndarray) -> np.ndarray:

@@ -17,24 +17,21 @@ matmuls —
 * dict decode -> fused plans: a tree of selects over a small decode table
   (SELECT_DECODE_CAP; the one gather left is a table wider than that); staged
   plans: host-materialized value columns cached in HBM (`datablock.values`);
-* group-by partials -> one-hot matmul `[rows, N] @ [N, keys]` up to MATMUL_KEY_CAP
-  (the common OLAP case; XLA fuses the iota-compare into the dot's tiles), the
-  CHUNKED 64x64-tile matmul `_grouped_chunk64` from there to CHUNK_KEY_CAP
-  (high-cardinality group-by AND the grouped-distinct presence product space,
-  bf16 3-part-split operands at full MXU tile utilization), per-key
-  broadcast-reduce for min/max, `segment_*` scatter above CHUNK_KEY_CAP where
-  the chunked path's N*K MACs cross over the K-independent scatter.
+* group-by partials -> one ladder over the padded key count, its crossovers
+  the constants of `engine/caps.py`: the one-hot matmul `[rows, N] @ [N, keys]`
+  up to `matmul_cap` (the common OLAP case; XLA fuses the iota-compare into the
+  dot's tiles), the CHUNKED 64x64-tile matmul `_grouped_chunk64` from there to
+  `chunk_cap` (high-cardinality group-by AND the grouped-distinct presence
+  product space, bf16 3-part-split operands at full MXU tile utilization), and
+  past `chunk_cap`, or past 2^24 rows a device at any key count, the
+  radix-partitioned sort `_grouped_partitioned` (no n-row scatter); min/max by
+  per-key broadcast-reduce up to `minmax_bcast_cap`, `segment_min` /
+  `segment_max` above.
 
 There is no 10k-doc batching loop (`DocIdSetPlanNode.MAX_DOC_PER_CALL`): the TPU analog of
 batching is the grid XLA tiles over the padded row axis. Kernels are cached by structural
 signature; literal operands arrive via runtime scalar arrays so changing `WHERE x > 5` to
 `x > 7` reuses the compiled program.
-
-A hand-tiled Pallas version of the masked multi-sum scan lives in
-`engine/pallas_scan.py` with its measurement: ~1.75x the XLA fusion under
-CSE-proof chained dispatch, but the engine's pipelined serving shape still
-measures faster through XLA — so XLA stays the default and the Pallas kernel is
-the measured foundation for future hand-scheduled integration.
 """
 
 from __future__ import annotations
@@ -53,7 +50,7 @@ from ..query.predicate import CmpLeaf, DocSetLeaf, FilterProgram, LutLeaf, NullL
 from ..sql.ast import Identifier
 from ..utils.memledger import get_ledger
 from ..utils.metrics import get_registry
-from .calibrate import get_caps
+from .caps import get_caps
 from .expr import eval_expr
 
 _INT_MIN_IDENT = np.iinfo(np.int32).max  # identity for masked-out min over int
@@ -62,29 +59,11 @@ _INT_MAX_IDENT = np.iinfo(np.int32).min
 # kernel outputs that are masked sums of integer powers of the argument
 _POWER_SUMS = {"sum": 1, "sum2": 2, "sum3": 3, "sum4": 4}
 
-# Above these sizes the matmul / broadcast-reduce does more device work than a
-# scatter; below them it stays at the dispatch latency floor.
-# SKINNY one-hot matmul ([1+sums, N] @ [N, keys], f32 HIGHEST): each 128-wide
-# output column tile re-walks the full contraction, so cost grows linearly in
-# keys; the chunked 64x64 formulation overtakes it at some key count. Where
-# is not yet measured on the directly attached chip (ROADMAP queues the
-# retune).
-#
-# These constants are the DEFAULT values of the calibrated caps in
-# `engine/calibrate.py`; the dispatch
-# ladder in `_make_body` reads `get_caps()`, not these names, so a persisted
-# calibration or PINOT_TPU_* env override retargets the ladder per platform.
-MATMUL_KEY_CAP = 512      # skinny one-hot matmul group-by partials
-MINMAX_BCAST_CAP = 1024   # per-key broadcast-reduce min/max, VPU-bound
+# The crossovers of the GROUP BY, min/max, bitmap, fused-decode and join
+# ladders are the fields of `engine/caps.py`'s `KernelCaps`, the only place
+# those numbers are written; `_make_body` reads them through `get_caps()`.
 DENSE_LUT_MATMUL_CAP = 8192  # scattered-LUT membership via one-hot matmul
 PRESENCE_MATMUL_CAP = 8192   # _presence_2d chunked presence counts
-# Mid/high-cardinality group-by rides the CHUNKED 64x64 one-hot matmul
-# (_grouped_chunk64): measured v5e 16M rows count+sum 24ms @1024..2048 keys,
-# 30ms @4096, 39ms @20k, 69ms @32k. Past this cap the SORT-BASED regimes take
-# over (`_grouped_partitioned` / `_grouped_sorted`): the chunked path's cost is
-# linear in keys (~2.1ms per 4096-key chunk per bf16 part per 16M rows) while
-# a jax.lax.sort of 16M keys+payload is ~67ms flat — crossover near 128k keys.
-CHUNK_KEY_CAP = 131072
 # A fused dict column whose padded decode table has at most this many entries
 # (`W`, the static last dimension of the table: a shape, so the choice is made
 # at trace time from the input) is decoded by a balanced tree of selects over
@@ -102,8 +81,8 @@ CHUNK_KEY_CAP = 131072
 # bounds the cap is the unrolled tree's compile: at 1024 it is longer than any
 # program this server compiles today (the 64Mi-row sorts, 22-37 s) and a cold
 # shape stalls the serial device pipeline that long; 256 stays under them.
-# A constant of the program, not a calibrated `KernelCaps` field: it is not
-# runtime-tunable, so it has no place in `signature()`.
+# A constant of the program, not a `KernelCaps` field: no test needs another
+# value through `set_caps`, so it has no place in `signature()`.
 SELECT_DECODE_CAP = 256
 
 
@@ -684,7 +663,7 @@ def _counts_from_sorted(key_s: jnp.ndarray, nseg: int, pad: int):
 
     `left[k]` is the first sorted position with key >= k (binary search, no
     scatter), so counts[k] = left[k+1] - left[k] — integer arithmetic with no
-    f32 accumulator, hence no 2^24-increment guard on these regimes. The
+    f32 accumulator, hence no 2^24-increment guard on the sort regime. The
     `pad` rows _sort_by_key appended all carry key nseg-1 and are deducted."""
     left = jnp.searchsorted(key_s, jnp.arange(nseg + 1, dtype=key_s.dtype))
     counts = (left[1:] - left[:-1]).astype(jnp.int32)
@@ -796,9 +775,9 @@ def _compact_decode(key_c: jnp.ndarray, vals_c, m, nseg: int, rows: int):
         put(jnp.zeros((nseg,), jnp.float32), t) for t in totals]
 
 
-def _decode_sorted(regime: str, key_s, vals_s, nseg: int, pad: int, block: int,
-                   dense, took):
-    """The dense [nseg] answer of a sort regime from its sorted rows: `dense`
+def _decode_sorted(key_s, vals_s, nseg: int, pad: int, block: int, dense,
+                   took):
+    """The dense [nseg] answer of the sort regime from its sorted rows: `dense`
     (the per-key decode, a binary search for every dense key) or, where few
     rows passed the filter, `_compact_decode` over the sorted prefix: the
     shortest of a short ladder of prefixes (COMPACT_RUNGS, then
@@ -816,13 +795,13 @@ def _decode_sorted(regime: str, key_s, vals_s, nseg: int, pad: int, block: int,
 
     def compact(rows):
         def branch():
-            with jax.named_scope(f"pinot.groupby.{regime}.compact"):
+            with jax.named_scope("pinot.groupby.partitioned.compact"):
                 return _compact_decode(key_s[:rows], [v[:rows] for v in vals_s],
                                        m, nseg, n - pad)
         return branch
 
     def dense_branch():
-        with jax.named_scope(f"pinot.groupby.{regime}.dense"):
+        with jax.named_scope("pinot.groupby.partitioned.dense"):
             return dense()
 
     if took is not None:
@@ -832,50 +811,10 @@ def _decode_sorted(regime: str, key_s, vals_s, nseg: int, pad: int, block: int,
     return jax.lax.switch(rung, [compact(c) for c in rungs] + [dense_branch])
 
 
-def _grouped_sorted(key: jnp.ndarray, nseg: int, value_rows, block: int = 4096,
-                    took=None):
-    """Sort + segmented-scan group-by: the pathological-cardinality fallback.
-
-    One `jax.lax.sort` of (key, values), head flags at run boundaries, one
-    segmented inclusive `associative_scan` per value row, and a gather of each
-    run's last position (left[k+1]-1). Cost is the sort plus O(N log N) scan
-    work with NO per-key term, so it is the regime of last resort when the
-    residual cardinality makes even the rank-partitioned matmul's per-key
-    decode expensive. Where few rows passed the filter the answer comes from
-    the sorted prefix instead (`_decode_sorted`).
-    Returns [int32 counts[nseg], f32 sums[nseg]...].
-    """
-    with jax.named_scope("pinot.groupby.sorted.sort"):
-        key_s, vals_s, pad = _sort_by_key(key, nseg, value_rows, block)
-    n = key_s.size
-
-    def dense():
-        with jax.named_scope("pinot.groupby.sorted.trim"):
-            left, counts = _counts_from_sorted(key_s, nseg, pad)
-        outs = [counts]
-        if not vals_s:
-            return outs
-        with jax.named_scope("pinot.groupby.sorted.scan"):
-            head = jnp.concatenate([jnp.ones((1,), bool),
-                                    key_s[1:] != key_s[:-1]])
-            v = jnp.stack(vals_s)  # [R, n]
-            flags = jnp.broadcast_to(head[None, :], v.shape)
-            _, scan = jax.lax.associative_scan(_seg_sum_op, (flags, v), axis=1)
-        with jax.named_scope("pinot.groupby.sorted.trim"):
-            end = jnp.clip(left[1:] - 1, 0, n - 1)  # last row of each key's run
-            occ = counts > 0
-            for r in range(v.shape[0]):
-                outs.append(jnp.where(occ, scan[r][end], 0.0))
-        return outs
-
-    return _decode_sorted("sorted", key_s, vals_s, nseg, pad, block, dense,
-                          took)
-
-
 def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
                          block: int = 4096, took=None):
-    """Two-level radix-partitioned sort group-by — the high-cardinality regime
-    replacing the flat `segment_sum` scatter.
+    """Two-level radix-partitioned sort group-by — the one regime past
+    `chunk_cap` keys or 2^24 rows a device.
 
     The sort IS the radix split: after `jax.lax.sort`, each `block`-row slab is
     one partition whose keys RANK-compress to a dense local id
@@ -885,8 +824,8 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
     MXU formulation of `_grouped_chunk64` as ONE batched
     [B, block, 64]^T @ [B, block, 64] dot per bf16 part — total MACs
     N * block, i.e. a single chunk64-tile-equivalent per part REGARDLESS of
-    key count, where the chunked path pays per 4096 keys and the scatter pays
-    its K-independent ~248ms. Groups spanning slab boundaries always occupy
+    key count, where the chunked path pays per 4096 keys and a flat
+    `segment_sum` scatter (what PR 1 replaced) paid a K-independent ~248ms. Groups spanning slab boundaries always occupy
     local id 0 of the continuation slabs, so a short segmented scan over the
     [B] slab-head sums stitches them. The dense decode has no n-row scatter
     either: `searchsorted` run boundaries give exact int32 counts and each
@@ -955,8 +894,7 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
                 outs.append(jnp.where(occ, total, 0.0))
         return outs
 
-    return _decode_sorted("partitioned", key_s, vals_s, nseg, pad, block,
-                          dense, took)
+    return _decode_sorted(key_s, vals_s, nseg, pad, block, dense, took)
 
 
 def combine_collective(name: str, v, axis: str):
@@ -981,7 +919,7 @@ def _make_body(spec: KernelSpec):
     group = bool(spec.group_cols)
     num_seg = spec.num_keys_pad + 1  # +1 overflow bucket for masked-out rows
     mask_fn = _make_mask_fn(spec)
-    caps = get_caps()  # regime crossovers (calibrated; part of signature())
+    caps = get_caps()  # the ladders' crossovers (part of signature())
     scope = jax.named_scope  # each stage of the scan, named in the device trace
 
     def grouped_distinct(ai, agg, ids, key, mask, took):
@@ -993,9 +931,9 @@ def _make_body(spec: KernelSpec):
         but the CHUNKED 64x64-tile formulation (_grouped_chunk64) runs the
         same product space at full MXU tile utilization — count-only, so one
         bf16 part per chunk (exact: 0/1 operands, f32 accumulation,
-        2^24-increment guard shared with the sum path). segment_sum remains
-        for widths past CHUNK_KEY_CAP and blocks that could overflow an f32
-        cell."""
+        2^24-increment guard shared with the sum path). Widths past
+        `chunk_cap`, and blocks that could overflow an f32 cell, take the
+        sort regime with no value rows."""
         size = spec.distinct_lut_sizes[ai]
         col_ids = ids[agg.arg.name].ravel()
         comb = key * size + col_ids
@@ -1004,15 +942,11 @@ def _make_body(spec: KernelSpec):
             fm = mask.ravel().astype(jnp.float32)
             pres = _grouped_chunk64(comb, width, [fm], [])[0]
             return jnp.round(pres).astype(jnp.int32).reshape(num_seg, size)
-        if caps.high_card_regime == "scatter":
-            return jax.ops.segment_sum(
-                mask.ravel().astype(jnp.int32), comb,
-                num_segments=width).reshape(num_seg, size)
         # presence counts over the combined (group, id) space past the chunk
-        # cap: sorted-run boundary counts are exact int32 with no matmul and
-        # no scatter
-        pres = _grouped_sorted(comb, width, [], caps.partition_block,
-                               took)[0]
+        # cap: the sort regime with no value rows, whose sorted-run boundary
+        # counts are exact int32 with no matmul and no scatter
+        pres = _grouped_partitioned(comb, width, [], caps.partition_block,
+                                    took)[0]
         return pres.reshape(num_seg, size)
 
     def scalar_distinct(ai, agg, ids, mask, fmask):
@@ -1076,8 +1010,8 @@ def _make_body(spec: KernelSpec):
                             minmax.append((f"{ai}.{o}", v.ravel(), o == "min"))
             # f32 one-hot counts are exact only up to 2^24 increments (2^24 itself
             # IS representable); the row count is static at trace time, so pick the
-            # exact int32 scatter when a single group could overflow the f32
-            # integer range (keys.size is the bound). The <= matters: a 16M-row
+            # sort regime's exact int32 counts when a single group could overflow
+            # the f32 integer range (keys.size is the bound). The <= matters: a 16M-row
             # padded block sits exactly at 2^24 and must keep the matmul path.
             count_exact_in_f32 = key.size <= (1 << 24)
             if num_seg <= caps.matmul_cap and count_exact_in_f32:
@@ -1096,27 +1030,13 @@ def _make_body(spec: KernelSpec):
                     out["count"] = jnp.round(res[0]).astype(jnp.int32)
                 for arr, name in zip(res[1:], sum_names[1:]):
                     out[name] = arr
-            elif caps.high_card_regime == "scatter":
-                # explicit escape hatch (calibration baseline / pathological
-                # platforms): the K-independent flat scatter
-                with scope("pinot.groupby.scatter"):
-                    out["count"] = jax.ops.segment_sum(
-                        mask.ravel().astype(jnp.int32), key,
-                        num_segments=num_seg)
-                    for row, name in zip(sum_rows[1:], sum_names[1:]):
-                        out[name] = jax.ops.segment_sum(row, key,
-                                                        num_segments=num_seg)
             else:
                 # VERY-HIGH-CARDINALITY group-by (> chunk_cap, or row counts
-                # past the f32 2^24 guard at any cardinality): sort-based
-                # regimes with exact int32 counts and no scatter
-                regime = ("sorted" if caps.high_card_regime == "sorted"
-                          else "partitioned")
-                grouped = (_grouped_sorted if regime == "sorted"
-                           else _grouped_partitioned)
-                with scope("pinot.groupby." + regime):
-                    res = grouped(key, num_seg, sum_rows[1:],
-                                  caps.partition_block, took)
+                # past the f32 2^24 guard at any cardinality): the sort
+                # regime, with exact int32 counts and no scatter
+                with scope("pinot.groupby.partitioned"):
+                    res = _grouped_partitioned(key, num_seg, sum_rows[1:],
+                                               caps.partition_block, took)
                 out["count"] = res[0]
                 for arr, name in zip(res[1:], sum_names[1:]):
                     out[name] = arr
@@ -1240,13 +1160,12 @@ def _staged_agg_spec(spec: KernelSpec) -> KernelSpec:
 
 def run_kernel_staged(spec: KernelSpec,
                       inputs: KernelInputs) -> Dict[str, np.ndarray]:
-    """The staged (pre-fusion) ladder rung: dispatch the filter mask as its
-    own launch, then the aggregate kernel over decoded columns with the mask
+    """The staged (pre-fusion) path: dispatch the filter mask as its own
+    launch, then the aggregate kernel over decoded columns with the mask
     riding in as `valid` — two device launches where `run_kernel` takes one.
-    The regime ladder (KernelCaps.fused_enabled / fused_lut_cap, executor
-    eligibility) routes here when in-kernel decode would lose: oversized
-    decode tables, multi-value value columns, or a platform whose calibration
-    probe measured gathers as a regression. Results are bit-identical to the
+    `_fused_cols` routes here the two inputs only this path runs: a decode
+    table over `KernelCaps.fused_lut_cap` and a multi-value value column.
+    Tests hold it as the fused path's reference: results are bit-identical to the
     fused path — both consume the same decode tables and the same mask
     semantics, only the HBM traffic and launch count differ."""
     if spec.filter.is_match_all:

@@ -441,7 +441,7 @@ def _device_join_ok(left: Block, right: Block, spec: JoinSpec) -> bool:
 def _scatter_slots(lkey: Sequence[np.ndarray], rkey: Sequence[np.ndarray],
                    lnull: np.ndarray, rnull: np.ndarray):
     """Scatter-regime inputs for a single integer-like key whose build-side
-    value span fits the calibrated direct-address cap: (build_slots,
+    value span fits the direct-address cap (`join_scatter_cap`): (build_slots,
     probe_slots, size) as (key - min) offsets, or None when the shape doesn't
     qualify. Null rows carry out-of-range slots the kernels drop."""
     if len(rkey) != 1:

@@ -381,12 +381,11 @@ class MeshQueryExecutor:
     """Executes aggregation queries over segment sets sharded across a device mesh."""
 
     def __init__(self, mesh: Optional[jax.sharding.Mesh] = None,
-                 fused_enabled: Optional[bool] = None):
+                 fused_enabled: bool = True):
         self.mesh = mesh if mesh is not None else default_mesh()
         self.n_devices = self.mesh.devices.size
-        # fused in-register dict decode over the stacked block
-        # (clusterConfig/server.fused.enabled): None defers to the
-        # calibrated KernelCaps.fused_enabled regime
+        # fused in-register dict decode over the stacked block; no
+        # production caller passes False (tests' decoded-column reference)
         self.fused_enabled = fused_enabled
         self._fallback = ServerQueryExecutor(fused_enabled=fused_enabled)
         self._set_blocks: Dict[Tuple, SegmentSetBlock] = {}
@@ -902,12 +901,10 @@ class MeshQueryExecutor:
         over `fused_lut_cap`) simply keep the decoded path — there is no
         separate staged mode on the mesh, fusion here only removes the
         decode materialization."""
-        from ..engine.calibrate import get_caps
+        from ..engine.caps import get_caps
         from ..query.executor import _plan_vals_cols
         caps = get_caps()
-        enabled = caps.fused_enabled if self.fused_enabled is None \
-            else self.fused_enabled
-        if not enabled or view is not None:
+        if not self.fused_enabled or view is not None:
             return ()
         fused = []
         for c in sorted(_plan_vals_cols(plan)):

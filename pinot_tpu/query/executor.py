@@ -47,17 +47,17 @@ class ServerQueryExecutor:
     """Executes a QueryContext over a set of local segments."""
 
     def __init__(self, use_device: bool = True, bitmap_enabled: bool = True,
-                 fused_enabled: Optional[bool] = None):
+                 fused_enabled: bool = True):
         self.use_device = use_device
         # packed-word bitmap filter indexes (clusterConfig/
         # server.index.bitmap.enabled): off -> every dict filter leaf keeps
         # the interval-compare / LUT path regardless of selectivity
         self.bitmap_enabled = bitmap_enabled
-        # fused single-launch execution over compressed resident forms
-        # (clusterConfig/server.fused.enabled): None defers to the calibrated
-        # KernelCaps.fused_enabled regime; False forces the staged
-        # two-launch ladder everywhere (decoded HBM columns, mask launch +
-        # aggregate launch)
+        # fused single-launch execution over compressed resident forms.
+        # No production caller passes False: it forces the staged two-launch
+        # path (decoded HBM columns, mask launch + aggregate launch)
+        # everywhere, which tests hold as the fused path's byte-identical
+        # reference
         self.fused_enabled = fused_enabled
 
     # -- public API --------------------------------------------------------
@@ -240,7 +240,8 @@ class ServerQueryExecutor:
     def _fused_cols(self, plan: SegmentPlan, seg,
                     block) -> Optional[Tuple[Tuple[str, str], ...]]:
         """(col, form) routing for a fused single-launch plan, or None when
-        the regime ladder sends this shape down the staged two-launch rung.
+        the plan takes the staged two-launch path, decided from the plan
+        alone.
 
         Fused iff every value column (filter compare expressions + aggregate
         arguments) stays in a compressed resident form the kernel can decode
@@ -250,12 +251,10 @@ class ServerQueryExecutor:
         columns pass through unrouted (their resident form IS the value
         form). A multi-value or over-cap dict value column means the decoded
         HBM cache would be built anyway — the plan stages instead."""
-        from ..engine.calibrate import get_caps
+        from ..engine.caps import get_caps
         from ..engine.datablock import lut_size
         caps = get_caps()
-        enabled = (caps.fused_enabled if self.fused_enabled is None
-                   else self.fused_enabled)
-        if not enabled or getattr(seg, "is_mutable", False):
+        if not self.fused_enabled or getattr(seg, "is_mutable", False):
             return None
         fused: List[Tuple[str, str]] = []
         for c in sorted(_plan_vals_cols(plan)):

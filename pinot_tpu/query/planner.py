@@ -167,13 +167,13 @@ def select_bitmap_leaves(plan: SegmentPlan,
     Per-leaf regime choice (reference: the broker/server pruners choose
     index-vs-scan per predicate): a leaf qualifies when its column can carry a
     bitmap index (single-value dict column within BITMAP_MAX_CARD) AND its
-    estimated selectivity sits at or below the calibrated
+    estimated selectivity sits at or below
     `KernelCaps.bitmap_sel_cap`. Selectivity comes from the inverted index's
     posting offsets when the segment has one (exact, O(ids) arithmetic),
     otherwise from matched-ids / cardinality (uniform-occupancy assumption).
     Dense predicates keep the interval-compare / one-hot LUT path, which beats
     streaming the whole word matrix when most rows match anyway."""
-    from ..engine.calibrate import get_caps
+    from ..engine.caps import get_caps
     from ..engine.datablock import BITMAP_MAX_CARD
     if plan.filter_prog is None or plan.filter_prog.is_match_all \
             or getattr(segment, "is_mutable", False):

@@ -10,7 +10,7 @@ then merged back into `QueryResult.stats` under well-known keys.
 Accounting sites publish through a thread-local "current stats" slot (same
 pattern as `utils.trace`): the server activates a fresh record on its
 execution thread, kernel/launch/fetch hooks `record()` into whatever record is
-active (a no-op when none is, e.g. warm-up and calibration; the pipeline's
+active (a no-op when none is, e.g. warm-up; the pipeline's
 dispatcher thread serves many queries at once, so it activates a scratch
 record around each query's prepare and around each launch and folds what the
 kernel cache recorded there into the items that launch answers), and the
@@ -293,7 +293,7 @@ def current_stats() -> Optional[ExecutionStats]:
 def record(key: str, n: float = 1) -> None:
     """Accounting hook for hot paths: add to the active record if any.
     Deliberately tolerant — kernel/fetch sites run on threads that may serve
-    many queries (pipeline dispatcher) or none (warmup/calibration), where
+    many queries (pipeline dispatcher) or none (warm-up), where
     per-query attribution happens elsewhere or not at all."""
     st = getattr(_local, "stats", None)
     if st is not None:

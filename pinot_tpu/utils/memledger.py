@@ -277,7 +277,7 @@ class MemoryLedger:
     def reconcile(self, baseline_bytes: int = 0) -> Dict[str, Any]:
         """Ledger total vs jax live-buffer bytes. `baseline_bytes` subtracts
         allocations that predate the measurement window (compile-time
-        constants, calibration arrays) so drift isolates *tracked* staging.
+        constants) so drift isolates *tracked* staging.
         driftPct is None when the runtime can't enumerate live arrays."""
         device = live_device_bytes()
         with self._lock:

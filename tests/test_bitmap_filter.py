@@ -166,18 +166,17 @@ def test_filter_count_declines_mixed_trees(indexed_segment):
 def test_select_bitmap_leaves_honors_selectivity_cap(indexed_segment):
     ctx = compile_query("SELECT COUNT(*) FROM bm WHERE region = 'r1'", SCHEMA)
     plan = plan_segment(ctx, indexed_segment)
-    from pinot_tpu.engine import calibrate
-    old = calibrate.get_caps()
-    calibrate.set_caps(
-        calibrate.KernelCaps(**{**old.__dict__, "bitmap_sel_cap": 0.5}))
+    from dataclasses import replace
+    from pinot_tpu.engine import caps
+    old = caps.get_caps()
+    caps.set_caps(replace(old, bitmap_sel_cap=0.5))
     try:
         assert select_bitmap_leaves(plan, indexed_segment) == (0,)
         # a cap below the leaf's ~1/8 selectivity rejects it
-        calibrate.set_caps(
-            calibrate.KernelCaps(**{**old.__dict__, "bitmap_sel_cap": 0.01}))
+        caps.set_caps(replace(old, bitmap_sel_cap=0.01))
         assert select_bitmap_leaves(plan, indexed_segment) == ()
     finally:
-        calibrate.set_caps(old)
+        caps.set_caps(old)
 
 
 def test_select_bitmap_leaves_skips_mutable_segments():

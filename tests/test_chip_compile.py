@@ -21,7 +21,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import chip_smoke
 from pinot_tpu.engine import kernels
-from pinot_tpu.engine.calibrate import get_caps, set_caps
 from pinot_tpu.parallel import combine
 from pinot_tpu.parallel.combine import MeshQueryExecutor
 from pinot_tpu.parallel.mesh import SEGMENT_AXIS, default_mesh
@@ -150,57 +149,36 @@ def _fits(compiled, hbm_bytes=16e9):
     assert total < hbm_bytes, f"program needs {total / 1e9:.1f} GB"
 
 
-# (smoke query, stacked segments, rows per segment, forced regime or None)
+# (smoke query, stacked segments, whether it is the sort regime's program)
 AGG_CASES = [
-    pytest.param("q1.1 filter+sum", SMOKE_SEGS, SEG_ROWS, None,
-                 id="q1.1-fused-scan"),
-    pytest.param("group-by region", MATMUL_SEGS, SEG_ROWS, None,
-                 id="lowcard-onehot"),
-    pytest.param("group-by 20k keys", MATMUL_SEGS, SEG_ROWS, None,
-                 id="20k-chunk64"),
+    pytest.param("q1.1 filter+sum", SMOKE_SEGS, False, id="q1.1-fused-scan"),
+    pytest.param("group-by region", MATMUL_SEGS, False, id="lowcard-onehot"),
+    pytest.param("group-by 20k keys", MATMUL_SEGS, False, id="20k-chunk64"),
     # what every GROUP BY of the one-chip smoke runs: past 2^24 rows per
-    # device the f32-exact matmul regimes are out and the default sort regime
-    # takes over, whatever the key count (~45 s of compile, on the chip too)
-    pytest.param("group-by 500k keys", SMOKE_SEGS, SEG_ROWS, "partitioned",
+    # device the f32-exact matmul regimes are out and the sort regime takes
+    # over, whatever the key count (~45 s of compile, on the chip too)
+    pytest.param("group-by 500k keys", SMOKE_SEGS, True,
                  id="500k-partitioned"),
-    # the non-default `sorted` regime compiles, but its segmented
-    # `associative_scan` makes compile time explode with the row count (PR 22,
-    # this sandbox's TPU compiler: 39 s at 1Mi rows, 157 s at 4Mi, 693 s at
-    # 16Mi, unfinished after 10 min at the smoke's 64Mi) — so it is held to
-    # 1Mi rows here, and ROADMAP queues its removal or repair
-    pytest.param("group-by 500k keys", 1, 1 << 20, "sorted",
-                 id="500k-sorted"),
-    pytest.param("bitmap-filter count", SMOKE_SEGS, SEG_ROWS, None,
-                 id="lut-count"),
-    pytest.param("distinctcounthll", SMOKE_SEGS, SEG_ROWS, None,
-                 id="hll-presence"),
+    pytest.param("bitmap-filter count", SMOKE_SEGS, False, id="lut-count"),
+    pytest.param("distinctcounthll", SMOKE_SEGS, False, id="hll-presence"),
 ]
 
 
-@pytest.mark.parametrize("name,segs,seg_rows,regime", AGG_CASES)
+@pytest.mark.parametrize("name,segs,sort_regime", AGG_CASES)
 def test_served_agg_kernel_compiles_for_v5e(topo, cpu_exec, segments, name,
-                                            segs, seg_rows, regime):
-    prev = get_caps()
-    if regime is not None:
-        from dataclasses import replace
-        set_caps(replace(prev, high_card_regime=regime))
-    try:
-        p, compiled = _compile_agg(topo, cpu_exec, segments, name, segs,
-                                   seg_rows=seg_rows)
-    finally:
-        if regime is not None:
-            set_caps(prev)
+                                            segs, sort_regime):
+    p, compiled = _compile_agg(topo, cpu_exec, segments, name, segs)
     _fits(compiled)
     if name == "q1.1 filter+sum":
         # the served scan decodes its dict columns in-register
         assert p.spec.fused_cols, "q1.1 no longer rides the fused decode"
-    if regime is not None:
+    if sort_regime:
         # 500k keys: the program holds both decodes of its sorted rows under
         # one conditional (PR 29), and the dense one's searches stay inside it
         text = compiled.as_text()
         assert " conditional(" in text
         for branch in ("compact", "dense"):
-            assert f"pinot.groupby.{regime}.{branch}" in text, branch
+            assert f"pinot.groupby.partitioned.{branch}" in text, branch
 
 
 def test_topk_kernel_compiles_for_v5e(topo, cpu_exec, segments):
@@ -286,24 +264,6 @@ def test_four_chip_program_compiles_with_its_collective(topo, cpu_exec,
                    jax.tree_util.tree_leaves(compiled.output_shardings))
     else:
         assert collective in text
-
-
-@pytest.mark.parametrize("variant", ["pallas", "xla"])
-def test_pallas_scan_compiles_for_v5e(topo, variant):
-    from jax.sharding import SingleDeviceSharding
-    from pinot_tpu.engine import pallas_scan
-    one = SingleDeviceSharding(topo.devices[0])
-    n = 1 << 24
-    assert n % pallas_scan.BLOCK_ROWS == 0
-    i32 = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one)
-    f32 = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one)
-    bands = [(19930101, 19931231), (1, 3), (-(1 << 31), 24)]
-    impl = (pallas_scan.masked_sums_pallas if variant == "pallas"
-            else pallas_scan.masked_sums_xla)
-    compiled = jax.jit(lambda *a: impl(a[:3], bands, a[3:])).lower(
-        i32, i32, i32, f32, f32).compile()
-    if variant == "pallas":
-        assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("width,decode", [

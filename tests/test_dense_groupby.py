@@ -11,7 +11,7 @@ DictionaryBasedGroupKeyGenerator.java:62 + GroupByDataTableReducer.java.
 import numpy as np
 import pytest
 
-from pinot_tpu.engine.kernels import CHUNK_KEY_CAP, MATMUL_KEY_CAP
+from pinot_tpu.engine.caps import KernelCaps, get_caps, set_caps
 from pinot_tpu.parallel import MeshQueryExecutor, default_mesh
 from pinot_tpu.query.executor import ServerQueryExecutor
 from pinot_tpu.schema import DataType, Schema, dimension, metric
@@ -19,7 +19,7 @@ from pinot_tpu.segment import load_segment
 from pinot_tpu.segment.writer import (SegmentGeneratorConfig,
                                       build_aligned_segments)
 
-N_KEYS = 2500  # > MATMUL_KEY_CAP -> the chunked kernel branch
+N_KEYS = 2500  # > matmul_cap -> the chunked kernel branch
 ROWS = 60_000
 
 
@@ -100,7 +100,7 @@ def test_onehot_sums_matches_numpy(n):
 
 
 def test_cap_structure():
-    assert MATMUL_KEY_CAP < N_KEYS + 1 <= CHUNK_KEY_CAP
+    assert KernelCaps().matmul_cap < N_KEYS + 1 <= KernelCaps().chunk_cap
 
 
 HC_QUERIES = [
@@ -289,17 +289,15 @@ def test_groupby_fuzz_across_cap_regimes(tmp_path_factory, mesh_exec, card):
 
 
 # ---------------------------------------------------------------------------
-# very-high-cardinality regimes: radix-partitioned + sort kernels (PR: the
-# segment_sum scatter fallback replacement) — differential vs the host engine
+# the very-high-cardinality regime: the radix-partitioned sort kernel —
+# differential vs the host engine
 # ---------------------------------------------------------------------------
-
-from pinot_tpu.engine.calibrate import KernelCaps, get_caps, set_caps  # noqa: E402
 
 
 @pytest.fixture(scope="module")
 def vhc_segments(tmp_path_factory):
     """6000-key set: padded key space 8192 crosses a FORCED chunk_cap of 4096,
-    so the sort-based regimes exercise cheaply in tier-1."""
+    so the sort regime exercises cheaply in tier-1."""
     rng = np.random.default_rng(7)
     rows = 40_000
     schema = Schema("vhc", [
@@ -343,37 +341,22 @@ VHC_QUERIES = [
 ]
 
 
-@pytest.mark.parametrize("regime", ["partitioned", "sorted"])
-def test_forced_high_card_regime_matches_host(vhc_segments, mesh_exec, regime):
-    """Force chunk_cap below the padded key space so BOTH new sort-based
-    kernels run through the full mesh stack, differentially vs the host."""
+def test_forced_sort_regime_matches_host(vhc_segments, mesh_exec):
+    """Force chunk_cap below the padded key space so the sort regime runs
+    through the full mesh stack, differentially vs the host."""
     host = ServerQueryExecutor(use_device=False)
     prev = get_caps()
-    set_caps(KernelCaps(chunk_cap=4096, high_card_regime=regime))
+    set_caps(KernelCaps(chunk_cap=4096))
     try:
         for sql in VHC_QUERIES:
             dev = mesh_exec.execute(vhc_segments, sql)
             want = host.execute(vhc_segments, sql)
-            _assert_rows_close(dev.rows, want.rows, (regime, sql))
+            _assert_rows_close(dev.rows, want.rows, sql)
     finally:
         set_caps(prev)
 
 
-def test_scatter_escape_hatch_matches_host(vhc_segments, mesh_exec):
-    """high_card_regime='scatter' keeps the legacy segment_sum path alive."""
-    host = ServerQueryExecutor(use_device=False)
-    prev = get_caps()
-    set_caps(KernelCaps(chunk_cap=4096, high_card_regime="scatter"))
-    try:
-        sql = VHC_QUERIES[0]
-        dev = mesh_exec.execute(vhc_segments, sql)
-        want = host.execute(vhc_segments, sql)
-        _assert_rows_close(dev.rows, want.rows, ("scatter", sql))
-    finally:
-        set_caps(prev)
-
-
-# --- the compact decode of the sort regimes (PR 29) --------------------------
+# --- the compact decode of the sort regime (PR 29) ---------------------------
 # 6000 keys pad to 8192 (nseg 8193) and cross a forced chunk_cap of 4096;
 # `pos` numbers the rows, so `WHERE pos < m` lets exactly m rows pass.
 COMPACT_ROWS = 12_000      # pads to 16,384 rows on one device
@@ -404,12 +387,12 @@ def compact_segment(tmp_path_factory):
     return load_segment(paths[0])
 
 
-def _compact_sql(seg, m):
+def _compact_sql(seg, m, aggs="COUNT(*), SUM(v), SUM(q)"):
     """Exactly m rows pass. For none, a range the segment's min/max cannot
     prune: the first row alone, less itself by its own `q`."""
     where = f"pos < {m}" if m else \
         f"pos < 1 AND q > {int(np.asarray(seg.column('q').values())[0])}"
-    return ("SELECT k, COUNT(*), SUM(v), SUM(q) FROM cd "
+    return (f"SELECT k, {aggs} FROM cd "
             f"WHERE {where} GROUP BY k ORDER BY k LIMIT 3000000")
 
 
@@ -432,25 +415,27 @@ def _dense_only(monkeypatch):
 
 @pytest.mark.parametrize("block", [256, 320], ids=["aligned", "ragged"])
 @pytest.mark.parametrize("passing", ["0", "1", "cap-1", "cap", "cap+1", "all"])
-@pytest.mark.parametrize("regime", ["partitioned", "sorted"])
+@pytest.mark.parametrize("aggs", ["COUNT(*)", "COUNT(*), SUM(v), SUM(q)"],
+                         ids=["count", "sums"])
 def test_compact_decode_matches_dense_and_host(compact_segment, monkeypatch,
-                                               regime, passing, block):
-    """Both branches of a sort regime's decode, against the host executor and
-    against each other, around the cap: the sorted prefix of rows that passed
-    answers up to `cap` rows (the last of them ends its group on the prefix's
-    last row), the per-key decode above. 16,384 padded rows are a multiple of
-    a 256-row block and 64 short of one of 320 (the sort pads them)."""
+                                               aggs, passing, block):
+    """Both branches of the sort regime's decode, against the host executor
+    and against each other, around the cap: the sorted prefix of rows that
+    passed answers up to `cap` rows (the last of them ends its group on the
+    prefix's last row), the per-key decode above. 16,384 padded rows are a
+    multiple of a 256-row block and 64 short of one of 320 (the sort pads
+    them). With COUNT(*) alone the sort carries no value rows: the decode a
+    grouped DISTINCTCOUNT past `chunk_cap` runs."""
     from pinot_tpu.engine import kernels
     n = 16_384 + (-16_384) % block
     cap = kernels.compact_cap(n, 8193, block)
     assert cap == n // 64
     m = {"0": 0, "1": 1, "cap-1": cap - 1, "cap": cap, "cap+1": cap + 1,
          "all": COMPACT_ROWS}[passing]
-    sql = _compact_sql(compact_segment, m)
+    sql = _compact_sql(compact_segment, m, aggs)
     mex = MeshQueryExecutor(default_mesh(1))
     prev = get_caps()
-    set_caps(KernelCaps(chunk_cap=4096, high_card_regime=regime,
-                        partition_block=block))
+    set_caps(KernelCaps(chunk_cap=4096, partition_block=block))
     try:
         got, took = _executed(mex, [compact_segment], sql)
         want = ServerQueryExecutor(use_device=False).execute(
@@ -464,11 +449,11 @@ def test_compact_decode_matches_dense_and_host(compact_segment, monkeypatch,
     assert neither == {"compactDecodeLaunches": 0, "denseDecodeLaunches": 0}
     assert len(want) == len(np.unique(
         np.asarray(compact_segment.column("k").values())[:m]))
-    _assert_rows_close(got, want, (regime, passing, block))
-    _assert_rows_close(dense, want, (regime, passing, block, "dense"))
+    _assert_rows_close(got, want, (aggs, passing, block))
+    _assert_rows_close(dense, want, (aggs, passing, block, "dense"))
     # against each other: groups and counts exactly, sums within tolerance
     assert [r[:2] for r in got] == [r[:2] for r in dense]
-    _assert_rows_close(got, dense, (regime, passing, block, "branches"))
+    _assert_rows_close(got, dense, (aggs, passing, block, "branches"))
 
 
 @pytest.mark.parametrize("length,p_head", [
@@ -497,17 +482,14 @@ def test_run_totals_match_a_row_by_row_walk(length, p_head, rows):
     np.testing.assert_allclose(np.asarray(sums), want, rtol=2e-6, atol=1e-2)
 
 
-@pytest.mark.parametrize("regime", ["partitioned", "sorted"])
-def test_compact_ladder_takes_the_shortest_prefix_that_fits(monkeypatch,
-                                                            regime):
+def test_compact_ladder_takes_the_shortest_prefix_that_fits(monkeypatch):
     """A ladder of prefixes (16 and 64 rows here, then the cap's 256) chosen
     by the same count: each side of every rung answers as numpy does, with
     three keys so that a run spans many blocks of the run-totals scan."""
     import jax
     from pinot_tpu.engine import kernels
     monkeypatch.setattr(kernels, "COMPACT_RUNGS", (16, 64))
-    fn = (kernels._grouped_partitioned if regime == "partitioned"
-          else kernels._grouped_sorted)
+    fn = kernels._grouped_partitioned
     n, nseg, block = 16_384, 8193, 256
     took = []
     run = jax.jit(lambda k, v: (fn(k, nseg, [v], block, took), took[-1]))
@@ -564,7 +546,7 @@ def test_one_chip_dense_three_compact_on_the_mesh(tmp_path_factory):
     mex = MeshQueryExecutor(default_mesh(4))
     host = ServerQueryExecutor(use_device=False)
     prev = get_caps()
-    set_caps(KernelCaps(chunk_cap=4096, high_card_regime="partitioned"))
+    set_caps(KernelCaps(chunk_cap=4096))
     try:
         for bound, passing, compact in ((100, 8030, 0), (10, 40, 1)):
             sql = ("SELECT k, COUNT(*), SUM(v) FROM cm "
@@ -642,7 +624,6 @@ def test_partitioned_regime_128k_groups(tmp_path_factory, mesh_exec):
     """Tier-1 anchor of the sweep: 140k REAL groups is past the default
     chunk_cap (131072), so the radix-partitioned kernel is the regime
     actually dispatched."""
-    assert get_caps().high_card_regime == "partitioned"
     _run_very_high_card(tmp_path_factory, mesh_exec, 140_000, 160_000)
 
 

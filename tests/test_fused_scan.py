@@ -219,23 +219,6 @@ def test_stacked_burst_one_launch_byte_identical(seg):
         assert sorted(map(tuple, got)) == _rows(want), sqls[i]
 
 
-def test_fused_kill_switch_env(seg, monkeypatch):
-    """PINOT_TPU_FUSED=0 routes every plan down the staged rung."""
-    from pinot_tpu.engine import calibrate
-    monkeypatch.setenv("PINOT_TPU_FUSED", "0")
-    calibrate.set_caps(None)  # force lazy re-resolution under the env var
-    try:
-        assert calibrate.get_caps().fused_enabled is False
-        sql = "SELECT COUNT(*), SUM(num_for) FROM fused WHERE dim_a = 'a1'"
-        with qstats.collect_stats() as st:
-            ServerQueryExecutor().execute([seg], sql)
-        assert int(st.counters.get(qstats.FUSED_LAUNCHES, 0)) == 0
-        assert int(st.counters.get(qstats.STAGED_LAUNCHES, 0)) >= 1
-    finally:
-        monkeypatch.delenv("PINOT_TPU_FUSED")
-        calibrate.set_caps(None)
-
-
 def test_staged_spec_reuses_match_all_single_launch(seg):
     """A match-all filter needs no mask launch: staged executes in ONE
     launch and records stagedLaunches=1."""

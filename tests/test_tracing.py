@@ -551,15 +551,13 @@ def _lowered_text(seg, sql):
 @pytest.mark.parametrize("regime,caps", [
     ("onehot", dict(matmul_cap=16384)),
     ("chunk64", dict()),
-    ("scatter", dict(chunk_cap=4096, high_card_regime="scatter")),
-    ("sorted", dict(chunk_cap=4096, high_card_regime="sorted")),
-    ("partitioned", dict(chunk_cap=4096, high_card_regime="partitioned")),
+    ("partitioned", dict(chunk_cap=4096)),
 ])
 def test_groupby_regime_scope_in_lowered_program(scope_segment, regime, caps):
     """Each GROUP BY regime's program names its stages: its own
     `pinot.groupby.<regime>` scope, the decode and the filter; and the jitted
     function is named for what it is, not `shard_body`."""
-    from pinot_tpu.engine.calibrate import KernelCaps, get_caps, set_caps
+    from pinot_tpu.engine.caps import KernelCaps, get_caps, set_caps
     prev = get_caps()
     set_caps(KernelCaps(**caps))
     try:
@@ -572,12 +570,12 @@ def test_groupby_regime_scope_in_lowered_program(scope_segment, regime, caps):
     for scope in (f"pinot.groupby.{regime}", "pinot.groupby.key",
                   "pinot.decode", "pinot.filter"):
         assert scope in text, scope
-    others = {"onehot", "chunk64", "scatter", "sorted", "partitioned"}
+    others = {"onehot", "chunk64", "partitioned"}
     for other in others - {regime}:
         assert f"pinot.groupby.{other}" not in text, other
-    if regime in ("sorted", "partitioned"):
+    if regime == "partitioned":
         for part in ("sort", "scan", "trim"):
-            assert f"pinot.groupby.{regime}.{part}" in text, part
+            assert f"pinot.groupby.partitioned.{part}" in text, part
     assert "module @jit_pinot_groupby_fused " in text
     assert "jit(pinot_groupby_fused)/" in text     # the head of every tf_op
 
@@ -671,17 +669,16 @@ def test_gather_free_launches_reaches_the_served_response(tmp_path):
         assert s["gatherFreeLaunches"] == free, (name, s)
 
 
-# -- PR 29: the sort regimes' two decodes, their scopes and their counters ----
+# -- PR 29: the sort regime's two decodes, their scopes and their counters ----
 
-@pytest.mark.parametrize("regime", ["sorted", "partitioned"])
-def test_sort_regime_program_holds_both_decodes(scope_segment, regime):
+def test_sort_regime_program_holds_both_decodes(scope_segment):
     """One conditional on the count of rows that passed: the answer from the
     sorted prefix under `.compact`, today's per-key decode (its `.trim` and
-    `.scan` names unchanged) under `.dense`; the sort is shared. Neither is
-    the flat n-row scatter, whose scope name stays its own."""
-    from pinot_tpu.engine.calibrate import KernelCaps, get_caps, set_caps
+    `.scan` names unchanged) under `.dense`; the sort is shared."""
+    from pinot_tpu.engine.caps import KernelCaps, get_caps, set_caps
+    regime = "partitioned"
     prev = get_caps()
-    set_caps(KernelCaps(chunk_cap=4096, high_card_regime=regime))
+    set_caps(KernelCaps(chunk_cap=4096))
     try:
         _, text = _lowered_text(
             scope_segment,
@@ -698,7 +695,6 @@ def test_sort_regime_program_holds_both_decodes(scope_segment, regime):
             in text, part
         assert f"pinot.groupby.{regime}.compact/pinot.groupby.{regime}." \
             f"{part}" not in text, part
-    assert "pinot.groupby.scatter" not in text
     from benchmark.harness.program_trace import scope_of
     assert scope_of(f"jit(pinot_groupby)/pinot.groupby.{regime}/cond/"
                     f"branch_1_fun/pinot.groupby.{regime}.compact/"
@@ -709,7 +705,7 @@ def test_sort_regime_program_holds_both_decodes(scope_segment, regime):
 def test_small_key_program_past_2_24_rows_holds_no_decode_branch():
     """Where today's decode costs less than a `cap`-row pass no branch is
     built: the full-size cell's 8,193-key templates (32Mi and 64Mi rows, where
-    every GROUP BY takes a sort regime) keep their one-branch programs, the
+    every GROUP BY takes the sort regime) keep their one-branch programs, the
     wide-key ones get n / 64 rows."""
     import jax
     import jax.numpy as jnp
@@ -739,11 +735,11 @@ def test_decode_counters_reach_response_explain_and_health(tmp_path):
     `compactDecodeLaunches` 1; one that passes every row of ONE chip's segment
     (and 10 rows of each other chip's) with `denseDecodeLaunches` 1, since a
     mesh launch is compact only if every chip took it; the same GROUP BY under
-    the default caps, where it takes no sort regime, with neither. EXPLAIN ANALYZE carries the same fields and
+    the default caps, where it does not take the sort regime, with neither. EXPLAIN ANALYZE carries the same fields and
     `/health`'s device block (the pipeline's `stats()`) sums the launches."""
     from tests.test_dense_groupby import one_full_quarter
     from pinot_tpu.cluster.device_server import DeviceQueryPipeline
-    from pinot_tpu.engine.calibrate import KernelCaps, get_caps, set_caps
+    from pinot_tpu.engine.caps import KernelCaps, get_caps, set_caps
     from pinot_tpu.parallel import MeshQueryExecutor, default_mesh
     from pinot_tpu.table import IndexingConfig
     schema, cols = one_full_quarter("dc")
@@ -766,7 +762,7 @@ def test_decode_counters_reach_response_explain_and_health(tmp_path):
           "ORDER BY k LIMIT 10000"
     prev = get_caps()
     try:
-        # 8,192 padded keys: the chunked matmul by default, a sort regime
+        # 8,192 padded keys: the chunked matmul by default, the sort regime
         # once the chunk cap is forced under them
         got = {"no sort regime": cluster.query(sql.format(100))}
         set_caps(KernelCaps(chunk_cap=4096))
