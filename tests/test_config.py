@@ -147,3 +147,22 @@ def test_server_lifecycle_and_readiness(tmp_path):
     node.shutdown()
     assert node.status == "SHUTTING_DOWN"
     assert cluster.catalog.instances[node.instance_id].alive is False
+
+
+def test_every_env_variable_is_documented():
+    """The `PINOT_TPU_*` names in the program and in README.md are the same
+    set: a variable is neither read undocumented nor documented unread."""
+    import os
+    import re
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    name = re.compile(r"PINOT_TPU_[A-Z0-9_]*[A-Z0-9]")
+    read = set()
+    for d, _, files in os.walk(os.path.join(root, "pinot_tpu")):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(d, f)) as fh:
+                    read |= set(name.findall(fh.read()))
+    with open(os.path.join(root, "README.md")) as fh:
+        documented = set(name.findall(fh.read()))
+    assert read == documented, (sorted(read - documented),
+                                sorted(documented - read))

@@ -456,6 +456,39 @@ def test_compact_decode_matches_dense_and_host(compact_segment, monkeypatch,
     _assert_rows_close(got, dense, (aggs, passing, block, "branches"))
 
 
+@pytest.mark.parametrize("passing", [50, 12_000], ids=["few", "many"])
+def test_grouped_distinct_past_chunk_cap_matches_host(tmp_path_factory,
+                                                      passing):
+    """A grouped DISTINCTCOUNT whose (group, id) product is wider than
+    `chunk_cap` counts presence by the sort regime with no value rows. Its
+    masked rows ride an overflow BAND of `ids` keys, not the one overflow key,
+    so the decode's count of rows that passed holds them too and the per-key
+    decode answers however few pass (ROADMAP S1)."""
+    rng = np.random.default_rng(30)
+    rows = COMPACT_ROWS
+    schema = Schema("dd", [dimension("g", DataType.INT),
+                           dimension("d", DataType.INT),
+                           metric("pos", DataType.INT)])
+    cols = {"g": rng.integers(0, 20, rows).astype(np.int32),
+            "d": rng.integers(0, 300, rows).astype(np.int32),
+            "pos": np.arange(rows, dtype=np.int32)}
+    cfg = SegmentGeneratorConfig(no_dictionary_columns=["pos"])
+    seg = load_segment(build_aligned_segments(
+        schema, cols, str(tmp_path_factory.mktemp("dd")), "dd", 1,
+        config=cfg)[0])
+    sql = ("SELECT g, DISTINCTCOUNT(d), COUNT(*) FROM dd "
+           f"WHERE pos < {passing} GROUP BY g ORDER BY g LIMIT 100")
+    prev = get_caps()
+    set_caps(KernelCaps(chunk_cap=4096))     # 33 groups x 512 ids = 16,896
+    try:
+        got, took = _executed(MeshQueryExecutor(default_mesh(1)), [seg], sql)
+    finally:
+        set_caps(prev)
+    want = ServerQueryExecutor(use_device=False).execute([seg], sql).rows
+    assert got == want and sum(r[2] for r in got) == passing
+    assert took == {"compactDecodeLaunches": 0, "denseDecodeLaunches": 1}
+
+
 @pytest.mark.parametrize("length,p_head", [
     (256, 0.3), (1024, 0.3), (4096, 0.01), (8192, 0.0005), (2048, 1.0),
     (2048, 0.0)])
@@ -744,3 +777,87 @@ def test_no_flat_scatter_at_high_card(tmp_path_factory):
     sizes = _scatter_update_rows(jaxpr.jaxpr)
     assert sizes and set(sizes) == {cap, 1}, sizes   # 1: the overflow bucket
     assert cap <= n // 64
+
+
+# --- one place chooses the kernel: the ladder at the production constants -----
+
+def _lowered_scan(aggs_sql, num_keys_pad, rows, distinct_size=None):
+    """The scan's lowered text (scope names included) for a GROUP BY k over
+    abstract shapes: nothing is staged, compiled or run."""
+    import jax
+    import jax.numpy as jnp
+
+    from pinot_tpu.engine import kernels
+    from pinot_tpu.query.aggregates import make_agg
+    from pinot_tpu.query.context import compile_query
+    from pinot_tpu.query.predicate import FilterProgram
+    schema = Schema("t", [dimension("k", DataType.INT),
+                          dimension("d", DataType.INT),
+                          metric("v", DataType.DOUBLE)])
+    ctx = compile_query(f"SELECT k, {aggs_sql} FROM t GROUP BY k", schema)
+    aggs = [make_agg(f) for f in ctx.aggregations]
+    spec = kernels.KernelSpec(
+        FilterProgram(), ("k",), num_keys_pad,
+        tuple((a, a.device_outputs) for a in aggs),
+        {i: distinct_size for i, a in enumerate(aggs)
+         if "distinct" in a.device_outputs}, rows)
+    shape = jax.ShapeDtypeStruct
+    i32, f32 = shape((rows,), jnp.int32), shape((rows,), jnp.float32)
+    return jax.jit(kernels.make_kernel_body(spec)).lower(
+        {"k": i32, "d": i32}, {"v": f32}, (), shape((0,), jnp.int32),
+        shape((0,), jnp.float32), {}, shape((rows,), jnp.bool_),
+        shape((1,), jnp.int32), {}, ()).as_text(debug_info=True)
+
+
+_SUMS, _MINMAX, _DISTINCT = "COUNT(*), SUM(v)", "MIN(v)", "DISTINCTCOUNT(d)"
+_2_24 = 1 << 24
+# (aggregates, padded keys, rows a device, ids of the distinct column,
+#  the scope the program must hold, scopes it must not)
+LADDER = [
+    # padded keys + 1 (the overflow bucket) against matmul_cap = 512
+    pytest.param(_SUMS, 511, 16_384, None, "pinot.groupby.onehot",
+                 ("chunk64", "partitioned"), id="keys-at-matmul_cap"),
+    pytest.param(_SUMS, 512, 16_384, None, "pinot.groupby.chunk64",
+                 ("onehot", "partitioned"), id="keys-past-matmul_cap"),
+    # ... against chunk_cap = 131,072
+    pytest.param(_SUMS, 131_071, 16_384, None, "pinot.groupby.chunk64",
+                 ("onehot", "partitioned"), id="keys-at-chunk_cap"),
+    pytest.param(_SUMS, 131_072, 16_384, None, "pinot.groupby.partitioned",
+                 ("onehot", "chunk64"), id="keys-past-chunk_cap"),
+    # 2^24 rows keep the f32-exact matmul regimes; one block more is the sort
+    pytest.param(_SUMS, 256, _2_24, None, "pinot.groupby.onehot",
+                 ("chunk64", "partitioned"), id="onehot-at-2^24-rows"),
+    pytest.param(_SUMS, 256, _2_24 + 4096, None, "pinot.groupby.partitioned",
+                 ("onehot", "chunk64"), id="onehot-past-2^24-rows"),
+    pytest.param(_SUMS, 8192, _2_24, None, "pinot.groupby.chunk64",
+                 ("onehot", "partitioned"), id="chunk64-at-2^24-rows"),
+    pytest.param(_SUMS, 8192, _2_24 + 4096, None, "pinot.groupby.partitioned",
+                 ("onehot", "chunk64"), id="chunk64-past-2^24-rows"),
+    # min/max: the broadcast-reduce up to minmax_bcast_cap = 1,024 keys + 1
+    pytest.param(_MINMAX, 1023, 16_384, None,
+                 "pinot.groupby.minmax/reduce_min", ("minmax/scatter-min",),
+                 id="minmax-at-bcast_cap"),
+    pytest.param(_MINMAX, 1024, 16_384, None,
+                 "pinot.groupby.minmax/scatter-min", ("minmax/reduce_min",),
+                 id="minmax-past-bcast_cap"),
+    # grouped distinct: (groups + 1) x ids against chunk_cap
+    pytest.param(_DISTINCT, 15, 16_384, 8192, "pinot.distinct/dot_general",
+                 ("partitioned",), id="distinct-product-at-chunk_cap"),
+    pytest.param(_DISTINCT, 16, 16_384, 8192,
+                 "pinot.distinct/pinot.groupby.partitioned.sort",
+                 ("pinot.distinct/dot_general",),
+                 id="distinct-product-past-chunk_cap"),
+]
+
+
+@pytest.mark.parametrize("aggs,keys,rows,ids,holds,not_these", LADDER)
+def test_regime_ladder_boundaries(aggs, keys, rows, ids, holds, not_these):
+    """Which kernel a plan's shape gets, at the constants of `engine/caps.py`
+    as they ship (no `set_caps`): each crossover of the ladder from both
+    sides, read from the scope names of the lowered program."""
+    assert get_caps() == KernelCaps()
+    text = _lowered_scan(aggs, keys, rows, ids)
+    assert holds in text
+    for scope in not_these:
+        assert (scope if "/" in scope else f"pinot.groupby.{scope}") \
+            not in text, scope
