@@ -23,8 +23,8 @@ then groups the prepared work before touching the device:
     into ONE batched kernel launch instead of N sequential dispatches;
   * everything dispatched in a drain is fetched with ONE host sync, so under
     concurrency the host round trip amortizes across the batch
-    (reference: `QueryScheduler.java:56` bounds per-server concurrency — here batching
-    is what concurrency buys, because the device serializes dispatches
+    (reference: `QueryScheduler.java:56` bounds per-server concurrency — here
+    batching is what concurrency buys, because the device serializes dispatches
     anyway).
 
 Queries whose plan cannot ride the device (host-only functions, doc-set

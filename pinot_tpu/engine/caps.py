@@ -62,7 +62,7 @@ class KernelCaps:
     join_scatter_cap: int = 1 << 20
 
     def token(self) -> Tuple:
-        """The caps as a jit cache key: every field changes compiled kernels."""
+        """The caps as a jit cache key: every field changes compiled code."""
         return astuple(self)
 
 
@@ -73,7 +73,7 @@ def get_caps() -> KernelCaps:
     return _ACTIVE
 
 
-def set_caps(caps: KernelCaps) -> KernelCaps:
+def set_caps(caps: KernelCaps) -> None:
     """Install other caps — for tests: nothing in the served path calls this.
     Flushes the compiled kernel caches: a cap change changes dispatch, and
     `KernelSpec.signature()` only protects NEW lookups, not memory held by
@@ -86,4 +86,3 @@ def set_caps(caps: KernelCaps) -> KernelCaps:
     from . import kernels
     kernels._KERNEL_CACHE.clear()
     combine._SHARD_KERNEL_CACHE.clear()
-    return caps

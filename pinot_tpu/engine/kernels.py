@@ -825,9 +825,10 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
     [B, block, 64]^T @ [B, block, 64] dot per bf16 part — total MACs
     N * block, i.e. a single chunk64-tile-equivalent per part REGARDLESS of
     key count, where the chunked path pays per 4096 keys and a flat
-    `segment_sum` scatter (what PR 1 replaced) paid a K-independent ~248ms. Groups spanning slab boundaries always occupy
-    local id 0 of the continuation slabs, so a short segmented scan over the
-    [B] slab-head sums stitches them. The dense decode has no n-row scatter
+    `segment_sum` scatter (what PR 1 replaced) paid a K-independent ~248ms.
+    Groups spanning slab boundaries always occupy local id 0 of the
+    continuation slabs, so a short segmented scan over the [B] slab-head sums
+    stitches them. The dense decode has no n-row scatter
     either: `searchsorted` run boundaries give exact int32 counts and each
     key's first sorted position, from which (slab, local id, continuation
     chain) are pure gathers — two binary searches for EVERY dense key, so
@@ -1010,9 +1011,10 @@ def _make_body(spec: KernelSpec):
                             minmax.append((f"{ai}.{o}", v.ravel(), o == "min"))
             # f32 one-hot counts are exact only up to 2^24 increments (2^24 itself
             # IS representable); the row count is static at trace time, so pick the
-            # sort regime's exact int32 counts when a single group could overflow
-            # the f32 integer range (keys.size is the bound). The <= matters: a 16M-row
-            # padded block sits exactly at 2^24 and must keep the matmul path.
+            # sort regime's exact int32 counts when a single group could
+            # overflow the f32 integer range (keys.size is the bound). The <=
+            # matters: a 16M-row padded block sits exactly at 2^24 and must
+            # keep the matmul path.
             count_exact_in_f32 = key.size <= (1 << 24)
             if num_seg <= caps.matmul_cap and count_exact_in_f32:
                 with scope("pinot.groupby.onehot"):
@@ -1165,9 +1167,9 @@ def run_kernel_staged(spec: KernelSpec,
     riding in as `valid` — two device launches where `run_kernel` takes one.
     `_fused_cols` routes here the two inputs only this path runs: a decode
     table over `KernelCaps.fused_lut_cap` and a multi-value value column.
-    Tests hold it as the fused path's reference: results are bit-identical to the
-    fused path — both consume the same decode tables and the same mask
-    semantics, only the HBM traffic and launch count differ."""
+    Tests hold it as the fused path's reference: results are bit-identical —
+    both consume the same decode tables and the same mask semantics, only
+    the HBM traffic and launch count differ."""
     if spec.filter.is_match_all:
         mask_dev = inputs.valid     # no filter: the mask launch would be a no-op
         qstats.record(qstats.STAGED_LAUNCHES)

@@ -122,23 +122,22 @@ def _prepare(cpu_exec, segments, name):
     return p
 
 
-def _real_spec(spec, seg_rows=SEG_ROWS):
+def _real_spec(spec):
     return kernels.KernelSpec(spec.filter, spec.group_cols, spec.num_keys_pad,
-                              spec.aggs, spec.distinct_lut_sizes, seg_rows,
+                              spec.aggs, spec.distinct_lut_sizes, SEG_ROWS,
                               mv_cols=spec.mv_cols,
                               bitmap_leaves=spec.bitmap_leaves,
                               fused_cols=spec.fused_cols)
 
 
-def _compile_agg(topo, cpu_exec, segments, name, segs, n_devices=1,
-                 seg_rows=SEG_ROWS):
-    """Compile the served shard kernel of QUERIES[name] at [segs, seg_rows]
+def _compile_agg(topo, cpu_exec, segments, name, segs, n_devices=1):
+    """Compile the served shard kernel of QUERIES[name] at [segs, SEG_ROWS]
     on `n_devices` described chips; returns (prepared, compiled)."""
     p = _prepare(cpu_exec, segments, name)
     mesh = _mesh(topo, n_devices)
-    ax = _abstract(p.inputs, (p.s_pad, p.rows), (segs, seg_rows), mesh)
+    ax = _abstract(p.inputs, (p.s_pad, p.rows), (segs, SEG_ROWS), mesh)
     chip_exec = MeshQueryExecutor(mesh)
-    fn = chip_exec._build_shard_kernel(_real_spec(p.spec, seg_rows))
+    fn = chip_exec._build_shard_kernel(_real_spec(p.spec))
     return p, fn.jitted_for(ax).lower(ax).compile()
 
 

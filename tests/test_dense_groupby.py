@@ -779,7 +779,7 @@ def test_no_flat_scatter_at_high_card(tmp_path_factory):
     assert cap <= n // 64
 
 
-# --- one place chooses the kernel: the ladder at the production constants -----
+# --- one place chooses the kernel: the ladder at the shipped constants -------
 
 def _lowered_scan(aggs_sql, num_keys_pad, rows, distinct_size=None):
     """The scan's lowered text (scope names included) for a GROUP BY k over

@@ -735,7 +735,8 @@ def test_decode_counters_reach_response_explain_and_health(tmp_path):
     `compactDecodeLaunches` 1; one that passes every row of ONE chip's segment
     (and 10 rows of each other chip's) with `denseDecodeLaunches` 1, since a
     mesh launch is compact only if every chip took it; the same GROUP BY under
-    the default caps, where it does not take the sort regime, with neither. EXPLAIN ANALYZE carries the same fields and
+    the default caps, where it does not take the sort regime, with neither.
+    EXPLAIN ANALYZE carries the same fields and
     `/health`'s device block (the pipeline's `stats()`) sums the launches."""
     from tests.test_dense_groupby import one_full_quarter
     from pinot_tpu.cluster.device_server import DeviceQueryPipeline
