@@ -31,3 +31,14 @@ def test_caps_change_kernel_signature(restore_caps):
     caps.set_caps(replace(caps.get_caps(), chunk_cap=4096))
     sig_b = spec.signature()
     assert sig_a != sig_b  # caps token folds into the jit cache key
+
+
+def test_set_caps_flushes_the_kernel_caches(restore_caps):
+    """Programs built under other caps are not kept: `set_caps` empties the
+    single-device and the mesh kernel caches."""
+    from pinot_tpu.engine import kernels
+    from pinot_tpu.parallel import combine
+    kernels._KERNEL_CACHE[("stale",)] = object()
+    combine._SHARD_KERNEL_CACHE[("stale",)] = object()
+    caps.set_caps(replace(caps.get_caps(), matmul_cap=256))
+    assert not kernels._KERNEL_CACHE and not combine._SHARD_KERNEL_CACHE

@@ -847,6 +847,13 @@ LADDER = [
                  "pinot.distinct/pinot.groupby.partitioned.sort",
                  ("pinot.distinct/dot_general",),
                  id="distinct-product-past-chunk_cap"),
+    # ... and its own 2^24-row guard (an f32 presence cell could overflow)
+    pytest.param(_DISTINCT, 15, _2_24, 64, "pinot.distinct/dot_general",
+                 ("partitioned",), id="distinct-at-2^24-rows"),
+    pytest.param(_DISTINCT, 15, _2_24 + 4096, 64,
+                 "pinot.distinct/pinot.groupby.partitioned.sort",
+                 ("pinot.distinct/dot_general",),
+                 id="distinct-past-2^24-rows"),
 ]
 
 

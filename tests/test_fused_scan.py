@@ -140,6 +140,50 @@ def test_mv_value_column_degrades_to_staged(seg):
     assert _rows(got) == _rows(want)
 
 
+@pytest.mark.parametrize("kind", ["server", "mesh"])
+def test_table_over_fused_lut_cap_is_not_fused(seg, kind):
+    """The other input only the staged path runs, decided from the plan alone:
+    a dict value column whose padded decode table (64 entries for `dim_i`'s
+    40) is over `fused_lut_cap` is not routed fused — the server executor
+    takes the two staged launches, the mesh keeps the decoded column — and
+    the answer is the fused one's, byte for byte."""
+    from dataclasses import replace
+
+    from pinot_tpu.engine.caps import get_caps, set_caps
+    from pinot_tpu.parallel import MeshQueryExecutor, default_mesh
+    from pinot_tpu.query.context import compile_query
+    sql = ("SELECT dim_a, SUM(dim_i), COUNT(*) FROM fused "
+           "WHERE num_for BETWEEN 1050 AND 1150 GROUP BY dim_a")
+    make = (ServerQueryExecutor if kind == "server"
+            else lambda: MeshQueryExecutor(default_mesh(1)))
+
+    def run():
+        """(rows, the launch counters, the mesh spec's fused columns)."""
+        ex = make()
+        with qstats.collect_stats() as st:
+            rows = _rows(ex.execute([seg], sql))
+        routed = (ex.prepare_partial(compile_query(sql, seg.schema),
+                                     [seg]).spec.fused_cols
+                  if kind == "mesh" else None)
+        return rows, {k: int(st.counters.get(k, 0)) for k in (
+            qstats.FUSED_LAUNCHES, qstats.STAGED_LAUNCHES)}, routed
+
+    fused, launches_f, routed_f = run()
+    prev = get_caps()
+    set_caps(replace(prev, fused_lut_cap=32))
+    try:
+        over, launches_s, routed_s = run()
+    finally:
+        set_caps(prev)
+        release_block(seg)
+    assert over == fused
+    if kind == "server":
+        assert launches_f == {"fusedLaunches": 1, "stagedLaunches": 0}
+        assert launches_s == {"fusedLaunches": 0, "stagedLaunches": 2}
+    else:
+        assert routed_f == (("dim_i", "dict"),) and routed_s == ()
+
+
 def test_for_form_eligibility(seg):
     """num_for (range 200, int16 raw) carries a FOR form; num_wide (range
     2^21) and the doubles do not."""

@@ -159,6 +159,28 @@ def test_admission_gate_rejects_past_target_and_host_tier_answers(
                           table=table) >= 1
 
 
+def test_admission_prices_the_fused_working_set(tmp_path, ssb_schema,
+                                                fresh_ledger):
+    """The gate charges the compressed-resident layout fused plans read (ids +
+    decode table, no decoded-values cache): a segment whose fused price fits
+    the target and whose decoded price does not is admitted."""
+    cluster, cfg, names = _build_cluster(tmp_path, ssb_schema, 1)
+    server = cluster.servers[0]
+    seg = server.tables[cfg.table_name_with_type].get(names[0])
+    fused = predicted_block_bytes(seg, fused=True)
+    decoded = predicted_block_bytes(seg)
+    assert 0 < fused < decoded
+    get_ledger().set_capacity(int((fused + decoded) / 2 / 0.9))  # the target
+
+    res = cluster.query("SELECT SUM(lo_revenue) FROM lineorder")
+    assert res.rows[0][0] is not None and not res.stats["partialResult"]
+    assert not res.stats.get("segmentsServedHostTier")
+    tiering = server.tiering.snapshot()
+    assert fused <= tiering["targetBytes"] < decoded
+    assert tiering["rejections"] == 0 and tiering["admissions"] >= 1
+    assert has_block(seg)
+
+
 def test_admission_reservations_prevent_same_query_overcommit(
         tmp_path, ssb_schema, fresh_ledger):
     """All of a query's segments admit BEFORE any block stages; without
