@@ -65,10 +65,11 @@ DEVICE_FALLBACK = _Sentinel()
 _STAGES = ("queue_wait", "dispatch", "prepare", "launch", "handoff", "fetch",
            "decode")
 
-#: what a launch on more than one device records about what crosses the
-#: chips; also summed over the launches in `stats()`
-_MESH_KEYS = (qstats.MESH_LAUNCHES, qstats.SCATTER_LAUNCHES,
-              qstats.COLLECTIVE_BYTES)
+#: what a launch records from the static shapes its program was built with,
+#: also summed over the launches in `stats()`: on more than one device, what
+#: crosses the chips; past 2^24 rows a device, a matmul GROUP BY slab by slab
+_SHAPE_KEYS = (qstats.MESH_LAUNCHES, qstats.SCATTER_LAUNCHES,
+               qstats.COLLECTIVE_BYTES, qstats.SLABBED_LAUNCHES)
 
 #: which decode branch a sort-regime GROUP BY launch ran: known once its
 #: outputs are fetched (`qstats.decode_branch`), summed over the launches in
@@ -80,7 +81,7 @@ _DECODE_KEYS = (qstats.COMPACT_DECODE_LAUNCHES, qstats.DENSE_DECODE_LAUNCHES)
 #: into the items a launch answers
 _LAUNCH_KEYS = (qstats.COMPILE_MS, qstats.COMPILE_CACHE_MISSES,
                 qstats.COMPILE_CACHE_HITS, qstats.DEVICE_LAUNCHES,
-                qstats.GATHER_FREE_LAUNCHES) + _MESH_KEYS
+                qstats.GATHER_FREE_LAUNCHES) + _SHAPE_KEYS
 
 #: the pipeline's per-query phases in the order a query passes them: the
 #: item.stats key of each and the request-Trace span `execute_partial` rebuilds
@@ -183,7 +184,7 @@ class DeviceQueryPipeline:
         self.dedupe_hits = 0
         self.stacked_launches = 0
         self.fused_launches = 0
-        self.mesh = dict.fromkeys(_MESH_KEYS, 0)
+        self.by_shape = dict.fromkeys(_SHAPE_KEYS, 0)
         self.decodes = dict.fromkeys(_DECODE_KEYS, 0)
         # how the batches form: drains that held one live query, why each
         # drain closed (`_drain`), and hand-offs that met a full fetch queue
@@ -474,8 +475,8 @@ class DeviceQueryPipeline:
                                 "fused_cols", ()) for i in idxs)
             if fused:
                 self.fused_launches += 1
-            for k in _MESH_KEYS:
-                self.mesh[k] += int(recorded.get(k, 0))
+            for k in _SHAPE_KEYS:
+                self.by_shape[k] += int(recorded.get(k, 0))
             for i in idxs:
                 for item, _ in rep_groups[i]:
                     item.stats[qstats.DEVICE_LAUNCH_MS] = round(launch.ms, 3)
@@ -609,7 +610,7 @@ class DeviceQueryPipeline:
                 "deviceErrors": self.device_errors, "timeouts": self.timeouts,
                 "launches": self.launches, "dedupeHits": self.dedupe_hits,
                 "stackedLaunches": self.stacked_launches,
-                "fusedLaunches": self.fused_launches, **self.mesh,
+                "fusedLaunches": self.fused_launches, **self.by_shape,
                 **self.decodes,
                 "batchesOfOne": self.batches_of_one,
                 "drainsClosedIdle": self.drains_closed_idle,

@@ -2,7 +2,7 @@
 that reads it.
 
 Which kernel runs for a plan is decided from what the trace can see (padded
-key count, row count, decode-table width, whether the plan can fuse) against
+key count, decode-table width, whether the plan can fuse) against
 the fields of `KernelCaps`, and these defaults are the only place the numbers
 are written. There is one supported `device_kind` (the v5e), so the table is
 a set of constants: retuning one is an edit of its default here, with the
@@ -37,8 +37,10 @@ class KernelCaps:
     # v5e 16M rows count+sum 24ms @1024..2048 keys, 30ms @4096, 39ms @20k,
     # 69ms @32k. Its cost is linear in keys (~2.1ms per 4096-key chunk per
     # bf16 part per 16M rows) while a `jax.lax.sort` of 16M keys+payload is
-    # ~67ms flat: crossover near 128k keys. Past it, and past 2^24 rows a
-    # device at any key count, the sort regime (`_grouped_partitioned`).
+    # ~67ms flat: crossover near 128k keys. Past it the sort regime
+    # (`_grouped_partitioned`), at any row count: rows choose no regime
+    # (past 2^24 a device the matmul regimes go slab by slab,
+    # `kernels._slab_sums`).
     chunk_cap: int = 131072
     # per-key broadcast-reduce min/max up to here (VPU-bound: above it the
     # broadcast does more device work than `segment_min` / `segment_max`)

@@ -23,10 +23,11 @@ matmuls —
   dot's tiles), the CHUNKED 64x64-tile matmul `_grouped_chunk64` from there to
   `chunk_cap` (high-cardinality group-by AND the grouped-distinct presence
   product space, bf16 3-part-split operands at full MXU tile utilization), and
-  past `chunk_cap`, or past 2^24 rows a device at any key count, the
-  radix-partitioned sort `_grouped_partitioned` (no n-row scatter); min/max by
-  per-key broadcast-reduce up to `minmax_bcast_cap`, `segment_min` /
-  `segment_max` above.
+  past `chunk_cap` the radix-partitioned sort `_grouped_partitioned` (no n-row
+  scatter). The row count chooses nothing: past SLAB_ROWS (2^24) rows a
+  device the two matmul regimes run slab by slab (`_slab_sums`), int32
+  counts added across the slabs; min/max by per-key broadcast-reduce up to
+  `minmax_bcast_cap`, `segment_min` / `segment_max` above.
 
 There is no 10k-doc batching loop (`DocIdSetPlanNode.MAX_DOC_PER_CALL`): the TPU analog of
 batching is the grid XLA tiles over the padded row axis. Kernels are cached by structural
@@ -378,6 +379,16 @@ def gather_free(spec: KernelSpec, vals) -> bool:
         for col, form in spec.fused_cols if form == "dict")
 
 
+def slabbed(spec: KernelSpec, rows: int) -> bool:
+    """Whether a launch of `spec` over `rows` rows a device (a static shape of
+    its inputs) runs a matmul regime of its GROUP BY over more than one slab
+    (what `slabbedLaunches` counts): past SLAB_ROWS rows, at most `chunk_cap`
+    keys. A grouped distinct's product space is the wider of the two, so it
+    is on the matmul side only where the group-by itself is."""
+    return (bool(spec.group_cols) and slab_count(rows) > 1
+            and spec.num_keys_pad + 1 <= get_caps().chunk_cap)
+
+
 def _fused_env(spec: KernelSpec, ids, vals, iscal):
     """The expression env over COMPRESSED resident forms: for every fused
     column, synthesize the decoded values at trace time — a dict column from
@@ -584,7 +595,8 @@ def _bf16_parts(v: jnp.ndarray):
     return tuple(p.astype(jnp.bfloat16) for p in (p1, p2, p3))
 
 
-def _grouped_chunk64(key: jnp.ndarray, nseg: int, exact_rows, split_rows):
+def _grouped_chunk64(key: jnp.ndarray, nseg: int, exact_rows, split_rows,
+                     real: Optional[int] = None):
     """Per-key sums over a LARGE dense key space as chunked 64x64-tile one-hot
     matmuls — the high-cardinality GROUP BY kernel (8192 < keys <= 128k).
 
@@ -601,16 +613,24 @@ def _grouped_chunk64(key: jnp.ndarray, nseg: int, exact_rows, split_rows):
 
     The hard limit of ANY one-hot formulation here is the MXU contraction
     stream, not FLOPs: a [64, N] @ [N, 64] dot walks the N-length contraction
-    regardless of its tiny output, once per 4096-key chunk and operand part.
-    Not yet timed on the directly attached chip.
+    regardless of its tiny output, once per 4096-key chunk and operand part
+    (16.8M rows, count and one sum, on the v5e: 7.8 ms for one chunk, 14.6
+    for two; PERF.md section 5, PR 31's probe).
 
-    `key` must already route masked-out rows to an overflow bucket (callers
-    pass the kernel's dense key with overflow = nseg-1). f32 accumulator
-    cells are exact to 2^24 increments; callers guard rows <= 2^24 exactly
-    like the skinny-matmul path. Returns f32[nseg] per row, exact_rows first.
+    The contract: `key` routes masked-out rows to the keys from `real` up
+    (callers pass the kernel's dense key with the one overflow key nseg-1,
+    `real` = nseg-1 by default; a grouped distinct's combined key has an
+    overflow BAND), and every row handed in is ALREADY MASKED by the caller:
+    zero wherever the key is `real` or more. So the chunks cover the real
+    keys [0, real) alone and the cells from there to nseg are filled with the
+    zeros they would sum to: nseg 8,193 is two passes over the rows, not a
+    third for the overflow cell. f32 accumulator cells are exact to 2^24
+    increments: past that many rows callers go slab by slab (`_slab_sums`).
+    Returns f32[nseg] per row, exact_rows first.
     """
     bf = jnp.bfloat16
-    n_chunks = max(1, -(-nseg // 4096))
+    real = nseg - 1 if real is None else real
+    n_chunks = max(1, -(-real // 4096))
     low = key & 4095
     oh_hi = jax.nn.one_hot(low // 64, 64, dtype=bf)
     oh_lo = jax.nn.one_hot(low % 64, 64, dtype=bf)
@@ -629,9 +649,68 @@ def _grouped_chunk64(key: jnp.ndarray, nseg: int, exact_rows, split_rows):
                 d = dot(oh_hi, (jnp.where(in_c, rp, 0))[:, None] * oh_lo)
                 s = d if s is None else s + d
             pieces[len(exact_rows) + j].append(s.reshape(-1))
-    if n_chunks == 1:
-        return [p[0][:nseg] for p in pieces]
-    return [jnp.concatenate(p)[:nseg] for p in pieces]
+    short = max(nseg - n_chunks * 4096, 0)   # cells past the chunks: zeros
+    return [jnp.pad(jnp.concatenate(p), (0, short))[:nseg] for p in pieces]
+
+
+# Rows an f32 one-hot cell counts exactly: 2^24 increments (2^24 itself IS
+# representable, so a slab of exactly 2^24 rows of one key still reads
+# right). A property of f32, not a crossover to tune: no `KernelCaps` field,
+# key, variable or argument feeds it; tests patch it as they patch
+# COMPACT_RUNGS.
+SLAB_ROWS = 1 << 24
+
+
+def slab_count(rows: int) -> int:
+    """Slabs the matmul GROUP BY regimes run over `rows` rows a device."""
+    return max(1, -(-rows // SLAB_ROWS))
+
+
+def _slab_sums(key: jnp.ndarray, nseg: int, rows, regime):
+    """[int32 counts[nseg], f32 sums[nseg]...] of a matmul GROUP BY regime at
+    ANY row count. `regime(key, rows)` is `_onehot_sums` or `_grouped_chunk64`
+    bound to its key count: f32[nseg] per row of `rows`, whose first is the
+    0/1 mask (the count row).
+
+    An f32 cell stops counting at 2^24 increments, which is a property of one
+    accumulator cell and not of the algorithm: past SLAB_ROWS rows the rows
+    go in `slab_count` slabs of equal length (the tail padded with the
+    overflow key and zero rows, as `_sort_by_key` pads), each slab's count
+    row is rounded to int32 BEFORE it is added to the running int32 counts,
+    and the slabs' f32 sum rows are added in slab order, as a mesh adds its
+    chips' partials. A `fori_loop` whose body slices slab i out of the flat
+    rows: XLA folds a batched dot plus the sum over its batch back into one
+    long contraction (`_onehot_sums`), and cannot fold a loop; the body is
+    traced and compiled once, and its temporaries are one slab's. NOT a
+    `lax.scan` over a [slabs, slab] view: it runs the same, but the v5e's
+    compiler takes 125-141 s over the 8,193-key program at 67M rows in that
+    form against 3.9 s in this one and 8.5 s for a Python loop over static
+    slices (compiled for the described chip, PR 31). One slab builds no
+    loop."""
+    as_int = lambda c: jnp.round(c).astype(jnp.int32)    # noqa: E731
+    k = slab_count(key.size)
+    if k == 1:
+        count, *sums = regime(key, rows)
+        return [as_int(count)] + sums
+    slab = -(-key.size // k)
+    pad = k * slab - key.size
+    if pad:
+        key = jnp.pad(key, (0, pad), constant_values=nseg - 1)
+        rows = [jnp.pad(r, (0, pad)) for r in rows]
+
+    def add_slab(i, total):
+        cut = lambda a: jax.lax.dynamic_slice_in_dim(    # noqa: E731
+            a, i * slab, slab)
+        count, *sums = regime(cut(key), [cut(r) for r in rows])
+        return [total[0] + as_int(count)] + [
+            t + s for t, s in zip(total[1:], sums)]
+
+    zero = [jnp.zeros((nseg,), jnp.int32)] + [
+        jnp.zeros((nseg,), jnp.float32)] * (len(rows) - 1)
+    chips = tuple(jax.typeof(key).vma)
+    if chips:   # under shard_map the totals vary by chip, as the rows do
+        zero = [jax.lax.pcast(z, chips, to="varying") for z in zero]
+    return jax.lax.fori_loop(0, k, add_slab, zero)
 
 
 def _seg_sum_op(a, b):
@@ -678,8 +757,8 @@ def compact_cap(n: int, nseg: int, block: int) -> int:
     alone: n / 64 (the widest SSB template passes n / 630), or 0 where no
     branch is built because today's per-key decode is the cheaper of the two:
     its gathers, nseg x (log2 n + log2(n / block) + 5), against `cap` updates.
-    At 67M rows the 8,193-key templates decode with 0.4M gathers against 1M
-    updates, so their programs hold one branch, as before."""
+    8,193 keys over 67M rows (a shape the ladder sends here only under other
+    caps) would decode with 0.4M gathers against 1M updates: one branch."""
     cap = n // 64
     steps = n.bit_length() + max(n // block, 1).bit_length() + 3  # the log2s + 5
     return cap if nseg * steps > cap > 0 else 0
@@ -814,7 +893,7 @@ def _decode_sorted(key_s, vals_s, nseg: int, pad: int, block: int, dense,
 def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
                          block: int = 4096, took=None):
     """Two-level radix-partitioned sort group-by — the one regime past
-    `chunk_cap` keys or 2^24 rows a device.
+    `chunk_cap` keys, at any row count.
 
     The sort IS the radix split: after `jax.lax.sort`, each `block`-row slab is
     one partition whose keys RANK-compress to a dense local id
@@ -931,18 +1010,19 @@ def _make_body(spec: KernelSpec):
         slower than a scatter at this width (keys*ids, tens of thousands),
         but the CHUNKED 64x64-tile formulation (_grouped_chunk64) runs the
         same product space at full MXU tile utilization — count-only, so one
-        bf16 part per chunk (exact: 0/1 operands, f32 accumulation,
-        2^24-increment guard shared with the sum path). Widths past
-        `chunk_cap`, and blocks that could overflow an f32 cell, take the
-        sort regime with no value rows."""
+        bf16 part per chunk (exact: 0/1 operands, f32 accumulation, int32
+        across the slabs of `_slab_sums`, as the sum path). Widths past
+        `chunk_cap` take the sort regime with no value rows."""
         size = spec.distinct_lut_sizes[ai]
         col_ids = ids[agg.arg.name].ravel()
         comb = key * size + col_ids
         width = num_seg * size
-        if width <= caps.chunk_cap and key.size <= (1 << 24):
+        if width <= caps.chunk_cap:
             fm = mask.ravel().astype(jnp.float32)
-            pres = _grouped_chunk64(comb, width, [fm], [])[0]
-            return jnp.round(pres).astype(jnp.int32).reshape(num_seg, size)
+            # the masked rows' band of keys starts where the last group ends
+            pres = _slab_sums(comb, width, [fm], lambda k, r: _grouped_chunk64(
+                k, width, r, [], real=width - size))[0]
+            return pres.reshape(num_seg, size)
         # presence counts over the combined (group, id) space past the chunk
         # cap: the sort regime with no value rows, whose sorted-run boundary
         # counts are exact int32 with no matmul and no scatter
@@ -1009,39 +1089,28 @@ def _make_body(spec: KernelSpec):
                             sum_names.append(f"{ai}.{o}")
                         elif o in ("min", "max"):
                             minmax.append((f"{ai}.{o}", v.ravel(), o == "min"))
-            # f32 one-hot counts are exact only up to 2^24 increments (2^24 itself
-            # IS representable); the row count is static at trace time, so pick the
-            # sort regime's exact int32 counts when a single group could
-            # overflow the f32 integer range (keys.size is the bound). The <=
-            # matters: a 16M-row padded block sits exactly at 2^24 and must
-            # keep the matmul path.
-            count_exact_in_f32 = key.size <= (1 << 24)
-            if num_seg <= caps.matmul_cap and count_exact_in_f32:
+            # the ladder: the padded key count against `KernelCaps` alone. The
+            # row count chooses nothing: the matmul regimes count in int32
+            # across slabs of at most SLAB_ROWS rows (`_slab_sums`).
+            # Each regime: [int32 counts[num_seg], f32 sums[num_seg]...]
+            if num_seg <= caps.matmul_cap:
                 with scope("pinot.groupby.onehot"):
-                    partials = _onehot_sums(key, num_seg, sum_rows)
-                    for r, name in enumerate(sum_names):
-                        p = partials[r]
-                        out[name] = (jnp.round(p).astype(jnp.int32)
-                                     if name == "count" else p)
-            elif num_seg <= caps.chunk_cap and count_exact_in_f32:
+                    res = _slab_sums(key, num_seg, sum_rows, lambda k, r:
+                                     _onehot_sums(k, num_seg, r))
+            elif num_seg <= caps.chunk_cap:
                 # HIGH-CARDINALITY group-by: chunked 64x64-tile matmuls (the
                 # redesigned >cap path — 6.4x the segment_sum scatter at 20k
                 # keys; see _grouped_chunk64's measurement + limit analysis)
                 with scope("pinot.groupby.chunk64"):
-                    res = _grouped_chunk64(key, num_seg, [fmask], sum_rows[1:])
-                    out["count"] = jnp.round(res[0]).astype(jnp.int32)
-                for arr, name in zip(res[1:], sum_names[1:]):
-                    out[name] = arr
+                    res = _slab_sums(key, num_seg, sum_rows, lambda k, r:
+                                     _grouped_chunk64(k, num_seg, r[:1], r[1:]))
             else:
-                # VERY-HIGH-CARDINALITY group-by (> chunk_cap, or row counts
-                # past the f32 2^24 guard at any cardinality): the sort
+                # VERY-HIGH-CARDINALITY group-by (> chunk_cap): the sort
                 # regime, with exact int32 counts and no scatter
                 with scope("pinot.groupby.partitioned"):
                     res = _grouped_partitioned(key, num_seg, sum_rows[1:],
                                                caps.partition_block, took)
-                out["count"] = res[0]
-                for arr, name in zip(res[1:], sum_names[1:]):
-                    out[name] = arr
+            out.update(zip(sum_names, res))
             for name, v, is_min in minmax:
                 with scope("pinot.groupby.minmax"):
                     if num_seg <= caps.minmax_bcast_cap:
@@ -1138,6 +1207,8 @@ def run_kernel(spec: KernelSpec, inputs: KernelInputs) -> Dict[str, np.ndarray]:
     qstats.record(qstats.FUSED_LAUNCHES)
     if gather_free(spec, inputs.vals):
         qstats.record(qstats.GATHER_FREE_LAUNCHES)
+    if slabbed(spec, inputs.valid.size):
+        qstats.record(qstats.SLABBED_LAUNCHES)
     # device_get, never np.asarray: asarray syncs leaf by leaf, device_get
     # fetches the whole tree in one batched round trip
     return _record_decode(fetch_outputs(dispatch_kernel(spec, inputs)))
@@ -1177,6 +1248,8 @@ def run_kernel_staged(spec: KernelSpec,
         mask_dev = dispatch_mask(spec, inputs)
         qstats.record(qstats.STAGED_LAUNCHES, 2)
     agg_spec = _staged_agg_spec(spec)
+    if slabbed(agg_spec, inputs.valid.size):
+        qstats.record(qstats.SLABBED_LAUNCHES)
     outs = get_kernel(agg_spec)(inputs.ids, inputs.vals, inputs.luts,
                                 inputs.iscal, inputs.fscal, inputs.nulls,
                                 mask_dev, inputs.strides, inputs.agg_luts,

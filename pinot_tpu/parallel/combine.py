@@ -38,7 +38,7 @@ import numpy as np
 
 from ..engine.datablock import lut_size, padded_rows
 from ..engine.kernels import (KernelSpec, _fence_first_call, gather_free,
-                              tree_bytes)
+                              slabbed, tree_bytes)
 from ..query import stats as qstats
 from ..query.aggregates import make_agg
 from ..query.context import QueryContext, compile_query
@@ -1386,6 +1386,9 @@ class MeshQueryExecutor:
             compiled = jitted_for(inputs)
             for key, v in built.get("mesh", {}).items():
                 qstats.record(key, v)
+            # the rows one device holds: a shape, like what `mesh` holds
+            if slabbed(spec, inputs["valid"].size // n):
+                qstats.record(qstats.SLABBED_LAUNCHES)
             return compiled(inputs)
 
         fn.jitted_for = jitted_for

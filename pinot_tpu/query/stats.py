@@ -76,6 +76,11 @@ STAGED_LAUNCHES = "stagedLaunches"
 # in them: every dict column's decode table was small enough for the select
 # tree (kernels.SELECT_DECODE_CAP), so the decode fused into the scan (PR 27)
 GATHER_FREE_LAUNCHES = "gatherFreeLaunches"
+# launches whose GROUP BY ran a matmul regime (one-hot, chunk64) over more
+# than one slab of rows: past kernels.SLAB_ROWS (2^24) rows a device an f32
+# cell cannot hold a count, so the rows go slab by slab and the counts are
+# added as int32 (PR 31). From the static shape the kernel was built with
+SLABBED_LAUNCHES = "slabbedLaunches"
 # which decode a sort-regime GROUP BY launch ran (PR 29): the dense answer from
 # the sorted prefix of rows that passed the filter (compact), or the per-key
 # binary searches (dense). The kernel decides on the device and returns the
@@ -135,7 +140,7 @@ COUNTER_KEYS = (
     COMPILE_MS, DEVICE_EXEC_MS, DEVICE_FETCH_MS, BYTES_FETCHED,
     QUEUE_WAIT_MS, DEVICE_PREPARE_MS, DEVICE_LAUNCH_MS, DEVICE_HANDOFF_MS,
     DEVICE_DECODE_MS, DEDUPED_LAUNCHES, STACKED_LAUNCHES,
-    FUSED_LAUNCHES, STAGED_LAUNCHES, GATHER_FREE_LAUNCHES,
+    FUSED_LAUNCHES, STAGED_LAUNCHES, GATHER_FREE_LAUNCHES, SLABBED_LAUNCHES,
     COMPACT_DECODE_LAUNCHES, DENSE_DECODE_LAUNCHES,
     NUM_CONSUMING_SEGMENTS_QUERIED, MUX_FRAME_QUEUE_MS, MUX_FLOW_CONTROL_MS,
     MESH_LAUNCHES, SCATTER_LAUNCHES, COLLECTIVE_BYTES,

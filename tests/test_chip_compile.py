@@ -30,9 +30,9 @@ from pinot_tpu.segment.writer import SegmentBuilder, SegmentGeneratorConfig
 
 SMOKE_SEGS = chip_smoke.DEFAULT_ROWS // chip_smoke.SEGMENT_ROWS   # 16
 SEG_ROWS = chip_smoke.SEGMENT_ROWS                                # 4Mi
-#: the largest stacked block (16Mi rows) that keeps the f32-exact one-hot
-#: matmul regimes; past it every group-by takes a sort regime
-MATMUL_SEGS = (1 << 24) // SEG_ROWS
+#: the largest stacked block (16Mi rows) the matmul regimes run as ONE slab;
+#: past it they go slab by slab inside a loop (`kernels._slab_sums`)
+MATMUL_SEGS = kernels.SLAB_ROWS // SEG_ROWS
 FIXTURE_ROWS = 1 << 20          # 500k keys stay dictionary-encoded (<= 0.7 x rows)
 SQL = dict(chip_smoke.QUERIES)
 #: inputs replicated over the mesh (everything else carries the segment axis)
@@ -153,9 +153,14 @@ AGG_CASES = [
     pytest.param("q1.1 filter+sum", SMOKE_SEGS, False, id="q1.1-fused-scan"),
     pytest.param("group-by region", MATMUL_SEGS, False, id="lowcard-onehot"),
     pytest.param("group-by 20k keys", MATMUL_SEGS, False, id="20k-chunk64"),
-    # what every GROUP BY of the one-chip smoke runs: past 2^24 rows per
-    # device the f32-exact matmul regimes are out and the sort regime takes
-    # over, whatever the key count (~45 s of compile, on the chip too)
+    # the one-chip smoke's 64Mi rows: the same two regimes over four slabs
+    # (PR 31; a few seconds of compile each, where the sort took 22-37)
+    pytest.param("group-by region", SMOKE_SEGS, False,
+                 id="lowcard-onehot-4-slabs"),
+    pytest.param("group-by 20k keys", SMOKE_SEGS, False,
+                 id="20k-chunk64-4-slabs"),
+    # past `chunk_cap` keys the sort regime, at any row count (~45 s of
+    # compile, on the chip too)
     pytest.param("group-by 500k keys", SMOKE_SEGS, True,
                  id="500k-partitioned"),
     pytest.param("bitmap-filter count", SMOKE_SEGS, False, id="lut-count"),
@@ -171,6 +176,14 @@ def test_served_agg_kernel_compiles_for_v5e(topo, cpu_exec, segments, name,
     if name == "q1.1 filter+sum":
         # the served scan decodes its dict columns in-register
         assert p.spec.fused_cols, "q1.1 no longer rides the fused decode"
+    if "group-by" in name and not sort_regime:
+        # one loop over the slabs where there is more than one; no
+        # contraction over more than a slab's rows, and no sort
+        text = compiled.as_text()
+        assert (" while(" in text) == (segs > MATMUL_SEGS)
+        assert " sort(" not in text
+        assert f"[{SMOKE_SEGS * SEG_ROWS}]" not in "".join(
+            ln for ln in text.splitlines() if " convolution(" in ln)
     if sort_regime:
         # 500k keys: the program holds both decodes of its sorted rows under
         # one conditional (PR 29), and the dense one's searches stay inside it

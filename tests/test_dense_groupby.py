@@ -781,9 +781,9 @@ def test_no_flat_scatter_at_high_card(tmp_path_factory):
 
 # --- one place chooses the kernel: the ladder at the shipped constants -------
 
-def _lowered_scan(aggs_sql, num_keys_pad, rows, distinct_size=None):
-    """The scan's lowered text (scope names included) for a GROUP BY k over
-    abstract shapes: nothing is staged, compiled or run."""
+def _abstract_scan(aggs_sql, num_keys_pad, rows, distinct_size=None):
+    """(the scan body of a GROUP BY k, its abstract arguments): nothing is
+    staged, compiled or run."""
     import jax
     import jax.numpy as jnp
 
@@ -803,10 +803,17 @@ def _lowered_scan(aggs_sql, num_keys_pad, rows, distinct_size=None):
          if "distinct" in a.device_outputs}, rows)
     shape = jax.ShapeDtypeStruct
     i32, f32 = shape((rows,), jnp.int32), shape((rows,), jnp.float32)
-    return jax.jit(kernels.make_kernel_body(spec)).lower(
+    return kernels.make_kernel_body(spec), (
         {"k": i32, "d": i32}, {"v": f32}, (), shape((0,), jnp.int32),
         shape((0,), jnp.float32), {}, shape((rows,), jnp.bool_),
-        shape((1,), jnp.int32), {}, ()).as_text(debug_info=True)
+        shape((1,), jnp.int32), {}, ())
+
+
+def _lowered_scan(aggs_sql, num_keys_pad, rows, distinct_size=None):
+    """The scan's lowered text (scope names included)."""
+    import jax
+    body, args = _abstract_scan(aggs_sql, num_keys_pad, rows, distinct_size)
+    return jax.jit(body).lower(*args).as_text(debug_info=True)
 
 
 _SUMS, _MINMAX, _DISTINCT = "COUNT(*), SUM(v)", "MIN(v)", "DISTINCTCOUNT(d)"
@@ -824,15 +831,22 @@ LADDER = [
                  ("onehot", "partitioned"), id="keys-at-chunk_cap"),
     pytest.param(_SUMS, 131_072, 16_384, None, "pinot.groupby.partitioned",
                  ("onehot", "chunk64"), id="keys-past-chunk_cap"),
-    # 2^24 rows keep the f32-exact matmul regimes; one block more is the sort
+    # the row count chooses no regime: one block past 2^24 rows, and at the
+    # full cell's 2^26, the matmul regimes stay (slab by slab)
     pytest.param(_SUMS, 256, _2_24, None, "pinot.groupby.onehot",
                  ("chunk64", "partitioned"), id="onehot-at-2^24-rows"),
-    pytest.param(_SUMS, 256, _2_24 + 4096, None, "pinot.groupby.partitioned",
-                 ("onehot", "chunk64"), id="onehot-past-2^24-rows"),
+    pytest.param(_SUMS, 256, _2_24 + 4096, None, "pinot.groupby.onehot",
+                 ("chunk64", "partitioned"), id="onehot-past-2^24-rows"),
+    pytest.param(_SUMS, 256, 1 << 26, None, "pinot.groupby.onehot",
+                 ("chunk64", "partitioned"), id="onehot-at-2^26-rows"),
     pytest.param(_SUMS, 8192, _2_24, None, "pinot.groupby.chunk64",
                  ("onehot", "partitioned"), id="chunk64-at-2^24-rows"),
-    pytest.param(_SUMS, 8192, _2_24 + 4096, None, "pinot.groupby.partitioned",
-                 ("onehot", "chunk64"), id="chunk64-past-2^24-rows"),
+    pytest.param(_SUMS, 8192, _2_24 + 4096, None, "pinot.groupby.chunk64",
+                 ("onehot", "partitioned"), id="chunk64-past-2^24-rows"),
+    pytest.param(_SUMS, 8192, 1 << 26, None, "pinot.groupby.chunk64",
+                 ("onehot", "partitioned"), id="chunk64-at-2^26-rows"),
+    pytest.param(_SUMS, 131_072, 1 << 26, None, "pinot.groupby.partitioned",
+                 ("onehot", "chunk64"), id="keys-past-chunk_cap-at-2^26-rows"),
     # min/max: the broadcast-reduce up to minmax_bcast_cap = 1,024 keys + 1
     pytest.param(_MINMAX, 1023, 16_384, None,
                  "pinot.groupby.minmax/reduce_min", ("minmax/scatter-min",),
@@ -847,12 +861,12 @@ LADDER = [
                  "pinot.distinct/pinot.groupby.partitioned.sort",
                  ("pinot.distinct/dot_general",),
                  id="distinct-product-past-chunk_cap"),
-    # ... and its own 2^24-row guard (an f32 presence cell could overflow)
+    # ... at any row count (an f32 presence cell counts one slab's rows)
     pytest.param(_DISTINCT, 15, _2_24, 64, "pinot.distinct/dot_general",
                  ("partitioned",), id="distinct-at-2^24-rows"),
     pytest.param(_DISTINCT, 15, _2_24 + 4096, 64,
-                 "pinot.distinct/pinot.groupby.partitioned.sort",
-                 ("pinot.distinct/dot_general",),
+                 "pinot.distinct/while/body/closed_call",
+                 ("partitioned", "pinot.distinct/dot_general"),
                  id="distinct-past-2^24-rows"),
 ]
 
@@ -868,3 +882,295 @@ def test_regime_ladder_boundaries(aggs, keys, rows, ids, holds, not_these):
     for scope in not_these:
         assert (scope if "/" in scope else f"pinot.groupby.{scope}") \
             not in text, scope
+
+
+# --- past 2^24 rows a device: the matmul regimes slab by slab (PR 31) --------
+
+def _eqns(jaxpr, *names):
+    """Every equation of these primitives, nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in names:
+            yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub, *names)
+
+
+def _contracted(eqn):
+    """The lengths a dot_general contracts over (its lhs dimensions)."""
+    (lhs_c, _), _ = eqn.params["dimension_numbers"]
+    return [eqn.invars[0].aval.shape[d] for d in lhs_c]
+
+
+@pytest.mark.parametrize("aggs,keys,ids,carries,dots", [
+    # one-hot: three bf16 parts, the rows stacked
+    (_SUMS, 256, None, [[("int32", 257), ("float32", 257)]], 3),
+    # two chunks x (the count's one contraction + the sum's three parts)
+    (_SUMS, 8192, None, [[("int32", 8193), ("float32", 8193)]], 8),
+    # 16 x 64 presence cells: one chunk, count only; then the group-by's own
+    # count (one-hot, 16 cells)
+    (_DISTINCT, 15, 64, [[("int32", 1024)], [("int32", 16)]], 1 + 3),
+], ids=["onehot-257", "chunk64-8193", "distinct"])
+def test_at_2_26_rows_the_matmul_regimes_contract_one_slab_at_a_time(
+        aggs, keys, ids, carries, dots):
+    """The full cell's shape, 2^26 rows a device: a loop of four steps whose
+    body holds every contraction of the regime, none of them over more than
+    2^24 rows (an f32 cell's exact range); the count leaves each slab as an
+    integer (rounded and converted inside the body, added to an int32 carry)
+    and the sums are carried in f32. No sort anywhere."""
+    import jax
+    body, args = _abstract_scan(aggs, keys, 1 << 26, ids)
+    jaxpr = jax.make_jaxpr(body)(*args).jaxpr
+    loops = list(_eqns(jaxpr, "scan", "while"))
+    assert [e.primitive.name for e in loops] == ["scan"] * len(carries)
+    in_loops = 0
+    for loop, carried in zip(loops, carries):
+        assert loop.params["length"] == 4
+        inner = loop.params["jaxpr"].jaxpr
+        in_loops += len(list(_eqns(inner, "dot_general")))
+        avals = [v.aval for v in loop.outvars[:loop.params["num_carry"]]]
+        assert [(a.dtype.name, a.shape[0]) for a in avals if a.shape] \
+            == carried
+        # the count: round -> int32 -> added to the int32 carry, in the body
+        assert list(_eqns(inner, "round"))
+        assert any((e.outvars[0].aval.dtype.name, e.outvars[0].aval.shape)
+                   == ("int32", (carried[0][1],))
+                   for e in _eqns(inner, "add"))
+    all_dots = list(_eqns(jaxpr, "dot_general"))
+    assert len(all_dots) == in_loops == dots    # traced once, not four times
+    for eqn in all_dots:
+        assert _contracted(eqn) == [1 << 24], eqn
+    assert not list(_eqns(jaxpr, "sort"))
+
+
+@pytest.mark.parametrize("aggs,keys,ids,rows", [
+    (_SUMS, 256, None, _2_24), (_SUMS, 8192, None, _2_24),
+    (_DISTINCT, 15, 64, _2_24), (_SUMS, 8192, None, 16_384),
+], ids=["onehot", "chunk64", "distinct", "chunk64-16k-rows"])
+def test_at_most_2_24_rows_build_no_loop(aggs, keys, ids, rows):
+    """One slab is the program as it was: no loop, one contraction a pass."""
+    import jax
+    body, args = _abstract_scan(aggs, keys, rows, ids)
+    jaxpr = jax.make_jaxpr(body)(*args).jaxpr
+    assert not list(_eqns(jaxpr, "scan", "while"))
+    dots = list(_eqns(jaxpr, "dot_general"))
+    assert dots and all(_contracted(e) == [rows] for e in dots)
+
+
+@pytest.mark.parametrize("keys,passes", [
+    (4095, 1), (4096, 1), (4097, 2), (8192, 2), (8193, 3), (12_288, 3)])
+def test_chunk64_passes_cover_the_real_keys_alone(keys, passes):
+    """`keys` padded keys + the overflow cell: one pass over the rows per 4,096
+    REAL keys (count + three bf16 parts = four contractions a pass). 8,192
+    keys are two passes, not a third whose only key is the overflow cell."""
+    import jax
+    body, args = _abstract_scan(_SUMS, keys, 16_384)
+    jaxpr = jax.make_jaxpr(body)(*args).jaxpr
+    assert len(list(_eqns(jaxpr, "dot_general"))) == 4 * passes
+
+
+@pytest.mark.parametrize("nseg", [257, 4097, 8193, 8200, 12_289])
+def test_chunk64_overflow_cell_is_the_zero_it_would_sum_to(nseg):
+    """Masked rows carry the overflow key and, by the contract, zeros: the
+    cells no chunk covers read 0, as the pass that summed them did."""
+    import jax.numpy as jnp
+    from pinot_tpu.engine.kernels import _grouped_chunk64
+    rng = np.random.default_rng(nseg)
+    n = 6000
+    key = rng.integers(0, nseg - 1, n).astype(np.int32)
+    key[rng.random(n) < 0.5] = nseg - 1             # masked out
+    fm = (key < nseg - 1).astype(np.float32)
+    v = np.round(rng.uniform(1, 60_000, n), 2).astype(np.float32) * fm
+    count, total = _grouped_chunk64(jnp.asarray(key), nseg,
+                                    [jnp.asarray(fm)], [jnp.asarray(v)])
+    assert count.shape == total.shape == (nseg,)
+    assert float(count[-1]) == 0.0 and float(total[-1]) == 0.0
+    live = key < nseg - 1
+    np.testing.assert_array_equal(
+        np.asarray(count), np.bincount(key[live], minlength=nseg))
+    np.testing.assert_allclose(
+        np.asarray(total, np.float64),
+        np.bincount(key[live], weights=v[live].astype(np.float64),
+                    minlength=nseg), rtol=1e-6)
+
+
+def _patch_slab_rows(monkeypatch, rows):
+    """Programs built from here on slab at `rows` (a module constant that no
+    cache key holds: the caches are emptied, and restored with it)."""
+    from pinot_tpu.engine import kernels
+    from pinot_tpu.parallel import combine
+    monkeypatch.setattr(kernels, "SLAB_ROWS", rows)
+    monkeypatch.setattr(kernels, "_KERNEL_CACHE", {})
+    monkeypatch.setattr(combine, "_SHARD_KERNEL_CACHE", {})
+
+
+# rows, slab: one slab; two and five that divide the rows; two, three and
+# five that do not (the tail is padded with the overflow key)
+SLABS = [(4096, 4096), (8192, 4096), (10_240, 2048), (5000, 4096),
+         (8193, 4096), (9999, 2048)]
+
+
+@pytest.mark.parametrize("masked", [0.0, 0.97], ids=["all-pass", "few-pass"])
+@pytest.mark.parametrize("n,slab", SLABS)
+@pytest.mark.parametrize("regime", ["onehot", "chunk64", "distinct"])
+def test_slab_sums_match_numpy(monkeypatch, regime, n, slab, masked):
+    """The slab helper over each matmul regime: int32 counts equal to numpy's,
+    sums within 1e-6 relative, whatever the slab count and the tail."""
+    import jax
+    from pinot_tpu.engine import kernels
+    monkeypatch.setattr(kernels, "SLAB_ROWS", slab)
+    assert kernels.slab_count(n) == -(-n // slab)
+    nseg, real, fn = {
+        "onehot": (257, 256, lambda k, r: kernels._onehot_sums(k, 257, r)),
+        "chunk64": (8193, 8192, lambda k, r: kernels._grouped_chunk64(
+            k, 8193, r[:1], r[1:])),
+        # 33 groups x 64 ids: the masked rows' band is the last 64 keys
+        "distinct": (2112, 2048, lambda k, r: kernels._grouped_chunk64(
+            k, 2112, r, [], real=2048)),
+    }[regime]
+    rng = np.random.default_rng(n + slab)
+    key = rng.integers(0, real, n).astype(np.int32)
+    out = rng.random(n) < masked
+    key[out] = rng.integers(real, nseg, int(out.sum()))
+    fm = (key < real).astype(np.float32)
+    rows = [fm] if regime == "distinct" else [
+        fm, np.round(rng.uniform(1, 60_000, n), 2).astype(np.float32) * fm,
+        rng.integers(1, 100, n).astype(np.float32) * fm]
+    got = jax.jit(lambda k, r: kernels._slab_sums(k, nseg, r, fn))(key, rows)
+    assert len(got) == len(rows)
+    assert got[0].dtype == np.int32 and got[0].shape == (nseg,)
+    np.testing.assert_array_equal(
+        np.asarray(got[0]), np.bincount(key, weights=fm, minlength=nseg))
+    for g, r in zip(got[1:], rows[1:]):
+        assert g.dtype == np.float32
+        np.testing.assert_allclose(
+            np.asarray(g, np.float64),
+            np.bincount(key, weights=r.astype(np.float64), minlength=nseg),
+            rtol=1e-6)
+
+
+SLAB_TABLE_ROWS = 12_000      # padded to 16,384 rows a device
+
+
+@pytest.fixture(scope="module")
+def slab_table(tmp_path_factory):
+    rng = np.random.default_rng(31)
+    rows = SLAB_TABLE_ROWS
+    schema = Schema("sl", [dimension("g", DataType.INT),
+                           dimension("k", DataType.INT),
+                           dimension("d", DataType.INT),
+                           metric("pos", DataType.INT),
+                           metric("v", DataType.DOUBLE)])
+    cols = {"g": rng.integers(0, 200, rows).astype(np.int32),
+            "k": rng.integers(0, 6000, rows).astype(np.int32),
+            "d": rng.integers(0, 40, rows).astype(np.int32),
+            "pos": np.arange(rows, dtype=np.int32),
+            "v": np.round(rng.uniform(1.0, 60_000.0, rows), 2)}
+    cfg = SegmentGeneratorConfig(no_dictionary_columns=["pos", "v"])
+    seg = load_segment(build_aligned_segments(
+        schema, cols, str(tmp_path_factory.mktemp("sl")), "sl", 1,
+        config=cfg)[0])
+    return seg, cols
+
+
+def _slab_answer(seg, sql):
+    from pinot_tpu.query import stats as qstats
+    with qstats.collect_stats() as st:
+        rows = MeshQueryExecutor(default_mesh(1)).execute([seg], sql).rows
+    return rows, int(st.counters.get(qstats.SLABBED_LAUNCHES, 0))
+
+
+@pytest.mark.parametrize("passing", [SLAB_TABLE_ROWS, 300],
+                         ids=["all-pass", "few-pass"])
+@pytest.mark.parametrize("by,agg", [("g", "SUM(v)"), ("k", "SUM(v)"),
+                                    ("g", "DISTINCTCOUNT(d)")],
+                         ids=["onehot", "chunk64", "distinct"])
+def test_slabbed_programs_answer_as_the_one_slab_program(
+        slab_table, monkeypatch, by, agg, passing):
+    """Through the served mesh executor with SLAB_ROWS patched small: 16,384
+    padded rows in one slab (the program as it ships), two, and five whose
+    tail is padded. Groups and counts equal to numpy's and to the one-slab
+    program's, sums within 1e-6 relative of both; `slabbedLaunches` counts
+    the launches of more than one slab and no other."""
+    seg, cols = slab_table
+    sql = (f"SELECT {by}, COUNT(*), {agg} FROM sl WHERE pos < {passing} "
+           f"GROUP BY {by} ORDER BY {by} LIMIT 100000")
+    live = cols["pos"] < passing
+    keys = np.unique(cols[by][live])
+    count = np.bincount(cols[by][live])[keys]
+    if agg == "SUM(v)":
+        want = np.bincount(cols[by][live], weights=cols["v"][live])[keys]
+    else:
+        want = np.array([len(np.unique(cols["d"][live & (cols[by] == k)]))
+                         for k in keys])
+    answers = {}
+    for slab, slabs in ((16_384, 1), (8192, 2), (3300, 5)):
+        _patch_slab_rows(monkeypatch, slab)
+        rows, slabbed = _slab_answer(seg, sql)
+        assert slabbed == int(slabs > 1), (slab, slabbed)
+        assert [r[0] for r in rows] == keys.tolist(), slab
+        assert [r[1] for r in rows] == count.tolist(), slab
+        np.testing.assert_allclose([r[2] for r in rows], want, rtol=1e-6,
+                                   err_msg=str(slab))
+        answers[slabs] = rows
+    for slabs in (2, 5):
+        assert [r[:2] for r in answers[slabs]] == [r[:2] for r in answers[1]]
+        np.testing.assert_allclose([r[2] for r in answers[slabs]],
+                                   [r[2] for r in answers[1]], rtol=1e-6)
+
+
+@pytest.mark.parametrize("slab,slabbed", [(16_384, 0), (4096, 1)])
+def test_direct_executor_counts_its_slabbed_launch(slab_table, monkeypatch,
+                                                   slab, slabbed):
+    """`run_kernel` (the per-segment executor's launch) records the counter
+    from the same rule, and answers as the host does."""
+    from pinot_tpu.query import stats as qstats
+    seg, _ = slab_table
+    sql = "SELECT k, COUNT(*), SUM(v) FROM sl GROUP BY k ORDER BY k LIMIT 9999"
+    _patch_slab_rows(monkeypatch, slab)
+    with qstats.collect_stats() as st:
+        got = ServerQueryExecutor().execute([seg], sql).rows
+    assert int(st.counters.get(qstats.SLABBED_LAUNCHES, 0)) == slabbed
+    want = ServerQueryExecutor(use_device=False).execute([seg], sql).rows
+    assert [r[:2] for r in got] == [r[:2] for r in want]
+    np.testing.assert_allclose([r[2] for r in got], [r[2] for r in want],
+                               rtol=1e-6)
+
+
+def test_a_stacked_burst_runs_its_slabs_inside_the_batch_scan(slab_table,
+                                                              monkeypatch):
+    """Three same-shape GROUP BYs that differ in a literal ride ONE stacked
+    `_b4` launch: the slab loop inside the batch's `lax.scan` inside
+    `shard_map`. One launch, one `slabbedLaunches`, each answer the host's."""
+    from pinot_tpu.query import stats as qstats
+    from pinot_tpu.query.aggregates import make_agg
+    from pinot_tpu.query.context import compile_query
+    from pinot_tpu.query.reduce import (merge_segment_results,
+                                        reduce_to_result)
+    seg, _ = slab_table
+    sqls = [f"SELECT k, COUNT(*), SUM(v) FROM sl WHERE pos < {t} GROUP BY k "
+            "ORDER BY k LIMIT 100000" for t in (300, 7000, 11_000)]
+    _patch_slab_rows(monkeypatch, 3300)         # five slabs, a padded tail
+    mex = MeshQueryExecutor(default_mesh(1))
+    ctxs = [compile_query(sql, seg.schema) for sql in sqls]
+    preps = [mex.prepare_partial(ctx, [seg]) for ctx in ctxs]
+    with qstats.collect_stats() as st:
+        launches = mex.dispatch_prepared(preps)
+        assert len(launches) == 1, "same-signature burst must stack"
+        outs_dev, finish, idxs, _ = launches[0]
+        outs_list = finish(mex.fetch([outs_dev])[0])
+    assert int(st.counters.get(qstats.DEVICE_LAUNCHES, 0)) == 1
+    assert int(st.counters.get(qstats.SLABBED_LAUNCHES, 0)) == 1
+    host = ServerQueryExecutor(use_device=False)
+    for pos, i in enumerate(idxs):
+        aggs = [make_agg(f) for f in ctxs[i].aggregations]
+        got = reduce_to_result(
+            ctxs[i], merge_segment_results(
+                [preps[i].decode(outs_list[pos])], aggs), aggs,
+            list(ctxs[i].group_by)).rows
+        want = host.execute([seg], sqls[i]).rows
+        assert [r[:2] for r in got] == [r[:2] for r in want], sqls[i]
+        np.testing.assert_allclose([r[2] for r in got],
+                                   [r[2] for r in want], rtol=1e-6)

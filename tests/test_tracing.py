@@ -704,9 +704,9 @@ def test_sort_regime_program_holds_both_decodes(scope_segment):
 
 def test_small_key_program_past_2_24_rows_holds_no_decode_branch():
     """Where today's decode costs less than a `cap`-row pass no branch is
-    built: the full-size cell's 8,193-key templates (32Mi and 64Mi rows, where
-    every GROUP BY takes the sort regime) keep their one-branch programs, the
-    wide-key ones get n / 64 rows."""
+    built: a sort over 32Mi or 64Mi rows for 8,193 keys (a shape the ladder no
+    longer sends here: since PR 31 those take the chunked matmul slab by slab)
+    holds one branch, the full-size cell's wide-key ones get n / 64 rows."""
     import jax
     import jax.numpy as jnp
     from pinot_tpu.engine import kernels
@@ -787,3 +787,68 @@ def test_decode_counters_reach_response_explain_and_health(tmp_path):
     assert (health["compactDecodeLaunches"], health["denseDecodeLaunches"]) \
         == (2, 1)
     assert health["deviceErrors"] == 0 and health["fallbacks"] == 0
+
+
+# -- PR 31: the matmul GROUP BY regimes slab by slab, and their counter -------
+
+def test_slabbed_launches_reach_response_explain_and_health(tmp_path,
+                                                            monkeypatch):
+    """Through the device pipeline of a served cluster whose mesh is four
+    devices, two segments each (2 x 4,096 padded rows a device), with the slab
+    patched to 4,096 rows: a GROUP BY of 200 keys (one-hot) and one of 2,000
+    (chunk64) answer with `slabbedLaunches` 1 and the rows the host computes;
+    a scalar aggregation, which has no GROUP BY, with 0. EXPLAIN ANALYZE
+    carries the field and `/health`'s device block sums the launches."""
+    from tests.test_dense_groupby import _patch_slab_rows
+    from pinot_tpu.cluster.device_server import DeviceQueryPipeline
+    from pinot_tpu.parallel import MeshQueryExecutor, default_mesh
+    from pinot_tpu.table import IndexingConfig
+    rng = np.random.default_rng(31)
+    segs, per = 8, 4000
+    schema = Schema("sl", [dimension("g", DataType.INT),
+                           dimension("k", DataType.INT),
+                           metric("w", DataType.INT)])
+    cols = {"g": rng.integers(0, 200, segs * per).astype(np.int32),
+            "k": rng.integers(0, 2000, segs * per).astype(np.int32),
+            "w": rng.integers(1, 1000, segs * per).astype(np.int32)}
+    for s in range(segs):       # every key in every segment: aligned
+        cols["g"][s * per:s * per + 200] = np.arange(200)
+        cols["k"][s * per + 200:s * per + 2200] = np.arange(2000)
+    cluster = QuickCluster(num_servers=1, work_dir=str(tmp_path))
+    cluster.servers[0].device_pipeline = pipeline = DeviceQueryPipeline(
+        mesh_exec=MeshQueryExecutor(default_mesh(4)))
+    cfg = TableConfig("sl", indexing=IndexingConfig(
+        no_dictionary_columns=["w"]))
+    cluster.create_table(schema, cfg)
+    for s in range(segs):
+        cluster.ingest_columns(cfg, {c: v[s * per:(s + 1) * per]
+                                     for c, v in cols.items()})
+    by = "SELECT {0}, COUNT(*), SUM(w) FROM sl WHERE w > 500 GROUP BY {0} " \
+         "ORDER BY {0} LIMIT 10000"
+    sqls = {"onehot": by.format("g"), "chunk64": by.format("k"),
+            "scalar": "SELECT COUNT(*), SUM(w) FROM sl WHERE w > 500",
+            "explain": "EXPLAIN ANALYZE " + by.format("g")}
+    _patch_slab_rows(monkeypatch, 4096)
+    try:
+        got = {name: cluster.query(sql) for name, sql in sqls.items()}
+        health = pipeline.stats()
+    finally:
+        pipeline.stop()
+    for name, slabbed in (("onehot", 1), ("chunk64", 1), ("scalar", 0),
+                          ("explain", 1)):
+        s = got[name].stats
+        assert s["deviceLaunches"] >= 1 and s["meshLaunches"] >= 1, (name, s)
+        assert s["slabbedLaunches"] == slabbed, (name, s)
+    assert got["explain"].stats["analyze"] is True
+    assert health["slabbedLaunches"] == 3
+    assert health["deviceErrors"] == 0 and health["fallbacks"] == 0
+    live = cols["w"] > 500
+    for name, col in (("onehot", "g"), ("chunk64", "k")):
+        keys = np.unique(cols[col][live])
+        assert [r[0] for r in got[name].rows] == keys.tolist()
+        assert [r[1] for r in got[name].rows] == \
+            np.bincount(cols[col][live])[keys].tolist()
+        np.testing.assert_allclose(
+            [r[2] for r in got[name].rows],
+            np.bincount(cols[col][live], weights=cols["w"][live])[keys],
+            rtol=1e-6)
