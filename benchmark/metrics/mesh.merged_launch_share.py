@@ -1,0 +1,10 @@
+"""mesh.merged_launch_share: what it reads is in the `.json` beside it. None
+where the program has no such counter (PR 32's parent) or nothing was
+launched."""
+
+
+def read(ctx):
+    c = ctx["counters"]
+    if "mergedLaunches" not in c or not c.get("launches"):
+        return None
+    return 100.0 * c["mergedLaunches"] / c["launches"]

@@ -104,6 +104,20 @@ MUX_FLOW_CONTROL_MS = "muxFlowControlMs"
 MESH_LAUNCHES = "meshLaunches"
 SCATTER_LAUNCHES = "scatterLaunches"
 COLLECTIVE_BYTES = "collectiveBytes"
+# what a launch over a server's RESIDENT segment set read (PR 32), per launch,
+# from what the launch was built with: the segments the query was routed to,
+# the segments the stacked block holds, the slots the program read (the slot
+# window's static length; the resident count where every slot is read and the
+# routed ones are a mask), and whether the plan is in a merged id space
+# (per-segment dictionaries differ: parallel/merged.py)
+ROUTED_SLOTS = "routedSlots"
+RESIDENT_SLOTS = "residentSlots"
+SCANNED_SLOTS = "scannedSlots"
+MERGED_LAUNCHES = "mergedLaunches"
+# segment-set blocks built, and the bytes `device_put` into them (stacked
+# columns, decode tables, slot masks): 0 for a query over a warm block
+SET_BLOCKS_STAGED = "setBlocksStaged"
+SET_BLOCK_BYTES = "setBlockBytes"
 DEVICE_SKEW_PCT = "deviceSkewPct"
 HEDGED_REQUESTS = "hedgedRequests"
 ADMISSION_DEFER_MS = "admissionDeferMs"
@@ -144,6 +158,8 @@ COUNTER_KEYS = (
     COMPACT_DECODE_LAUNCHES, DENSE_DECODE_LAUNCHES,
     NUM_CONSUMING_SEGMENTS_QUERIED, MUX_FRAME_QUEUE_MS, MUX_FLOW_CONTROL_MS,
     MESH_LAUNCHES, SCATTER_LAUNCHES, COLLECTIVE_BYTES,
+    ROUTED_SLOTS, RESIDENT_SLOTS, SCANNED_SLOTS, MERGED_LAUNCHES,
+    SET_BLOCKS_STAGED, SET_BLOCK_BYTES,
     HEDGED_REQUESTS, ADMISSION_DEFER_MS,
     SEGMENTS_SERVED_HOST_TIER, TIER_PROMOTIONS,
     SEGMENTS_COLD_LOADED, COLD_LOAD_MS,
