@@ -67,9 +67,16 @@ _STAGES = ("queue_wait", "dispatch", "prepare", "launch", "handoff", "fetch",
 
 #: what a launch records from the static shapes its program was built with,
 #: also summed over the launches in `stats()`: on more than one device, what
-#: crosses the chips; past 2^24 rows a device, a matmul GROUP BY slab by slab
+#: crosses the chips; past 2^24 rows a device, a matmul GROUP BY slab by slab;
+#: over a resident set, the slots routed, held and read, and the id space
 _SHAPE_KEYS = (qstats.MESH_LAUNCHES, qstats.SCATTER_LAUNCHES,
-               qstats.COLLECTIVE_BYTES, qstats.SLABBED_LAUNCHES)
+               qstats.COLLECTIVE_BYTES, qstats.SLABBED_LAUNCHES,
+               qstats.ROUTED_SLOTS, qstats.RESIDENT_SLOTS,
+               qstats.SCANNED_SLOTS, qstats.MERGED_LAUNCHES)
+
+#: what a prepare that had to stage records: set blocks built and the bytes
+#: put into them, folded into the query that staged and summed in `stats()`
+_STAGE_KEYS = (qstats.SET_BLOCKS_STAGED, qstats.SET_BLOCK_BYTES)
 
 #: which decode branch a sort-regime GROUP BY launch ran: known once its
 #: outputs are fetched (`qstats.decode_branch`), summed over the launches in
@@ -81,7 +88,7 @@ _DECODE_KEYS = (qstats.COMPACT_DECODE_LAUNCHES, qstats.DENSE_DECODE_LAUNCHES)
 #: into the items a launch answers
 _LAUNCH_KEYS = (qstats.COMPILE_MS, qstats.COMPILE_CACHE_MISSES,
                 qstats.COMPILE_CACHE_HITS, qstats.DEVICE_LAUNCHES,
-                qstats.GATHER_FREE_LAUNCHES) + _SHAPE_KEYS
+                qstats.GATHER_FREE_LAUNCHES) + _SHAPE_KEYS + _STAGE_KEYS
 
 #: the pipeline's per-query phases in the order a query passes them: the
 #: item.stats key of each and the request-Trace span `execute_partial` rebuilds
@@ -122,12 +129,13 @@ def _items(launches):
 
 
 class _Item:
-    __slots__ = ("ctx", "segments", "future", "t_enqueue", "stats",
-                 "trace_id")
+    __slots__ = ("ctx", "segments", "resident", "future", "t_enqueue",
+                 "stats", "trace_id")
 
-    def __init__(self, ctx, segments, trace_id: str = ""):
+    def __init__(self, ctx, segments, trace_id: str = "", resident=None):
         self.ctx = ctx
         self.segments = segments
+        self.resident = resident
         self.trace_id = trace_id
         self.future: Future = Future()
         self.t_enqueue = time.perf_counter()
@@ -184,7 +192,7 @@ class DeviceQueryPipeline:
         self.dedupe_hits = 0
         self.stacked_launches = 0
         self.fused_launches = 0
-        self.by_shape = dict.fromkeys(_SHAPE_KEYS, 0)
+        self.by_shape = dict.fromkeys(_SHAPE_KEYS + _STAGE_KEYS, 0)
         self.decodes = dict.fromkeys(_DECODE_KEYS, 0)
         # how the batches form: drains that held one live query, why each
         # drain closed (`_drain`), and hand-offs that met a full fetch queue
@@ -225,12 +233,16 @@ class DeviceQueryPipeline:
         self._hists[stage_name].observe(ms)
 
     # -- caller side ------------------------------------------------------
-    def execute_partial(self, ctx, segments: Sequence):
-        """Submit and wait; returns a SegmentResult partial or DEVICE_FALLBACK."""
+    def execute_partial(self, ctx, segments: Sequence, resident=None):
+        """Submit and wait; returns a SegmentResult partial or DEVICE_FALLBACK.
+        `segments` are the members the query was routed to; `resident`, where
+        the caller knows it, the set the server holds of the table: what the
+        executor stages and plans (`MeshQueryExecutor.prepare_partial`)."""
         from ..utils.trace import current_depth, current_trace
         tr = current_trace()
         item = _Item(ctx, list(segments),
-                     trace_id=tr.trace_id if tr is not None else "")
+                     trace_id=tr.trace_id if tr is not None else "",
+                     resident=resident)
         submit_ms = tr.now_ms() if tr is not None else 0.0
         # deadline propagation: never wait on the device past the broker's
         # stamped deadline — timing out here cancels the item, and the
@@ -421,8 +433,9 @@ class DeviceQueryPipeline:
                 with qstats.activate(scratch), \
                         stage("pipeline.prepare",
                               trace_id=item.trace_id) as prep:
-                    p = self.mesh_exec.prepare_partial(item.ctx,
-                                                       item.segments)
+                    p = self.mesh_exec.prepare_partial(
+                        item.ctx, item.segments,
+                        *(() if item.resident is None else (item.resident,)))
             except Exception:
                 # planning RAISED on the device path: the host path still
                 # answers the query, but as a counted, logged device error —
@@ -433,6 +446,8 @@ class DeviceQueryPipeline:
             self._observe("prepare", prep.ms)
             item.stats[qstats.DEVICE_PREPARE_MS] = round(prep.ms, 3)
             _fold(item.stats, scratch.counters)
+            for k in _STAGE_KEYS:
+                self.by_shape[k] += int(scratch.counters.get(k, 0))
             if p is None:
                 self.fallbacks += 1
                 _resolve(item.future, DEVICE_FALLBACK)

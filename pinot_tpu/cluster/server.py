@@ -12,6 +12,7 @@ external view.
 from __future__ import annotations
 
 import os
+import re
 import threading
 from typing import Dict, List, Optional, Sequence, Union
 
@@ -28,6 +29,10 @@ from .catalog import (COLD, CONSUMING, DROPPED, OFFLINE, ONLINE, Catalog,
                       InstanceInfo)
 from .deepstore import DeepStoreFS, untar_segment
 from .tiering import PRESSURE_INTERVAL_S, TieringManager
+
+
+def _push_order(name: str) -> list:
+    return [int(t) if t.isdigit() else t for t in re.split(r"(\d+)", name)]
 
 
 class TableDataManager:
@@ -99,6 +104,15 @@ class TableDataManager:
             from ..engine.datablock import release_block
             for seg in doomed:
                 release_block(seg)
+
+    def resident(self) -> List[ImmutableSegment]:
+        """Every loaded segment in push order (names compared with their
+        digit runs as numbers: `t_2` before `t_10`), whatever order they
+        were loaded in: the set the device pipeline stages and plans, in
+        which time-pruned subsets are contiguous."""
+        with self._lock:
+            return [self._segments[n]
+                    for n in sorted(self._segments, key=_push_order)]
 
     def refcount(self, name: str) -> int:
         """In-flight acquisitions of `name` — the tiering eviction loop's
@@ -704,6 +718,7 @@ class ServerNode:
         upsert = getattr(handler, "upsert", None) if handler else None
         segments = mgr.acquire(segment_names)
         admitted: List[ImmutableSegment] = []
+        settle: List[str] = []
         try:
             # cold tier: requested segments assigned COLD to this server with
             # no local copy lazily download NOW, bounded by the propagated
@@ -771,10 +786,24 @@ class ServerNode:
                 # valid masks always take the host path — per-doc visibility
                 # is host state)
                 from .device_server import DEVICE_FALLBACK
+                # what is staged and planned is the table's RESIDENT set:
+                # every immutable segment this server holds of it that the
+                # admission gate lets onto the device, in push order. The
+                # admitted members of the routed set are an input of the
+                # launch, so a pruned query builds no block of its own
+                routed = {seg.name for seg in admitted}
+                rejected = {seg.name for seg in host_tier}
+                resident = [
+                    seg for seg in mgr.resident()
+                    if seg.name in routed
+                    or (seg.name not in rejected
+                        and not getattr(seg, "is_mutable", False)
+                        and self.tiering.admit(table, seg, mgr))]
+                settle = [seg.name for seg in resident]
                 with span("device"):
                     try:
-                        out = self.device_pipeline.execute_partial(ctx,
-                                                                   admitted)
+                        out = self.device_pipeline.execute_partial(
+                            ctx, admitted, resident)
                     except Exception:
                         # fetch/decode raised on the device path: the host
                         # answers, the pipeline logs and counts the error
@@ -834,7 +863,7 @@ class ServerNode:
             # reservations made by THIS query's admissions are settled: a
             # block either staged (the ledger counts it now) or never will
             # until another query re-admits it
-            self.tiering.settle([seg.name for seg in admitted])
+            self.tiering.settle(settle or [seg.name for seg in admitted])
             mgr.release(segments)
         aggs = [make_agg(f) for f in ctx.aggregations]
         with span("merge"):

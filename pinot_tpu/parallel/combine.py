@@ -18,6 +18,14 @@ with one ICI collective and no host-side value merge. Two ways a segment set qua
   column, per-segment ids remapped host-side once at block-build time, after which the
   set is aligned by construction.
 
+What is STAGED and PLANNED is the set a server holds of a table (its RESIDENT
+set): one `SegmentSetBlock`, one merged view, one plan a query shape. The
+segments a query was ROUTED to (the broker prunes by time, range, partition)
+are a runtime input of the launch: a per-slot mask ANDed into `valid` and, on
+a mesh of one device, a window of slots of static power-of-two length whose
+start is a runtime scalar (`_route_window`), so a pruned query reads the rows
+of its window and builds no block, no view and no dictionary shape of its own.
+
 JSON_MATCH/TEXT_MATCH/geo doc-set bitmaps stack [S, rows] into the kernel's
 `docsets` input (cached per predicate on the block), and multi-value LUT filter
 columns stack as [S, rows, W] padded id matrices — both on the ALIGNED immutable
@@ -50,7 +58,8 @@ from ..query.result import ResultTable
 from ..segment.reader import ImmutableSegment
 from ..sql.ast import Expr, Function, Identifier, identifiers_in
 from ..utils.metrics import get_registry
-from .merged import MergedSegmentView, view_key
+from ..utils.trace import stage
+from .merged import MergedSegmentView, set_facts, view_key
 from .mesh import (SEGMENT_AXIS, default_mesh, pad_slots, placement_slots,
                    skew_pct)
 
@@ -115,6 +124,8 @@ class PreparedDispatch:
     fscal_np: Optional[np.ndarray] = None
     trim_keys: Tuple[int, int] = (0, 0)  # (num_keys_pad, num_keys_real) device trim
     launch: Any = None           # "topk": () -> outs_dev (pre-bound kernel)
+    window: int = 0              # slots the program reads; 0 = no routing input
+    slot_stats: Optional[dict] = None  # routed/resident/scanned slots, merged
 
 
 def _record_fused(p: PreparedDispatch) -> None:
@@ -187,12 +198,37 @@ def aligned_dictionaries(segments: Sequence[ImmutableSegment], cols: Sequence[st
     return True
 
 
+def _route_window(slots: Sequence[int], s_pad: int, n_devices: int):
+    """(window, start): the slots a launch routed to `slots` reads. On a mesh
+    of one device the smallest power-of-two run of slots that covers them (a
+    ladder 1, 2, 4, ... s_pad, so a query shape owns at most log2(s_pad) + 1
+    programs), placed so that it ends inside the block; time-pruned subsets
+    are contiguous in push order, a subset that is not takes the window that
+    covers it. Where the slot axis is sharded every device reads its slots
+    and the routed ones are a mask alone (the window there: ROADMAP S13)."""
+    lo, hi = min(slots), max(slots)
+    if n_devices > 1:
+        return s_pad, 0
+    window = min(1 << (hi - lo).bit_length(), s_pad)
+    return window, min(lo, s_pad - window)
+
+
 class SegmentSetBlock:
-    """Stacked device columns for an aligned segment set: [S_pad, P] arrays.
+    """Stacked device columns of one segment set: [S_pad, P] arrays.
+
+    The set is what a server holds of a table (its resident set), staged once:
+    `MeshQueryExecutor._set_blocks` keys a block by the set's segment paths
+    (and whether it is in a merged id space), with the members' `view_key` and
+    `s_pad` as the value's subkey, so a set that changes (a segment added,
+    replaced or dropped, a consuming member grown) restages and its
+    predecessor is dropped. The segments a query is routed to never key a
+    block: they are `route()`, a per-slot mask of the launch.
 
     Arrays are `device_put` once with their final mesh sharding (segment axis sharded,
     decode tables replicated) so repeated queries dispatch with zero re-shard copies —
     the analog of the reference's segment-resident mmap buffers being scan-ready.
+    What is put is counted (`setBlocksStaged`, `setBlockBytes`) and timed
+    under a `pinot:mesh.stage` span.
     """
 
     def __init__(self, segments: Sequence[ImmutableSegment], s_pad: int,
@@ -230,10 +266,28 @@ class SegmentSetBlock:
         self._sharded = jax.sharding.NamedSharding(mesh, P(SEGMENT_AXIS))
         self._replicated = jax.sharding.NamedSharding(mesh, P())
         self._cache: Dict[Tuple[str, str], jnp.ndarray] = {}
+        self.slot_of = {getattr(seg, "path", seg.name): sl
+                        for seg, sl in zip(self.segments, self.slots)}
+        qstats.record(qstats.SET_BLOCKS_STAGED)
+
+    def _put(self, key: Tuple[str, str], build) -> jnp.ndarray:
+        """The block's array `key`, built on the host by `build()` and put on
+        the mesh (segment axis sharded) the first time it is asked for."""
+        if key not in self._cache:
+            with stage("mesh.stage", cols=1) as st:
+                host = build()
+                self._cache[key] = jax.device_put(host, self._sharded)
+                st.note(bytes=int(host.nbytes))
+            qstats.record(qstats.SET_BLOCK_BYTES, int(host.nbytes))
+        return self._cache[key]
+
+    def routed_slots(self, segments) -> Tuple[int, ...]:
+        """The slots of the members a query was routed to, in slot order."""
+        return tuple(sorted(self.slot_of[getattr(s, "path", s.name)]
+                            for s in segments))
 
     def _stack(self, kind: str, col: str, fill, per_seg) -> jnp.ndarray:
-        key = (kind, col)
-        if key not in self._cache:
+        def build():
             first = np.asarray(per_seg(0, self.segments[0]))
             # 1-D per-segment arrays stack to [S, rows]; 2-D (padded MV id
             # matrices [rows, W]) stack to [S, rows, W]
@@ -244,8 +298,8 @@ class SegmentSetBlock:
                 # grown since the view (and its remap tables) were built
                 arr = np.asarray(per_seg(i, seg))[:self.seg_docs[i]]
                 out[self.slots[i], :len(arr)] = arr
-            self._cache[key] = jax.device_put(out, self._sharded)
-        return self._cache[key]
+            return out
+        return self._put((kind, col), build)
 
     def ids(self, col: str) -> jnp.ndarray:
         """Dict ids in the space the plan was made in: segment-local ids for aligned
@@ -317,8 +371,7 @@ class SegmentSetBlock:
         the decoded column (PR 26's traces), saving only its residency.
         Aligned sets only: merged views remap ids into the global dictionary
         space, which a per-segment LUT stack cannot decode."""
-        key = ("dictlut", col)
-        if key not in self._cache:
+        def build():
             from ..engine.datablock import _narrow, lut_size
             tables = []
             for s in self.segments:
@@ -332,8 +385,8 @@ class SegmentSetBlock:
                            dtype=np.result_type(*[t.dtype for t in tables]))
             for i, t in enumerate(tables):
                 out[self.slots[i], :len(t)] = t
-            self._cache[key] = jax.device_put(out, self._sharded)
-        return self._cache[key]
+            return out
+        return self._put(("dictlut", col), build)
 
     def null_mask(self, col: str) -> jnp.ndarray:
         def per_seg(i, s):
@@ -375,6 +428,19 @@ def _pack_kernel(meta: Tuple, trim_keys: Tuple[int, int], batched: bool):
         fn = jax.jit(pack_impl)
         _SHARD_KERNEL_CACHE[key] = fn
     return fn
+
+
+def _drop_superseded(cache: dict, new_key: Tuple) -> None:
+    """Drop from `_set_blocks` / `_views` (both keyed (segment paths, in a
+    merged id space)) every other entry of the same id space that holds one
+    of `new_key`'s paths: a set that grew, shrank or had a member replaced
+    leaves its predecessor's device arrays to the garbage collector (launches
+    in flight hold their own references). What stays is one entry a resident
+    set and id space, so neither cache needs a bound of its own."""
+    paths, merged = set(new_key[0]), new_key[1]
+    for key in [k for k in cache if k != new_key and k[1] == merged]:
+        if paths.intersection(key[0]):
+            del cache[key]
 
 
 class MeshQueryExecutor:
@@ -425,14 +491,20 @@ class MeshQueryExecutor:
         except DocsetPlanDivergence:
             return self._fallback.execute(segments, ctx)
 
-    def _plan_for_set(self, ctx: QueryContext, segments):
+    def _plan_for_set(self, ctx: QueryContext, segments, routed=None):
         """Choose the planning surface for a segment set.
 
         Returns (plan, view): view is None for the aligned fast path (ids agree by
         dictHash), a MergedSegmentView when ids must be remapped to a global
         dictionary, and plan is None when the set must take the per-segment
-        fallback."""
-        star_plans = self._star_fit_plans(ctx, segments)
+        fallback. Either way the plan is the SET's: a leaf is folded by the
+        set's facts (min of mins, max of maxes, `hasNulls` of any, the
+        cardinality of the id space the plan is in), never by the first
+        member's (`merged.set_facts`). `routed` (members of `segments`) is
+        what a star-tree answer is made over: its record masks are the
+        query's own, so it stacks the routed members alone."""
+        star_plans = self._star_fit_plans(
+            ctx, segments if routed is None else routed)
         if star_plans is not None:
             # every segment answers from a pre-aggregated star-tree record
             # table. SMALL tables (~100s of records): the per-segment host
@@ -444,7 +516,8 @@ class MeshQueryExecutor:
             # split-dim predicates compile into the kernel mask as LUT/
             # interval leaves and the tree-traversal record masks ride the
             # kernel's valid input (BASELINE config 3 as designed).
-            star = self._plan_star_device(ctx, segments, star_plans)
+            star = self._plan_star_device(
+                ctx, segments if routed is None else routed, star_plans)
             if star is not None:
                 return star, "star"
             return None, None
@@ -457,9 +530,11 @@ class MeshQueryExecutor:
         total_docs = sum(s.num_docs for s in segments)
         any_mutable = any(getattr(s, "is_mutable", False) for s in segments)
         if not any_mutable:
-            plan = plan_segment(ctx, segments[0], scan_docs=total_docs)
-            if plan.kind != "device":
-                return plan, None
+            plan = plan_segment(ctx, set_facts(segments),
+                                scan_docs=total_docs)
+            # the first member's dictionaries speak for the set only where
+            # they agree: a literal its dictionary lacks folds the plan to
+            # "empty", which time-ordered members do not share
             if self._alignable(plan, segments):
                 return plan, None
         if special:
@@ -571,12 +646,11 @@ class MeshQueryExecutor:
         # keyed by STABLE segment identity; the volatile part (mutable row counts)
         # is the value's subkey, so a grown consuming segment REPLACES its stale
         # view instead of accumulating one per growth step
-        stable = tuple(getattr(s, "path", s.name) for s in segments)
+        stable = (tuple(getattr(s, "path", s.name) for s in segments), True)
         vkey = view_key(segments)
         entry = self._views.get(stable)
         if entry is None or entry[0] != vkey:
-            if len(self._views) > 64:
-                self._views.clear()
+            _drop_superseded(self._views, stable)
             entry = (vkey, MergedSegmentView(segments))
             self._views[stable] = entry
         return entry[1]
@@ -660,33 +734,43 @@ class MeshQueryExecutor:
             return None
 
     # -- prepared dispatch (the serving pipeline's unit of work) -------
-    def prepare_partial(self, ctx: QueryContext, segments):
+    def prepare_partial(self, ctx: QueryContext, segments, resident=None):
         """Plan + build (but do NOT launch) a server-level partial dispatch.
+
+        `segments` are the members the query was routed to; `resident`, where
+        the caller knows it, is the set the server holds of the table, in
+        push order. Block, view and plan are the resident set's; the routed
+        members are a runtime input of the launch (`_prepare_sharded`).
 
         Returns a PreparedDispatch or None (host fallback). The pipeline
         groups prepared items by dedupe/stack key and launches them through
         `dispatch_prepared`, so N same-shape queries pay one traced
         executable and — where only runtime scalars differ — one batched
         kernel launch."""
+        routed = None
+        if resident is not None and len(segments) < len(resident):
+            held = {getattr(s, "path", s.name) for s in resident}
+            if all(getattr(s, "path", s.name) in held for s in segments):
+                routed, segments = list(segments), list(resident)
         if not ctx.aggregations and not ctx.distinct:
             # selection: only the immutable top-k path rides the device (no
             # merged-view remap — a fallback verdict must stay cheap)
             if not segments or any(getattr(s, "is_mutable", False)
                                    for s in segments):
                 return None
-            plan = plan_segment(ctx, segments[0],
+            plan = plan_segment(ctx, set_facts(segments),
                                 scan_docs=sum(s.num_docs for s in segments))
             if plan.kind != "selection":
                 return None  # empty/pruned: the host path answers trivially
-            return self._prepare_topk(ctx, plan, segments)
-        plan, view = self._plan_for_set(ctx, segments)
+            return self._prepare_topk(ctx, plan, segments, routed)
+        plan, view = self._plan_for_set(ctx, segments, routed)
         if isinstance(plan, StarSetPlan):
             return self._prepare_star(ctx, plan)
         if plan is None or plan.kind != "device":
             return None
         try:
             return self._prepare_sharded(ctx, plan, segments, view,
-                                         partial=True)
+                                         partial=True, routed=routed)
         except DocsetPlanDivergence:
             return None
 
@@ -736,7 +820,8 @@ class MeshQueryExecutor:
                             # compiler's own collectives are not counted
                             qstats.record(qstats.MESH_LAUNCHES)
                     else:
-                        fn = self._get_shard_kernel(p.spec, p.s_pad, p.rows)
+                        fn = self._get_shard_kernel(p.spec, p.s_pad, p.rows,
+                                                    window=p.window)
                         _record_fused(p)
                         outs = fn(p.inputs)
                     packed, unpack = self._pack(outs, p.trim_keys, batched=0)
@@ -747,6 +832,10 @@ class MeshQueryExecutor:
                                                 batched=b_real)
                     finish = (lambda host, u=unpack, n=b_real:
                               [u(host, b) for b in range(n)])
+                # what the launch read of the resident set (a stacked launch
+                # shares its members' block and routing: `stack_key`)
+                for k, v in (ps[0].slot_stats or {}).items():
+                    qstats.record(k, v)
             launches.append((packed, finish, idxs, recorded.to_wire()))
         return launches
 
@@ -766,7 +855,7 @@ class MeshQueryExecutor:
         inputs["iscal"] = self._const(iscal)
         inputs["fscal"] = self._const(fscal)
         fn = self._get_shard_kernel(ps[0].spec, ps[0].s_pad, ps[0].rows,
-                                    batch=b_pad)
+                                    batch=b_pad, window=ps[0].window)
         # one persistent launch carries every stacked query's fused scan
         _record_fused(ps[0])
         return fn(inputs), b
@@ -820,8 +909,7 @@ class MeshQueryExecutor:
         vkey = (view_key(segments), s_pad)
         entry = self._set_blocks.get(stable)
         if entry is None or entry[0] != vkey:
-            if len(self._set_blocks) > 64:
-                self._set_blocks.clear()
+            _drop_superseded(self._set_blocks, stable)
             entry = (vkey, SegmentSetBlock(segments, s_pad, self.mesh, view))
             self._set_blocks[stable] = entry
             # padding-waste accounting: fraction of the stacked [s_pad, rows]
@@ -870,7 +958,7 @@ class MeshQueryExecutor:
         decode reassemble slot states into the original aggregations."""
         p = self._prepare_sharded(ctx, plan, segments, view, valid_override,
                                   star, partial)
-        fn = self._get_shard_kernel(p.spec, p.s_pad, p.rows)
+        fn = self._get_shard_kernel(p.spec, p.s_pad, p.rows, window=p.window)
         return fn(p.inputs), p.decode
 
     def _prepare_star(self, ctx: QueryContext, sp: "StarSetPlan",
@@ -917,11 +1005,37 @@ class MeshQueryExecutor:
                 fused.append((c, "dict"))
         return tuple(fused)
 
+    def _routing(self, block: SegmentSetBlock, routed) -> Tuple[int, dict,
+                                                                 dict]:
+        """(window, inputs, slot stats) of a launch over `block` for a query
+        routed to `routed` of its members (None: to all). A launch routed to
+        every member has no routing input and is the program it always was;
+        a subset adds the per-slot mask `route` and the window's start
+        `route_start`, both runtime operands, and reads `window` slots."""
+        resident = len(block.segments)
+        slots = block.routed_slots(routed) if routed is not None else ()
+        window, inputs = 0, {}
+        if routed is not None and len(slots) < resident:
+            window, start = _route_window(slots, block.s_pad, self.n_devices)
+            mask = np.zeros(block.s_pad, dtype=bool)
+            mask[list(slots)] = True
+            inputs = dict(route=self._const(mask),
+                          route_start=self._const(np.asarray(start, np.int32)))
+        stats = {qstats.ROUTED_SLOTS: len(slots) if window else resident,
+                 qstats.RESIDENT_SLOTS: resident,
+                 qstats.SCANNED_SLOTS: window if 0 < window < block.s_pad
+                 else resident,
+                 qstats.MERGED_LAUNCHES:
+                 int(isinstance(block.view, MergedSegmentView))}
+        return window, inputs, stats
+
     def _prepare_sharded(self, ctx: QueryContext, plan, segments, view=None,
                          valid_override=None, star=None,
-                         partial=False) -> PreparedDispatch:
+                         partial=False, routed=None) -> PreparedDispatch:
         """Plan-shape + runtime-input construction WITHOUT the kernel launch
-        (the separable front half of `_dispatch_sharded`)."""
+        (the separable front half of `_dispatch_sharded`). `segments` is the
+        set that is staged and planned; `routed`, its members the query was
+        routed to (None: all of them)."""
         build_device_geometry(plan)
         agg_specs = []
         distinct_lut_sizes: Dict[int, int] = {}
@@ -1000,6 +1114,8 @@ class MeshQueryExecutor:
             agg_luts=agg_luts,
             docsets=docsets,
         )
+        window, route_inputs, slot_stats = self._routing(block, routed)
+        inputs.update(route_inputs)
 
         def decode(outs):
             return self._finish_mesh_stats(_decode_impl(outs), block, outs)
@@ -1065,13 +1181,14 @@ class MeshQueryExecutor:
             return reduce_to_result(ctx, merged, plan.aggs, group_exprs)
 
         sig = spec.signature()
-        shape_key = ("agg", sig, id(block), s_pad, block.rows, id(self.mesh))
+        shape_key = ("agg", sig, id(block), s_pad, block.rows, id(self.mesh),
+                     window)
         # device operands are content-addressed (`_const`) or block-cached, so
         # object identity == content identity: two queries stack iff the same
         # executable reads the same device arrays (scalars ride the stack)
         operands = (tuple(id(a) for a in inputs["luts"]),
                     id(inputs["valid"]), id(inputs["strides"]),
-                    tuple(id(d) for d in docsets))
+                    tuple(id(d) for d in docsets), id(inputs.get("route")))
         stackable = (star is None and valid_override is None and not docsets)
         stack_key = shape_key + operands
         dedupe_key = None if valid_override is not None else \
@@ -1084,11 +1201,14 @@ class MeshQueryExecutor:
             kind="agg", spec=spec, inputs=inputs, s_pad=s_pad,
             rows=block.rows, stack_key=stack_key, dedupe_key=dedupe_key,
             stackable=stackable, decode=decode, iscal_np=iscal_np,
-            fscal_np=fscal_np, trim_keys=trim)
+            fscal_np=fscal_np, trim_keys=trim, window=window,
+            slot_stats=slot_stats)
 
     # ------------------------------------------------------------------
-    def _prepare_topk(self, ctx: QueryContext, plan, segments):
-        """Prepared device top-k for a served ORDER-BY-limit selection.
+    def _prepare_topk(self, ctx: QueryContext, plan, segments, routed=None):
+        """Prepared device top-k for a served ORDER-BY-limit selection over
+        the set `segments`; `routed` (None: all) are the members the query
+        was routed to, the others masked out of `valid`.
 
         Mirrors `ServerQueryExecutor._topk_candidates` eligibility over the
         STACKED segment set, dispatching the same fused `compute_topk` kernel
@@ -1151,6 +1271,10 @@ class MeshQueryExecutor:
             nulls={c: block.null_mask(c) for c in nulls_cols},
             valid=block.valid,
         )
+        route = self._routing(block, routed)[1].get("route")
+        if route is not None:
+            # a plain jit over the block: the routed slots as one more pass
+            inputs["valid"] = block.valid & route[:, None]
         slack = ServerQueryExecutor.TOPK_SLACK
         fn, kk = topk_kernel(spec, order.expr, order.desc, k + slack,
                              total_rows=s_pad * block.rows)
@@ -1165,7 +1289,7 @@ class MeshQueryExecutor:
         return PreparedDispatch(
             kind="topk", spec=static, inputs=inputs, s_pad=s_pad,
             rows=block.rows, stack_key=static,
-            dedupe_key=static + (tuple(id(a) for a in luts),
+            dedupe_key=static + (tuple(id(a) for a in luts), id(route),
                                  iscal_np.tobytes(), fscal_np.tobytes()),
             stackable=False, decode=decode, launch=launch)
 
@@ -1238,9 +1362,9 @@ class MeshQueryExecutor:
 
     # ------------------------------------------------------------------
     def _get_shard_kernel(self, spec: KernelSpec, s_pad: int, rows: int,
-                          batch: int = 0):
+                          batch: int = 0, window: int = 0):
         cache_key = (spec.signature(), self.n_devices, s_pad, rows,
-                     id(self.mesh), batch)
+                     id(self.mesh), batch, window)
         fn = _SHARD_KERNEL_CACHE.get(cache_key)
         if fn is None:
             qstats.record(qstats.COMPILE_CACHE_MISSES)
@@ -1248,14 +1372,16 @@ class MeshQueryExecutor:
             # same first-call compile fence as the single-device cache: the
             # cold call's wall (trace + compile + first run) lands in the
             # compile histogram, not in whichever query drew the short straw
-            fn = _fence_first_call(self._build_shard_kernel(spec, batch))
+            fn = _fence_first_call(self._build_shard_kernel(spec, batch,
+                                                            window))
             _SHARD_KERNEL_CACHE[cache_key] = fn
         else:
             qstats.record(qstats.COMPILE_CACHE_HITS)
             get_registry().counter("pinot_kernel_cache_hits").inc()
         return fn
 
-    def _build_shard_kernel(self, spec: KernelSpec, batch: int = 0):
+    def _build_shard_kernel(self, spec: KernelSpec, batch: int = 0,
+                            window: int = 0):
         """jit(shard_map(fused scan body + per-output ICI collective)).
 
         The body is the SAME gather/scatter-free kernel as the single-device path
@@ -1276,7 +1402,15 @@ class MeshQueryExecutor:
 
         `batch > 0` builds the STACKED variant: iscal/fscal arrive [B, n] and
         the body scans over them — B same-shape queries in one launch, reading
-        the HBM columns once per scan step but paying ONE dispatch."""
+        the HBM columns once per scan step but paying ONE dispatch.
+
+        `window > 0` builds the ROUTED variant for a query routed to some of
+        the block's members: two more runtime operands, the per-slot mask
+        `route` (ANDed into `valid`) and the scalar `route_start`; where
+        `window` is less than the slots a device holds (a mesh of one) the
+        body reads `window` slots from `route_start` on, a `dynamic_slice`
+        of every per-segment operand over the slot axis, so the rows read are
+        the window's and every start shares the program."""
         from ..engine.kernels import (combine_collective, kernel_name,
                                       make_kernel_body)
         body = make_kernel_body(spec)
@@ -1285,10 +1419,32 @@ class MeshQueryExecutor:
         n = self.n_devices
         sharded, repl = P(ax), P()
 
-        in_specs = (dict(ids=sharded, vals=sharded, luts=repl, iscal=repl,
-                         fscal=repl, nulls=sharded, valid=sharded, strides=repl,
-                         agg_luts=sharded, docsets=sharded),)
-        _REPL_KEYS = ("luts", "iscal", "fscal", "strides")
+        in_specs = dict(ids=sharded, vals=sharded, luts=repl, iscal=repl,
+                        fscal=repl, nulls=sharded, valid=sharded, strides=repl,
+                        agg_luts=sharded, docsets=sharded)
+        if window:
+            in_specs.update(route=sharded, route_start=repl)
+        in_specs = (in_specs,)
+        _REPL_KEYS = ("luts", "iscal", "fscal", "strides", "route_start")
+        _SLOT_KEYS = ("ids", "vals", "nulls", "valid", "agg_luts", "docsets")
+
+        def routed(inputs):
+            """The per-segment operands a routed launch reads: its window of
+            slots, `valid` cleared in the slots it was not routed to."""
+            if not window:
+                return inputs
+            with jax.named_scope("pinot.route"):
+                out = dict(inputs)
+                route = inputs["route"]
+                if window < route.shape[0]:         # slots this device holds
+                    def cut(x):
+                        return jax.lax.dynamic_slice_in_dim(
+                            x, inputs["route_start"], window, axis=0)
+                    for key in _SLOT_KEYS:
+                        out[key] = jax.tree_util.tree_map(cut, inputs[key])
+                    route = cut(route)
+                out["valid"] = out["valid"] & route[:, None]
+            return out
 
         num_seg = spec.num_keys_pad + 1
         pad = spec.num_keys_pad
@@ -1296,6 +1452,8 @@ class MeshQueryExecutor:
 
         if batch:
             def call_body(inputs):
+                inputs = routed(inputs)
+
                 def step(carry, scal):
                     i_s, f_s = scal
                     out = body(inputs["ids"], inputs["vals"], inputs["luts"],
@@ -1308,6 +1466,7 @@ class MeshQueryExecutor:
                 return outs
         else:
             def call_body(inputs):
+                inputs = routed(inputs)
                 return body(inputs["ids"], inputs["vals"], inputs["luts"],
                             inputs["iscal"], inputs["fscal"], inputs["nulls"],
                             inputs["valid"], inputs["strides"],
@@ -1386,8 +1545,12 @@ class MeshQueryExecutor:
             compiled = jitted_for(inputs)
             for key, v in built.get("mesh", {}).items():
                 qstats.record(key, v)
-            # the rows one device holds: a shape, like what `mesh` holds
-            if slabbed(spec, inputs["valid"].size // n):
+            # the rows one device reads: a shape, like what `mesh` holds
+            rows_read = inputs["valid"].size // n
+            held = inputs["valid"].shape[0] // n
+            if 0 < window < held:
+                rows_read = rows_read // held * window
+            if slabbed(spec, rows_read):
                 qstats.record(qstats.SLABBED_LAUNCHES)
             return compiled(inputs)
 

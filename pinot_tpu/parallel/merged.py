@@ -58,6 +58,20 @@ def _merge_sorted_values(dicts: List[Dictionary], data_type: DataType):
     return Dictionary(list(merged), data_type), remaps
 
 
+def _set_bound(readers, attr: str, combine) -> Any:
+    """min/max over members with rows; a NONEMPTY member without stats poisons
+    the bound to None (empty members genuinely contribute no values)."""
+    vals = []
+    for r in readers:
+        v = getattr(r, attr)
+        if v is None:
+            if r.num_docs > 0:
+                return None
+            continue
+        vals.append(v)
+    return combine(vals) if vals else None
+
+
 class MergedColumnReader:
     """ColumnReader-compatible view of one column across a segment set.
 
@@ -131,26 +145,13 @@ class MergedColumnReader:
             "cardinality": self.cardinality,
         }
 
-    def _merged_bound(self, attr: str, combine) -> Any:
-        """min/max over members with rows; a NONEMPTY member without stats poisons
-        the bound to None (empty members genuinely contribute no values)."""
-        vals = []
-        for r in self._readers:
-            v = getattr(r, attr)
-            if v is None:
-                if r.num_docs > 0:
-                    return None
-                continue
-            vals.append(v)
-        return combine(vals) if vals else None
-
     @property
     def min_value(self) -> Any:
-        return self._merged_bound("min_value", min)
+        return _set_bound(self._readers, "min_value", min)
 
     @property
     def max_value(self) -> Any:
-        return self._merged_bound("max_value", max)
+        return _set_bound(self._readers, "max_value", max)
 
     # aux indexes are per-segment; the mesh path pre-bails on JSON/TEXT_MATCH filters
     inverted_index = None
@@ -207,6 +208,66 @@ class MergedSegmentView:
 
     def __repr__(self) -> str:
         return f"MergedSegmentView({len(self.segments)} segments, docs={self.num_docs})"
+
+
+class _SetFactsReader:
+    """The first member's reader of a column whose dictionaries AGREE across
+    the set, with the SET's facts where a plan folds a leaf by them: min of
+    mins, max of maxes, `hasNulls` of any. A member's bloom filter speaks for
+    that member alone, so the set has none."""
+
+    bloom_filter = None
+
+    def __init__(self, readers: Sequence[Any]):
+        self._readers = list(readers)
+
+    def __getattr__(self, name: str) -> Any:
+        return getattr(self._readers[0], name)
+
+    @property
+    def num_docs(self) -> int:
+        return sum(r.num_docs for r in self._readers)
+
+    @property
+    def meta(self) -> Dict[str, Any]:
+        return dict(self._readers[0].meta, hasNulls=any(
+            r.meta.get("hasNulls", False) for r in self._readers))
+
+    @property
+    def min_value(self) -> Any:
+        return _set_bound(self._readers, "min_value", min)
+
+    @property
+    def max_value(self) -> Any:
+        return _set_bound(self._readers, "max_value", max)
+
+
+class SegmentSetFacts:
+    """The planning surface of an ALIGNED set: the first member (its
+    dictionaries are every member's, so ids stay segment-local and nothing is
+    remapped), with each column's metadata the set's (`_SetFactsReader`).
+    A plan made on the first member alone folds `WHERE pos < m` by that
+    member's min/max, which row-ordered (time-ordered) members do not share."""
+
+    def __init__(self, segments: Sequence[Any]):
+        self.segments = list(segments)
+        self.num_docs = sum(s.num_docs for s in segments)
+        self._columns: Dict[str, _SetFactsReader] = {}
+
+    def __getattr__(self, name: str) -> Any:
+        return getattr(self.segments[0], name)
+
+    def column(self, name: str) -> _SetFactsReader:
+        if name not in self._columns:
+            self._columns[name] = _SetFactsReader(
+                [s.column(name) for s in self.segments])
+        return self._columns[name]
+
+
+def set_facts(segments: Sequence[Any]):
+    """What an aligned set is planned on: the one member itself, or the
+    first member with the set's facts."""
+    return segments[0] if len(segments) == 1 else SegmentSetFacts(segments)
 
 
 def view_key(segments: Sequence[Any]) -> Tuple:
