@@ -22,8 +22,8 @@ What is STAGED and PLANNED is the set a server holds of a table (its RESIDENT
 set): one `SegmentSetBlock`, one merged view, one plan a query shape. The
 segments a query was ROUTED to (the broker prunes by time, range, partition)
 are a runtime input of the launch: a per-slot mask ANDed into `valid` and, on
-a mesh of one device, a window of slots of static power-of-two length whose
-start is a runtime scalar (`_route_window`), so a pruned query reads the rows
+a mesh of one device, a window of slots of static length (a ladder of three
+significant bits) whose start is a runtime scalar (`_route_window`), so a pruned query reads the rows
 of its window and builds no block, no view and no dictionary shape of its own.
 
 JSON_MATCH/TEXT_MATCH/geo doc-set bitmaps stack [S, rows] into the kernel's
@@ -200,16 +200,20 @@ def aligned_dictionaries(segments: Sequence[ImmutableSegment], cols: Sequence[st
 
 def _route_window(slots: Sequence[int], s_pad: int, n_devices: int):
     """(window, start): the slots a launch routed to `slots` reads. On a mesh
-    of one device the smallest power-of-two run of slots that covers them (a
-    ladder 1, 2, 4, ... s_pad, so a query shape owns at most log2(s_pad) + 1
-    programs), placed so that it ends inside the block; time-pruned subsets
-    are contiguous in push order, a subset that is not takes the window that
-    covers it. Where the slot axis is sharded every device reads its slots
-    and the routed ones are a mask alone (the window there: ROADMAP S13)."""
+    of one device the run of slots that covers them, its length rounded up to
+    three significant bits (1 .. 8, 10, 12, 14, 16, 20, ...): a ladder of
+    four static lengths an octave, so a query shape owns a bounded set of
+    programs, and a launch reads less than a quarter more slots than the run
+    it was routed (nothing more up to 8; 14 of 16 read 14, 15 read 16). Time-pruned subsets are contiguous in push order; a subset that
+    is not takes the window that covers it. The window is placed so that it
+    ends inside the block. Where the slot axis is sharded every device reads
+    its slots and the routed ones are a mask alone (ROADMAP S13)."""
     lo, hi = min(slots), max(slots)
     if n_devices > 1:
         return s_pad, 0
-    window = min(1 << (hi - lo).bit_length(), s_pad)
+    span = hi - lo + 1
+    step = 1 << max(span.bit_length() - 3, 0)
+    window = min(-(-span // step) * step, s_pad)
     return window, min(lo, s_pad - window)
 
 
@@ -748,10 +752,13 @@ class MeshQueryExecutor:
         executable and — where only runtime scalars differ — one batched
         kernel launch."""
         routed = None
-        if resident is not None and len(segments) < len(resident):
+        if resident is not None:
             held = {getattr(s, "path", s.name) for s in resident}
             if all(getattr(s, "path", s.name) in held for s in segments):
-                routed, segments = list(segments), list(resident)
+                # the set in the resident's order, whatever order the broker
+                # named its members in: one key, one block
+                routed = list(segments) if len(segments) < len(held) else None
+                segments = list(resident)
         if not ctx.aggregations and not ctx.distinct:
             # selection: only the immutable top-k path rides the device (no
             # merged-view remap — a fallback verdict must stay cheap)

@@ -211,8 +211,8 @@ def test_answer_says_what_was_routed_resident_and_read(served, template,
         if n == 4 or routed == SEGMENTS:
             assert resp["scannedSlots"] == SEGMENTS
         else:
+            # up to 8 slots the window is the span of the routed slots
             assert routed <= resp["scannedSlots"] <= SEGMENTS
-            assert resp["scannedSlots"] & (resp["scannedSlots"] - 1) == 0
     one = served[1][1 + variant][0][key]
     if template in ("q1.2",):               # a month: one or two segments
         assert one["scannedSlots"] <= 2
@@ -222,7 +222,7 @@ def test_other_literals_and_subsets_stage_nothing_and_build_no_block(served):
     """After one query of each template, the second variants (other literals,
     other routed subsets) put no byte on the device, build no block and no
     view, and compile at most the ladder's programs."""
-    ladder = SEGMENTS.bit_length()          # log2(s_pad) + 1 = 4 windows
+    ladder = SEGMENTS                       # window lengths 1 .. 8
     for n in (1, 4):
         start, first, second = served[n]
         subsets = {v: {k[0]: a["numSegmentsQueried"]
@@ -352,17 +352,29 @@ def test_routed_subset_answers_as_the_subset_alone(segments, subset, sql,
 
 @pytest.mark.parametrize("slots,s_pad,want", [
     ((3,), 16, (1, 3)), ((2, 3), 16, (2, 2)), ((3, 4), 16, (2, 3)),
-    ((5, 6, 7), 16, (4, 5)), ((4, 5, 6, 7, 8), 16, (8, 4)),
-    ((0, 15), 16, (16, 0)), ((13, 14, 15), 16, (4, 12)),
-    ((0, 2, 7), 8, (8, 0)), ((9, 14), 16, (8, 8)), ((7, 8), 8, None)])
+    ((5, 6, 7), 16, (3, 5)), ((4, 5, 6, 7, 8), 16, (5, 4)),
+    ((0, 15), 16, (16, 0)), ((13, 14, 15), 16, (3, 13)),
+    ((0, 2, 7), 8, (8, 0)), ((9, 14), 16, (6, 9)),
+    # past 8 slots the length keeps three significant bits: 9 -> 10, 13 ->
+    # 14, 15 -> 16, 17 -> 20, and the window ends inside the block
+    (tuple(range(2, 11)), 16, (10, 2)), (tuple(range(3, 16)), 16, (14, 2)),
+    (tuple(range(1, 16)), 16, (16, 0)), (tuple(range(40, 57)), 64, (20, 40)),
+    (tuple(range(50, 64)), 64, (14, 50)), ((30, 63), 64, (40, 24))])
 def test_route_window_is_the_ladder_step_that_covers_the_slots(slots, s_pad,
                                                                want):
-    if want is None:
-        return      # slots past s_pad never reach it: the block holds s_pad
     window, start = _route_window(slots, s_pad, 1)
     assert (window, start) == want
-    assert window & (window - 1) == 0 and start + window <= s_pad
+    span = max(slots) - min(slots) + 1
+    assert span <= window < 1.25 * span + 1 and start + window <= s_pad
+    assert start <= min(slots) and max(slots) < start + window
     assert _route_window(slots, s_pad, 4) == (s_pad, 0)
+
+
+def test_route_ladder_has_at_most_four_steps_an_octave():
+    for s_pad in (8, 16, 64, 256):
+        lengths = {_route_window((0, hi), s_pad, 1)[0] for hi in range(s_pad)}
+        assert len(lengths) <= 4 * s_pad.bit_length()
+        assert max(lengths) == s_pad
 
 
 def test_one_block_one_view_whatever_is_routed(segments):
@@ -382,6 +394,12 @@ def test_one_block_one_view_whatever_is_routed(segments):
     (_, view), = mex._views.values()
     assert isinstance(view, MergedSegmentView)
     assert view.column("d_year").cardinality == 7 and pads == {8}
+        # the whole set, named in another order than it is held in
+        p = mex.prepare_partial(ctx, segments[::-1], segments)
+        assert p.window == 0 and p.stack_key[2] in blocks
+        staged = st.counters[qstats.SET_BLOCK_BYTES]
+        mex.prepare_partial(ctx, [segments[5], segments[1]], segments)
+        assert st.counters[qstats.SET_BLOCK_BYTES] == staged
     assert st.counters[qstats.SET_BLOCKS_STAGED] == 1
     # a member replaced (another object at the same path) restages, and the
     # superseded block goes

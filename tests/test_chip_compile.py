@@ -36,7 +36,7 @@ MATMUL_SEGS = kernels.SLAB_ROWS // SEG_ROWS
 FIXTURE_ROWS = 1 << 20          # 500k keys stay dictionary-encoded (<= 0.7 x rows)
 SQL = dict(chip_smoke.QUERIES)
 #: inputs replicated over the mesh (everything else carries the segment axis)
-_REPLICATED = ("luts", "iscal", "fscal", "strides")
+_REPLICATED = ("luts", "iscal", "fscal", "strides", "route_start")
 
 
 @pytest.fixture(scope="module")
@@ -191,6 +191,48 @@ def test_served_agg_kernel_compiles_for_v5e(topo, cpu_exec, segments, name,
         assert " conditional(" in text
         for branch in ("compact", "dense"):
             assert f"pinot.groupby.partitioned.{branch}" in text, branch
+
+
+# (smoke query, the window's slots of the 16 resident, sort regime)
+WINDOW_CASES = [
+    pytest.param("group-by 500k keys", 8, True, id="500k-partitioned-window8"),
+    pytest.param("group-by 20k keys", 8, False, id="20k-chunk64-window8"),
+    pytest.param("q1.1 filter+sum", 2, False, id="q1.1-window2"),
+]
+
+
+@pytest.mark.parametrize("name,window,sort_regime", WINDOW_CASES)
+def test_routed_window_program_compiles_for_v5e(topo, cpu_exec, segments,
+                                                name, window, sort_regime):
+    """A query routed to some of the 16 resident segments (PR 32): the program
+    reads `window` slots of the 67M resident rows from a runtime start. The
+    sort runs over the window's rows, the slab loop over the window's slabs,
+    and the slice of the slot axis is no pass of its own."""
+    ctx = compile_query(SQL[name], segments[0].schema)
+    p = cpu_exec.prepare_partial(ctx, segments[:1], segments)
+    assert p is not None and p.window == 1 and "route" in p.inputs
+    mesh = _mesh(topo, 1)
+    ax = _abstract(p.inputs, (p.s_pad, p.rows), (SMOKE_SEGS, SEG_ROWS), mesh)
+    fn = MeshQueryExecutor(mesh)._build_shard_kernel(_real_spec(p.spec),
+                                                     window=window)
+    compiled = fn.jitted_for(ax).lower(ax).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    rows = window * SEG_ROWS
+    assert "pinot.route" in text
+    if sort_regime:
+        sorts = [ln for ln in text.splitlines() if " sort(" in ln]
+        assert sorts and all(f"[{rows}]" in ln for ln in sorts)
+        assert not any(f"[{SMOKE_SEGS * SEG_ROWS}]" in ln for ln in sorts)
+    elif "group-by" in name:
+        assert (" while(" in text) == (rows > kernels.SLAB_ROWS)
+        assert " sort(" not in text
+    # the window is cut inside the fusions that read it: no top-level
+    # dynamic-slice writes a copy of a column's window
+    entry = text[text.index("ENTRY"):]
+    copies = [ln for ln in entry.splitlines()
+              if " dynamic-slice(" in ln and f"{SEG_ROWS}]" in ln]
+    assert not copies, copies[:3]
 
 
 def test_topk_kernel_compiles_for_v5e(topo, cpu_exec, segments):
