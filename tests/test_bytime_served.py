@@ -390,16 +390,16 @@ def test_one_block_one_view_whatever_is_routed(segments):
                                     segments)
             pads.add(p.spec.num_keys_pad)
             blocks.add(p.stack_key[2])
-    assert len(mex._set_blocks) == len(mex._views) == len(blocks) == 1
-    (_, view), = mex._views.values()
-    assert isinstance(view, MergedSegmentView)
-    assert view.column("d_year").cardinality == 7 and pads == {8}
         # the whole set, named in another order than it is held in
         p = mex.prepare_partial(ctx, segments[::-1], segments)
         assert p.window == 0 and p.stack_key[2] in blocks
         staged = st.counters[qstats.SET_BLOCK_BYTES]
         mex.prepare_partial(ctx, [segments[5], segments[1]], segments)
         assert st.counters[qstats.SET_BLOCK_BYTES] == staged
+    assert len(mex._set_blocks) == len(mex._views) == len(blocks) == 1
+    (_, view), = mex._views.values()
+    assert isinstance(view, MergedSegmentView)
+    assert view.column("d_year").cardinality == 7 and pads == {8}
     assert st.counters[qstats.SET_BLOCKS_STAGED] == 1
     # a member replaced (another object at the same path) restages, and the
     # superseded block goes
