@@ -23,8 +23,9 @@ set): one `SegmentSetBlock`, one merged view, one plan a query shape. The
 segments a query was ROUTED to (the broker prunes by time, range, partition)
 are a runtime input of the launch: a per-slot mask ANDed into `valid` and, on
 a mesh of one device, a window of slots of static length (a ladder of three
-significant bits) whose start is a runtime scalar (`_route_window`), so a pruned query reads the rows
-of its window and builds no block, no view and no dictionary shape of its own.
+significant bits) whose start is a runtime scalar (`_route_window`), so a
+pruned query reads the rows of its window and builds no block, no view and no
+dictionary shape of its own.
 
 JSON_MATCH/TEXT_MATCH/geo doc-set bitmaps stack [S, rows] into the kernel's
 `docsets` input (cached per predicate on the block), and multi-value LUT filter
@@ -198,15 +199,21 @@ def aligned_dictionaries(segments: Sequence[ImmutableSegment], cols: Sequence[st
     return True
 
 
+def _path(segment) -> str:
+    """A member's identity in the set caches: its directory, or its name."""
+    return getattr(segment, "path", segment.name)
+
+
 def _route_window(slots: Sequence[int], s_pad: int, n_devices: int):
     """(window, start): the slots a launch routed to `slots` reads. On a mesh
     of one device the run of slots that covers them, its length rounded up to
     three significant bits (1 .. 8, 10, 12, 14, 16, 20, ...): a ladder of
     four static lengths an octave, so a query shape owns a bounded set of
     programs, and a launch reads less than a quarter more slots than the run
-    it was routed (nothing more up to 8; 14 of 16 read 14, 15 read 16). Time-pruned subsets are contiguous in push order; a subset that
-    is not takes the window that covers it. The window is placed so that it
-    ends inside the block. Where the slot axis is sharded every device reads
+    it was routed (nothing more up to 8; 14 of 16 read 14, 15 read 16).
+    Time-pruned subsets are contiguous in push order; a subset that is not
+    takes the window that covers it. The window is placed so that it ends
+    inside the block. Where the slot axis is sharded every device reads
     its slots and the routed ones are a mask alone (ROADMAP S13)."""
     lo, hi = min(slots), max(slots)
     if n_devices > 1:
@@ -226,7 +233,7 @@ class SegmentSetBlock:
     `s_pad` as the value's subkey, so a set that changes (a segment added,
     replaced or dropped, a consuming member grown) restages and its
     predecessor is dropped. The segments a query is routed to never key a
-    block: they are `route()`, a per-slot mask of the launch.
+    block: they are `routed_slots()`, a per-slot mask of the launch.
 
     Arrays are `device_put` once with their final mesh sharding (segment axis sharded,
     decode tables replicated) so repeated queries dispatch with zero re-shard copies —
@@ -270,7 +277,7 @@ class SegmentSetBlock:
         self._sharded = jax.sharding.NamedSharding(mesh, P(SEGMENT_AXIS))
         self._replicated = jax.sharding.NamedSharding(mesh, P())
         self._cache: Dict[Tuple[str, str], jnp.ndarray] = {}
-        self.slot_of = {getattr(seg, "path", seg.name): sl
+        self.slot_of = {_path(seg): sl
                         for seg, sl in zip(self.segments, self.slots)}
         qstats.record(qstats.SET_BLOCKS_STAGED)
 
@@ -287,7 +294,7 @@ class SegmentSetBlock:
 
     def routed_slots(self, segments) -> Tuple[int, ...]:
         """The slots of the members a query was routed to, in slot order."""
-        return tuple(sorted(self.slot_of[getattr(s, "path", s.name)]
+        return tuple(sorted(self.slot_of[_path(s)]
                             for s in segments))
 
     def _stack(self, kind: str, col: str, fill, per_seg) -> jnp.ndarray:
@@ -650,7 +657,7 @@ class MeshQueryExecutor:
         # keyed by STABLE segment identity; the volatile part (mutable row counts)
         # is the value's subkey, so a grown consuming segment REPLACES its stale
         # view instead of accumulating one per growth step
-        stable = (tuple(getattr(s, "path", s.name) for s in segments), True)
+        stable = (tuple(_path(s) for s in segments), True)
         vkey = view_key(segments)
         entry = self._views.get(stable)
         if entry is None or entry[0] != vkey:
@@ -753,8 +760,8 @@ class MeshQueryExecutor:
         kernel launch."""
         routed = None
         if resident is not None:
-            held = {getattr(s, "path", s.name) for s in resident}
-            if all(getattr(s, "path", s.name) in held for s in segments):
+            held = {_path(s) for s in resident}
+            if all(_path(s) in held for s in segments):
                 # the set in the resident's order, whatever order the broker
                 # named its members in: one key, one block
                 routed = list(segments) if len(segments) < len(held) else None
@@ -911,7 +918,7 @@ class MeshQueryExecutor:
     def _block_for(self, segments, view, s_pad: int) -> SegmentSetBlock:
         # stable key + volatile subkey: growth of a consuming segment frees the
         # superseded block's device arrays instead of pinning up to 64 dead copies
-        stable = (tuple(getattr(s, "path", s.name) for s in segments),
+        stable = (tuple(_path(s) for s in segments),
                   view is not None)
         vkey = (view_key(segments), s_pad)
         entry = self._set_blocks.get(stable)
@@ -1020,9 +1027,9 @@ class MeshQueryExecutor:
         a subset adds the per-slot mask `route` and the window's start
         `route_start`, both runtime operands, and reads `window` slots."""
         resident = len(block.segments)
-        slots = block.routed_slots(routed) if routed is not None else ()
+        slots = block.routed_slots(routed) if routed is not None else None
         window, inputs = 0, {}
-        if routed is not None and len(slots) < resident:
+        if slots is not None and len(slots) < resident:
             window, start = _route_window(slots, block.s_pad, self.n_devices)
             mask = np.zeros(block.s_pad, dtype=bool)
             mask[list(slots)] = True

@@ -169,6 +169,14 @@ class MergedColumnReader:
 class MergedSegmentView:
     """Virtual segment over an unaligned set, planned against like one segment.
 
+    The set is what a server holds of a table (its resident set), not what a
+    query is routed to: `MeshQueryExecutor._views` keys a view by the set's
+    segment paths, with the members' `view_key` (a consuming member's row
+    count) as the value's subkey, so one view, one set of global dictionaries
+    and so one key space a query shape serves every routed subset, and a set
+    that changes replaces its view (`combine._drop_superseded`). A column
+    whose dictionaries agree across the members keeps every remap `None`.
+
     Not mutable even when members are: the planner's mutable->host routing is about
     single-segment host scans; here mutable members are snapshotted into the stacked
     device block (see `SegmentSetBlock`), so the device path applies.

@@ -137,7 +137,8 @@ def render_report(stats: Dict[str, Any]) -> str:
                 "numSegmentsMatched", "numDocsScanned", "scanRowsAvoided",
                 "numGroupsTotal", "deviceLaunches", "fusedLaunches",
                 "stagedLaunches", "meshLaunches", "scatterLaunches",
-                "collectiveBytes",
+                "collectiveBytes", "routedSlots", "residentSlots",
+                "scannedSlots", "mergedLaunches", "setBlockBytes",
                 "dedupedLaunches", "stackedLaunches", "compileCacheHits",
                 "compileCacheMisses", "bytesFetched", "deviceBatchSize",
                 "numServersQueried", "numServersResponded"):
