@@ -469,3 +469,28 @@ def test_new_metric_is_declared_for_the_new_cell_alone(name):
     assert set(entry) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
     json.dumps(meta)
+
+
+def test_routed_queries_of_one_subset_stack_into_one_launch(segments):
+    """Three queries that differ in literals alone, routed to one subset: one
+    stacked launch over the window (the routing operands are the stack's),
+    each answering as the subset alone does."""
+    import jax
+    from pinot_tpu.query.executor import ServerQueryExecutor
+    mex = MeshQueryExecutor(default_mesh(1))
+    sqls = ["SELECT d_year, COUNT(*), SUM(lo_revenue) FROM lineorder WHERE "
+            f"lo_quantity BETWEEN {a} AND {a + 9} GROUP BY d_year LIMIT 100"
+            for a in (1, 11, 21)]
+    routed = segments[2:5]
+    ps = [mex.prepare_partial(compile_query(q, segments[0].schema), routed,
+                              segments) for q in sqls]
+    assert all(p.stackable and p.window == 3 for p in ps)
+    assert len({p.stack_key for p in ps}) == 1
+    other = mex.prepare_partial(compile_query(sqls[0], segments[0].schema),
+                                segments[3:6], segments)
+    assert other.stack_key != ps[0].stack_key       # another start, no stack
+    (outs, finish, idxs, recorded), = mex.dispatch_prepared(ps)
+    assert idxs == [0, 1, 2] and recorded[qstats.SCANNED_SLOTS] == 3
+    for p, host, sql in zip(ps, finish(jax.device_get(outs)), sqls):
+        want = ServerQueryExecutor().execute(routed, sql)
+        assert p.decode(host).num_docs_scanned == sum(r[1] for r in want.rows)
