@@ -433,12 +433,8 @@ class DeviceQueryPipeline:
                 with qstats.activate(scratch), \
                         stage("pipeline.prepare",
                               trace_id=item.trace_id) as prep:
-                    # an executor that knows no resident set (the tests'
-                    # fakes) is never handed one
-                    resident = () if item.resident is None \
-                        else (item.resident,)
                     p = self.mesh_exec.prepare_partial(
-                        item.ctx, item.segments, *resident)
+                        item.ctx, item.segments, item.resident)
             except Exception:
                 # planning RAISED on the device path: the host path still
                 # answers the query, but as a counted, logged device error —

@@ -46,6 +46,9 @@ class TableDataManager:
         # segments unloaded while a query still held a ref: their device
         # block + ledger release defers until release() drains the refcount
         self._deferred: Dict[str, ImmutableSegment] = {}
+        # names in push order, reckoned by `resident()` and forgotten when
+        # the set changes
+        self._order: Optional[List[str]] = None
         self._lock = threading.RLock()
 
     def add_segment(self, name: str, segment: ImmutableSegment) -> None:
@@ -55,6 +58,7 @@ class TableDataManager:
             # object which stays valid until its holders release it
             old = self._deferred.pop(name, None)
             self._segments[name] = segment
+            self._order = None
             self._refcounts.setdefault(name, 0)
         if old is not None and old is not segment:
             from ..engine.datablock import release_block
@@ -67,6 +71,7 @@ class TableDataManager:
     def remove_segment(self, name: str) -> None:
         with self._lock:
             seg = self._segments.pop(name, None)
+            self._order = None
             if seg is not None and self._refcounts.get(name, 0) > 0:
                 # unload-vs-in-flight-query race: a running query acquired
                 # this segment — yanking the device block now would fail its
@@ -111,8 +116,9 @@ class TableDataManager:
         were loaded in: the set the device pipeline stages and plans, in
         which time-pruned subsets are contiguous."""
         with self._lock:
-            return [self._segments[n]
-                    for n in sorted(self._segments, key=_push_order)]
+            if self._order is None:
+                self._order = sorted(self._segments, key=_push_order)
+            return [self._segments[n] for n in self._order]
 
     def refcount(self, name: str) -> int:
         """In-flight acquisitions of `name` — the tiering eviction loop's

@@ -50,7 +50,7 @@ class FakeMeshExec:
         self.fetch_started = threading.Event()
         self.recorded = {}        # stack_key -> what that launch "recorded"
 
-    def prepare_partial(self, ctx, segments):
+    def prepare_partial(self, ctx, segments, resident=None):
         self.prepared.append(ctx)
         if ctx.get("fallback"):
             return None
@@ -228,7 +228,7 @@ class _Boom(RuntimeError):
 
 
 class _RaisingPrepare(FakeMeshExec):
-    def prepare_partial(self, ctx, segments):
+    def prepare_partial(self, ctx, segments, resident=None):
         if ctx.get("boom"):
             raise _Boom("prepare")
         return super().prepare_partial(ctx, segments)
@@ -359,7 +359,7 @@ class StatsMeshExec(FakeMeshExec):
         super().__init__(fetch_latency=fetch_latency)
         self.prepare_s, self.launch_s = prepare_s, launch_s
 
-    def prepare_partial(self, ctx, segments):
+    def prepare_partial(self, ctx, segments, resident=None):
         time.sleep(self.prepare_s)
         p = super().prepare_partial(ctx, segments)
         if p is not None:
