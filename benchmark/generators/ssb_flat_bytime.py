@@ -9,7 +9,9 @@ range `[2406 * i // S, 2406 * (i + 1) // S)` of SSB's 2,406 days, S =
 `cfg["segments"]`. The first rows walk every brand and city, as there, and the
 segment's OWN days only: the INT date columns (`lo_orderdate`, `d_year`,
 `d_yearmonthnum`, `d_weeknuminyear`) then get per-segment dictionaries and
-real min/max from the builder. Imports numpy and the sibling generator only.
+real min/max from the builder. Imports numpy and the sibling generator; and
+`tables`, which a run calls first, asks the program's counter names whether it
+stages a server's RESIDENT segment set once (`needs_resident_set`).
 """
 
 import importlib.util
@@ -32,7 +34,27 @@ def _flat():
     return _FLAT
 
 
+def needs_resident_set() -> None:
+    """A clean failure, before anything is built, on a program that stages one
+    device block for every distinct subset of segments a query is routed to:
+    it cannot hold this deployment. The pool's 52 queries route 17 or 18
+    distinct subsets, 12.4-13.0 GB of blocks at 4,194,304 rows a segment,
+    beside the sort's temporaries on a chip of 16.9 GB: four seeds fitted at
+    14.1-14.7 GB, seed 895230935 ran the device out of memory in its window
+    (RESOURCE_EXHAUSTED; `correct` false with 2,014 device errors on the
+    driver's machine and 1,327 on a second run; PERF.md section 6, PR 32).
+    Told by the counter the resident-set executor brought: a light import
+    that does not open JAX."""
+    from pinot_tpu.query import stats
+    if "residentSlots" not in getattr(stats, "COUNTER_KEYS", ()):
+        raise SystemExit(
+            "ssb10-flat-bytime needs a server that stages its resident "
+            "segment set once (the program has no `residentSlots` counter): "
+            "one block a routed subset does not fit the chip on every seed")
+
+
 def tables(cfg) -> dict:
+    needs_resident_set()
     return _flat().tables(cfg)
 
 

@@ -76,6 +76,26 @@ def test_segment_holds_its_own_days_and_ssb_flats_other_columns(cell, gen, i):
         == ours["d_year"].tobytes()                   # the seed decides
 
 
+@pytest.mark.parametrize("keys,runs", [
+    (qstats.COUNTER_KEYS, True),
+    (tuple(k for k in qstats.COUNTER_KEYS if k != qstats.RESIDENT_SLOTS),
+     False),                     # the parent: one block a routed subset
+    (None, False)])
+def test_generator_fails_cleanly_on_a_program_without_the_resident_set(
+        cell, gen, monkeypatch, keys, runs):
+    if keys is None:
+        monkeypatch.delattr(qstats, "COUNTER_KEYS")
+    else:
+        monkeypatch.setattr(qstats, "COUNTER_KEYS", keys)
+    if runs:
+        assert gen.tables(cell["config"])
+        return
+    with pytest.raises(SystemExit) as exit_:
+        gen.tables(cell["config"])
+    assert exit_.value.code not in (0, None)      # an exit code other than 0
+    assert "residentSlots" in str(exit_.value.code)
+
+
 def test_configuration_is_ssb10_flat_pushed_by_time():
     flat = cells.read_json(cells.BENCH, "configs", "ssb10-flat.json")
     ours = cells.read_json(cells.BENCH, "configs", "ssb10-flat-bytime.json")
