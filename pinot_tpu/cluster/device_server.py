@@ -81,7 +81,7 @@ _STAGE_KEYS = (qstats.SET_BLOCKS_STAGED, qstats.SET_BLOCK_BYTES)
 #: which decode branch a sort-regime GROUP BY launch ran: known once its
 #: outputs are fetched (`qstats.decode_branch`), summed over the launches in
 #: `stats()`; the decode hook puts the same key on each answer's partial
-_DECODE_KEYS = (qstats.COMPACT_DECODE_LAUNCHES, qstats.DENSE_DECODE_LAUNCHES)
+_DECODE_KEYS = tuple(k for keys in qstats.DECODE_FLAGS.values() for k in keys)
 
 #: what the kernel cache, the first-call fence and the executor's launch
 #: accounting record on the dispatcher thread, folded from a scratch record
@@ -589,8 +589,7 @@ class DeviceQueryPipeline:
                     _resolve(item.future, None, exc=e)
             return
         for outs, group in zip(outs_list, groups):
-            took = qstats.decode_branch(outs)
-            if took:
+            for took in qstats.decode_branch(outs):
                 self.decodes[took] += 1
             for item, decode in group:
                 if item.future.done():

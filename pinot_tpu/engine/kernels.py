@@ -854,40 +854,77 @@ def _compact_decode(key_c: jnp.ndarray, vals_c, m, nseg: int, rows: int):
         put(jnp.zeros((nseg,), jnp.float32), t) for t in totals]
 
 
-def _decode_sorted(key_s, vals_s, nseg: int, pad: int, block: int, dense,
-                   took):
-    """The dense [nseg] answer of the sort regime from its sorted rows: `dense`
-    (the per-key decode, a binary search for every dense key) or, where few
-    rows passed the filter, `_compact_decode` over the sorted prefix: the
-    shortest of a short ladder of prefixes (COMPACT_RUNGS, then
-    `compact_cap`) that holds them. One HLO conditional on m, the count of
-    rows that passed, which only the device knows; one branch runs, and all
-    share the sort. Where `compact_cap` builds no branch the program is the
-    dense decode alone. `took` (a list, or None) collects the scalar that
-    says whether a compact branch ran."""
-    n = key_s.size
-    cap = compact_cap(n, nseg, block)
-    if not cap:
-        return dense()
-    rungs = tuple(c for c in COMPACT_RUNGS if 4 * c <= cap) + (cap,)
-    m = jnp.sum(key_s < nseg - 1, dtype=jnp.int32)
-
-    def compact(rows):
+def _compact_ladder(key_s, vals_s, m, nseg: int, rows: int, rungs, dense=None):
+    """`_compact_decode` over the shortest of the prefixes `rungs` of the
+    sorted rows that holds the `m` rows that passed; past the last of them
+    `dense` (where there is none the caller knows the last rung holds them).
+    One HLO conditional on m, which only the device knows; one branch runs,
+    and all share the sort."""
+    def compact(length):
         def branch():
             with jax.named_scope("pinot.groupby.partitioned.compact"):
-                return _compact_decode(key_s[:rows], [v[:rows] for v in vals_s],
-                                       m, nseg, n - pad)
+                return _compact_decode(key_s[:length],
+                                       [v[:length] for v in vals_s], m, nseg,
+                                       rows)
         return branch
 
     def dense_branch():
         with jax.named_scope("pinot.groupby.partitioned.dense"):
             return dense()
 
-    if took is not None:
-        took.append(m <= cap)
-    # the first rung that holds m rows; past the last one, the dense decode
-    rung = sum((m > c).astype(jnp.int32) for c in rungs)
-    return jax.lax.switch(rung, [compact(c) for c in rungs] + [dense_branch])
+    steps = rungs if dense else rungs[:-1]
+    rung = sum((m > c).astype(jnp.int32) for c in steps)
+    return jax.lax.switch(rung, [compact(c) for c in rungs]
+                          + ([dense_branch] if dense else []))
+
+
+def compact_rungs(cap: int):
+    """The prefixes the compact decode tries: COMPACT_RUNGS where they are at
+    most a quarter of `cap`, then `cap`."""
+    return tuple(c for c in COMPACT_RUNGS if 4 * c <= cap) + (cap,)
+
+
+# Rows a tile of the presort compaction; a tile keeps PRESORT_TILE / 64 slots
+# for its rows that passed, so the compacted rows are n / 64: `compact_cap`.
+# Q3.2, the densest SSB template, passes 1.64 rows a tile on average, and the
+# chance that any of 65,536 tiles of independent draws holds more than 16 is
+# about 2e-7. Chosen by PR 33's chip probe (PERF.md section 6); no
+# `KernelCaps` field, key or variable feeds it, tests patch it as they patch
+# SLAB_ROWS.
+PRESORT_TILE = 1024
+
+
+def _presort_compact(key_t, vals_t, live, nseg: int):
+    """The rows of every tile that passed the filter, moved into the tile's
+    own PRESORT_TILE / 64 slots in row order: `key_t` [T, B] int32, `vals_t`
+    f32 [T, B] each, `live` [T, B] (key < nseg - 1), at most that many live
+    rows a tile (the caller's `fits`). Returns the flat compacted key
+    [T * slots] (a slot no row took carries the overflow key nseg-1) and value
+    rows (zero there).
+
+    No n-row scatter and no flat scan. A row's slot is the count of live rows
+    before it in its tile: the 0/1 mask against a strict [B, B] triangle on
+    the MXU (bf16 operands, f32 accumulation: exact, every cell at most B).
+    Slot k's key and values are then one select and one row reduce over the
+    tile on the VPU: a slot receives one row, so an int32 key and an f32
+    value arrive as they were (no digits, no bf16 parts), and XLA fuses the
+    slots of one operand into one multi-output pass over it (PR 33's probe)."""
+    tile = key_t.shape[1]
+    slots = tile // 64
+    before = jnp.tri(tile, tile, -1, dtype=jnp.bfloat16).T      # [u, b]: u < b
+    slot = jnp.where(live, jax.lax.dot(
+        live.astype(jnp.bfloat16), before,
+        preferred_element_type=jnp.float32), slots)     # dead rows: no slot
+    over = nseg - 1
+    keys, vals = [], [[] for _ in vals_t]
+    for k in range(slots):
+        mine = slot == k
+        # less the overflow key, so that a slot no row took sums to it
+        keys.append(jnp.sum(jnp.where(mine, key_t - over, 0), axis=-1))
+        for out, v in zip(vals, vals_t):
+            out.append(jnp.sum(jnp.where(mine, v, 0.0), axis=-1))
+    return ((jnp.stack(keys, axis=-1) + over).reshape(-1),
+            [jnp.stack(v, axis=-1).reshape(-1) for v in vals])
 
 
 def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
@@ -911,77 +948,125 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
     either: `searchsorted` run boundaries give exact int32 counts and each
     key's first sorted position, from which (slab, local id, continuation
     chain) are pure gathers — two binary searches for EVERY dense key, so
-    where few rows passed the filter `_decode_sorted` answers from the sorted
-    prefix instead and none of the above runs. Value sums use the 3-part bf16
-    split (full f32 precision) with f32 accumulation.
+    where few rows passed the filter (at most `compact_cap`) the answer comes
+    from the sorted prefix of rows that passed instead (`_compact_ladder`)
+    and none of the above runs. Value sums use the 3-part bf16 split (full
+    f32 precision) with f32 accumulation.
+
+    Where the program has the compact decode at all (`compact_cap` not 0: else
+    it is the sort and the dense decode alone), one count over the key's
+    tiles of PRESORT_TILE rows comes first, and one HLO conditional on what it
+    found. If no tile holds more rows that passed than its slots
+    (`_presort_compact`), those rows are moved to the front of their tiles,
+    the n / 64 compacted rows are sorted in place of all n, and the compact
+    ladder answers from them: the rows keep their order, so the sums are the
+    full sort's to the bit. Otherwise (an unselective filter, or rows that
+    passed clustered in a few tiles) the full sort and its ladder run as
+    before: such a table costs what it did plus the one count. `took` (a
+    list, or None) collects the pair of scalars (a compact decode ran, the
+    compacted sort ran).
     Returns [int32 counts[nseg], f32 sums[nseg]...].
     """
-    with jax.named_scope("pinot.groupby.partitioned.sort"):
-        key_s, vals_s, pad = _sort_by_key(key, nseg, value_rows, block)
+    rows = key.size
+    cap = compact_cap(rows + (-rows) % block, nseg, block)
+
+    def full(m=None):
+        with jax.named_scope("pinot.groupby.partitioned.sort"):
+            key_s, vals_s, pad = _sort_by_key(key, nseg, value_rows, block)
+        dense = lambda: _dense_decode(key_s, vals_s, nseg, pad, block)  # noqa: E731
+        if m is None:
+            return dense()
+        return _compact_ladder(key_s, vals_s, m, nseg, rows,
+                               compact_rungs(cap), dense)
+
+    if not cap:
+        return full()
+    with jax.named_scope("pinot.groupby.partitioned.presort"):
+        short = (-rows) % PRESORT_TILE
+        key_t = jnp.pad(key, (0, short), constant_values=nseg - 1).reshape(
+            -1, PRESORT_TILE)
+        live = key_t < nseg - 1
+        passed = jnp.sum(live, axis=-1, dtype=jnp.int32)         # a tile
+        m = jnp.sum(passed)
+        fits = jnp.max(passed) <= PRESORT_TILE // 64
+
+    def presorted():
+        with jax.named_scope("pinot.groupby.partitioned.presort"):
+            key_c, vals_c = _presort_compact(
+                key_t, [jnp.pad(v, (0, short)).reshape(key_t.shape)
+                        for v in value_rows], live, nseg)
+            key_s, vals_s, _ = _sort_by_key(key_c, nseg, vals_c, 1)
+        return _compact_ladder(key_s, vals_s, m, nseg, rows,
+                               compact_rungs(key_s.size))
+
+    if took is not None:
+        took.append((fits | (m <= cap), fits))
+    return jax.lax.cond(fits, presorted, lambda: full(m))
+
+
+def _dense_decode(key_s, vals_s, nseg: int, pad: int, block: int):
+    """The per-key decode of the sorted rows (`_grouped_partitioned`)."""
     n = key_s.size
     nb = n // block
-
-    def dense():
-        with jax.named_scope("pinot.groupby.partitioned.trim"):
-            left, counts = _counts_from_sorted(key_s, nseg, pad)
-        outs = [counts]
-        if not vals_s:
-            return outs
-        with jax.named_scope("pinot.groupby.partitioned.scan"):
-            head = jnp.concatenate([jnp.ones((1,), bool),
-                                    key_s[1:] != key_s[:-1]])
-            rank = jnp.cumsum(head.astype(jnp.int32)) - 1       # nondecreasing
-            rank_start = rank.reshape(nb, block)[:, 0]          # [nb]
-            j = rank.reshape(nb, block) - rank_start[:, None]   # local id < block
-            bf = jnp.bfloat16
-            oh_hi = jax.nn.one_hot(j // 64, block // 64, dtype=bf)  # [nb, block, B/64]
-            oh_lo = jax.nn.one_hot(j % 64, 64, dtype=bf)            # [nb, block, 64]
-            dot = lambda a, b: jax.lax.dot_general(             # noqa: E731
-                a, b, (((1,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)
-            local = []
-            for v in vals_s:
-                s = None
-                for part in _bf16_parts(v.reshape(nb, block)):
-                    d = dot(oh_hi, part[:, :, None] * oh_lo)    # [nb, B/64, 64]
-                    s = d if s is None else s + d
-                local.append(s.reshape(nb, block))              # sums per (slab, j)
-            # stitch slab-spanning groups: a group continuing into slab b sits at
-            # local id 0 there, so a segmented scan over local[:, 0] (heads where
-            # rank_start changes) accumulates each continuation chain
-            heads_b = jnp.concatenate([jnp.ones((1,), bool),
-                                       rank_start[1:] != rank_start[:-1]])
-            slab0 = jnp.stack([l[:, 0] for l in local])         # [R, nb]
-            flags = jnp.broadcast_to(heads_b[None, :], slab0.shape)
-            _, chain = jax.lax.associative_scan(_seg_sum_op, (flags, slab0),
-                                                axis=1)
-        # dense decode: each key's first sorted row -> (slab g0, local id j0); the
-        # last slab of its chain is the last rank_start <= its rank
-        with jax.named_scope("pinot.groupby.partitioned.trim"):
-            p = jnp.minimum(left[:-1], n - 1)
-            r = rank[p]
-            g0 = p // block
-            j0 = r - rank_start[g0]
-            g1 = jnp.searchsorted(rank_start, r, side="right") - 1
-            occ = counts > 0
-            for li, ci in zip(local, chain):
-                start = li[g0, j0]
-                tail = ci[g1]
-                # j0 == 0: the chain includes slab g0 itself; otherwise the chain
-                # (if any: g1 > g0) covers only the continuation slabs after g0
-                total = jnp.where(j0 == 0, tail,
-                                  start + jnp.where(g1 > g0, tail, 0.0))
-                outs.append(jnp.where(occ, total, 0.0))
+    with jax.named_scope("pinot.groupby.partitioned.trim"):
+        left, counts = _counts_from_sorted(key_s, nseg, pad)
+    outs = [counts]
+    if not vals_s:
         return outs
-
-    return _decode_sorted(key_s, vals_s, nseg, pad, block, dense, took)
+    with jax.named_scope("pinot.groupby.partitioned.scan"):
+        head = jnp.concatenate([jnp.ones((1,), bool),
+                                key_s[1:] != key_s[:-1]])
+        rank = jnp.cumsum(head.astype(jnp.int32)) - 1       # nondecreasing
+        rank_start = rank.reshape(nb, block)[:, 0]          # [nb]
+        j = rank.reshape(nb, block) - rank_start[:, None]   # local id < block
+        bf = jnp.bfloat16
+        oh_hi = jax.nn.one_hot(j // 64, block // 64, dtype=bf)  # [nb, block, B/64]
+        oh_lo = jax.nn.one_hot(j % 64, 64, dtype=bf)            # [nb, block, 64]
+        dot = lambda a, b: jax.lax.dot_general(             # noqa: E731
+            a, b, (((1,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        local = []
+        for v in vals_s:
+            s = None
+            for part in _bf16_parts(v.reshape(nb, block)):
+                d = dot(oh_hi, part[:, :, None] * oh_lo)    # [nb, B/64, 64]
+                s = d if s is None else s + d
+            local.append(s.reshape(nb, block))              # sums per (slab, j)
+        # stitch slab-spanning groups: a group continuing into slab b sits at
+        # local id 0 there, so a segmented scan over local[:, 0] (heads where
+        # rank_start changes) accumulates each continuation chain
+        heads_b = jnp.concatenate([jnp.ones((1,), bool),
+                                   rank_start[1:] != rank_start[:-1]])
+        slab0 = jnp.stack([l[:, 0] for l in local])         # [R, nb]
+        flags = jnp.broadcast_to(heads_b[None, :], slab0.shape)
+        _, chain = jax.lax.associative_scan(_seg_sum_op, (flags, slab0),
+                                            axis=1)
+    # dense decode: each key's first sorted row -> (slab g0, local id j0); the
+    # last slab of its chain is the last rank_start <= its rank
+    with jax.named_scope("pinot.groupby.partitioned.trim"):
+        p = jnp.minimum(left[:-1], n - 1)
+        r = rank[p]
+        g0 = p // block
+        j0 = r - rank_start[g0]
+        g1 = jnp.searchsorted(rank_start, r, side="right") - 1
+        occ = counts > 0
+        for li, ci in zip(local, chain):
+            start = li[g0, j0]
+            tail = ci[g1]
+            # j0 == 0: the chain includes slab g0 itself; otherwise the chain
+            # (if any: g1 > g0) covers only the continuation slabs after g0
+            total = jnp.where(j0 == 0, tail,
+                              start + jnp.where(g1 > g0, tail, 0.0))
+            outs.append(jnp.where(occ, total, 0.0))
+    return outs
 
 
 def combine_collective(name: str, v, axis: str):
     """The cross-device combine for one kernel output: partials agree on dense keys
     (aligned dictionaries), so one ICI collective merges them."""
-    # the decode flag: a launch took the compact decode only if every chip did
-    if name.endswith((".min", ".max")) or name == qstats.COMPACT_FLAG:
+    # the decode flags: a launch took the compact decode, or the compacted
+    # sort, only if every chip did
+    if name.endswith((".min", ".max")) or name in qstats.DECODE_FLAGS:
         with jax.named_scope("pinot.collective.minmax"):
             return (jax.lax.pmax if name.endswith(".max")
                     else jax.lax.pmin)(v, axis)
@@ -1057,7 +1142,7 @@ def _make_body(spec: KernelSpec):
         mask = mask_fn(ids, vals, luts, iscal, fscal, nulls, valid, docsets,
                        bitmaps)
         out: Dict[str, jnp.ndarray] = {}
-        took: list = []  # which decode branch each sort regime of the scan ran
+        took: list = []  # which decode and sort each sort regime of the scan ran
 
         if group:
             with scope("pinot.groupby.key"):
@@ -1126,9 +1211,9 @@ def _make_body(spec: KernelSpec):
                               else jax.ops.segment_max)
                         out[name] = op(v, key, num_segments=num_seg)
             if took:
-                # compact only if every sort regime of the scan took it
-                out[qstats.COMPACT_FLAG] = jnp.all(
-                    jnp.stack(took)).astype(jnp.int32)
+                # compact, presorted: only if every sort regime of the scan was
+                for flag, each in zip(qstats.DECODE_FLAGS, zip(*took)):
+                    out[flag] = jnp.all(jnp.stack(each)).astype(jnp.int32)
         else:
             with scope("pinot.agg"):
                 fmask = mask.ravel().astype(jnp.float32)
@@ -1215,9 +1300,8 @@ def run_kernel(spec: KernelSpec, inputs: KernelInputs) -> Dict[str, np.ndarray]:
 
 
 def _record_decode(outs):
-    """Count which decode branch a fetched sort-regime launch ran."""
-    took = qstats.decode_branch(outs)
-    if took:
+    """Count which decode and which sort a fetched sort-regime launch ran."""
+    for took in qstats.decode_branch(outs):
         qstats.record(took)
     return outs
 

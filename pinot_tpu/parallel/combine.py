@@ -950,14 +950,14 @@ class MeshQueryExecutor:
             if skewed:
                 st[qstats.DEVICE_SKEW_PCT] = max(
                     st.get(qstats.DEVICE_SKEW_PCT, 0.0), block.skew_pct)
-            if took:
-                st[took] = st.get(took, 0) + 1
+            for key in took:
+                st[key] = st.get(key, 0) + 1
             res.stats = st
         else:
             if skewed:
                 qstats.record_max(qstats.DEVICE_SKEW_PCT, block.skew_pct)
-            if took:
-                qstats.record(took)
+            for key in took:
+                qstats.record(key)
         return res
 
     def _dispatch_sharded(self, ctx: QueryContext, plan, segments, view=None,

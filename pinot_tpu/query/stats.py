@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import threading
 from contextlib import contextmanager
-from typing import Dict, Iterator, Optional
+from typing import Dict, Iterator, Optional, Tuple
 
 # -- well-known stats keys ---------------------------------------------------
 # Every key the executor/broker can emit into `QueryResult.stats`, with the
@@ -92,6 +92,15 @@ DENSE_DECODE_LAUNCHES = "denseDecodeLaunches"
 # graftcheck: ignore[drift-stats-keys] -- the kernel OUTPUT's name (read by
 # decode_branch below), never a key of a stats record
 COMPACT_FLAG = "decode.compact"
+# which SORT such a launch ran (PR 33): the rows that passed the filter,
+# compacted tile by tile in front of the sort (n / 64 rows sorted), or every
+# row. The scalar PRESORT_FLAG rides with COMPACT_FLAG (1 only if every sort
+# regime of the scan, on every chip, sorted the compacted rows); a launch that
+# did also counts as a compact decode
+PRESORT_COMPACT_LAUNCHES = "presortCompactLaunches"
+FULL_SORT_LAUNCHES = "fullSortLaunches"
+# graftcheck: ignore[drift-stats-keys] -- a kernel OUTPUT's name, as above
+PRESORT_FLAG = "decode.presort"
 NUM_CONSUMING_SEGMENTS_QUERIED = "numConsumingSegmentsQueried"
 MIN_CONSUMING_FRESHNESS_TIME_MS = "minConsumingFreshnessTimeMs"
 MUX_FRAME_QUEUE_MS = "muxFrameQueueMs"
@@ -156,6 +165,7 @@ COUNTER_KEYS = (
     DEVICE_DECODE_MS, DEDUPED_LAUNCHES, STACKED_LAUNCHES,
     FUSED_LAUNCHES, STAGED_LAUNCHES, GATHER_FREE_LAUNCHES, SLABBED_LAUNCHES,
     COMPACT_DECODE_LAUNCHES, DENSE_DECODE_LAUNCHES,
+    PRESORT_COMPACT_LAUNCHES, FULL_SORT_LAUNCHES,
     NUM_CONSUMING_SEGMENTS_QUERIED, MUX_FRAME_QUEUE_MS, MUX_FLOW_CONTROL_MS,
     MESH_LAUNCHES, SCATTER_LAUNCHES, COLLECTIVE_BYTES,
     ROUTED_SLOTS, RESIDENT_SLOTS, SCANNED_SLOTS, MERGED_LAUNCHES,
@@ -321,14 +331,22 @@ def record(key: str, n: float = 1) -> None:
         st.add(key, n)
 
 
-def decode_branch(outs) -> Optional[str]:
-    """The counter one launch's fetched outputs add to: which decode branch
-    its sort regimes ran (COMPACT_FLAG), or None for a program without the
-    branch (and for whatever a test's fake executor hands back)."""
-    flag = outs.get(COMPACT_FLAG) if isinstance(outs, dict) else None
-    if flag is None:
-        return None
-    return COMPACT_DECODE_LAUNCHES if int(flag) else DENSE_DECODE_LAUNCHES
+#: a sort-regime launch's flag -> the counter it adds to when set, when not
+DECODE_FLAGS = {
+    COMPACT_FLAG: (COMPACT_DECODE_LAUNCHES, DENSE_DECODE_LAUNCHES),
+    PRESORT_FLAG: (PRESORT_COMPACT_LAUNCHES, FULL_SORT_LAUNCHES),
+}
+
+
+def decode_branch(outs) -> Tuple[str, ...]:
+    """The counters one launch's fetched outputs add to: which decode branch
+    and which sort its sort regimes ran (DECODE_FLAGS), or none for a program
+    without the branches (and for whatever a test's fake executor hands
+    back)."""
+    if not isinstance(outs, dict):
+        return ()
+    return tuple(keys[0] if int(outs[flag]) else keys[1]
+                 for flag, keys in DECODE_FLAGS.items() if flag in outs)
 
 
 def record_min(key: str, v: float) -> None:

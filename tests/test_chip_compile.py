@@ -185,12 +185,18 @@ def test_served_agg_kernel_compiles_for_v5e(topo, cpu_exec, segments, name,
         assert f"[{SMOKE_SEGS * SEG_ROWS}]" not in "".join(
             ln for ln in text.splitlines() if " convolution(" in ln)
     if sort_regime:
-        # 500k keys: the program holds both decodes of its sorted rows under
-        # one conditional (PR 29), and the dense one's searches stay inside it
+        # 500k keys: one conditional at the top holds both sorts (PR 33): the
+        # rows that passed, compacted tile by tile (n / 64 rows), or all of
+        # them, whose branch holds both decodes of the sorted rows (PR 29)
         text = compiled.as_text()
-        assert " conditional(" in text
-        for branch in ("compact", "dense"):
-            assert f"pinot.groupby.partitioned.{branch}" in text, branch
+        entry = text[text.index("ENTRY"):]
+        assert entry.count(" conditional(") == 1 and " sort(" not in entry
+        for scope in ("presort", "sort", "compact", "dense"):
+            assert f"pinot.groupby.partitioned.{scope}/" in text, scope
+        rows = segs * SEG_ROWS
+        sorts = [ln for ln in text.splitlines() if " sort(" in ln]
+        assert {n for n in (rows, rows // 64)
+                if any(f"[{n}]" in ln for ln in sorts)} == {rows, rows // 64}
 
 
 # (smoke query, the window's slots of the 16 resident, sort regime)
@@ -222,7 +228,11 @@ def test_routed_window_program_compiles_for_v5e(topo, cpu_exec, segments,
     assert "pinot.route" in text
     if sort_regime:
         sorts = [ln for ln in text.splitlines() if " sort(" in ln]
-        assert sorts and all(f"[{rows}]" in ln for ln in sorts)
+        # the full sort over the window's rows, the compacted one over a
+        # 64th of them (PR 33)
+        assert sorts and all(f"[{rows}]" in ln or f"[{rows // 64}]" in ln
+                             for ln in sorts)
+        assert any(f"[{rows // 64}]" in ln for ln in sorts)
         assert not any(f"[{SMOKE_SEGS * SEG_ROWS}]" in ln for ln in sorts)
     elif "group-by" in name:
         assert (" while(" in text) == (rows > kernels.SLAB_ROWS)

@@ -528,15 +528,19 @@ def test_compact_ladder_takes_the_shortest_prefix_that_fits(monkeypatch):
     run = jax.jit(lambda k, v: (fn(k, nseg, [v], block, took), took[-1]))
     jaxpr = str(jax.make_jaxpr(lambda k, v: fn(k, nseg, [v], block))(
         np.zeros(n, np.int32), np.zeros(n, np.float32)))
-    assert jaxpr.count("branches=") == 1        # one conditional, four ways
+    # one conditional on what the tiles hold, and a ladder each side of it:
+    # three ways over the compacted rows, four over all of them
+    assert jaxpr.count("branches=") == 3
     rng = np.random.default_rng(9)
     for m in (0, 1, 16, 17, 64, 65, 256, 257, n):
         key = np.full(n, nseg - 1, np.int32)
         key[rng.choice(n, m, replace=False)] = rng.integers(0, 3, m) * 100
         v = np.where(key < nseg - 1, rng.uniform(-500, 500, n),
                      0).astype(np.float32)
-        (counts, sums), compact = run(key, v)
+        (counts, sums), (compact, presorted) = run(key, v)
         assert bool(compact) == (m <= 256), m
+        in_a_tile = (key < nseg - 1).reshape(-1, kernels.PRESORT_TILE).sum(1)
+        assert bool(presorted) == (in_a_tile.max() <= 16), m
         assert np.array_equal(np.asarray(counts),
                               np.bincount(key, minlength=nseg)), m
         np.testing.assert_allclose(
@@ -591,6 +595,222 @@ def test_one_chip_dense_three_compact_on_the_mesh(tmp_path_factory):
                             "denseDecodeLaunches": 1 - compact}, bound
     finally:
         set_caps(prev)
+
+
+# --- the compacted sort in front of the sort regime (PR 33) ------------------
+# One segment of 12,000 rows (16,384 padded on one device); `pos` numbers the
+# rows and `s` is pos % 64, so `s = 0` passes one row in 64: exactly the slots
+# a tile keeps, in every tile, whatever the tile's length.
+SORT_KEYS = ("presortCompactLaunches", "fullSortLaunches")
+
+
+def _presort_cols(rng, rows, per_segment):
+    v = np.round(rng.uniform(-500, 500, rows), 3).astype(object)
+    v[rng.random(rows) < 0.02] = None          # a table with nulls
+    pos = np.arange(rows, dtype=np.int32)
+    return {"k": rng.integers(0, 6000, rows).astype(np.int32), "pos": pos,
+            "s": (pos % per_segment % 64).astype(np.int32), "v": v,
+            "q": rng.integers(0, 1 << 30, rows).astype(np.int32)}
+
+
+def _presort_segments(tmp_path_factory, name, rows, segments):
+    schema = Schema(name, [dimension("k", DataType.INT),
+                           metric("pos", DataType.INT),
+                           metric("s", DataType.INT),
+                           metric("v", DataType.DOUBLE),
+                           metric("q", DataType.INT)])
+    cols = _presort_cols(np.random.default_rng(33), rows, rows // segments)
+    cfg = SegmentGeneratorConfig(no_dictionary_columns=["pos", "s", "v", "q"])
+    return [load_segment(p) for p in build_aligned_segments(
+        schema, cols, str(tmp_path_factory.mktemp(name)), name, segments,
+        config=cfg)]
+
+
+@pytest.fixture(scope="module")
+def presort_segment(tmp_path_factory):
+    return _presort_segments(tmp_path_factory, "ps", COMPACT_ROWS, 1)[0]
+
+
+def _patch_presort_tile(monkeypatch, tile):
+    """Programs built from here on compact tiles of `tile` rows."""
+    from pinot_tpu.engine import kernels
+    from pinot_tpu.parallel import combine
+    monkeypatch.setattr(kernels, "PRESORT_TILE", tile)
+    monkeypatch.setattr(kernels, "_KERNEL_CACHE", {})
+    monkeypatch.setattr(combine, "_SHARD_KERNEL_CACHE", {})
+
+
+def _sorts_and_decodes(mex, segs, sql):
+    """(rows, the four counters the sort regime's launch recorded)."""
+    from pinot_tpu.query import stats as qstats
+    with qstats.collect_stats() as st:
+        rows = mex.execute(segs, sql).rows
+    return rows, {k: int(st.counters.get(k, 0)) for k in (
+        "compactDecodeLaunches", "denseDecodeLaunches") + SORT_KEYS}
+
+
+# passing -> (WHERE, rows that pass, the tiles hold them in their slots)
+PRESORT_CASES = {
+    "0": (None, 0, True),
+    "1": ("pos < 1", 1, True),
+    "slots-a-tile": ("s = 0", COMPACT_ROWS // 64 + 1, True),
+    "slots+1-in-one-tile": ("s = 0 OR pos = 1", COMPACT_ROWS // 64 + 2, False),
+    "all-in-one-tile": ("pos < 200", 200, False),
+    "all": (f"pos < {COMPACT_ROWS}", COMPACT_ROWS, False),
+}
+
+
+@pytest.mark.parametrize("tile", [256, 320], ids=["aligned", "ragged"])
+@pytest.mark.parametrize("passing", sorted(PRESORT_CASES))
+@pytest.mark.parametrize("aggs", ["COUNT(*)", "COUNT(*), SUM(v), SUM(q)"],
+                         ids=["count", "sums"])
+def test_presorted_rows_answer_as_the_full_sort_and_the_host(
+        presort_segment, monkeypatch, aggs, passing, tile):
+    """The compacted sort against the host executor and against a build with
+    the full sort alone, each side of what a tile's slots hold: no row, one,
+    exactly the slots in every tile, one row more in one tile (falls back),
+    every passing row in one tile (falls back), every row (falls back, and
+    decodes per key). 16,384 padded rows are 64 tiles of 256 rows and 64 rows
+    short of 52 tiles of 320 (the count pads them); a 320-row tile keeps 5
+    slots, a 256-row one 4. With COUNT(*) alone no value row is moved."""
+    from pinot_tpu.engine import kernels
+    where, m, fits = PRESORT_CASES[passing]
+    where = where or ("pos < 1 AND q > %d" % int(
+        np.asarray(presort_segment.column("q").values())[0]))
+    sql = (f"SELECT k, {aggs} FROM ps WHERE {where} "
+           "GROUP BY k ORDER BY k LIMIT 3000000")
+    cap = kernels.compact_cap(16_384, 8193, 4096)
+    assert cap == 256
+    mex = MeshQueryExecutor(default_mesh(1))
+    prev = get_caps()
+    set_caps(KernelCaps(chunk_cap=4096))
+    try:
+        _patch_presort_tile(monkeypatch, tile)
+        got, took = _sorts_and_decodes(mex, [presort_segment], sql)
+        want = ServerQueryExecutor(use_device=False).execute(
+            [presort_segment], sql).rows
+        _dense_only(monkeypatch)
+        full, neither = _sorts_and_decodes(mex, [presort_segment], sql)
+    finally:
+        set_caps(prev)
+    assert sum(r[1] for r in want) == m
+    assert took == {"presortCompactLaunches": int(fits),
+                    "fullSortLaunches": int(not fits),
+                    "compactDecodeLaunches": int(m <= cap),
+                    "denseDecodeLaunches": int(m > cap)}
+    assert not any(neither.values())
+    _assert_rows_close(got, want, (aggs, passing, tile))
+    assert [r[:2] for r in got] == [r[:2] for r in full]
+    _assert_rows_close(got, full, (aggs, passing, tile, "full sort"))
+
+
+def test_presorted_sums_are_the_full_sorts_to_the_bit(monkeypatch):
+    """Contiguous tiles and slots in row order keep the passing rows' order,
+    and the sort is stable: the compacted sort hands the compact decode the
+    rows the full sort would, so the f32 sums are equal bit for bit. A
+    64-row tile keeps one slot, so the same rows fall back there."""
+    import jax
+    from pinot_tpu.engine import kernels
+    n, nseg, block = 16_384, 8193, 4096
+    rng = np.random.default_rng(33)
+    key = np.full(n, nseg - 1, np.int32)
+    rows = rng.choice(n, 60, replace=False)     # about 4 a 1,024-row tile
+    key[rows] = rng.integers(0, 12, 60) * 100
+    v = np.where(key < nseg - 1, rng.uniform(-500, 500, n), 0).astype(np.float32)
+    answers = {}
+    for tile in (1024, 64):
+        monkeypatch.setattr(kernels, "PRESORT_TILE", tile)
+        took = []
+        outs, flags = jax.jit(lambda k, x: (kernels._grouped_partitioned(
+            k, nseg, [x], block, took), took[-1]))(key, v)
+        in_a_tile = (key < nseg - 1).reshape(-1, tile).sum(1).max()
+        assert (tile == 1024) == (in_a_tile <= tile // 64)
+        assert [bool(f) for f in flags] == [True, tile == 1024]
+        answers[tile] = [np.asarray(o) for o in outs]
+    assert np.array_equal(answers[1024][0], np.bincount(key, minlength=nseg))
+    assert answers[1024][1].tobytes() == answers[64][1].tobytes()
+
+
+@pytest.mark.parametrize("where,m,fits", [
+    ("s = 0", 6 * 32, True), ("pos >= 2048 AND pos < 2148", 100, False)],
+    ids=["slots-a-tile", "all-in-one-tile"])
+def test_presort_over_a_routed_window_of_six_slots(tmp_path_factory,
+                                                   monkeypatch, where, m, fits):
+    """Eight resident segments of 2,048 rows, six routed: on a mesh of one the
+    launch reads a window of 6 slots, 12,288 rows that are 48 tiles of 256
+    rows; the tile view holds for any slot count, and the answer is the six
+    segments' alone."""
+    import jax
+    from pinot_tpu.query import stats as qstats
+    from pinot_tpu.query.aggregates import make_agg
+    from pinot_tpu.query.context import compile_query
+    from pinot_tpu.query.reduce import merge_segment_results, reduce_to_result
+    segs = _presort_segments(tmp_path_factory, "pw", 8 * 2048, 8)
+    routed = segs[1:7]
+    sql = (f"SELECT k, COUNT(*), SUM(v) FROM pw WHERE {where} "
+           "GROUP BY k ORDER BY k LIMIT 3000000")
+    ctx = compile_query(sql, segs[0].schema)
+    mex = MeshQueryExecutor(default_mesh(1))
+    prev = get_caps()
+    set_caps(KernelCaps(chunk_cap=4096))
+    try:
+        _patch_presort_tile(monkeypatch, 256)
+        p = mex.prepare_partial(ctx, routed, segs)
+        assert p is not None and p.window == 6
+        (outs, finish, _, recorded), = mex.dispatch_prepared([p])
+        fetched = finish(jax.device_get(outs))[0]
+    finally:
+        set_caps(prev)
+    assert recorded[qstats.SCANNED_SLOTS] == 6
+    assert set(qstats.decode_branch(fetched)) == {
+        "compactDecodeLaunches",
+        "presortCompactLaunches" if fits else "fullSortLaunches"}
+    aggs = [make_agg(f) for f in ctx.aggregations]
+    got = reduce_to_result(ctx, merge_segment_results([p.decode(fetched)], aggs),
+                           aggs, list(ctx.group_by)).rows
+    want = ServerQueryExecutor(use_device=False).execute(routed, sql).rows
+    assert sum(r[1] for r in want) == m
+    _assert_rows_close(got, want, where)
+
+
+@pytest.mark.parametrize("where,compact,presorted", [
+    ("w < 100", 0, 0),    # one chip sorts every row and decodes per key
+    ("w < 10", 1, 0),     # 40 rows in one tile of one chip: it falls back
+    ("w = 500", 1, 1),    # about 16 rows of three chips, none of the fourth
+], ids=["one-dense", "one-clustered", "all-compact"])
+def test_one_chip_sorts_every_row_three_compact_on_the_mesh(
+        tmp_path_factory, where, compact, presorted):
+    """Four devices, a segment each of 8,000 rows (8 tiles of 1,024 rows and
+    16 slots). Each chip takes its own branch from its own tiles' counts, the
+    answer equals the host's, and the launch counts under the compacted sort
+    only if every chip took it."""
+    schema, cols = one_full_quarter("pm")
+    cfg = SegmentGeneratorConfig(no_dictionary_columns=["w", "v"])
+    segs = [load_segment(p) for p in build_aligned_segments(
+        schema, cols, str(tmp_path_factory.mktemp("pm")), "pm", 4,
+        config=cfg)]
+    sql = (f"SELECT k, COUNT(*), SUM(v) FROM pm WHERE {where} "
+           "GROUP BY k ORDER BY k LIMIT 3000000")
+    prev = get_caps()
+    set_caps(KernelCaps(chunk_cap=4096))
+    try:
+        got, took = _sorts_and_decodes(MeshQueryExecutor(default_mesh(4)),
+                                       segs, sql)
+    finally:
+        set_caps(prev)
+    w = cols["w"].reshape(4, -1)
+    passed = {"w < 100": w < 100, "w < 10": w < 10, "w = 500": w == 500}[where]
+    in_a_tile = np.pad(passed, ((0, 0), (0, 192))).reshape(4, 8, 1024).sum(-1)
+    assert list((in_a_tile <= 16).all(axis=1)) == {
+        "w < 100": [True, False, True, True],
+        "w < 10": [True, False, True, True]}.get(where, [True] * 4)
+    assert sum(r[1] for r in got) == passed.sum() > 0
+    _assert_rows_close(got, ServerQueryExecutor(use_device=False).execute(
+        segs, sql).rows, where)
+    assert took == {"compactDecodeLaunches": compact,
+                    "denseDecodeLaunches": 1 - compact,
+                    "presortCompactLaunches": presorted,
+                    "fullSortLaunches": 1 - presorted}, where
 
 
 def _guaranteed_card_keys(rng, card, rows):
@@ -727,6 +947,39 @@ def _scatter_update_rows(jaxpr):
     return sizes
 
 
+def _flat_scans(jaxpr):
+    """(primitive, elements along the scanned axis) of every cumulative
+    primitive in a jaxpr, nested jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("cum"):
+            aval = eqn.invars[0].aval
+            axis = eqn.params.get("axis", 0)
+            found.append((eqn.primitive.name, int(aval.shape[axis])))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found.extend(_flat_scans(sub))
+    return found
+
+
+def _presort_branch(jaxpr):
+    """The jaxpr of the compacted sort's branch: the true side of the one
+    two-way conditional at the top of the sort regime."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond" and len(eqn.params["branches"]) == 2:
+            return eqn.params["branches"][1].jaxpr
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found = _presort_branch(sub)
+                    if found is not None:
+                        return found
+    return None
+
+
 @pytest.mark.slow
 def test_no_flat_scatter_at_high_card(tmp_path_factory):
     """Regression guard: the >=128k-group count+sum kernel must never lower
@@ -777,6 +1030,16 @@ def test_no_flat_scatter_at_high_card(tmp_path_factory):
     sizes = _scatter_update_rows(jaxpr.jaxpr)
     assert sizes and set(sizes) == {cap, 1}, sizes   # 1: the overflow bucket
     assert cap <= n // 64
+    # the compacted sort's branch (PR 33) moves the rows that passed with no
+    # n-row scatter and no flat scan: nothing cumulative over n rows or over
+    # its n / PRESORT_TILE tiles (the full sort's per-key decode, the other
+    # side of the conditional, keeps its `cumsum` of run heads over n)
+    short = _presort_branch(jaxpr.jaxpr)
+    assert short is not None
+    assert set(_scatter_update_rows(short)) == {cap, 1}
+    assert not [s for s in _flat_scans(short)
+                if s[1] >= n // kernels.PRESORT_TILE], _flat_scans(short)
+    assert ("cumsum", n) in _flat_scans(jaxpr.jaxpr)
 
 
 # --- one place chooses the kernel: the ladder at the shipped constants -------
@@ -858,7 +1121,9 @@ LADDER = [
     pytest.param(_DISTINCT, 15, 16_384, 8192, "pinot.distinct/dot_general",
                  ("partitioned",), id="distinct-product-at-chunk_cap"),
     pytest.param(_DISTINCT, 16, 16_384, 8192,
-                 "pinot.distinct/pinot.groupby.partitioned.sort",
+                 # the full sort, beside the compacted one (PR 33)
+                 "pinot.distinct/cond/branch_0_fun/"
+                 "pinot.groupby.partitioned.sort",
                  ("pinot.distinct/dot_general",),
                  id="distinct-product-past-chunk_cap"),
     # ... at any row count (an f32 presence cell counts one slab's rows)

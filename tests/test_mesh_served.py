@@ -34,6 +34,8 @@ MESH_KEYS = ("meshLaunches", "scatterLaunches", "collectiveBytes")
 # sort, whose program holds both decodes (PR 29); the filters leave a few rows
 WIDE_KEY = ("q3.2", "q3.3", "q3.4", "q4.3")
 DECODE_KEYS = ("compactDecodeLaunches", "denseDecodeLaunches")
+# ... and both sorts (PR 33): the rows that passed, compacted tile by tile, or all
+SORT_KEYS = ("presortCompactLaunches", "fullSortLaunches")
 
 
 def _serve_and_ask(work, config, mesh_devices, seg_src, pool):
@@ -142,6 +144,32 @@ def test_wide_key_templates_say_which_decode_ran(served, template):
     took = {t: DECODE_KEYS[served[4][0][t]["denseDecodeLaunches"]]
             for t in WIDE_KEY}
     assert set(took.values()) == set(DECODE_KEYS), took
+
+
+@pytest.mark.parametrize("template", TEMPLATES)
+def test_wide_key_templates_say_which_sort_ran(served, template):
+    """A wide-key template's launch counts in exactly one of the two sort
+    counters, on a mesh of four and of one, and a launch that sorted the
+    compacted rows decoded them compactly (16,384 rows a device are 16 tiles
+    of 16 slots: the templates' filters fall on both sides, and `correct`
+    above holds for both); a template that takes no sort regime counts in
+    neither. `/health` sums what the answers said."""
+    for n in (4, 1):
+        resp = served[n][0][template]
+        assert sum(resp[k] for k in SORT_KEYS) == \
+            int(template in WIDE_KEY), (n, template)
+        assert resp["presortCompactLaunches"] <= resp["compactDecodeLaunches"]
+    if template == TEMPLATES[0]:
+        for n in (4, 1):
+            answers, before, after = served[n]
+            for k in SORT_KEYS:
+                assert after[k] - before[k] == \
+                    sum(a[k] for a in answers.values()), (n, k)
+            assert sum(after[k] - before[k] for k in SORT_KEYS) \
+                == len(WIDE_KEY)
+        took = {SORT_KEYS[served[n][0][t]["fullSortLaunches"]]
+                for t in WIDE_KEY for n in (4, 1)}
+        assert took == set(SORT_KEYS), took
 
 
 def test_health_sums_what_the_answers_said(served):

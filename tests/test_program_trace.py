@@ -30,6 +30,7 @@ MESH_METRICS = (
     "mesh.devices_busy", "kernels.collective_share",
     "mesh.scatter_launch_share", "mesh.collective_bytes_per_answer")
 DECODE_METRIC = "kernels.compact_decode_share"      # PR 29, every cell
+PRESORT_METRIC = "kernels.presort_compact_share"    # PR 33, every cell
 
 
 @pytest.fixture(scope="module")
@@ -74,10 +75,11 @@ def test_reader_returns_none_on_the_parents_run(name):
 
 
 @pytest.mark.parametrize("name",
-                         NEW_METRICS + MESH_METRICS + (DECODE_METRIC,))
+                         NEW_METRICS + MESH_METRICS
+                         + (DECODE_METRIC, PRESORT_METRIC))
 def test_every_new_metric_is_declared_like_the_old(name):
     """A `.json` with the keys of PR 25's and a `per_layer` entry that says
-    the same; PR 26's and PR 29's have no `workloads` key (every cell owes
+    the same; PR 26's, PR 29's and PR 33's have no `workloads` key (every cell owes
     them), PR 28's list the one four-chip cell (a mesh of one has nothing for
     them to read)."""
     meta = cells.read_json(cells.BENCH, "metrics", name + ".json")
@@ -113,6 +115,25 @@ def test_compact_decode_share_reads_the_counter_delta(counters, want,
     assert got == want if want is None else got == pytest.approx(want)
     for parent in (mesh_recorded["counters"], recorded["counters"]):
         assert "compactDecodeLaunches" not in parent
+        assert read(_ctx([], parent, None)) is None
+
+
+@pytest.mark.parametrize("counters,want", [
+    ({"presortCompactLaunches": 152, "fullSortLaunches": 0}, 100.0),
+    ({"presortCompactLaunches": 1, "fullSortLaunches": 3}, 25.0),
+    ({"presortCompactLaunches": 0, "fullSortLaunches": 7}, 0.0),
+    # no launch held the two sorts: nothing to read, never 0
+    ({"presortCompactLaunches": 0, "fullSortLaunches": 0}, None),
+    # PR 33's parent counts its decodes and no sort
+    ({"compactDecodeLaunches": 152, "denseDecodeLaunches": 0}, None),
+])
+def test_presort_compact_share_reads_the_counter_delta(counters, want,
+                                                       mesh_recorded, recorded):
+    read = cells.load_reader(PRESORT_METRIC)
+    got = read(_ctx([], counters, None))
+    assert got == want if want is None else got == pytest.approx(want)
+    for parent in (mesh_recorded["counters"], recorded["counters"]):
+        assert "presortCompactLaunches" not in parent
         assert read(_ctx([], parent, None)) is None
 
 

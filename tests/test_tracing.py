@@ -671,10 +671,13 @@ def test_gather_free_launches_reaches_the_served_response(tmp_path):
 
 # -- PR 29: the sort regime's two decodes, their scopes and their counters ----
 
-def test_sort_regime_program_holds_both_decodes(scope_segment):
-    """One conditional on the count of rows that passed: the answer from the
-    sorted prefix under `.compact`, today's per-key decode (its `.trim` and
-    `.scan` names unchanged) under `.dense`; the sort is shared."""
+def test_sort_regime_program_holds_both_sorts_and_both_decodes(scope_segment):
+    """One conditional on what the tiles' counts say (PR 33): `.presort` (the
+    count outside it; the move and the short sort in its own branch, with the
+    `.compact` ladder over the compacted rows) beside `.sort`, the full sort,
+    whose branch holds PR 29's conditional on the count of rows that passed:
+    the answer from the sorted prefix under `.compact`, the per-key decode
+    (its `.trim` and `.scan` names unchanged) under `.dense`."""
     from pinot_tpu.engine.caps import KernelCaps, get_caps, set_caps
     regime = "partitioned"
     prev = get_caps()
@@ -686,9 +689,17 @@ def test_sort_regime_program_holds_both_decodes(scope_segment):
     finally:
         set_caps(prev)
     assert "stablehlo.case" in text
-    assert f"pinot.groupby.{regime}/pinot.groupby.{regime}.sort/" in text
-    for branch in ("compact", "dense"):     # each inside the conditional
-        assert re.search(rf"pinot\.groupby\.{regime}/cond/branch_\d_fun/"
+    top = f"pinot.groupby.{regime}/"
+    full, short = top + "cond/branch_0_fun/", top + "cond/branch_1_fun/"
+    assert f"{top}pinot.groupby.{regime}.presort/" in text      # the count
+    assert f"{short}pinot.groupby.{regime}.presort/" in text    # move and sort
+    assert f"{full}pinot.groupby.{regime}.sort/" in text
+    assert f"{short}pinot.groupby.{regime}.sort/" not in text
+    assert f"{top}pinot.groupby.{regime}.sort/" not in text
+    assert f"{short}pinot.groupby.{regime}.compact/" in text
+    assert f"{short}pinot.groupby.{regime}.dense/" not in text
+    for branch in ("compact", "dense"):     # each inside the inner conditional
+        assert re.search(rf"{re.escape(full)}cond/branch_\d_fun/"
                          rf"pinot\.groupby\.{regime}\.{branch}/", text), branch
     for part in ("trim", "scan"):       # inside the dense branch, as before
         assert f"pinot.groupby.{regime}.dense/pinot.groupby.{regime}.{part}" \
@@ -696,10 +707,11 @@ def test_sort_regime_program_holds_both_decodes(scope_segment):
         assert f"pinot.groupby.{regime}.compact/pinot.groupby.{regime}." \
             f"{part}" not in text, part
     from benchmark.harness.program_trace import scope_of
-    assert scope_of(f"jit(pinot_groupby)/pinot.groupby.{regime}/cond/"
-                    f"branch_1_fun/pinot.groupby.{regime}.compact/"
-                    "scatter-add:") \
-        == f"pinot.groupby.{regime}"        # still one family for the share
+    for op in (f"{full}cond/branch_1_fun/pinot.groupby.{regime}.compact/"
+               "scatter-add:",
+               f"{short}pinot.groupby.{regime}.presort/reduce_sum:"):
+        assert scope_of("jit(pinot_groupby)/" + op) \
+            == f"pinot.groupby.{regime}"    # still one family for the share
 
 
 def test_small_key_program_past_2_24_rows_holds_no_decode_branch():
@@ -736,6 +748,11 @@ def test_decode_counters_reach_response_explain_and_health(tmp_path):
     (and 10 rows of each other chip's) with `denseDecodeLaunches` 1, since a
     mesh launch is compact only if every chip took it; the same GROUP BY under
     the default caps, where it does not take the sort regime, with neither.
+    Which SORT ran rides beside (PR 33): the 40 rows sit in one tile of one
+    chip, which falls back, so that launch reads `fullSortLaunches` 1 (only
+    if every chip compacted does it read `presortCompactLaunches`), as the
+    dense one does; about 16 rows scattered over each of three chips read
+    `presortCompactLaunches` 1 and a compact decode with it.
     EXPLAIN ANALYZE carries the same fields and
     `/health`'s device block (the pipeline's `stats()`) sums the launches."""
     from tests.test_dense_groupby import one_full_quarter
@@ -770,6 +787,7 @@ def test_decode_counters_reach_response_explain_and_health(tmp_path):
         got.update({
             "compact": cluster.query(sql.format(10)),
             "dense": cluster.query(sql.format(100)),
+            "presorted": cluster.query(sql.replace("w < {}", "w = 500")),
             "explain": cluster.query("EXPLAIN ANALYZE " + sql.format(11))})
         health = pipeline.stats()
     finally:
@@ -777,15 +795,23 @@ def test_decode_counters_reach_response_explain_and_health(tmp_path):
         pipeline.stop()
     assert sum(r[1] for r in got["compact"].rows) == 40
     assert sum(r[1] for r in got["dense"].rows) == per + 30
-    for name, compact, dense in (("compact", 1, 0), ("dense", 0, 1),
-                                 ("no sort regime", 0, 0), ("explain", 1, 0)):
+    assert sum(r[1] for r in got["presorted"].rows) \
+        == (cols["w"] == 500).sum() > 0
+    for name, compact, dense, presorted in (
+            ("compact", 1, 0, 0), ("dense", 0, 1, 0), ("presorted", 1, 0, 1),
+            ("no sort regime", 0, 0, None), ("explain", 1, 0, 0)):
         s = got[name].stats
         assert s["deviceLaunches"] >= 1 and s["meshLaunches"] >= 1, (name, s)
         assert (s["compactDecodeLaunches"], s["denseDecodeLaunches"]) \
             == (compact, dense), (name, s)
+        assert (s["presortCompactLaunches"], s["fullSortLaunches"]) == (
+            (0, 0) if presorted is None else (presorted, 1 - presorted)), \
+            (name, s)
     assert got["explain"].stats["analyze"] is True
     assert (health["compactDecodeLaunches"], health["denseDecodeLaunches"]) \
-        == (2, 1)
+        == (3, 1)
+    assert (health["presortCompactLaunches"], health["fullSortLaunches"]) \
+        == (1, 3)
     assert health["deviceErrors"] == 0 and health["fallbacks"] == 0
 
 
