@@ -854,7 +854,7 @@ def _compact_decode(key_c: jnp.ndarray, vals_c, m, nseg: int, rows: int):
         put(jnp.zeros((nseg,), jnp.float32), t) for t in totals]
 
 
-def _compact_ladder(key_s, vals_s, m, nseg: int, rows: int, rungs, dense=None):
+def _decode_sorted(key_s, vals_s, m, nseg: int, rows: int, rungs, dense=None):
     """`_compact_decode` over the shortest of the prefixes `rungs` of the
     sorted rows that holds the `m` rows that passed; past the last of them
     `dense` (where there is none the caller knows the last rung holds them).
@@ -888,9 +888,9 @@ def compact_rungs(cap: int):
 # for its rows that passed, so the compacted rows are n / 64: `compact_cap`.
 # Q3.2, the densest SSB template, passes 1.64 rows a tile on average, and the
 # chance that any of 65,536 tiles of independent draws holds more than 16 is
-# about 2e-7. Chosen by PR 33's chip probe (PERF.md section 6); no
-# `KernelCaps` field, key or variable feeds it, tests patch it as they patch
-# SLAB_ROWS.
+# about 2e-7. The one size PR 33's chip probe ran (PERF.md section 6: the move
+# is a fifth of its budget there); no `KernelCaps` field, key or variable
+# feeds it, tests patch it as they patch SLAB_ROWS.
 PRESORT_TILE = 1024
 
 
@@ -905,103 +905,25 @@ def _presort_compact(key_t, vals_t, live, nseg: int):
     No n-row scatter and no flat scan. A row's slot is the count of live rows
     before it in its tile: the 0/1 mask against a strict [B, B] triangle on
     the MXU (bf16 operands, f32 accumulation: exact, every cell at most B).
-    Slot k's key and values are then one select and one row reduce over the
-    tile on the VPU: a slot receives one row, so an int32 key and an f32
-    value arrive as they were (no digits, no bf16 parts), and XLA fuses the
-    slots of one operand into one multi-output pass over it (PR 33's probe)."""
+    Slot k's key and values are then a select and a reduce over the tile on
+    the VPU, all slots of an operand in one fused pass ([T, B, slots] is
+    never written): a slot receives one row, so an int32 key and an f32 value
+    arrive as they were, with no digits and no bf16 parts. On the v5e 2.9 ms
+    a 2^24 rows and 7.5 a 2^26, key and one value row (PR 33's probe; a
+    one-hot contraction on the MXU 3.0 and 15.6, `lax.sort` along the tiles
+    6.9 and 27.2)."""
     tile = key_t.shape[1]
     slots = tile // 64
     before = jnp.tri(tile, tile, -1, dtype=jnp.bfloat16).T      # [u, b]: u < b
     slot = jnp.where(live, jax.lax.dot(
         live.astype(jnp.bfloat16), before,
         preferred_element_type=jnp.float32), slots)     # dead rows: no slot
-    over = nseg - 1
-    keys, vals = [], [[] for _ in vals_t]
-    for k in range(slots):
-        mine = slot == k
-        # less the overflow key, so that a slot no row took sums to it
-        keys.append(jnp.sum(jnp.where(mine, key_t - over, 0), axis=-1))
-        for out, v in zip(vals, vals_t):
-            out.append(jnp.sum(jnp.where(mine, v, 0.0), axis=-1))
-    return ((jnp.stack(keys, axis=-1) + over).reshape(-1),
-            [jnp.stack(v, axis=-1).reshape(-1) for v in vals])
-
-
-def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
-                         block: int = 4096, took=None):
-    """Two-level radix-partitioned sort group-by — the one regime past
-    `chunk_cap` keys, at any row count.
-
-    The sort IS the radix split: after `jax.lax.sort`, each `block`-row slab is
-    one partition whose keys RANK-compress to a dense local id
-    j = rank - rank_start (ranks rise by at most 1 per row, so j < block no
-    matter how many of the 2^21 global keys land in the slab). That local id
-    is exactly the chunked one-hot shape, so each slab reuses the 64x64-tile
-    MXU formulation of `_grouped_chunk64` as ONE batched
-    [B, block, 64]^T @ [B, block, 64] dot per bf16 part — total MACs
-    N * block, i.e. a single chunk64-tile-equivalent per part REGARDLESS of
-    key count, where the chunked path pays per 4096 keys and a flat
-    `segment_sum` scatter (what PR 1 replaced) paid a K-independent ~248ms.
-    Groups spanning slab boundaries always occupy local id 0 of the
-    continuation slabs, so a short segmented scan over the [B] slab-head sums
-    stitches them. The dense decode has no n-row scatter
-    either: `searchsorted` run boundaries give exact int32 counts and each
-    key's first sorted position, from which (slab, local id, continuation
-    chain) are pure gathers — two binary searches for EVERY dense key, so
-    where few rows passed the filter (at most `compact_cap`) the answer comes
-    from the sorted prefix of rows that passed instead (`_compact_ladder`)
-    and none of the above runs. Value sums use the 3-part bf16 split (full
-    f32 precision) with f32 accumulation.
-
-    Where the program has the compact decode at all (`compact_cap` not 0: else
-    it is the sort and the dense decode alone), one count over the key's
-    tiles of PRESORT_TILE rows comes first, and one HLO conditional on what it
-    found. If no tile holds more rows that passed than its slots
-    (`_presort_compact`), those rows are moved to the front of their tiles,
-    the n / 64 compacted rows are sorted in place of all n, and the compact
-    ladder answers from them: the rows keep their order, so the sums are the
-    full sort's to the bit. Otherwise (an unselective filter, or rows that
-    passed clustered in a few tiles) the full sort and its ladder run as
-    before: such a table costs what it did plus the one count. `took` (a
-    list, or None) collects the pair of scalars (a compact decode ran, the
-    compacted sort ran).
-    Returns [int32 counts[nseg], f32 sums[nseg]...].
-    """
-    rows = key.size
-    cap = compact_cap(rows + (-rows) % block, nseg, block)
-
-    def full(m=None):
-        with jax.named_scope("pinot.groupby.partitioned.sort"):
-            key_s, vals_s, pad = _sort_by_key(key, nseg, value_rows, block)
-        dense = lambda: _dense_decode(key_s, vals_s, nseg, pad, block)  # noqa: E731
-        if m is None:
-            return dense()
-        return _compact_ladder(key_s, vals_s, m, nseg, rows,
-                               compact_rungs(cap), dense)
-
-    if not cap:
-        return full()
-    with jax.named_scope("pinot.groupby.partitioned.presort"):
-        short = (-rows) % PRESORT_TILE
-        key_t = jnp.pad(key, (0, short), constant_values=nseg - 1).reshape(
-            -1, PRESORT_TILE)
-        live = key_t < nseg - 1
-        passed = jnp.sum(live, axis=-1, dtype=jnp.int32)         # a tile
-        m = jnp.sum(passed)
-        fits = jnp.max(passed) <= PRESORT_TILE // 64
-
-    def presorted():
-        with jax.named_scope("pinot.groupby.partitioned.presort"):
-            key_c, vals_c = _presort_compact(
-                key_t, [jnp.pad(v, (0, short)).reshape(key_t.shape)
-                        for v in value_rows], live, nseg)
-            key_s, vals_s, _ = _sort_by_key(key_c, nseg, vals_c, 1)
-        return _compact_ladder(key_s, vals_s, m, nseg, rows,
-                               compact_rungs(key_s.size))
-
-    if took is not None:
-        took.append((fits | (m <= cap), fits))
-    return jax.lax.cond(fits, presorted, lambda: full(m))
+    mine = slot[:, :, None] == jnp.arange(slots, dtype=jnp.float32)
+    over = nseg - 1     # less it, so that a slot no row took sums to it
+    key_c = jnp.sum(jnp.where(mine, (key_t - over)[:, :, None], 0), axis=1)
+    return ((key_c + over).reshape(-1),
+            [jnp.sum(jnp.where(mine, v[:, :, None], 0.0), axis=1).reshape(-1)
+             for v in vals_t])
 
 
 def _dense_decode(key_s, vals_s, nseg: int, pad: int, block: int):
@@ -1059,6 +981,83 @@ def _dense_decode(key_s, vals_s, nseg: int, pad: int, block: int):
                               start + jnp.where(g1 > g0, tail, 0.0))
             outs.append(jnp.where(occ, total, 0.0))
     return outs
+
+
+def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
+                         block: int = 4096, took=None):
+    """Two-level radix-partitioned sort group-by — the one regime past
+    `chunk_cap` keys, at any row count.
+
+    The sort IS the radix split: after `jax.lax.sort`, each `block`-row slab is
+    one partition whose keys RANK-compress to a dense local id
+    j = rank - rank_start (ranks rise by at most 1 per row, so j < block no
+    matter how many of the 2^21 global keys land in the slab). That local id
+    is exactly the chunked one-hot shape, so each slab reuses the 64x64-tile
+    MXU formulation of `_grouped_chunk64` as ONE batched
+    [B, block, 64]^T @ [B, block, 64] dot per bf16 part — total MACs
+    N * block, i.e. a single chunk64-tile-equivalent per part REGARDLESS of
+    key count, where the chunked path pays per 4096 keys and a flat
+    `segment_sum` scatter (what PR 1 replaced) paid a K-independent ~248ms.
+    Groups spanning slab boundaries always occupy local id 0 of the
+    continuation slabs, so a short segmented scan over the [B] slab-head sums
+    stitches them. The dense decode has no n-row scatter
+    either: `searchsorted` run boundaries give exact int32 counts and each
+    key's first sorted position, from which (slab, local id, continuation
+    chain) are pure gathers — two binary searches for EVERY dense key, so
+    where few rows passed the filter (at most `compact_cap`) the answer comes
+    from the sorted prefix of rows that passed instead (`_decode_sorted`)
+    and none of the above runs. Value sums use the 3-part bf16 split (full
+    f32 precision) with f32 accumulation.
+
+    Where the program has the compact decode at all (`compact_cap` not 0: else
+    it is the sort and the dense decode alone), one count over the key's
+    tiles of PRESORT_TILE rows comes first, and one HLO conditional on what it
+    found. If no tile holds more rows that passed than its slots
+    (`_presort_compact`), those rows are moved to the front of their tiles,
+    the n / 64 compacted rows are sorted in place of all n, and the compact
+    ladder answers from them: the rows keep their order, so the sums are the
+    full sort's to the bit. Otherwise (an unselective filter, or rows that
+    passed clustered in a few tiles) the full sort and its ladder run as
+    before: such a table costs what it did plus the one count. `took` (a
+    list, or None) collects the pair of scalars (a compact decode ran, the
+    compacted sort ran).
+    Returns [int32 counts[nseg], f32 sums[nseg]...].
+    """
+    rows = key.size
+    cap = compact_cap(rows + (-rows) % block, nseg, block)
+
+    def full(m=None):
+        with jax.named_scope("pinot.groupby.partitioned.sort"):
+            key_s, vals_s, pad = _sort_by_key(key, nseg, value_rows, block)
+        dense = lambda: _dense_decode(key_s, vals_s, nseg, pad, block)  # noqa: E731
+        if m is None:
+            return dense()
+        return _decode_sorted(key_s, vals_s, m, nseg, rows,
+                               compact_rungs(cap), dense)
+
+    if not cap:
+        return full()
+    with jax.named_scope("pinot.groupby.partitioned.presort"):
+        short = (-rows) % PRESORT_TILE
+        key_t = jnp.pad(key, (0, short), constant_values=nseg - 1).reshape(
+            -1, PRESORT_TILE)
+        live = key_t < nseg - 1
+        passed = jnp.sum(live, axis=-1, dtype=jnp.int32)         # a tile
+        m = jnp.sum(passed)
+        fits = jnp.max(passed) <= PRESORT_TILE // 64
+
+    def presorted():
+        with jax.named_scope("pinot.groupby.partitioned.presort"):
+            key_c, vals_c = _presort_compact(
+                key_t, [jnp.pad(v, (0, short)).reshape(key_t.shape)
+                        for v in value_rows], live, nseg)
+            key_s, vals_s, _ = _sort_by_key(key_c, nseg, vals_c, 1)
+        return _decode_sorted(key_s, vals_s, m, nseg, rows,
+                               compact_rungs(key_s.size))
+
+    if took is not None:
+        took.append((fits | (m <= cap), fits))
+    return jax.lax.cond(fits, presorted, lambda: full(m))
 
 
 def combine_collective(name: str, v, axis: str):
