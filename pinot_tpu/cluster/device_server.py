@@ -78,9 +78,8 @@ _SHAPE_KEYS = (qstats.MESH_LAUNCHES, qstats.SCATTER_LAUNCHES,
 #: put into them, folded into the query that staged and summed in `stats()`
 _STAGE_KEYS = (qstats.SET_BLOCKS_STAGED, qstats.SET_BLOCK_BYTES)
 
-#: which decode branch a sort-regime GROUP BY launch ran: known once its
-#: outputs are fetched (`qstats.decode_branch`), summed over the launches in
-#: `stats()`; the decode hook puts the same key on each answer's partial
+#: which decode branch and which sort a sort-regime GROUP BY launch ran: known
+#: once its outputs are fetched (`qstats.decode_branch`), summed over the launches in `stats()`; the decode hook puts the same key on each answer's partial
 _DECODE_KEYS = tuple(k for keys in qstats.DECODE_FLAGS.values() for k in keys)
 
 #: what the kernel cache, the first-call fence and the executor's launch

@@ -894,11 +894,11 @@ def compact_rungs(cap: int):
 PRESORT_TILE = 1024
 
 
-def _presort_compact(key_t, vals_t, live, nseg: int):
-    """The rows of every tile that passed the filter, moved into the tile's
-    own PRESORT_TILE / 64 slots in row order: `key_t` [T, B] int32, `vals_t`
-    f32 [T, B] each, `live` [T, B] (key < nseg - 1), at most that many live
-    rows a tile (the caller's `fits`). Returns the flat compacted key
+def _presort_compact(key_t, vals_t, nseg: int):
+    """The rows of every tile that passed the filter (key < nseg - 1), moved
+    into the tile's own PRESORT_TILE / 64 slots in row order: `key_t` [T, B]
+    int32, `vals_t` f32 [T, B] each, at most that many such rows a tile (the
+    caller's `fits`). Returns the flat compacted key
     [T * slots] (a slot no row took carries the overflow key nseg-1) and value
     rows (zero there).
 
@@ -915,11 +915,13 @@ def _presort_compact(key_t, vals_t, live, nseg: int):
     tile = key_t.shape[1]
     slots = tile // 64
     before = jnp.tri(tile, tile, -1, dtype=jnp.bfloat16).T      # [u, b]: u < b
+    over = nseg - 1
+    live = key_t < over
     slot = jnp.where(live, jax.lax.dot(
         live.astype(jnp.bfloat16), before,
         preferred_element_type=jnp.float32), slots)     # dead rows: no slot
     mine = slot[:, :, None] == jnp.arange(slots, dtype=jnp.float32)
-    over = nseg - 1     # less it, so that a slot no row took sums to it
+    # less the overflow key, so that a slot no row took sums to it
     key_c = jnp.sum(jnp.where(mine, (key_t - over)[:, :, None], 0), axis=1)
     return ((key_c + over).reshape(-1),
             [jnp.sum(jnp.where(mine, v[:, :, None], 0.0), axis=1).reshape(-1)
@@ -1019,8 +1021,8 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
     full sort's to the bit. Otherwise (an unselective filter, or rows that
     passed clustered in a few tiles) the full sort and its ladder run as
     before: such a table costs what it did plus the one count. `took` (a
-    list, or None) collects the pair of scalars (a compact decode ran, the
-    compacted sort ran).
+    list, or None) collects the scalars of `qstats.DECODE_FLAGS`: a compact
+    decode ran, the compacted sort ran.
     Returns [int32 counts[nseg], f32 sums[nseg]...].
     """
     rows = key.size
@@ -1041,8 +1043,7 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
         short = (-rows) % PRESORT_TILE
         key_t = jnp.pad(key, (0, short), constant_values=nseg - 1).reshape(
             -1, PRESORT_TILE)
-        live = key_t < nseg - 1
-        passed = jnp.sum(live, axis=-1, dtype=jnp.int32)         # a tile
+        passed = jnp.sum(key_t < nseg - 1, axis=-1, dtype=jnp.int32)  # a tile
         m = jnp.sum(passed)
         fits = jnp.max(passed) <= PRESORT_TILE // 64
 
@@ -1050,13 +1051,14 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
         with jax.named_scope("pinot.groupby.partitioned.presort"):
             key_c, vals_c = _presort_compact(
                 key_t, [jnp.pad(v, (0, short)).reshape(key_t.shape)
-                        for v in value_rows], live, nseg)
+                        for v in value_rows], nseg)
             key_s, vals_s, _ = _sort_by_key(key_c, nseg, vals_c, 1)
         return _decode_sorted(key_s, vals_s, m, nseg, rows,
                                compact_rungs(key_s.size))
 
     if took is not None:
-        took.append((fits | (m <= cap), fits))
+        took.append({qstats.COMPACT_FLAG: fits | (m <= cap),
+                     qstats.PRESORT_FLAG: fits})
     return jax.lax.cond(fits, presorted, lambda: full(m))
 
 
@@ -1211,8 +1213,9 @@ def _make_body(spec: KernelSpec):
                         out[name] = op(v, key, num_segments=num_seg)
             if took:
                 # compact, presorted: only if every sort regime of the scan was
-                for flag, each in zip(qstats.DECODE_FLAGS, zip(*took)):
-                    out[flag] = jnp.all(jnp.stack(each)).astype(jnp.int32)
+                for flag in qstats.DECODE_FLAGS:
+                    out[flag] = jnp.all(jnp.stack(
+                        [t[flag] for t in took])).astype(jnp.int32)
         else:
             with scope("pinot.agg"):
                 fmask = mask.ravel().astype(jnp.float32)

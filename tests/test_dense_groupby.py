@@ -396,13 +396,16 @@ def _compact_sql(seg, m, aggs="COUNT(*), SUM(v), SUM(q)"):
             f"WHERE {where} GROUP BY k ORDER BY k LIMIT 3000000")
 
 
-def _executed(mex, segs, sql):
-    """(rows, the decode counters the launch recorded)."""
+DECODE_KEYS = ("compactDecodeLaunches", "denseDecodeLaunches")
+SORT_KEYS = ("presortCompactLaunches", "fullSortLaunches")      # PR 33
+
+
+def _executed(mex, segs, sql, keys=DECODE_KEYS):
+    """(rows, the counters of `keys` the launch recorded)."""
     from pinot_tpu.query import stats as qstats
     with qstats.collect_stats() as st:
         rows = mex.execute(segs, sql).rows
-    return rows, {k: int(st.counters.get(k, 0)) for k in (
-        qstats.COMPACT_DECODE_LAUNCHES, qstats.DENSE_DECODE_LAUNCHES)}
+    return rows, {k: int(st.counters.get(k, 0)) for k in keys}
 
 
 def _dense_only(monkeypatch):
@@ -537,10 +540,10 @@ def test_compact_ladder_takes_the_shortest_prefix_that_fits(monkeypatch):
         key[rng.choice(n, m, replace=False)] = rng.integers(0, 3, m) * 100
         v = np.where(key < nseg - 1, rng.uniform(-500, 500, n),
                      0).astype(np.float32)
-        (counts, sums), (compact, presorted) = run(key, v)
-        assert bool(compact) == (m <= 256), m
+        (counts, sums), flags = run(key, v)
+        assert bool(flags["decode.compact"]) == (m <= 256), m
         in_a_tile = (key < nseg - 1).reshape(-1, kernels.PRESORT_TILE).sum(1)
-        assert bool(presorted) == (in_a_tile.max() <= 16), m
+        assert bool(flags["decode.presort"]) == (in_a_tile.max() <= 16), m
         assert np.array_equal(np.asarray(counts),
                               np.bincount(key, minlength=nseg)), m
         np.testing.assert_allclose(
@@ -601,7 +604,6 @@ def test_one_chip_dense_three_compact_on_the_mesh(tmp_path_factory):
 # One segment of 12,000 rows (16,384 padded on one device); `pos` numbers the
 # rows and `s` is pos % 64, so `s = 0` passes one row in 64: exactly the slots
 # a tile keeps, in every tile, whatever the tile's length.
-SORT_KEYS = ("presortCompactLaunches", "fullSortLaunches")
 
 
 def _presort_cols(rng, rows, per_segment):
@@ -638,15 +640,6 @@ def _patch_presort_tile(monkeypatch, tile):
     monkeypatch.setattr(kernels, "PRESORT_TILE", tile)
     monkeypatch.setattr(kernels, "_KERNEL_CACHE", {})
     monkeypatch.setattr(combine, "_SHARD_KERNEL_CACHE", {})
-
-
-def _sorts_and_decodes(mex, segs, sql):
-    """(rows, the four counters the sort regime's launch recorded)."""
-    from pinot_tpu.query import stats as qstats
-    with qstats.collect_stats() as st:
-        rows = mex.execute(segs, sql).rows
-    return rows, {k: int(st.counters.get(k, 0)) for k in (
-        "compactDecodeLaunches", "denseDecodeLaunches") + SORT_KEYS}
 
 
 # passing -> (WHERE, rows that pass, the tiles hold them in their slots)
@@ -686,11 +679,13 @@ def test_presorted_rows_answer_as_the_full_sort_and_the_host(
     set_caps(KernelCaps(chunk_cap=4096))
     try:
         _patch_presort_tile(monkeypatch, tile)
-        got, took = _sorts_and_decodes(mex, [presort_segment], sql)
+        got, took = _executed(mex, [presort_segment], sql,
+                              DECODE_KEYS + SORT_KEYS)
         want = ServerQueryExecutor(use_device=False).execute(
             [presort_segment], sql).rows
         _dense_only(monkeypatch)
-        full, neither = _sorts_and_decodes(mex, [presort_segment], sql)
+        full, neither = _executed(mex, [presort_segment], sql,
+                                  DECODE_KEYS + SORT_KEYS)
     finally:
         set_caps(prev)
     assert sum(r[1] for r in want) == m
@@ -725,7 +720,8 @@ def test_presorted_sums_are_the_full_sorts_to_the_bit(monkeypatch):
             k, nseg, [x], block, took), took[-1]))(key, v)
         in_a_tile = (key < nseg - 1).reshape(-1, tile).sum(1).max()
         assert (tile == 1024) == (in_a_tile <= tile // 64)
-        assert [bool(f) for f in flags] == [True, tile == 1024]
+        assert {k: bool(f) for k, f in flags.items()} == {
+            "decode.compact": True, "decode.presort": tile == 1024}
         answers[tile] = [np.asarray(o) for o in outs]
     assert np.array_equal(answers[1024][0], np.bincount(key, minlength=nseg))
     assert answers[1024][1].tobytes() == answers[64][1].tobytes()
@@ -794,8 +790,8 @@ def test_one_chip_sorts_every_row_three_compact_on_the_mesh(
     prev = get_caps()
     set_caps(KernelCaps(chunk_cap=4096))
     try:
-        got, took = _sorts_and_decodes(MeshQueryExecutor(default_mesh(4)),
-                                       segs, sql)
+        got, took = _executed(MeshQueryExecutor(default_mesh(4)), segs, sql,
+                              DECODE_KEYS + SORT_KEYS)
     finally:
         set_caps(prev)
     w = cols["w"].reshape(4, -1)
@@ -932,6 +928,15 @@ def test_dense_partial_roundtrip(vhc_segments, mesh_exec):
     _assert_rows_close(got.rows, want.rows, sql)
 
 
+def _nested(eqn):
+    """The jaxprs an equation holds: conditional branches, loop bodies."""
+    for v in eqn.params.values():
+        for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
 def _scatter_update_rows(jaxpr):
     """Rows of the updates operand of every scatter in a jaxpr, conditional
     branches and other nested jaxprs included."""
@@ -939,11 +944,8 @@ def _scatter_update_rows(jaxpr):
     for eqn in jaxpr.eqns:
         if "scatter" in eqn.primitive.name:
             sizes.append(int(np.prod(eqn.invars[2].aval.shape, dtype=np.int64)))
-        for v in eqn.params.values():
-            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    sizes.extend(_scatter_update_rows(sub))
+        for sub in _nested(eqn):
+            sizes.extend(_scatter_update_rows(sub))
     return sizes
 
 
@@ -956,11 +958,8 @@ def _flat_scans(jaxpr):
             aval = eqn.invars[0].aval
             axis = eqn.params.get("axis", 0)
             found.append((eqn.primitive.name, int(aval.shape[axis])))
-        for v in eqn.params.values():
-            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    found.extend(_flat_scans(sub))
+        for sub in _nested(eqn):
+            found.extend(_flat_scans(sub))
     return found
 
 
@@ -970,13 +969,10 @@ def _presort_branch(jaxpr):
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "cond" and len(eqn.params["branches"]) == 2:
             return eqn.params["branches"][1].jaxpr
-        for v in eqn.params.values():
-            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    found = _presort_branch(sub)
-                    if found is not None:
-                        return found
+        for sub in _nested(eqn):
+            found = _presort_branch(sub)
+            if found is not None:
+                return found
     return None
 
 
