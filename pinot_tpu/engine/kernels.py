@@ -884,21 +884,27 @@ def compact_rungs(cap: int):
     return tuple(c for c in COMPACT_RUNGS if 4 * c <= cap) + (cap,)
 
 
-# Rows a tile of the presort compaction; a tile keeps PRESORT_TILE / 64 slots
-# for its rows that passed, so the compacted rows are n / 64: `compact_cap`.
-# Q3.2, the densest SSB template, passes 1.64 rows a tile on average, and the
-# chance that any of 65,536 tiles of independent draws holds more than 16 is
-# about 2e-7. The one size PR 33's chip probe ran (PERF.md section 6: the move
-# is a fifth of its budget there); no `KernelCaps` field, key or variable
-# feeds it, tests patch it as they patch SLAB_ROWS.
+# Rows a tile of the presort compaction, and the slots a tile keeps for its
+# rows that passed: the first count that holds every tile's, so the compacted
+# rows are n / 64 (`compact_cap`) or n / 16. Q3.2, the densest SSB template,
+# passes 1.64 rows a tile on average, and the chance that any of 65,536 tiles
+# of independent draws holds more than 16 is about 2e-7; rows that are not
+# independent draws need the second step (the benchmark's generator opens
+# every segment with 2,406 rows that walk every key space, the customer's city
+# equal to the supplier's: Q3.2 passes runs of ten of them, up to 50 a tile).
+# A slot costs a select over every row, so the steps are few and short: the
+# move is 7.5 ms a 2^26 rows at 16 slots (PR 33's chip probe, PERF.md section
+# 6). No `KernelCaps` field, key or variable feeds either; tests patch them as
+# they patch SLAB_ROWS.
 PRESORT_TILE = 1024
+PRESORT_SLOTS = (16, 64)
 
 
-def _presort_compact(key_t, vals_t, nseg: int):
+def _presort_compact(key_t, vals_t, nseg: int, slots: int):
     """The rows of every tile that passed the filter (key < nseg - 1), moved
-    into the tile's own PRESORT_TILE / 64 slots in row order: `key_t` [T, B]
-    int32, `vals_t` f32 [T, B] each, at most that many such rows a tile (the
-    caller's `fits`). Returns the flat compacted key
+    into the tile's own `slots` slots in row order: `key_t` [T, B] int32,
+    `vals_t` f32 [T, B] each, at most that many such rows a tile (the caller
+    counted). Returns the flat compacted key
     [T * slots] (a slot no row took carries the overflow key nseg-1) and value
     rows (zero there).
 
@@ -913,7 +919,6 @@ def _presort_compact(key_t, vals_t, nseg: int):
     one-hot contraction on the MXU 3.0 and 15.6, `lax.sort` along the tiles
     6.9 and 27.2)."""
     tile = key_t.shape[1]
-    slots = tile // 64
     before = jnp.tri(tile, tile, -1, dtype=jnp.bfloat16).T      # [u, b]: u < b
     over = nseg - 1
     live = key_t < over
@@ -1014,13 +1019,14 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
     Where the program has the compact decode at all (`compact_cap` not 0: else
     it is the sort and the dense decode alone), one count over the key's
     tiles of PRESORT_TILE rows comes first, and one HLO conditional on what it
-    found. If no tile holds more rows that passed than its slots
-    (`_presort_compact`), those rows are moved to the front of their tiles,
-    the n / 64 compacted rows are sorted in place of all n, and the compact
-    ladder answers from them: the rows keep their order, so the sums are the
-    full sort's to the bit. Otherwise (an unselective filter, or rows that
-    passed clustered in a few tiles) the full sort and its ladder run as
-    before: such a table costs what it did plus the one count. `took` (a
+    found. If no tile holds more rows that passed than the slots of a step of
+    PRESORT_SLOTS (the first that holds them), those rows are moved to the
+    front of their tiles (`_presort_compact`), the n / 64 or n / 16 compacted
+    rows are sorted in place of all n, and the compact ladder answers from
+    them: the rows keep their order, so the sums are the full sort's to the
+    bit. Otherwise (an unselective filter, or rows that passed clustered in a
+    few tiles) the full sort and its ladder run as before: such a table costs
+    what it did plus the one count. `took` (a
     list, or None) collects the scalars of `qstats.DECODE_FLAGS`: a compact
     decode ran, the compacted sort ran.
     Returns [int32 counts[nseg], f32 sums[nseg]...].
@@ -1035,7 +1041,7 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
         if m is None:
             return dense()
         return _decode_sorted(key_s, vals_s, m, nseg, rows,
-                               compact_rungs(cap), dense)
+                              compact_rungs(cap), dense)
 
     if not cap:
         return full()
@@ -1045,21 +1051,27 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
             -1, PRESORT_TILE)
         passed = jnp.sum(key_t < nseg - 1, axis=-1, dtype=jnp.int32)  # a tile
         m = jnp.sum(passed)
-        fits = jnp.max(passed) <= PRESORT_TILE // 64
+        most = jnp.max(passed)
 
-    def presorted():
-        with jax.named_scope("pinot.groupby.partitioned.presort"):
-            key_c, vals_c = _presort_compact(
-                key_t, [jnp.pad(v, (0, short)).reshape(key_t.shape)
-                        for v in value_rows], nseg)
-            key_s, vals_s, _ = _sort_by_key(key_c, nseg, vals_c, 1)
-        return _decode_sorted(key_s, vals_s, m, nseg, rows,
-                               compact_rungs(key_s.size))
+    def presorted(slots):
+        def branch():
+            with jax.named_scope("pinot.groupby.partitioned.presort"):
+                key_c, vals_c = _presort_compact(
+                    key_t, [jnp.pad(v, (0, short)).reshape(key_t.shape)
+                            for v in value_rows], nseg, slots)
+                key_s, vals_s, _ = _sort_by_key(key_c, nseg, vals_c, 1)
+            return _decode_sorted(key_s, vals_s, m, nseg, rows,
+                                  compact_rungs(key_s.size))
+        return branch
 
+    fits = most <= PRESORT_SLOTS[-1]
     if took is not None:
         took.append({qstats.COMPACT_FLAG: fits | (m <= cap),
                      qstats.PRESORT_FLAG: fits})
-    return jax.lax.cond(fits, presorted, lambda: full(m))
+    # the first step whose slots hold every tile's rows; past the last, all n
+    step = sum((most > s).astype(jnp.int32) for s in PRESORT_SLOTS)
+    return jax.lax.switch(step, [presorted(s) for s in PRESORT_SLOTS]
+                          + [lambda: full(m)])
 
 
 def combine_collective(name: str, v, axis: str):

@@ -185,9 +185,10 @@ def test_served_agg_kernel_compiles_for_v5e(topo, cpu_exec, segments, name,
         assert f"[{SMOKE_SEGS * SEG_ROWS}]" not in "".join(
             ln for ln in text.splitlines() if " convolution(" in ln)
     if sort_regime:
-        # 500k keys: one conditional at the top holds both sorts (PR 33): the
-        # rows that passed, compacted tile by tile (n / 64 rows), or all of
-        # them, whose branch holds both decodes of the sorted rows (PR 29)
+        # 500k keys: one conditional at the top holds the sorts (PR 33): the
+        # rows that passed, compacted tile by tile (n / 64 rows, or n / 16),
+        # or all of them, whose branch holds both decodes of the sorted rows
+        # (PR 29)
         text = compiled.as_text()
         entry = text[text.index("ENTRY"):]
         assert entry.count(" conditional(") == 1 and " sort(" not in entry
@@ -195,8 +196,8 @@ def test_served_agg_kernel_compiles_for_v5e(topo, cpu_exec, segments, name,
             assert f"pinot.groupby.partitioned.{scope}/" in text, scope
         rows = segs * SEG_ROWS
         sorts = [ln for ln in text.splitlines() if " sort(" in ln]
-        assert {n for n in (rows, rows // 64)
-                if any(f"[{n}]" in ln for ln in sorts)} == {rows, rows // 64}
+        assert all(any(f"[{n}]" in ln for ln in sorts)
+                   for n in (rows, rows // 64, rows // 16))
 
 
 # (smoke query, the window's slots of the 16 resident, sort regime)
@@ -228,9 +229,10 @@ def test_routed_window_program_compiles_for_v5e(topo, cpu_exec, segments,
     assert "pinot.route" in text
     if sort_regime:
         sorts = [ln for ln in text.splitlines() if " sort(" in ln]
-        # the full sort over the window's rows, the compacted one over a
-        # 64th of them (PR 33)
-        assert sorts and all(f"[{rows}]" in ln or f"[{rows // 64}]" in ln
+        # the full sort over the window's rows, the compacted ones over a
+        # 64th and a 16th of them (PR 33)
+        assert sorts and all(any(f"[{n}]" in ln for n in
+                                 (rows, rows // 64, rows // 16))
                              for ln in sorts)
         assert any(f"[{rows // 64}]" in ln for ln in sorts)
         assert not any(f"[{SMOKE_SEGS * SEG_ROWS}]" in ln for ln in sorts)
@@ -309,6 +311,9 @@ def test_bitmap_word_kernel_compiles_for_v5e(topo, segments):
 @pytest.mark.parametrize("name,collective", [
     ("q1.1 filter+sum", "all-reduce"),
     ("group-by 20k keys", "reduce-scatter"),
+    # the sort regime under `shard_map`: each chip its own conditional on its
+    # own tiles' counts (PR 33), the flags `pmin`ned, the sums reduce-scattered
+    ("group-by 500k keys", "reduce-scatter"),
 ])
 def test_four_chip_program_compiles_with_its_collective(topo, cpu_exec,
                                                         segments, name,
@@ -324,10 +329,20 @@ def test_four_chip_program_compiles_with_its_collective(topo, cpu_exec,
         assert "reduce-scatter" in text or (
             "all-reduce" in text and "dynamic-slice" in text)
         # each device keeps 1/4 of the key space: the outputs stay sharded
-        assert all(s.spec == P(SEGMENT_AXIS) for s in
-                   jax.tree_util.tree_leaves(compiled.output_shardings))
+        # (the sort regime's two scalar flags are `pmin`ned and replicated)
+        from pinot_tpu.query.stats import DECODE_FLAGS
+        assert all(s.spec == P(SEGMENT_AXIS)
+                   for out, s in compiled.output_shardings.items()
+                   if out not in DECODE_FLAGS)
     else:
         assert collective in text
+    if "500k" in name:
+        rows = SMOKE_SEGS * SEG_ROWS // 4
+        sorts = [ln for ln in text.splitlines() if " sort(" in ln]
+        assert any(f"[{rows}]" in ln for ln in sorts)
+        assert any(f"[{rows // 64}]" in ln for ln in sorts)
+        assert any(f"[{rows // 16}]" in ln for ln in sorts)
+        assert "pinot.groupby.partitioned.presort/" in text
 
 
 @pytest.mark.parametrize("width,decode", [

@@ -531,9 +531,9 @@ def test_compact_ladder_takes_the_shortest_prefix_that_fits(monkeypatch):
     run = jax.jit(lambda k, v: (fn(k, nseg, [v], block, took), took[-1]))
     jaxpr = str(jax.make_jaxpr(lambda k, v: fn(k, nseg, [v], block))(
         np.zeros(n, np.int32), np.zeros(n, np.float32)))
-    # one conditional on what the tiles hold, and a ladder each side of it:
-    # three ways over the compacted rows, four over all of them
-    assert jaxpr.count("branches=") == 3
+    # one conditional on what the tiles hold (16 slots, 64, or every row) and
+    # a ladder in each of its three branches
+    assert jaxpr.count("branches=") == 4
     rng = np.random.default_rng(9)
     for m in (0, 1, 16, 17, 64, 65, 256, 257, n):
         key = np.full(n, nseg - 1, np.int32)
@@ -541,9 +541,11 @@ def test_compact_ladder_takes_the_shortest_prefix_that_fits(monkeypatch):
         v = np.where(key < nseg - 1, rng.uniform(-500, 500, n),
                      0).astype(np.float32)
         (counts, sums), flags = run(key, v)
-        assert bool(flags["decode.compact"]) == (m <= 256), m
         in_a_tile = (key < nseg - 1).reshape(-1, kernels.PRESORT_TILE).sum(1)
-        assert bool(flags["decode.presort"]) == (in_a_tile.max() <= 16), m
+        presorted = in_a_tile.max() <= 64
+        assert bool(flags["decode.presort"]) == presorted, m
+        # (64 slots a tile are n / 16 compacted rows: 257 spread rows fit them)
+        assert bool(flags["decode.compact"]) == (m <= 256 or presorted), m
         assert np.array_equal(np.asarray(counts),
                               np.bincount(key, minlength=nseg)), m
         np.testing.assert_allclose(
@@ -633,11 +635,14 @@ def presort_segment(tmp_path_factory):
     return _presort_segments(tmp_path_factory, "ps", COMPACT_ROWS, 1)[0]
 
 
-def _patch_presort_tile(monkeypatch, tile):
-    """Programs built from here on compact tiles of `tile` rows."""
+def _patch_presort_tile(monkeypatch, tile, slots=None):
+    """Programs built from here on compact tiles of `tile` rows into `slots`
+    slots (a 64th of the tile, then a 16th, as the constants that ship)."""
     from pinot_tpu.engine import kernels
     from pinot_tpu.parallel import combine
     monkeypatch.setattr(kernels, "PRESORT_TILE", tile)
+    monkeypatch.setattr(kernels, "PRESORT_SLOTS",
+                        slots or (tile // 64, tile // 16))
     monkeypatch.setattr(kernels, "_KERNEL_CACHE", {})
     monkeypatch.setattr(combine, "_SHARD_KERNEL_CACHE", {})
 
@@ -647,7 +652,8 @@ PRESORT_CASES = {
     "0": (None, 0, True),
     "1": ("pos < 1", 1, True),
     "slots-a-tile": ("s = 0", COMPACT_ROWS // 64 + 1, True),
-    "slots+1-in-one-tile": ("s = 0 OR pos = 1", COMPACT_ROWS // 64 + 2, False),
+    "slots+1-in-one-tile": ("s = 0 OR pos = 1", COMPACT_ROWS // 64 + 2, True),
+    "past-the-last-step": ("s = 0 OR pos < 24", COMPACT_ROWS // 64 + 24, False),
     "all-in-one-tile": ("pos < 200", 200, False),
     "all": (f"pos < {COMPACT_ROWS}", COMPACT_ROWS, False),
 }
@@ -661,11 +667,13 @@ def test_presorted_rows_answer_as_the_full_sort_and_the_host(
         presort_segment, monkeypatch, aggs, passing, tile):
     """The compacted sort against the host executor and against a build with
     the full sort alone, each side of what a tile's slots hold: no row, one,
-    exactly the slots in every tile, one row more in one tile (falls back),
-    every passing row in one tile (falls back), every row (falls back, and
-    decodes per key). 16,384 padded rows are 64 tiles of 256 rows and 64 rows
-    short of 52 tiles of 320 (the count pads them); a 320-row tile keeps 5
-    slots, a 256-row one 4. With COUNT(*) alone no value row is moved."""
+    exactly the first step's slots in every tile, one row more in one tile
+    (the second step's slots hold it), more in one tile than the last step's
+    (falls back), every passing row in one tile (falls back), every row
+    (falls back, and decodes per key). 16,384 padded rows are 64 tiles of 256
+    rows and 64 rows short of 52 tiles of 320 (the count pads them); a 320-row
+    tile keeps 5 slots, then 20, a 256-row one 4, then 16. With COUNT(*) alone
+    no value row is moved."""
     from pinot_tpu.engine import kernels
     where, m, fits = PRESORT_CASES[passing]
     where = where or ("pos < 1 AND q > %d" % int(
@@ -715,6 +723,7 @@ def test_presorted_sums_are_the_full_sorts_to_the_bit(monkeypatch):
     answers = {}
     for tile in (1024, 64):
         monkeypatch.setattr(kernels, "PRESORT_TILE", tile)
+        monkeypatch.setattr(kernels, "PRESORT_SLOTS", (tile // 64,))
         took = []
         outs, flags = jax.jit(lambda k, x: (kernels._grouped_partitioned(
             k, nseg, [x], block, took), took[-1]))(key, v)
@@ -775,9 +784,9 @@ def test_presort_over_a_routed_window_of_six_slots(tmp_path_factory,
     ("w = 500", 1, 1),    # about 16 rows of three chips, none of the fourth
 ], ids=["one-dense", "one-clustered", "all-compact"])
 def test_one_chip_sorts_every_row_three_compact_on_the_mesh(
-        tmp_path_factory, where, compact, presorted):
+        tmp_path_factory, monkeypatch, where, compact, presorted):
     """Four devices, a segment each of 8,000 rows (8 tiles of 1,024 rows and
-    16 slots). Each chip takes its own branch from its own tiles' counts, the
+    16 slots, then 32 here). Each chip takes its own branch from its own tiles' counts, the
     answer equals the host's, and the launch counts under the compacted sort
     only if every chip took it."""
     schema, cols = one_full_quarter("pm")
@@ -790,6 +799,7 @@ def test_one_chip_sorts_every_row_three_compact_on_the_mesh(
     prev = get_caps()
     set_caps(KernelCaps(chunk_cap=4096))
     try:
+        _patch_presort_tile(monkeypatch, 1024, (16, 32))
         got, took = _executed(MeshQueryExecutor(default_mesh(4)), segs, sql,
                               DECODE_KEYS + SORT_KEYS)
     finally:
@@ -797,7 +807,7 @@ def test_one_chip_sorts_every_row_three_compact_on_the_mesh(
     w = cols["w"].reshape(4, -1)
     passed = {"w < 100": w < 100, "w < 10": w < 10, "w = 500": w == 500}[where]
     in_a_tile = np.pad(passed, ((0, 0), (0, 192))).reshape(4, 8, 1024).sum(-1)
-    assert list((in_a_tile <= 16).all(axis=1)) == {
+    assert list((in_a_tile <= 32).all(axis=1)) == {
         "w < 100": [True, False, True, True],
         "w < 10": [True, False, True, True]}.get(where, [True] * 4)
     assert sum(r[1] for r in got) == passed.sum() > 0
@@ -963,17 +973,17 @@ def _flat_scans(jaxpr):
     return found
 
 
-def _presort_branch(jaxpr):
-    """The jaxpr of the compacted sort's branch: the true side of the one
-    two-way conditional at the top of the sort regime."""
+def _presort_branches(jaxpr):
+    """The jaxprs of the compacted sort's branches: all but the last of the
+    outermost conditional of the sort regime."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "cond" and len(eqn.params["branches"]) == 2:
-            return eqn.params["branches"][1].jaxpr
+        if eqn.primitive.name == "cond":
+            return [b.jaxpr for b in eqn.params["branches"][:-1]]
         for sub in _nested(eqn):
-            found = _presort_branch(sub)
-            if found is not None:
+            found = _presort_branches(sub)
+            if found:
                 return found
-    return None
+    return []
 
 
 @pytest.mark.slow
@@ -1024,17 +1034,21 @@ def test_no_flat_scatter_at_high_card(tmp_path_factory):
     cap = kernels.compact_cap(n, plan.num_keys_pad + 1,
                               get_caps().partition_block)
     sizes = _scatter_update_rows(jaxpr.jaxpr)
-    assert sizes and set(sizes) == {cap, 1}, sizes   # 1: the overflow bucket
+    # (1: the overflow bucket; n / 16: what 64 slots a tile compact to)
+    assert sizes and set(sizes) == {cap, n // 16, 1}, sizes
     assert cap <= n // 64
     # the compacted sort's branch (PR 33) moves the rows that passed with no
     # n-row scatter and no flat scan: nothing cumulative over n rows or over
     # its n / PRESORT_TILE tiles (the full sort's per-key decode, the other
     # side of the conditional, keeps its `cumsum` of run heads over n)
-    short = _presort_branch(jaxpr.jaxpr)
-    assert short is not None
-    assert set(_scatter_update_rows(short)) == {cap, 1}
-    assert not [s for s in _flat_scans(short)
-                if s[1] >= n // kernels.PRESORT_TILE], _flat_scans(short)
+    presorted = _presort_branches(jaxpr.jaxpr)
+    assert len(presorted) == len(kernels.PRESORT_SLOTS)
+    for short, slots in zip(presorted, kernels.PRESORT_SLOTS):
+        moved = n // kernels.PRESORT_TILE * slots
+        assert set(_scatter_update_rows(short)) <= {
+            1, *kernels.compact_rungs(moved)}
+        assert not [s for s in _flat_scans(short)
+                    if s[1] >= n // kernels.PRESORT_TILE], _flat_scans(short)
     assert ("cumsum", n) in _flat_scans(jaxpr.jaxpr)
 
 
@@ -1118,7 +1132,7 @@ LADDER = [
                  ("partitioned",), id="distinct-product-at-chunk_cap"),
     pytest.param(_DISTINCT, 16, 16_384, 8192,
                  # the full sort, beside the compacted one (PR 33)
-                 "pinot.distinct/cond/branch_0_fun/"
+                 "pinot.distinct/cond/branch_2_fun/"
                  "pinot.groupby.partitioned.sort",
                  ("pinot.distinct/dot_general",),
                  id="distinct-product-past-chunk_cap"),

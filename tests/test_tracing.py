@@ -673,9 +673,10 @@ def test_gather_free_launches_reaches_the_served_response(tmp_path):
 
 def test_sort_regime_program_holds_both_sorts_and_both_decodes(scope_segment):
     """One conditional on what the tiles' counts say (PR 33): `.presort` (the
-    count outside it; the move and the short sort in its own branch, with the
-    `.compact` ladder over the compacted rows) beside `.sort`, the full sort,
-    whose branch holds PR 29's conditional on the count of rows that passed:
+    count outside it; the move and the short sort in a branch a step of
+    `PRESORT_SLOTS`, each with the `.compact` ladder over its compacted rows)
+    beside `.sort`, the full sort, in the last branch,
+    which holds PR 29's conditional on the count of rows that passed:
     the answer from the sorted prefix under `.compact`, the per-key decode
     (its `.trim` and `.scan` names unchanged) under `.dense`."""
     from pinot_tpu.engine.caps import KernelCaps, get_caps, set_caps
@@ -690,14 +691,15 @@ def test_sort_regime_program_holds_both_sorts_and_both_decodes(scope_segment):
         set_caps(prev)
     assert "stablehlo.case" in text
     top = f"pinot.groupby.{regime}/"
-    full, short = top + "cond/branch_0_fun/", top + "cond/branch_1_fun/"
+    full, short = top + "cond/branch_2_fun/", top + "cond/branch_0_fun/"
     assert f"{top}pinot.groupby.{regime}.presort/" in text      # the count
-    assert f"{short}pinot.groupby.{regime}.presort/" in text    # move and sort
     assert f"{full}pinot.groupby.{regime}.sort/" in text
-    assert f"{short}pinot.groupby.{regime}.sort/" not in text
     assert f"{top}pinot.groupby.{regime}.sort/" not in text
-    assert f"{short}pinot.groupby.{regime}.compact/" in text
-    assert f"{short}pinot.groupby.{regime}.dense/" not in text
+    for short in (short, top + "cond/branch_1_fun/"):   # 16 slots a tile, 64
+        assert f"{short}pinot.groupby.{regime}.presort/" in text    # the move
+        assert f"{short}pinot.groupby.{regime}.sort/" not in text
+        assert f"{short}pinot.groupby.{regime}.compact/" in text
+        assert f"{short}pinot.groupby.{regime}.dense/" not in text
     for branch in ("compact", "dense"):     # each inside the inner conditional
         assert re.search(rf"{re.escape(full)}cond/branch_\d_fun/"
                          rf"pinot\.groupby\.{regime}\.{branch}/", text), branch
@@ -741,14 +743,16 @@ def test_small_key_program_past_2_24_rows_holds_no_decode_branch():
         assert absent not in text, absent
 
 
-def test_decode_counters_reach_response_explain_and_health(tmp_path):
+def test_decode_counters_reach_response_explain_and_health(tmp_path,
+                                                           monkeypatch):
     """Through the device pipeline of a served cluster whose mesh is four
     devices, a segment each: a query that passes 40 rows answers with
     `compactDecodeLaunches` 1; one that passes every row of ONE chip's segment
     (and 10 rows of each other chip's) with `denseDecodeLaunches` 1, since a
     mesh launch is compact only if every chip took it; the same GROUP BY under
     the default caps, where it does not take the sort regime, with neither.
-    Which SORT ran rides beside (PR 33): the 40 rows sit in one tile of one
+    Which SORT ran rides beside (PR 33; a tile keeps 16 slots, then 32 here):
+    the 40 rows sit in one tile of one
     chip, which falls back, so that launch reads `fullSortLaunches` 1 (only
     if every chip compacted does it read `presortCompactLaunches`), as the
     dense one does; about 16 rows scattered over each of three chips read
@@ -784,6 +788,8 @@ def test_decode_counters_reach_response_explain_and_health(tmp_path):
         # once the chunk cap is forced under them
         got = {"no sort regime": cluster.query(sql.format(100))}
         set_caps(KernelCaps(chunk_cap=4096))
+        from pinot_tpu.engine import kernels
+        monkeypatch.setattr(kernels, "PRESORT_SLOTS", (16, 32))
         got.update({
             "compact": cluster.query(sql.format(10)),
             "dense": cluster.query(sql.format(100)),
