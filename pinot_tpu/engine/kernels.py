@@ -1019,14 +1019,17 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
     Where the program has the compact decode at all (`compact_cap` not 0: else
     it is the sort and the dense decode alone), one count over the key's
     tiles of PRESORT_TILE rows comes first, and one HLO conditional on what it
-    found. If no tile holds more rows that passed than the slots of a step of
-    PRESORT_SLOTS (the first that holds them), those rows are moved to the
-    front of their tiles (`_presort_compact`), the n / 64 or n / 16 compacted
-    rows are sorted in place of all n, and the compact ladder answers from
-    them: the rows keep their order, so the sums are the full sort's to the
-    bit. Otherwise (an unselective filter, or rows that passed clustered in a
-    few tiles) the full sort and its ladder run as before: such a table costs
-    what it did plus the one count. `took` (a
+    found. If at most `compact_cap` rows passed and no tile holds more of them
+    than the slots of a step of PRESORT_SLOTS (the first that holds them),
+    those rows are moved to the front of their tiles (`_presort_compact`), the
+    n / 64 or n / 16 compacted rows are sorted in place of all n, and the
+    compact ladder answers from the first `compact_cap` of them (one ladder
+    for both steps: a step is its move and its sort alone, since what a
+    program holds it also loads at every start): the rows keep their order,
+    so the sums are the full sort's to the bit. Otherwise (an unselective
+    filter, or rows that passed clustered in a few tiles) the full sort and
+    its ladder run as before: such a table costs what it did plus the one
+    count. `took` (a
     list, or None) collects the scalars of `qstats.DECODE_FLAGS`: a compact
     decode ran, the compacted sort ran.
     Returns [int32 counts[nseg], f32 sums[nseg]...].
@@ -1053,25 +1056,31 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
         m = jnp.sum(passed)
         most = jnp.max(passed)
 
-    def presorted(slots):
+    def moved(slots):
         def branch():
-            with jax.named_scope("pinot.groupby.partitioned.presort"):
-                key_c, vals_c = _presort_compact(
-                    key_t, [jnp.pad(v, (0, short)).reshape(key_t.shape)
-                            for v in value_rows], nseg, slots)
-                key_s, vals_s, _ = _sort_by_key(key_c, nseg, vals_c, 1)
-            return _decode_sorted(key_s, vals_s, m, nseg, rows,
-                                  compact_rungs(key_s.size))
+            key_c, vals_c = _presort_compact(
+                key_t, [jnp.pad(v, (0, short)).reshape(key_t.shape)
+                        for v in value_rows], nseg, slots)
+            key_s, vals_s, _ = _sort_by_key(key_c, nseg, vals_c, 1)
+            # the first `cap` sorted rows hold the m that passed
+            more = max(cap - key_s.size, 0)
+            return (jnp.pad(key_s, (0, more), constant_values=nseg - 1)[:cap],
+                    [jnp.pad(v, (0, more))[:cap] for v in vals_s])
         return branch
 
-    fits = most <= PRESORT_SLOTS[-1]
+    def presorted():
+        with jax.named_scope("pinot.groupby.partitioned.presort"):
+            # the first step whose slots hold every tile's rows
+            step = sum((most > s).astype(jnp.int32)
+                       for s in PRESORT_SLOTS[:-1])
+            key_s, vals_s = jax.lax.switch(
+                step, [moved(s) for s in PRESORT_SLOTS])
+        return _decode_sorted(key_s, vals_s, m, nseg, rows, compact_rungs(cap))
+
+    fits = (m <= cap) & (most <= PRESORT_SLOTS[-1])
     if took is not None:
-        took.append({qstats.COMPACT_FLAG: fits | (m <= cap),
-                     qstats.PRESORT_FLAG: fits})
-    # the first step whose slots hold every tile's rows; past the last, all n
-    step = sum((most > s).astype(jnp.int32) for s in PRESORT_SLOTS)
-    return jax.lax.switch(step, [presorted(s) for s in PRESORT_SLOTS]
-                          + [lambda: full(m)])
+        took.append({qstats.COMPACT_FLAG: m <= cap, qstats.PRESORT_FLAG: fits})
+    return jax.lax.cond(fits, presorted, lambda: full(m))
 
 
 def combine_collective(name: str, v, axis: str):

@@ -186,9 +186,9 @@ def test_served_agg_kernel_compiles_for_v5e(topo, cpu_exec, segments, name,
             ln for ln in text.splitlines() if " convolution(" in ln)
     if sort_regime:
         # 500k keys: one conditional at the top holds the sorts (PR 33): the
-        # rows that passed, compacted tile by tile (n / 64 rows, or n / 16),
-        # or all of them, whose branch holds both decodes of the sorted rows
-        # (PR 29)
+        # rows that passed, compacted tile by tile (n / 64 rows, or n / 16,
+        # by one more inside), or all of them, whose branch holds both
+        # decodes of the sorted rows (PR 29)
         text = compiled.as_text()
         entry = text[text.index("ENTRY"):]
         assert entry.count(" conditional(") == 1 and " sort(" not in entry

@@ -531,8 +531,8 @@ def test_compact_ladder_takes_the_shortest_prefix_that_fits(monkeypatch):
     run = jax.jit(lambda k, v: (fn(k, nseg, [v], block, took), took[-1]))
     jaxpr = str(jax.make_jaxpr(lambda k, v: fn(k, nseg, [v], block))(
         np.zeros(n, np.int32), np.zeros(n, np.float32)))
-    # one conditional on what the tiles hold (16 slots, 64, or every row) and
-    # a ladder in each of its three branches
+    # one conditional on what the tiles hold, the compacted sort or the full
+    # one; the step of slots inside the first; a ladder each side
     assert jaxpr.count("branches=") == 4
     rng = np.random.default_rng(9)
     for m in (0, 1, 16, 17, 64, 65, 256, 257, n):
@@ -542,10 +542,9 @@ def test_compact_ladder_takes_the_shortest_prefix_that_fits(monkeypatch):
                      0).astype(np.float32)
         (counts, sums), flags = run(key, v)
         in_a_tile = (key < nseg - 1).reshape(-1, kernels.PRESORT_TILE).sum(1)
-        presorted = in_a_tile.max() <= 64
-        assert bool(flags["decode.presort"]) == presorted, m
-        # (64 slots a tile are n / 16 compacted rows: 257 spread rows fit them)
-        assert bool(flags["decode.compact"]) == (m <= 256 or presorted), m
+        assert bool(flags["decode.presort"]) == (
+            m <= 256 and in_a_tile.max() <= 64), m
+        assert bool(flags["decode.compact"]) == (m <= 256), m
         assert np.array_equal(np.asarray(counts),
                               np.bincount(key, minlength=nseg)), m
         np.testing.assert_allclose(
@@ -973,17 +972,17 @@ def _flat_scans(jaxpr):
     return found
 
 
-def _presort_branches(jaxpr):
-    """The jaxprs of the compacted sort's branches: all but the last of the
+def _presort_branch(jaxpr):
+    """The jaxpr of the compacted sort's branch: the true side of the
     outermost conditional of the sort regime."""
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "cond":
-            return [b.jaxpr for b in eqn.params["branches"][:-1]]
+            return eqn.params["branches"][1].jaxpr
         for sub in _nested(eqn):
-            found = _presort_branches(sub)
-            if found:
+            found = _presort_branch(sub)
+            if found is not None:
                 return found
-    return []
+    return None
 
 
 @pytest.mark.slow
@@ -1034,21 +1033,18 @@ def test_no_flat_scatter_at_high_card(tmp_path_factory):
     cap = kernels.compact_cap(n, plan.num_keys_pad + 1,
                               get_caps().partition_block)
     sizes = _scatter_update_rows(jaxpr.jaxpr)
-    # (1: the overflow bucket; n / 16: what 64 slots a tile compact to)
-    assert sizes and set(sizes) == {cap, n // 16, 1}, sizes
+    assert sizes and set(sizes) == {cap, 1}, sizes   # 1: the overflow bucket
     assert cap <= n // 64
     # the compacted sort's branch (PR 33) moves the rows that passed with no
     # n-row scatter and no flat scan: nothing cumulative over n rows or over
     # its n / PRESORT_TILE tiles (the full sort's per-key decode, the other
     # side of the conditional, keeps its `cumsum` of run heads over n)
-    presorted = _presort_branches(jaxpr.jaxpr)
-    assert len(presorted) == len(kernels.PRESORT_SLOTS)
-    for short, slots in zip(presorted, kernels.PRESORT_SLOTS):
-        moved = n // kernels.PRESORT_TILE * slots
-        assert set(_scatter_update_rows(short)) <= {
-            1, *kernels.compact_rungs(moved)}
-        assert not [s for s in _flat_scans(short)
-                    if s[1] >= n // kernels.PRESORT_TILE], _flat_scans(short)
+    short = _presort_branch(jaxpr.jaxpr)
+    assert short is not None and str(short).count("sort[") == len(
+        kernels.PRESORT_SLOTS)          # a step of slots is a move and a sort
+    assert set(_scatter_update_rows(short)) == {cap, 1}
+    assert not [s for s in _flat_scans(short)
+                if s[1] >= n // kernels.PRESORT_TILE], _flat_scans(short)
     assert ("cumsum", n) in _flat_scans(jaxpr.jaxpr)
 
 
@@ -1132,7 +1128,7 @@ LADDER = [
                  ("partitioned",), id="distinct-product-at-chunk_cap"),
     pytest.param(_DISTINCT, 16, 16_384, 8192,
                  # the full sort, beside the compacted one (PR 33)
-                 "pinot.distinct/cond/branch_2_fun/"
+                 "pinot.distinct/cond/branch_0_fun/"
                  "pinot.groupby.partitioned.sort",
                  ("pinot.distinct/dot_general",),
                  id="distinct-product-past-chunk_cap"),

@@ -133,28 +133,28 @@ def test_answer_says_what_crossed_the_chips(served, template):
 @pytest.mark.parametrize("template", TEMPLATES)
 def test_wide_key_templates_say_which_decode_ran(served, template):
     """A wide-key template's launch counts in exactly one of the two decode
-    counters, on a mesh of four and of one; a template that takes no sort
-    regime counts in neither. (At 16,384 rows a device the full sort's prefix
-    is 256 rows, which the 1,319 rows Q3.2's filter passes overflow: until PR
-    33 it answered by the per-key decode here. They fit 64 slots a tile, so it
-    now sorts the compacted rows and decodes them compactly, as the other
-    three do; `tests/test_tracing.py` drives the other branches through a
-    served cluster.)"""
+    counters, on a mesh of four and of one (at 16,384 rows a device the prefix
+    is 256 rows, which Q3.2's filter overflows: both branches answer here,
+    and `correct` above holds for both); a template that takes no sort regime
+    counts in neither."""
     for n in (4, 1):
         resp = served[n][0][template]
         assert sum(resp[k] for k in DECODE_KEYS) == \
             int(template in WIDE_KEY), (n, template)
-        assert resp["denseDecodeLaunches"] == 0
+    took = {t: DECODE_KEYS[served[4][0][t]["denseDecodeLaunches"]]
+            for t in WIDE_KEY}
+    assert set(took.values()) == set(DECODE_KEYS), took
 
 
 @pytest.mark.parametrize("template", TEMPLATES)
 def test_wide_key_templates_say_which_sort_ran(served, template):
     """A wide-key template's launch counts in exactly one of the two sort
     counters, on a mesh of four and of one, and a launch that sorted the
-    compacted rows decoded them compactly (16,384 rows a device are 16 tiles
-    of 16 slots, or 64: every template's filter fits them here); a template
-    that takes no sort regime counts in neither. `/health` sums what the
-    answers said."""
+    compacted rows decoded them compactly (at 16,384 rows a device Q3.2's
+    filter passes more rows than the compact decode reads, so it sorts every
+    row; the other three fit their tiles' slots: both sorts answer here, and
+    `correct` above holds for both); a template that takes no sort regime
+    counts in neither. `/health` sums what the answers said."""
     for n in (4, 1):
         resp = served[n][0][template]
         assert sum(resp[k] for k in SORT_KEYS) == \
@@ -168,8 +168,9 @@ def test_wide_key_templates_say_which_sort_ran(served, template):
                     sum(a[k] for a in answers.values()), (n, k)
             assert sum(after[k] - before[k] for k in SORT_KEYS) \
                 == len(WIDE_KEY)
-        assert all(served[n][0][t]["presortCompactLaunches"] == 1
-                   for t in WIDE_KEY for n in (4, 1))
+        took = {SORT_KEYS[served[n][0][t]["fullSortLaunches"]]
+                for t in WIDE_KEY for n in (4, 1)}
+        assert took == set(SORT_KEYS), took
 
 
 def test_health_sums_what_the_answers_said(served):

@@ -673,10 +673,10 @@ def test_gather_free_launches_reaches_the_served_response(tmp_path):
 
 def test_sort_regime_program_holds_both_sorts_and_both_decodes(scope_segment):
     """One conditional on what the tiles' counts say (PR 33): `.presort` (the
-    count outside it; the move and the short sort in a branch a step of
-    `PRESORT_SLOTS`, each with the `.compact` ladder over its compacted rows)
-    beside `.sort`, the full sort, in the last branch,
-    which holds PR 29's conditional on the count of rows that passed:
+    count outside it; inside it the move and the short sort, one branch a
+    step of `PRESORT_SLOTS`, then one `.compact` ladder over the compacted
+    rows) beside `.sort`, the full sort, whose branch
+    holds PR 29's conditional on the count of rows that passed:
     the answer from the sorted prefix under `.compact`, the per-key decode
     (its `.trim` and `.scan` names unchanged) under `.dense`."""
     from pinot_tpu.engine.caps import KernelCaps, get_caps, set_caps
@@ -691,15 +691,21 @@ def test_sort_regime_program_holds_both_sorts_and_both_decodes(scope_segment):
         set_caps(prev)
     assert "stablehlo.case" in text
     top = f"pinot.groupby.{regime}/"
-    full, short = top + "cond/branch_2_fun/", top + "cond/branch_0_fun/"
+    full, short = top + "cond/branch_0_fun/", top + "cond/branch_1_fun/"
     assert f"{top}pinot.groupby.{regime}.presort/" in text      # the count
     assert f"{full}pinot.groupby.{regime}.sort/" in text
     assert f"{top}pinot.groupby.{regime}.sort/" not in text
-    for short in (short, top + "cond/branch_1_fun/"):   # 16 slots a tile, 64
-        assert f"{short}pinot.groupby.{regime}.presort/" in text    # the move
-        assert f"{short}pinot.groupby.{regime}.sort/" not in text
-        assert f"{short}pinot.groupby.{regime}.compact/" in text
-        assert f"{short}pinot.groupby.{regime}.dense/" not in text
+    assert f"{short}pinot.groupby.{regime}.sort/" not in text
+    for step in (0, 1):                     # 16 slots a tile, 64: the move
+        assert f"{short}pinot.groupby.{regime}.presort/cond/" \
+            f"branch_{step}_fun/" in text
+    assert re.search(rf"{re.escape(short)}(cond/branch_\d_fun/)?"
+                     rf"pinot\.groupby\.{regime}\.compact/", text)
+    assert f"pinot.groupby.{regime}.presort/cond/branch_0_fun/" \
+        f"pinot.groupby.{regime}.compact" not in text   # one ladder, after
+    assert f"{short}pinot.groupby.{regime}.dense/" not in text
+    assert not re.search(rf"{re.escape(short)}cond/branch_\d_fun/"
+                         rf"pinot\.groupby\.{regime}\.dense/", text)
     for branch in ("compact", "dense"):     # each inside the inner conditional
         assert re.search(rf"{re.escape(full)}cond/branch_\d_fun/"
                          rf"pinot\.groupby\.{regime}\.{branch}/", text), branch
@@ -711,7 +717,8 @@ def test_sort_regime_program_holds_both_sorts_and_both_decodes(scope_segment):
     from benchmark.harness.program_trace import scope_of
     for op in (f"{full}cond/branch_1_fun/pinot.groupby.{regime}.compact/"
                "scatter-add:",
-               f"{short}pinot.groupby.{regime}.presort/reduce_sum:"):
+               f"{short}pinot.groupby.{regime}.presort/cond/branch_0_fun/"
+               "reduce_sum:"):
         assert scope_of("jit(pinot_groupby)/" + op) \
             == f"pinot.groupby.{regime}"    # still one family for the share
 
