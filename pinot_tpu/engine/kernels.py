@@ -1029,9 +1029,8 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
     so the sums are the full sort's to the bit. Otherwise (an unselective
     filter, or rows that passed clustered in a few tiles) the full sort and
     its ladder run as before: such a table costs what it did plus the one
-    count. `took` (a
-    list, or None) collects the scalars of `qstats.DECODE_FLAGS`: a compact
-    decode ran, the compacted sort ran.
+    count. `took` (a list, or None) collects the scalars of
+    `qstats.DECODE_FLAGS`: a compact decode ran, the compacted sort ran.
     Returns [int32 counts[nseg], f32 sums[nseg]...].
     """
     rows = key.size
