@@ -291,10 +291,23 @@ def solo_replay(jax, args, loadgen, cell, pool, url, rows, solo_dir):
     return records, solo
 
 
+def widest_by_aggregate(answers, pool, want) -> dict:
+    """{"template/aggregate": the widest gap of that SUM or AVG} over
+    (pool index, rows) answers."""
+    out = {}
+    for q, rows in answers:
+        for name, gap in reference.gaps_by_name(pool[q]["spec"], rows,
+                                                want[q]).items():
+            key = f"{pool[q]['template']}/{name}"
+            out[key] = max(out.get(key, 0.0), gap)
+    return out
+
+
 def control_numbers(controls, records, pool, want, limits) -> dict:
     """Each control put in the program's place and judged by the same
     comparison and the same limits: its numbers and its own `correct`, which
-    has to come out false; and the program's widest sum gaps beside them."""
+    has to come out false; beside them the program's widest sum gaps, and
+    every side's widest gap aggregate by aggregate."""
     sum_limit = limits["sum_rel_gap_max"]
     out = {}
     for name, answers in controls.items():
@@ -303,12 +316,15 @@ def control_numbers(controls, records, pool, want, limits) -> dict:
             "resultTable": {"rows": answers[q]}}} for q in range(len(pool))]
         numbers = judge(fake, pool, want, sum_limit)["numbers"]
         out[name] = {"correct": all(numbers[k] <= limits[k] for k in numbers),
-                     "numbers": numbers}
-    gaps = [reference.compare(pool[r["pool"]]["spec"],
-                              r["response"]["resultTable"]["rows"],
-                              want[r["pool"]], sum_limit)["sum_gap"]
-            for r in records if r["ok"] and not incomplete(r["response"])]
+                     "numbers": numbers,
+                     "gap_by_aggregate": widest_by_aggregate(
+                         enumerate(answers), pool, want)}
+    served = [(r["pool"], r["response"]["resultTable"]["rows"])
+              for r in records if r["ok"] and not incomplete(r["response"])]
+    gaps = [reference.compare(pool[q]["spec"], rows, want[q],
+                              sum_limit)["sum_gap"] for q, rows in served]
     out["program_sum_gaps_sorted_top"] = sorted(gaps)[-5:]
+    out["program_gap_by_aggregate"] = widest_by_aggregate(served, pool, want)
     return out
 
 
@@ -473,9 +489,7 @@ def main(argv=None) -> int:
             result["control"] = control_numbers(controls, records, pool, want,
                                                 limits)
             for name, c in result["control"].items():
-                if isinstance(c, dict):
-                    log(f"control {name}: correct {c['correct']} "
-                        f"{c['numbers']}")
+                log(f"control {name}: {c}")
         result["checked"] = {k: {"value": numbers[k], "limit": limits[k]}
                              for k in numbers}
         for note in verdict["notes"]:

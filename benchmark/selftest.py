@@ -38,19 +38,36 @@ from benchmark.harness import (cells, readers, reference, traffic,  # noqa: E402
 SEED = 2_147_483_777
 
 
-def all_templates():
-    out = []
-    for family in sorted(os.listdir(os.path.join(cells.BENCH, "queries"))):
-        d = os.path.join(cells.BENCH, "queries", family)
-        for f in sorted(os.listdir(d)):
-            if f.endswith(".json"):
-                out.append(cells.read_json(d, f))
+def template(name: str) -> dict:
+    return cells.read_json(cells.BENCH, "queries", name + ".json")
+
+
+def templates_by_config() -> dict:
+    """{configuration name: its templates}: every traffic mix's templates on
+    the configuration of the first cell that uses the mix; a mix kept as data
+    names its own (`config`, else ssb10-flat). Found by name, so that a PR
+    that adds a schema as files is covered without an edit here."""
+    bench = cells.read_json(cells.ROOT, "BENCHMARK.json")
+    out, seen = {}, set()
+    d = os.path.join(cells.BENCH, "traffic")
+    for f in sorted(os.listdir(d)):
+        mix = cells.read_json(d, f)
+        config = next((w["config"] for w in bench["workloads"]
+                       if w["traffic"] == mix["name"]),
+                      mix.get("config", "ssb10-flat"))
+        generator = cells.read_json(cells.BENCH, "configs",
+                                    config + ".json")["generator"]
+        for name in mix["templates"]:
+            if (generator, name) not in seen:
+                seen.add((generator, name))
+                out.setdefault(config, []).append(template(name))
     return out
 
 
 def brute_force(spec, cols, tables) -> list:
     """The same answer by a python loop over the rows, sharing no code with
-    reference.partial/merge/finish except the final ordering rule."""
+    reference.partial/merge/finish except the final ordering rule. Every
+    aggregate in python's own whole numbers; an AVG divided at the end."""
     def value(c, i):
         return tables[c][cols[c][i]] if c in tables else cols[c][i]
     ops = {"eq": lambda v, a: v == a[0], "lt": lambda v, a: v < a[0],
@@ -58,6 +75,7 @@ def brute_force(spec, cols, tables) -> list:
            "ge": lambda v, a: v >= a[0],
            "between": lambda v, a: a[0] <= v <= a[1],
            "in": lambda v, a: v in a}
+    aggs = spec.get("aggregates") or [dict(spec["aggregate"], name="agg")]
     groups = {}
     n = len(next(iter(cols.values())))
     for i in range(n):
@@ -65,57 +83,89 @@ def brute_force(spec, cols, tables) -> list:
                    for f in spec.get("filters", [])):
             continue
         key = tuple(value(c, i) for c in spec.get("group_by", []))
-        if spec["aggregate"]["fn"] == "count":
-            add = 1
-        else:
-            add = 0
-            for t in spec["aggregate"]["terms"]:
-                term = int(t.get("coef", 1))
-                for c in t["columns"]:
-                    term *= int(value(c, i))
-                add += term
-        groups[key] = groups.get(key, 0) + add
+        held = groups.setdefault(key, {"rows": 0})
+        held["rows"] += 1
+        for a in aggs:
+            if a["fn"] in ("sum", "avg"):
+                add = 0
+                for t in a["terms"]:
+                    term = int(t.get("coef", 1))
+                    for c in t["columns"]:
+                        term *= int(value(c, i))
+                    add += term
+                held[a["name"]] = held.get(a["name"], 0) + add
+            elif a["fn"] in ("min", "max"):
+                v = int(value(a["column"], i))
+                held[a["name"]] = (v if a["name"] not in held else
+                                   min(held[a["name"]], v) if a["fn"] == "min"
+                                   else max(held[a["name"]], v))
+
+    def cells(key, held):
+        named = dict(zip(spec["group_by"], key))
+        for a in aggs:
+            named[a["name"]] = (held["rows"] if a["fn"] == "count" else
+                                held[a["name"]] / held["rows"]
+                                if a["fn"] == "avg" else held[a["name"]])
+        return named
     if not spec.get("group_by"):
-        return [[groups.get((), 0)]]
-    rows = []
-    for key, agg in groups.items():
-        named = dict(zip(spec["group_by"], key), agg=agg)
-        rows.append(named)
+        if () not in groups:    # over no rows: COUNT 0, SUM 0, NULL the others
+            over_none = {a["name"]: {"count": 0, "sum": 0}.get(a["fn"])
+                         for a in aggs}
+            return [[over_none[c] for c in spec["select"]]]
+        return [[cells((), groups[()])[c] for c in spec["select"]]]
+    rows = [cells(key, held) for key, held in groups.items()]
     for col, direction in reversed(spec.get("order_by", [])):
         rows.sort(key=lambda r: r[col], reverse=(direction == "desc"))
     rows = rows[:spec.get("limit", len(rows))]
     return [[r[c] for c in spec["select"]] for r in rows]
 
 
-def check_reference() -> None:
-    config = cells.read_json(cells.BENCH, "configs", "ssb10-flat.json")
+def table_of(config_name: str, rows: int, segments: int):
+    """(config, tables, [segment...]) of a configuration at a test's size."""
+    config = cells.read_json(cells.BENCH, "configs", config_name + ".json")
     gen = cells.load_generator(config)
-    tables = gen.tables(config)
-    segs = [gen.segment(config, SEED, i, 3000) for i in range(2)]
-    whole = {c: np.concatenate([s[c] for s in segs]) for c in segs[0]}
+    return config, gen.tables(config), [gen.segment(config, SEED, i, rows)
+                                        for i in range(segments)]
+
+
+def evaluate(spec, segs, tables, precision="exact", evaluator=reference) -> list:
+    """The evaluator's answer over the segments: parts, merged, finished."""
+    return evaluator.finish(spec, evaluator.merge(
+        [evaluator.partial(spec, s, tables, precision) for s in segs]), tables)
+
+
+def plain(row) -> list:
+    return [x if x is None else str(x) if isinstance(x, (str, np.str_))
+            else float(x) for x in row]
+
+
+def check_reference() -> None:
     rng = np.random.default_rng(SEED)
-    for t in all_templates():
-        for _ in range(3):
-            holes = traffic.draw_holes(t, tables, rng)
-            # wide literals so that a tiny table still has rows to group
-            spec = reference.bind(t["reference"], holes)
-            got = reference.finish(spec, reference.merge(
-                [reference.partial(spec, s, tables) for s in segs]), tables)
-            want = brute_force(spec, whole, tables)
-            assert len(got) == len(want), (t["name"], len(got), len(want))
-            for g, w in zip(got, want):
-                assert [str(x) if isinstance(x, str) else float(x) for x in g] \
-                    == [str(x) if isinstance(x, (str, np.str_)) else float(x)
-                        for x in w], (t["name"], g, w)
-            c = reference.compare(spec, json.loads(json.dumps(got)), got, 1e-6)
-            assert not c["wrong"] and not c["count_wrong"] \
-                and c["sum_gap"] == 0.0, (t["name"], c)
+    seen = []
+    for config_name, templates in templates_by_config().items():
+        _, tables, segs = table_of(config_name, 3000, 2)
+        whole = {c: np.concatenate([s[c] for s in segs]) for c in segs[0]}
+        for t in templates:
+            seen.append(f"{t['family']}/{t['name']}")
+            for _ in range(3):
+                holes = traffic.draw_holes(t, tables, rng)
+                # wide literals so that a tiny table still has rows to group
+                spec = reference.bind(t["reference"], holes)
+                got = evaluate(spec, segs, tables)
+                want = brute_force(spec, whole, tables)
+                assert len(got) == len(want), (t["name"], len(got), len(want))
+                for g, w in zip(got, want):
+                    assert plain(g) == plain(w), (t["name"], g, w)
+                c = reference.compare(spec, json.loads(json.dumps(got)), got,
+                                      1e-6)
+                assert not c["wrong"] and not c["count_wrong"] \
+                    and c["sum_gap"] == 0.0, (t["name"], c)
+    assert {"check/q1-shape", "check/minmax", "ssb/q1.1"} <= set(seen), seen
     # the comparison itself: each kind of wrong answer is seen
-    t = next(t for t in all_templates() if t["name"] == "q3.1")
-    spec = reference.bind(t["reference"], {"region": "ASIA", "y0": 1992,
-                                           "y1": 1997})
-    want = reference.finish(spec, reference.merge(
-        [reference.partial(spec, s, tables) for s in segs]), tables)
+    _, tables, segs = table_of("ssb10-flat", 3000, 2)
+    spec = reference.bind(template("ssb/q3.1")["reference"],
+                          {"region": "ASIA", "y0": 1992, "y1": 1997})
+    want = evaluate(spec, segs, tables)
     assert len(want) > 3
     agg = spec["select"].index("agg")
     scaled = [list(r) for r in want]
@@ -126,27 +176,40 @@ def check_reference() -> None:
     renamed = [list(r) for r in want]
     renamed[0][0] = "NOWHERE"
     assert reference.compare(spec, renamed, want, 1e-6)["wrong"]
-    t = next(t for t in all_templates() if t["name"] == "region")
-    spec = reference.bind(t["reference"], {"region": "ASIA"})
-    want = reference.finish(spec, reference.merge(
-        [reference.partial(spec, s, tables) for s in segs]), tables)
+    spec = reference.bind(template("tiles/region")["reference"],
+                          {"region": "ASIA"})
+    want = evaluate(spec, segs, tables)
     assert want[0][0] > 0
     assert reference.compare(spec, [[want[0][0] + 1]], want, 1e-6)["count_wrong"]
-    print("ok reference: every template equals the brute-force loop; the "
-          "comparison sees a scaled sum, a missing row, a wrong order, a "
-          "wrong key, a count one too high")
+    # a row of several aggregates: every aggregate cell is judged
+    spec = reference.bind(template("check/q1-shape")["reference"],
+                          {"d": 19980603})
+    want = evaluate(spec, segs, tables)
+    sel = spec["select"]
+    for name, scale, number in (("sum_charge", 1 + 1e-4, "sum_gap"),
+                                ("avg_disc", 1 + 1e-4, "sum_gap"),
+                                ("count_order", None, "count_wrong")):
+        bad = [list(r) for r in want]
+        cell = bad[-1][sel.index(name)]
+        bad[-1][sel.index(name)] = cell * scale if scale else cell + 1
+        c = reference.compare(spec, bad, want, 1e-6)
+        assert c[number] > 0.9e-4 and not c["wrong"], (name, c)
+    print(f"ok reference: {len(seen)} templates equal the brute-force loop; "
+          "the comparison sees a scaled sum, a missing row, a wrong order, a "
+          "wrong key, a count one too high, and in a row of eight aggregates "
+          "a scaled SUM, a scaled AVG and a COUNT one too high")
 
 
 def check_least_bytes() -> None:
     config = cells.read_json(cells.BENCH, "configs", "ssb10-flat.json")
-    t = next(t for t in all_templates() if t["name"] == "q2.1")
+    t = template("ssb/q2.1")
     # Q2.1 reads lo_revenue (81,000..10,000,000: 4 bytes), d_year (7 values: 1),
     # p_brand1 (1,000 values: 2), p_category (25: 1), s_region (5: 1) = 9 bytes
     # a row, times 67,108,864 rows
     assert reference.columns_read(t["reference"]) == [
         "d_year", "lo_revenue", "p_brand1", "p_category", "s_region"]
     assert readers.least_bytes(t["reference"], config) == 9 * 67_108_864
-    t = next(t for t in all_templates() if t["name"] == "q1.1")
+    t = template("ssb/q1.1")
     # d_year 1 + lo_discount 1 + lo_quantity 1 + lo_extendedprice 4
     assert readers.least_bytes(t["reference"], config) == 7 * 67_108_864
     print("ok least bytes: Q2.1 = 9 bytes a row, Q1.1 = 7 bytes a row")
@@ -174,32 +237,46 @@ def check_trace_reduce() -> None:
 
 
 def check_controls() -> None:
-    config = cells.read_json(cells.BENCH, "configs", "ssb10-flat-quarter.json")
-    limit = float(config["guarantees"]["sum_rel_gap"])
-    gen = cells.load_generator(config)
-    tables = gen.tables(config)
-    segs = [gen.segment(config, SEED, i, 65_536) for i in range(4)]
-    worst_bf16, counts_wrong, rows_wrong = 0.0, 0, 0
+    """Both controls, each configuration's templates by its own limit; each
+    has to fail an answer of several aggregates too."""
     rng = np.random.default_rng(SEED + 1)
-    for t in all_templates():
-        holes = traffic.draw_holes(t, tables, rng)
-        spec = reference.bind(t["reference"], holes)
-
-        def answer(precision="exact", keep=4):
-            return reference.finish(spec, reference.merge(
-                [reference.partial(spec, s, tables, precision)
-                 for s in segs[:keep]]), tables)
-        want = answer()
-        c = reference.compare(spec, answer("bf16"), want, limit)
-        worst_bf16 = max(worst_bf16, c["sum_gap"])
-        c = reference.compare(spec, answer(keep=3), want, limit)
-        counts_wrong += c["count_wrong"]
-        rows_wrong += c["wrong"] or not c["sum_gap"] <= limit
-    assert worst_bf16 > 3 * limit, worst_bf16
+    worst_bf16, counts_wrong, rows_wrong, several = 0.0, 0, 0, {}
+    for config_name, templates in templates_by_config().items():
+        config, tables, segs = table_of(config_name, 65_536, 4)
+        limit = float(config["guarantees"]["sum_rel_gap"])
+        for t in templates:
+            holes = traffic.draw_holes(t, tables, rng)
+            spec = reference.bind(t["reference"], holes)
+            want = evaluate(spec, segs, tables)
+            c = reference.compare(spec, evaluate(spec, segs, tables, "bf16"),
+                                  want, limit)
+            worst_bf16 = max(worst_bf16, c["sum_gap"])
+            c = reference.compare(spec, evaluate(spec, segs[:3], tables), want,
+                                  limit)
+            counts_wrong += c["count_wrong"]
+            rows_wrong += c["wrong"] or not c["sum_gap"] <= limit
+            several[t["name"]] = c["count_wrong"]
+        assert worst_bf16 > 3 * limit, worst_bf16
     assert counts_wrong >= 1 and rows_wrong >= 1, (counts_wrong, rows_wrong)
+    assert several["q1-shape"] and several["minmax"], several
+    # bfloat16 on eight aggregates at once, at 240 rows a group: the rows'
+    # roundings cancel as the root of a group's rows, so that an unselective
+    # SUM of 10,000 rows a group sits at the limit and one of 670,000 under it
+    _, tables, segs = table_of("ssb10-flat", 3000, 2)
+    spec = reference.bind(template("check/q1-shape")["reference"],
+                          {"d": 19980603})
+    want = evaluate(spec, segs, tables)
+    got = evaluate(spec, segs, tables, "bf16")
+    q1 = reference.compare(spec, got, want, limit)["sum_gap"]
+    by_name = reference.gaps_by_name(spec, got, want)
+    assert q1 > 3 * limit and q1 == max(by_name.values()), (q1, by_name)
+    assert by_name["sum_qty"] == 0.0 and by_name["avg_price"] > limit \
+        and by_name["sum_charge"] > limit, by_name
     print(f"ok controls: the reference at bfloat16 is {worst_bf16:.2e} off "
-          f"(limit {limit:.0e}); with a segment left out {counts_wrong} counts "
-          f"and {rows_wrong} other answers are wrong")
+          f"(limit {limit:.0e}), {q1:.2e} on Q1's eight aggregates at 240 rows "
+          f"a group; with a segment left out {counts_wrong} counts (Q1's "
+          f"shape and MIN/MAX among them) and {rows_wrong} other answers are "
+          f"wrong")
 
 
 def rehearse(workload: str, fault=None, control: int = 0) -> dict:
