@@ -82,12 +82,16 @@ def build_segment(job: dict) -> dict:
 def reference_segment(job: dict) -> dict:
     """job: config, seed, index, rows, pool (bound specs), control. The
     segment again from the seed, and the reference's part of every answer of
-    the pool over it; with `control` also at bfloat16."""
+    the pool over it; with `control` also at each lowered precision
+    (`reference.ROUNDINGS`)."""
     config = job["config"]
     gen = cells.load_generator(config)
     tables = gen.tables(config)
     cols = gen.segment(config, job["seed"], job["index"], job["rows"])
     parts = [reference.partial(spec, cols, tables) for spec in job["pool"]]
-    control = ([reference.partial(spec, cols, tables, precision="bf16")
-                for spec in job["pool"]] if job.get("control") else None)
-    return {"index": job["index"], "parts": parts, "control": control}
+    out = {"index": job["index"], "parts": parts}
+    if job.get("control"):
+        for name in reference.ROUNDINGS:
+            out[name] = [reference.partial(spec, cols, tables, precision=name)
+                         for spec in job["pool"]]
+    return out

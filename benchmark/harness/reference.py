@@ -26,18 +26,24 @@ What is exact. COUNT, MIN and MAX are integers and exact. A SUM whose
 coefficients are whole is carried in int64 from the row to `finish` and is
 exact whatever its size, a product of three fixed-point columns over 67M rows
 too (1.5e11 a row, 4e18 a table, where float64's 2**53 = 9e15 ends): below
-2**53 one float64 `np.bincount` adds it as before, past it the values are
-added limb by limb (`_add_by_group`). A row or a group sum past 2**63 raises
-OverflowError; nothing wraps in silence. `finish` rounds each sum once to the
-float64 it prints, and an AVG once more in its division: the reference's own
-error on a SUM or AVG cell is at most 2**-52 = 2.2e-16 of the cell, eleven
-orders under the 2e-5 the program is held to. Only a sum with a fractional
-`coef` is carried in float64: `np.bincount` adds in row order, so a part over
-n rows is within n * 1.1e-16 of the sum of the rows' magnitudes (5e-10 at the
-4Mi rows of a segment); no template the benchmark holds has one.
+2**53 one float64 `np.bincount` adds it as before, past it `np.add.at` adds
+in int64 (`_add_by_group`). A row's value, or a group's sum of magnitudes,
+past 2**63 raises OverflowError; nothing wraps in silence. `finish` rounds
+each sum once to the float64 it prints, and an AVG once more in its division:
+the reference's own error on a SUM or AVG cell is at most 2**-52 = 2.2e-16 of
+the cell, eleven orders under the 2e-5 the program is held to. Only a sum with
+a fractional `coef` is carried in float64: `np.bincount` adds in row order, so
+a part over n rows is within n * 1.1e-16 of the sum of the rows' magnitudes
+(5e-10 at the 4Mi rows of a segment); no template the benchmark holds has one.
 
-`precision="bf16"` is the control: each row's value of every SUM and AVG
-aggregate is rounded to bfloat16 before it is added (in float64).
+A `precision` other than "exact" is a control (`ROUNDINGS`): each row's value
+of every SUM and AVG aggregate is brought to bfloat16 before it is added (in
+float64). "bf16" rounds to the nearest, ties to even: its errors cancel as the
+root of a group's rows, so it fails a selective sum (3e-3 where thousands of
+rows are added) and passes an unselective one (8e-6 at 670,000 rows a group).
+"bf16_truncated" keeps the float32's high half, rounding toward zero: every
+row loses 0 to 2**-7 of itself, nothing cancels, and a sum of values wider than
+eight bits reads 2.7e-3 to 2.9e-3 low whatever its rows.
 """
 
 from collections import namedtuple
@@ -63,7 +69,7 @@ ADDED = ("sum", "avg")          # judged by `sum_gap`; the other cells are exact
 EXTREME = {     # fn: (its field of a Part, the fold, where the fold starts)
     "min": ("least", np.minimum.at, np.iinfo(np.int64).max),
     "max": ("greatest", np.maximum.at, np.iinfo(np.int64).min)}
-INT64_END = 2.0 ** 63 * (1 - 2.0 ** -20)
+INT64_END = 2.0 ** 63 * (1 - 2.0 ** -20)    # float64 estimates stay under it
 
 
 def bind(spec, holes: dict):
@@ -103,6 +109,17 @@ def to_bf16(x: np.ndarray) -> np.ndarray:
     return u.view(np.float32).astype(np.float64)
 
 
+def truncated_to_bf16(x: np.ndarray) -> np.ndarray:
+    """The float32's high 16 bits (bfloat16 toward zero), as float64."""
+    u = np.ascontiguousarray(x, dtype=np.float32).view(np.uint32)
+    return (u & np.uint32(0xFFFF0000)).view(np.float32).astype(np.float64)
+
+
+# The controls that lower the precision: {`precision`: what it does to each
+# row's value of a SUM or AVG}. run.py puts every one in the program's place.
+ROUNDINGS = {"bf16": to_bf16, "bf16_truncated": truncated_to_bf16}
+
+
 def _typed(args, table):
     if table.dtype.kind in "US":
         return [str(a) for a in args]
@@ -135,21 +152,20 @@ def _row_values(terms, value) -> np.ndarray:
 def _add_by_group(inv, val, size: int) -> np.ndarray:
     """The sum of `val` by group. float64 values: one `np.bincount`, in row
     order. int64 values: exact. Where rows x the largest magnitude stays below
-    2**53 one float64 bincount holds every partial sum exactly; else the
-    values go as a low limb that does and the rest, shifted. int64 wraps, and
-    the limbs' wraps cancel where the sum itself fits, which the float64
-    estimate shows."""
+    2**53 one float64 bincount holds every partial sum exactly; else
+    `np.add.at` adds in int64, which cannot wrap where a group's sum of
+    magnitudes (a float64 estimate, good to 1e-9) is under 2**63."""
     if val.dtype.kind == "f":
         return np.bincount(inv, weights=val, minlength=size)
     if _reach(val) * val.size < 2 ** 53:
         return np.bincount(inv, weights=val, minlength=size).astype(np.int64)
-    near = np.bincount(inv, weights=val, minlength=size)
-    if np.abs(near).max() >= INT64_END:
-        raise OverflowError(f"a group's sum is near {near.max()}: past int64")
-    bits = 53 - val.size.bit_length()
-    low = val & np.int64((1 << bits) - 1)
-    return (_add_by_group(inv, low, size)
-            + _add_by_group(inv, val >> bits, size) * np.int64(1 << bits))
+    most = np.bincount(inv, weights=np.abs(val.astype(np.float64)),
+                       minlength=size).max()
+    if most >= INT64_END:
+        raise OverflowError(f"a group's sum can reach {most}: past int64")
+    out = np.zeros(size, dtype=np.int64)
+    np.add.at(out, inv, val)
+    return out
 
 
 def _extreme_by_group(fn: str, inv, val, size: int) -> np.ndarray:
@@ -186,8 +202,8 @@ def partial(spec, cols: dict, tables: dict, precision: str = "exact") -> Part:
     for a in aggregates(spec):
         if a["fn"] in ADDED:
             val = _row_values(a["terms"], value)
-            if precision == "bf16":
-                val = to_bf16(val)
+            if precision != "exact":
+                val = ROUNDINGS[precision](val)
             part.sums[a["name"]] = _add_by_group(inv, val, keys.size)
         elif a["fn"] in EXTREME:
             getattr(part, EXTREME[a["fn"]][0])[a["name"]] = _extreme_by_group(

@@ -11,9 +11,10 @@ result object; see benchmark/README.md.
 
 `--rehearse` (CPU sandbox) relaxes the platform check and cuts the rows; it
 prints `cpu` as its device and is never a measurement. `--control 1` also
-puts each control in the program's place (the reference at bfloat16; the
-reference with one segment left out) and reports its numbers and its own
-`correct`, by the same limits; each has to come out false.
+puts each control in the program's place (the reference at bfloat16, rounded
+to the nearest and truncated: `reference.ROUNDINGS`; the reference with one
+segment left out) and reports its numbers and its own `correct`, by the same
+limits; each has to come out false.
 """
 
 import time
@@ -159,8 +160,8 @@ def reference_answers(pool_exec, cell, seed, rows_per_segment, pool, tables,
     controls = None
     if control:
         last = len(parts) - 1
-        controls = {"bf16": answers("control"),
-                    "segment_left_out": answers("parts", lambda i: i != last)}
+        controls = {name: answers(name) for name in reference.ROUNDINGS}
+        controls["segment_left_out"] = answers("parts", lambda i: i != last)
     return want, controls
 
 

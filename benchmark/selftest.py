@@ -6,8 +6,10 @@
   2. the least-bytes arithmetic of one template, by hand;
   3. the trace reducer: interval arithmetic on a made-up trace, and the recorded
      chip trace under fixtures/ against the numbers written down beside it;
-  4. the controls come out as not correct: the reference at bfloat16 fails the
-     sum limit, the reference with one segment left out fails counts and rows;
+  4. the controls come out as not correct: the reference at bfloat16, rounded
+     and truncated, fails the sum limit (the truncated one also where a group
+     has so many rows that the rounded one's errors cancel), the reference
+     with one segment left out fails counts and rows;
   5. (not with --quick) two whole rehearsal runs through run.py, which skip
      only the look for a chip: a clean one with `--control 1`, whose last line
      must have the contract's shape and `correct` true, and each control's own
@@ -36,6 +38,7 @@ from benchmark.harness import (cells, readers, reference, traffic,  # noqa: E402
                                trace_reduce)
 
 SEED = 2_147_483_777
+KEPT_AS_DATA_ON = "ssb10-flat"     # the configuration of a mix no cell uses
 
 
 def template(name: str) -> dict:
@@ -44,17 +47,16 @@ def template(name: str) -> dict:
 
 def templates_by_config() -> dict:
     """{configuration name: its templates}: every traffic mix's templates on
-    the configuration of the first cell that uses the mix; a mix kept as data
-    names its own (`config`, else ssb10-flat). Found by name, so that a PR
-    that adds a schema as files is covered without an edit here."""
+    the configuration of the first cell that uses the mix (a mix kept as data,
+    which no cell uses, on `KEPT_AS_DATA_ON`). Found by name, so that a PR
+    that adds a schema with its cell is covered without an edit here."""
     bench = cells.read_json(cells.ROOT, "BENCHMARK.json")
     out, seen = {}, set()
     d = os.path.join(cells.BENCH, "traffic")
     for f in sorted(os.listdir(d)):
         mix = cells.read_json(d, f)
         config = next((w["config"] for w in bench["workloads"]
-                       if w["traffic"] == mix["name"]),
-                      mix.get("config", "ssb10-flat"))
+                       if w["traffic"] == mix["name"]), KEPT_AS_DATA_ON)
         generator = cells.read_json(cells.BENCH, "configs",
                                     config + ".json")["generator"]
         for name in mix["templates"]:
@@ -100,7 +102,7 @@ def brute_force(spec, cols, tables) -> list:
                                    min(held[a["name"]], v) if a["fn"] == "min"
                                    else max(held[a["name"]], v))
 
-    def cells(key, held):
+    def row_of(key, held):
         named = dict(zip(spec["group_by"], key))
         for a in aggs:
             named[a["name"]] = (held["rows"] if a["fn"] == "count" else
@@ -112,8 +114,8 @@ def brute_force(spec, cols, tables) -> list:
             over_none = {a["name"]: {"count": 0, "sum": 0}.get(a["fn"])
                          for a in aggs}
             return [[over_none[c] for c in spec["select"]]]
-        return [[cells((), groups[()])[c] for c in spec["select"]]]
-    rows = [cells(key, held) for key, held in groups.items()]
+        return [[row_of((), groups[()])[c] for c in spec["select"]]]
+    rows = [row_of(key, held) for key, held in groups.items()]
     for col, direction in reversed(spec.get("order_by", [])):
         rows.sort(key=lambda r: r[col], reverse=(direction == "desc"))
     rows = rows[:spec.get("limit", len(rows))]
@@ -237,46 +239,65 @@ def check_trace_reduce() -> None:
 
 
 def check_controls() -> None:
-    """Both controls, each configuration's templates by its own limit; each
-    has to fail an answer of several aggregates too."""
+    """Every control, each configuration's templates by its own limit; each
+    has to fail an answer of several aggregates too, and the truncated
+    bfloat16 one where the rounded one cannot."""
     rng = np.random.default_rng(SEED + 1)
-    worst_bf16, counts_wrong, rows_wrong, several = 0.0, 0, 0, {}
+    worst, counts_wrong, rows_wrong, several = {}, 0, 0, {}
     for config_name, templates in templates_by_config().items():
         config, tables, segs = table_of(config_name, 65_536, 4)
         limit = float(config["guarantees"]["sum_rel_gap"])
+        here = dict.fromkeys(reference.ROUNDINGS, 0.0)
         for t in templates:
             holes = traffic.draw_holes(t, tables, rng)
             spec = reference.bind(t["reference"], holes)
             want = evaluate(spec, segs, tables)
-            c = reference.compare(spec, evaluate(spec, segs, tables, "bf16"),
-                                  want, limit)
-            worst_bf16 = max(worst_bf16, c["sum_gap"])
+            for name in here:
+                c = reference.compare(spec, evaluate(spec, segs, tables, name),
+                                      want, limit)
+                here[name] = max(here[name], c["sum_gap"])
             c = reference.compare(spec, evaluate(spec, segs[:3], tables), want,
                                   limit)
             counts_wrong += c["count_wrong"]
             rows_wrong += c["wrong"] or not c["sum_gap"] <= limit
             several[t["name"]] = c["count_wrong"]
-        assert worst_bf16 > 3 * limit, worst_bf16
+        assert min(here.values()) > 3 * limit, (config_name, here)
+        worst[config_name] = here
     assert counts_wrong >= 1 and rows_wrong >= 1, (counts_wrong, rows_wrong)
     assert several["q1-shape"] and several["minmax"], several
-    # bfloat16 on eight aggregates at once, at 240 rows a group: the rows'
-    # roundings cancel as the root of a group's rows, so that an unselective
-    # SUM of 10,000 rows a group sits at the limit and one of 670,000 under it
-    _, tables, segs = table_of("ssb10-flat", 3000, 2)
+    # Q1's eight aggregates at once. A rounding to the nearest bfloat16 errs
+    # evenly, so a group's errors cancel as the root of its rows: at 240 rows
+    # a group it fails the limit, at 168,000 (4 x 1Mi rows in 25 groups) it
+    # does not separate from it. The truncation errs one way and reads the
+    # same at both sizes.
+    config, tables, small = table_of(KEPT_AS_DATA_ON, 3000, 2)
+    limit = float(config["guarantees"]["sum_rel_gap"])
     spec = reference.bind(template("check/q1-shape")["reference"],
                           {"d": 19980603})
-    want = evaluate(spec, segs, tables)
-    got = evaluate(spec, segs, tables, "bf16")
-    q1 = reference.compare(spec, got, want, limit)["sum_gap"]
-    by_name = reference.gaps_by_name(spec, got, want)
-    assert q1 > 3 * limit and q1 == max(by_name.values()), (q1, by_name)
-    assert by_name["sum_qty"] == 0.0 and by_name["avg_price"] > limit \
-        and by_name["sum_charge"] > limit, by_name
-    print(f"ok controls: the reference at bfloat16 is {worst_bf16:.2e} off "
-          f"(limit {limit:.0e}), {q1:.2e} on Q1's eight aggregates at 240 rows "
-          f"a group; with a segment left out {counts_wrong} counts (Q1's "
-          f"shape and MIN/MAX among them) and {rows_wrong} other answers are "
-          f"wrong")
+    read = {}
+    for size, segs in (("small", small),
+                       ("large", table_of(KEPT_AS_DATA_ON, 1 << 20, 4)[2])):
+        want = evaluate(spec, segs, tables)
+        for name in reference.ROUNDINGS:
+            got = evaluate(spec, segs, tables, name)
+            by_name = reference.gaps_by_name(spec, got, want)
+            read[size, name] = reference.compare(spec, got, want,
+                                                 limit)["sum_gap"]
+            assert read[size, name] == max(by_name.values()), by_name
+            assert by_name["sum_qty"] == 0.0, by_name   # 1..50: eight bits hold
+            if (size, name) != ("large", "bf16"):
+                assert by_name["avg_price"] > 3 * limit \
+                    and by_name["sum_charge"] > 3 * limit, (size, name, by_name)
+    assert read["large", "bf16"] < 3 * limit, read
+    assert read["large", "bf16_truncated"] > 100 * limit, read
+    print(f"ok controls: by configuration {worst} (limit {limit:.0e}); on "
+          f"Q1's eight aggregates the rounded bfloat16 reads "
+          f"{read['small', 'bf16']:.2e} at 240 rows a group and "
+          f"{read['large', 'bf16']:.2e} at 168,000, where it no longer "
+          f"separates, the truncated one {read['small', 'bf16_truncated']:.2e} "
+          f"and {read['large', 'bf16_truncated']:.2e}; with a segment left out "
+          f"{counts_wrong} counts (Q1's shape and MIN/MAX among them) and "
+          f"{rows_wrong} other answers are wrong")
 
 
 def rehearse(workload: str, fault=None, control: int = 0) -> dict:
@@ -324,10 +345,10 @@ def check_runs() -> None:
                                    "memory_peak_bytes"}
     for c in line["checked"].values():
         assert set(c) == {"value", "limit"}
-    for name in ("bf16", "segment_left_out"):
+    for name in (*reference.ROUNDINGS, "segment_left_out"):
         assert line["control"][name]["correct"] is False, line["control"]
     print("ok last line: the contract's keys, `checked` last, correct true "
-          "on a clean rehearsal; both controls in its place correct false")
+          "on a clean rehearsal; every control in its place correct false")
 
     def is_number(v):
         return isinstance(v, (int, float)) and not isinstance(v, bool)

@@ -2,8 +2,8 @@
 `python3 -m pytest benchmark/tests -q` from the root of the repo, on the CPU.
 
 Every aggregate alone and all together against a python loop, the merge over
-segments, each planted fault seen by `compare`, both controls on an answer of
-several aggregates, sums past float64's 2**53 exact, and the one-aggregate
+segments, each planted fault seen by `compare`, every control on an answer of
+several aggregates, the truncated bfloat16 where the rounded one passes, sums past float64's 2**53 exact, and the one-aggregate
 form held to PR 33's evaluator (reference_pr33.py, frozen) to the bit.
 """
 
@@ -217,22 +217,47 @@ def test_compare_sees_a_wrong_order_by_an_aggregate(by):
         (1 if by == "least" else 0)
 
 
-def test_both_controls_fail_an_answer_of_several_aggregates():
+@pytest.mark.parametrize("precision", sorted(reference.ROUNDINGS))
+def test_a_lowered_precision_fails_an_answer_of_several_aggregates(precision):
     spec = CASES["all-grouped"]
     want = evaluate(spec)
-    bf16 = evaluate(spec, precision="bf16")
-    c = reference.compare(spec, bf16, want, LIMIT)
+    lowered = evaluate(spec, precision=precision)
+    c = reference.compare(spec, lowered, want, LIMIT)
     assert c["sum_gap"] > 3 * LIMIT and not c["wrong"] and not c["count_wrong"]
-    by_name = reference.gaps_by_name(spec, bf16, want)
+    by_name = reference.gaps_by_name(spec, lowered, want)
     assert set(by_name) == {"charge", "mean_price"}
     assert min(by_name.values()) > LIMIT and max(by_name.values()) == c["sum_gap"]
+
+
+def test_truncation_fails_a_sum_whose_roundings_cancel():
+    """One group of 2**20 rows of six-digit values: the errors of a rounding
+    to the nearest bfloat16 cancel under the limit, a truncation's do not."""
+    n = 1 << 20
+    cols = {"v": np.random.default_rng(35).integers(100_000, 1_000_000, n)}
+    spec = {"group_by": [], "select": ["s", "m"], "aggregates": [
+        {"name": "s", "fn": "sum", "terms": [{"columns": ["v"]}]},
+        {"name": "m", "fn": "avg", "terms": [{"columns": ["v"]}]}]}
+
+    def gap(precision):
+        part = reference.partial(spec, cols, {}, precision)
+        return reference.compare(
+            spec, reference.finish(spec, reference.merge([part]), {}),
+            [[int(cols["v"].sum()), cols["v"].sum() / n]], LIMIT)["sum_gap"]
+    assert gap("exact") == 0.0
+    assert gap("bf16") < LIMIT
+    assert 100 * LIMIT < gap("bf16_truncated") < 2.0 ** -7
+
+
+def test_a_segment_left_out_fails_an_answer_of_several_aggregates():
+    spec = CASES["all-grouped"]
+    want = evaluate(spec)
     left_out = reference.compare(spec, evaluate(spec, SEGS[:1]), want, LIMIT)
     assert left_out["count_wrong"] == 1 and left_out["sum_gap"] > 0.1
 
 
 def test_a_sum_past_float64_is_exact():
     """Q1's three-column product at the full table's scale: 2**22 rows of
-    about 1.5e11 in four groups. The limbs add to python's own whole numbers;
+    about 1.5e11 in four groups. int64 adds to python's own whole numbers;
     `finish` prints each within 2**-52 of it."""
     rng = np.random.default_rng(35)
     n = 1 << 22
