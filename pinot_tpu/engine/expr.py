@@ -9,7 +9,7 @@ host path powers selection/reduce/post-aggregation, so semantics match by constr
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Mapping
+from typing import Any, Callable, Dict, Mapping, Tuple
 
 import numpy as np
 
@@ -28,12 +28,15 @@ def register_function(name: str):
     return deco
 
 
-def eval_expr(e: Expr, columns: Mapping[str, Any], xp=np):
+def eval_expr(e: Expr, columns: Mapping[str, Any], xp=np, ranges=None):
     """Evaluate expression over a column environment.
 
     `columns` maps identifier name -> array (already decoded values, or whatever the
     caller wants identifiers to mean — the reduce stage maps aggregation result columns).
-    `xp` is numpy or jax.numpy.
+    `xp` is numpy or jax.numpy. `ranges` (device plans: `int_bounds`) maps a column to
+    the (lo, hi) its integers lie in, or to None for a column that is not of integers:
+    `+`, `-` and `*` of integers are WIDENED where the result can leave int32, never
+    wrapped (`_widen_host`, `_widen_device`).
     """
     if isinstance(e, Literal):
         return e.value
@@ -47,51 +50,161 @@ def eval_expr(e: Expr, columns: Mapping[str, Any], xp=np):
     args = e.args
 
     if name == "and":
-        out = _as_bool(eval_expr(args[0], columns, xp), xp)
+        out = _as_bool(eval_expr(args[0], columns, xp, ranges), xp)
         for a in args[1:]:
-            out = out & _as_bool(eval_expr(a, columns, xp), xp)
+            out = out & _as_bool(eval_expr(a, columns, xp, ranges), xp)
         return out
     if name == "or":
-        out = _as_bool(eval_expr(args[0], columns, xp), xp)
+        out = _as_bool(eval_expr(args[0], columns, xp, ranges), xp)
         for a in args[1:]:
-            out = out | _as_bool(eval_expr(a, columns, xp), xp)
+            out = out | _as_bool(eval_expr(a, columns, xp, ranges), xp)
         return out
     if name == "not":
-        return ~_as_bool(eval_expr(args[0], columns, xp), xp)
+        return ~_as_bool(eval_expr(args[0], columns, xp, ranges), xp)
     if name == "case":
         # case(w1, t1, ..., wn, tn, default): right-fold of xp.where
-        default = eval_expr(args[-1], columns, xp)
+        default = eval_expr(args[-1], columns, xp, ranges)
         out = default
         for i in range(len(args) - 3, -1, -2):
-            cond = _as_bool(eval_expr(args[i - 1], columns, xp), xp)
-            out = xp.where(cond, eval_expr(args[i], columns, xp), out)
+            cond = _as_bool(eval_expr(args[i - 1], columns, xp, ranges), xp)
+            out = xp.where(cond, eval_expr(args[i], columns, xp, ranges), out)
         return out
     if name == "cast":
-        val = eval_expr(args[0], columns, xp)
+        val = eval_expr(args[0], columns, xp, ranges)
         return _cast(val, args[1].value, xp)
     if name == "in":
-        needle = eval_expr(args[0], columns, xp)
+        needle = eval_expr(args[0], columns, xp, ranges)
         out = None
         for a in args[1:]:
-            m = needle == eval_expr(a, columns, xp)
+            m = needle == eval_expr(a, columns, xp, ranges)
             out = m if out is None else (out | m)
         return out
     if name == "not_in":
-        return ~eval_expr(Function("in", args), columns, xp)
+        return ~eval_expr(Function("in", args), columns, xp, ranges)
     if name == "between":
-        v = eval_expr(args[0], columns, xp)
-        return (v >= eval_expr(args[1], columns, xp)) & (v <= eval_expr(args[2], columns, xp))
+        v = eval_expr(args[0], columns, xp, ranges)
+        return (v >= eval_expr(args[1], columns, xp, ranges)) \
+            & (v <= eval_expr(args[2], columns, xp, ranges))
 
     binop = _BINOPS.get(name)
     if binop is not None:
-        left = eval_expr(args[0], columns, xp)
-        right = eval_expr(args[1], columns, xp)
+        left = eval_expr(args[0], columns, xp, ranges)
+        right = eval_expr(args[1], columns, xp, ranges)
+        if name in _WIDENING and _of_integers(left) and _of_integers(right):
+            if xp is np:
+                left, right = _widen_host(name, left, right)
+            elif widens(e, ranges):
+                left, right = _widen_device(left), _widen_device(right)
         return binop(left, right, xp)
 
     fn = _FUNCTIONS.get(name)
     if fn is not None:
-        return fn(xp, *[eval_expr(a, columns, xp) for a in args])
+        return fn(xp, *[eval_expr(a, columns, xp, ranges) for a in args])
     raise KeyError(f"unknown function {name!r}")
+
+
+# -- INT arithmetic that can leave int32 is widened, never wrapped ------------
+# Upstream's Addition/Subtraction/MultiplicationTransformFunction compute in
+# double. Here: on numpy (host executor, reduce, post-aggregation) operands
+# narrower than 64 bits go to int64, which holds every sum, difference and
+# product of two of them exactly, and 64-bit operands whose result can pass
+# 2^63 go to float64. Under jax.numpy (no 64-bit types on the chip) the
+# operands go to float32 where the result can leave int32, decided from the
+# plan's `ranges`: literals and the columns' min/max. A result that fits stays
+# the int32 program it was.
+
+_WIDENING = ("plus", "minus", "times")
+_INT32_LO, _INT32_HI = -(1 << 31), (1 << 31) - 1
+_NOT_INTEGERS = "not integers"   # `int_bounds` of a float sub-expression
+
+
+def _of_integers(v) -> bool:
+    if isinstance(v, (bool, np.bool_)):
+        return False
+    if isinstance(v, int):
+        return True
+    return getattr(getattr(v, "dtype", None), "kind", "") in "iu" \
+        and hasattr(v, "astype")
+
+
+def _reach(v) -> int:
+    """The largest magnitude among integers `v` (a python int)."""
+    if isinstance(v, int):
+        return abs(v)
+    v = np.asarray(v)
+    return max(abs(int(v.max())), abs(int(v.min()))) if v.size else 0
+
+
+def _widen_host(name: str, left, right):
+    """numpy operands of integers: to int64; to float64 where a 64-bit operand
+    (a LONG column, a product of products) lets the result pass 2^63."""
+    arrays = [v for v in (left, right) if hasattr(v, "astype")]
+    if any(v.dtype.itemsize >= 8 for v in arrays):
+        a, b = _reach(left), _reach(right)
+        if (a * b if name == "times" else a + b) >= 1 << 63:
+            return tuple(v.astype(np.float64) if hasattr(v, "astype")
+                         else float(v) for v in (left, right))
+    return tuple(v.astype(np.int64) if hasattr(v, "astype") else v
+                 for v in (left, right))
+
+
+def _widen_device(v):
+    return v.astype(np.float32) if hasattr(v, "astype") else v
+
+
+def int_bounds(e: Expr, ranges):
+    """What the plan knows of an expression's integers: (lo, hi);
+    _NOT_INTEGERS for a float sub-expression; None where it cannot see (a
+    column `ranges` does not hold, a function that is not `+`, `-`, `*`)."""
+    if isinstance(e, Literal):
+        v = e.value
+        if isinstance(v, (int, np.integer)):    # a bool is 0 or 1
+            return int(v), int(v)
+        return _NOT_INTEGERS if isinstance(v, (float, np.floating)) else None
+    if isinstance(e, Identifier):
+        if not ranges or e.name not in ranges:
+            return None
+        return _NOT_INTEGERS if ranges[e.name] is None else ranges[e.name]
+    if e.name == "divide":
+        return _NOT_INTEGERS
+    if e.name == "cast":
+        return _NOT_INTEGERS if str(e.args[1].value).upper() in (
+            "FLOAT", "DOUBLE") else None
+    if e.name not in _WIDENING:
+        return None
+    a, b = (int_bounds(x, ranges) for x in e.args)
+    if _NOT_INTEGERS in (a, b):
+        return _NOT_INTEGERS
+    if a is None or b is None:
+        return None
+    if e.name == "plus":
+        return a[0] + b[0], a[1] + b[1]
+    if e.name == "minus":
+        return a[0] - b[1], a[1] - b[0]
+    corners = [x * y for x in a for y in b]
+    return min(corners), max(corners)
+
+
+def widens(e: Expr, ranges) -> bool:
+    """Whether the device evaluates this `+`, `-` or `*` of integers in
+    float32: its result can leave int32; or, where the plan cannot see a
+    range, it is a product of two non-literals."""
+    b = int_bounds(e, ranges)
+    if b == _NOT_INTEGERS:
+        return False
+    if b is None:       # no operand is known to be a float, or b would say so
+        return e.name == "times" and not any(isinstance(a, Literal)
+                                             for a in e.args)
+    return b[0] < _INT32_LO or b[1] > _INT32_HI
+
+
+def widen_marks(e, ranges) -> Tuple[bool, ...]:
+    """`widens` of every `+`, `-`, `*` in `e`, in walk order: what of `ranges`
+    a compiled program depends on (part of the kernels' cache keys)."""
+    if not isinstance(e, Function):
+        return ()
+    mine = (widens(e, ranges),) if e.name in _WIDENING else ()
+    return mine + tuple(m for a in e.args for m in widen_marks(a, ranges))
 
 
 def _as_bool(v, xp):

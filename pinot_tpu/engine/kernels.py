@@ -52,7 +52,7 @@ from ..sql.ast import Identifier
 from ..utils.memledger import get_ledger
 from ..utils.metrics import get_registry
 from .caps import get_caps
-from .expr import eval_expr
+from .expr import eval_expr, widen_marks
 
 _INT_MIN_IDENT = np.iinfo(np.int32).max  # identity for masked-out min over int
 _INT_MAX_IDENT = np.iinfo(np.int32).min
@@ -119,6 +119,13 @@ class KernelSpec:
     # staged layout (vals[col] is the decoded column), so the flag is part of
     # `signature()` — fused and staged plans never share a compiled kernel.
     fused_cols: Tuple[Tuple[str, str], ...] = ()
+    # what the plan knows of the value columns' integers (`expr.int_bounds`):
+    # column -> (lo, hi), or None for a column that is not of integers. `+`,
+    # `-` and `*` whose result can leave int32 are evaluated in float32
+    # (`expr.widens`); `signature()` holds the choices (`_widen_marks`), never
+    # the ranges, so segments whose min/max differ share a program.
+    int_ranges: Dict[str, Optional[Tuple[int, int]]] = field(
+        default_factory=dict)
 
     # per-leaf runtime input routing, computed in __post_init__
     lut_index: Dict[int, int] = field(default_factory=dict)       # dense (scattered) LUTs
@@ -173,6 +180,7 @@ class KernelSpec:
             self.mv_cols,
             self.bitmap_leaves,
             self.fused_cols,
+            _filter_widen_marks(self), _agg_widen_marks(self),
             # regime caps change the traced program for the same plan shape
             get_caps().token(),
         )
@@ -379,6 +387,23 @@ def gather_free(spec: KernelSpec, vals) -> bool:
         for col, form in spec.fused_cols if form == "dict")
 
 
+def _filter_widen_marks(spec: KernelSpec) -> Tuple:
+    return tuple(widen_marks(leaf.expr, spec.int_ranges)
+                 for leaf in spec.filter.leaves if isinstance(leaf, CmpLeaf))
+
+
+def _agg_widen_marks(spec: KernelSpec) -> Tuple:
+    return tuple(widen_marks(agg.arg, spec.int_ranges)
+                 for agg, outs in spec.aggs if "distinct" not in outs)
+
+
+def widened(spec: KernelSpec) -> bool:
+    """Whether a launch of `spec` evaluates an aggregate's argument widened
+    (what `widenedAggLaunches` counts): some `+`, `-` or `*` of integers in it
+    can leave int32 by the plan's ranges and is computed in float32."""
+    return any(any(marks) for marks in _agg_widen_marks(spec))
+
+
 def slabbed(spec: KernelSpec, rows: int) -> bool:
     """Whether a launch of `spec` over `rows` rows a device (a static shape of
     its inputs) runs a matmul regime of its GROUP BY over more than one slab
@@ -463,7 +488,7 @@ def _make_mask_fn(spec: KernelSpec):
             m = nulls[leaf.col]
             return ~m if leaf.negated else m
         assert isinstance(leaf, CmpLeaf)
-        v = eval_expr(leaf.expr, vals, jnp)
+        v = eval_expr(leaf.expr, vals, jnp, spec.int_ranges)
         arr_name, off = spec.cmp_offset[i]
         sc = iscal if arr_name == "iscal" else fscal
         if leaf.op == "eq":
@@ -1183,7 +1208,7 @@ def _make_body(spec: KernelSpec):
                             ai, agg, ids, key, mask, took)
                     continue
                 with scope("pinot.groupby.key"):
-                    v = _agg_arg(agg, vals)
+                    v = _agg_arg(agg, vals, spec.int_ranges)
                     for o in outs:
                         if o in _POWER_SUMS:
                             # sums of powers ride the same stacked matmul
@@ -1249,7 +1274,7 @@ def _make_body(spec: KernelSpec):
                 if outs == ("count",):
                     continue
                 with scope("pinot.agg"):
-                    v = _agg_arg(agg, vals)
+                    v = _agg_arg(agg, vals, spec.int_ranges)
                     for o in outs:
                         if o == "count":
                             continue
@@ -1316,6 +1341,8 @@ def run_kernel(spec: KernelSpec, inputs: KernelInputs) -> Dict[str, np.ndarray]:
         qstats.record(qstats.GATHER_FREE_LAUNCHES)
     if slabbed(spec, inputs.valid.size):
         qstats.record(qstats.SLABBED_LAUNCHES)
+    if widened(spec):
+        qstats.record(qstats.WIDENED_AGG_LAUNCHES)
     # device_get, never np.asarray: asarray syncs leaf by leaf, device_get
     # fetches the whole tree in one batched round trip
     return _record_decode(fetch_outputs(dispatch_kernel(spec, inputs)))
@@ -1334,7 +1361,8 @@ def _staged_agg_spec(spec: KernelSpec) -> KernelSpec:
     no fused columns (staged inputs are decoded HBM columns)."""
     return KernelSpec(FilterProgram(), spec.group_cols, spec.num_keys_pad,
                       spec.aggs, dict(spec.distinct_lut_sizes),
-                      spec.padded_rows, mv_cols=spec.mv_cols)
+                      spec.padded_rows, mv_cols=spec.mv_cols,
+                      int_ranges=spec.int_ranges)
 
 
 def run_kernel_staged(spec: KernelSpec,
@@ -1356,6 +1384,8 @@ def run_kernel_staged(spec: KernelSpec,
     agg_spec = _staged_agg_spec(spec)
     if slabbed(agg_spec, inputs.valid.size):
         qstats.record(qstats.SLABBED_LAUNCHES)
+    if widened(agg_spec):
+        qstats.record(qstats.WIDENED_AGG_LAUNCHES)
     outs = get_kernel(agg_spec)(inputs.ids, inputs.vals, inputs.luts,
                                 inputs.iscal, inputs.fscal, inputs.nulls,
                                 mask_dev, inputs.strides, inputs.agg_luts,
@@ -1367,7 +1397,7 @@ def _mask_kernel(spec: KernelSpec):
     """Cached jit of the filter-only kernel (selection queries and the staged
     pair's first launch share it)."""
     key = ("mask", spec.filter.signature(), spec.padded_rows,
-           spec.bitmap_leaves, spec.fused_cols)
+           spec.bitmap_leaves, spec.fused_cols, _filter_widen_marks(spec))
 
     def build():
         mask_fn = _make_mask_fn(spec)
@@ -1455,7 +1485,8 @@ def topk_kernel(spec: KernelSpec, order_expr, desc: bool, k: int,
     outputs asynchronously in the pipeline's batched device_get."""
     k = min(k, total_rows if total_rows is not None else spec.padded_rows)
     key = ("topk", spec.filter.signature(), repr(order_expr), desc, k,
-           spec.padded_rows, total_rows, spec.fused_cols)
+           spec.padded_rows, total_rows, spec.fused_cols,
+           _filter_widen_marks(spec), widen_marks(order_expr, spec.int_ranges))
 
     def build():
         mask_fn = _make_mask_fn(spec)
@@ -1464,7 +1495,8 @@ def topk_kernel(spec: KernelSpec, order_expr, desc: bool, k: int,
             vals = _fused_env(spec, ids, vals, iscal)
             mask = mask_fn(ids, vals, luts, iscal, fscal, nulls, valid, docsets).ravel()
             with jax.named_scope("pinot.topk"):
-                v = eval_expr(order_expr, vals, jnp).ravel().astype(jnp.float32)
+                v = eval_expr(order_expr, vals, jnp,
+                              spec.int_ranges).ravel().astype(jnp.float32)
                 # NaN keys sink to the bottom (numpy sorts NaN last ascending;
                 # exact parity for NaN keys is out of contract either way)
                 nan = jnp.isnan(v)
@@ -1503,7 +1535,7 @@ def compute_topk(spec: KernelSpec, inputs: KernelInputs, order_expr,
             np.asarray(outs["ok"]))
 
 
-def _agg_arg(agg: AggFunc, vals) -> Optional[jnp.ndarray]:
+def _agg_arg(agg: AggFunc, vals, ranges) -> Optional[jnp.ndarray]:
     if agg.arg is None or (isinstance(agg.arg, Identifier) and agg.arg.name == "*"):
         return None
-    return eval_expr(agg.arg, vals, jnp)
+    return eval_expr(agg.arg, vals, jnp, ranges)

@@ -47,12 +47,13 @@ import numpy as np
 
 from ..engine.datablock import lut_size, padded_rows
 from ..engine.kernels import (KernelSpec, _fence_first_call, gather_free,
-                              slabbed, tree_bytes)
+                              slabbed, tree_bytes, widened)
 from ..query import stats as qstats
 from ..query.aggregates import make_agg
 from ..query.context import QueryContext, compile_query
 from ..query.executor import ServerQueryExecutor
-from ..query.planner import build_device_geometry, plan_segment
+from ..query.planner import (build_device_geometry, int_ranges,
+                             plan_segment)
 from ..query.predicate import CmpLeaf, LutLeaf, NullLeaf
 from ..query.reduce import merge_segment_results, reduce_to_result
 from ..query.result import ResultTable
@@ -1073,7 +1074,8 @@ class MeshQueryExecutor:
         spec = KernelSpec(plan.filter_prog, plan.group_cols, plan.num_keys_pad,
                           tuple(agg_specs), distinct_lut_sizes, block.rows,
                           mv_cols=_mv_lut_cols(plan, plan.segment),
-                          fused_cols=fused_cols)
+                          fused_cols=fused_cols,
+                          int_ranges=int_ranges(plan))
 
         # -- gather runtime inputs ------------------------------------
         # ids only where dict ids are semantically needed (group keys, interval/LUT
@@ -1257,7 +1259,8 @@ class MeshQueryExecutor:
         from ..engine.kernels import topk_kernel
         s_pad = pad_slots(len(segments), self.n_devices)
         block = self._block_for(segments, None, s_pad)
-        spec = KernelSpec(plan.filter_prog, (), 1, (), {}, block.rows)
+        spec = KernelSpec(plan.filter_prog, (), 1, (), {}, block.rows,
+                          int_ranges=int_ranges(plan, [order.expr]))
 
         ids_cols, vals_cols, nulls_cols = set(), {col}, set()
         luts, iscal, fscal = [], [], []
@@ -1555,6 +1558,8 @@ class MeshQueryExecutor:
                     out_specs=out_specs))
             return compiled
 
+        is_widened = widened(spec)      # from the static plan, once
+
         def fn(inputs):
             compiled = jitted_for(inputs)
             for key, v in built.get("mesh", {}).items():
@@ -1566,6 +1571,8 @@ class MeshQueryExecutor:
                 rows_read = rows_read // held * window
             if slabbed(spec, rows_read):
                 qstats.record(qstats.SLABBED_LAUNCHES)
+            if is_widened:
+                qstats.record(qstats.WIDENED_AGG_LAUNCHES)
             return compiled(inputs)
 
         fn.jitted_for = jitted_for

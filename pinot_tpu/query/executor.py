@@ -20,7 +20,8 @@ from ..sql.ast import Expr, Function, Identifier, identifiers_in
 from . import stats as qstats
 from .aggregates import AggFunc, make_agg
 from .context import QueryContext, compile_query
-from .planner import SegmentPlan, build_device_geometry, plan_segment
+from .planner import (SegmentPlan, build_device_geometry, int_ranges,
+                      plan_segment)
 from .predicate import CmpLeaf, DocSetLeaf, LutLeaf, NullLeaf
 from .reduce import DensePartial, SegmentResult, merge_segment_results, reduce_to_result
 from .result import ResultTable
@@ -226,7 +227,8 @@ class ServerQueryExecutor:
                                   tuple(agg_specs), distinct_lut_sizes, block.padded,
                                   mv_cols=_mv_lut_cols(plan, seg),
                                   bitmap_leaves=plan.bitmap_leaves,
-                                  fused_cols=fused_cols or ())
+                                  fused_cols=fused_cols or (),
+                                  int_ranges=int_ranges(plan))
         inputs = self._kernel_inputs(plan, spec, block)
         if fused_cols is None:
             outs = kernels.run_kernel_staged(spec, inputs)
@@ -640,7 +642,8 @@ class ServerQueryExecutor:
         from ..engine.datablock import block_for
         block = block_for(seg)
         spec = kernels.KernelSpec(plan.filter_prog, (), 1, (), {}, block.padded,
-                                  mv_cols=_mv_lut_cols(plan, seg))
+                                  mv_cols=_mv_lut_cols(plan, seg),
+                                  int_ranges=int_ranges(plan, [order.expr]))
         inputs = self._kernel_inputs(plan, spec, block)
         for c in identifiers_in(order.expr):
             if c not in inputs.vals:
@@ -672,7 +675,8 @@ class ServerQueryExecutor:
             plan.bitmap_leaves = self._bitmap_leaves(plan, seg)
             spec = kernels.KernelSpec(plan.filter_prog, (), 1, (), {}, block.padded,
                                       mv_cols=_mv_lut_cols(plan, seg),
-                                      bitmap_leaves=plan.bitmap_leaves)
+                                      bitmap_leaves=plan.bitmap_leaves,
+                                      int_ranges=int_ranges(plan))
             inputs = self._kernel_inputs(plan, spec, block)
             return kernels.compute_mask(spec, inputs)[:seg.num_docs]
         return host_filter_mask(plan, seg)

@@ -54,6 +54,7 @@ def _accelerator_backend() -> bool:
 _ACCELERATOR_BACKEND: Optional[bool] = None
 
 from ..engine.datetime_fns import DEVICE_DATETIME_FUNCS
+from ..engine.expr import widen_marks
 
 _DEVICE_FUNCS = {"plus", "minus", "times", "divide", "mod", "case", "cast", "abs", "ceil",
                  "floor", "exp", "ln", "log10", "log2", "log", "sqrt", "power", "round",
@@ -388,6 +389,12 @@ def _device_feasible(plan: SegmentPlan, segment: ImmutableSegment) -> str:
             err = _expr_device_ok(arg, segment)
             if err:
                 return err
+            if {"min", "max"} & set(agg.device_outputs) \
+                    and any(widen_marks(arg, int_ranges(plan))):
+                # widened, the device's MIN/MAX would be a float32 near the
+                # whole number; the host computes it in int64
+                return (f"{agg.name} of INT arithmetic that leaves int32 "
+                        f"(exact on the host: the device has no 64-bit integers)")
 
     if plan.filter_prog:
         for leaf in plan.filter_prog.leaves:
@@ -449,6 +456,36 @@ def _expr_device_ok(e: Expr, segment: ImmutableSegment) -> str:
                     return err
         return ""
     return check(e)
+
+
+def int_ranges(plan: SegmentPlan, extra=()) -> Dict[str, Optional[Tuple[int, int]]]:
+    """What the plan knows of the integers in every column its compare leaves,
+    its aggregate arguments and the `extra` expressions read: column -> (lo,
+    hi) from the segment's (on the mesh path the SET's) min/max, the type's own
+    range where the metadata has none, and None for a column that is not of
+    integers. `KernelSpec.int_ranges`: from it the device decides which `+`,
+    `-`, `*` leave int32 and are computed in float32 (`engine/expr.widens`)."""
+    exprs = [leaf.expr for leaf in (plan.filter_prog.leaves if plan.filter_prog
+                                    else ()) if isinstance(leaf, CmpLeaf)]
+    exprs += [a.arg for a in plan.aggs if a.arg is not None] + list(extra)
+    out: Dict[str, Optional[Tuple[int, int]]] = {}
+    for name in {n for e in exprs for n in identifiers_in(e)}:
+        try:
+            reader = plan.segment.column(name)
+        except KeyError:
+            continue
+        if not reader.data_type.is_numeric:
+            continue
+        dtype = np.dtype(reader.data_type.numpy_dtype)
+        if dtype.kind not in "iu":
+            out[name] = None
+            continue
+        mn, mx = reader.min_value, reader.max_value
+        if isinstance(mn, (int, np.integer)) and isinstance(mx, (int, np.integer)):
+            out[name] = (int(mn), int(mx))
+        else:
+            out[name] = (int(np.iinfo(dtype).min), int(np.iinfo(dtype).max))
+    return out
 
 
 def build_device_geometry(plan: SegmentPlan) -> None:

@@ -127,7 +127,8 @@ def _real_spec(spec):
                               spec.aggs, spec.distinct_lut_sizes, SEG_ROWS,
                               mv_cols=spec.mv_cols,
                               bitmap_leaves=spec.bitmap_leaves,
-                              fused_cols=spec.fused_cols)
+                              fused_cols=spec.fused_cols,
+                              int_ranges=spec.int_ranges)
 
 
 def _compile_agg(topo, cpu_exec, segments, name, segs, n_devices=1):
@@ -198,6 +199,53 @@ def test_served_agg_kernel_compiles_for_v5e(topo, cpu_exec, segments, name,
         sorts = [ln for ln in text.splitlines() if " sort(" in ln]
         assert all(any(f"[{n}]" in ln for ln in sorts)
                    for n in (rows, rows // 64, rows // 16))
+
+
+@pytest.fixture(scope="module")
+def lineitem(tmp_path_factory):
+    """Two small segments of the benchmark's `tpch10-lineitem` table, built as
+    a run builds them (its generator, schema and raw price column)."""
+    from benchmark.harness import build, cells
+    config = cells.read_json(cells.BENCH, "configs", "tpch10-lineitem.json")
+    out = tmp_path_factory.mktemp("chipcompile_tpch")
+    return [load_segment(build.build_segment(
+        {"config": config, "seed": 36, "index": i, "rows": 1 << 16,
+         "out_dir": str(out)})["seg_dir"]) for i in range(2)]
+
+
+@pytest.mark.parametrize("template,literals", [
+    ("q1", {"d": 19980902}),
+    ("q6", {"lo": 19940101, "hi": 19950101, "dlo": 5, "dhi": 7, "q": 24}),
+])
+def test_tpch_program_compiles_for_v5e_at_67m_rows(topo, cpu_exec, lineitem,
+                                                   template, literals):
+    """TPC-H Q1 and Q6 as the `tpch10-lineitem.tpch-q1q6-c4` cell serves
+    them, at its [16, 4Mi] rows on one described chip (PR 36). Q1: seven
+    value rows and a count over 9 key cells in ONE one-hot launch over four
+    slabs (one loop, no contraction over the 64Mi rows), its charge widened to
+    float32; Q6 is the fused dictionary scan, its product the int32 it fits."""
+    from benchmark.harness import cells
+    sql = cells.read_json(cells.BENCH, "queries", "tpch",
+                          template + ".json")["sql"].format(**literals)
+    p = cpu_exec.prepare_partial(compile_query(sql, lineitem[0].schema),
+                                 lineitem)
+    assert p is not None, f"{template}: the plan is not device-eligible"
+    mesh = _mesh(topo, 1)
+    ax = _abstract(p.inputs, (p.s_pad, p.rows), (SMOKE_SEGS, SEG_ROWS), mesh)
+    spec = _real_spec(p.spec)
+    fn = MeshQueryExecutor(mesh)._build_shard_kernel(spec)
+    compiled = fn.jitted_for(ax).lower(ax).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    assert p.spec.fused_cols, "the served scan decodes in-register"
+    q1 = template == "q1"
+    assert kernels.widened(spec) == q1
+    assert kernels.slabbed(spec, SMOKE_SEGS * SEG_ROWS) == q1
+    assert ("pinot.groupby.onehot" in text) == q1
+    assert (" while(" in text) == q1 and (" convolution(" in text) == q1
+    assert " sort(" not in text
+    assert f"[{SMOKE_SEGS * SEG_ROWS}]" not in "".join(
+        ln for ln in text.splitlines() if " convolution(" in ln)
 
 
 # (smoke query, the window's slots of the 16 resident, sort regime)
