@@ -68,11 +68,12 @@ _STAGES = ("queue_wait", "dispatch", "prepare", "launch", "handoff", "fetch",
 #: what a launch records from the static shapes its program was built with,
 #: also summed over the launches in `stats()`: on more than one device, what
 #: crosses the chips; past 2^24 rows a device, a matmul GROUP BY slab by slab;
-#: an aggregate's INT arithmetic widened where it would leave int32;
+#: an aggregate's INT arithmetic widened where it would leave int32; a GROUP
+#: BY of a handful of key cells as the masked reduce;
 #: over a resident set, the slots routed, held and read, and the id space
 _SHAPE_KEYS = (qstats.MESH_LAUNCHES, qstats.SCATTER_LAUNCHES,
                qstats.COLLECTIVE_BYTES, qstats.SLABBED_LAUNCHES,
-               qstats.WIDENED_AGG_LAUNCHES,
+               qstats.WIDENED_AGG_LAUNCHES, qstats.MASKED_GROUPBY_LAUNCHES,
                qstats.ROUTED_SLOTS, qstats.RESIDENT_SLOTS,
                qstats.SCANNED_SLOTS, qstats.MERGED_LAUNCHES)
 

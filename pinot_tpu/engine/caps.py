@@ -27,7 +27,17 @@ class KernelCaps:
     """Crossovers of the kernel ladders. Key counts are PADDED keys + 1 (the
     overflow bucket), as `_make_body` compares them."""
 
-    # SKINNY one-hot matmul ([1+sums, N] @ [N, keys]) up to here: each
+    # MASKED VPU reduce (`kernels._masked_sums`: a compare, a select and a
+    # reduce a key cell and value row, no MXU) up to here. Its cost is linear
+    # in cells x rows, the one-hot matmul's flat to 128 cells. Measured v5e,
+    # 2^26 rows of uniform keys, ms (one-hot | masked; PERF.md, PR 36, call
+    # 2): count + 1 value row 21.1 | 6.9 at 9 cells, 29.2 | 20.1 at 65,
+    # 40.7 | 38.5 at 129; count + 7 rows 98.3 | 29.7 at 9, 106.4 | 87.2 at
+    # 65, 118.2 | 146.7 at 129: it wins on both sides up to 65 cells and
+    # LOSES at 129 with several value rows. Its sums are also tighter (TPC-H
+    # Q1 at 67M rows: 1.6e-7 against the one-hot regime's 9.3e-6).
+    masked_cap: int = 65
+    # SKINNY one-hot matmul ([1+sums, N] @ [N, keys]) from there to here: each
     # 128-wide output column tile re-walks the full contraction, so cost grows
     # linearly in keys and the chunked 64x64 formulation overtakes it at some
     # key count. Where is not yet measured on the directly attached chip

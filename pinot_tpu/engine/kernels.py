@@ -7,7 +7,8 @@ This replaces the reference's entire per-segment operator chain
     mask   = filter_tree(id-interval compares | vector compares | null bitmaps) & valid
     key    = sum(group_ids * strides)        (dense dict-id keys, reference:
                                               DictionaryBasedGroupKeyGenerator.java:62)
-    partials = [mask; masked values] @ one_hot(key)   (ONE stacked matmul on the MXU)
+    partials = [mask; masked values] @ one_hot(key)   (ONE stacked matmul on the MXU;
+                                              a masked reduce up to `masked_cap` key cells)
 
 GATHER-FREE ON THE HOT MASK PATH: the kernel favors compares, selects, reductions and
 matmuls —
@@ -18,9 +19,12 @@ matmuls —
   (SELECT_DECODE_CAP; the one gather left is a table wider than that); staged
   plans: host-materialized value columns cached in HBM (`datablock.values`);
 * group-by partials -> one ladder over the padded key count, its crossovers
-  the constants of `engine/caps.py`: the one-hot matmul `[rows, N] @ [N, keys]`
-  up to `matmul_cap` (the common OLAP case; XLA fuses the iota-compare into the
-  dot's tiles), the CHUNKED 64x64-tile matmul `_grouped_chunk64` from there to
+  the constants of `engine/caps.py`: a masked VPU reduce a key cell and value
+  row (`_masked_sums`: no MXU, no slabs, int32 counts) up to `masked_cap`, a
+  handful of keys (a status, a flag, a region: TPC-H Q1); the one-hot matmul
+  `[rows, N] @ [N, keys]` from there to `matmul_cap` (XLA fuses the
+  iota-compare into the dot's tiles), the CHUNKED 64x64-tile matmul
+  `_grouped_chunk64` from there to
   `chunk_cap` (high-cardinality group-by AND the grouped-distinct presence
   product space, bf16 3-part-split operands at full MXU tile utilization), and
   past `chunk_cap` the radix-partitioned sort `_grouped_partitioned` (no n-row
@@ -404,14 +408,29 @@ def widened(spec: KernelSpec) -> bool:
     return any(any(marks) for marks in _agg_widen_marks(spec))
 
 
+def masked(spec: KernelSpec) -> bool:
+    """Whether a launch of `spec` runs its GROUP BY's counts and sums as the
+    masked VPU reduce (what `maskedGroupByLaunches` counts): at most
+    `masked_cap` padded keys + 1, the bottom rung of `_make_body`'s ladder."""
+    return (bool(spec.group_cols)
+            and spec.num_keys_pad + 1 <= get_caps().masked_cap)
+
+
 def slabbed(spec: KernelSpec, rows: int) -> bool:
     """Whether a launch of `spec` over `rows` rows a device (a static shape of
     its inputs) runs a matmul regime of its GROUP BY over more than one slab
-    (what `slabbedLaunches` counts): past SLAB_ROWS rows, at most `chunk_cap`
-    keys. A grouped distinct's product space is the wider of the two, so it
-    is on the matmul side only where the group-by itself is."""
-    return (bool(spec.group_cols) and slab_count(rows) > 1
-            and spec.num_keys_pad + 1 <= get_caps().chunk_cap)
+    (what `slabbedLaunches` counts): past SLAB_ROWS rows, more than
+    `masked_cap` keys (the masked reduce counts in int32 and builds no slab)
+    and at most `chunk_cap`. A grouped distinct's product space is the wider
+    of the two, so past `masked_cap` it is on the matmul side only where the
+    group-by itself is; under it the presence product alone decides."""
+    if not spec.group_cols or slab_count(rows) == 1:
+        return False
+    num_seg, caps = spec.num_keys_pad + 1, get_caps()
+    if not masked(spec):
+        return num_seg <= caps.chunk_cap
+    return any(num_seg * size <= caps.chunk_cap
+               for _, size in spec.distinct_lut_sizes.items())
 
 
 def _fused_env(spec: KernelSpec, ids, vals, iscal):
@@ -580,6 +599,31 @@ def _presence_2d(fmask: jnp.ndarray, col_ids: jnp.ndarray, size: int) -> jnp.nda
             preferred_element_type=jnp.float32).reshape(-1))
     counts = jnp.concatenate(chunks) if len(chunks) > 1 else chunks[0]
     return counts[:size]
+
+
+def _masked_sums(key: jnp.ndarray, num_seg: int, rows):
+    """[int32 counts[num_seg], f32 sums[num_seg]...] of a GROUP BY over a
+    handful of key cells, on the VPU: for each real key cell `k` the compare
+    `key == k`, the count an int32 sum of it (exact at any row count: no f32
+    cell, so no slabs and no loop) and each value row's sum one f32 reduce of
+    the rows the compare selects. `rows[0]` is the count row (the 0/1 mask),
+    which the compare stands in for: masked-out rows carry the overflow key
+    `num_seg - 1`, whose cell is filled with the zero it would sum to, as
+    `_grouped_chunk64` fills it.
+
+    FLAT, one reduce a cell and row, which XLA fuses into few passes over the
+    rows (TPC-H Q1 at 67M rows on the v5e, 9 cells and 7 value rows: 8.1 ms
+    and 1.6e-7 of the int64 sums, where the one-hot regime in four slabs read
+    66.2 ms and 9.3e-6; PERF.md, PR 36; served, PR 37: Q1 alone 8.88 ms for
+    72.08). NOT two levels (blocks of 2^12 rows
+    summed first): XLA did not fold them, 14.9 ms and five times wider. No
+    bf16 parts: the f32 rows are added as they are. Linear in cells x rows,
+    so it is the ladder's bottom rung alone (`KernelCaps.masked_cap`)."""
+    hits = [key == k for k in range(num_seg - 1)]
+    cells = lambda per_hit: jnp.pad(jnp.stack(per_hit), (0, 1))  # noqa: E731
+    return [cells([jnp.sum(h, dtype=jnp.int32) for h in hits])] + [
+        cells([jnp.sum(jnp.where(h, r, 0.0)) for h in hits])
+        for r in rows[1:]]
 
 
 def _onehot_sums(key: jnp.ndarray, num_seg: int, rows) -> jnp.ndarray:
@@ -1197,8 +1241,9 @@ def _make_body(spec: KernelSpec):
                     key = key + ids[gc] * strides[gi]
                 key = jnp.where(mask, key, spec.num_keys_pad).ravel()
                 fmask = mask.ravel().astype(jnp.float32)
-            # collect count + every sum row, then ONE stacked one-hot matmul:
-            # [1 + n_sums, N] @ one_hot(key)[N, num_seg] -> [1 + n_sums, num_seg]
+            # collect count + every sum row for ONE launch of the ladder's
+            # regime (the one-hot matmul: [1 + n_sums, N] @ one_hot(key)
+            # [N, num_seg] -> [1 + n_sums, num_seg])
             sum_rows, sum_names = [fmask], ["count"]
             minmax = []  # (out name, values, is_min)
             for ai, (agg, outs) in enumerate(spec.aggs):
@@ -1221,10 +1266,16 @@ def _make_body(spec: KernelSpec):
                         elif o in ("min", "max"):
                             minmax.append((f"{ai}.{o}", v.ravel(), o == "min"))
             # the ladder: the padded key count against `KernelCaps` alone. The
-            # row count chooses nothing: the matmul regimes count in int32
-            # across slabs of at most SLAB_ROWS rows (`_slab_sums`).
+            # row count chooses nothing: the masked reduce counts in int32,
+            # the matmul regimes in int32 across slabs of at most SLAB_ROWS
+            # rows (`_slab_sums`).
             # Each regime: [int32 counts[num_seg], f32 sums[num_seg]...]
-            if num_seg <= caps.matmul_cap:
+            if num_seg <= caps.masked_cap:
+                # A HANDFUL of key cells: a compare, a select and a reduce a
+                # cell and row on the VPU beat any walk of the contraction
+                with scope("pinot.groupby.masked"):
+                    res = _masked_sums(key, num_seg, sum_rows)
+            elif num_seg <= caps.matmul_cap:
                 with scope("pinot.groupby.onehot"):
                     res = _slab_sums(key, num_seg, sum_rows, lambda k, r:
                                      _onehot_sums(k, num_seg, r))
@@ -1339,13 +1390,21 @@ def run_kernel(spec: KernelSpec, inputs: KernelInputs) -> Dict[str, np.ndarray]:
     qstats.record(qstats.FUSED_LAUNCHES)
     if gather_free(spec, inputs.vals):
         qstats.record(qstats.GATHER_FREE_LAUNCHES)
-    if slabbed(spec, inputs.valid.size):
-        qstats.record(qstats.SLABBED_LAUNCHES)
-    if widened(spec):
-        qstats.record(qstats.WIDENED_AGG_LAUNCHES)
+    _record_plan(spec, inputs.valid.size)
     # device_get, never np.asarray: asarray syncs leaf by leaf, device_get
     # fetches the whole tree in one batched round trip
     return _record_decode(fetch_outputs(dispatch_kernel(spec, inputs)))
+
+
+def _record_plan(spec: KernelSpec, rows: int) -> None:
+    """Count what a launch of `spec` over `rows` rows a device is, from the
+    static plan: its GROUP BY regime's counters and the widened argument."""
+    if masked(spec):
+        qstats.record(qstats.MASKED_GROUPBY_LAUNCHES)
+    if slabbed(spec, rows):
+        qstats.record(qstats.SLABBED_LAUNCHES)
+    if widened(spec):
+        qstats.record(qstats.WIDENED_AGG_LAUNCHES)
 
 
 def _record_decode(outs):
@@ -1382,10 +1441,7 @@ def run_kernel_staged(spec: KernelSpec,
         mask_dev = dispatch_mask(spec, inputs)
         qstats.record(qstats.STAGED_LAUNCHES, 2)
     agg_spec = _staged_agg_spec(spec)
-    if slabbed(agg_spec, inputs.valid.size):
-        qstats.record(qstats.SLABBED_LAUNCHES)
-    if widened(agg_spec):
-        qstats.record(qstats.WIDENED_AGG_LAUNCHES)
+    _record_plan(agg_spec, inputs.valid.size)
     outs = get_kernel(agg_spec)(inputs.ids, inputs.vals, inputs.luts,
                                 inputs.iscal, inputs.fscal, inputs.nulls,
                                 mask_dev, inputs.strides, inputs.agg_luts,

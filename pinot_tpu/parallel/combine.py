@@ -47,7 +47,7 @@ import numpy as np
 
 from ..engine.datablock import lut_size, padded_rows
 from ..engine.kernels import (KernelSpec, _fence_first_call, gather_free,
-                              slabbed, tree_bytes, widened)
+                              masked, slabbed, tree_bytes, widened)
 from ..query import stats as qstats
 from ..query.aggregates import make_agg
 from ..query.context import QueryContext, compile_query
@@ -1558,7 +1558,8 @@ class MeshQueryExecutor:
                     out_specs=out_specs))
             return compiled
 
-        is_widened = widened(spec)      # from the static plan, once
+        # from the static plan, once
+        is_widened, is_masked = widened(spec), masked(spec)
 
         def fn(inputs):
             compiled = jitted_for(inputs)
@@ -1573,6 +1574,8 @@ class MeshQueryExecutor:
                 qstats.record(qstats.SLABBED_LAUNCHES)
             if is_widened:
                 qstats.record(qstats.WIDENED_AGG_LAUNCHES)
+            if is_masked:
+                qstats.record(qstats.MASKED_GROUPBY_LAUNCHES)
             return compiled(inputs)
 
         fn.jitted_for = jitted_for

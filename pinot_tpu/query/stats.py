@@ -81,6 +81,11 @@ GATHER_FREE_LAUNCHES = "gatherFreeLaunches"
 # cell cannot hold a count, so the rows go slab by slab and the counts are
 # added as int32 (PR 31). From the static shape the kernel was built with
 SLABBED_LAUNCHES = "slabbedLaunches"
+# launches whose GROUP BY ran the masked VPU reduce (PR 37): at most
+# `KernelCaps.masked_cap` padded keys + 1, so its counts and sums are a
+# compare, a select and a reduce a key cell and value row, with no matmul and
+# no slabs. From the static plan (`kernels.masked`)
+MASKED_GROUPBY_LAUNCHES = "maskedGroupByLaunches"
 # launches whose aggregate argument was evaluated WIDENED (PR 36): a `+`, `-`
 # or `*` of INT columns in it can leave int32 by the literals and the columns'
 # min/max the plan holds, so the device computes it in float32 (the host in
@@ -169,7 +174,7 @@ COUNTER_KEYS = (
     QUEUE_WAIT_MS, DEVICE_PREPARE_MS, DEVICE_LAUNCH_MS, DEVICE_HANDOFF_MS,
     DEVICE_DECODE_MS, DEDUPED_LAUNCHES, STACKED_LAUNCHES,
     FUSED_LAUNCHES, STAGED_LAUNCHES, GATHER_FREE_LAUNCHES, SLABBED_LAUNCHES,
-    WIDENED_AGG_LAUNCHES,
+    WIDENED_AGG_LAUNCHES, MASKED_GROUPBY_LAUNCHES,
     COMPACT_DECODE_LAUNCHES, DENSE_DECODE_LAUNCHES,
     PRESORT_COMPACT_LAUNCHES, FULL_SORT_LAUNCHES,
     NUM_CONSUMING_SEGMENTS_QUERIED, MUX_FRAME_QUEUE_MS, MUX_FLOW_CONTROL_MS,

@@ -150,14 +150,18 @@ def _fits(compiled, hbm_bytes=16e9):
 
 
 # (smoke query, stacked segments, whether it is the sort regime's program)
+ONEHOT = "group-by region x quantity"     # 256 padded keys: SSB Q4.1's shape
 AGG_CASES = [
     pytest.param("q1.1 filter+sum", SMOKE_SEGS, False, id="q1.1-fused-scan"),
-    pytest.param("group-by region", MATMUL_SEGS, False, id="lowcard-onehot"),
+    # 5 keys: the masked VPU reduce (PR 37), which builds no loop at any size
+    pytest.param("group-by region", MATMUL_SEGS, False, id="lowcard-masked"),
+    pytest.param("group-by region", SMOKE_SEGS, False,
+                 id="lowcard-masked-64Mi-rows"),
+    pytest.param(ONEHOT, MATMUL_SEGS, False, id="lowcard-onehot"),
     pytest.param("group-by 20k keys", MATMUL_SEGS, False, id="20k-chunk64"),
     # the one-chip smoke's 64Mi rows: the same two regimes over four slabs
     # (PR 31; a few seconds of compile each, where the sort took 22-37)
-    pytest.param("group-by region", SMOKE_SEGS, False,
-                 id="lowcard-onehot-4-slabs"),
+    pytest.param(ONEHOT, SMOKE_SEGS, False, id="lowcard-onehot-4-slabs"),
     pytest.param("group-by 20k keys", SMOKE_SEGS, False,
                  id="20k-chunk64-4-slabs"),
     # past `chunk_cap` keys the sort regime, at any row count (~45 s of
@@ -178,10 +182,18 @@ def test_served_agg_kernel_compiles_for_v5e(topo, cpu_exec, segments, name,
         # the served scan decodes its dict columns in-register
         assert p.spec.fused_cols, "q1.1 no longer rides the fused decode"
     if "group-by" in name and not sort_regime:
-        # one loop over the slabs where there is more than one; no
+        # one loop over the slabs where a matmul regime has more than one
+        # (the masked reduce has none and no contraction at all); no
         # contraction over more than a slab's rows, and no sort
         text = compiled.as_text()
-        assert (" while(" in text) == (segs > MATMUL_SEGS)
+        rung = kernels.masked(p.spec)
+        assert rung == (name == "group-by region")
+        assert ("pinot.groupby.masked" in text) == rung
+        assert ("pinot.groupby.onehot" in text) == (name == ONEHOT)
+        assert kernels.slabbed(_real_spec(p.spec), segs * SEG_ROWS) == (
+            segs > MATMUL_SEGS and not rung)
+        assert (" while(" in text) == (segs > MATMUL_SEGS and not rung)
+        assert (" convolution(" in text) == (not rung)
         assert " sort(" not in text
         assert f"[{SMOKE_SEGS * SEG_ROWS}]" not in "".join(
             ln for ln in text.splitlines() if " convolution(" in ln)
@@ -221,9 +233,9 @@ def test_tpch_program_compiles_for_v5e_at_67m_rows(topo, cpu_exec, lineitem,
                                                    template, literals):
     """TPC-H Q1 and Q6 as the `tpch10-lineitem.tpch-q1q6-c4` cell serves
     them, at its [16, 4Mi] rows on one described chip (PR 36). Q1: seven
-    value rows and a count over 9 key cells in ONE one-hot launch over four
-    slabs (one loop, no contraction over the 64Mi rows), its charge widened to
-    float32; Q6 is the fused dictionary scan, its product the int32 it fits."""
+    value rows and a count over 9 key cells as the masked VPU reduce (PR 37:
+    no loop, no contraction, no slabs), its charge widened to float32; Q6 is
+    the fused dictionary scan, its product the int32 it fits."""
     from benchmark.harness import cells
     sql = cells.read_json(cells.BENCH, "queries", "tpch",
                           template + ".json")["sql"].format(**literals)
@@ -239,13 +251,12 @@ def test_tpch_program_compiles_for_v5e_at_67m_rows(topo, cpu_exec, lineitem,
     text = compiled.as_text()
     assert p.spec.fused_cols, "the served scan decodes in-register"
     q1 = template == "q1"
-    assert kernels.widened(spec) == q1
-    assert kernels.slabbed(spec, SMOKE_SEGS * SEG_ROWS) == q1
-    assert ("pinot.groupby.onehot" in text) == q1
-    assert (" while(" in text) == q1 and (" convolution(" in text) == q1
-    assert " sort(" not in text
-    assert f"[{SMOKE_SEGS * SEG_ROWS}]" not in "".join(
-        ln for ln in text.splitlines() if " convolution(" in ln)
+    assert kernels.widened(spec) == q1 and kernels.masked(spec) == q1
+    assert not kernels.slabbed(spec, SMOKE_SEGS * SEG_ROWS)
+    assert ("pinot.groupby.masked" in text) == q1
+    assert "pinot.groupby.onehot" not in text
+    for op in (" while(", " convolution(", " sort("):
+        assert op not in text, op
 
 
 # (smoke query, the window's slots of the 16 resident, sort regime)

@@ -1078,11 +1078,13 @@ def _abstract_scan(aggs_sql, num_keys_pad, rows, distinct_size=None):
         shape((1,), jnp.int32), {}, ())
 
 
-def _lowered_scan(aggs_sql, num_keys_pad, rows, distinct_size=None):
-    """The scan's lowered text (scope names included)."""
+def _lowered_scan(aggs_sql, num_keys_pad, rows, distinct_size=None,
+                  scopes=True):
+    """The scan's lowered text, with its scope names (and the source
+    locations of whoever called) or bare."""
     import jax
     body, args = _abstract_scan(aggs_sql, num_keys_pad, rows, distinct_size)
-    return jax.jit(body).lower(*args).as_text(debug_info=True)
+    return jax.jit(body).lower(*args).as_text(debug_info=scopes)
 
 
 _SUMS, _MINMAX, _DISTINCT = "COUNT(*), SUM(v)", "MIN(v)", "DISTINCTCOUNT(d)"
@@ -1090,9 +1092,30 @@ _2_24 = 1 << 24
 # (aggregates, padded keys, rows a device, ids of the distinct column,
 #  the scope the program must hold, scopes it must not)
 LADDER = [
-    # padded keys + 1 (the overflow bucket) against matmul_cap = 512
+    # padded keys + 1 (the overflow bucket) against masked_cap = 65
+    pytest.param(_SUMS, 64, 16_384, None, "pinot.groupby.masked",
+                 ("onehot", "chunk64", "partitioned"),
+                 id="keys-at-masked_cap"),
+    pytest.param(_SUMS, 65, 16_384, None, "pinot.groupby.onehot",
+                 ("masked", "chunk64", "partitioned"),
+                 id="keys-past-masked_cap"),
+    pytest.param(_SUMS, 128, 16_384, None, "pinot.groupby.onehot",
+                 ("masked", "chunk64", "partitioned"),
+                 id="next-padded-keys-past-masked_cap"),
+    # ... at any row count: an int32 count needs no slab, so no loop and no
+    # contraction under its scope (TPC-H Q1's 9 key cells at the cell's 2^26)
+    pytest.param(_SUMS, 8, _2_24 + 4096, None, "pinot.groupby.masked",
+                 ("onehot", "pinot.groupby.masked/while",
+                  "pinot.groupby.masked/dot_general"),
+                 id="masked-past-2^24-rows"),
+    pytest.param(_SUMS, 8, 1 << 26, None, "pinot.groupby.masked",
+                 ("onehot", "pinot.groupby.masked/while",
+                  "pinot.groupby.masked/dot_general"),
+                 id="masked-at-2^26-rows"),
+    # ... against matmul_cap = 512
     pytest.param(_SUMS, 511, 16_384, None, "pinot.groupby.onehot",
-                 ("chunk64", "partitioned"), id="keys-at-matmul_cap"),
+                 ("masked", "chunk64", "partitioned"),
+                 id="keys-at-matmul_cap"),
     pytest.param(_SUMS, 512, 16_384, None, "pinot.groupby.chunk64",
                  ("onehot", "partitioned"), id="keys-past-matmul_cap"),
     # ... against chunk_cap = 131,072
@@ -1155,6 +1178,171 @@ def test_regime_ladder_boundaries(aggs, keys, rows, ids, holds, not_these):
             not in text, scope
 
 
+# --- a handful of key cells: the masked VPU reduce (PR 37) -------------------
+
+@pytest.mark.parametrize("aggs,keys,ids,same", [
+    # SSB's GROUP BYs: Q4.1's 257 cells, the 8,193 of Q2.x-Q3.1 and Q4.2, the
+    # wide four past chunk_cap; a grouped distinct past the rung
+    (_SUMS, 256, None, True), (_SUMS, 8192, None, True),
+    (_SUMS, 131_072, None, True), (_MINMAX, 256, None, True),
+    (_DISTINCT, 256, 64, True), (_SUMS, 65, None, True),
+    # at and under the cap the program is another
+    (_SUMS, 64, None, False), (_SUMS, 8, None, False),
+])
+def test_past_masked_cap_the_program_is_the_parents_text(aggs, keys, ids,
+                                                         same):
+    """With `masked_cap` 0 the ladder is the one PR 37's parent had. Past the
+    cap the lowered scan is that ladder's text, letter for letter (the four
+    SSB cells' programs do not move); at or under it, it is not."""
+    from dataclasses import replace
+    ours = _lowered_scan(aggs, keys, 16_384, ids, scopes=False)
+    prev = get_caps()
+    set_caps(replace(prev, masked_cap=0))
+    try:
+        parents = _lowered_scan(aggs, keys, 16_384, ids, scopes=False)
+        assert "pinot.groupby.masked" not in _lowered_scan(aggs, keys, 16_384,
+                                                           ids)
+    finally:
+        set_caps(prev)
+    assert "stablehlo" in ours and (ours == parents) == same
+
+@pytest.mark.parametrize("keys,distinct,rows,is_masked,is_slabbed", [
+    (8, None, 1 << 26, True, False),        # TPC-H Q1 in the lineitem cell
+    (64, None, 1 << 26, True, False),       # at the cap: 65 cells
+    (64, None, 16_384, True, False),
+    (128, None, 1 << 26, False, True),      # past it: the one-hot regime
+    (256, None, 1 << 26, False, True),      # SSB Q4.1's 257 cells, four slabs
+    (256, None, 1 << 24, False, False),     # ... and one
+    (131_072, None, 1 << 26, False, False),  # the sort regime has no slabs
+    # a grouped distinct under the cap: the presence product's chunked matmul
+    # still goes slab by slab; past chunk_cap it sorts
+    (15, 64, 1 << 26, True, True),
+    (16, 8192, 1 << 26, True, False),
+])
+def test_masked_and_slabbed_read_the_static_plan(keys, distinct, rows,
+                                                 is_masked, is_slabbed):
+    """What `maskedGroupByLaunches` and `slabbedLaunches` count, from the
+    padded key count against `engine/caps.py` and the rows a device holds."""
+    from pinot_tpu.engine import kernels
+    from pinot_tpu.query.predicate import FilterProgram
+    assert get_caps() == KernelCaps() and get_caps().masked_cap == 65
+    spec = kernels.KernelSpec(
+        FilterProgram(), ("k",), keys, (),
+        {} if distinct is None else {0: distinct}, rows)
+    assert kernels.masked(spec) == is_masked
+    assert kernels.slabbed(spec, rows) == is_slabbed
+    scalar = kernels.KernelSpec(FilterProgram(), (), 1, (), {}, rows)
+    assert not kernels.masked(scalar) and not kernels.slabbed(scalar, rows)
+
+
+@pytest.mark.parametrize("nseg", [2, 9, 65])
+@pytest.mark.parametrize("n", [4096, 5000])
+def test_masked_sums_match_numpy_and_int64(nseg, n):
+    """The rung's helper alone: int32 counts equal to numpy's, each sum
+    within 1e-6 of the int64 sum of the same integers, the overflow cell (the
+    masked-out rows') the zero the other regimes fill it with, an empty key
+    cell 0."""
+    import jax
+    from pinot_tpu.engine import kernels
+    rng = np.random.default_rng(nseg * n)
+    real = nseg - 1
+    key = rng.integers(0, real, n).astype(np.int32)
+    empty = real // 2
+    key[key == empty] = real                        # nobody lands in `empty`
+    key[rng.random(n) < 0.3] = real                 # masked out
+    live = key < real
+    q = rng.integers(1, 60_000, n).astype(np.int64)
+    fm = live.astype(np.float32)
+    rows = [fm, q.astype(np.float32) * fm, (q % 97).astype(np.float32) * fm]
+    got = jax.jit(lambda k, r: kernels._masked_sums(k, nseg, r))(key, rows)
+    assert len(got) == 3 and got[0].dtype == np.int32
+    assert all(g.shape == (nseg,) for g in got)
+    np.testing.assert_array_equal(
+        np.asarray(got[0]), np.bincount(key[live], minlength=nseg))
+    assert int(got[0][empty]) == 0 and int(got[0][-1]) == 0
+    for g, r in zip(got[1:], (q, q % 97)):
+        want = np.zeros(nseg, np.int64)
+        np.add.at(want, key[live], r[live])
+        assert g.dtype == np.float32 and float(g[-1]) == 0.0
+        np.testing.assert_allclose(np.asarray(g, np.float64), want, rtol=1e-6)
+
+
+@pytest.fixture(scope="module")
+def masked_table(tmp_path_factory):
+    """4 x 3,000 rows, 7 dictionary keys of `g`: `w < 900` passes nine rows in
+    ten and NO row of key 3 (a key cell of the dictionary left empty)."""
+    rng = np.random.default_rng(37)
+    rows = 12_000
+    g = rng.integers(0, 7, rows).astype(np.int32)
+    w = rng.integers(0, 1000, rows).astype(np.int32)
+    w[g == 3] = 999
+    schema = Schema("mk", [dimension("g", DataType.INT),
+                           metric("w", DataType.INT),
+                           metric("q", DataType.INT),
+                           metric("v", DataType.DOUBLE)])
+    cols = {"g": g, "w": w,
+            "q": rng.integers(1, 60_000, rows).astype(np.int32),
+            "v": np.round(rng.uniform(-500.0, 60_000.0, rows), 2)}
+    cfg = SegmentGeneratorConfig(no_dictionary_columns=["w", "q", "v"])
+    segs = [load_segment(p) for p in build_aligned_segments(
+        schema, cols, str(tmp_path_factory.mktemp("mk")), "mk", 4,
+        config=cfg)]
+    return segs, cols
+
+
+@pytest.mark.parametrize("path", ["one-device", "mesh-of-four", "direct",
+                                  "staged"])
+def test_masked_rung_answers_as_numpy_and_counts_its_launches(masked_table,
+                                                              path):
+    """A GROUP BY of 7 keys (8 padded, 9 cells) through the served executor on
+    one device, under `shard_map` on four, and through `run_kernel` and
+    `run_kernel_staged` (a mask launch, then the aggregate): groups and
+    counts numpy's, SUM of an INT column within 1e-6 of its int64 sum,
+    the empty key cell no group; every launch counts in
+    `maskedGroupByLaunches` and none in `slabbedLaunches`."""
+    from pinot_tpu.query import stats as qstats
+    segs, cols = masked_table
+    sql = ("SELECT g, COUNT(*), SUM(q), SUM(v), MAX(q) FROM mk "
+           "WHERE w < 900 GROUP BY g ORDER BY g LIMIT 100")
+    ex = {"one-device": lambda: MeshQueryExecutor(default_mesh(1)),
+          "mesh-of-four": lambda: MeshQueryExecutor(default_mesh(4)),
+          "direct": ServerQueryExecutor,
+          "staged": lambda: ServerQueryExecutor(fused_enabled=False)}[path]()
+    with qstats.collect_stats() as st:
+        rows = ex.execute(segs, sql).rows
+    live = cols["w"] < 900
+    keys = np.unique(cols["g"][live])
+    assert 3 not in keys and [r[0] for r in rows] == keys.tolist()
+    assert [r[1] for r in rows] == np.bincount(cols["g"][live])[keys].tolist()
+    want = np.zeros(7, np.int64)
+    np.add.at(want, cols["g"][live], cols["q"][live].astype(np.int64))
+    np.testing.assert_allclose([r[2] for r in rows], want[keys], rtol=1e-6)
+    np.testing.assert_allclose(
+        [r[3] for r in rows],
+        np.bincount(cols["g"][live], weights=cols["v"][live])[keys],
+        rtol=1e-6)
+    assert [r[4] for r in rows] == [
+        int(cols["q"][live & (cols["g"] == k)].max()) for k in keys]
+    # a launch a segment through the per-segment executor, two where staged
+    rung = len(segs) if path in ("direct", "staged") else 1
+    assert int(st.counters.get(qstats.DEVICE_LAUNCHES, 0)) == rung * (
+        2 if path == "staged" else 1)
+    assert int(st.counters.get(qstats.MASKED_GROUPBY_LAUNCHES, 0)) == rung
+    assert int(st.counters.get(qstats.SLABBED_LAUNCHES, 0)) == 0
+
+
+def test_masked_rung_is_not_taken_past_the_cap(slab_table):
+    """200 keys (256 padded, 257 cells: SSB Q4.1's shape) keep the one-hot
+    regime and count no masked launch."""
+    from pinot_tpu.query import stats as qstats
+    seg, _ = slab_table
+    with qstats.collect_stats() as st:
+        MeshQueryExecutor(default_mesh(1)).execute(
+            [seg], "SELECT g, COUNT(*), SUM(v) FROM sl GROUP BY g LIMIT 999")
+    assert int(st.counters.get(qstats.DEVICE_LAUNCHES, 0)) == 1
+    assert int(st.counters.get(qstats.MASKED_GROUPBY_LAUNCHES, 0)) == 0
+
+
 # --- past 2^24 rows a device: the matmul regimes slab by slab (PR 31) --------
 
 def _eqns(jaxpr, *names):
@@ -1180,10 +1368,13 @@ def _contracted(eqn):
     (_SUMS, 256, None, [[("int32", 257), ("float32", 257)]], 3),
     # two chunks x (the count's one contraction + the sum's three parts)
     (_SUMS, 8192, None, [[("int32", 8193), ("float32", 8193)]], 8),
-    # 16 x 64 presence cells: one chunk, count only; then the group-by's own
-    # count (one-hot, 16 cells)
-    (_DISTINCT, 15, 64, [[("int32", 1024)], [("int32", 16)]], 1 + 3),
-], ids=["onehot-257", "chunk64-8193", "distinct"])
+    # 16 x 64 presence cells: one chunk, count only; the group-by's own count
+    # (16 cells: the masked reduce) builds no loop and no contraction
+    (_DISTINCT, 15, 64, [[("int32", 1024)]], 1),
+    # 257 groups x 64 ids: four chunks over the 256 real groups' presence
+    # cells; then the group-by's own count (one-hot, 257 cells)
+    (_DISTINCT, 256, 64, [[("int32", 257 * 64)], [("int32", 257)]], 4 + 3),
+], ids=["onehot-257", "chunk64-8193", "distinct", "distinct-257-groups"])
 def test_at_2_26_rows_the_matmul_regimes_contract_one_slab_at_a_time(
         aggs, keys, ids, carries, dots):
     """The full cell's shape, 2^26 rows a device: a loop of four steps whose

@@ -142,6 +142,31 @@ def test_presort_compact_share_reads_the_counter_delta(counters, want,
         assert read(_ctx([], parent, None)) is None
 
 
+MASKED_METRIC = "kernels.masked_groupby_share"      # PR 37, the lineitem cell
+
+
+@pytest.mark.parametrize("counters,want", [
+    # a window of Q1 (9 key cells: the masked reduce) and Q6 (no GROUP BY)
+    ({"launches": 1144, "maskedGroupByLaunches": 572,
+      "widenedAggLaunches": 572}, 50.0),
+    ({"launches": 8, "maskedGroupByLaunches": 8}, 100.0),
+    # Q1 back on the one-hot matmul: 0, not None
+    ({"launches": 8, "maskedGroupByLaunches": 0}, 0.0),
+    # nothing launched: nothing to read
+    ({"launches": 0, "maskedGroupByLaunches": 0}, None),
+    # PR 37's parent counts its widened launches and no masked one
+    ({"launches": 1144, "widenedAggLaunches": 572}, None),
+])
+def test_masked_groupby_share_reads_the_counter_delta(counters, want,
+                                                      mesh_recorded, recorded):
+    read = cells.load_reader(MASKED_METRIC)
+    got = read(_ctx([], counters, None))
+    assert got == want if want is None else got == pytest.approx(want)
+    for parent in (mesh_recorded["counters"], recorded["counters"]):
+        assert "maskedGroupByLaunches" not in parent
+        assert read(_ctx([], parent, None)) is None
+
+
 @pytest.fixture(scope="module")
 def mesh_recorded():
     with open(os.path.join(FIXTURES, "pr28-mesh4-slice.json")) as f:

@@ -23,7 +23,8 @@ TEMPLATES = ("q1", "q6")
 VARIANTS = 2
 CUTOFF = 19950617
 GROUPS = {("A", "F"), ("N", "F"), ("N", "O"), ("R", "F")}
-NEW_METRICS = ("kernels.q1_hbm_roofline", "kernels.widened_agg_share")
+NEW_METRICS = ("kernels.q1_hbm_roofline", "kernels.widened_agg_share",
+               "kernels.masked_groupby_share")           # the last: PR 37
 
 
 @pytest.fixture(scope="module")
@@ -251,6 +252,11 @@ def test_q1_and_q6_answer_as_the_reference(served, q, mesh):
     # on the device path, in one launch; Q1's charge widened, Q6's product not
     assert resp["deviceLaunches"] == 1
     assert resp["widenedAggLaunches"] == (1 if p["template"] == "q1" else 0)
+    # Q1's 9 key cells take the masked reduce (PR 37) and build no slab; Q6
+    # has no GROUP BY
+    assert resp["maskedGroupByLaunches"] == (
+        1 if p["template"] == "q1" else 0)
+    assert resp["slabbedLaunches"] == 0
     if p["template"] == "q1":
         assert len(resp["resultTable"]["rows"]) == 4
 
@@ -275,6 +281,8 @@ def test_health_sums_the_widened_launches_and_nothing_fell_back(served):
         start, end = served[n][0][1], served[n][-1][1]
         assert end["widenedAggLaunches"] - start["widenedAggLaunches"] \
             == VARIANTS                                    # Q1's, not Q6's
+        assert end["maskedGroupByLaunches"] - start["maskedGroupByLaunches"] \
+            == VARIANTS
         assert end["launches"] - start["launches"] == 2 * VARIANTS
         for k in ("deviceErrors", "fallbacks", "timeouts"):
             assert end[k] == start[k], (n, k)
@@ -301,14 +309,21 @@ def test_host_path_answers_the_same(served, cell, tmp_path):
 
 # -- (d) the new readers -------------------------------------------------------
 
-def test_widened_agg_share_reads_the_served_counters(served):
-    read = cells.load_reader("kernels.widened_agg_share")
+@pytest.mark.parametrize("name,key,parents", [
+    ("kernels.widened_agg_share", "widenedAggLaunches",
+     {"launches": 4}),                                    # PR 36's parent
+    ("kernels.masked_groupby_share", "maskedGroupByLaunches",
+     {"launches": 4, "widenedAggLaunches": 2}),           # PR 37's parent
+])
+def test_launch_share_reads_the_served_counters(served, name, key, parents):
+    read = cells.load_reader(name)
     start, end = served[1][0][1], served[1][-1][1]
     delta = {k: end[k] - start[k] for k in start
              if isinstance(start[k], (int, float))}
     assert read({"counters": delta}) == 50.0              # Q1's of Q1 and Q6
-    assert read({"counters": {"launches": 4}}) is None    # PR 36's parent
+    assert read({"counters": parents}) is None            # no such counter
     assert read({"counters": dict(delta, launches=0)}) is None
+    assert read({"counters": dict(delta, **{key: 0})}) == 0.0
 
 
 def test_q1_hbm_roofline_reads_q1s_solo_replay(cell):

@@ -891,3 +891,66 @@ def test_slabbed_launches_reach_response_explain_and_health(tmp_path,
             [r[2] for r in got[name].rows],
             np.bincount(cols[col][live], weights=cols["w"][live])[keys],
             rtol=1e-6)
+
+
+# -- PR 37: the masked reduce at the bottom of the ladder, and its counter ----
+
+def test_masked_groupby_launches_reach_response_explain_and_health(tmp_path):
+    """Through the device pipeline of a served cluster whose mesh is four
+    devices: a GROUP BY of 5 keys (8 padded, 9 cells: the masked reduce)
+    answers with `maskedGroupByLaunches` 1 and the rows numpy computes, one of
+    200 keys (the one-hot regime) and a scalar aggregation with 0. EXPLAIN
+    ANALYZE carries the field and `/health`'s device block sums the launches;
+    none of them is slabbed."""
+    from pinot_tpu.cluster.device_server import DeviceQueryPipeline
+    from pinot_tpu.parallel import MeshQueryExecutor, default_mesh
+    from pinot_tpu.table import IndexingConfig
+    rng = np.random.default_rng(37)
+    segs, per = 4, 4000
+    schema = Schema("mk", [dimension("m", DataType.INT),
+                           dimension("g", DataType.INT),
+                           metric("w", DataType.INT)])
+    cols = {"m": rng.integers(0, 5, segs * per).astype(np.int32),
+            "g": rng.integers(0, 200, segs * per).astype(np.int32),
+            "w": rng.integers(1, 1000, segs * per).astype(np.int32)}
+    for s in range(segs):       # every key in every segment: aligned
+        cols["m"][s * per:s * per + 5] = np.arange(5)
+        cols["g"][s * per + 5:s * per + 205] = np.arange(200)
+    cluster = QuickCluster(num_servers=1, work_dir=str(tmp_path))
+    cluster.servers[0].device_pipeline = pipeline = DeviceQueryPipeline(
+        mesh_exec=MeshQueryExecutor(default_mesh(4)))
+    cfg = TableConfig("mk", indexing=IndexingConfig(
+        no_dictionary_columns=["w"]))
+    cluster.create_table(schema, cfg)
+    for s in range(segs):
+        cluster.ingest_columns(cfg, {c: v[s * per:(s + 1) * per]
+                                     for c, v in cols.items()})
+    by = "SELECT {0}, COUNT(*), SUM(w) FROM mk WHERE w > 500 GROUP BY {0} " \
+         "ORDER BY {0} LIMIT 10000"
+    sqls = {"masked": by.format("m"), "onehot": by.format("g"),
+            "scalar": "SELECT COUNT(*), SUM(w) FROM mk WHERE w > 500",
+            "explain": "EXPLAIN ANALYZE " + by.format("m")}
+    try:
+        got = {name: cluster.query(sql) for name, sql in sqls.items()}
+        health = pipeline.stats()
+    finally:
+        pipeline.stop()
+    for name, masked in (("masked", 1), ("onehot", 0), ("scalar", 0),
+                         ("explain", 1)):
+        s = got[name].stats
+        assert s["deviceLaunches"] >= 1 and s["meshLaunches"] >= 1, (name, s)
+        assert s["maskedGroupByLaunches"] == masked, (name, s)
+        assert s["slabbedLaunches"] == 0, (name, s)
+    assert got["explain"].stats["analyze"] is True
+    assert health["maskedGroupByLaunches"] == 2
+    assert health["deviceErrors"] == 0 and health["fallbacks"] == 0
+    live = cols["w"] > 500
+    for name, col in (("masked", "m"), ("onehot", "g")):
+        keys = np.unique(cols[col][live])
+        assert [r[0] for r in got[name].rows] == keys.tolist()
+        assert [r[1] for r in got[name].rows] == \
+            np.bincount(cols[col][live])[keys].tolist()
+        np.testing.assert_allclose(
+            [r[2] for r in got[name].rows],
+            np.bincount(cols[col][live], weights=cols["w"][live])[keys],
+            rtol=1e-6)
