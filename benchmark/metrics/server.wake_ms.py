@@ -1,0 +1,8 @@
+"""server.wake_ms: what it reads is in the `.json` beside it.
+None where the program has no such field (PR 38's parent)."""
+
+from benchmark.harness import program_trace as pt
+
+
+def read(ctx):
+    return pt.mean_sum(ctx, "deviceWakeMs")

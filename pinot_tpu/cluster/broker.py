@@ -339,7 +339,8 @@ class Broker:
                     stmt = parse_query(sql)
                 stmt = self._rewrite_subqueries(stmt)
                 table = stmt.table
-                shape = self._plan_shape(stmt)
+                with tracing.span("broker.fingerprint"):
+                    shape = self._plan_shape(stmt)
                 trace_on = _truthy(stmt.options.get("trace"))
                 # always-on: the trace records regardless, the sampler only
                 # gates ring retention; OPTION(trace=true) force-samples AND
@@ -381,8 +382,9 @@ class Broker:
             result.stats[qstats.WORKLOAD_FINGERPRINT] = shape.fingerprint
         reg.counter("pinot_broker_queries").inc()
         reg.timer("pinot_broker_query_latency_ms").update(elapsed_ms)
-        self._account_query(sql, result, elapsed_ms, tr=tr, table=table,
-                            shape=shape)
+        with tracing.span("broker.account"):
+            self._account_query(sql, result, elapsed_ms, tr=tr, table=table,
+                                shape=shape)
         return result
 
     @staticmethod

@@ -85,12 +85,13 @@ _STAGE_KEYS = (qstats.SET_BLOCKS_STAGED, qstats.SET_BLOCK_BYTES)
 #: once its outputs are fetched (`qstats.decode_branch`), summed over the launches in `stats()`; the decode hook puts the same key on each answer's partial
 _DECODE_KEYS = tuple(k for keys in qstats.DECODE_FLAGS.values() for k in keys)
 
-#: what the kernel cache, the first-call fence and the executor's launch
-#: accounting record on the dispatcher thread, folded from a scratch record
-#: into the items a launch answers
+#: what the kernel cache, the first-call fence, the executor's launch
+#: accounting and the prepare's two halves record on the dispatcher thread,
+#: folded from a scratch record into the items a prepare or launch answers
 _LAUNCH_KEYS = (qstats.COMPILE_MS, qstats.COMPILE_CACHE_MISSES,
                 qstats.COMPILE_CACHE_HITS, qstats.DEVICE_LAUNCHES,
-                qstats.GATHER_FREE_LAUNCHES) + _SHAPE_KEYS + _STAGE_KEYS
+                qstats.GATHER_FREE_LAUNCHES, qstats.DEVICE_PLAN_MS,
+                qstats.DEVICE_INPUTS_MS) + _SHAPE_KEYS + _STAGE_KEYS
 
 #: the pipeline's per-query phases in the order a query passes them: the
 #: item.stats key of each and the request-Trace span `execute_partial` rebuilds
@@ -132,7 +133,7 @@ def _items(launches):
 
 class _Item:
     __slots__ = ("ctx", "segments", "resident", "future", "t_enqueue",
-                 "stats", "trace_id")
+                 "t_resolved", "stats", "trace_id")
 
     def __init__(self, ctx, segments, trace_id: str = "", resident=None):
         self.ctx = ctx
@@ -141,6 +142,9 @@ class _Item:
         self.trace_id = trace_id
         self.future: Future = Future()
         self.t_enqueue = time.perf_counter()
+        # when the fetcher resolved the future: the handler thread's wake-up
+        # is measured from here, on its own side
+        self.t_resolved = 0.0
         # per-item launch attribution (queue wait, dedupe/stack flags): the
         # pipeline threads serve MANY queries per drain, so per-query stats
         # can't ride thread-locals — they attach to the decoded partial
@@ -259,6 +263,10 @@ class DeviceQueryPipeline:
         self._q.put(item)
         try:
             result = item.future.result(timeout=timeout_s)
+            if item.t_resolved and isinstance(getattr(result, "stats", None),
+                                              dict):
+                result.stats[qstats.DEVICE_WAKE_MS] = round(
+                    (time.perf_counter() - item.t_resolved) * 1000, 3)
             if tr is not None and result is not DEVICE_FALLBACK:
                 # the pipeline threads can't see this query's trace; rebuild
                 # its phases from the item's attribution, laid end to end from
@@ -433,7 +441,7 @@ class DeviceQueryPipeline:
             scratch = qstats.ExecutionStats()
             try:
                 with qstats.activate(scratch), \
-                        stage("pipeline.prepare",
+                        stage("pipeline.prepare", cpu=True,
                               trace_id=item.trace_id) as prep:
                     p = self.mesh_exec.prepare_partial(
                         item.ctx, item.segments, item.resident)
@@ -446,6 +454,7 @@ class DeviceQueryPipeline:
                 continue
             self._observe("prepare", prep.ms)
             item.stats[qstats.DEVICE_PREPARE_MS] = round(prep.ms, 3)
+            item.stats[qstats.DEVICE_PREPARE_CPU_MS] = round(prep.cpu_ms, 3)
             _fold(item.stats, scratch.counters)
             for k in _STAGE_KEYS:
                 self.by_shape[k] += int(scratch.counters.get(k, 0))
@@ -469,7 +478,7 @@ class DeviceQueryPipeline:
             return [], 0
         n_live = sum(len(g) for g in rep_groups)
         try:
-            with stage("pipeline.launch", batch=n_live,
+            with stage("pipeline.launch", cpu=True, batch=n_live,
                        devices=self.devices) as launch:
                 launches = self.mesh_exec.dispatch_prepared(reps)
                 launch.note(launches=len(launches))
@@ -496,6 +505,8 @@ class DeviceQueryPipeline:
             for i in idxs:
                 for item, _ in rep_groups[i]:
                     item.stats[qstats.DEVICE_LAUNCH_MS] = round(launch.ms, 3)
+                    item.stats[qstats.DEVICE_LAUNCH_CPU_MS] = round(
+                        launch.cpu_ms, 3)
                     _fold(item.stats, recorded)
                     if fused:
                         item.stats["fusedLaunches"] = 1
@@ -517,7 +528,7 @@ class DeviceQueryPipeline:
             self._observe("queue_wait", wait_ms)
             item.stats[qstats.QUEUE_WAIT_MS] = round(wait_ms, 3)
             try:
-                with stage("pipeline.launch", batch=1,
+                with stage("pipeline.launch", cpu=True, batch=1,
                            trace_id=item.trace_id) as launch:
                     dp = self.mesh_exec.dispatch_partial(item.ctx,
                                                          item.segments)
@@ -531,6 +542,7 @@ class DeviceQueryPipeline:
                 continue
             self._observe("launch", launch.ms)
             item.stats[qstats.DEVICE_LAUNCH_MS] = round(launch.ms, 3)
+            item.stats[qstats.DEVICE_LAUNCH_CPU_MS] = round(launch.cpu_ms, 3)
             item.stats[qstats.DEVICE_LAUNCHES] = 1
             entry.append((dp[0], (lambda host: [host]),
                           [[(item, dp[1])]]))
@@ -617,6 +629,7 @@ class DeviceQueryPipeline:
                         (time.perf_counter() - t0) * 1000, 3)
                     s.update(r.stats or {})
                     r.stats = s
+                item.t_resolved = time.perf_counter()
                 _resolve(item.future, r)
 
     def stats(self) -> dict:

@@ -25,6 +25,8 @@ import urllib.request
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, Dict, Optional, Tuple
 
+from ..utils.trace import stage
+
 # route handler: (path_parts, query_params, body) -> (status, content_type, body_bytes)
 RouteHandler = Callable[[list, Dict[str, str], bytes], Tuple[int, str, bytes]]
 
@@ -142,6 +144,15 @@ class HttpService:
                 parts = [p for p in parsed.path.split("/") if p]
                 params = dict(urllib.parse.parse_qsl(parsed.query))
                 head = parts[0] if parts else ""
+                if method == "POST" and head == "query":
+                    # a query's front end on the profiler's clock, from its
+                    # body read to its response written
+                    with stage("http.query"):
+                        self._serve(method, parts, params, head)
+                else:
+                    self._serve(method, parts, params, head)
+
+            def _serve(self, method: str, parts, params, head: str) -> None:
                 if (method, head) in service._stream_body or \
                         (method, head) in service._duplex:
                     # streaming-body route: hand the handler an incremental

@@ -134,15 +134,15 @@ class _MuxConnection:
         return (time.perf_counter() - oldest) > self._timeout_s
 
     def submit(self, payload: bytes, *, trace=None, depth: int = 0,
-               dispatch_ms: float = 0.0, span_name: Optional[str] = None
-               ) -> "Future":
+               dispatch_ms: float = 0.0, span_name: Optional[str] = None,
+               serialize_ms: float = 0.0) -> "Future":
         fut: "Future" = Future()
         entry: Dict[str, Any] = {
             "fut": fut, "trace": trace, "depth": depth,
             "dispatch_ms": dispatch_ms, "span_name": span_name,
             "t0": time.perf_counter(),
             "enq_ms": trace.now_ms() if trace is not None else 0.0,
-            "queue_ms": 0.0, "sent_ms": 0.0,
+            "queue_ms": 0.0, "sent_ms": 0.0, "serialize_ms": serialize_ms,
         }
         with self._lock:
             if self._closed:
@@ -245,7 +245,8 @@ class _MuxConnection:
             self._fail(ConnectionError("mux stream closed by server"))
 
     def _complete(self, entry: Dict[str, Any], payload: bytearray) -> None:
-        from ..query.stats import MUX_FRAME_QUEUE_MS
+        from ..query import stats as qstats
+        from ..utils.trace import stage
         from .wire import decode_segment_result
         fut: "Future" = entry["fut"]
         (status,) = _STATUS.unpack_from(payload, 0)
@@ -271,20 +272,18 @@ class _MuxConnection:
         tr = entry["trace"]
         try:
             arrive_ms = tr.now_ms() if tr is not None else 0.0
-            t0 = time.perf_counter()
-            result = decode_segment_result(body)
-            decode_dur = (time.perf_counter() - t0) * 1000
-            if entry["queue_ms"]:
-                stats = result.stats if isinstance(result.stats, dict) \
-                    else {}
-                stats[MUX_FRAME_QUEUE_MS] = round(
-                    stats.get(MUX_FRAME_QUEUE_MS, 0.0) + entry["queue_ms"], 3)
-                result.stats = stats
+            with stage("broker.deserialize") as decoded:
+                result = decode_segment_result(body)
+            qstats.add_ms(result,
+                          (qstats.MUX_FRAME_QUEUE_MS, entry["queue_ms"]),
+                          (qstats.SCATTER_SERIALIZE_MS, entry["serialize_ms"]),
+                          (qstats.SCATTER_DESERIALIZE_MS, decoded.ms))
             if tr is not None:
                 depth = entry["depth"]
-                tr.record("send", entry["sent_ms"],
+                tr.record("broker.send", entry["sent_ms"],
                           arrive_ms - entry["sent_ms"], depth + 1)
-                tr.record("deserialize", arrive_ms, decode_dur, depth + 1)
+                tr.record("broker.deserialize", arrive_ms, decoded.ms,
+                          depth + 1)
                 spans = getattr(result, "trace_spans", None)
                 if spans:
                     # splice HERE (mirrors RemoteServerHandle.__call__) and
@@ -371,8 +370,8 @@ class MuxClient:
             return conn
 
     def submit(self, payload: bytes, *, trace=None, depth: int = 0,
-               dispatch_ms: float = 0.0, span_name: Optional[str] = None
-               ) -> "Future":
+               dispatch_ms: float = 0.0, span_name: Optional[str] = None,
+               serialize_ms: float = 0.0) -> "Future":
         """Submit one tagged frame, reconnecting with jittered exponential
         backoff on a dying stream. The attempts cap bounds how long a dead
         server is hammered; exhausting it raises ConnectionError, which the
@@ -393,7 +392,8 @@ class MuxClient:
                 conn = self._connection()
                 return conn.submit(payload, trace=trace, depth=depth,
                                    dispatch_ms=dispatch_ms,
-                                   span_name=span_name)
+                                   span_name=span_name,
+                                   serialize_ms=serialize_ms)
             except (MuxStreamClosed, ConnectionError) as e:
                 last_exc = e  # dying stream or failed mint: back off, retry
         raise ConnectionError(

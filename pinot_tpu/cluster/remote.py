@@ -25,6 +25,7 @@ import time
 import urllib.parse
 from typing import Dict, Optional, Sequence
 
+from ..query import stats as qstats
 from ..schema import Schema
 from ..table import TableConfig
 from ..utils.faults import fault_point
@@ -337,26 +338,25 @@ class RemoteServerHandle:
         # taxonomy marks the server unhealthy and retries on another replica
         fault_point("server.crash")
         from ..utils.metrics import get_registry
-        from ..utils.trace import current_depth, current_trace
+        from ..utils.trace import current_depth, current_trace, stage
         sql = ctx if isinstance(ctx, str) else ctx.sql
         if not sql:
             raise ValueError("remote dispatch requires the query SQL text")
         tr = current_trace()
         depth = current_depth() if tr is not None else 0
-        dispatch_ms = tr.elapsed_ms() if tr is not None else 0.0
-        t0 = time.perf_counter()
-        body = encode_query_request(
-            table, sql, segment_names, time_filter,
-            trace=tr is not None,
-            trace_id=tr.trace_id if tr is not None else "",
-            sampled=bool(tr.sampled) if tr is not None else False)
+        dispatch_ms = tr.now_ms() if tr is not None else 0.0
+        with stage("broker.serialize") as encoded:
+            body = encode_query_request(
+                table, sql, segment_names, time_filter,
+                trace=tr is not None,
+                trace_id=tr.trace_id if tr is not None else "",
+                sampled=bool(tr.sampled) if tr is not None else False)
         if tr is not None:
-            tr.record("serialize", dispatch_ms,
-                      (time.perf_counter() - t0) * 1000, depth + 1)
+            tr.record("broker.serialize", dispatch_ms, encoded.ms, depth + 1)
         try:
             return self._mux_client().submit(
                 body, trace=tr, depth=depth, dispatch_ms=dispatch_ms,
-                span_name=span_name)
+                span_name=span_name, serialize_ms=encoded.ms)
         except HttpError as e:
             if e.status in (404, 405, 501):
                 # peer without a /mux route: remember and use legacy for good
@@ -423,24 +423,26 @@ class RemoteServerHandle:
         if not sql:
             raise ValueError("remote dispatch requires the query SQL text")
         tr = current_trace()
-        dispatch_ms = tr.elapsed_ms() if tr is not None else 0.0
+        dispatch_ms = tr.now_ms() if tr is not None else 0.0
         # wire-level spans decompose the broker<->server hop: serialize the
         # request, the on-the-wire round trip (send), deserialize the result —
-        # the server's own queue_wait/deserialize/exec spans splice in below
-        with span("serialize"):
+        # the server's own queue_wait/exec spans splice in below
+        with span("broker.serialize") as encoded:
             body = encode_query_request(
                 table, sql, segment_names, time_filter,
                 trace=tr is not None,
                 trace_id=tr.trace_id if tr is not None else "",
                 sampled=bool(tr.sampled) if tr is not None else False)
-        with span("send"):
+        with span("broker.send"):
             fault_point("server.crash")
             resp = http_call("POST", f"{self.server_url}/query", body,
                              timeout=self.timeout_s,
                              content_type="application/octet-stream",
                              token=self.token)
-        with span("deserialize"):
+        with span("broker.deserialize") as decoded:
             result = decode_segment_result(resp)
+        qstats.add_ms(result, (qstats.SCATTER_SERIALIZE_MS, encoded.ms),
+                      (qstats.SCATTER_DESERIALIZE_MS, decoded.ms))
         spans = getattr(result, "trace_spans", None)
         if tr is not None and spans:
             # already prefixed server-side with its instance id; rebase the server's

@@ -25,6 +25,7 @@ from ..query.reduce import SegmentResult, merge_segment_results
 from ..segment.reader import ImmutableSegment, load_segment
 from ..utils.events import emit as emit_event
 from ..utils.faults import fault_point
+from ..utils.trace import install_gc_hook
 from .catalog import (COLD, CONSUMING, DROPPED, OFFLINE, ONLINE, Catalog,
                       InstanceInfo)
 from .deepstore import DeepStoreFS, untar_segment
@@ -147,6 +148,9 @@ class ServerNode:
         self.catalog = catalog
         self.deepstore = deepstore
         self.data_dir = data_dir
+        # the process's collections, counted on /health's `device` block and
+        # spanned as `pinot:gc` (generation 2) under a profiler session
+        install_gc_hook()
         # device bitmap filter indexes default on; operators can force the
         # LUT/interval filter path cluster-wide (e.g. to bisect a wrong-result
         # report) without redeploying servers
@@ -722,7 +726,11 @@ class ServerNode:
         mgr = self._table_manager(table)
         handler = self._realtime_managers.get(table)
         upsert = getattr(handler, "upsert", None) if handler else None
-        segments = mgr.acquire(segment_names)
+        # acquire, admission and settle: three `server.acquire` spans whose
+        # wall is the answer's serverAcquireMs
+        with span("server.acquire") as acq:
+            segments = mgr.acquire(segment_names)
+        acquire_ms = acq.ms
         admitted: List[ImmutableSegment] = []
         settle: List[str] = []
         try:
@@ -757,28 +765,19 @@ class ServerNode:
             # rejected segments run the host plan instead of OOMing
             from ..engine.datablock import has_block
             host_tier: List[ImmutableSegment] = []
-            for seg in segments:
-                fresh = not has_block(seg)
-                if self.tiering.admit(table, seg, mgr):
-                    admitted.append(seg)
-                    if fresh:
-                        self.tiering.note_promotion()
-                        emit_event("tier.promoted", node=self.instance_id,
-                                   table=table,
-                                   segment=getattr(seg, "name", ""))
-                        qstats.record(qstats.TIER_PROMOTIONS, 1)
-                else:
-                    host_tier.append(seg)
-            if host_tier:
-                qstats.record(qstats.SEGMENTS_SERVED_HOST_TIER,
-                              len(host_tier))
-
-            results = []
-            device_partial = None
-            if (self.device_pipeline is not None and admitted
-                    and upsert is None
-                    and (ctx.aggregations or ctx.distinct
-                         or device_topk_screen(ctx))):
+            with span("server.acquire") as acq:
+                for seg in segments:
+                    fresh = not has_block(seg)
+                    if self.tiering.admit(table, seg, mgr):
+                        admitted.append(seg)
+                        if fresh:
+                            self.tiering.note_promotion()
+                            emit_event("tier.promoted", node=self.instance_id,
+                                       table=table,
+                                       segment=getattr(seg, "name", ""))
+                            qstats.record(qstats.TIER_PROMOTIONS, 1)
+                    else:
+                        host_tier.append(seg)
                 # pre-screened on THIS thread: only shapes that CAN plan on
                 # device enter the pipeline — everything else goes straight
                 # to the host loop instead of waiting out the pipeline's
@@ -786,26 +785,40 @@ class ServerNode:
                 # rewrites to a group-by, which plans on device; ORDER-BY-
                 # limit selections ride the fused top-k kernel when the
                 # screen admits them (single-column order, bounded k)
+                on_device = (self.device_pipeline is not None and admitted
+                             and upsert is None
+                             and (ctx.aggregations or ctx.distinct
+                                  or device_topk_screen(ctx)))
+                if on_device:
+                    # what is staged and planned is the table's RESIDENT
+                    # set: every immutable segment this server holds of it
+                    # that the admission gate lets onto the device, in push
+                    # order. The admitted members of the routed set are an
+                    # input of the launch, so a pruned query builds no block
+                    # of its own
+                    routed = {seg.name for seg in admitted}
+                    rejected = {seg.name for seg in host_tier}
+                    resident = [
+                        seg for seg in mgr.resident()
+                        if seg.name in routed
+                        or (seg.name not in rejected
+                            and not getattr(seg, "is_mutable", False)
+                            and self.tiering.admit(table, seg, mgr))]
+                    settle = [seg.name for seg in resident]
+            acquire_ms += acq.ms
+            if host_tier:
+                qstats.record(qstats.SEGMENTS_SERVED_HOST_TIER,
+                              len(host_tier))
+
+            results = []
+            device_partial = None
+            if on_device:
                 # device path: ONE server-level partial for the whole set,
                 # executed on the mesh with batched fetches; falls back per
                 # segment below when the plan can't ride the device (upsert
                 # valid masks always take the host path — per-doc visibility
                 # is host state)
                 from .device_server import DEVICE_FALLBACK
-                # what is staged and planned is the table's RESIDENT set:
-                # every immutable segment this server holds of it that the
-                # admission gate lets onto the device, in push order. The
-                # admitted members of the routed set are an input of the
-                # launch, so a pruned query builds no block of its own
-                routed = {seg.name for seg in admitted}
-                rejected = {seg.name for seg in host_tier}
-                resident = [
-                    seg for seg in mgr.resident()
-                    if seg.name in routed
-                    or (seg.name not in rejected
-                        and not getattr(seg, "is_mutable", False)
-                        and self.tiering.admit(table, seg, mgr))]
-                settle = [seg.name for seg in resident]
                 with span("device"):
                     try:
                         out = self.device_pipeline.execute_partial(
@@ -869,11 +882,14 @@ class ServerNode:
             # reservations made by THIS query's admissions are settled: a
             # block either staged (the ledger counts it now) or never will
             # until another query re-admits it
-            self.tiering.settle(settle or [seg.name for seg in admitted])
-            mgr.release(segments)
+            with span("server.acquire") as acq:
+                self.tiering.settle(settle or [seg.name for seg in admitted])
+                mgr.release(segments)
+            qstats.record(qstats.SERVER_ACQUIRE_MS, acquire_ms + acq.ms)
         aggs = [make_agg(f) for f in ctx.aggregations]
-        with span("merge"):
+        with span("server.merge") as merging:
             merged = merge_segment_results(results, aggs)
+        qstats.record(qstats.SERVER_MERGE_MS, merging.ms)
         merged.served = served
         # ServerMeter QUERIES / NUM_DOCS_SCANNED / NUM_SEGMENTS_QUERIED analogs
         reg.counter("pinot_server_queries", {"table": table}).inc()

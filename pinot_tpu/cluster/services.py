@@ -22,8 +22,10 @@ import threading
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, Optional
 
+from ..query import stats as qstats
 from ..schema import Schema
 from ..table import TableConfig
+from ..utils.trace import gc_stats, stage
 from .broker import Broker
 from .catalog import Catalog, InstanceInfo
 from .controller import Controller
@@ -776,6 +778,10 @@ class ServerService:
                                             thread_name_prefix="mux-exec")
         self._mux_open = 0           # open mux streams (gauge has no inc/dec)
         self._mux_lock = threading.Lock()
+        # answers encoded and the wall it took: the encode cannot ride in its
+        # own payload, so /health's `device` block counts it
+        self._encodes = 0
+        self._encode_ms = 0.0
         self.http.route("POST", "query", self._query)
         self.http.route("POST", "mux", self._mux, duplex=True)
         self.http.route("POST", "explain", self._explain)
@@ -854,6 +860,11 @@ class ServerService:
                     streams_gauge.set(self._mux_open)
         return 200, "application/octet-stream", gen()
 
+    def _count_encode(self, ms: float) -> None:
+        with self._mux_lock:
+            self._encodes += 1
+            self._encode_ms += ms
+
     def _reject_body(self, e) -> dict:
         """429 body: the error plus a Retry-After hint. The scheduler stamps
         its drain-rate estimate on the exception; when absent (e.g. a quota
@@ -886,26 +897,21 @@ class ServerService:
         milliseconds a frame spent gated by the window attributable. The
         response is gathered `encode_segment_result_parts` buffers: array
         payloads go to the socket without an intermediate join."""
-        import time as _time
         from ..auth import require_table_access
         from ..query.scheduler import QueryRejectedError, QueryTimeoutError
-        from ..query.stats import MUX_FLOW_CONTROL_MS
         from ..utils.trace import request_trace
         from .wire import encode_segment_result_parts
-        t_decode = _time.perf_counter()
-        req = decode_query_request(payload)
-        decode_ms = (_time.perf_counter() - t_decode) * 1000
+        with stage("server.decode") as decoded:
+            req = decode_query_request(payload)
         require_table_access(req["table"], "READ")
         try:
             with request_trace(bool(req.get("trace")),
                                trace_id=req.get("traceId") or None) as tr:
-                if tr is not None:
-                    # pre-origin, like _query's deserialize: the window wait
-                    # and the wire decode both preceded this trace's origin
-                    if flow_wait_ms:
-                        tr.record("mux:flow_control",
-                                  -(decode_ms + flow_wait_ms), flow_wait_ms)
-                    tr.record("deserialize", -decode_ms, decode_ms)
+                if tr is not None and flow_wait_ms:
+                    # pre-origin: the window wait and the wire decode both
+                    # preceded this trace's origin
+                    tr.record("mux:flow_control",
+                              -(decoded.ms + flow_wait_ms), flow_wait_ms)
                 result = self.server.execute_partial(
                     req["table"], req["sql"], req["segments"],
                     time_filter=req.get("timeFilter"))
@@ -913,37 +919,30 @@ class ServerService:
             return 429, [json.dumps(self._reject_body(e)).encode()]
         except QueryTimeoutError as e:
             return 408, [json.dumps(self._timeout_body(e)).encode()]
-        if flow_wait_ms:
-            stats = result.stats if isinstance(result.stats, dict) else {}
-            stats[MUX_FLOW_CONTROL_MS] = round(
-                stats.get(MUX_FLOW_CONTROL_MS, 0.0) + flow_wait_ms, 3)
-            result.stats = stats
+        qstats.add_ms(result, (qstats.MUX_FLOW_CONTROL_MS, flow_wait_ms),
+                      (qstats.SERVER_DECODE_MS, decoded.ms))
         spans = None
         if tr is not None:
             spans = [dict(s,
                           name=f"server:{self.server.instance_id}/{s['name']}")
                      for s in tr.to_rows()]
-        return 200, encode_segment_result_parts(result, trace_spans=spans)
+        with stage("server.encode") as encoded:
+            parts = encode_segment_result_parts(result, trace_spans=spans)
+        self._count_encode(encoded.ms)
+        return 200, parts
 
     def _query(self, parts, params, body):
-        import time as _time
         from ..auth import require_table_access
         from ..query.scheduler import QueryRejectedError, QueryTimeoutError
         from ..utils.trace import request_trace
-        t_decode = _time.perf_counter()
-        req = decode_query_request(body)
-        decode_ms = (_time.perf_counter() - t_decode) * 1000
+        with stage("server.decode") as decoded:
+            req = decode_query_request(body)
         require_table_access(req["table"], "READ")
         try:
             # traceId propagates the dispatching broker's trace context so this
             # server's spans splice into the SAME distributed trace
             with request_trace(bool(req.get("trace")),
                                trace_id=req.get("traceId") or None) as tr:
-                if tr is not None:
-                    # the wire decode ran just before this trace's origin;
-                    # record it pre-origin (negative start) so the hop reads
-                    # serialize -> send -> deserialize -> execute once rebased
-                    tr.record("deserialize", -decode_ms, decode_ms)
                 result = self.server.execute_partial(
                     req["table"], req["sql"], req["segments"],
                     time_filter=req.get("timeFilter"))
@@ -953,13 +952,17 @@ class ServerService:
         except QueryTimeoutError as e:
             return 408, "application/json", json.dumps(
                 self._timeout_body(e)).encode()
+        qstats.add_ms(result, (qstats.SERVER_DECODE_MS, decoded.ms))
         spans = None
         if tr is not None:
             # prefix with this server's id so the broker's spliced view reads like
             # its own scatter spans (server:<id>/segment:...)
             spans = [dict(s, name=f"server:{self.server.instance_id}/{s['name']}")
                      for s in tr.to_rows()]
-        return binary_response(encode_segment_result(result, trace_spans=spans))
+        with stage("server.encode") as encoded:
+            payload = encode_segment_result(result, trace_spans=spans)
+        self._count_encode(encoded.ms)
+        return binary_response(payload)
 
     def _health(self, parts, params, body):
         """GET /health — pure liveness, always 200 while the process serves
@@ -974,6 +977,13 @@ class ServerService:
             # amortized fetches; tests/bench read this to verify the served
             # path actually executed on the device
             st["device"] = self.server.device_pipeline.stats()
+            # the process's collections and this service's answer encodes
+            # beside them: a reader's delta over a window says whether a
+            # collection or the encode was behind a stall
+            st["device"].update(gc_stats())
+            with self._mux_lock:
+                st["device"].update(encodes=self._encodes,
+                                    encodeMs=round(self._encode_ms, 3))
         if parts and parts[0] == "readiness":
             return json_response(st, status=200 if st["ready"] else 503)
         return json_response(st, status=200)

@@ -767,6 +767,24 @@ class MeshQueryExecutor:
                 # named its members in: one key, one block
                 routed = list(segments) if len(segments) < len(held) else None
                 segments = list(resident)
+        # the two halves of a prepare, timed where they run: the plan, then
+        # the kernel spec, decode tables and runtime inputs
+        with stage("prepare.plan") as planned:
+            plan = self._plan_partial(ctx, segments, routed)
+        qstats.record(qstats.DEVICE_PLAN_MS, planned.ms)
+        if plan is None:
+            return None
+        with stage("prepare.inputs") as built:
+            try:
+                p = self._build_partial(ctx, plan, segments, routed)
+            except DocsetPlanDivergence:
+                p = None
+        qstats.record(qstats.DEVICE_INPUTS_MS, built.ms)
+        return p
+
+    def _plan_partial(self, ctx: QueryContext, segments, routed):
+        """`prepare_partial`'s plan: (plan, view) of the set, or None where the
+        host answers."""
         if not ctx.aggregations and not ctx.distinct:
             # selection: only the immutable top-k path rides the device (no
             # merged-view remap — a fallback verdict must stay cheap)
@@ -777,17 +795,22 @@ class MeshQueryExecutor:
                                 scan_docs=sum(s.num_docs for s in segments))
             if plan.kind != "selection":
                 return None  # empty/pruned: the host path answers trivially
-            return self._prepare_topk(ctx, plan, segments, routed)
+            return plan, None
         plan, view = self._plan_for_set(ctx, segments, routed)
+        if not isinstance(plan, StarSetPlan) and (plan is None
+                                                  or plan.kind != "device"):
+            return None
+        return plan, view
+
+    def _build_partial(self, ctx: QueryContext, planned, segments, routed):
+        """`prepare_partial`'s inputs: the PreparedDispatch of a plan."""
+        plan, view = planned
+        if plan.kind == "selection":
+            return self._prepare_topk(ctx, plan, segments, routed)
         if isinstance(plan, StarSetPlan):
             return self._prepare_star(ctx, plan)
-        if plan is None or plan.kind != "device":
-            return None
-        try:
-            return self._prepare_sharded(ctx, plan, segments, view,
-                                         partial=True, routed=routed)
-        except DocsetPlanDivergence:
-            return None
+        return self._prepare_sharded(ctx, plan, segments, view,
+                                     partial=True, routed=routed)
 
     def fetch(self, trees):
         """One host sync for a batch of dispatched output trees (the
@@ -825,26 +848,34 @@ class MeshQueryExecutor:
         for key in order:
             idxs = groups[key]
             ps = [reps[i] for i in idxs]
+            # a launch's two pieces: the executable's lookup (a build and
+            # compile on a miss), then the jitted calls that enqueue it
             with qstats.scoped() as recorded:
                 if len(ps) == 1:
                     p = ps[0]
                     if p.kind == "topk":
-                        outs = p.launch()
+                        with stage("launch.call"):
+                            outs = p.launch()
                         if self.n_devices > 1:
                             # a plain jit over the sharded block: the
                             # compiler's own collectives are not counted
                             qstats.record(qstats.MESH_LAUNCHES)
                     else:
-                        fn = self._get_shard_kernel(p.spec, p.s_pad, p.rows,
-                                                    window=p.window)
+                        with stage("launch.kernel"):
+                            fn = self._get_shard_kernel(
+                                p.spec, p.s_pad, p.rows, window=p.window)
                         _record_fused(p)
-                        outs = fn(p.inputs)
-                    packed, unpack = self._pack(outs, p.trim_keys, batched=0)
+                        with stage("launch.call"):
+                            outs = fn(p.inputs)
+                    with stage("launch.call"):
+                        packed, unpack = self._pack(outs, p.trim_keys,
+                                                    batched=0)
                     finish = (lambda host, u=unpack: [u(host)])
                 else:
                     outs, b_real = self._launch_stacked(ps)
-                    packed, unpack = self._pack(outs, ps[0].trim_keys,
-                                                batched=b_real)
+                    with stage("launch.call"):
+                        packed, unpack = self._pack(outs, ps[0].trim_keys,
+                                                    batched=b_real)
                     finish = (lambda host, u=unpack, n=b_real:
                               [u(host, b) for b in range(n)])
                 # what the launch read of the resident set (a stacked launch
@@ -869,11 +900,13 @@ class MeshQueryExecutor:
         inputs = dict(ps[0].inputs)
         inputs["iscal"] = self._const(iscal)
         inputs["fscal"] = self._const(fscal)
-        fn = self._get_shard_kernel(ps[0].spec, ps[0].s_pad, ps[0].rows,
-                                    batch=b_pad, window=ps[0].window)
+        with stage("launch.kernel"):
+            fn = self._get_shard_kernel(ps[0].spec, ps[0].s_pad, ps[0].rows,
+                                        batch=b_pad, window=ps[0].window)
         # one persistent launch carries every stacked query's fused scan
         _record_fused(ps[0])
-        return fn(inputs), b
+        with stage("launch.call"):
+            return fn(inputs), b
 
     def _pack(self, outs_dev: Dict[str, jnp.ndarray], trim_keys: Tuple[int, int],
               batched: int):

@@ -64,6 +64,22 @@ DEVICE_HANDOFF_MS = "deviceHandoffMs"
 DEVICE_DECODE_MS = "deviceDecodeMs"
 DEVICE_BATCH_SIZE = "deviceBatchSize"
 SERVER_TIME_MS = "serverTimeMs"
+# the hop's pieces, each timed where its work runs (PR 38): the CPU its
+# prepare and its drain's launch took on the dispatcher thread (wall far above
+# CPU is a wait for the GIL); the prepare's plan and its input build; the
+# fetcher resolving its answer -> the handler thread back from the future;
+# the server's acquire + admission + settle, its merge, its request decode;
+# the broker's request encode and its result decode
+DEVICE_PREPARE_CPU_MS = "devicePrepareCpuMs"
+DEVICE_LAUNCH_CPU_MS = "deviceLaunchCpuMs"
+DEVICE_PLAN_MS = "devicePlanMs"
+DEVICE_INPUTS_MS = "deviceInputsMs"
+DEVICE_WAKE_MS = "deviceWakeMs"
+SERVER_ACQUIRE_MS = "serverAcquireMs"
+SERVER_MERGE_MS = "serverMergeMs"
+SERVER_DECODE_MS = "serverDecodeMs"
+SCATTER_SERIALIZE_MS = "scatterSerializeMs"
+SCATTER_DESERIALIZE_MS = "scatterDeserializeMs"
 DEDUPED_LAUNCHES = "dedupedLaunches"
 STACKED_LAUNCHES = "stackedLaunches"
 # fused-vs-staged execution split (PR 16): fusedLaunches counts single-launch
@@ -172,7 +188,11 @@ COUNTER_KEYS = (
     DEVICE_LAUNCHES, COMPILE_CACHE_HITS, COMPILE_CACHE_MISSES,
     COMPILE_MS, DEVICE_EXEC_MS, DEVICE_FETCH_MS, BYTES_FETCHED,
     QUEUE_WAIT_MS, DEVICE_PREPARE_MS, DEVICE_LAUNCH_MS, DEVICE_HANDOFF_MS,
-    DEVICE_DECODE_MS, DEDUPED_LAUNCHES, STACKED_LAUNCHES,
+    DEVICE_DECODE_MS, DEVICE_PREPARE_CPU_MS, DEVICE_LAUNCH_CPU_MS,
+    DEVICE_PLAN_MS, DEVICE_INPUTS_MS, DEVICE_WAKE_MS,
+    SERVER_ACQUIRE_MS, SERVER_MERGE_MS, SERVER_DECODE_MS,
+    SCATTER_SERIALIZE_MS, SCATTER_DESERIALIZE_MS,
+    DEDUPED_LAUNCHES, STACKED_LAUNCHES,
     FUSED_LAUNCHES, STAGED_LAUNCHES, GATHER_FREE_LAUNCHES, SLABBED_LAUNCHES,
     WIDENED_AGG_LAUNCHES, MASKED_GROUPBY_LAUNCHES,
     COMPACT_DECODE_LAUNCHES, DENSE_DECODE_LAUNCHES,
@@ -358,6 +378,17 @@ def decode_branch(outs) -> Tuple[str, ...]:
         return ()
     return tuple(keys[0] if int(outs[flag]) else keys[1]
                  for flag, keys in DECODE_FLAGS.items() if flag in outs)
+
+
+def add_ms(result, *timed: Tuple[str, float]) -> None:
+    """Add each (key, ms) to a partial's flat stats dict (`SegmentResult.stats`,
+    the wire's form): what a transport or handler timed outside the record
+    the server merged. A 0 adds nothing."""
+    stats = result.stats if isinstance(result.stats, dict) else {}
+    for key, ms in timed:
+        if ms:
+            stats[key] = round(stats.get(key, 0.0) + ms, 3)
+    result.stats = stats
 
 
 def record_min(key: str, v: float) -> None:

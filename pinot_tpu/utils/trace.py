@@ -31,11 +31,21 @@ runs the same spans land in its `/host:CPU` plane on the clock of the device
 operations (a span of a request carries its `trace_id`). `stage()` is for
 threads that serve many requests and so have no `Trace` of their own (the
 device pipeline's dispatcher and fetcher). With no session an annotation is a
-flag test.
+flag test. Both time their body on the wall clock (`ms`); a `stage(...,
+cpu=True)` also on its thread's CPU clock (`cpu_ms`, `time.thread_time`):
+wall far above CPU in a body that does no I/O is a thread waiting for the
+GIL. The CPU clock is a system call (8.6 us a read on the chip's host, 0.35
+in a sandbox: PR 38), so only the stages whose CPU lands on an answer read it.
+
+The process's garbage collections are the third thing on that clock:
+`install_gc_hook()` (once a process, by the server) counts every collection
+and its pause (`gc_stats()`, on `/health`'s `device` block) and opens
+`pinot:gc` around each generation-2 one.
 """
 
 from __future__ import annotations
 
+import gc
 import random
 import threading
 import time
@@ -75,10 +85,6 @@ class Trace:
         starts, remote rebasing, and pipeline attribution read this instead of
         reaching into `_t0`."""
         return (time.perf_counter() - self._t0) * 1000
-
-    def elapsed_ms(self) -> float:
-        """Alias of `now_ms` (kept for the dispatch-rebasing call sites)."""
-        return self.now_ms()
 
     def record(self, name: str, start_ms: float, duration_ms: float,
                depth: int = 0, error: bool = False) -> None:
@@ -157,45 +163,51 @@ def request_trace(enabled: bool, request_id: str = "",
 
 @contextmanager
 def span(name: str):
-    """Record a named span on the current thread's active trace (no-op if none).
-    A body that exits via exception marks the span `error: true` so failed
-    phases are visible in exported timelines."""
+    """`with span("server.merge") as sp: ...` then `sp.ms`: a `stage()` that
+    is also recorded on the current thread's active trace
+    (only the annotation where there is none). A body that exits via
+    exception marks the span `error: true` so failed phases are visible in
+    exported timelines."""
     tr = getattr(_local, "trace", None)
     if tr is None:
-        with TraceAnnotation(ANNOTATION_PREFIX + name):
-            yield
+        with stage(name) as st:
+            yield st
         return
     depth = getattr(_local, "depth", 0)
     _local.depth = depth + 1
     start_ms = tr.now_ms()
-    t0 = time.perf_counter()
+    st = stage(name, trace_id=tr.trace_id)
     error = False
     try:
-        with TraceAnnotation(ANNOTATION_PREFIX + name, trace_id=tr.trace_id):
-            yield
+        with st:
+            yield st
     except BaseException:
         error = True
         raise
     finally:
         _local.depth = depth
-        tr.record(name, start_ms, (time.perf_counter() - t0) * 1000, depth,
-                  error=error)
+        tr.record(name, start_ms, st.ms, depth, error=error)
 
 
 class stage:
     """`with stage("pipeline.fetch", batch=2) as st: ...` then `st.ms`: a span
     on a thread that has no request `Trace`. It opens the profiler annotation
-    `pinot:<name>` with `attrs` and times the body on `perf_counter`; where the
-    milliseconds go (an item's stats, a histogram) is the caller's business."""
+    `pinot:<name>` with `attrs` and times the body on `perf_counter`, and with
+    `cpu=True` on the thread's CPU clock too (`st.cpu_ms`; None without);
+    where the milliseconds go (an item's stats, a histogram) is the caller's
+    business."""
 
-    __slots__ = ("_annotation", "_t0", "ms")
+    __slots__ = ("_annotation", "_t0", "_c0", "ms", "cpu_ms")
 
-    def __init__(self, name: str, **attrs: Any):
+    def __init__(self, name: str, cpu: bool = False, **attrs: Any):
         self._annotation = TraceAnnotation(ANNOTATION_PREFIX + name, **attrs)
         self.ms = 0.0
+        self.cpu_ms = 0.0 if cpu else None
 
     def __enter__(self) -> "stage":
         self._annotation.__enter__()
+        if self.cpu_ms is not None:
+            self._c0 = time.thread_time()
         self._t0 = time.perf_counter()
         return self
 
@@ -205,8 +217,48 @@ class stage:
 
     def __exit__(self, *exc) -> bool:
         self.ms = (time.perf_counter() - self._t0) * 1000
+        if self.cpu_ms is not None:
+            self.cpu_ms = (time.thread_time() - self._c0) * 1000
         self._annotation.__exit__(*exc)
         return False
+
+
+# -- garbage collection on the same clock -------------------------------------
+
+#: collections of every generation, and their pauses summed (ms)
+_gc_counts = {"gcCollections": 0, "gcPauseMs": 0.0}
+#: the collection in progress: (its start, its `pinot:gc` stage or None). A
+#: collection runs on one thread and never overlaps another
+_gc_open: list = []
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    if phase == "start":
+        st = None
+        if info.get("generation") == 2:
+            # a young collection is only counted: annotating each costs more
+            # than it tells
+            st = stage("gc", generation=2).__enter__()
+        _gc_open.append((time.perf_counter(), st))
+    elif _gc_open:
+        t0, st = _gc_open.pop()
+        if st is not None:
+            st.__exit__(None, None, None)
+        _gc_counts["gcCollections"] += 1
+        _gc_counts["gcPauseMs"] += (time.perf_counter() - t0) * 1000
+
+
+def install_gc_hook() -> None:
+    """Count the process's collections and time their pauses (idempotent)."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def gc_stats() -> Dict[str, Any]:
+    """`gcCollections`, `gcPauseMs` since the hook went in (counters: a
+    reader takes deltas over its own window)."""
+    return {"gcCollections": _gc_counts["gcCollections"],
+            "gcPauseMs": round(_gc_counts["gcPauseMs"], 3)}
 
 
 # -- sampling + retention -----------------------------------------------------

@@ -586,3 +586,60 @@ def test_cold_kernel_says_so_in_its_own_response_not_its_neighbours(
             assert sum(s[k] for k in PHASE_KEYS) <= s["serverTimeMs"]
     finally:
         pipeline.stop()
+
+
+# -- PR 38: the hop's pieces timed where they run ------------------------------
+
+HOP_KEYS = ("devicePrepareCpuMs", "deviceLaunchCpuMs", "devicePlanMs",
+            "deviceInputsMs", "deviceWakeMs", "serverAcquireMs",
+            "serverMergeMs")
+
+
+def test_served_answer_carries_the_hops_pieces(tmp_path, ssb_schema):
+    """Real executor, CPU mesh: a served answer carries the pieces that were
+    found by subtraction, each timed where it ran. Acquire, merge and the
+    handler's wake-up fit in what `server.host_ms` subtracts; the prepare's
+    two halves fit in the prepare; CPU never exceeds its wall."""
+    cluster = QuickCluster(num_servers=1, work_dir=str(tmp_path))
+    pipeline = DeviceQueryPipeline()
+    cluster.servers[0].device_pipeline = pipeline
+    cfg = TableConfig(ssb_schema.name)
+    cluster.create_table(ssb_schema, cfg)
+    cluster.ingest_columns(cfg, make_ssb_columns(np.random.default_rng(38),
+                                                 1500))
+    try:
+        for sql in ("SELECT COUNT(*), SUM(lo_revenue) FROM lineorder "
+                    "WHERE lo_quantity >= 7",
+                    "SELECT lo_quantity, SUM(lo_revenue) FROM lineorder "
+                    "GROUP BY lo_quantity"):
+            cluster.query(sql)                  # compiled, then served warm
+            s = cluster.query(sql).stats
+            assert s["deviceLaunches"] >= 1
+            for key in HOP_KEYS:
+                assert key in s, key
+            assert s["deviceWakeMs"] >= 0.0
+            host_ms = s["serverTimeMs"] - sum(s[k] for k in PHASE_KEYS)
+            assert (s["serverAcquireMs"] + s["serverMergeMs"]
+                    + s["deviceWakeMs"]) <= host_ms + 0.01
+            assert s["serverAcquireMs"] > 0.0 and s["serverMergeMs"] > 0.0
+            assert 0.0 < s["devicePlanMs"] + s["deviceInputsMs"] \
+                <= s["devicePrepareMs"] + 0.01
+            assert s["devicePrepareCpuMs"] <= s["devicePrepareMs"] + 0.5
+            assert s["deviceLaunchCpuMs"] <= s["deviceLaunchMs"] + 0.5
+    finally:
+        pipeline.stop()
+
+
+def test_wake_is_measured_from_the_resolve_on_the_handlers_side():
+    """deviceWakeMs runs from the fetcher's resolve to the caller back from
+    the future: a fake answer resolved at a known time reads the gap."""
+    fake = StatsMeshExec(fetch_latency=0.005)
+    pipeline = DeviceQueryPipeline(mesh_exec=fake)
+    try:
+        r = pipeline.execute_partial({"shape": "A", "literal": 1}, [])
+        assert 0.0 <= r.stats["deviceWakeMs"] < 1000.0
+        assert r.stats["deviceLaunchCpuMs"] <= r.stats["deviceLaunchMs"] + 0.5
+        assert r.stats["devicePrepareCpuMs"] <= \
+            r.stats["devicePrepareMs"] + 0.5
+    finally:
+        pipeline.stop()

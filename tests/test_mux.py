@@ -56,7 +56,8 @@ SCHEMA = Schema("trips", [
 #: transport-mechanics spans excluded when diffing server execution trees —
 #: the wire decomposition differs BY DESIGN between the two transports
 #: (matches the exclusion set in test_tracing's dual-transport differential)
-WIRE_SPANS = frozenset(("serialize", "send", "deserialize", "queue_wait",
+WIRE_SPANS = frozenset(("broker.serialize", "broker.send",
+                        "broker.deserialize", "queue_wait",
                         "mux:frame_queue", "mux:flow_control"))
 
 
@@ -166,6 +167,31 @@ def test_mux_vs_legacy_differential(dual_broker_cluster):
         for k in deterministic:
             if k in resp_m:
                 assert resp_m[k] == resp_l[k], (sql, k)
+
+
+def test_both_transports_time_the_wire_where_it_runs(dual_broker_cluster):
+    """The broker's request encode and answer decode, and the server's request
+    decode, ride every answer over both transports; the server's answer
+    encode, which cannot ride its own payload, is counted on /health's
+    `device` block beside the process's collections."""
+    from pinot_tpu.cluster.device_server import DeviceQueryPipeline
+    from pinot_tpu.cluster.http_service import get_json
+    _load_trips(dual_broker_cluster)
+    clients = _converged_clients(dual_broker_cluster)
+    for name, bc in clients.items():
+        resp = bc.query("SELECT city, SUM(fare) FROM trips GROUP BY city")
+        for key in ("scatterSerializeMs", "scatterDeserializeMs",
+                    "serverDecodeMs", "serverAcquireMs", "serverMergeMs"):
+            assert resp[key] > 0.0, (name, key)
+    node, _, ssvc = dual_broker_cluster["servers"][0]
+    node.device_pipeline = DeviceQueryPipeline(mesh_exec=object(),
+                                               start=False)
+    try:
+        device = get_json(f"{ssvc.url}/health")["device"]
+    finally:
+        node.device_pipeline = None
+    assert device["encodes"] >= 2 and device["encodeMs"] > 0.0
+    assert device["gcCollections"] >= 0 and device["gcPauseMs"] >= 0.0
 
 
 def test_mux_vs_legacy_explain_analyze(dual_broker_cluster):

@@ -394,3 +394,160 @@ def test_slice_of_finds_the_runs_profile(tmp_path, monkeypatch):
     assert program_trace.slice_of(ctx) is got            # kept on ctx
     untraced = dict(fresh(), trace=None)
     assert program_trace.slice_of(untraced) is None
+
+
+# -- PR 38: the idle split into classes that add up; the hop's fields ---------
+
+PR38_IDLE = ("device.idle_in_program_share", "device.idle_gc_share",
+             "device.idle_fetch_share", "device.idle_request_share",
+             "device.idle_unattributed_share")
+PR38_FIELDS = ("broker.wire_ms", "server.wake_ms", "server.acquire_merge_ms",
+               "pipeline.prepare_cpu_share")
+PR38_METRICS = PR38_IDLE + PR38_FIELDS
+
+
+def _idle_ctx(spans, modules, busy):
+    ctx = _ctx([], {}, _program(busy=busy, spans=spans))
+    ctx["program_modules"] = modules
+    return ctx
+
+
+def _read_all(ctx, names):
+    return {n: cells.load_reader(n)(ctx) for n in names}
+
+
+def test_idle_classes_partition_a_slice_made_by_hand():
+    """100 ns, the device idle in [10, 30), [40, 80), [85, 100): 75 ns.
+    [10, 30): prepare [10, 14) and a decode [26, 28) are host work (7 with
+    the prepare at [42, 43) below), wait without a fetch [14, 18) starves
+    (4), the fetch and the gather hold [18, 30) but for the decode (10).
+    [40, 80): a module runs to 45 (4: the prepare inside it wins), a full
+    collection [45, 50) (5), a hand-off [50, 52) (fetch: 12 in all), then
+    only the request's spans (28). [85, 100): the request to 95 (38 in all),
+    nothing after (5). The seven add up to device.idle_share exactly."""
+    ctx = _idle_ctx(
+        spans={"pinot:pipeline.prepare": [(10.0, 14.0), (42.0, 43.0)],
+               "pinot:pipeline.decode": [(26.0, 28.0)],
+               "pinot:pipeline.wait": [(14.0, 20.0)],
+               "pinot:pipeline.fetch": [(18.0, 24.0)],
+               "pinot:pipeline.gather": [(24.0, 30.0)],
+               "pinot:pipeline.handoff": [(50.0, 52.0)],
+               "pinot:gc": [(45.0, 50.0)],
+               "pinot:http.query": [(52.0, 95.0)],
+               "pinot:server.execute": [(60.0, 70.0)]},
+        modules=[[30.0, 45.0]],
+        busy=[[0.0, 10.0], [30.0, 40.0], [80.0, 85.0]])
+    got = _read_all(ctx, ("device.idle_host_busy_share",
+                          "device.idle_starved_share") + PR38_IDLE)
+    assert got == pytest.approx({
+        "device.idle_host_busy_share": 7.0, "device.idle_starved_share": 4.0,
+        "device.idle_in_program_share": 4.0, "device.idle_gc_share": 5.0,
+        "device.idle_fetch_share": 12.0, "device.idle_request_share": 38.0,
+        "device.idle_unattributed_share": 5.0})
+    t = ctx["program_trace"]
+    idle = 100.0 * program_trace.length(program_trace.complement(
+        t["busy"], t["lo"], t["hi"])) / (t["hi"] - t["lo"])
+    assert sum(got.values()) == pytest.approx(idle, abs=1e-12) == 75.0
+
+
+def test_idle_classes_leave_out_what_the_two_older_ones_share():
+    """A decode on the fetcher while the dispatcher waits (no fetch open)
+    counts in both older classes; the five newer ones count it in neither,
+    and the partition says how much the two share."""
+    from benchmark.harness import idle_classes
+    ctx = _idle_ctx(
+        spans={"pinot:pipeline.wait": [(10.0, 20.0)],
+               "pinot:pipeline.decode": [(15.0, 25.0)],
+               "pinot:http.query": [(0.0, 100.0)]},
+        modules=[], busy=[[0.0, 10.0], [50.0, 100.0]])
+    got = _read_all(ctx, ("device.idle_host_busy_share",
+                          "device.idle_starved_share") + PR38_IDLE)
+    assert got["device.idle_host_busy_share"] == pytest.approx(10.0)
+    assert got["device.idle_starved_share"] == pytest.approx(10.0)
+    assert got["device.idle_request_share"] == pytest.approx(25.0)
+    assert idle_classes.partition(ctx)["overlap"] == pytest.approx(5.0)
+    assert sum(got.values()) == pytest.approx(40.0 + 5.0)
+
+
+@pytest.mark.parametrize("name", PR38_METRICS)
+def test_pr38_reader_returns_none_on_its_parents_run(name, recorded, sliced):
+    """PR 26's chip slice has the pipeline's spans and none that PR 38 added;
+    its answers have none of PR 38's fields. Nothing raises."""
+    ctx = _ctx(recorded["responses"], recorded["counters"], sliced)
+    ctx["program_modules"] = []
+    assert cells.load_reader(name)(ctx) is None
+    assert cells.load_reader(name)(_ctx([], {}, None)) is None
+
+
+def test_pr38_field_readers_on_answers_made_by_hand():
+    responses = [
+        {"scatterSerializeMs": 0.1, "scatterDeserializeMs": 0.2,
+         "serverDecodeMs": 0.3, "deviceWakeMs": 0.5, "serverAcquireMs": 0.4,
+         "serverMergeMs": 0.6, "devicePrepareCpuMs": 2.0,
+         "deviceLaunchCpuMs": 1.0, "devicePrepareMs": 4.0,
+         "deviceLaunchMs": 2.0},
+        {"scatterSerializeMs": 0.3, "scatterDeserializeMs": 0.4,
+         "serverDecodeMs": 0.5, "deviceWakeMs": 1.5, "serverAcquireMs": 1.4,
+         "serverMergeMs": 0.2, "devicePrepareCpuMs": 3.0,
+         "deviceLaunchCpuMs": 0.0, "devicePrepareMs": 6.0,
+         "deviceLaunchMs": 8.0}]
+    got = _read_all(_ctx(responses, {}, None), PR38_FIELDS)
+    assert got == pytest.approx({
+        "broker.wire_ms": 0.9, "server.wake_ms": 1.0,
+        "server.acquire_merge_ms": 1.3,
+        "pipeline.prepare_cpu_share": 100.0 * 6.0 / 20.0})
+
+
+def test_module_intervals_cover_the_devices_operations(recorded, sliced):
+    """The "XLA Modules" line of the chip slice, walked alone: every
+    operation of the first device runs inside a module's execution."""
+    from benchmark.harness import idle_classes
+    modules = idle_classes.module_intervals(SLICE, sliced["lo"], sliced["hi"])
+    busy = sliced["busy"]
+    assert modules and sum(sliced["modules"].values()) >= len(modules)
+    inside = program_trace.intersect(busy, [list(m) for m in modules])
+    assert program_trace.length(inside) == pytest.approx(
+        program_trace.length(busy))
+
+
+@pytest.mark.parametrize("name", PR38_METRICS)
+def test_pr38_metric_is_declared_like_the_old(name):
+    """Nine `per_layer` entries with no `workloads` key: every cell owes
+    them; the `.json` beside each reader says the same."""
+    meta = cells.read_json(cells.BENCH, "metrics", name + ".json")
+    bench = cells.read_json(cells.ROOT, "BENCHMARK.json")
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
+    assert "workloads" not in entry
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert entry[key] == meta[key], key
+    assert meta["name"] == name and meta["what"]
+    assert entry["layer"] in {m["layer"] for m in bench["per_layer"]
+                              if m["name"] not in PR38_METRICS}
+
+
+def test_idle_map_counts_each_threads_innermost_span_once():
+    """benchmark/idle_map.py on a slice made by hand: the device idle in
+    [0, 30) of 100 ns. The handler's http.query holds scatter, which holds a
+    serialize; the dispatcher's prepare holds its plan and inputs. Each
+    instant of a thread goes to the span opened last there."""
+    from benchmark import idle_map
+    rows = {"pinot:http.query": [(0.0, 50.0, "h")],
+            "pinot:broker.scatter": [(10.0, 40.0, "h")],
+            "pinot:broker.serialize": [(12.0, 14.0, "h")],
+            "pinot:pipeline.prepare": [(20.0, 30.0, "d")],
+            "pinot:prepare.plan": [(20.0, 24.0, "d")],
+            "pinot:prepare.inputs": [(24.0, 29.0, "d")],
+            "pinot:pipeline.launch": [(29.5, 30.0, "d")]}
+    t = {"lo": 0.0, "hi": 100.0, "busy": [[30.0, 100.0]], "ops": [],
+         "modules": {}, "spans": {k: [(s, e, {}, line) for s, e, line in v]
+                                  for k, v in rows.items()}}
+    got = idle_map.idle_map(t, [])
+    assert dict(got["innermost_by_idle"]) == pytest.approx({
+        "pinot:http.query": 10.0, "pinot:broker.scatter": 18.0,
+        "pinot:broker.serialize": 2.0, "pinot:prepare.plan": 4.0,
+        "pinot:prepare.inputs": 5.0, "pinot:pipeline.prepare": 0.5,
+        "pinot:pipeline.launch": 0.5})
+    assert got["prepare_covered"] == pytest.approx(90.0)
+    assert got["launch_covered"] == 0.0
+    assert got["idle_share"] == 30.0 and got["host_busy"] == 10.0
+    assert got["seven_minus_idle"] == pytest.approx(0.0)

@@ -3,7 +3,8 @@ trace-event export, and the distributed span tree over BOTH transports.
 
 Acceptance shape (ISSUE 6): every slow-query log line carries a trace id that
 resolves at GET /debug/traces, whose spans decompose the broker<->server HTTP
-hop (serialize / send / queue_wait / deserialize / device exec); the Chrome
+hop (broker.serialize / broker.send / queue_wait / broker.deserialize /
+device exec); the Chrome
 export of a sampled multi-server query loads as a valid timeline; the in-proc
 transport produces the SAME server-execution span tree as HTTP.
 """
@@ -28,7 +29,8 @@ from pinot_tpu.utils.trace import (Trace, TraceRing, TraceSampler,
 # broker-side wire spans + scheduler admission: transport mechanics, not
 # server execution — excluded from the dual-transport differential (the mux
 # transport adds frame-queue / flow-control decomposition to the same hop)
-WIRE_SPANS = frozenset(("serialize", "send", "deserialize", "queue_wait",
+WIRE_SPANS = frozenset(("broker.serialize", "broker.send",
+                        "broker.deserialize", "queue_wait",
                         "mux:frame_queue", "mux:flow_control"))
 
 
@@ -310,7 +312,8 @@ def test_dual_transport_span_tree_differential(inproc_traced, http_traced):
                    for s in spans), [s["name"] for s in spans]
     # HTTP decomposes the hop with wire spans the in-proc transport never pays
     http_names = {s["name"] for s in http_spans}
-    assert {"serialize", "send", "deserialize"} <= http_names
+    assert {"broker.serialize", "broker.send",
+            "broker.deserialize"} <= http_names
     assert any(n.endswith("/queue_wait") for n in http_names)
     # ... but the server-execution tree (what ran, nested where) is IDENTICAL
     assert _server_exec_shape(inproc_spans) == _server_exec_shape(http_spans)
@@ -355,8 +358,8 @@ def test_http_slow_query_resolves_at_debug_traces(http_traced):
     assert got["slow"] is True
     names = {s["name"] for s in got["spans"]}
     # the 110ms-floor decomposition: wire + admission + server execution
-    assert {"serialize", "send", "deserialize"} <= names
-    assert any(n.endswith("/deserialize") for n in names)
+    assert {"broker.serialize", "broker.send", "broker.deserialize"} <= names
+    assert any(n.endswith("/server.merge") for n in names)
     assert any(n.endswith("/queue_wait") for n in names)
     assert any(re.match(r"server:server_\d+/(segment:|device)", n)
                for n in names), sorted(names)
@@ -414,10 +417,10 @@ def test_query_report_renders_exported_traces(http_traced, capsys):
     assert entries
     body = render_trace(entries[0])
     assert body.startswith("trace: ")
-    assert "serialize" in body
+    assert "broker.serialize" in body
     # the chrome form folds back into the same waterfall
     chrome = _trace_entries(get_json(f"{url}/debug/traces?format=chrome"))
-    assert chrome and any("serialize" in s["name"]
+    assert chrome and any("broker.serialize" in s["name"]
                           for e in chrome for s in e["spans"])
 
 
@@ -464,6 +467,79 @@ def test_stage_without_a_profiler_session_only_times():
         time.sleep(0.005)
     assert st.ms >= 4.0
     assert tracing.current_trace() is None      # and it opened no Trace
+
+
+def test_stage_records_cpu_beside_wall():
+    """With `cpu=True`, `cpu_ms` is the thread's CPU over the body: never
+    above the wall, near 0 for a body that sleeps, near the wall for one that
+    computes. Without, the CPU clock (a system call) is not read."""
+    with tracing.stage("pipeline.wait") as unread:
+        pass
+    assert unread.cpu_ms is None
+    with tracing.stage("pipeline.wait", cpu=True) as asleep:
+        time.sleep(0.03)
+    assert asleep.cpu_ms <= asleep.ms
+    assert asleep.cpu_ms < 5.0 and asleep.ms >= 25.0
+    with tracing.stage("pipeline.prepare", cpu=True) as busy:
+        t_end = time.perf_counter() + 0.03
+        while time.perf_counter() < t_end:
+            pass
+    assert busy.cpu_ms <= busy.ms + 0.5      # the two clocks' granularity
+    assert busy.cpu_ms > 0.3 * busy.ms
+
+
+def test_span_yields_its_timings_with_and_without_a_trace():
+    tr = tracing.Trace("r")
+    with tr.activate():
+        with tracing.span("server.merge") as sp:
+            time.sleep(0.01)
+    assert sp.ms >= 9.0 and sp.cpu_ms is None
+    (row,) = tr.to_rows()
+    assert row["name"] == "server.merge"
+    assert row["durationMs"] == pytest.approx(sp.ms, abs=1e-3)
+    with tracing.span("server.acquire") as orphan:      # no Trace
+        time.sleep(0.005)
+    assert orphan.ms >= 4.0 and len(tr.to_rows()) == 1
+
+
+def test_gc_hook_counts_a_full_collection_and_spans_it(tmp_path, monkeypatch):
+    """One hook a process: a `gc.collect(2)` counts as a collection with its
+    pause, and opens and closes `pinot:gc` (generation 2) on the thread that
+    collected; a young collection is counted and not spanned."""
+    import gc
+
+    tracing.install_gc_hook()
+    tracing.install_gc_hook()                   # idempotent
+    assert gc.callbacks.count(tracing._on_gc) == 1
+    before = tracing.gc_stats()
+    gc.collect(2)
+    after = tracing.gc_stats()
+    assert after["gcCollections"] >= before["gcCollections"] + 1
+    assert after["gcPauseMs"] > before["gcPauseMs"]
+
+    seen = []
+
+    class Recorder(tracing.stage):
+        __slots__ = ()
+
+        def __enter__(self):
+            seen.append(("enter", threading.get_ident()))
+            return super().__enter__()
+
+        def __exit__(self, *exc):
+            seen.append(("exit", threading.get_ident()))
+            return super().__exit__(*exc)
+    monkeypatch.setattr(tracing, "stage", Recorder)
+    gc.collect(0)
+    assert seen == []
+    worker = threading.Thread(target=gc.collect, args=(2,))
+    worker.start()
+    worker.join()
+    assert [k for k, _ in seen] == ["enter", "exit"]
+    assert seen[0][1] == seen[1][1] == worker.ident
+    monkeypatch.undo()
+    events = _host_events(_profiled(tmp_path, lambda: gc.collect(2)))
+    assert events["pinot:gc"][3] == {"generation": 2}
 
 
 def test_span_lands_in_both_sinks_with_the_trace_id(tmp_path):
