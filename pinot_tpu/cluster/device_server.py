@@ -12,9 +12,12 @@ device_put once at first touch with their mesh sharding and stay scan-ready,
 the data-readiness analog of the reference's mmap-resident buffers.
 
 THE PIPELINE IS THE SCHEDULER. One dispatcher thread owns the device; HTTP
-handler threads submit (ctx, segments) items and block on futures. Each drain
-of the queue PREPARES every pending query (plan + build inputs, no launch),
-then groups the prepared work before touching the device:
+handler threads submit (ctx, segments) items and block on futures. Every
+query of a drain is PREPARED (plan + build inputs, no launch) before its
+batch launches: as it is taken, while the batch before is still being
+fetched (the device is busy then, so the host work costs it nothing), else
+when the drain closes. The prepared work is grouped before touching the
+device:
 
   * items with equal `dedupe_key` are byte-identical dispatches — they share
     ONE kernel launch and ONE fetched result;
@@ -93,6 +96,14 @@ _LAUNCH_KEYS = (qstats.COMPILE_MS, qstats.COMPILE_CACHE_MISSES,
                 qstats.GATHER_FREE_LAUNCHES, qstats.DEVICE_PLAN_MS,
                 qstats.DEVICE_INPUTS_MS) + _SHAPE_KEYS + _STAGE_KEYS
 
+#: what the fetcher puts on the dispatcher's queue when a fetch ends: a
+#: drain holding its batch open for that fetch wakes and closes at once
+_FETCH_DONE = object()
+
+#: how long the gather waits on an empty queue before it looks again at the
+#: fetch and the burst window (a fetch's end does not wait for it)
+_GATHER_POLL_S = 0.005
+
 #: the pipeline's per-query phases in the order a query passes them: the
 #: item.stats key of each and the request-Trace span `execute_partial` rebuilds
 _PHASES = ((qstats.QUEUE_WAIT_MS, "pipeline:queue_wait"),
@@ -133,7 +144,7 @@ def _items(launches):
 
 class _Item:
     __slots__ = ("ctx", "segments", "resident", "future", "t_enqueue",
-                 "t_resolved", "stats", "trace_id")
+                 "t_resolved", "stats", "trace_id", "prepared", "ahead")
 
     def __init__(self, ctx, segments, trace_id: str = "", resident=None):
         self.ctx = ctx
@@ -149,6 +160,10 @@ class _Item:
         # pipeline threads serve MANY queries per drain, so per-query stats
         # can't ride thread-locals — they attach to the decoded partial
         self.stats: dict = {}
+        # its PreparedDispatch where the gather prepared it while a fetch was
+        # in flight, and whether that prepare ended before the fetch did
+        self.prepared = None
+        self.ahead = False
 
 
 class DeviceQueryPipeline:
@@ -162,6 +177,7 @@ class DeviceQueryPipeline:
             from ..parallel.combine import MeshQueryExecutor
             mesh_exec = MeshQueryExecutor()
         self.mesh_exec = mesh_exec
+        self._prepared_api = hasattr(mesh_exec, "prepare_partial")
         # chips one launch enqueues on and one fetch reads (fakes have none)
         self.devices = getattr(mesh_exec, "n_devices", 1)
         self.max_batch = max_batch
@@ -175,8 +191,9 @@ class DeviceQueryPipeline:
         self.burst_window_s = burst_window_s
         # graftcheck: ignore[admission-bypass] -- producers block in submit()
         # with submit_timeout_s and the dispatcher drains continuously; the
-        # real bound is _fetchq's max_inflight window right below
-        self._q: "queue.Queue[_Item]" = queue.Queue()
+        # real bound is _fetchq's max_inflight window right below. Holds
+        # _Items and the fetcher's _FETCH_DONE
+        self._q: "queue.Queue" = queue.Queue()
         # dispatched-but-unfetched batches: bounded so a slow fetch applies
         # backpressure to dispatch instead of piling device work up
         self._fetchq: "queue.Queue[list]" = queue.Queue(maxsize=max_inflight)
@@ -207,6 +224,8 @@ class DeviceQueryPipeline:
         self.drains_closed_full = 0
         self.drains_closed_burst = 0
         self.handoff_blocked = 0
+        # live queries whose prepare ended while the batch before was fetched
+        self.prepared_ahead = 0
         # per-stage wall times: the process registry histograms back /metrics
         self._hists = {s: get_registry().histogram(
             f"pinot_server_device_pipeline_{s}_ms") for s in _STAGES}
@@ -304,7 +323,8 @@ class DeviceQueryPipeline:
                 item = self._q.get_nowait()
             except queue.Empty:
                 break
-            _resolve(item.future, DEVICE_FALLBACK)
+            if isinstance(item, _Item):
+                _resolve(item.future, DEVICE_FALLBACK)
         while True:
             try:
                 entry, _ = self._fetchq.get_nowait()
@@ -314,12 +334,19 @@ class DeviceQueryPipeline:
                 _resolve(item.future, DEVICE_FALLBACK)
 
     # -- dispatcher thread ------------------------------------------------
+    def _fetch_in_flight(self) -> bool:
+        return self._fetch_busy.is_set() or not self._fetchq.empty()
+
     def _drain(self) -> Optional[list]:
         """Gather the next batch: everything already queued, plus — while a
         fetch is still in flight — whatever arrives before it completes.
         Dispatching earlier than that wins nothing (the fetcher is busy for
         a full host round trip anyway) and would shatter the batch into
-        singleton fetches, each paying its own round trip.
+        singleton fetches, each paying its own round trip. An item taken
+        while that fetch is in flight is prepared at once (`_prepare`), so
+        its plan and inputs are built while the device runs the batch
+        before; the fetch's end (`_FETCH_DONE`) wakes the gather, which then
+        closes without waiting for its next poll.
 
         Why the drain closed is counted: `drainsClosedFull` (`max_batch`),
         `drainsClosedBurst` (the queue was empty and nothing in flight, and the
@@ -327,25 +354,32 @@ class DeviceQueryPipeline:
         `drainsClosedIdle` (queue empty and no fetch in flight)."""
         with stage("pipeline.wait"):
             try:
-                first = self._q.get(timeout=0.05)
+                item = self._q.get(timeout=0.05)
             except queue.Empty:
                 return None
-        batch = [first]
+        if not isinstance(item, _Item):
+            return None
+        batch = []
         deadline = (time.perf_counter() + self.burst_window_s
                     if self.burst_window_s > 0 else None)
         held_by_window = False
         with stage("pipeline.gather") as gather:
             while True:
+                if isinstance(item, _Item):
+                    batch.append(item)
+                    if self._prepared_api and self._fetch_in_flight():
+                        item.prepared = self._prepare(item)
+                        item.ahead = (item.prepared is not None
+                                      and self._fetch_in_flight())
                 if len(batch) >= self.max_batch:
                     self.drains_closed_full += 1
                     break
                 try:
-                    batch.append(self._q.get_nowait())
+                    item = self._q.get_nowait()
                     continue
                 except queue.Empty:
                     pass
-                busy = self._fetch_busy.is_set() or not self._fetchq.empty()
-                if not busy:
+                if not self._fetch_in_flight():
                     if deadline is None or time.perf_counter() >= deadline:
                         if held_by_window:
                             self.drains_closed_burst += 1
@@ -354,20 +388,20 @@ class DeviceQueryPipeline:
                         break
                     held_by_window = True
                 try:
-                    batch.append(self._q.get(timeout=0.005))
+                    item = self._q.get(timeout=_GATHER_POLL_S)
                 except queue.Empty:
-                    continue
+                    item = None
             gather.note(n=len(batch))
         return batch
 
     def _loop(self) -> None:
-        """Dispatcher: drain -> prepare + group -> launch -> hand to fetcher.
+        """Dispatcher: drain (preparing what it takes while a fetch is in
+        flight) -> prepare the rest + group -> launch -> hand to fetcher.
 
         Two-stage pipelining: while the fetcher blocks in the host sync for
         batch N (one host round trip), batch N+1's kernels are ALREADY
         dispatched and executing on the device — the round trip overlaps
         compute instead of serializing behind it."""
-        prepared_api = hasattr(self.mesh_exec, "prepare_partial")
         while not self._stop.is_set():
             batch = self._drain()
             if batch is None:
@@ -384,8 +418,8 @@ class DeviceQueryPipeline:
                     _resolve(item.future, DEVICE_FALLBACK)
                 continue
             t0 = time.perf_counter()
-            if prepared_api:
-                entry, n_live = self._dispatch_grouped(batch, t0)
+            if self._prepared_api:
+                entry, n_live = self._dispatch_grouped(batch)
             else:
                 entry, n_live = self._dispatch_legacy(batch, t0)
             if not entry:
@@ -419,10 +453,51 @@ class DeviceQueryPipeline:
                 for item in _items(entry):
                     _resolve(item.future, DEVICE_FALLBACK)
 
-    def _dispatch_grouped(self, batch, t0):
-        """Prepare every live item, collapse identical dispatches, launch the
-        dedupe representatives (stacking where executables align). Returns
-        (fetch entry, live item count); the entry is a list of launches
+    def _prepare(self, item):
+        """Plan one live item and build its inputs (`prepare_partial`), no
+        launch. Returns its PreparedDispatch, or None where the item was
+        resolved instead: its caller timed out, its plan is not
+        device-eligible (a `fallbacks`), or the prepare raised (a
+        `deviceErrors`); the host path answers those."""
+        if item.future.done():
+            # caller already timed out and cancelled: don't burn a
+            # device dispatch on a result nobody will read
+            return None
+        # this thread serves many queries: what the kernel cache records
+        # while it plans THIS one goes to a scratch record, then the item
+        scratch = qstats.ExecutionStats()
+        try:
+            with qstats.activate(scratch), \
+                    stage("pipeline.prepare", cpu=True,
+                          trace_id=item.trace_id) as prep:
+                p = self.mesh_exec.prepare_partial(
+                    item.ctx, item.segments, item.resident)
+        except Exception:
+            # planning RAISED on the device path: the host path still
+            # answers the query, but as a counted, logged device error —
+            # not as a plan fallback
+            self.record_error("prepare_partial")
+            _resolve(item.future, DEVICE_FALLBACK)
+            return None
+        self._observe("prepare", prep.ms)
+        item.stats[qstats.DEVICE_PREPARE_MS] = round(prep.ms, 3)
+        item.stats[qstats.DEVICE_PREPARE_CPU_MS] = round(prep.cpu_ms, 3)
+        _fold(item.stats, scratch.counters)
+        for k in _STAGE_KEYS:
+            self.by_shape[k] += int(scratch.counters.get(k, 0))
+        if p is None:
+            self.fallbacks += 1
+            _resolve(item.future, DEVICE_FALLBACK)
+            return None
+        if not self.stack:
+            p.stackable = False
+        return p
+
+    def _dispatch_grouped(self, batch):
+        """Prepare every live item the drain has not, collapse identical
+        dispatches, launch the dedupe representatives (stacking where
+        executables align), in arrival order. Returns (fetch entry, live
+        item count); the entry is a list of launches
         `(outs_dev, finish, groups)` where `groups[i]` holds the
         (item, decode) pairs answered by the launch's i-th result."""
         reps = []          # dedupe-group representative PreparedDispatch
@@ -430,40 +505,14 @@ class DeviceQueryPipeline:
         dedupe_index: Dict[tuple, int] = {}
         for item in batch:
             if item.future.done():
-                # caller already timed out and cancelled: don't burn a
-                # device dispatch on a result nobody will read
+                # cancelled by its caller (its early prepare notwithstanding)
+                # or resolved by that prepare
                 continue
-            wait_ms = (t0 - item.t_enqueue) * 1000
-            self._observe("queue_wait", wait_ms)
-            item.stats[qstats.QUEUE_WAIT_MS] = round(wait_ms, 3)
-            # this thread serves many queries: what the kernel cache records
-            # while it plans THIS one goes to a scratch record, then the item
-            scratch = qstats.ExecutionStats()
-            try:
-                with qstats.activate(scratch), \
-                        stage("pipeline.prepare", cpu=True,
-                              trace_id=item.trace_id) as prep:
-                    p = self.mesh_exec.prepare_partial(
-                        item.ctx, item.segments, item.resident)
-            except Exception:
-                # planning RAISED on the device path: the host path still
-                # answers the query, but as a counted, logged device error —
-                # not as a plan fallback
-                self.record_error("prepare_partial")
-                _resolve(item.future, DEVICE_FALLBACK)
-                continue
-            self._observe("prepare", prep.ms)
-            item.stats[qstats.DEVICE_PREPARE_MS] = round(prep.ms, 3)
-            item.stats[qstats.DEVICE_PREPARE_CPU_MS] = round(prep.cpu_ms, 3)
-            _fold(item.stats, scratch.counters)
-            for k in _STAGE_KEYS:
-                self.by_shape[k] += int(scratch.counters.get(k, 0))
+            p = item.prepared if item.prepared is not None \
+                else self._prepare(item)
+            item.prepared = None    # the launch keeps what it needs of it
             if p is None:
-                self.fallbacks += 1
-                _resolve(item.future, DEVICE_FALLBACK)
                 continue
-            if not self.stack:
-                p.stackable = False
             if p.dedupe_key is not None and p.dedupe_key in dedupe_index:
                 rep_groups[dedupe_index[p.dedupe_key]].append(
                     (item, p.decode))
@@ -476,9 +525,18 @@ class DeviceQueryPipeline:
             rep_groups.append([(item, p.decode)])
         if not reps:
             return [], 0
-        n_live = sum(len(g) for g in rep_groups)
+        live = [item for group in rep_groups for item, _ in group]
+        t_launch = time.perf_counter()
+        for item in live:
+            # enqueue -> its batch's launch, less its own prepare: the six
+            # phases tile the item's time in the pipeline, and what it waited
+            # through of its neighbours' prepares is queue wait
+            wait_ms = max(0.0, (t_launch - item.t_enqueue) * 1000
+                          - item.stats[qstats.DEVICE_PREPARE_MS])
+            self._observe("queue_wait", wait_ms)
+            item.stats[qstats.QUEUE_WAIT_MS] = round(wait_ms, 3)
         try:
-            with stage("pipeline.launch", cpu=True, batch=n_live,
+            with stage("pipeline.launch", cpu=True, batch=len(live),
                        devices=self.devices) as launch:
                 launches = self.mesh_exec.dispatch_prepared(reps)
                 launch.note(launches=len(launches))
@@ -487,11 +545,11 @@ class DeviceQueryPipeline:
             # execution (availability over the fast path) — logged and
             # counted as ONE device error, not as plan fallbacks
             self.record_error("dispatch_prepared")
-            for group in rep_groups:
-                for item, _ in group:
-                    _resolve(item.future, DEVICE_FALLBACK)
+            for item in live:
+                _resolve(item.future, DEVICE_FALLBACK)
             return [], 0
         self._observe("launch", launch.ms)
+        self.prepared_ahead += sum(item.ahead for item in live)
         self.stacked_launches += sum(1 for _, _, idxs, _ in launches
                                      if len(idxs) > 1)
         for _, _, idxs, recorded in launches:
@@ -514,7 +572,7 @@ class DeviceQueryPipeline:
                         item.stats["stackedLaunches"] = 1
         entry = [(outs_dev, finish, [rep_groups[i] for i in idxs])
                  for outs_dev, finish, idxs, _ in launches]
-        return entry, n_live
+        return entry, len(live)
 
     def _dispatch_legacy(self, batch, t0):
         """One launch per item for executors without the prepared API (fakes,
@@ -589,6 +647,7 @@ class DeviceQueryPipeline:
                 self._observe("decode", dec.ms)
             finally:
                 self._fetch_busy.clear()
+                self._q.put(_FETCH_DONE)
 
     def _decode_launch(self, finish, groups, host, waited: dict) -> None:
         """Unpack one launch and resolve the queries it answers. `waited` is
@@ -644,7 +703,8 @@ class DeviceQueryPipeline:
                 "drainsClosedIdle": self.drains_closed_idle,
                 "drainsClosedFull": self.drains_closed_full,
                 "drainsClosedBurst": self.drains_closed_burst,
-                "handoffBlocked": self.handoff_blocked}
+                "handoffBlocked": self.handoff_blocked,
+                "preparedAhead": self.prepared_ahead}
 
 
 def pipeline_from_config(cfg) -> Optional[DeviceQueryPipeline]:

@@ -20,7 +20,7 @@ import time
 import numpy as np
 import pytest
 
-from pinot_tpu.cluster import QuickCluster
+from pinot_tpu.cluster import QuickCluster, device_server
 from pinot_tpu.cluster.device_server import (DEVICE_FALLBACK,
                                              DeviceQueryPipeline, _Item)
 from pinot_tpu.table import TableConfig
@@ -167,7 +167,7 @@ def test_all_timed_out_launches_never_fetched():
         b = _Item({"shape": "A", "literal": 1}, [])
         # dispatch on the calling thread (threads not running yet), then
         # cancel BOTH callers before the fetcher ever sees the entry
-        entry, n = pipeline._dispatch_grouped([a, b], time.perf_counter())
+        entry, n = pipeline._dispatch_grouped([a, b])
         assert n == 2 and entry
         a.future.cancel()
         b.future.cancel()
@@ -641,5 +641,192 @@ def test_wake_is_measured_from_the_resolve_on_the_handlers_side():
         assert r.stats["deviceLaunchCpuMs"] <= r.stats["deviceLaunchMs"] + 0.5
         assert r.stats["devicePrepareCpuMs"] <= \
             r.stats["devicePrepareMs"] + 0.5
+    finally:
+        pipeline.stop()
+
+
+# -- prepared while the batch before is fetched; woken by its end -----------
+
+class HeldFetchExec(StatsMeshExec):
+    """Every fetch waits until `release` is set: what is submitted after the
+    first fetch started arrives while a fetch is in flight. Each prepare
+    says whether it ran while that fetch was held."""
+
+    def __init__(self):
+        super().__init__()
+        self.release = threading.Event()
+        self.prepared_while_held = []
+
+    def prepare_partial(self, ctx, segments, resident=None):
+        self.prepared_while_held.append(self.fetch_started.is_set()
+                                        and not self.release.is_set())
+        if ctx.get("boom"):
+            raise _Boom("prepare")
+        return super().prepare_partial(ctx, segments)
+
+    def fetch(self, trees):
+        self.fetch_started.set()
+        assert self.release.wait(timeout=10)
+        return super().fetch(trees)
+
+
+def _submit(pipeline, ctxs):
+    """Each ctx from a thread of its own, at once; (threads, results)."""
+    results = [None] * len(ctxs)
+
+    def run(i):
+        results[i] = pipeline.execute_partial(ctxs[i], [])
+    threads = [threading.Thread(target=run, args=(i,))
+               for i in range(len(ctxs))]
+    for t in threads:
+        t.start()
+    return threads, results
+
+
+def _wait_for(cond, timeout=5.0):
+    deadline = time.time() + timeout
+    while not cond() and time.time() < deadline:
+        time.sleep(0.002)
+    return cond()
+
+
+def _join(threads):
+    for t in threads:
+        t.join(timeout=10)
+        assert not t.is_alive()
+
+
+def _hold_a_fetch(pipeline, fake):
+    """Launch one query of its own shape and hold its fetch open."""
+    head, _ = _submit(pipeline, [{"shape": "head", "literal": 0}])
+    assert fake.fetch_started.wait(timeout=5)
+    return head
+
+
+DRAIN = [{"shape": "A", "literal": 0}, {"shape": "A", "literal": 1},
+         {"shape": "B", "literal": 9}, {"shape": "A", "literal": 0}]
+
+
+def _one_drain(ctxs, ahead):
+    """The batch of `ctxs` formed while a fetch is in flight (`ahead`) or
+    queued before the pipeline starts: what it launched and answered."""
+    fake = HeldFetchExec()
+    if not ahead:
+        fake.release.set()
+        pipeline = DeviceQueryPipeline(mesh_exec=fake, start=False)
+        try:
+            results = _submit_concurrently(pipeline, ctxs)
+            return fake, pipeline.stats(), results
+        finally:
+            pipeline.stop()
+    pipeline = DeviceQueryPipeline(mesh_exec=fake)
+    try:
+        head = _hold_a_fetch(pipeline, fake)
+        t0 = time.perf_counter()
+        threads, results = _submit(pipeline, ctxs)
+        assert _wait_for(lambda: len(fake.prepared) == 1 + len(ctxs))
+        time.sleep(0.05)
+        # every item that arrived during the fetch is prepared before it ends
+        assert fake.prepared_while_held == [False] + [True] * len(ctxs)
+        assert fake.launched_keys == [("shape", "head")]
+        fake.release.set()
+        _join(head + threads)
+        wall_ms = (time.perf_counter() - t0) * 1000
+        for r in results:
+            # the phases still tile: waiting out the held fetch is queue wait
+            assert r.stats["queueWaitMs"] >= 40.0
+            assert sum(r.stats[k] for k in PHASE_KEYS) <= wall_ms
+        # ... and none is prepared again when the drain closes
+        assert fake.prepared == [{"shape": "head", "literal": 0}] + ctxs
+        del fake.launched_keys[0], fake.fetch_calls[0]
+        return fake, pipeline.stats(), results
+    finally:
+        pipeline.stop()
+
+
+def test_a_batch_prepared_during_the_fetch_launches_as_one_prepared_at_close():
+    """Items submitted while the fetch before is held are prepared before it
+    is released; they then launch as ONE batch with the grouping and dedupe
+    of the same items queued and prepared when the drain closes. Only the
+    early prepares count in `preparedAhead`."""
+    late, s_late, r_late = _one_drain(DRAIN, ahead=True)
+    close, s_close, r_close = _one_drain(DRAIN, ahead=False)
+    assert late.launched_keys == close.launched_keys == [("shape", "A"),
+                                                         ("shape", "B")]
+    assert late.fetch_calls == close.fetch_calls == [2]
+    for key in ("dedupeHits", "stackedLaunches"):
+        assert s_late[key] == s_close[key], key
+    assert (s_late["batches"], s_late["dispatched"]) == (2, 5)
+    assert (s_close["batches"], s_close["dispatched"]) == (1, 4)
+    assert [r.tag for r in r_late] == [r.tag for r in r_close] == [0, 1, 9, 0]
+    assert all(r.stats["deviceBatchSize"] == 4 for r in r_late + r_close)
+    assert r_late[3].stats["dedupedLaunches"] == 1
+    assert s_late["preparedAhead"] == 4 and s_close["preparedAhead"] == 0
+
+
+def test_an_item_cancelled_after_its_early_prepare_is_not_launched():
+    fake = HeldFetchExec()
+    pipeline = DeviceQueryPipeline(mesh_exec=fake)
+    try:
+        head = _hold_a_fetch(pipeline, fake)
+        stale = _Item({"shape": "stale", "literal": 1}, [])
+        pipeline._q.put(stale)
+        threads, results = _submit(pipeline, [{"shape": "B", "literal": 2}])
+        assert _wait_for(lambda: len(fake.prepared) == 3)
+        stale.future.cancel()       # its caller timed out after the prepare
+        fake.release.set()
+        _join(head + threads)
+        assert results[0].tag == 2
+        assert fake.launched_keys == [("shape", "head"), ("shape", "B")]
+        assert fake.fetch_calls == [1, 1]
+        s = pipeline.stats()
+        assert (s["dispatched"], s["preparedAhead"]) == (2, 1)
+    finally:
+        pipeline.stop()
+
+
+def test_an_early_prepare_that_raises_falls_back_and_the_rest_launch(caplog):
+    fake = HeldFetchExec()
+    pipeline = DeviceQueryPipeline(mesh_exec=fake)
+    try:
+        head = _hold_a_fetch(pipeline, fake)
+        with caplog.at_level("ERROR",
+                             logger="pinot_tpu.cluster.device_server"):
+            threads, results = _submit(
+                pipeline, [{"shape": "A", "literal": 1, "boom": True},
+                           {"shape": "A", "literal": 2}])
+            # the host answers the one that raised before the fetch ends
+            assert _wait_for(lambda: results[0] is not None)
+            assert results[0] is DEVICE_FALLBACK
+            assert _wait_for(lambda: len(fake.prepared_while_held) == 3)
+            fake.release.set()
+            _join(head + threads)
+        assert results[1].tag == 2
+        s = pipeline.stats()
+        assert (s["deviceErrors"], s["fallbacks"]) == (1, 0)
+        assert (s["batches"], s["dispatched"], s["preparedAhead"]) == (2, 2, 1)
+        assert fake.launched_keys == [("shape", "head"), ("shape", "A")]
+        assert any("prepare_partial" in r.getMessage() for r in caplog.records)
+    finally:
+        pipeline.stop()
+
+
+def test_the_fetchs_end_wakes_the_gather_not_its_poll(monkeypatch):
+    """With the gather's poll at 10 s, a drain held open by a fetch still
+    closes as soon as that fetch ends."""
+    monkeypatch.setattr(device_server, "_GATHER_POLL_S", 10.0)
+    fake = HeldFetchExec()
+    pipeline = DeviceQueryPipeline(mesh_exec=fake)
+    try:
+        head = _hold_a_fetch(pipeline, fake)
+        threads, results = _submit(pipeline, [{"shape": "A", "literal": 1}])
+        assert _wait_for(lambda: len(fake.prepared) == 2)
+        time.sleep(0.05)            # the gather is in its 10 s wait now
+        t_release = time.perf_counter()
+        fake.release.set()
+        _join(threads)
+        assert time.perf_counter() - t_release < 0.5
+        assert results[0].tag == 1
+        _join(head)
     finally:
         pipeline.stop()

@@ -551,3 +551,43 @@ def test_idle_map_counts_each_threads_innermost_span_once():
     assert got["launch_covered"] == 0.0
     assert got["idle_share"] == 30.0 and got["host_busy"] == 10.0
     assert got["seven_minus_idle"] == pytest.approx(0.0)
+
+
+# -- queries prepared while the batch before was fetched ----------------------
+
+AHEAD_METRIC = "pipeline.prepared_ahead_share"
+
+
+@pytest.mark.parametrize("counters,want", [
+    # a window's deltas of /health's device block, made by hand
+    ({"dispatched": 200, "batches": 100, "preparedAhead": 190}, 95.0),
+    ({"dispatched": 8, "batches": 8, "preparedAhead": 0}, 0.0),
+    # nothing dispatched: nothing to read
+    ({"dispatched": 0, "batches": 0, "preparedAhead": 0}, None),
+    # the parent's counters: no such key
+    ({"dispatched": 200, "batches": 100, "batchesOfOne": 0}, None),
+])
+def test_prepared_ahead_share_reads_the_counter_delta(counters, want,
+                                                      mesh_recorded, recorded):
+    read = cells.load_reader(AHEAD_METRIC)
+    got = read(_ctx([], counters, None))
+    assert got == want if want is None else got == pytest.approx(want)
+    for parent in (mesh_recorded["counters"], recorded["counters"]):
+        assert "preparedAhead" not in parent
+        assert read(_ctx([], parent, None)) is None
+
+
+def test_prepared_ahead_share_is_declared_like_the_old():
+    """A `per_layer` entry with no `workloads` key (every cell runs the
+    pipeline) in the form of `pipeline.singleton_batch_share`."""
+    meta = cells.read_json(cells.BENCH, "metrics", AHEAD_METRIC + ".json")
+    bench = cells.read_json(cells.ROOT, "BENCHMARK.json")
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == AHEAD_METRIC]
+    (like,) = [m for m in bench["per_layer"]
+               if m["name"] == "pipeline.singleton_batch_share"]
+    assert "workloads" not in entry
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert entry[key] == meta[key], key
+    for key in ("unit", "source", "layer", "moves"):
+        assert entry[key] == like[key], key
+    assert meta["name"] == AHEAD_METRIC and meta["what"]
