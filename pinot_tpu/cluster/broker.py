@@ -11,6 +11,7 @@ partial results + marked unhealthy (reference: `ConnectionFailureDetector` ->
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import logging
 import threading
@@ -24,7 +25,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple, Union
 
 from ..query import stats as qstats
 from ..query.aggregates import make_agg
-from ..query.context import QueryContext, QueryValidationError, compile_query
+from ..query.context import (SOLE_SERVER, QueryContext, QueryValidationError,
+                             compile_query)
 from ..query.reduce import SegmentResult, merge_segment_results, reduce_to_result
 from ..query.result import ResultTable
 from ..sql.ast import to_sql
@@ -772,6 +774,11 @@ class Broker:
                 uncovered_segments.extend(f"{table}:{s}" for s in sorted(unroutable))
                 missing: Dict[str, Set[str]] = {}  # segment -> servers that missed it
                 units: List[_DispatchUnit] = []
+                # one table routed to one server: its partial is the whole
+                # answer (retries and hedges keep the plain context)
+                sole_ctx = dataclasses.replace(
+                    ctx, options=dict(ctx.options, **{SOLE_SERVER: True})) \
+                    if len(physical) == 1 and len(routing) == 1 else ctx
                 for server_id, segments in routing.items():
                     handle = self._servers.get(server_id)
                     if handle is None:
@@ -782,7 +789,7 @@ class Broker:
                             missing.setdefault(seg, set()).add(server_id)
                         continue
                     fut = self._dispatch_partial(handle, server_id, _traced,
-                                                 table, ctx, segments, tf)
+                                                 table, sole_ctx, segments, tf)
                     units.append(_DispatchUnit(server_id, list(segments), fut))
                 q, f = self._gather_units(table, ctx, tf, _traced, units, partials,
                                           exec_stats, missing, query_errors,

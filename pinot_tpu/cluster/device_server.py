@@ -72,11 +72,13 @@ _STAGES = ("queue_wait", "dispatch", "prepare", "launch", "handoff", "fetch",
 #: also summed over the launches in `stats()`: on more than one device, what
 #: crosses the chips; past 2^24 rows a device, a matmul GROUP BY slab by slab;
 #: an aggregate's INT arithmetic widened where it would leave int32; a GROUP
-#: BY of a handful of key cells as the masked reduce;
+#: BY of a handful of key cells as the masked reduce; one past the dense key
+#: space from its sorted groups, and its ORDER BY ... LIMIT cut on the device;
 #: over a resident set, the slots routed, held and read, and the id space
 _SHAPE_KEYS = (qstats.MESH_LAUNCHES, qstats.SCATTER_LAUNCHES,
                qstats.COLLECTIVE_BYTES, qstats.SLABBED_LAUNCHES,
                qstats.WIDENED_AGG_LAUNCHES, qstats.MASKED_GROUPBY_LAUNCHES,
+               qstats.SPARSE_GROUPBY_LAUNCHES, qstats.DEVICE_TRIMMED_LAUNCHES,
                qstats.ROUTED_SLOTS, qstats.RESIDENT_SLOTS,
                qstats.SCANNED_SLOTS, qstats.MERGED_LAUNCHES)
 
@@ -664,6 +666,10 @@ class DeviceQueryPipeline:
         for outs, group in zip(outs_list, groups):
             for took in qstats.decode_branch(outs):
                 self.decodes[took] += 1
+            # its share of the batch's fetch: what the host sync moved for it
+            fetched = {qstats.BYTES_FETCHED: sum(
+                int(getattr(v, "nbytes", 0)) for v in outs.values())} \
+                if isinstance(outs, dict) else {}
             for item, decode in group:
                 if item.future.done():
                     continue  # caller timed out mid-fetch: skip the decode
@@ -683,7 +689,7 @@ class DeviceQueryPipeline:
                     # query-scoped thread-locals to publish into).
                     # deviceDecodeMs runs from the start of its launch's
                     # decode to this answer
-                    s = dict(item.stats, **waited)
+                    s = dict(item.stats, **waited, **fetched)
                     s[qstats.DEVICE_DECODE_MS] = round(
                         (time.perf_counter() - t0) * 1000, 3)
                     s.update(r.stats or {})

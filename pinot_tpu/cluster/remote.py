@@ -35,6 +35,13 @@ from .http_service import HttpError, get_json, http_call, post_json
 from .wire import decode_segment_result, encode_query_request
 
 
+def _sole(ctx) -> bool:
+    """Whether the broker routed this query to this server alone."""
+    from ..query.context import SOLE_SERVER
+    return bool(getattr(ctx, "options", None)
+                and ctx.options.get(SOLE_SERVER))
+
+
 class RemoteCatalog(Catalog):
     """Catalog mirror for a remote role process.
 
@@ -350,7 +357,8 @@ class RemoteServerHandle:
                 table, sql, segment_names, time_filter,
                 trace=tr is not None,
                 trace_id=tr.trace_id if tr is not None else "",
-                sampled=bool(tr.sampled) if tr is not None else False)
+                sampled=bool(tr.sampled) if tr is not None else False,
+                sole=_sole(ctx))
         if tr is not None:
             tr.record("broker.serialize", dispatch_ms, encoded.ms, depth + 1)
         try:
@@ -432,7 +440,8 @@ class RemoteServerHandle:
                 table, sql, segment_names, time_filter,
                 trace=tr is not None,
                 trace_id=tr.trace_id if tr is not None else "",
-                sampled=bool(tr.sampled) if tr is not None else False)
+                sampled=bool(tr.sampled) if tr is not None else False,
+                sole=_sole(ctx))
         with span("broker.send"):
             fault_point("server.crash")
             resp = http_call("POST", f"{self.server_url}/query", body,

@@ -11,6 +11,7 @@ external view.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import re
 import threading
@@ -18,7 +19,7 @@ from typing import Dict, List, Optional, Sequence, Union
 
 from ..query import stats as qstats
 from ..query.aggregates import make_agg
-from ..query.context import QueryContext, compile_query
+from ..query.context import SOLE_SERVER, QueryContext, compile_query
 from ..parallel.combine import device_topk_screen
 from ..query.executor import ServerQueryExecutor
 from ..query.reduce import SegmentResult, merge_segment_results
@@ -605,7 +606,8 @@ class ServerNode:
 
     def execute_partial(self, table: str, ctx: Union[str, QueryContext],
                         segment_names: Optional[Sequence[str]] = None,
-                        time_filter: Optional[str] = None) -> SegmentResult:
+                        time_filter: Optional[str] = None,
+                        sole: bool = False) -> SegmentResult:
         """Run the query over this server's copy of `segment_names`, return the merged
         server-level partial (reference: ServerQueryExecutorV1Impl.processQuery returning
         a DataTable).
@@ -613,10 +615,14 @@ class ServerNode:
         `time_filter` is an optional SQL boolean expression ANDed into the WHERE
         clause — the broker's hybrid-table time-boundary split (reference: the
         brokerRequest's timeBoundary attachment in BaseSingleStageBrokerRequestHandler).
+        `sole`: the broker routed the query to this server alone (the request's
+        flag over the wire; an in-process broker sets `SOLE_SERVER` on `ctx`).
         """
         schema = self.catalog.schema_for_table(table)
         if isinstance(ctx, str):
             ctx = compile_query(ctx, schema)
+        if sole:
+            ctx.options[SOLE_SERVER] = True
         if time_filter:
             ctx = _apply_time_filter(ctx, time_filter, schema)
         # graftfault: a crash here dies exactly where a killed process would
@@ -812,6 +818,15 @@ class ServerNode:
 
             results = []
             device_partial = None
+            if on_device and ctx.options.get(SOLE_SERVER) and (
+                    host_tier or handler is not None
+                    or (segment_names is not None
+                        and set(segment_names) - {s.name for s in segments})):
+                # other parts join this partial (host-tier or consuming
+                # segments), or a routed segment is missing: not the whole
+                # answer, so no cut of the ORDER BY ... LIMIT on the device
+                ctx = dataclasses.replace(ctx, options={
+                    k: v for k, v in ctx.options.items() if k != SOLE_SERVER})
             if on_device:
                 # device path: ONE server-level partial for the whole set,
                 # executed on the mesh with batched fetches; falls back per

@@ -914,7 +914,8 @@ class ServerService:
                               -(decoded.ms + flow_wait_ms), flow_wait_ms)
                 result = self.server.execute_partial(
                     req["table"], req["sql"], req["segments"],
-                    time_filter=req.get("timeFilter"))
+                    time_filter=req.get("timeFilter"),
+                    sole=bool(req.get("sole")))
         except QueryRejectedError as e:  # backpressure, not a server fault
             return 429, [json.dumps(self._reject_body(e)).encode()]
         except QueryTimeoutError as e:
@@ -945,7 +946,8 @@ class ServerService:
                                trace_id=req.get("traceId") or None) as tr:
                 result = self.server.execute_partial(
                     req["table"], req["sql"], req["segments"],
-                    time_filter=req.get("timeFilter"))
+                    time_filter=req.get("timeFilter"),
+                    sole=bool(req.get("sole")))
         except QueryRejectedError as e:   # backpressure, not a server fault
             return 429, "application/json", json.dumps(
                 self._reject_body(e)).encode()

@@ -338,6 +338,15 @@ def _segment_result_doc(r: SegmentResult, trace_spans=None) -> Dict[str, Any]:
             "outs": r.dense.outs,
             "groupValues": [np.asarray(v) for v in r.dense.group_values],
         },
+        # the groups that occur, past the dense key space
+        # (reduce.SparsePartial): arrays a group, `aggs` build-side only
+        "sparse": None if r.sparse is None else {
+            "groupValues": [np.asarray(v) for v in r.sparse.group_values],
+            "counts": r.sparse.counts,
+            "outs": r.sparse.outs,
+            "groups": r.sparse.groups,
+            "trimmed": r.sparse.trimmed,
+        },
     }
 
 
@@ -381,6 +390,16 @@ def decode_segment_result(data: Buffer) -> SegmentResult:
             group_values=[v if isinstance(v, np.ndarray)
                           else np.asarray(v, dtype=object)
                           for v in dd["groupValues"]])
+    sd = d.get("sparse")
+    if sd is not None:
+        from ..query.reduce import SparsePartial
+        r.sparse = SparsePartial(
+            group_values=[v if isinstance(v, np.ndarray)
+                          else np.asarray(v, dtype=object)
+                          for v in sd["groupValues"]],
+            counts=np.asarray(sd["counts"]),
+            outs={k: np.asarray(v) for k, v in sd["outs"].items()},
+            groups=int(sd["groups"]), trimmed=bool(sd["trimmed"]))
     if d.get("trace"):
         r.trace_spans = d["trace"]  # spliced into the broker's trace by the caller
     if d.get("stats"):
@@ -398,16 +417,23 @@ def decode_block(d: Dict[str, Any]) -> Dict[str, np.ndarray]:
 
 def encode_query_request(table: str, sql: str, segments,
                          time_filter: str = None, trace: bool = False,
-                         trace_id: str = "", sampled: bool = False) -> bytes:
+                         trace_id: str = "", sampled: bool = False,
+                         sole: bool = False) -> bytes:
     """Broker -> server query dispatch (reference: thrift InstanceRequest with the
     compiled query + searchSegments list, `InstanceRequestHandler.java:96`;
     `timeFilter` carries the hybrid time-boundary predicate, `trace` the request's
     trace-enabled flag — CommonConstants.Request.TRACE). `trace_id`/`sampled`
     propagate the dispatching broker's trace context so the server's spans splice
-    into the SAME distributed trace (the trace-context header analog)."""
-    return json.dumps({"table": table, "sql": sql, "segments": list(segments),
-                       "timeFilter": time_filter, "trace": trace,
-                       "traceId": trace_id, "sampled": sampled}).encode()
+    into the SAME distributed trace (the trace-context header analog). `sole`:
+    the broker routed the query to this server alone, so its partial is the
+    whole answer (the `soleServer` option: an ORDER BY ... LIMIT may be cut
+    server-side); absent on old peers, read as False."""
+    doc = {"table": table, "sql": sql, "segments": list(segments),
+           "timeFilter": time_filter, "trace": trace,
+           "traceId": trace_id, "sampled": sampled}
+    if sole:
+        doc["sole"] = True
+    return json.dumps(doc).encode()
 
 
 def decode_query_request(data: Buffer) -> Dict[str, Any]:

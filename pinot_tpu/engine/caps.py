@@ -55,6 +55,15 @@ class KernelCaps:
     # per-key broadcast-reduce min/max up to here (VPU-bound: above it the
     # broadcast does more device work than `segment_min` / `segment_max`)
     minmax_bcast_cap: int = 1024
+    # the widest key space (padded keys, the product of the group columns'
+    # cardinalities) a GROUP BY answers as a DENSE table of every key: the
+    # sort regime's dense decode, a fetch and a partial of one entry a key.
+    # Past it the sort regime answers from its SORTED GROUPS alone
+    # (`kernels._grouped_sparse`: the keys that occur, at most as many as the
+    # rows that passed), whose cost is in rows and not in ids: TPC-H Q3's
+    # GROUP BY over 16.8M order ids, of which about 117k occur (PERF.md,
+    # section 5). No plan at or under it changes program.
+    dense_keys: int = 1 << 21
     # rows a slab of the sort regime (a multiple of 64: its local ids are
     # the chunked matmul's two 64-wide digits)
     partition_block: int = 4096

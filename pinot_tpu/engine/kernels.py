@@ -26,9 +26,12 @@ matmuls —
   iota-compare into the dot's tiles), the CHUNKED 64x64-tile matmul
   `_grouped_chunk64` from there to
   `chunk_cap` (high-cardinality group-by AND the grouped-distinct presence
-  product space, bf16 3-part-split operands at full MXU tile utilization), and
+  product space, bf16 3-part-split operands at full MXU tile utilization),
   past `chunk_cap` the radix-partitioned sort `_grouped_partitioned` (no n-row
-  scatter). The row count chooses nothing: past SLAB_ROWS (2^24) rows a
+  scatter), and past `dense_keys` the same sort answering from its sorted
+  groups alone (`_grouped_sparse`: no table of the key space, the ORDER BY
+  ... LIMIT cut on the device where the partial is whole). The row count
+  chooses nothing: past SLAB_ROWS (2^24) rows a
   device the two matmul regimes run slab by slab (`_slab_sums`), int32
   counts added across the slabs; min/max by per-key broadcast-reduce up to
   `minmax_bcast_cap`, `segment_min` / `segment_max` above.
@@ -130,6 +133,11 @@ class KernelSpec:
     # the ranges, so segments whose min/max differ share a program.
     int_ranges: Dict[str, Optional[Tuple[int, int]]] = field(
         default_factory=dict)
+    # the ORDER BY ... LIMIT a sparse GROUP BY (`sparse`) cuts on the device
+    # where its partial is the whole answer: (k, ((source, desc), ...)), a
+    # source ("out", an output's name: "count", "<i>.sum", "<i>.min", ...)
+    # or ("key", group column j); () = no cut (`executor.sparse_trim_spec`)
+    trim: Tuple = ()
 
     # per-leaf runtime input routing, computed in __post_init__
     lut_index: Dict[int, int] = field(default_factory=dict)       # dense (scattered) LUTs
@@ -187,6 +195,7 @@ class KernelSpec:
             _filter_widen_marks(self), _agg_widen_marks(self),
             # regime caps change the traced program for the same plan shape
             get_caps().token(),
+            self.trim,
         )
 
 
@@ -412,8 +421,22 @@ def masked(spec: KernelSpec) -> bool:
     """Whether a launch of `spec` runs its GROUP BY's counts and sums as the
     masked VPU reduce (what `maskedGroupByLaunches` counts): at most
     `masked_cap` padded keys + 1, the bottom rung of `_make_body`'s ladder."""
-    return (bool(spec.group_cols)
+    return (bool(spec.group_cols) and not sparse(spec)
             and spec.num_keys_pad + 1 <= get_caps().masked_cap)
+
+
+def sparse(spec: KernelSpec) -> bool:
+    """Whether a launch of `spec` answers its GROUP BY from its sorted groups
+    (what `sparseGroupByLaunches` counts): a padded key space past
+    `KernelCaps.dense_keys`, the ladder's top rung (`_grouped_sparse`)."""
+    return (bool(spec.group_cols)
+            and spec.num_keys_pad > get_caps().dense_keys)
+
+
+def trimmed(spec: KernelSpec) -> bool:
+    """Whether such a launch also cuts the ORDER BY ... LIMIT on the device
+    (what `deviceTrimmedLaunches` counts): its partial is the whole answer."""
+    return sparse(spec) and bool(spec.trim)
 
 
 def slabbed(spec: KernelSpec, rows: int) -> bool:
@@ -884,6 +907,36 @@ def _run_totals(head: jnp.ndarray, v: jnp.ndarray):
              ).reshape(r, length))
 
 
+def _run_extremes(head: jnp.ndarray, v: jnp.ndarray, is_min: bool):
+    """The least (`is_min`) or the greatest of `v` [L] over every run of rows
+    restarting wherever `head` [L] is set, in `v`'s dtype (an int32 MIN of a
+    yyyymmdd date past 2^24 stays exact): at a run's last row, the run's. The
+    two levels of `_run_totals`, with a min or a max in place of the sum."""
+    length = v.size
+    nb, w = length // SCAN_WIDTH, SCAN_WIDTH
+    if jnp.issubdtype(v.dtype, jnp.floating):
+        ident = jnp.inf if is_min else -jnp.inf
+    else:
+        info = jnp.iinfo(v.dtype)
+        ident = info.max if is_min else info.min
+    fold = jnp.minimum if is_min else jnp.maximum
+    at = jnp.arange(w, dtype=jnp.int32)
+    upto = jnp.tri(w, dtype=bool)
+    heads = head.reshape(nb, 1, w)
+    began = jnp.max(jnp.where(heads & upto, at, -1), axis=-1)   # [nb, w]
+    seen = began >= 0
+    mine = upto & (at >= began[:, :, None])                     # [nb, w, u]
+    cells = jnp.where(mine, v.reshape(nb, 1, w), ident)
+    ext = jnp.min(cells, axis=-1) if is_min else jnp.max(cells, axis=-1)
+
+    def seg_op(a, b):
+        (fa, va), (fb, vb) = a, b
+        return fa | fb, jnp.where(fb, vb, fold(va, vb))
+    _, carried = jax.lax.associative_scan(seg_op, (seen[:, -1], ext[:, -1]))
+    owed = jnp.concatenate([jnp.full((1,), ident, v.dtype), carried[:-1]])
+    return jnp.where(seen, ext, fold(ext, owed[:, None])).reshape(length)
+
+
 def _compact_decode(key_c: jnp.ndarray, vals_c, m, nseg: int, rows: int):
     """Dense [nseg] counts and sums from the sorted PREFIX `key_c`, `vals_c`
     (the first rows of the sorted array), of which the first `m` passed the
@@ -997,8 +1050,13 @@ def _presort_compact(key_t, vals_t, nseg: int, slots: int):
     mine = slot[:, :, None] == jnp.arange(slots, dtype=jnp.float32)
     # less the overflow key, so that a slot no row took sums to it
     key_c = jnp.sum(jnp.where(mine, (key_t - over)[:, :, None], 0), axis=1)
+
+    def zero(v):    # an int32 row (a MIN's values) stays int32
+        return 0.0 if jnp.issubdtype(v.dtype, jnp.floating) \
+            else jnp.zeros((), v.dtype)
     return ((key_c + over).reshape(-1),
-            [jnp.sum(jnp.where(mine, v[:, :, None], 0.0), axis=1).reshape(-1)
+            [jnp.sum(jnp.where(mine, v[:, :, None], zero(v)),
+                     axis=1).reshape(-1)
              for v in vals_t])
 
 
@@ -1151,6 +1209,231 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
     return jax.lax.cond(fits, presorted, lambda: full(m))
 
 
+# The fewest rows that passed the sparse regime answers from at any table
+# size (n / 64 at the benchmark's): small tables in tests and rehearsals
+SPARSE_MIN_ROWS = 1 << 12
+
+
+def sparse_cap(n: int) -> int:
+    """How many sorted rows that passed `_grouped_sparse` reads at most, from
+    the row count alone: n / 64 (what the compacted sort's first step holds),
+    at least SPARSE_MIN_ROWS, a multiple of SCAN_WIDTH. Past it the launch
+    says so (`sparse.groups` -1) and the host answers."""
+    cap = max(n // 64, min(n, SPARSE_MIN_ROWS))
+    return -(-cap // SCAN_WIDTH) * SCAN_WIDTH
+
+# The sparse regime's outputs beside the per-group ones ("count", "<i>.sum",
+# "<i>.min", ...): the groups' keys, how many groups the rows that passed hold
+# (-1 where more rows passed than `sparse_cap`), and how many rows passed
+SPARSE_KEYS, SPARSE_GROUPS, SPARSE_ROWS = (
+    "sparse.keys", "sparse.groups", "sparse.rows")
+
+
+def _group_id(key, strides, j: int, n_cols: int):
+    """Group column j's dictionary id in the mixed-radix key (`strides` are
+    the runtime strides, stride 0 first): dictionaries are sorted, so id
+    order is value order."""
+    q = key // strides[j]
+    return q % (strides[j + 1] // strides[j]) if j + 1 < n_cols else q
+
+
+def _descending(v):
+    """`v` with its order reversed, exactly: -v for floats, ~v for ints
+    (no overflow at the least int32)."""
+    return -v if jnp.issubdtype(v.dtype, jnp.floating) else ~v
+
+
+def _sorted_groups(key_c, sums_c, ext_c, m, nseg: int):
+    """The groups of a sorted prefix whose first `m` rows passed: at every
+    row, whether it closes a group (`tail`) and, there, the group's row count,
+    sums (`_run_totals`: added as a tree) and MINs / MAXs (`_run_extremes`,
+    in the values' dtype)."""
+    length = key_c.size
+    pos = jnp.arange(length, dtype=jnp.int32)
+    nxt = jnp.concatenate([key_c[1:], jnp.full((1,), nseg - 1, key_c.dtype)])
+    tail = (pos < m) & ((key_c != nxt) | (pos == length - 1))
+    head = jnp.concatenate([jnp.ones((1,), bool), key_c[1:] != key_c[:-1]])
+    v = jnp.stack(sums_c) if sums_c else jnp.zeros((0, length), jnp.float32)
+    lengths, totals = _run_totals(head, v)
+    extremes = [_run_extremes(head, e, is_min) for e, is_min in ext_c]
+    return tail, [lengths] + list(totals) + extremes
+
+
+def _orderable(v, desc: bool):
+    """`v` (f32 or int32) as uint32 whose unsigned order is the ORDER BY's:
+    the larger, the earlier. A float's sign bit flips it and a negative's
+    other bits invert, an int's sign bit flips; ascending inverts all."""
+    bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+    top = jnp.uint32(0x80000000)
+    if jnp.issubdtype(v.dtype, jnp.floating):
+        u = jnp.where(bits >= top, ~bits, bits | top)
+    else:
+        u = bits ^ top
+    return u if desc else ~u
+
+
+def _kth_largest(u, active, need):
+    """The `need`-th largest of the uint32 `u` over the `active` rows, bit by
+    bit from the top (32 counts over the rows: a radix select, no sort); 0
+    where fewer than `need` rows are active."""
+    def bit(b, t):
+        cand = t | (jnp.uint32(1) << (31 - b).astype(jnp.uint32))
+        hits = jnp.sum(active & (u >= cand), dtype=jnp.int32)
+        return jnp.where(hits >= need, cand, t)
+    start = jnp.uint32(0)
+    chips = tuple(jax.typeof(u).vma)
+    if chips:   # under shard_map the threshold varies by chip, as `u` does
+        start = jax.lax.pcast(start, chips, to="varying")
+    return jax.lax.fori_loop(0, 32, bit, start)
+
+
+def _trim_groups(trim, key_c, tail, cols, strides, n_cols: int):
+    """ORDER BY ... LIMIT over the groups closed at `tail`: the k that come
+    first, by each ORDER BY key in turn and then the key (the broker's stable
+    sort of the untrimmed groups, which come in key order, breaks a tie so),
+    moved into k slots in key order; the broker orders those k. `trim` is
+    `KernelSpec.trim`'s (k, ((source, desc), ...)) with a source ("key", j),
+    a group column, or ("col", i), the i-th of `cols` (count, sums, MINs and
+    MAXs at the tails).
+
+    No sort: a sort or top-k over the prefix costs the v5e's compiler 18-34 s
+    apiece, one of four keys and eight operands 350 s (PERF.md, section 6). Key by key, a
+    radix select (`_kth_largest`) finds the value at which the k still owed
+    run out: the groups above it are taken, those equal to it go on to the
+    next key, and the last key, the group's own, is unique."""
+    k, order = trim
+    keys = [_orderable(_group_id(key_c, strides, i, n_cols) if kind == "key"
+                       else cols[i], desc) for (kind, i), desc in order]
+    keys.append(_orderable(key_c, False))
+    taken = jnp.zeros_like(tail)
+    active, need = tail, jnp.int32(k)
+    for u in keys:
+        t = _kth_largest(u, active, need)
+        above = active & (u > t)
+        taken = taken | above
+        need = need - jnp.sum(above, dtype=jnp.int32)
+        active = active & (u == t)
+    taken = taken | (active & (need > 0))
+    return _pick_rows(key_c, taken, cols, k)
+
+
+# The most slots `_pick_rows` fills by a masked reduce over [k, rows]; past
+# it a scatter. On the v5e a scatter of the 1M-row prefix into ten slots took
+# 5.1 ms an output, a third of TPC-H Q3's device time (PERF.md, section 5)
+PICK_REDUCE_MAX = 256
+
+
+def _pick_rows(key_c, taken, cols, size: int):
+    """The rows at `taken` (at most `size`), in key order, in `size` slots:
+    a row's slot is the taken rows before it, and each slot one select and
+    one reduce over the rows (a slot gets one row, so a value arrives as it
+    was), key 0 and zeros in the slots left over."""
+    if size > PICK_REDUCE_MAX:
+        return _scatter_groups(key_c, taken, cols, 0, size)
+    first = jnp.zeros_like(taken).at[0].set(True)
+    _, ranked = _run_totals(first, taken.astype(jnp.float32)[None])
+    slot = jnp.where(taken, ranked[0].astype(jnp.int32) - 1, size)
+    hit = slot[None, :] == jnp.arange(size, dtype=jnp.int32)[:, None]
+    return [jnp.sum(jnp.where(hit, v[None, :], jnp.zeros((), v.dtype)),
+                    axis=1) for v in [key_c] + list(cols)]
+
+
+def _scatter_groups(key_c, tail, cols, fill: int, size: int):
+    """The rows at `tail`, in key order, at the front of `size` slots (a
+    row's slot: the tails before it, a running count of the two levels of
+    `_run_totals`); the slots past them hold the key `fill` and zeros."""
+    first = jnp.zeros_like(tail).at[0].set(True)
+    _, ranked = _run_totals(first, tail.astype(jnp.float32)[None])
+    pos = jnp.arange(tail.size, dtype=jnp.int32)
+    idx = jnp.where(tail, ranked[0].astype(jnp.int32) - 1, size + pos)
+
+    def put(fill, v):
+        return jnp.full((size,), fill, v.dtype).at[idx].set(
+            v, unique_indices=True, mode="drop")
+    return [put(fill, key_c)] + [put(0, c) for c in cols]
+
+
+def _grouped_sparse(key: jnp.ndarray, nseg: int, sum_rows, ext_rows,
+                    took=None, trim=(), strides=None, n_cols: int = 1):
+    """The sort regime past `KernelCaps.dense_keys`: a GROUP BY answered from
+    its SORTED GROUPS, the keys that occur and their counts, sums and MINs /
+    MAXs, never a table of the key space. Its cost is in rows: TPC-H Q3's
+    GROUP BY over 16.8M order ids, of which about 117k occur among 0.5% of
+    67M rows.
+
+    The front is `_grouped_partitioned`'s: one count over the key's tiles of
+    PRESORT_TILE rows, and where at most `sparse_cap(n)` rows passed and no
+    tile holds more than the slots of a step of PRESORT_SLOTS, the rows that
+    passed move to the front of their tiles and the compacted rows are
+    sorted; else every row is. Either way the first `sparse_cap` sorted rows
+    hold the `m` that passed, and the groups are found over that prefix
+    (`_sorted_groups`): exact int32 counts (run lengths), tree-added f32
+    sums, MINs and MAXs in the values' dtype. No key passes through a float.
+    One prefix and no ladder of shorter ones (`compact_rungs`): a rung is one
+    more copy of the groups' program, and compile time is what this regime
+    is short of (`_trim_groups`).
+
+    `sum_rows` are f32 rows (each SUM's, masked), `ext_rows` (values,
+    is_min) pairs. With `trim` (`KernelSpec.trim`: the partial is the whole
+    answer) the groups end in `_trim_groups`, k entries; else
+    `_scatter_groups`, `sparse_cap` entries in key order. Returns
+    [keys, counts, sums..., extremes...] of that length and the scalars
+    (groups, rows): groups is -1 where more than `sparse_cap` rows passed
+    (the caller's host answers)."""
+    rows = key.size
+    cap = sparse_cap(rows)
+    over = nseg - 1
+    vals = list(sum_rows) + [v for v, _ in ext_rows]
+    with jax.named_scope("pinot.groupby.sparse.presort"):
+        short = (-rows) % PRESORT_TILE
+        key_t = jnp.pad(key, (0, short), constant_values=over).reshape(
+            -1, PRESORT_TILE)
+        passed = jnp.sum(key_t < over, axis=-1, dtype=jnp.int32)  # a tile
+        m = jnp.sum(passed)
+        most = jnp.max(passed)
+
+    def prefix(key_s, vals_s):
+        more = max(cap - key_s.size, 0)
+        return (jnp.pad(key_s, (0, more), constant_values=over)[:cap],
+                [jnp.pad(v, (0, more))[:cap] for v in vals_s])
+
+    def moved(slots):
+        def branch():
+            key_c, vals_c = _presort_compact(
+                key_t, [jnp.pad(v, (0, short)).reshape(key_t.shape)
+                        for v in vals], nseg, slots)
+            return prefix(*_sort_by_key(key_c, nseg, vals_c, 1)[:2])
+        return branch
+
+    def presorted():
+        with jax.named_scope("pinot.groupby.sparse.presort"):
+            step = sum((most > s).astype(jnp.int32)
+                       for s in PRESORT_SLOTS[:-1])
+            return jax.lax.switch(step, [moved(s) for s in PRESORT_SLOTS])
+
+    def full():
+        with jax.named_scope("pinot.groupby.sparse.sort"):
+            return prefix(*_sort_by_key(key, nseg, vals, 1)[:2])
+
+    fits = (m <= cap) & (most <= PRESORT_SLOTS[-1])
+    if took is not None:
+        took.append({qstats.COMPACT_FLAG: m <= cap, qstats.PRESORT_FLAG: fits})
+    key_p, vals_p = jax.lax.cond(fits, presorted, full)
+    with jax.named_scope("pinot.groupby.sparse.groups"):
+        tail, cols = _sorted_groups(
+            key_p, vals_p[:len(sum_rows)],
+            [(v, is_min) for v, (_, is_min)
+             in zip(vals_p[len(sum_rows):], ext_rows)], m, nseg)
+        groups = jnp.sum(tail, dtype=jnp.int32)
+    if trim:
+        with jax.named_scope("pinot.trim"):
+            out = _trim_groups(trim, key_p, tail, cols, strides, n_cols)
+    else:
+        with jax.named_scope("pinot.groupby.sparse.groups"):
+            out = _scatter_groups(key_p, tail, cols, over, cap)
+    return out, jnp.where(m > cap, -1, groups), m
+
+
 def combine_collective(name: str, v, axis: str):
     """The cross-device combine for one kernel output: partials agree on dense keys
     (aligned dictionaries), so one ICI collective merges them."""
@@ -1270,7 +1553,26 @@ def _make_body(spec: KernelSpec):
             # the matmul regimes in int32 across slabs of at most SLAB_ROWS
             # rows (`_slab_sums`).
             # Each regime: [int32 counts[num_seg], f32 sums[num_seg]...]
-            if num_seg <= caps.masked_cap:
+            if sparse(spec):
+                # a key space past the dense table (whatever the other caps
+                # say, as the planner reads it): the groups that occur,
+                # their MINs and MAXs with them, and where the partial is
+                # the whole answer its ORDER BY ... LIMIT
+                with scope("pinot.groupby.sparse"):
+                    names = (sum_names[1:]
+                             + [name for name, _, _ in minmax])
+                    cols = ["count"] + names
+                    trim = spec.trim and (spec.trim[0], tuple(
+                        ((kind, cols.index(src) if kind == "out" else src),
+                         desc) for (kind, src), desc in spec.trim[1]))
+                    res, groups, passed = _grouped_sparse(
+                        key, num_seg, sum_rows[1:],
+                        [(v, is_min) for _, v, is_min in minmax], took,
+                        trim, strides, len(spec.group_cols))
+                out.update(zip([SPARSE_KEYS] + cols, res))
+                out[SPARSE_GROUPS], out[SPARSE_ROWS] = groups, passed
+                res, minmax = (), []
+            elif num_seg <= caps.masked_cap:
                 # A HANDFUL of key cells: a compare, a select and a reduce a
                 # cell and row on the VPU beat any walk of the contraction
                 with scope("pinot.groupby.masked"):
@@ -1292,7 +1594,8 @@ def _make_body(spec: KernelSpec):
                 with scope("pinot.groupby.partitioned"):
                     res = _grouped_partitioned(key, num_seg, sum_rows[1:],
                                                caps.partition_block, took)
-            out.update(zip(sum_names, res))
+            if res:
+                out.update(zip(sum_names, res))
             for name, v, is_min in minmax:
                 with scope("pinot.groupby.minmax"):
                     if num_seg <= caps.minmax_bcast_cap:
@@ -1401,6 +1704,10 @@ def _record_plan(spec: KernelSpec, rows: int) -> None:
     static plan: its GROUP BY regime's counters and the widened argument."""
     if masked(spec):
         qstats.record(qstats.MASKED_GROUPBY_LAUNCHES)
+    if sparse(spec):
+        qstats.record(qstats.SPARSE_GROUPBY_LAUNCHES)
+    if trimmed(spec):
+        qstats.record(qstats.DEVICE_TRIMMED_LAUNCHES)
     if slabbed(spec, rows):
         qstats.record(qstats.SLABBED_LAUNCHES)
     if widened(spec):

@@ -47,11 +47,12 @@ import numpy as np
 
 from ..engine.datablock import lut_size, padded_rows
 from ..engine.kernels import (KernelSpec, _fence_first_call, gather_free,
-                              masked, slabbed, tree_bytes, widened)
+                              masked, slabbed, sparse, tree_bytes, trimmed,
+                              widened)
 from ..query import stats as qstats
 from ..query.aggregates import make_agg
-from ..query.context import QueryContext, compile_query
-from ..query.executor import ServerQueryExecutor
+from ..query.context import SOLE_SERVER, QueryContext, compile_query
+from ..query.executor import ServerQueryExecutor, sparse_trim_spec
 from ..query.planner import (build_device_geometry, int_ranges,
                              plan_segment)
 from ..query.predicate import CmpLeaf, LutLeaf, NullLeaf
@@ -79,6 +80,10 @@ def _has_docset_filter(ctx: QueryContext) -> bool:
     return ctx.filter is not None and walk(ctx.filter)
 
 _SHARD_KERNEL_CACHE: Dict[Tuple, object] = {}
+
+# what a sparse launch's decode returns where more rows passed than the launch
+# could hold (`kernels.sparse_cap`): the host answers that query
+_HOST_ANSWERS = object()
 
 # Dense grouped outputs at or above this key count combine with a reduce-
 # scatter (`psum_scatter`, each device keeping 1/n of the key space) instead
@@ -496,7 +501,7 @@ class MeshQueryExecutor:
         if isinstance(plan, StarSetPlan):
             outs_dev, decode = self._dispatch_star(ctx, plan)
             return decode(jax.device_get(outs_dev))
-        if plan is None or plan.kind != "device":
+        if plan is None or plan.kind != "device" or not self._fits(plan):
             return self._fallback.execute(segments, ctx)
         try:
             return self._execute_sharded(ctx, plan, segments, view)
@@ -588,7 +593,8 @@ class MeshQueryExecutor:
             return None
         views = [p.tree.view for p in plans]
         plan2 = plan_segment(plans[0].ctx2, views[0], scan_docs=total)
-        if plan2.kind != "device" or not self._alignable(plan2, views):
+        if plan2.kind != "device" or plan2.sparse \
+                or not self._alignable(plan2, views):
             return None
         return StarSetPlan(plans, views, plan2)
 
@@ -708,7 +714,8 @@ class MeshQueryExecutor:
             if isinstance(plan, StarSetPlan):
                 outs_dev, decode = self._dispatch_star(ctx, plan)
                 pending.append((qi, outs_dev, decode))
-            elif plan is None or plan.kind != "device":
+            elif plan is None or plan.kind != "device" \
+                    or not self._fits(plan):
                 pending.append((qi, self._fallback.execute(segments, ctx)))
             else:
                 try:
@@ -737,7 +744,7 @@ class MeshQueryExecutor:
         plan, view = self._plan_for_set(ctx, segments)
         if isinstance(plan, StarSetPlan):
             return self._dispatch_star(ctx, plan, partial=True)
-        if plan is None or plan.kind != "device":
+        if plan is None or plan.kind != "device" or not self._fits(plan):
             return None
         try:
             return self._dispatch_sharded(ctx, plan, segments, view,
@@ -797,10 +804,17 @@ class MeshQueryExecutor:
                 return None  # empty/pruned: the host path answers trivially
             return plan, None
         plan, view = self._plan_for_set(ctx, segments, routed)
-        if not isinstance(plan, StarSetPlan) and (plan is None
-                                                  or plan.kind != "device"):
+        if not isinstance(plan, StarSetPlan) and (
+                plan is None or plan.kind != "device" or not self._fits(plan)):
             return None
         return plan, view
+
+    def _fits(self, plan) -> bool:
+        """Whether this mesh runs the plan's kernel: a GROUP BY past the
+        dense key space (`plan.sparse`) answers from one device's sorted
+        groups; merging several chips' groups is not built, so on a mesh of
+        more than one the host answers it."""
+        return not (plan.sparse and self.n_devices > 1)
 
     def _build_partial(self, ctx: QueryContext, planned, segments, routed):
         """`prepare_partial`'s inputs: the PreparedDispatch of a plan."""
@@ -1104,11 +1118,17 @@ class MeshQueryExecutor:
         # plain segment readers); everything else may fuse
         fused_cols = () if star is not None \
             else self._mesh_fused_cols(plan, segments, view)
+        # a full result, or a partial the broker routed here alone, is the
+        # whole answer: a sparse GROUP BY cuts its ORDER BY ... LIMIT on the
+        # device
+        whole = not partial or bool(ctx.options.get(SOLE_SERVER))
+        trim = sparse_trim_spec(ctx, plan) \
+            if plan.sparse and whole and star is None else ()
         spec = KernelSpec(plan.filter_prog, plan.group_cols, plan.num_keys_pad,
                           tuple(agg_specs), distinct_lut_sizes, block.rows,
                           mv_cols=_mv_lut_cols(plan, plan.segment),
                           fused_cols=fused_cols,
-                          int_ranges=int_ranges(plan))
+                          int_ranges=int_ranges(plan), trim=trim)
 
         # -- gather runtime inputs ------------------------------------
         # ids only where dict ids are semantically needed (group keys, interval/LUT
@@ -1167,7 +1187,14 @@ class MeshQueryExecutor:
         inputs.update(route_inputs)
 
         def decode(outs):
-            return self._finish_mesh_stats(_decode_impl(outs), block, outs)
+            res = _decode_impl(outs)
+            if res is _HOST_ANSWERS:
+                # more rows passed than the sparse launch held
+                if partial:
+                    from ..cluster.device_server import DEVICE_FALLBACK
+                    return DEVICE_FALLBACK
+                return self._fallback.execute(list(segments), ctx)
+            return self._finish_mesh_stats(res, block, outs)
 
         def _decode_impl(outs):
             # replicated outputs decode exactly like the single-segment path;
@@ -1195,6 +1222,18 @@ class MeshQueryExecutor:
                                if orig_ctx.distinct else list(orig_ctx.group_by))
                 return reduce_to_result(orig_ctx, merged, orig_aggs,
                                         group_exprs)
+            if plan.sparse:
+                seg_result = self._fallback._decode_sparse_partial(
+                    plan, outs, bool(trim))
+                if seg_result is None:
+                    return _HOST_ANSWERS
+                if partial:
+                    return seg_result
+                merged = merge_segment_results([seg_result], plan.aggs)
+                return reduce_to_result(
+                    ctx, merged, plan.aggs,
+                    [e for e, _ in ctx.select_items] if ctx.distinct
+                    else list(ctx.group_by))
             if plan.group_cols:
                 if not partial:
                     # vectorized dense decode for the common agg shapes:
@@ -1244,13 +1283,14 @@ class MeshQueryExecutor:
             stack_key + (iscal_np.tobytes(), fscal_np.tobytes())
         # device-side key-axis trim: a grouped server partial only ever decodes
         # the first num_keys_real entries, so padding rows are never fetched
-        trim = (plan.num_keys_pad, plan.num_keys_real) \
-            if (partial and plan.group_cols and star is None) else (0, 0)
+        trim_keys = (plan.num_keys_pad, plan.num_keys_real) \
+            if (partial and plan.group_cols and star is None
+                and not plan.sparse) else (0, 0)
         return PreparedDispatch(
             kind="agg", spec=spec, inputs=inputs, s_pad=s_pad,
             rows=block.rows, stack_key=stack_key, dedupe_key=dedupe_key,
             stackable=stackable, decode=decode, iscal_np=iscal_np,
-            fscal_np=fscal_np, trim_keys=trim, window=window,
+            fscal_np=fscal_np, trim_keys=trim_keys, window=window,
             slot_stats=slot_stats)
 
     # ------------------------------------------------------------------
@@ -1593,6 +1633,7 @@ class MeshQueryExecutor:
 
         # from the static plan, once
         is_widened, is_masked = widened(spec), masked(spec)
+        is_sparse, is_trimmed = sparse(spec), trimmed(spec)
 
         def fn(inputs):
             compiled = jitted_for(inputs)
@@ -1609,6 +1650,10 @@ class MeshQueryExecutor:
                 qstats.record(qstats.WIDENED_AGG_LAUNCHES)
             if is_masked:
                 qstats.record(qstats.MASKED_GROUPBY_LAUNCHES)
+            if is_sparse:
+                qstats.record(qstats.SPARSE_GROUPBY_LAUNCHES)
+            if is_trimmed:
+                qstats.record(qstats.DEVICE_TRIMMED_LAUNCHES)
             return compiled(inputs)
 
         fn.jitted_for = jitted_for

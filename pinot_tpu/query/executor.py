@@ -235,6 +235,10 @@ class ServerQueryExecutor:
         else:
             outs = kernels.run_kernel(spec, inputs)
 
+        if plan.sparse:
+            # one segment's groups are a partial: never trimmed here
+            r = self._decode_sparse_partial(plan, outs, trimmed=False)
+            return r if r is not None else self._host_aggregate(plan)
         if plan.group_cols:
             return self._decode_group_partials(plan, outs)
         return self._decode_scalar_partials(plan, outs)
@@ -466,6 +470,34 @@ class ServerQueryExecutor:
                           aggs=plan.aggs)
         return SegmentResult("groups", dense=dp,
                              num_docs_scanned=int(counts.sum()))
+
+    def _decode_sparse_partial(self, plan: SegmentPlan, outs,
+                               trimmed: bool) -> Optional[SegmentResult]:
+        """The sorted groups of a GROUP BY past the dense key space
+        (`kernels._grouped_sparse`) as a `SparsePartial`: each group's key
+        decoded to its values through the plan's dictionaries (a merged id
+        space's global ones on that path), counts and outputs as arrays, in
+        key order or, `trimmed`, in the ORDER BY's. None where more rows
+        passed than the launch held: the host answers."""
+        from ..engine.kernels import SPARSE_GROUPS, SPARSE_KEYS, SPARSE_ROWS
+        from .reduce import SparsePartial
+        groups = int(outs[SPARSE_GROUPS])
+        if groups < 0:
+            return None
+        keys = np.asarray(outs[SPARSE_KEYS])
+        n = min(groups, len(keys))
+        keys = keys[:n].astype(np.int64)
+        seg = plan.segment
+        values = [seg.column(col).dictionary.take(
+            (keys // plan.strides[j]) % max(plan.cards[j], 1))
+            for j, col in enumerate(plan.group_cols)]
+        arrays = {f"{i}.{o}": np.asarray(outs[f"{i}.{o}"][:n])
+                  for i, agg in enumerate(plan.aggs)
+                  for o in agg.device_outputs if o != "count"}
+        sp = SparsePartial(values, np.asarray(outs["count"][:n]).astype(
+            np.int64), arrays, groups=groups, trimmed=trimmed, aggs=plan.aggs)
+        return SegmentResult("groups", sparse=sp,
+                             num_docs_scanned=int(outs[SPARSE_ROWS]))
 
     def _decode_scalar_partials(self, plan: SegmentPlan, outs) -> SegmentResult:
         seg = plan.segment
@@ -888,6 +920,41 @@ def group_trim_spec(ctx: QueryContext, plan: SegmentPlan):
             if outs in (("count",), ("sum",), ("min",), ("max",), ("sum", "count")):
                 return (i, o.desc, k)
     return None
+
+
+def sparse_trim_spec(ctx: QueryContext, plan: SegmentPlan) -> Tuple:
+    """`KernelSpec.trim` of a sparse GROUP BY whose partial is the whole
+    answer: (k, ((source, desc), ...)) with k = OFFSET + LIMIT, a source
+    ("key", j) for group column j or ("out", name) for the output that
+    orders an aggregate as the broker does (COUNT, SUM, MIN, MAX); () where
+    the cut cannot be made on the device and the partial stays untrimmed and
+    exact: HAVING (it could keep a group the cut dropped), DISTINCT, gapfill,
+    NULLS FIRST/LAST, an ORDER BY item that is neither, or k out of (0,
+    MAX_DEVICE_TOPK]. Several keys, aggregates and group columns are fine."""
+    if (ctx.having is not None or ctx.distinct or ctx.gapfill is not None
+            or not ctx.order_by or len(ctx.group_by) != len(plan.group_cols)):
+        return ()
+    k = ctx.offset + ctx.limit
+    if k <= 0 or k > ServerQueryExecutor.MAX_DEVICE_TOPK:
+        return ()
+    groups = {repr(g): j for j, g in enumerate(ctx.group_by)}
+    calls = {repr(c): i for i, c in enumerate(ctx.aggregations)}
+    order = []
+    for o in ctx.order_by:
+        r = repr(o.expr)
+        if o.nulls_last is not None:
+            return ()
+        if r in groups:
+            order.append((("key", groups[r]), o.desc))
+            continue
+        outs = plan.aggs[calls[r]].device_outputs if r in calls else ()
+        if outs == ("count",):
+            order.append((("out", "count"), o.desc))
+        elif outs in (("sum",), ("min",), ("max",)):
+            order.append((("out", f"{calls[r]}.{outs[0]}"), o.desc))
+        else:
+            return ()
+    return (k, tuple(order))
 
 
 def _trim_occupied(plan: SegmentPlan, outs, occupied: np.ndarray) -> np.ndarray:

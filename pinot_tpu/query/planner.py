@@ -24,12 +24,14 @@ from ..segment.reader import ImmutableSegment
 from ..sql.ast import Expr, Function, Identifier, Literal, identifiers_in
 from .aggregates import AggContext, AggFunc, make_agg
 from .context import QueryContext, QueryValidationError
+from .dense_reduce import _dense_capable
 from .predicate import CmpLeaf, FilterProgram, LutLeaf, NullLeaf, compile_filter
 
-# dense-key cap (reference caps group-by at 100k groups). Raised to 2M now
-# that the sort-based kernel regimes (engine/kernels.py) keep per-key cost
-# sublinear past the chunked-matmul cap instead of falling off a scatter cliff.
-MAX_DEVICE_GROUP_KEYS = 1 << 21
+# The device's GROUP BY key is one int32 (`sum(ids * strides)`, the overflow
+# key `num_keys_pad` for masked rows): a key space past it plans on the host.
+# Up to `KernelCaps.dense_keys` the answer is a dense table of every key, past
+# it the sort regime's sorted groups (`SegmentPlan.sparse`).
+MAX_DEVICE_KEY_SPACE = (1 << 31) - 2
 # grouped distinct presence matrix cap: (padded keys) x (dict-id lut) int32 cells
 MAX_GROUPED_DISTINCT_CELLS = 1 << 22  # 16MB of presence counts per aggregation
 
@@ -78,6 +80,9 @@ class SegmentPlan:
     strides: Tuple[int, ...] = ()
     num_keys_real: int = 0
     num_keys_pad: int = 0
+    # the key space is past `KernelCaps.dense_keys`: the kernel answers with
+    # the groups that occur (`kernels._grouped_sparse`), not a dense table
+    sparse: bool = False
     # upper bound on OCCUPIED groups (dictionary key-space product capped by
     # scanned docs): drives merge/decode strategy — array-form dense partials
     # vs per-group state dicts — without waiting for exact device counts
@@ -355,8 +360,10 @@ def _device_feasible(plan: SegmentPlan, segment: ImmutableSegment) -> str:
     num_keys = 1
     for c in cards:
         num_keys *= max(c, 1)
-    if num_keys > MAX_DEVICE_GROUP_KEYS:
-        return f"group key space {num_keys} exceeds device cap"
+    if cols and pad_keys(num_keys) > MAX_DEVICE_KEY_SPACE:
+        return f"group key space {num_keys} is past the device's int32 key"
+    from ..engine.caps import get_caps
+    plan.sparse = bool(cols) and pad_keys(num_keys) > get_caps().dense_keys
     plan.group_cols = tuple(cols)
     plan.card_hint = num_keys if cols else 0  # clamped by scan docs in plan_segment
 
@@ -371,6 +378,11 @@ def _device_feasible(plan: SegmentPlan, segment: ImmutableSegment) -> str:
              and not getattr(segment.column(arg.name), "is_multi_value", False))
         if not agg.device_ok(AggContext(group_by, arg_is_dict, arg_numeric)):
             return f"aggregation {agg.name} not device-supported here"
+        if plan.sparse and ("distinct" in agg.device_outputs
+                            or not _dense_capable(agg)):
+            return (f"aggregation {agg.name} over a key space of {num_keys} "
+                    f"(past the dense table: sorted groups carry sums, "
+                    f"counts, MINs and MAXs)")
         err = _power_sum_f32_safe(agg, segment)
         if err:
             return err
@@ -488,6 +500,14 @@ def int_ranges(plan: SegmentPlan, extra=()) -> Dict[str, Optional[Tuple[int, int
     return out
 
 
+def pad_keys(s: int) -> int:
+    """The padded key count of a key space of `s` keys: a power of two to
+    4096, then a multiple of 4096 (`build_device_geometry`)."""
+    if s <= 4096:
+        return 1 << max(0, (s - 1)).bit_length()
+    return -(-s // 4096) * 4096
+
+
 def build_device_geometry(plan: SegmentPlan) -> None:
     """Fill dense-key geometry: strides over real cardinalities, padded key count.
 
@@ -505,7 +525,4 @@ def build_device_geometry(plan: SegmentPlan) -> None:
     plan.cards = tuple(cards)
     plan.strides = tuple(strides)
     plan.num_keys_real = s
-    if s <= 4096:
-        plan.num_keys_pad = 1 << max(0, (s - 1)).bit_length()
-    else:
-        plan.num_keys_pad = -(-s // 4096) * 4096
+    plan.num_keys_pad = pad_keys(s)

@@ -59,6 +59,72 @@ class DensePartial:
 
 
 @dataclass
+class SparsePartial:
+    """A group-by partial in ARRAY form over the groups that OCCUR.
+
+    Past `KernelCaps.dense_keys` a dense table of the key space would be one
+    entry a key (67 MB a value row at TPC-H Q3's 16.8M order ids, of which
+    about 117k occur), so the device answers with its sorted groups
+    (`kernels._grouped_sparse`) and they stay arrays end to end: the group
+    key VALUES (dictionaries differ across servers: values, not ids), the
+    counts and every output a group, in key order. Partials merge by value,
+    vectorized (`merge_sparse`), and the broker finalizes them as it does a
+    `DensePartial`. A `trimmed` partial is already cut to the ORDER BY ...
+    LIMIT on the device: it is the whole answer, and merges with nothing.
+    """
+
+    group_values: List[np.ndarray]  # per group column, each group's value
+    counts: np.ndarray              # int64[groups] rows that passed a group
+    outs: Dict[str, np.ndarray]     # "<agg idx>.<out>" arrays, a group each
+    groups: int                     # groups the rows that passed held in all
+    trimmed: bool = False
+    # build-side only (never on the wire), as DensePartial's
+    aggs: Optional[List[AggFunc]] = None
+
+
+def merge_sparse(parts: List[SparsePartial]) -> SparsePartial:
+    """Sparse partials as one, by group key value: counts and sums added, the
+    least of the MINs and the greatest of the MAXs, in one vectorized pass.
+    The groups come out in the order a device's key gives them (the last
+    group column most significant, each column's values ascending), so a tie
+    of the ORDER BY falls as it does in one server's trimmed answer."""
+    if len(parts) == 1:
+        return parts[0]
+    if any(p.trimmed for p in parts):
+        raise ValueError("a trimmed sparse partial is a whole answer: it "
+                         "merges with no other partial")
+    n_cols = len(parts[0].group_values)
+    code = np.zeros(sum(len(p.counts) for p in parts), dtype=np.int64)
+    for j in reversed(range(n_cols)):
+        col = np.concatenate([np.asarray(p.group_values[j]) for p in parts])
+        _, inv = np.unique(col, return_inverse=True)
+        # compress after every column, so the code stays below the rows
+        _, code = np.unique(code * (int(inv.max(initial=0)) + 1)
+                            + inv.reshape(-1), return_inverse=True)
+        code = code.reshape(-1)
+    uniq, first = np.unique(code, return_index=True)
+    size = len(uniq)
+    values = [np.concatenate([np.asarray(p.group_values[j])
+                              for p in parts])[first] for j in range(n_cols)]
+    counts = np.zeros(size, dtype=np.int64)
+    np.add.at(counts, code, np.concatenate([p.counts for p in parts]))
+    outs: Dict[str, np.ndarray] = {}
+    for name in parts[0].outs:
+        v = np.concatenate([p.outs[name] for p in parts])
+        if name.endswith((".min", ".max")):
+            lo = name.endswith(".min")
+            start = (v.max() if lo else v.min()) if v.size else 0
+            acc = np.full(size, start, dtype=v.dtype)
+            (np.minimum if lo else np.maximum).at(acc, code, v)
+        else:
+            acc = np.zeros(size, dtype=np.float64)
+            np.add.at(acc, code, v.astype(np.float64))
+        outs[name] = acc
+    return SparsePartial(values, counts, outs, groups=size,
+                         aggs=parts[0].aggs)
+
+
+@dataclass
 class SegmentResult:
     """Partial result of one segment (reference: IntermediateResultsBlock)."""
 
@@ -75,6 +141,9 @@ class SegmentResult:
     # high-cardinality array-form partial; when set, `groups` is EMPTY until
     # `materialize_dense` converts (consumers that need the dict form call it)
     dense: Optional[DensePartial] = None
+    # the array form past the dense key space (SparsePartial); `groups` is
+    # EMPTY beside it, as beside `dense`
+    sparse: Optional[SparsePartial] = None
     # per-query ExecutionStats counters accumulated producing this partial
     # (flat summable dict — see query/stats.py); rides the wire and merges
     # into the broker's record
@@ -83,6 +152,8 @@ class SegmentResult:
     def materialize_dense(self, aggs: Optional[List[AggFunc]] = None) -> None:
         """Convert the array-form partial into the classic state dict (for
         dict-merge with non-dense partials, hash-partition shuffles, ...)."""
+        if self.sparse is not None:
+            self._materialize_sparse(aggs)
         dp = self.dense
         if dp is None:
             return
@@ -107,6 +178,25 @@ class SegmentResult:
             self.groups[keys[row]] = states
         self.dense = None
 
+    def _materialize_sparse(self, aggs: Optional[List[AggFunc]]) -> None:
+        sp = self.sparse
+        use_aggs = aggs if aggs is not None else sp.aggs
+        if use_aggs is None:
+            raise ValueError("sparse partial needs aggs to materialize")
+        if sp.trimmed:
+            raise ValueError("a trimmed sparse partial is a whole answer")
+        keys = list(zip(*[np.asarray(v).tolist() for v in sp.group_values]))
+        for row, key in enumerate(keys):
+            states = []
+            for i, agg in enumerate(use_aggs):
+                o = {"count": int(sp.counts[row])}
+                for out_name in agg.device_outputs:
+                    if out_name != "count":
+                        o[out_name] = sp.outs[f"{i}.{out_name}"][row]
+                states.append(agg.state_from_device(o))
+            self.groups[key] = states
+        self.sparse = None
+
 
 def merge_segment_results(results: List[SegmentResult], aggs: List[AggFunc]) -> SegmentResult:
     """Server-level combine (also reused broker-side across servers)."""
@@ -129,6 +219,13 @@ def merge_segment_results(results: List[SegmentResult], aggs: List[AggFunc]) -> 
                 merged_stats[k] = merged_stats.get(k, 0) + v
     out.stats = merged_stats or None  # set BEFORE the dense early return
     if kind == "groups":
+        held = [r for r in results if r.groups or r.dense is not None
+                or r.sparse is not None]
+        if held and all(r.sparse is not None for r in held):
+            # groups past the dense key space: merged by value, as arrays
+            # (a pruned segment's empty partial adds nothing)
+            out.sparse = merge_sparse([r.sparse for r in held])
+            return out
         denses = [r.dense for r in results]
         if all(d is not None for d in denses) and \
                 len({d.token for d in denses}) == 1:
@@ -194,6 +291,7 @@ def reduce_to_result(ctx: QueryContext, merged: SegmentResult, aggs: List[AggFun
 
     # -- build the result-expression environment ---------------------------
     env: Dict[str, np.ndarray] = {}
+    n_groups = None
     if merged.kind == "groups" and merged.dense is not None:
         # array-form partial: finalize VECTORIZED over occupied dense keys
         # (dense_values per agg + dictionary takes per group column) instead
@@ -201,26 +299,18 @@ def reduce_to_result(ctx: QueryContext, merged: SegmentResult, aggs: List[AggFun
         dp = merged.dense
         occupied = np.nonzero(dp.counts > 0)[0]
         n = len(occupied)
-        counts_occ = dp.counts[occupied]
-        for j, g in enumerate(group_exprs):
-            ids_j = (occupied // dp.strides[j]) % max(dp.cards[j], 1)
-            env[repr(g)] = _object_array(
-                np.asarray(dp.group_values[j])[ids_j].tolist())
-        for i, call in enumerate(ctx.aggregations):
-            agg = aggs[i]
-
-            def get(name, i=i):
-                if name == "count":
-                    return counts_occ
-                return dp.outs[f"{i}.{name}"][occupied]
-
-            vals = np.asarray(agg.dense_values(get, counts_occ))
-            cells = _object_array(vals.tolist())
-            if agg.dense_nan_is_null and vals.dtype.kind == "f":
-                # scalar finalize returns None where the dense form emits NaN
-                for bad in np.nonzero(vals != vals)[0]:
-                    cells[bad] = None
-            env[repr(call)] = cells
+        key_values = [np.asarray(dp.group_values[j])[
+            (occupied // dp.strides[j]) % max(dp.cards[j], 1)]
+            for j in range(len(group_exprs))]
+        _array_env(env, ctx, aggs, group_exprs, key_values,
+                   dp.counts[occupied], lambda k: dp.outs[k][occupied])
+    elif merged.kind == "groups" and merged.sparse is not None:
+        # the groups that occur, already arrays (SparsePartial): the same
+        # vectorized finalize
+        sp = merged.sparse
+        n, n_groups = len(sp.counts), sp.groups
+        _array_env(env, ctx, aggs, group_exprs, sp.group_values, sp.counts,
+                   lambda k: sp.outs[k])
     elif merged.kind == "groups":
         keys = list(merged.groups.keys())
         n = len(keys)
@@ -273,9 +363,36 @@ def reduce_to_result(ctx: QueryContext, merged: SegmentResult, aggs: List[AggFun
 
     idx = idx[ctx.offset:ctx.offset + ctx.limit]
     rows = [[col[i] for col in out_cols] for i in idx]
+    if merged.kind == "groups" and n_groups is None:
+        n_groups = n
     return ResultTable([name for _, name in ctx.select_items], _pyify(rows),
                        {"numDocsScanned": merged.num_docs_scanned,
-                        "numGroupsTotal": n if merged.kind == "groups" else None})
+                        "numGroupsTotal": n_groups})
+
+
+def _array_env(env: Dict[str, np.ndarray], ctx: QueryContext,
+               aggs: List[AggFunc], group_exprs: List[Expr], key_values,
+               counts: np.ndarray, out) -> None:
+    """The reduce's environment from array-form groups: each group column's
+    values, and each aggregation finalized over every group at once
+    (`dense_values`; `out(name)` is an output array a group)."""
+    for j, g in enumerate(group_exprs):
+        env[repr(g)] = _object_array(np.asarray(key_values[j]).tolist())
+    for i, call in enumerate(ctx.aggregations):
+        agg = aggs[i]
+
+        def get(name, i=i):
+            if name == "count":
+                return counts
+            return out(f"{i}.{name}")
+
+        vals = np.asarray(agg.dense_values(get, counts))
+        cells = _object_array(vals.tolist())
+        if agg.dense_nan_is_null and vals.dtype.kind == "f":
+            # scalar finalize returns None where the dense form emits NaN
+            for bad in np.nonzero(vals != vals)[0]:
+                cells[bad] = None
+        env[repr(call)] = cells
 
 
 def _apply_gapfill(ctx: QueryContext, group_exprs: List[Expr],

@@ -102,6 +102,14 @@ SLABBED_LAUNCHES = "slabbedLaunches"
 # compare, a select and a reduce a key cell and value row, with no matmul and
 # no slabs. From the static plan (`kernels.masked`)
 MASKED_GROUPBY_LAUNCHES = "maskedGroupByLaunches"
+# launches whose GROUP BY answered from its SORTED GROUPS: a key space
+# past `KernelCaps.dense_keys`, so the sort regime returns the keys that occur
+# with their counts, sums, MINs and MAXs, never a table of every key; and of
+# them, those that also cut the ORDER BY ... LIMIT on the device, where the
+# broker routed the query to this one server (the partial is the whole
+# answer). From the static plan (`kernels.sparse`, `kernels.trimmed`)
+SPARSE_GROUPBY_LAUNCHES = "sparseGroupByLaunches"
+DEVICE_TRIMMED_LAUNCHES = "deviceTrimmedLaunches"
 # launches whose aggregate argument was evaluated WIDENED (PR 36): a `+`, `-`
 # or `*` of INT columns in it can leave int32 by the literals and the columns'
 # min/max the plan holds, so the device computes it in float32 (the host in
@@ -195,6 +203,7 @@ COUNTER_KEYS = (
     DEDUPED_LAUNCHES, STACKED_LAUNCHES,
     FUSED_LAUNCHES, STAGED_LAUNCHES, GATHER_FREE_LAUNCHES, SLABBED_LAUNCHES,
     WIDENED_AGG_LAUNCHES, MASKED_GROUPBY_LAUNCHES,
+    SPARSE_GROUPBY_LAUNCHES, DEVICE_TRIMMED_LAUNCHES,
     COMPACT_DECODE_LAUNCHES, DENSE_DECODE_LAUNCHES,
     PRESORT_COMPACT_LAUNCHES, FULL_SORT_LAUNCHES,
     NUM_CONSUMING_SEGMENTS_QUERIED, MUX_FRAME_QUEUE_MS, MUX_FLOW_CONTROL_MS,

@@ -1139,6 +1139,16 @@ LADDER = [
                  ("onehot", "partitioned"), id="chunk64-at-2^26-rows"),
     pytest.param(_SUMS, 131_072, 1 << 26, None, "pinot.groupby.partitioned",
                  ("onehot", "chunk64"), id="keys-past-chunk_cap-at-2^26-rows"),
+    # ... against dense_keys = 2^21: at it the dense table of the
+    # sort regime, past it the sorted groups alone, the MINs with them
+    pytest.param(_SUMS, 1 << 21, 16_384, None, "pinot.groupby.partitioned",
+                 ("sparse", "chunk64"), id="keys-at-dense_keys"),
+    pytest.param(_SUMS, (1 << 21) + 4096, 16_384, None,
+                 "pinot.groupby.sparse", ("partitioned", "chunk64"),
+                 id="keys-past-dense_keys"),
+    pytest.param(_MINMAX, (1 << 21) + 4096, 16_384, None,
+                 "pinot.groupby.sparse.groups", ("pinot.groupby.minmax",),
+                 id="minmax-past-dense_keys"),
     # min/max: the broadcast-reduce up to minmax_bcast_cap = 1,024 keys + 1
     pytest.param(_MINMAX, 1023, 16_384, None,
                  "pinot.groupby.minmax/reduce_min", ("minmax/scatter-min",),

@@ -591,3 +591,47 @@ def test_prepared_ahead_share_is_declared_like_the_old():
     for key in ("unit", "source", "layer", "moves"):
         assert entry[key] == like[key], key
     assert meta["name"] == AHEAD_METRIC and meta["what"]
+
+
+# A GROUP BY past the dense key space runs under `pinot.groupby.sparse`,
+# and the ORDER BY ... LIMIT cut on the device under `pinot.trim` inside it
+@pytest.mark.parametrize("tf_op", [
+    "jit(pinot_groupby)/jit(shmap_body)/pinot.groupby.key/add:",
+    "jit(pinot_groupby)/jit(shmap_body)/pinot.groupby.sparse/"
+    "pinot.groupby.sparse.presort/reduce_sum:",
+    "jit(pinot_groupby)/jit(shmap_body)/pinot.groupby.sparse/cond/"
+    "branch_1_fun/pinot.groupby.sparse.sort/sort:",
+    "jit(pinot_groupby)/jit(shmap_body)/pinot.groupby.sparse/"
+    "pinot.groupby.sparse.groups/reduce_min:",
+    "jit(pinot_groupby)/jit(shmap_body)/pinot.groupby.sparse/pinot.trim/"
+    "while/body/reduce_sum:",
+])
+def test_sparse_and_trim_scopes_count_as_groupby(tf_op):
+    """The outermost scope of each of the regime's operations is a
+    `pinot.groupby.*` one, so `kernels.groupby_share` (their union over the
+    device's busy time) counts the sorted groups and the cut with no edit."""
+    scope = program_trace.scope_of(tf_op)
+    assert scope.startswith("pinot.groupby.")
+    read = cells.load_reader("kernels.groupby_share")
+    program = _program([(0.0, 40.0)], {},
+                       ops=[(0.0, 30.0, scope), (30.0, 40.0, "pinot.agg")])
+    assert read(_ctx([], {}, program)) == pytest.approx(75.0)
+
+
+@pytest.mark.parametrize("counters,want", [
+    ({"launches": 600, "sparseGroupByLaunches": 600,
+      "deviceTrimmedLaunches": 600}, 100.0),
+    ({"launches": 8, "sparseGroupByLaunches": 2}, 25.0),
+    ({"launches": 8, "sparseGroupByLaunches": 0}, 0.0),
+    ({"launches": 0, "sparseGroupByLaunches": 0}, None),
+    # a program without the regime counts no such launch
+    ({"launches": 600, "maskedGroupByLaunches": 0}, None),
+])
+def test_sparse_groupby_share_reads_the_counter_delta(counters, want,
+                                                      mesh_recorded, recorded):
+    read = cells.load_reader("kernels.sparse_groupby_share")
+    got = read(_ctx([], counters, None))
+    assert got == want if want is None else got == pytest.approx(want)
+    for parent in (mesh_recorded["counters"], recorded["counters"]):
+        assert "sparseGroupByLaunches" not in parent
+        assert read(_ctx([], parent, None)) is None
