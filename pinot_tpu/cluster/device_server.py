@@ -86,7 +86,7 @@ _SHAPE_KEYS = (qstats.MESH_LAUNCHES, qstats.SCATTER_LAUNCHES,
 #: put into them, folded into the query that staged and summed in `stats()`
 _STAGE_KEYS = (qstats.SET_BLOCKS_STAGED, qstats.SET_BLOCK_BYTES)
 
-#: which decode branch and which sort a sort-regime GROUP BY launch ran: known
+#: which decode branch and which sort a sort-regime GROUP BY launch ran, and which rows a matmul rung contracted: known
 #: once its outputs are fetched (`qstats.decode_branch`), summed over the launches in `stats()`; the decode hook puts the same key on each answer's partial
 _DECODE_KEYS = tuple(k for keys in qstats.DECODE_FLAGS.values() for k in keys)
 

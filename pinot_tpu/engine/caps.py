@@ -38,9 +38,13 @@ class KernelCaps:
     # Q1 at 67M rows: 1.6e-7 against the one-hot regime's 9.3e-6).
     masked_cap: int = 65
     # SKINNY one-hot matmul ([1+sums, N] @ [N, keys]) from there to here: each
-    # 128-wide output column tile re-walks the full contraction, so cost grows
+    # 128-wide output column tile re-walks the contraction, so cost grows
     # linearly in keys and the chunked 64x64 formulation overtakes it at some
-    # key count. Where is not yet measured on the directly attached chip
+    # key count. N is the rows that passed, compacted tile by tile, where the
+    # filter leaves few in most tiles (n / 64 or n / 16 rows and n / 256:
+    # `kernels._compacted_sums`, in front of both matmul rungs), else every
+    # row; so the crossover weighs a few ms either way on a selective query.
+    # Where it lies is not yet measured on the directly attached chip
     # (ROADMAP S8).
     matmul_cap: int = 512
     # CHUNKED 64x64 one-hot matmul (`_grouped_chunk64`) up to here: measured

@@ -31,9 +31,11 @@ matmuls —
   scatter), and past `dense_keys` the same sort answering from its sorted
   groups alone (`_grouped_sparse`: no table of the key space, the ORDER BY
   ... LIMIT cut on the device where the partial is whole). The row count
-  chooses nothing: past SLAB_ROWS (2^24) rows a
-  device the two matmul regimes run slab by slab (`_slab_sums`), int32
-  counts added across the slabs; min/max by per-key broadcast-reduce up to
+  chooses nothing: the two matmul regimes contract over the rows that
+  passed, compacted tile by tile where few tiles hold many of them
+  (`_compacted_sums`), else over every row, past SLAB_ROWS (2^24) rows a
+  device slab by slab (`_slab_sums`), int32 counts added across the
+  slabs; min/max by per-key broadcast-reduce up to
   `minmax_bcast_cap`, `segment_min` / `segment_max` above.
 
 There is no 10k-doc batching loop (`DocIdSetPlanNode.MAX_DOC_PER_CALL`): the TPU analog of
@@ -441,11 +443,14 @@ def trimmed(spec: KernelSpec) -> bool:
 
 def slabbed(spec: KernelSpec, rows: int) -> bool:
     """Whether a launch of `spec` over `rows` rows a device (a static shape of
-    its inputs) runs a matmul regime of its GROUP BY over more than one slab
-    (what `slabbedLaunches` counts): past SLAB_ROWS rows, more than
-    `masked_cap` keys (the masked reduce counts in int32 and builds no slab)
-    and at most `chunk_cap`. A grouped distinct's product space is the wider
-    of the two, so past `masked_cap` it is on the matmul side only where the
+    its inputs) runs a program that HOLDS a matmul regime of its GROUP BY over
+    more than one slab (what `slabbedLaunches` counts): past SLAB_ROWS rows,
+    more than `masked_cap` keys (the masked reduce counts in int32 and builds
+    no slab) and at most `chunk_cap`. Where the group-by's own sums are
+    compacted in front of the rung (`_compacted_sums`) the loop is the
+    fall-back's alone: the launch may not have run it (`fullMatmulLaunches`
+    says it did). A grouped distinct's product space is the wider of the
+    two, so past `masked_cap` it is on the matmul side only where the
     group-by itself is; under it the presence product alone decides."""
     if not spec.group_cols or slab_count(rows) == 1:
         return False
@@ -751,18 +756,29 @@ def _grouped_chunk64(key: jnp.ndarray, nseg: int, exact_rows, split_rows,
 # key, variable or argument feeds it; tests patch it as they patch
 # COMPACT_RUNGS.
 SLAB_ROWS = 1 << 24
+# Rows a slab of the COMPACTED rows' contraction (`_compacted_sums`). A
+# contraction adds its terms into one f32 accumulator a cell, so a sum's
+# error grows with the terms of its group that one accumulator takes: the
+# rows that passed, compacted, put a group's rows into one contraction where
+# every row put them into four slabs of 2^24. On the v5e at 2^26 rows, 31,944
+# rows a group (PERF.md section 6): one contraction 14.4 ms and 2.8e-6 of
+# the exact sums, slabs of 2^20 15.3 ms and 2.3e-7, of 2^18 14.4 ms and
+# 2.0e-7, every row in four slabs 57.7 ms and 3.8e-7. Tests patch it.
+COMPACT_SLAB_ROWS = 1 << 18
 
 
-def slab_count(rows: int) -> int:
-    """Slabs the matmul GROUP BY regimes run over `rows` rows a device."""
-    return max(1, -(-rows // SLAB_ROWS))
+def slab_count(rows: int, slab: int = 0) -> int:
+    """Slabs the matmul GROUP BY regimes run over `rows` rows a device, in
+    slabs of at most `slab` rows (SLAB_ROWS where 0)."""
+    return max(1, -(-rows // (slab or SLAB_ROWS)))
 
 
-def _slab_sums(key: jnp.ndarray, nseg: int, rows, regime):
+def _slab_sums(key: jnp.ndarray, nseg: int, rows, regime, slab: int = 0):
     """[int32 counts[nseg], f32 sums[nseg]...] of a matmul GROUP BY regime at
     ANY row count. `regime(key, rows)` is `_onehot_sums` or `_grouped_chunk64`
     bound to its key count: f32[nseg] per row of `rows`, whose first is the
-    0/1 mask (the count row).
+    0/1 mask (the count row). `slab`: rows a slab at most, SLAB_ROWS where 0
+    (`COMPACT_SLAB_ROWS` for the compacted rows).
 
     An f32 cell stops counting at 2^24 increments, which is a property of one
     accumulator cell and not of the algorithm: past SLAB_ROWS rows the rows
@@ -780,7 +796,7 @@ def _slab_sums(key: jnp.ndarray, nseg: int, rows, regime):
     slices (compiled for the described chip, PR 31). One slab builds no
     loop."""
     as_int = lambda c: jnp.round(c).astype(jnp.int32)    # noqa: E731
-    k = slab_count(key.size)
+    k = slab_count(key.size, slab)
     if k == 1:
         count, *sums = regime(key, rows)
         return [as_int(count)] + sums
@@ -1008,7 +1024,11 @@ def compact_rungs(cap: int):
 
 # Rows a tile of the presort compaction, and the slots a tile keeps for its
 # rows that passed: the first count that holds every tile's, so the compacted
-# rows are n / 64 (`compact_cap`) or n / 16. Q3.2, the densest SSB template,
+# rows are n / 64 (`compact_cap`) or n / 16. Two kinds of rung compact so:
+# the sort regimes (`_grouped_partitioned`, `_grouped_sparse`), which need
+# every tile within its slots to keep the rows in order, and the matmul
+# rungs (`_compacted_sums`), which take the few tiles past them whole
+# (PRESORT_SPILL). Q3.2, the densest SSB template,
 # passes 1.64 rows a tile on average, and the chance that any of 65,536 tiles
 # of independent draws holds more than 16 is about 2e-7; rows that are not
 # independent draws need the second step (the benchmark's generator opens
@@ -1020,6 +1040,32 @@ def compact_rungs(cap: int):
 # they patch SLAB_ROWS.
 PRESORT_TILE = 1024
 PRESORT_SLOTS = (16, 64)
+# One tile in this many may hold more rows that passed than a step's slots
+# and still take that step in front of a matmul rung: such a tile is taken
+# whole, beside the slots. 256 tiles (n / 256 rows) at 2^26 rows a device, 64
+# at 2^24; the benchmark's walk rows overfill 3 tiles a segment (48 and 12).
+# From the tile count alone, as PRESORT_SLOTS; tests patch it.
+PRESORT_SPILL = 256
+
+
+def compacted_rows(rows: int) -> int:
+    """How many rows a matmul rung contracts over at most where it takes a
+    step of the compaction (`_compacted_sums`) over `rows` rows a device:
+    every tile's slots at the last step and the tiles taken whole. 0 where
+    that is not fewer than the rows: no stage is built."""
+    tiles = -(-rows // PRESORT_TILE)
+    length = tiles * PRESORT_SLOTS[-1] + tiles // PRESORT_SPILL * PRESORT_TILE
+    return length if length < rows else 0
+
+
+def _tile_counts(key: jnp.ndarray, nseg: int):
+    """The count in front of every compaction: the key as [T, PRESORT_TILE]
+    tiles (the tail padded with the overflow key nseg-1), that padding's
+    length, and the rows that passed (key < nseg-1) in each tile."""
+    short = (-key.size) % PRESORT_TILE
+    key_t = jnp.pad(key, (0, short), constant_values=nseg - 1).reshape(
+        -1, PRESORT_TILE)
+    return key_t, short, jnp.sum(key_t < nseg - 1, axis=-1, dtype=jnp.int32)
 
 
 def _presort_compact(key_t, vals_t, nseg: int, slots: int):
@@ -1058,6 +1104,101 @@ def _presort_compact(key_t, vals_t, nseg: int, slots: int):
             [jnp.sum(jnp.where(mine, v[:, :, None], zero(v)),
                      axis=1).reshape(-1)
              for v in vals_t])
+
+
+def _spill_index(flagged: jnp.ndarray, spill: int):
+    """The first `spill` tiles set in `flagged` [T], in tile order: their
+    indices (int32 [spill]) and which of the `spill` places one took.
+
+    No sort, `top_k`, flat scan or `nonzero` over the tiles (each costs the
+    v5e's compiler its time: `_run_totals`, `_trim_groups`). A tile's place is
+    the flagged tiles before it: within its group of PRESORT_TILE tiles the
+    strict triangle on the MXU counts them, as `_presort_compact` counts
+    slots, and the few groups before add theirs by a masked reduce. One
+    scatter sets every index in its place; the unflagged are sent out of
+    bounds, each to its own index, and dropped."""
+    w = PRESORT_TILE
+    groups = -(-flagged.size // w)
+    f = jnp.pad(flagged, (0, groups * w - flagged.size)).reshape(groups, w)
+    before = jnp.tri(w, w, -1, dtype=jnp.bfloat16).T      # [u, b]: u < b
+    rank = jax.lax.dot(f.astype(jnp.bfloat16), before,
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+    per = jnp.sum(f, axis=1, dtype=jnp.int32)
+    ahead = jnp.sum(jnp.where(jnp.tri(groups, groups, -1, dtype=bool),
+                              per[None, :], 0), axis=1)   # the groups before
+    tile = jnp.arange(groups * w, dtype=jnp.int32).reshape(groups, w)
+    place = jnp.where(f, rank + ahead[:, None], groups * w + tile)
+    index = jnp.zeros((spill,), jnp.int32).at[place.reshape(-1)].set(
+        tile.reshape(-1), unique_indices=True, mode="drop")
+    return index, jnp.arange(spill) < jnp.sum(per)
+
+
+def _compacted_sums(key: jnp.ndarray, nseg: int, rows, regime, took=None,
+                    name: str = "pinot.groupby"):
+    """`_slab_sums` of a matmul rung (`regime`: `_onehot_sums` or
+    `_grouped_chunk64` bound to its key count) over the rows that passed
+    where the device finds them few: [int32 counts[nseg], f32 sums[nseg]...].
+    `rows[0]` is the count row (the 0/1 mask), the others masked value rows.
+
+    The cost of a matmul rung is the length of its contraction, not its
+    FLOPs, and a selective filter leaves most rows contracted as zeros. So one
+    count over the key's tiles of PRESORT_TILE rows (`_tile_counts`, the sort
+    regimes' count) comes first, and one HLO conditional on what it found:
+    the first step of PRESORT_SLOTS whose slots hold the rows that passed of
+    every tile but at most T / PRESORT_SPILL. There each other tile moves its
+    rows into its slots (`_presort_compact`, the sort regimes' move; the
+    overfull tiles' slots keep the overflow key and zeros), the overfull
+    tiles are taken whole (`_spill_index`: their key and value rows, the
+    places no tile took the overflow key and zeros), and the rung contracts
+    over the T x slots + T / PRESORT_SPILL x PRESORT_TILE rows, the count
+    row the compacted key's, in slabs of COMPACT_SLAB_ROWS (an accumulator
+    that adds fewer terms of a group adds them tighter). No row is
+    contracted twice: a row is in its tile's slots or in its whole tile,
+    never both. At 2^26 rows a device that is at most n / 16 + n / 256
+    rows, 17 slabs of 2^18. Past the last step (an unselective filter, or
+    rows that passed clustered in more tiles) the rung runs over every row
+    as before, at the cost of the one count.
+
+    Built only where the compacted rows are fewer than the rows
+    (`compacted_rows`), else the rung as it was. `took` (a list, or None)
+    collects the scalar `qstats.MATMUL_FLAG`: the compacted rows were
+    contracted."""
+    if not compacted_rows(key.size):
+        return _slab_sums(key, nseg, rows, regime)
+    over = nseg - 1
+    with jax.named_scope(f"{name}.presort"):
+        key_t, short, passed = _tile_counts(key, nseg)
+        spill = key_t.shape[0] // PRESORT_SPILL
+        # the first step whose slots leave at most `spill` tiles overfull
+        step = sum((jnp.sum(passed > s, dtype=jnp.int32) > spill).astype(
+            jnp.int32) for s in PRESORT_SLOTS)
+
+    def moved(slots):
+        def branch():
+            with jax.named_scope(f"{name}.presort"):
+                whole = passed > slots
+                vals_t = [jnp.pad(r, (0, short)).reshape(key_t.shape)
+                          for r in rows[1:]]
+                key_c, vals_c = _presort_compact(
+                    jnp.where(whole[:, None], over, key_t), vals_t, nseg,
+                    slots)
+                if spill:
+                    at, filled = _spill_index(whole, spill)
+                    taken = filled[:, None]
+                    key_c = jnp.concatenate(
+                        [key_c, jnp.where(taken, key_t[at], over).reshape(-1)])
+                    vals_c = [jnp.concatenate(
+                        [c, jnp.where(taken, v[at], 0.0).reshape(-1)])
+                        for c, v in zip(vals_c, vals_t)]
+                count = (key_c < over).astype(jnp.float32)
+            return _slab_sums(key_c, nseg, [count] + vals_c, regime,
+                              COMPACT_SLAB_ROWS)
+        return branch
+
+    if took is not None:
+        took.append({qstats.MATMUL_FLAG: step < len(PRESORT_SLOTS)})
+    return jax.lax.switch(step, [moved(s) for s in PRESORT_SLOTS] + [
+        lambda: _slab_sums(key, nseg, rows, regime)])
 
 
 def _dense_decode(key_s, vals_s, nseg: int, pad: int, block: int):
@@ -1175,10 +1316,7 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
     if not cap:
         return full()
     with jax.named_scope("pinot.groupby.partitioned.presort"):
-        short = (-rows) % PRESORT_TILE
-        key_t = jnp.pad(key, (0, short), constant_values=nseg - 1).reshape(
-            -1, PRESORT_TILE)
-        passed = jnp.sum(key_t < nseg - 1, axis=-1, dtype=jnp.int32)  # a tile
+        key_t, short, passed = _tile_counts(key, nseg)
         m = jnp.sum(passed)
         most = jnp.max(passed)
 
@@ -1385,10 +1523,7 @@ def _grouped_sparse(key: jnp.ndarray, nseg: int, sum_rows, ext_rows,
     over = nseg - 1
     vals = list(sum_rows) + [v for v, _ in ext_rows]
     with jax.named_scope("pinot.groupby.sparse.presort"):
-        short = (-rows) % PRESORT_TILE
-        key_t = jnp.pad(key, (0, short), constant_values=over).reshape(
-            -1, PRESORT_TILE)
-        passed = jnp.sum(key_t < over, axis=-1, dtype=jnp.int32)  # a tile
+        key_t, short, passed = _tile_counts(key, nseg)
         m = jnp.sum(passed)
         most = jnp.max(passed)
 
@@ -1437,8 +1572,8 @@ def _grouped_sparse(key: jnp.ndarray, nseg: int, sum_rows, ext_rows,
 def combine_collective(name: str, v, axis: str):
     """The cross-device combine for one kernel output: partials agree on dense keys
     (aligned dictionaries), so one ICI collective merges them."""
-    # the decode flags: a launch took the compact decode, or the compacted
-    # sort, only if every chip did
+    # the decode flags: a launch took the compact decode, the compacted sort
+    # or the compacted matmul rows only if every chip did
     if name.endswith((".min", ".max")) or name in qstats.DECODE_FLAGS:
         with jax.named_scope("pinot.collective.minmax"):
             return (jax.lax.pmax if name.endswith(".max")
@@ -1515,7 +1650,9 @@ def _make_body(spec: KernelSpec):
         mask = mask_fn(ids, vals, luts, iscal, fscal, nulls, valid, docsets,
                        bitmaps)
         out: Dict[str, jnp.ndarray] = {}
-        took: list = []  # which decode and sort each sort regime of the scan ran
+        # which decode and sort each sort regime of the scan ran, and which
+        # rows each matmul rung contracted
+        took: list = []
 
         if group:
             with scope("pinot.groupby.key"):
@@ -1578,16 +1715,23 @@ def _make_body(spec: KernelSpec):
                 with scope("pinot.groupby.masked"):
                     res = _masked_sums(key, num_seg, sum_rows)
             elif num_seg <= caps.matmul_cap:
+                # the matmul rungs contract over the rows that passed,
+                # compacted where the device finds them few
                 with scope("pinot.groupby.onehot"):
-                    res = _slab_sums(key, num_seg, sum_rows, lambda k, r:
-                                     _onehot_sums(k, num_seg, r))
+                    res = _compacted_sums(
+                        key, num_seg, sum_rows,
+                        lambda k, r: _onehot_sums(k, num_seg, r), took,
+                        "pinot.groupby.onehot")
             elif num_seg <= caps.chunk_cap:
                 # HIGH-CARDINALITY group-by: chunked 64x64-tile matmuls (the
                 # redesigned >cap path — 6.4x the segment_sum scatter at 20k
                 # keys; see _grouped_chunk64's measurement + limit analysis)
                 with scope("pinot.groupby.chunk64"):
-                    res = _slab_sums(key, num_seg, sum_rows, lambda k, r:
-                                     _grouped_chunk64(k, num_seg, r[:1], r[1:]))
+                    res = _compacted_sums(
+                        key, num_seg, sum_rows,
+                        lambda k, r: _grouped_chunk64(k, num_seg, r[:1],
+                                                      r[1:]), took,
+                        "pinot.groupby.chunk64")
             else:
                 # VERY-HIGH-CARDINALITY group-by (> chunk_cap): the sort
                 # regime, with exact int32 counts and no scatter
@@ -1610,11 +1754,12 @@ def _make_body(spec: KernelSpec):
                         op = (jax.ops.segment_min if is_min
                               else jax.ops.segment_max)
                         out[name] = op(v, key, num_segments=num_seg)
-            if took:
-                # compact, presorted: only if every sort regime of the scan was
-                for flag in qstats.DECODE_FLAGS:
-                    out[flag] = jnp.all(jnp.stack(
-                        [t[flag] for t in took])).astype(jnp.int32)
+            # each flag (compact, presorted, compacted matmul) only if every
+            # regime of the scan that sets it took its short branch
+            for flag in qstats.DECODE_FLAGS:
+                mine = [t[flag] for t in took if flag in t]
+                if mine:
+                    out[flag] = jnp.all(jnp.stack(mine)).astype(jnp.int32)
         else:
             with scope("pinot.agg"):
                 fmask = mask.ravel().astype(jnp.float32)

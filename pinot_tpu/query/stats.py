@@ -92,10 +92,12 @@ STAGED_LAUNCHES = "stagedLaunches"
 # in them: every dict column's decode table was small enough for the select
 # tree (kernels.SELECT_DECODE_CAP), so the decode fused into the scan (PR 27)
 GATHER_FREE_LAUNCHES = "gatherFreeLaunches"
-# launches whose GROUP BY ran a matmul regime (one-hot, chunk64) over more
+# launches whose program HOLDS a matmul regime (one-hot, chunk64) over more
 # than one slab of rows: past kernels.SLAB_ROWS (2^24) rows a device an f32
 # cell cannot hold a count, so the rows go slab by slab and the counts are
-# added as int32 (PR 31). From the static shape the kernel was built with
+# added as int32. From the static shape the kernel was built with;
+# where the rung's rows are compacted in front of it the loop is only the
+# fall-back's (FULL_MATMUL_LAUNCHES counts the launches that ran it)
 SLABBED_LAUNCHES = "slabbedLaunches"
 # launches whose GROUP BY ran the masked VPU reduce (PR 37): at most
 # `KernelCaps.masked_cap` padded keys + 1, so its counts and sums are a
@@ -135,6 +137,16 @@ PRESORT_COMPACT_LAUNCHES = "presortCompactLaunches"
 FULL_SORT_LAUNCHES = "fullSortLaunches"
 # graftcheck: ignore[drift-stats-keys] -- a kernel OUTPUT's name, as above
 PRESORT_FLAG = "decode.presort"
+# which rows a matmul GROUP BY rung (one-hot, chunk64) contracted: those that
+# passed the filter, compacted tile by tile with the few overfull tiles taken
+# whole (n / 64 or n / 16 rows and n / 256), or every row. The kernel returns
+# the scalar MATMUL_FLAG (1 only if every matmul rung of the scan, on every
+# chip, contracted the compacted rows); a program built without the stage
+# (`kernels.compacted_rows` 0) returns none and counts neither
+COMPACT_MATMUL_LAUNCHES = "compactMatmulLaunches"
+FULL_MATMUL_LAUNCHES = "fullMatmulLaunches"
+# graftcheck: ignore[drift-stats-keys] -- a kernel OUTPUT's name, as above
+MATMUL_FLAG = "decode.matmul"
 NUM_CONSUMING_SEGMENTS_QUERIED = "numConsumingSegmentsQueried"
 MIN_CONSUMING_FRESHNESS_TIME_MS = "minConsumingFreshnessTimeMs"
 MUX_FRAME_QUEUE_MS = "muxFrameQueueMs"
@@ -206,6 +218,7 @@ COUNTER_KEYS = (
     SPARSE_GROUPBY_LAUNCHES, DEVICE_TRIMMED_LAUNCHES,
     COMPACT_DECODE_LAUNCHES, DENSE_DECODE_LAUNCHES,
     PRESORT_COMPACT_LAUNCHES, FULL_SORT_LAUNCHES,
+    COMPACT_MATMUL_LAUNCHES, FULL_MATMUL_LAUNCHES,
     NUM_CONSUMING_SEGMENTS_QUERIED, MUX_FRAME_QUEUE_MS, MUX_FLOW_CONTROL_MS,
     MESH_LAUNCHES, SCATTER_LAUNCHES, COLLECTIVE_BYTES,
     ROUTED_SLOTS, RESIDENT_SLOTS, SCANNED_SLOTS, MERGED_LAUNCHES,
@@ -371,18 +384,19 @@ def record(key: str, n: float = 1) -> None:
         st.add(key, n)
 
 
-#: a sort-regime launch's flag -> the counter it adds to when set, when not
+#: a GROUP BY launch's flag -> the counter it adds to when set, when not
 DECODE_FLAGS = {
     COMPACT_FLAG: (COMPACT_DECODE_LAUNCHES, DENSE_DECODE_LAUNCHES),
     PRESORT_FLAG: (PRESORT_COMPACT_LAUNCHES, FULL_SORT_LAUNCHES),
+    MATMUL_FLAG: (COMPACT_MATMUL_LAUNCHES, FULL_MATMUL_LAUNCHES),
 }
 
 
 def decode_branch(outs) -> Tuple[str, ...]:
     """The counters one launch's fetched outputs add to: which decode branch
-    and which sort its sort regimes ran (DECODE_FLAGS), or none for a program
-    without the branches (and for whatever a test's fake executor hands
-    back)."""
+    and which sort its sort regimes ran, which rows its matmul rung
+    contracted (DECODE_FLAGS), or none for a program without the branches
+    (and for whatever a test's fake executor hands back)."""
     if not isinstance(outs, dict):
         return ()
     return tuple(keys[0] if int(outs[flag]) else keys[1]

@@ -142,6 +142,26 @@ def _compile_agg(topo, cpu_exec, segments, name, segs, n_devices=1):
     return p, fn.jitted_for(ax).lower(ax).compile()
 
 
+def _slab_loops(text, cells):
+    """The slab loops of a matmul regime: the whiles that carry its int32
+    counts of `cells` cells (the compiler adds loops of one trip of its own
+    that copy a stacked row)."""
+    return [ln for ln in text.splitlines()
+            if " while(" in ln and f"s32[{cells}]" in ln]
+
+
+def _slabbed_somewhere(rows):
+    """Whether a matmul rung's program over `rows` rows a device holds a slab
+    loop: the fall-back's past SLAB_ROWS rows, or a step of the compaction
+    whose rows take more than one COMPACT_SLAB_ROWS slab."""
+    tiles = -(-rows // kernels.PRESORT_TILE)
+    moved = [tiles * s + tiles // kernels.PRESORT_SPILL * kernels.PRESORT_TILE
+             for s in kernels.PRESORT_SLOTS] if kernels.compacted_rows(rows) \
+        else []
+    return kernels.slab_count(rows) > 1 or any(
+        kernels.slab_count(n, kernels.COMPACT_SLAB_ROWS) > 1 for n in moved)
+
+
 def _fits(compiled, hbm_bytes=16e9):
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
@@ -192,7 +212,8 @@ def test_served_agg_kernel_compiles_for_v5e(topo, cpu_exec, segments, name,
         assert ("pinot.groupby.onehot" in text) == (name == ONEHOT)
         assert kernels.slabbed(_real_spec(p.spec), segs * SEG_ROWS) == (
             segs > MATMUL_SEGS and not rung)
-        assert (" while(" in text) == (segs > MATMUL_SEGS and not rung)
+        assert bool(_slab_loops(text, p.spec.num_keys_pad + 1)) == (
+            _slabbed_somewhere(segs * SEG_ROWS) and not rung)
         assert (" convolution(" in text) == (not rung)
         assert " sort(" not in text
         assert f"[{SMOKE_SEGS * SEG_ROWS}]" not in "".join(
@@ -296,7 +317,8 @@ def test_routed_window_program_compiles_for_v5e(topo, cpu_exec, segments,
         assert any(f"[{rows // 64}]" in ln for ln in sorts)
         assert not any(f"[{SMOKE_SEGS * SEG_ROWS}]" in ln for ln in sorts)
     elif "group-by" in name:
-        assert (" while(" in text) == (rows > kernels.SLAB_ROWS)
+        assert bool(_slab_loops(text, p.spec.num_keys_pad + 1)) == (
+            _slabbed_somewhere(rows))
         assert " sort(" not in text
     # the window is cut inside the fusions that read it: no top-level
     # dynamic-slice writes a copy of a column's window
@@ -402,6 +424,39 @@ def test_four_chip_program_compiles_with_its_collective(topo, cpu_exec,
         assert any(f"[{rows // 64}]" in ln for ln in sorts)
         assert any(f"[{rows // 16}]" in ln for ln in sorts)
         assert "pinot.groupby.partitioned.presort/" in text
+
+
+@pytest.mark.parametrize("keys,limit_s", [
+    pytest.param(256, 60, id="257-cells-onehot"),      # SSB Q4.1's rung
+    pytest.param(8192, 90, id="8193-cells-chunk64"),   # Q2.1-Q3.1, Q4.2's
+])
+def test_compacted_matmul_program_compiles_for_v5e_at_67m_rows(topo, keys,
+                                                               limit_s):
+    """A matmul rung at the full cell's 2^26 rows on one described chip, the
+    rows that passed compacted in front of it: the tiles' count, ONE
+    conditional whose compacted branches contract over n / 64 or n / 16 rows
+    plus n / 256 (in slabs of COMPACT_SLAB_ROWS), and the fall-back's loop
+    over all the rows; no sort. Its compile, which a cold start pays, stays
+    within its own limit."""
+    import time
+    from test_dense_groupby import _abstract_scan
+    body, args = _abstract_scan("COUNT(*), SUM(v)", keys, 1 << 26)
+    one = NamedSharding(_mesh(topo, 1), P())
+    placed = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), args)
+    t0 = time.perf_counter()
+    compiled = jax.jit(body).lower(*placed).compile()
+    seconds = time.perf_counter() - t0
+    _fits(compiled)
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    assert entry.count(" conditional(") == 1 and _slab_loops(text, keys + 1)
+    assert " sort(" not in text
+    tiles = (1 << 26) // kernels.PRESORT_TILE
+    for slots in kernels.PRESORT_SLOTS:
+        assert f"[{tiles * slots + tiles // 256 * kernels.PRESORT_TILE}]" \
+            in text, slots
+    assert seconds < limit_s, f"{seconds:.1f} s to compile"
 
 
 @pytest.mark.parametrize("width,decode", [

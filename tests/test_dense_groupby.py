@@ -1373,47 +1373,82 @@ def _contracted(eqn):
     return [eqn.invars[0].aval.shape[d] for d in lhs_c]
 
 
-@pytest.mark.parametrize("aggs,keys,ids,carries,dots", [
+@pytest.mark.parametrize("aggs,keys,ids,carries,dots,moved", [
     # one-hot: three bf16 parts, the rows stacked
-    (_SUMS, 256, None, [[("int32", 257), ("float32", 257)]], 3),
+    (_SUMS, 256, None, [[("int32", 257), ("float32", 257)]], 3, 3),
     # two chunks x (the count's one contraction + the sum's three parts)
-    (_SUMS, 8192, None, [[("int32", 8193), ("float32", 8193)]], 8),
+    (_SUMS, 8192, None, [[("int32", 8193), ("float32", 8193)]], 8, 8),
     # 16 x 64 presence cells: one chunk, count only; the group-by's own count
     # (16 cells: the masked reduce) builds no loop and no contraction
-    (_DISTINCT, 15, 64, [[("int32", 1024)]], 1),
+    (_DISTINCT, 15, 64, [[("int32", 1024)]], 1, 0),
     # 257 groups x 64 ids: four chunks over the 256 real groups' presence
     # cells; then the group-by's own count (one-hot, 257 cells)
-    (_DISTINCT, 256, 64, [[("int32", 257 * 64)], [("int32", 257)]], 4 + 3),
+    (_DISTINCT, 256, 64, [[("int32", 257 * 64)], [("int32", 257)]], 4 + 3, 3),
 ], ids=["onehot-257", "chunk64-8193", "distinct", "distinct-257-groups"])
 def test_at_2_26_rows_the_matmul_regimes_contract_one_slab_at_a_time(
-        aggs, keys, ids, carries, dots):
-    """The full cell's shape, 2^26 rows a device: a loop of four steps whose
-    body holds every contraction of the regime, none of them over more than
-    2^24 rows (an f32 cell's exact range); the count leaves each slab as an
-    integer (rounded and converted inside the body, added to an int32 carry)
-    and the sums are carried in f32. No sort anywhere."""
+        aggs, keys, ids, carries, dots, moved):
+    """The full cell's shape, 2^26 rows a device: every loop over all the rows
+    is one of four steps whose body holds every contraction of its regime,
+    none of them over more than 2^24 rows (an f32 cell's exact range); the
+    count leaves each slab as an integer (rounded and converted inside the
+    body, added to an int32 carry) and the sums are carried in f32. The
+    group-by's own one-hot or chunk64 rung sits in one conditional: its
+    branch for each step of the compaction (16 and 64 slots a 1,024-row
+    tile, one tile in 256 taken whole) contracts over T x S + T / 256 x
+    1,024 rows in slabs of COMPACT_SLAB_ROWS, 5 and 17 of them, with the
+    same carries, besides the two triangles over a tile; the fall-back holds
+    the loop over all the rows. A grouped distinct's presence product keeps
+    its loop. No sort anywhere."""
     import jax
+    from pinot_tpu.engine import kernels
     body, args = _abstract_scan(aggs, keys, 1 << 26, ids)
     jaxpr = jax.make_jaxpr(body)(*args).jaxpr
     loops = list(_eqns(jaxpr, "scan", "while"))
-    assert [e.primitive.name for e in loops] == ["scan"] * len(carries)
-    in_loops = 0
-    for loop, carried in zip(loops, carries):
-        assert loop.params["length"] == 4
-        inner = loop.params["jaxpr"].jaxpr
-        in_loops += len(list(_eqns(inner, "dot_general")))
+    assert {e.primitive.name for e in loops} == {"scan"}
+    full = [e for e in loops if e.params["length"] == 4]
+    assert len(full) == len(carries)
+    tile, steps = kernels.PRESORT_TILE, kernels.PRESORT_SLOTS
+    tiles, slab = (1 << 26) // tile, kernels.COMPACT_SLAB_ROWS
+
+    def carried_by(loop):
         avals = [v.aval for v in loop.outvars[:loop.params["num_carry"]]]
-        assert [(a.dtype.name, a.shape[0]) for a in avals if a.shape] \
-            == carried
+        return [(a.dtype.name, a.shape[0]) for a in avals if a.shape]
+
+    looped = []
+    for loop, carried in zip(full, carries):
+        inner = loop.params["jaxpr"].jaxpr
+        looped += list(_eqns(inner, "dot_general"))
+        assert carried_by(loop) == carried
         # the count: round -> int32 -> added to the int32 carry, in the body
         assert list(_eqns(inner, "round"))
         assert any((e.outvars[0].aval.dtype.name, e.outvars[0].aval.shape)
                    == ("int32", (carried[0][1],))
                    for e in _eqns(inner, "add"))
-    all_dots = list(_eqns(jaxpr, "dot_general"))
-    assert len(all_dots) == in_loops == dots    # traced once, not four times
-    for eqn in all_dots:
+    assert len(looped) == dots              # traced once, not four times
+    for eqn in looped:
         assert _contracted(eqn) == [1 << 24], eqn
+    compacted = [e for e in loops if e.params["length"] != 4]
+    assert sorted(e.params["length"] for e in compacted) == sorted(
+        kernels.slab_count(tiles * s + tiles // 256 * tile, slab)
+        for s in steps if moved)
+    in_loops = {id(e) for e in looped}
+    for loop in compacted:
+        assert carried_by(loop) == carries[-1]      # the rung's own
+        inner = list(_eqns(loop.params["jaxpr"].jaxpr, "dot_general"))
+        assert len(inner) == moved
+        assert all(_contracted(e) == [slab] for e in inner)
+        in_loops |= {id(e) for e in inner}
+    outside = [tuple(_contracted(e)) for e in _eqns(jaxpr, "dot_general")
+               if id(e) not in in_loops]
+    assert outside == [(tile,)] * (2 * len(steps) if moved else 0)
+    switches = [e for e in _eqns(jaxpr, "cond")
+                if len(e.params["branches"]) == len(steps) + 1]
+    assert len(switches) == int(bool(moved))
+    for switch in switches:
+        *moving, last = switch.params["branches"]
+        assert all(e.params["length"] != 4
+                   for b in moving for e in _eqns(b.jaxpr, "scan"))
+        assert [e.params["length"] for e in _eqns(last.jaxpr, "scan")] == [4]
     assert not list(_eqns(jaxpr, "sort"))
 
 
@@ -1422,13 +1457,25 @@ def test_at_2_26_rows_the_matmul_regimes_contract_one_slab_at_a_time(
     (_DISTINCT, 15, 64, _2_24), (_SUMS, 8192, None, 16_384),
 ], ids=["onehot", "chunk64", "distinct", "chunk64-16k-rows"])
 def test_at_most_2_24_rows_build_no_loop(aggs, keys, ids, rows):
-    """One slab is the program as it was: no loop, one contraction a pass."""
+    """One slab of all the rows is the program as it was: no loop over them,
+    one contraction a pass over the rows. The one-hot and chunk64 rungs'
+    compacted branches contract over T x S + T / 256 x 1,024 rows (T tiles
+    of 1,024 rows, S slots a tile), in slabs of COMPACT_SLAB_ROWS where they
+    are longer (a loop of that many steps), besides the triangles over a
+    tile."""
     import jax
+    from pinot_tpu.engine import kernels
     body, args = _abstract_scan(aggs, keys, rows, ids)
     jaxpr = jax.make_jaxpr(body)(*args).jaxpr
-    assert not list(_eqns(jaxpr, "scan", "while"))
-    dots = list(_eqns(jaxpr, "dot_general"))
-    assert dots and all(_contracted(e) == [rows] for e in dots)
+    tile, tiles = kernels.PRESORT_TILE, rows // kernels.PRESORT_TILE
+    moved = [] if ids else [tiles * s + tiles // 256 * tile
+                            for s in kernels.PRESORT_SLOTS]
+    slabs = [kernels.slab_count(n, kernels.COMPACT_SLAB_ROWS) for n in moved]
+    assert sorted(e.params["length"] for e in _eqns(jaxpr, "scan", "while")) \
+        == sorted(k for k in slabs if k > 1)
+    dots = {tuple(_contracted(e)) for e in _eqns(jaxpr, "dot_general")}
+    assert dots == {(rows,)} | ({(tile,)} if moved else set()) | {
+        (-(-n // k),) for n, k in zip(moved, slabs)}
 
 
 @pytest.mark.parametrize("keys,passes", [
@@ -1440,7 +1487,9 @@ def test_chunk64_passes_cover_the_real_keys_alone(keys, passes):
     import jax
     body, args = _abstract_scan(_SUMS, keys, 16_384)
     jaxpr = jax.make_jaxpr(body)(*args).jaxpr
-    assert len(list(_eqns(jaxpr, "dot_general"))) == 4 * passes
+    # over every row: the fall-back of the compaction in front of the rung
+    assert len([e for e in _eqns(jaxpr, "dot_general")
+                if _contracted(e) == [16_384]]) == 4 * passes
 
 
 @pytest.mark.parametrize("nseg", [257, 4097, 8193, 8200, 12_289])
@@ -1646,3 +1695,188 @@ def test_a_stacked_burst_runs_its_slabs_inside_the_batch_scan(slab_table,
         assert [r[:2] for r in got] == [r[:2] for r in want], sqls[i]
         np.testing.assert_allclose([r[2] for r in got],
                                    [r[2] for r in want], rtol=1e-6)
+
+
+# --- the rows that passed, compacted in front of the matmul rungs ------------
+# 16,384 rows in 64 tiles of 256: a tile keeps 4 slots, then 16, and one tile
+# in 4 (16) may be taken whole beside them. Masked-out rows carry the overflow
+# key and zeros, as `_make_body` hands them to the rungs.
+
+COMPACT_N, COMPACT_TILE, COMPACT_STEPS, COMPACT_SPILL = 16_384, 256, (4, 16), 4
+
+
+def _patch_compaction(monkeypatch, tile=COMPACT_TILE, slots=COMPACT_STEPS,
+                      spill=COMPACT_SPILL, slab=None):
+    """Programs built from here on compact a matmul rung's rows in tiles of
+    `tile` rows into `slots` slots, one tile in `spill` taken whole, and
+    contract them in slabs of `slab` rows (the constant that ships where
+    None)."""
+    from pinot_tpu.engine import kernels
+    from pinot_tpu.parallel import combine
+    if slab:
+        monkeypatch.setattr(kernels, "COMPACT_SLAB_ROWS", slab)
+    monkeypatch.setattr(kernels, "PRESORT_TILE", tile)
+    monkeypatch.setattr(kernels, "PRESORT_SLOTS", slots)
+    monkeypatch.setattr(kernels, "PRESORT_SPILL", spill)
+    monkeypatch.setattr(kernels, "_KERNEL_CACHE", {})
+    monkeypatch.setattr(combine, "_SHARD_KERNEL_CACHE", {})
+
+
+def _layout(name):
+    """Which of COMPACT_N rows pass, and whether the compaction holds them:
+    a step whose slots leave at most COMPACT_N / COMPACT_TILE / COMPACT_SPILL
+    = 16 tiles overfull."""
+    live = np.zeros(COMPACT_N, bool)
+    tiles = live.reshape(-1, COMPACT_TILE)             # a view
+    sparse = np.arange(COMPACT_N) % 128 == 7           # 2 a tile
+    if name == "every-tile-within-4":
+        live[np.arange(COMPACT_N) % 64 == 3] = True    # 4 a tile: the slots
+        return live, True
+    if name == "unselective":
+        live[np.random.default_rng(1).random(COMPACT_N) < 0.9] = True
+        return live, False
+    live |= sparse
+    if name == "a-tile-at-4":
+        tiles[5, :2] = True                            # 2 + 2 more
+    elif name == "a-tile-at-5":
+        tiles[5, 100:103] = True                       # 5: taken whole
+    elif name == "a-whole-tile":
+        tiles[9] = True                                # all 256 rows
+    elif name in ("16-overfull-tiles", "17-overfull-tiles"):
+        for t in range(16 if name.startswith("16") else 17):
+            tiles[3 * t + 1, 30:50] = True             # 22 a tile
+        return live, name.startswith("16")
+    elif name == "walk":
+        # every segment of 4,096 rows opens with runs of 50 rows that pass
+        # every 150 rows: its first three tiles hold 102, 102 and 52
+        for seg in range(COMPACT_N // 4096):
+            for run in range(0, 750, 150):
+                live[seg * 4096 + run:seg * 4096 + run + 50] = True
+    return live, True
+
+
+COMPACT_LAYOUTS = ["every-tile-within-4", "a-tile-at-4", "a-tile-at-5",
+                   "a-whole-tile", "16-overfull-tiles", "17-overfull-tiles",
+                   "walk", "unselective"]
+_COMPACTED_FNS: dict = {}
+
+
+def _compacted_fn(regime, n_vals):
+    """The jitted rung (its counts and sums, and its flag) under the patched
+    constants, built once for every layout."""
+    import jax
+    from pinot_tpu.engine import kernels
+    from pinot_tpu.query import stats as qstats
+    key = (regime, n_vals, kernels.PRESORT_TILE, kernels.PRESORT_SLOTS,
+           kernels.PRESORT_SPILL, kernels.COMPACT_SLAB_ROWS)
+    if key not in _COMPACTED_FNS:
+        nseg, fn = {
+            "onehot": (257, lambda k, r: kernels._onehot_sums(k, 257, r)),
+            "chunk64": (8193, lambda k, r: kernels._grouped_chunk64(
+                k, 8193, r[:1], r[1:])),
+        }[regime]
+
+        def run(k, rows):
+            took = []
+            return (kernels._compacted_sums(k, nseg, rows, fn, took),
+                    took[0][qstats.MATMUL_FLAG])
+        _COMPACTED_FNS[key] = (nseg, jax.jit(run))
+    return _COMPACTED_FNS[key]
+
+
+@pytest.mark.parametrize("slab", [None, 1500], ids=["one-slab", "slabs"])
+@pytest.mark.parametrize("aggs", ["sums", "count"])
+@pytest.mark.parametrize("layout", COMPACT_LAYOUTS)
+@pytest.mark.parametrize("regime", ["onehot", "chunk64"])
+def test_compacted_rung_counts_every_row_once(monkeypatch, regime, layout,
+                                              aggs, slab):
+    """Each matmul rung over the rows that passed, compacted or not: int32
+    counts equal to numpy's (a row contracted twice, or not at all, would
+    show), sums within 1e-6 of the int64 / float64 sums, the overflow cell
+    0, and the flag 1 exactly where a step's slots leave at most one tile in
+    four overfull: a tile at its slots, one past them and one whose every
+    row passed are taken; 16 tiles past both steps are, 17 fall back, as
+    an unselective filter does; the walk's runs of 50 fit. The compacted
+    rows are contracted in one slab, or in slabs of 1,500 rows whose tail is
+    padded (3 at the first step, 4 at the second)."""
+    from pinot_tpu.engine import kernels
+    _patch_compaction(monkeypatch, slab=slab)
+    assert kernels.compacted_rows(COMPACT_N) == 64 * 16 + 16 * 256
+    live, fits = _layout(layout)
+    per_tile = live.reshape(-1, COMPACT_TILE).sum(1)
+    assert fits == any((per_tile > s).sum() <= 16 for s in COMPACT_STEPS)
+    rng = np.random.default_rng(COMPACT_LAYOUTS.index(layout))
+    nseg, run = _compacted_fn(regime, 3 if aggs == "sums" else 1)
+    key = np.where(live, rng.integers(0, nseg - 1, COMPACT_N),
+                   nseg - 1).astype(np.int32)
+    fm = live.astype(np.float32)
+    q = rng.integers(1, 60_000, COMPACT_N)
+    v = np.round(rng.uniform(-500, 60_000, COMPACT_N), 2)
+    rows = [fm] if aggs == "count" else [
+        fm, q.astype(np.float32) * fm, v.astype(np.float32) * fm]
+    got, flag = run(key, rows)
+    assert int(flag) == int(fits)
+    assert len(got) == len(rows) and got[0].dtype == np.int32
+    np.testing.assert_array_equal(np.asarray(got[0]),
+                                  np.bincount(key[live], minlength=nseg))
+    for g, want in zip(got[1:], (q, v.astype(np.float32))):
+        assert g.dtype == np.float32 and float(g[-1]) == 0.0
+        np.testing.assert_allclose(
+            np.asarray(g, np.float64),
+            np.bincount(key[live], weights=want[live].astype(np.float64),
+                        minlength=nseg), rtol=1e-6, atol=1e-6)
+
+
+def test_spill_index_finds_the_flagged_tiles_in_order(monkeypatch):
+    """The overfull tiles' indices, in tile order, across groups of
+    PRESORT_TILE tiles (here 4): at most `spill` of them, the places left
+    over marked as not taken."""
+    import jax
+    from pinot_tpu.engine import kernels
+    monkeypatch.setattr(kernels, "PRESORT_TILE", 4)
+    flagged = np.zeros(22, bool)
+    flagged[[1, 2, 3, 7, 8, 21]] = True
+    for spill, want in ((8, [1, 2, 3, 7, 8, 21]), (6, [1, 2, 3, 7, 8, 21]),
+                        (3, [1, 2, 3])):
+        at, taken = jax.jit(lambda f, s=spill: kernels._spill_index(f, s))(
+            flagged)
+        n = len(want)
+        assert np.asarray(at)[:n].tolist() == want[:spill]
+        assert np.asarray(taken).tolist() == [True] * min(n, spill) + [
+            False] * (spill - min(n, spill))
+
+
+MATMUL_KEYS = ("compactMatmulLaunches", "fullMatmulLaunches")
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+@pytest.mark.parametrize("by", ["g", "k"], ids=["onehot", "chunk64"])
+def test_one_chip_falls_back_three_compact_on_the_mesh(tmp_path_factory, by,
+                                                       devices):
+    """Four segments of 8,000 rows, at the constants that ship: `w < 100`
+    passes every row of the second segment (its tiles overflow every step:
+    the launch contracts every row, on a mesh of four the chip that holds
+    it alone, and the launch counts as full: compacted only if every chip
+    was), `w < 10` 40 rows of one tile (the second step), `w = 500` about
+    16 scattered in each segment but the second. Answers equal to the
+    host's, one launch counted in one of the two."""
+    schema, cols = one_full_quarter(f"cmm{devices}")
+    cols["g"] = np.random.default_rng(41).integers(
+        0, 200, len(cols["k"])).astype(np.int32)
+    schema = Schema(schema.name, [dimension("g", DataType.INT)]
+                    + list(schema.fields))
+    cfg = SegmentGeneratorConfig(no_dictionary_columns=["w", "v"])
+    segs = [load_segment(p) for p in build_aligned_segments(
+        schema, cols, str(tmp_path_factory.mktemp(f"cmm{devices}")),
+        f"cmm{devices}", 4, config=cfg)]
+    mex = MeshQueryExecutor(default_mesh(devices))
+    host = ServerQueryExecutor(use_device=False)
+    for where, compact in (("w < 100", 0), ("w < 10", 1), ("w = 500", 1)):
+        sql = (f"SELECT {by}, COUNT(*), SUM(v) FROM {schema.name} "
+               f"WHERE {where} GROUP BY {by} ORDER BY {by} LIMIT 100000")
+        got, took = _executed(mex, segs, sql, MATMUL_KEYS + SORT_KEYS)
+        _assert_rows_close(got, host.execute(segs, sql).rows, where)
+        assert took == {"compactMatmulLaunches": compact,
+                        "fullMatmulLaunches": 1 - compact,
+                        "presortCompactLaunches": 0,
+                        "fullSortLaunches": 0}, where

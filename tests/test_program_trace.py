@@ -31,6 +31,7 @@ MESH_METRICS = (
     "mesh.scatter_launch_share", "mesh.collective_bytes_per_answer")
 DECODE_METRIC = "kernels.compact_decode_share"      # PR 29, every cell
 PRESORT_METRIC = "kernels.presort_compact_share"    # PR 33, every cell
+MATMUL_METRIC = "kernels.compact_matmul_share"      # the four SSB cells
 
 
 @pytest.fixture(scope="module")
@@ -76,7 +77,7 @@ def test_reader_returns_none_on_the_parents_run(name):
 
 @pytest.mark.parametrize("name",
                          NEW_METRICS + MESH_METRICS
-                         + (DECODE_METRIC, PRESORT_METRIC))
+                         + (DECODE_METRIC, PRESORT_METRIC, MATMUL_METRIC))
 def test_every_new_metric_is_declared_like_the_old(name):
     """A `.json` with the keys of PR 25's and a `per_layer` entry that says
     the same; PR 26's have no `workloads` key (every cell owes them), PR 28's
@@ -91,7 +92,7 @@ def test_every_new_metric_is_declared_like_the_old(name):
         assert entry[0]["workloads"] == [MESH_CELL]
         assert entry[0]["layer"] in {m["layer"] for m in bench["per_layer"]
                                      if "workloads" not in m}
-    elif name in (DECODE_METRIC, PRESORT_METRIC):
+    elif name in (DECODE_METRIC, PRESORT_METRIC, MATMUL_METRIC):
         assert entry[0]["workloads"] == [
             w["name"] for w in bench["workloads"]
             if w["traffic"] == "flights-c4"]
@@ -139,6 +140,25 @@ def test_presort_compact_share_reads_the_counter_delta(counters, want,
     assert got == want if want is None else got == pytest.approx(want)
     for parent in (mesh_recorded["counters"], recorded["counters"]):
         assert "presortCompactLaunches" not in parent
+        assert read(_ctx([], parent, None)) is None
+
+
+@pytest.mark.parametrize("counters,want", [
+    ({"compactMatmulLaunches": 1144, "fullMatmulLaunches": 0}, 100.0),
+    ({"compactMatmulLaunches": 5, "fullMatmulLaunches": 1}, 500 / 6),
+    ({"compactMatmulLaunches": 0, "fullMatmulLaunches": 6}, 0.0),
+    # no launch held the stage (the lineitem and Q3 mixes): never 0
+    ({"compactMatmulLaunches": 0, "fullMatmulLaunches": 0}, None),
+    # the parent counts its sorts and no matmul rung's rows
+    ({"presortCompactLaunches": 152, "fullSortLaunches": 0}, None),
+])
+def test_compact_matmul_share_reads_the_counter_delta(counters, want,
+                                                      mesh_recorded, recorded):
+    read = cells.load_reader(MATMUL_METRIC)
+    got = read(_ctx([], counters, None))
+    assert got == want if want is None else got == pytest.approx(want)
+    for parent in (mesh_recorded["counters"], recorded["counters"]):
+        assert "compactMatmulLaunches" not in parent
         assert read(_ctx([], parent, None)) is None
 
 

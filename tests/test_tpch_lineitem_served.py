@@ -170,10 +170,12 @@ def test_new_metric_is_declared_for_the_new_cell_alone(name):
 
 
 @pytest.mark.parametrize("name", ("kernels.compact_decode_share",
-                                  "kernels.presort_compact_share"))
+                                  "kernels.presort_compact_share",
+                                  "kernels.compact_matmul_share"))
 def test_sort_regime_metrics_list_the_cells_that_reach_it(name):
-    """Neither Q1 (9 key cells) nor Q6 (none) reaches the sort regime, where
-    alone these two read anything: they list the four accepted cells."""
+    """Neither Q1 (9 key cells) nor Q6 (none) reaches the sort regime or a
+    matmul rung, where alone these three read anything: they list the four
+    SSB cells."""
     bench = cells.read_json(cells.ROOT, "BENCHMARK.json")
     entry, = [m for m in bench["per_layer"] if m["name"] == name]
     assert entry["workloads"] == [

@@ -969,6 +969,74 @@ def test_slabbed_launches_reach_response_explain_and_health(tmp_path,
             rtol=1e-6)
 
 
+# -- the rows that passed, compacted in front of the matmul rungs ------------
+
+def test_matmul_compaction_counters_reach_response_explain_and_health(
+        tmp_path):
+    """Through the device pipeline of a served cluster whose mesh is four
+    devices, two segments each (8 tiles of 1,024 rows a device): a selective
+    GROUP BY of 200 keys (one-hot) and one of 2,000 (chunk64) answer with
+    `compactMatmulLaunches` 1, an unselective one with `fullMatmulLaunches`
+    1, a scalar aggregation with neither; EXPLAIN ANALYZE carries the fields
+    and `/health`'s device block sums the launches. Every answer is numpy's."""
+    from pinot_tpu.cluster.device_server import DeviceQueryPipeline
+    from pinot_tpu.parallel import MeshQueryExecutor, default_mesh
+    from pinot_tpu.table import IndexingConfig
+    rng = np.random.default_rng(41)
+    segs, per = 8, 4000
+    schema = Schema("cm", [dimension("g", DataType.INT),
+                           dimension("k", DataType.INT),
+                           metric("w", DataType.INT)])
+    cols = {"g": rng.integers(0, 200, segs * per).astype(np.int32),
+            "k": rng.integers(0, 2000, segs * per).astype(np.int32),
+            "w": rng.integers(1, 1000, segs * per).astype(np.int32)}
+    for s in range(segs):       # every key in every segment: aligned
+        cols["g"][s * per:s * per + 200] = np.arange(200)
+        cols["k"][s * per + 200:s * per + 2200] = np.arange(2000)
+    cluster = QuickCluster(num_servers=1, work_dir=str(tmp_path))
+    cluster.servers[0].device_pipeline = pipeline = DeviceQueryPipeline(
+        mesh_exec=MeshQueryExecutor(default_mesh(4)))
+    cfg = TableConfig("cm", indexing=IndexingConfig(
+        no_dictionary_columns=["w"]))
+    cluster.create_table(schema, cfg)
+    for s in range(segs):
+        cluster.ingest_columns(cfg, {c: v[s * per:(s + 1) * per]
+                                     for c, v in cols.items()})
+    by = "SELECT {0}, COUNT(*), SUM(w) FROM cm WHERE w > {1} GROUP BY {0} " \
+         "ORDER BY {0} LIMIT 10000"
+    sqls = {"onehot": by.format("g", 990), "chunk64": by.format("k", 990),
+            "unselective": by.format("g", 500),
+            "scalar": "SELECT COUNT(*), SUM(w) FROM cm WHERE w > 990",
+            "explain": "EXPLAIN ANALYZE " + by.format("g", 990)}
+    try:
+        got = {name: cluster.query(sql) for name, sql in sqls.items()}
+        health = pipeline.stats()
+    finally:
+        pipeline.stop()
+    for name, compact, full in (("onehot", 1, 0), ("chunk64", 1, 0),
+                                ("unselective", 0, 1), ("scalar", 0, 0),
+                                ("explain", 1, 0)):
+        s = got[name].stats
+        assert s["deviceLaunches"] >= 1 and s["meshLaunches"] >= 1, (name, s)
+        assert (s["compactMatmulLaunches"], s["fullMatmulLaunches"]) \
+            == (compact, full), (name, s)
+    assert got["explain"].stats["analyze"] is True
+    assert (health["compactMatmulLaunches"], health["fullMatmulLaunches"]) \
+        == (3, 1)
+    assert health["deviceErrors"] == 0 and health["fallbacks"] == 0
+    for name, col, bound in (("onehot", "g", 990), ("chunk64", "k", 990),
+                             ("unselective", "g", 500)):
+        live = cols["w"] > bound
+        keys = np.unique(cols[col][live])
+        assert [r[0] for r in got[name].rows] == keys.tolist()
+        assert [r[1] for r in got[name].rows] == \
+            np.bincount(cols[col][live])[keys].tolist()
+        np.testing.assert_allclose(
+            [r[2] for r in got[name].rows],
+            np.bincount(cols[col][live], weights=cols["w"][live])[keys],
+            rtol=1e-6)
+
+
 # -- PR 37: the masked reduce at the bottom of the ladder, and its counter ----
 
 def test_masked_groupby_launches_reach_response_explain_and_health(tmp_path):
