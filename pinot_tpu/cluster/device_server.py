@@ -79,6 +79,7 @@ _SHAPE_KEYS = (qstats.MESH_LAUNCHES, qstats.SCATTER_LAUNCHES,
                qstats.COLLECTIVE_BYTES, qstats.SLABBED_LAUNCHES,
                qstats.WIDENED_AGG_LAUNCHES, qstats.MASKED_GROUPBY_LAUNCHES,
                qstats.SPARSE_GROUPBY_LAUNCHES, qstats.DEVICE_TRIMMED_LAUNCHES,
+               qstats.DENSE_TRIMMED_LAUNCHES, qstats.SORTED_MINMAX_LAUNCHES,
                qstats.ROUTED_SLOTS, qstats.RESIDENT_SLOTS,
                qstats.SCANNED_SLOTS, qstats.MERGED_LAUNCHES)
 

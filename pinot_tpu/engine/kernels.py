@@ -36,7 +36,10 @@ matmuls —
   (`_compacted_sums`), else over every row, past SLAB_ROWS (2^24) rows a
   device slab by slab (`_slab_sums`), int32 counts added across the
   slabs; min/max by per-key broadcast-reduce up to
-  `minmax_bcast_cap`, `segment_min` / `segment_max` above.
+  `minmax_bcast_cap`, `segment_min` / `segment_max` above, and in the sort
+  regimes from the sorted rows (`_run_extremes`); where the partial is whole
+  the dense key space too is answered from its sorted groups, cut to the
+  ORDER BY ... LIMIT on the device (`_grouped_sparse`, `every_row`).
 
 There is no 10k-doc batching loop (`DocIdSetPlanNode.MAX_DOC_PER_CALL`): the TPU analog of
 batching is the grid XLA tiles over the padded row axis. Kernels are cached by structural
@@ -135,10 +138,11 @@ class KernelSpec:
     # the ranges, so segments whose min/max differ share a program.
     int_ranges: Dict[str, Optional[Tuple[int, int]]] = field(
         default_factory=dict)
-    # the ORDER BY ... LIMIT a sparse GROUP BY (`sparse`) cuts on the device
-    # where its partial is the whole answer: (k, ((source, desc), ...)), a
-    # source ("out", an output's name: "count", "<i>.sum", "<i>.min", ...)
-    # or ("key", group column j); () = no cut (`executor.sparse_trim_spec`)
+    # the ORDER BY ... LIMIT a sort-regime GROUP BY (`sparse`, `partitioned`)
+    # cuts on the device where its partial is the whole answer: (k, ((source,
+    # desc), ...)), a source ("out", an output's name: "count", "<i>.sum",
+    # "<i>.min", ...) or ("key", group column j); () = no cut
+    # (`executor.sort_trim_spec`)
     trim: Tuple = ()
 
     # per-leaf runtime input routing, computed in __post_init__
@@ -439,6 +443,31 @@ def trimmed(spec: KernelSpec) -> bool:
     """Whether such a launch also cuts the ORDER BY ... LIMIT on the device
     (what `deviceTrimmedLaunches` counts): its partial is the whole answer."""
     return sparse(spec) and bool(spec.trim)
+
+
+def partitioned(spec: KernelSpec) -> bool:
+    """Whether a launch of `spec` takes the sort regime of a dense key space:
+    past `chunk_cap` padded keys and not past `dense_keys`
+    (`_grouped_partitioned`'s table, or where it is cut its sorted
+    groups)."""
+    return (bool(spec.group_cols) and not sparse(spec)
+            and spec.num_keys_pad + 1 > get_caps().chunk_cap)
+
+
+def dense_trimmed(spec: KernelSpec) -> bool:
+    """Whether such a launch cuts its dense key space to the ORDER BY ...
+    LIMIT on the device, from its sorted groups and with no table (what
+    `denseTrimmedLaunches` counts; `_grouped_sparse`, `every_row`)."""
+    return partitioned(spec) and bool(spec.trim)
+
+
+def sorted_minmax(spec: KernelSpec) -> bool:
+    """Whether a launch of `spec` takes its GROUP BY's MINs and MAXs from the
+    rows a sort regime sorted (what `sortedMinMaxLaunches` counts), with no
+    scatter over the rows."""
+    return (sparse(spec) or partitioned(spec)) and any(
+        o in ("min", "max") for _, outs in spec.aggs
+        if "distinct" not in outs for o in outs)
 
 
 def slabbed(spec: KernelSpec, rows: int) -> bool:
@@ -923,6 +952,15 @@ def _run_totals(head: jnp.ndarray, v: jnp.ndarray):
              ).reshape(r, length))
 
 
+def _extreme_ident(dtype, is_min: bool):
+    """Where a MIN (`is_min`) or a MAX of `dtype` starts: the value every
+    other one replaces."""
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.inf if is_min else -jnp.inf
+    info = jnp.iinfo(dtype)
+    return info.max if is_min else info.min
+
+
 def _run_extremes(head: jnp.ndarray, v: jnp.ndarray, is_min: bool):
     """The least (`is_min`) or the greatest of `v` [L] over every run of rows
     restarting wherever `head` [L] is set, in `v`'s dtype (an int32 MIN of a
@@ -930,11 +968,7 @@ def _run_extremes(head: jnp.ndarray, v: jnp.ndarray, is_min: bool):
     two levels of `_run_totals`, with a min or a max in place of the sum."""
     length = v.size
     nb, w = length // SCAN_WIDTH, SCAN_WIDTH
-    if jnp.issubdtype(v.dtype, jnp.floating):
-        ident = jnp.inf if is_min else -jnp.inf
-    else:
-        info = jnp.iinfo(v.dtype)
-        ident = info.max if is_min else info.min
+    ident = _extreme_ident(v.dtype, is_min)
     fold = jnp.minimum if is_min else jnp.maximum
     at = jnp.arange(w, dtype=jnp.int32)
     upto = jnp.tri(w, dtype=bool)
@@ -953,7 +987,8 @@ def _run_extremes(head: jnp.ndarray, v: jnp.ndarray, is_min: bool):
     return jnp.where(seen, ext, fold(ext, owed[:, None])).reshape(length)
 
 
-def _compact_decode(key_c: jnp.ndarray, vals_c, m, nseg: int, rows: int):
+def _compact_decode(key_c: jnp.ndarray, vals_c, m, nseg: int, rows: int,
+                    exts=()):
     """Dense [nseg] counts and sums from the sorted PREFIX `key_c`, `vals_c`
     (the first rows of the sorted array), of which the first `m` passed the
     filter; rows past m carry the overflow key nseg-1 and zero values. Work is
@@ -967,8 +1002,11 @@ def _compact_decode(key_c: jnp.ndarray, vals_c, m, nseg: int, rows: int):
     scatter-add of every row, which adds a run in row order; PR 29's chip
     probe). Counts are run
     lengths, int32 throughout. `rows` is the real (unpadded) row count: the
-    overflow bucket holds rows - m, as the dense decode's does.
-    Returns [int32 counts[nseg], f32 sums[nseg]...]."""
+    overflow bucket holds rows - m, as the dense decode's does. `exts` are
+    (j, is_min) pairs: a MIN (True) or a MAX of the j-th of the rows that
+    follow the sums in `vals_c`, in its own dtype (`_extreme_rows`): each
+    run's extreme (`_run_extremes`) set at its tail the same way.
+    Returns [int32 counts[nseg], f32 sums[nseg]..., extremes[nseg]...]."""
     short = (-key_c.size) % SCAN_WIDTH
     if short:   # more rows that did not pass
         key_c = jnp.pad(key_c, (0, short), constant_values=nseg - 1)
@@ -982,17 +1020,22 @@ def _compact_decode(key_c: jnp.ndarray, vals_c, m, nseg: int, rows: int):
     # there is one, did not pass)
     tail = (pos < m) & ((key_c != nxt) | (pos == length - 1))
     head = jnp.concatenate([jnp.ones((1,), bool), key_c[1:] != key_c[:-1]])
-    v = jnp.stack(vals_c) if vals_c else jnp.zeros((0, length), jnp.float32)
+    sums_c, exts_c = _split_extremes(vals_c, exts)
+    v = jnp.stack(sums_c) if sums_c else jnp.zeros((0, length), jnp.float32)
     lengths, totals = _run_totals(head, v)
     idx = jnp.where(tail, key_c, nseg + pos)
     put = lambda zero, upd: zero.at[idx].set(        # noqa: E731
         upd, unique_indices=True, mode="drop")
     counts = put(jnp.zeros((nseg,), jnp.int32), lengths)
     return [counts.at[nseg - 1].set(jnp.int32(rows) - m)] + [
-        put(jnp.zeros((nseg,), jnp.float32), t) for t in totals]
+        put(jnp.zeros((nseg,), jnp.float32), t) for t in totals] + [
+        put(jnp.full((nseg,), _extreme_ident(exts_c[j].dtype, is_min),
+                     exts_c[j].dtype), _run_extremes(head, exts_c[j], is_min))
+        for j, is_min in exts]
 
 
-def _decode_sorted(key_s, vals_s, m, nseg: int, rows: int, rungs, dense=None):
+def _decode_sorted(key_s, vals_s, m, nseg: int, rows: int, rungs, dense=None,
+                   exts=()):
     """`_compact_decode` over the shortest of the prefixes `rungs` of the
     sorted rows that holds the `m` rows that passed; past the last of them
     `dense` (where there is none the caller knows the last rung holds them).
@@ -1003,7 +1046,7 @@ def _decode_sorted(key_s, vals_s, m, nseg: int, rows: int, rungs, dense=None):
             with jax.named_scope("pinot.groupby.partitioned.compact"):
                 return _compact_decode(key_s[:length],
                                        [v[:length] for v in vals_s], m, nseg,
-                                       rows)
+                                       rows, exts)
         return branch
 
     def dense_branch():
@@ -1201,15 +1244,19 @@ def _compacted_sums(key: jnp.ndarray, nseg: int, rows, regime, took=None,
         lambda: _slab_sums(key, nseg, rows, regime)])
 
 
-def _dense_decode(key_s, vals_s, nseg: int, pad: int, block: int):
-    """The per-key decode of the sorted rows (`_grouped_partitioned`)."""
+def _dense_decode(key_s, vals_s, nseg: int, pad: int, block: int, exts=()):
+    """The per-key decode of the sorted rows (`_grouped_partitioned`); `exts`
+    name the MINs and MAXs among the rows that follow the sums
+    (`_compact_decode`), each key's the extreme of its run at its last sorted
+    row (`_sorted_extremes`)."""
     n = key_s.size
     nb = n // block
     with jax.named_scope("pinot.groupby.partitioned.trim"):
         left, counts = _counts_from_sorted(key_s, nseg, pad)
     outs = [counts]
+    vals_s, exts_s = _split_extremes(vals_s, exts)
     if not vals_s:
-        return outs
+        return outs + _sorted_extremes(key_s, exts_s, exts, left, counts)
     with jax.named_scope("pinot.groupby.partitioned.scan"):
         head = jnp.concatenate([jnp.ones((1,), bool),
                                 key_s[1:] != key_s[:-1]])
@@ -1255,11 +1302,49 @@ def _dense_decode(key_s, vals_s, nseg: int, pad: int, block: int):
             total = jnp.where(j0 == 0, tail,
                               start + jnp.where(g1 > g0, tail, 0.0))
             outs.append(jnp.where(occ, total, 0.0))
-    return outs
+    return outs + _sorted_extremes(key_s, exts_s, exts, left, counts)
+
+
+def _extreme_rows(ext_rows):
+    """(values, is_min) pairs as the rows a sort carries and (j, is_min)
+    pairs naming them: a MIN and a MAX of one column (the same traced row)
+    ride the sort as one operand, since each operand costs the compiler and
+    the sort its share."""
+    rows, exts = [], []
+    for v, is_min in ext_rows:
+        j = next((j for j, r in enumerate(rows) if r is v), None)
+        if j is None:
+            j = len(rows)
+            rows.append(v)
+        exts.append((j, is_min))
+    return rows, tuple(exts)
+
+
+def _split_extremes(vals, exts):
+    """`vals` as (the sum rows, the rows `exts` name after them)."""
+    n = len({j for j, _ in exts})
+    return vals[:len(vals) - n], vals[len(vals) - n:]
+
+
+def _sorted_extremes(key_s, exts_s, exts, left, counts):
+    """Each key's MIN or MAX, as `exts` names them, of the rows `exts_s` the
+    sort carried with it: the run's extreme at every row (`_run_extremes`,
+    two levels of fused reduces, no scatter), read at the key's last sorted
+    row `left[k + 1] - 1`; a key no row holds reads the fold's start."""
+    if not exts:
+        return []
+    with jax.named_scope("pinot.groupby.partitioned.minmax"):
+        head = jnp.concatenate([jnp.ones((1,), bool),
+                                key_s[1:] != key_s[:-1]])
+        last = jnp.maximum(left[1:] - 1, 0)
+        return [jnp.where(counts > 0,
+                          _run_extremes(head, exts_s[j], is_min)[last],
+                          _extreme_ident(exts_s[j].dtype, is_min))
+                for j, is_min in exts]
 
 
 def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
-                         block: int = 4096, took=None):
+                         block: int = 4096, took=None, ext_rows=()):
     """Two-level radix-partitioned sort group-by — the one regime past
     `chunk_cap` keys, at any row count.
 
@@ -1299,19 +1384,25 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
     its ladder run as before: such a table costs what it did plus the one
     count. `took` (a list, or None) collects the scalars of
     `qstats.DECODE_FLAGS`: a compact decode ran, the compacted sort ran.
-    Returns [int32 counts[nseg], f32 sums[nseg]...].
+    `ext_rows`, (values, is_min) pairs, ride the sort beside the sum rows,
+    in their own dtype, and each key's MIN or MAX is its run's (as
+    `_grouped_sparse` takes them): no scatter over the rows.
+    Returns [int32 counts[nseg], f32 sums[nseg]..., extremes[nseg]...].
     """
     rows = key.size
     cap = compact_cap(rows + (-rows) % block, nseg, block)
+    ext_vals, exts = _extreme_rows(ext_rows)
+    value_rows = list(value_rows) + ext_vals
 
     def full(m=None):
         with jax.named_scope("pinot.groupby.partitioned.sort"):
             key_s, vals_s, pad = _sort_by_key(key, nseg, value_rows, block)
-        dense = lambda: _dense_decode(key_s, vals_s, nseg, pad, block)  # noqa: E731
+        dense = lambda: _dense_decode(key_s, vals_s, nseg, pad, block,  # noqa: E731
+                                      exts)
         if m is None:
             return dense()
         return _decode_sorted(key_s, vals_s, m, nseg, rows,
-                              compact_rungs(cap), dense)
+                              compact_rungs(cap), dense, exts)
 
     if not cap:
         return full()
@@ -1339,7 +1430,8 @@ def _grouped_partitioned(key: jnp.ndarray, nseg: int, value_rows,
                        for s in PRESORT_SLOTS[:-1])
             key_s, vals_s = jax.lax.switch(
                 step, [moved(s) for s in PRESORT_SLOTS])
-        return _decode_sorted(key_s, vals_s, m, nseg, rows, compact_rungs(cap))
+        return _decode_sorted(key_s, vals_s, m, nseg, rows, compact_rungs(cap),
+                              exts=exts)
 
     fits = (m <= cap) & (most <= PRESORT_SLOTS[-1])
     if took is not None:
@@ -1391,8 +1483,16 @@ def _sorted_groups(key_c, sums_c, ext_c, m, nseg: int):
     nxt = jnp.concatenate([key_c[1:], jnp.full((1,), nseg - 1, key_c.dtype)])
     tail = (pos < m) & ((key_c != nxt) | (pos == length - 1))
     head = jnp.concatenate([jnp.ones((1,), bool), key_c[1:] != key_c[:-1]])
-    v = jnp.stack(sums_c) if sums_c else jnp.zeros((0, length), jnp.float32)
-    lengths, totals = _run_totals(head, v)
+    if len(sums_c) > 1:
+        # a reduce a row: over rows stacked, the compiler writes the runs'
+        # [L / W, W, W] mask out (16 GB at 2^26 sorted rows) where one row
+        # alone fuses it into its reduce
+        lengths = _run_totals(head, sums_c[0][None])[0]
+        totals = [_run_totals(head, v[None])[1][0] for v in sums_c]
+    else:
+        v = jnp.stack(sums_c) if sums_c else jnp.zeros((0, length),
+                                                       jnp.float32)
+        lengths, totals = _run_totals(head, v)
     extremes = [_run_extremes(head, e, is_min) for e, is_min in ext_c]
     return tail, [lengths] + list(totals) + extremes
 
@@ -1419,7 +1519,7 @@ def _kth_largest(u, active, need):
         hits = jnp.sum(active & (u >= cand), dtype=jnp.int32)
         return jnp.where(hits >= need, cand, t)
     start = jnp.uint32(0)
-    chips = tuple(jax.typeof(u).vma)
+    chips = tuple(sorted(set(jax.typeof(u).vma) | set(jax.typeof(active).vma)))
     if chips:   # under shard_map the threshold varies by chip, as `u` does
         start = jax.lax.pcast(start, chips, to="varying")
     return jax.lax.fori_loop(0, 32, bit, start)
@@ -1457,7 +1557,8 @@ def _trim_groups(trim, key_c, tail, cols, strides, n_cols: int):
 
 # The most slots `_pick_rows` fills by a masked reduce over [k, rows]; past
 # it a scatter. On the v5e a scatter of the 1M-row prefix into ten slots took
-# 5.1 ms an output, a third of TPC-H Q3's device time (PERF.md, section 5)
+# 5.1 ms an output, a third of TPC-H Q3's device time (PERF.md, section 5).
+# Also the largest k a dense table is cut to (`executor.sort_trim_spec`)
 PICK_REDUCE_MAX = 256
 
 
@@ -1492,7 +1593,9 @@ def _scatter_groups(key_c, tail, cols, fill: int, size: int):
 
 
 def _grouped_sparse(key: jnp.ndarray, nseg: int, sum_rows, ext_rows,
-                    took=None, trim=(), strides=None, n_cols: int = 1):
+                    took=None, trim=(), strides=None, n_cols: int = 1,
+                    every_row: bool = False,
+                    name: str = "pinot.groupby.sparse"):
     """The sort regime past `KernelCaps.dense_keys`: a GROUP BY answered from
     its SORTED GROUPS, the keys that occur and their counts, sums and MINs /
     MAXs, never a table of the key space. Its cost is in rows: TPC-H Q3's
@@ -1517,12 +1620,21 @@ def _grouped_sparse(key: jnp.ndarray, nseg: int, sum_rows, ext_rows,
     `_scatter_groups`, `sparse_cap` entries in key order. Returns
     [keys, counts, sums..., extremes...] of that length and the scalars
     (groups, rows): groups is -1 where more than `sparse_cap` rows passed
-    (the caller's host answers)."""
+    (the caller's host answers).
+
+    `every_row` (with `trim`): the sort regime's dense key space cut on the
+    device (`_make_body`, scopes under `name`). Where more rows pass than the
+    prefix holds, the groups and the cut run over every sorted row instead,
+    inside the conditional's branch (two copies of them, one a branch): no
+    table of the key space is built and no key is searched for, where the
+    dense decode's two binary searches for every key cost the v5e about 1.2 s
+    at 2M keys over 67M sorted rows (PERF.md, section 6)."""
     rows = key.size
     cap = sparse_cap(rows)
     over = nseg - 1
-    vals = list(sum_rows) + [v for v, _ in ext_rows]
-    with jax.named_scope("pinot.groupby.sparse.presort"):
+    ext_vals, exts = _extreme_rows(ext_rows)
+    vals = list(sum_rows) + ext_vals
+    with jax.named_scope(f"{name}.presort"):
         key_t, short, passed = _tile_counts(key, nseg)
         m = jnp.sum(passed)
         most = jnp.max(passed)
@@ -1541,31 +1653,41 @@ def _grouped_sparse(key: jnp.ndarray, nseg: int, sum_rows, ext_rows,
         return branch
 
     def presorted():
-        with jax.named_scope("pinot.groupby.sparse.presort"):
+        with jax.named_scope(f"{name}.presort"):
             step = sum((most > s).astype(jnp.int32)
                        for s in PRESORT_SLOTS[:-1])
             return jax.lax.switch(step, [moved(s) for s in PRESORT_SLOTS])
 
     def full():
-        with jax.named_scope("pinot.groupby.sparse.sort"):
-            return prefix(*_sort_by_key(key, nseg, vals, 1)[:2])
+        with jax.named_scope(f"{name}.sort"):
+            key_s, vals_s, _ = _sort_by_key(key, nseg, vals,
+                                            SCAN_WIDTH if every_row else 1)
+        return (key_s, vals_s) if every_row else prefix(key_s, vals_s)
+
+    def answer(key_p, vals_p):
+        """The groups of sorted rows, and their cut or their first `cap`."""
+        with jax.named_scope(f"{name}.groups"):
+            tail, cols = _sorted_groups(
+                key_p, vals_p[:len(sum_rows)],
+                [(vals_p[len(sum_rows) + j], is_min) for j, is_min in exts],
+                m, nseg)
+            groups = jnp.sum(tail, dtype=jnp.int32)
+        if trim:
+            with jax.named_scope("pinot.trim"):
+                return _trim_groups(trim, key_p, tail, cols, strides,
+                                    n_cols), groups
+        with jax.named_scope(f"{name}.groups"):
+            return _scatter_groups(key_p, tail, cols, over, cap), groups
 
     fits = (m <= cap) & (most <= PRESORT_SLOTS[-1])
     if took is not None:
-        took.append({qstats.COMPACT_FLAG: m <= cap, qstats.PRESORT_FLAG: fits})
-    key_p, vals_p = jax.lax.cond(fits, presorted, full)
-    with jax.named_scope("pinot.groupby.sparse.groups"):
-        tail, cols = _sorted_groups(
-            key_p, vals_p[:len(sum_rows)],
-            [(v, is_min) for v, (_, is_min)
-             in zip(vals_p[len(sum_rows):], ext_rows)], m, nseg)
-        groups = jnp.sum(tail, dtype=jnp.int32)
-    if trim:
-        with jax.named_scope("pinot.trim"):
-            out = _trim_groups(trim, key_p, tail, cols, strides, n_cols)
-    else:
-        with jax.named_scope("pinot.groupby.sparse.groups"):
-            out = _scatter_groups(key_p, tail, cols, over, cap)
+        took.append({qstats.COMPACT_FLAG: fits if every_row else m <= cap,
+                     qstats.PRESORT_FLAG: fits})
+    if every_row:
+        out, groups = jax.lax.cond(fits, lambda: answer(*presorted()),
+                                   lambda: answer(*full()))
+        return out, groups, m
+    out, groups = answer(*jax.lax.cond(fits, presorted, full))
     return out, jnp.where(m > cap, -1, groups), m
 
 
@@ -1666,6 +1788,9 @@ def _make_body(spec: KernelSpec):
             # [N, num_seg] -> [1 + n_sums, num_seg])
             sum_rows, sum_names = [fmask], ["count"]
             minmax = []  # (out name, values, is_min)
+            # an argument's row, one for every MIN and MAX of it (a sort
+            # regime carries it once: `_extreme_rows`)
+            extreme_rows = {}
             for ai, (agg, outs) in enumerate(spec.aggs):
                 if "distinct" in outs:
                     with scope("pinot.distinct"):
@@ -1684,24 +1809,28 @@ def _make_body(spec: KernelSpec):
                             sum_rows.append(row * fmask)
                             sum_names.append(f"{ai}.{o}")
                         elif o in ("min", "max"):
-                            minmax.append((f"{ai}.{o}", v.ravel(), o == "min"))
+                            if repr(agg.arg) not in extreme_rows:
+                                extreme_rows[repr(agg.arg)] = v.ravel()
+                            minmax.append((f"{ai}.{o}",
+                                           extreme_rows[repr(agg.arg)],
+                                           o == "min"))
             # the ladder: the padded key count against `KernelCaps` alone. The
             # row count chooses nothing: the masked reduce counts in int32,
             # the matmul regimes in int32 across slabs of at most SLAB_ROWS
             # rows (`_slab_sums`).
             # Each regime: [int32 counts[num_seg], f32 sums[num_seg]...]
+            # the sort regimes' outputs: the count, the sums, then the MINs
+            # and MAXs that ride the sort; and the cut's sources among them
+            cols = sum_names + [name for name, _, _ in minmax]
+            trim = spec.trim and (spec.trim[0], tuple(
+                ((kind, cols.index(src) if kind == "out" else src), desc)
+                for (kind, src), desc in spec.trim[1]))
             if sparse(spec):
                 # a key space past the dense table (whatever the other caps
                 # say, as the planner reads it): the groups that occur,
                 # their MINs and MAXs with them, and where the partial is
                 # the whole answer its ORDER BY ... LIMIT
                 with scope("pinot.groupby.sparse"):
-                    names = (sum_names[1:]
-                             + [name for name, _, _ in minmax])
-                    cols = ["count"] + names
-                    trim = spec.trim and (spec.trim[0], tuple(
-                        ((kind, cols.index(src) if kind == "out" else src),
-                         desc) for (kind, src), desc in spec.trim[1]))
                     res, groups, passed = _grouped_sparse(
                         key, num_seg, sum_rows[1:],
                         [(v, is_min) for _, v, is_min in minmax], took,
@@ -1734,10 +1863,28 @@ def _make_body(spec: KernelSpec):
                         "pinot.groupby.chunk64")
             else:
                 # VERY-HIGH-CARDINALITY group-by (> chunk_cap): the sort
-                # regime, with exact int32 counts and no scatter
+                # regime, with exact int32 counts and no scatter, its MINs and
+                # MAXs from the rows it sorted, and where the partial is the
+                # whole answer its table cut to the ORDER BY ... LIMIT
                 with scope("pinot.groupby.partitioned"):
-                    res = _grouped_partitioned(key, num_seg, sum_rows[1:],
-                                               caps.partition_block, took)
+                    ext = [(v, is_min) for _, v, is_min in minmax]
+                    if trim:
+                        # the cut needs no table of the key space: the
+                        # sorted groups, as past `dense_keys`, over every
+                        # sorted row where many passed
+                        res, groups, passed = _grouped_sparse(
+                            key, num_seg, sum_rows[1:], ext, took, trim,
+                            strides, len(spec.group_cols), every_row=True,
+                            name="pinot.groupby.partitioned")
+                    else:
+                        res = _grouped_partitioned(
+                            key, num_seg, sum_rows[1:], caps.partition_block,
+                            took, ext)
+                    sum_names, minmax = cols, []
+                if trim:
+                    out.update(zip([SPARSE_KEYS] + cols, res))
+                    out[SPARSE_GROUPS], out[SPARSE_ROWS] = groups, passed
+                    res = ()
             if res:
                 out.update(zip(sum_names, res))
             for name, v, is_min in minmax:
@@ -1853,6 +2000,10 @@ def _record_plan(spec: KernelSpec, rows: int) -> None:
         qstats.record(qstats.SPARSE_GROUPBY_LAUNCHES)
     if trimmed(spec):
         qstats.record(qstats.DEVICE_TRIMMED_LAUNCHES)
+    if dense_trimmed(spec):
+        qstats.record(qstats.DENSE_TRIMMED_LAUNCHES)
+    if sorted_minmax(spec):
+        qstats.record(qstats.SORTED_MINMAX_LAUNCHES)
     if slabbed(spec, rows):
         qstats.record(qstats.SLABBED_LAUNCHES)
     if widened(spec):

@@ -46,13 +46,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..engine.datablock import lut_size, padded_rows
-from ..engine.kernels import (KernelSpec, _fence_first_call, gather_free,
-                              masked, slabbed, sparse, tree_bytes, trimmed,
-                              widened)
+from ..engine.kernels import (KernelSpec, _fence_first_call, dense_trimmed,
+                              gather_free, masked, slabbed, sorted_minmax,
+                              sparse, tree_bytes, trimmed, widened)
 from ..query import stats as qstats
 from ..query.aggregates import make_agg
 from ..query.context import SOLE_SERVER, QueryContext, compile_query
-from ..query.executor import ServerQueryExecutor, sparse_trim_spec
+from ..query.executor import ServerQueryExecutor, sort_trim_spec
 from ..query.planner import (build_device_geometry, int_ranges,
                              plan_segment)
 from ..query.predicate import CmpLeaf, LutLeaf, NullLeaf
@@ -1119,11 +1119,11 @@ class MeshQueryExecutor:
         fused_cols = () if star is not None \
             else self._mesh_fused_cols(plan, segments, view)
         # a full result, or a partial the broker routed here alone, is the
-        # whole answer: a sparse GROUP BY cuts its ORDER BY ... LIMIT on the
-        # device
+        # whole answer: a sort-regime GROUP BY cuts its ORDER BY ... LIMIT
+        # on the device, and its partial is then the sorted groups' form
         whole = not partial or bool(ctx.options.get(SOLE_SERVER))
-        trim = sparse_trim_spec(ctx, plan) \
-            if plan.sparse and whole and star is None else ()
+        trim = sort_trim_spec(ctx, plan, self.n_devices) \
+            if whole and star is None else ()
         spec = KernelSpec(plan.filter_prog, plan.group_cols, plan.num_keys_pad,
                           tuple(agg_specs), distinct_lut_sizes, block.rows,
                           mv_cols=_mv_lut_cols(plan, plan.segment),
@@ -1222,7 +1222,7 @@ class MeshQueryExecutor:
                                if orig_ctx.distinct else list(orig_ctx.group_by))
                 return reduce_to_result(orig_ctx, merged, orig_aggs,
                                         group_exprs)
-            if plan.sparse:
+            if plan.sparse or trim:
                 seg_result = self._fallback._decode_sparse_partial(
                     plan, outs, bool(trim))
                 if seg_result is None:
@@ -1285,7 +1285,7 @@ class MeshQueryExecutor:
         # the first num_keys_real entries, so padding rows are never fetched
         trim_keys = (plan.num_keys_pad, plan.num_keys_real) \
             if (partial and plan.group_cols and star is None
-                and not plan.sparse) else (0, 0)
+                and not plan.sparse and not trim) else (0, 0)
         return PreparedDispatch(
             kind="agg", spec=spec, inputs=inputs, s_pad=s_pad,
             rows=block.rows, stack_key=stack_key, dedupe_key=dedupe_key,
@@ -1634,6 +1634,8 @@ class MeshQueryExecutor:
         # from the static plan, once
         is_widened, is_masked = widened(spec), masked(spec)
         is_sparse, is_trimmed = sparse(spec), trimmed(spec)
+        is_dense_trimmed, is_sorted_minmax = (dense_trimmed(spec),
+                                              sorted_minmax(spec))
 
         def fn(inputs):
             compiled = jitted_for(inputs)
@@ -1654,6 +1656,10 @@ class MeshQueryExecutor:
                 qstats.record(qstats.SPARSE_GROUPBY_LAUNCHES)
             if is_trimmed:
                 qstats.record(qstats.DEVICE_TRIMMED_LAUNCHES)
+            if is_dense_trimmed:
+                qstats.record(qstats.DENSE_TRIMMED_LAUNCHES)
+            if is_sorted_minmax:
+                qstats.record(qstats.SORTED_MINMAX_LAUNCHES)
             return compiled(inputs)
 
         fn.jitted_for = jitted_for

@@ -38,7 +38,7 @@ class GapfillSpec:
 # The option a broker sets on the context it hands the one server it routed a
 # query to (`Broker._handle_single`; over the wire the request's `sole`): that
 # server's partial is the whole answer, so an ORDER BY ... LIMIT may be cut on
-# its device (`executor.sparse_trim_spec`). A server whose partial is not
+# its device (`executor.sort_trim_spec`). A server whose partial is not
 # whole (a segment it lacks, a host-tier or consuming part) drops it.
 SOLE_SERVER = "soleServer"
 

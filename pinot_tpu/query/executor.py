@@ -922,20 +922,43 @@ def group_trim_spec(ctx: QueryContext, plan: SegmentPlan):
     return None
 
 
-def sparse_trim_spec(ctx: QueryContext, plan: SegmentPlan) -> Tuple:
-    """`KernelSpec.trim` of a sparse GROUP BY whose partial is the whole
-    answer: (k, ((source, desc), ...)) with k = OFFSET + LIMIT, a source
-    ("key", j) for group column j or ("out", name) for the output that
-    orders an aggregate as the broker does (COUNT, SUM, MIN, MAX); () where
-    the cut cannot be made on the device and the partial stays untrimmed and
-    exact: HAVING (it could keep a group the cut dropped), DISTINCT, gapfill,
-    NULLS FIRST/LAST, an ORDER BY item that is neither, or k out of (0,
-    MAX_DEVICE_TOPK]. Several keys, aggregates and group columns are fine."""
+def sort_trim_spec(ctx: QueryContext, plan: SegmentPlan,
+                   devices: int = 1) -> Tuple:
+    """`KernelSpec.trim` of a sort-regime GROUP BY whose partial is the whole
+    answer, on a mesh of `devices`: (k, ((source, desc), ...)) with k =
+    OFFSET + LIMIT, a source ("key", j) for group column j or ("out", name)
+    for the output that orders an aggregate as the broker does (COUNT, SUM,
+    MIN, MAX); () where the cut cannot be made on the device and the partial
+    stays untrimmed and exact: HAVING (it could keep a group the cut
+    dropped), DISTINCT, gapfill, NULLS FIRST/LAST, an ORDER BY item that is
+    neither, or k out of (0, MAX_DEVICE_TOPK]. Several keys, aggregates and
+    group columns are fine.
+
+    The sorted groups past `dense_keys` (`plan.sparse`) are cut so wherever
+    the rule holds. The sort regime between `chunk_cap` and `dense_keys`
+    keys is answered from its sorted groups and cut (`kernels.
+    _grouped_sparse`, `every_row`) on one device, with no DISTINCT
+    aggregate, and where k is at most `kernels.PICK_REDUCE_MAX`: the k groups
+    are then moved by a masked reduce over the sorted rows, and past it by a
+    scatter of every sorted row (67M at SF10), which costs the device more
+    than the fetch of the table it saves (at most 2^21 keys, 8 MB a value
+    row). A mesh of several devices adds the chips' tables after the
+    kernel: a cut inside it would be one chip's."""
+    from ..engine.caps import get_caps
+    from ..engine.kernels import PICK_REDUCE_MAX
+    if plan.group_cols:
+        build_device_geometry(plan)     # the padded key count
+    dense = (bool(plan.group_cols) and not plan.sparse
+             and plan.num_keys_pad + 1 > get_caps().chunk_cap)
+    if not plan.sparse and not (dense and devices == 1 and not any(
+            "distinct" in a.device_outputs for a in plan.aggs)):
+        return ()
     if (ctx.having is not None or ctx.distinct or ctx.gapfill is not None
             or not ctx.order_by or len(ctx.group_by) != len(plan.group_cols)):
         return ()
     k = ctx.offset + ctx.limit
-    if k <= 0 or k > ServerQueryExecutor.MAX_DEVICE_TOPK:
+    if k <= 0 or k > (ServerQueryExecutor.MAX_DEVICE_TOPK if plan.sparse
+                      else PICK_REDUCE_MAX):
         return ()
     groups = {repr(g): j for j, g in enumerate(ctx.group_by)}
     calls = {repr(c): i for i, c in enumerate(ctx.aggregations)}

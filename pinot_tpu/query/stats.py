@@ -112,6 +112,15 @@ MASKED_GROUPBY_LAUNCHES = "maskedGroupByLaunches"
 # answer). From the static plan (`kernels.sparse`, `kernels.trimmed`)
 SPARSE_GROUPBY_LAUNCHES = "sparseGroupByLaunches"
 DEVICE_TRIMMED_LAUNCHES = "deviceTrimmedLaunches"
+# launches whose GROUP BY took the sort regime of a DENSE key space (past
+# chunk_cap keys, not past dense_keys) and cut its ORDER BY ... LIMIT on the
+# device from its sorted groups, with no table of the key space, the partial
+# being the whole answer (`kernels.dense_trimmed`); and
+# launches whose GROUP BY's MINs and MAXs rode a sort regime's sort, taken
+# from the sorted rows with no scatter (`kernels.sorted_minmax`). From the
+# static plan
+DENSE_TRIMMED_LAUNCHES = "denseTrimmedLaunches"
+SORTED_MINMAX_LAUNCHES = "sortedMinMaxLaunches"
 # launches whose aggregate argument was evaluated WIDENED (PR 36): a `+`, `-`
 # or `*` of INT columns in it can leave int32 by the literals and the columns'
 # min/max the plan holds, so the device computes it in float32 (the host in
@@ -216,6 +225,7 @@ COUNTER_KEYS = (
     FUSED_LAUNCHES, STAGED_LAUNCHES, GATHER_FREE_LAUNCHES, SLABBED_LAUNCHES,
     WIDENED_AGG_LAUNCHES, MASKED_GROUPBY_LAUNCHES,
     SPARSE_GROUPBY_LAUNCHES, DEVICE_TRIMMED_LAUNCHES,
+    DENSE_TRIMMED_LAUNCHES, SORTED_MINMAX_LAUNCHES,
     COMPACT_DECODE_LAUNCHES, DENSE_DECODE_LAUNCHES,
     PRESORT_COMPACT_LAUNCHES, FULL_SORT_LAUNCHES,
     COMPACT_MATMUL_LAUNCHES, FULL_MATMUL_LAUNCHES,

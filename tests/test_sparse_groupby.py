@@ -296,12 +296,12 @@ def test_a_key_space_past_int32_plans_on_the_host(tmp_path):
 
 
 def test_trim_spec_reads_the_order_by(merged_set, small_caps):
-    from pinot_tpu.query.executor import sparse_trim_spec
+    from pinot_tpu.query.executor import sort_trim_spec
     from pinot_tpu.query.planner import plan_segment
 
     def spec(sql):
         ctx = compile_query(sql, SCHEMA)
-        return sparse_trim_spec(ctx, plan_segment(ctx, merged_set[0]))
+        return sort_trim_spec(ctx, plan_segment(ctx, merged_set[0]))
     base = "SELECT k, SUM(price), MIN(day), COUNT(*) FROM o GROUP BY k "
     assert spec(base + "ORDER BY SUM(price) DESC, MIN(day), k LIMIT 10") == (
         10, ((("out", "0.sum"), True), (("out", "1.min"), False),
@@ -335,8 +335,10 @@ PARENT_TEXT = [
      "8cb982fa81fa3284aec016e9380338b8b2d7f94c83897856b0a9f81ebcaa5491"),
     ("COUNT(*), SUM(v)", 1 << 21, 16_384, None,
      "bc508d3dac034e540f156b5855ac90b4883a1950df35ef6a79721c0708a1bbe7"),
+    # re-pinned where the sort regime's MIN came to ride its sort
+    # (`kernels._sorted_extremes`) in place of a segment_min over the rows
     ("MIN(v)", 131_072, 16_384, None,
-     "d9f0fe1842afb3ef0d8d14458bfbf0c629452d4ff022a18cd6f4a0fe046a8ebf"),
+     "7b15e78ab219aba391f94436179e16eadfb2d2965fe9235964db99dbf73732c7"),
     ("DISTINCTCOUNT(d)", 16, 16_384, 8192,
      "dae4aec09f894ff35f27b5d6fbe983cb20c59eb7b747159b60550c566813a930"),
 ]
